@@ -1,0 +1,591 @@
+#!/usr/bin/env python
+"""The quickest proof that the system still starts on the chip.
+
+One process, one pass over the main path — ``StreamEnvironment`` ->
+``ClusterRunner``: host feed in, fused block program, keyed exchange,
+determinant log, in-flight ring, checkpoint fence, kill, causal recovery,
+committed sink out — on whatever TPU JAX finds, with nothing that lets
+it pass off the device:
+
+  K  the kernels alone at deployment width: the Pallas histogram against
+     the XLA scatter and NumPy, the three exchange routes (kernel /
+     scatter / sort) against the per-step exchange, the MXU one-hot
+     gather against NumPy over the whole int32 range, and that
+     ``jax.block_until_ready`` returns only when the work is done.
+  A  the served path, host-fed, at config4's recorded width
+     (``BASELINE.json.configs[3]``, ``bench.bench_config4``): 64 subtasks,
+     a cascading kill of one source, one window and one reduce subtask
+     mid-epoch, recovery. Pass = the committed stream equals a NumPy fold
+     of the same fed records written here, every record exactly once,
+     and the audit ledger shows no divergence.
+  B  the headline deployment as ``bench.py`` builds it (32 subtasks,
+     5.24 GiB of carry on the device, wall-clock causal time, pipelined
+     fence): kill one window subtask over two un-truncated epochs,
+     recover. Pass = recovery's bit-identity verification and the audit
+     validator; peak HBM is printed.
+  C  job A again under a four-chip task mesh, when there are four chips.
+     Pass = committed stream byte-identical to A's, ledgers equal, every
+     sharded carry leaf on four devices at a quarter each.
+
+Exits non-zero, printing no result, when JAX finds no TPU. Times printed
+are host walls for orientation, under no metric's name. The last line of
+stdout is ``{"ok": true, "device": {...}}``.
+"""
+
+import argparse
+import dataclasses
+import gc
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+# --- part A/C: the served deployment ----------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class ServedShape:
+    """config4's width (bench.bench_config4). Two differences from the
+    bench so that answers exist to be checked: the window closes every
+    ``window_steps`` (the bench's 1 << 30 never fires, so its reduce and
+    sink see nothing) and values are drawn from the seed."""
+
+    parallelism: int = 16
+    batch: int = 32
+    num_keys: int = 499
+    edge_capacity: int = 512
+    steps_per_epoch: int = 1024
+    window_steps: int = 64
+    #: single steps run into the kill epoch before the kill (half the
+    #: epoch, so what remains is whole blocks)
+    kill_after: int = 512
+    #: warm + two + the kill epoch + one more
+    epochs: int = 5
+
+    @property
+    def total_steps(self) -> int:
+        return self.epochs * self.steps_per_epoch
+
+
+def make_feed(shape: ServedShape, seed: int) -> np.ndarray:
+    """``[P, total_steps * batch, 2]`` int32 (key, value) records: uniform
+    keys, values in [1, 2^18) so window sums pass 2^24 (the one-hot
+    gathers must be exact past an f32 mantissa) and never cancel to 0."""
+    rng = np.random.RandomState(seed)
+    n = shape.total_steps * shape.batch
+    keys = rng.randint(0, shape.num_keys, (shape.parallelism, n))
+    vals = rng.randint(1, 1 << 18, (shape.parallelism, n))
+    return np.stack([keys, vals], axis=-1).astype(np.int32)
+
+
+def reference_committed(feed: np.ndarray, batch: int, window_steps: int,
+                        steps_run: int) -> np.ndarray:
+    """What the sink must have committed after ``steps_run`` supersteps,
+    as sorted ``[n, 3]`` (key, running sum, window end) rows — a plain
+    NumPy fold, independent of ``clonos_tpu``.
+
+    Source subtask p emits records ``[s*batch, (s+1)*batch)`` of its
+    partition at step s; causal time is the step index. Edges are one
+    step deep, so that batch reaches the window at step s+1 and counts
+    into window ``(s+1) // W``. Window w closes at step ``(w+1)*W``,
+    emitting per key its sum of values (if nonzero) stamped
+    ``(w+1)*W``; the reduce adds it to the key's running sum one step
+    later and the sink sees it one step after that."""
+    w = window_steps
+    n_closed = max(0, (steps_run - 3) // w)
+    keys = feed[:, :, 0]
+    win = np.broadcast_to((np.arange(feed.shape[1]) // batch + 1) // w,
+                          keys.shape)
+    live = win < n_closed
+    sums = np.zeros((n_closed, int(keys.max()) + 1), np.int64)
+    np.add.at(sums, (win[live], keys[live]),
+              feed[:, :, 1][live].astype(np.int64))
+    sums = sums.astype(np.int32)                    # the device's wrap
+    running = np.cumsum(sums, axis=0, dtype=np.int64).astype(np.int32)
+    wi, ki = np.nonzero(sums)
+    rows = np.stack([ki, running[wi, ki], (wi + 1) * w],
+                    axis=1).astype(np.int32)
+    return sort_rows(rows)
+
+
+def sort_rows(rows: np.ndarray) -> np.ndarray:
+    return rows[np.lexsort((rows[:, 1], rows[:, 2], rows[:, 0]))]
+
+
+def build_served_job(shape: ServedShape):
+    from clonos_tpu.api.environment import StreamEnvironment
+
+    p = shape.parallelism
+    env = StreamEnvironment(name="smoke-served", num_key_groups=64,
+                            default_edge_capacity=shape.edge_capacity)
+    (env.host_source(batch_size=shape.batch, parallelism=p)
+        .key_by().window_count(num_keys=shape.num_keys,
+                               window_size=shape.window_steps,
+                               parallelism=p)
+        .key_by().reduce(num_keys=shape.num_keys, parallelism=p)
+        .sink(parallelism=p, transactional=True))
+    return env.build()
+
+
+def run_served(shape: ServedShape, feed: np.ndarray, ckpt_dir: str,
+               seed: int, mesh=None) -> dict:
+    """Drive the served deployment through warm epoch, prewarm, two
+    epochs, a mid-epoch cascading kill, recovery, the rest of that epoch
+    and one more; return what came out and the live runner."""
+    from clonos_tpu.api.feeds import ListFeedReader
+    from clonos_tpu.runtime.cluster import ClusterRunner
+
+    job = build_served_job(shape)
+    spe, p = shape.steps_per_epoch, shape.parallelism
+    t0 = time.monotonic()
+    runner = ClusterRunner(
+        job, steps_per_epoch=spe,
+        log_capacity=1 << (spe * 8 - 1).bit_length(), max_epochs=16,
+        inflight_ring_steps=1 << (spe - 1).bit_length(), seed=seed,
+        logical_time=True, audit=True, checkpoint_dir=ckpt_dir, mesh=mesh)
+    runner.executor.register_feed(0, ListFeedReader(list(feed)))
+    runner.run_epoch(complete_checkpoint=True)
+    prewarm_s = runner.prewarm_recovery()
+    warm_s = time.monotonic() - t0
+    for _ in range(shape.epochs - 3):
+        runner.run_epoch(complete_checkpoint=True)
+    for _ in range(shape.kill_after):
+        runner.step()
+    # One subtask of every class on one path (bench.py's cascading kill).
+    victims = [2 % p, job.subtask_base(1) + (3 % p),
+               job.subtask_base(2) + (7 % p)]
+    runner.inject_failure(victims)
+    t1 = time.monotonic()
+    report = runner.recover()
+    recover_s = time.monotonic() - t1
+    runner.run_epoch(complete_checkpoint=True)
+    runner.run_epoch(complete_checkpoint=True)
+    runner.drain_fence()
+    sink_vid = next(iter(runner.txn_logs))
+    return {
+        "runner": runner, "report": report,
+        "committed": runner.txn_logs[sink_vid].committed_stream(),
+        "ledger": runner.coordinator.read_ledger(),
+        "warm_s": warm_s, "prewarm_s": prewarm_s, "recover_s": recover_s,
+        "steps_run": runner.global_step,
+    }
+
+
+def check_served(shape: ServedShape, feed: np.ndarray, res: dict) -> None:
+    """Part A's pass condition; raises on any miss."""
+    if res["steps_run"] != shape.total_steps:
+        raise AssertionError(
+            f"ran {res['steps_run']} steps, meant {shape.total_steps}")
+    want = reference_committed(feed, shape.batch, shape.window_steps,
+                               res["steps_run"])
+    got = sort_rows(np.asarray(res["committed"], np.int32))
+    if want.shape[0] == 0:
+        raise AssertionError("reference is empty: nothing to check")
+    if got.shape != want.shape or not np.array_equal(got, want):
+        raise AssertionError(
+            f"committed stream != NumPy reference: {got.shape[0]} rows "
+            f"committed, {want.shape[0]} expected"
+            + (f"; first difference at sorted row "
+               f"{int(np.nonzero((got != want).any(axis=1))[0][0])}"
+               if got.shape == want.shape else ""))
+    # The kill falls in the open epoch and every earlier checkpoint
+    # completed, so no CLOSED epoch is replayed: the validator has
+    # nothing to recompute here (part B gives it two epochs) and the
+    # ledger's own verdict is what counts.
+    check_audit(res["runner"], res["report"], min_validated=0)
+
+
+def job_counter(runner, name: str) -> int:
+    return runner.metrics.group(f"job.{runner.job.name}").counter(
+        name).value
+
+
+def check_audit(runner, report, min_validated: int) -> None:
+    div = job_counter(runner, "audit.divergences")
+    val = job_counter(runner, "audit.epochs-validated")
+    if div != 0 or val < min_validated:
+        raise AssertionError(
+            f"audit: {div} divergences, {val} epochs validated")
+    if report.steps_replayed < 1 or report.records_replayed < 1:
+        raise AssertionError(
+            f"recovery replayed nothing: {report.steps_replayed} steps, "
+            f"{report.records_replayed} records")
+
+
+# --- part B: the headline deployment ----------------------------------------
+
+HEADLINE_PAR, HEADLINE_BATCH = 8, 128
+HEADLINE_SPE, HEADLINE_FILL = 4096, 4
+
+
+def build_headline_job():
+    """bench.build_job, verbatim."""
+    from clonos_tpu.api.environment import StreamEnvironment
+
+    env = StreamEnvironment(name="bench-allround", num_key_groups=64,
+                            default_edge_capacity=1024)
+    (env.synthetic_source(vocab=997, batch_size=HEADLINE_BATCH,
+                          parallelism=HEADLINE_PAR)
+        .key_by()
+        .window_count(num_keys=997, window_size=1 << 30, name="window")
+        .key_by()
+        .reduce(num_keys=997, name="reduce")
+        .sink())
+    return env.build()
+
+
+def run_headline(spe: int = HEADLINE_SPE, fill: int = HEADLINE_FILL,
+                 block_steps: int = 1024,
+                 recovery_block_steps: int = 8192) -> dict:
+    """bench.main's runner (sizes from FILL x SPE as there) with the
+    audit on: warm epoch, prewarm, two un-truncated epochs, kill window
+    subtask 1, recover, one more epoch."""
+    from clonos_tpu.runtime.cluster import ClusterRunner
+    from clonos_tpu.runtime.executor import DETS_PER_STEP
+
+    need = fill * spe * DETS_PER_STEP
+    span = max(fill * spe, 2)
+    t0 = time.monotonic()
+    runner = ClusterRunner(
+        build_headline_job(), steps_per_epoch=spe,
+        log_capacity=1 << need.bit_length(), max_epochs=32,
+        inflight_ring_steps=1 << (span - 1).bit_length(),
+        recovery_block_steps=recovery_block_steps,
+        block_steps=block_steps, latency_marker_every=64, seed=7,
+        overlap_epoch=True, audit=True)
+    runner.run_epoch(complete_checkpoint=True)
+    prewarm_s = runner.prewarm_recovery()
+    warm_s = time.monotonic() - t0
+    runner.run_epoch(complete_checkpoint=False)
+    runner.run_epoch(complete_checkpoint=False)
+    runner.inject_failure([HEADLINE_PAR + 1])
+    t1 = time.monotonic()
+    report = runner.recover()
+    recover_s = time.monotonic() - t1
+    runner.run_epoch(complete_checkpoint=True)
+    runner.drain_fence()
+    return {"runner": runner, "report": report, "warm_s": warm_s,
+            "prewarm_s": prewarm_s, "recover_s": recover_s}
+
+
+def carry_bytes(carry) -> int:
+    import jax
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(carry))
+
+
+# --- part C: the mesh --------------------------------------------------------
+
+
+def check_sharded(runner, n_dev: int) -> int:
+    """Every carry leaf the partition rules shard sits on ``n_dev``
+    devices with 1/n of its bytes on each; returns how many there are."""
+    import jax
+    from jax.sharding import PartitionSpec
+
+    compiled = runner.executor.compiled
+    carry = runner.executor.carry
+    want = jax.tree_util.tree_leaves(
+        compiled.carry_shardings(carry),
+        is_leaf=lambda s: hasattr(s, "spec"))
+    n = 0
+    for leaf, ns in zip(jax.tree_util.tree_leaves(carry), want):
+        if ns.spec == PartitionSpec():
+            continue
+        n += 1
+        devs = len(leaf.sharding.device_set)
+        per = leaf.addressable_shards[0].data.nbytes
+        if devs != n_dev or per * n_dev != leaf.nbytes:
+            raise AssertionError(
+                f"carry leaf {leaf.shape} {ns.spec}: on {devs} devices, "
+                f"{per} of {leaf.nbytes} bytes on the first")
+    if n == 0:
+        raise AssertionError("no carry leaf is sharded")
+    return n
+
+
+# --- part K: kernels at width -----------------------------------------------
+
+
+def check_kernels(seed: int) -> None:
+    import jax
+    import jax.numpy as jnp
+    from clonos_tpu.api.records import RecordBatch, zero_invalid
+    from clonos_tpu.obs import trace
+    from clonos_tpu.ops.histogram import KERNEL_MAX_KEYS, keyed_hist
+    from clonos_tpu.ops.matops import onehot_gather_rows
+    from clonos_tpu.parallel import routing
+
+    rng = np.random.RandomState(seed)
+
+    def same(a, b, what):
+        if not np.array_equal(np.asarray(a), np.asarray(b)):
+            raise AssertionError(f"{what}: results differ")
+
+    # Histogram: kernel == scatter == NumPy, at the headline's key count
+    # and at the widest table the exchange may hand the kernel.
+    for shp, nk in (((64, 8, 300), 997), ((16, 1024), KERNEL_MAX_KEYS)):
+        keys = rng.randint(-3, nk + 5, shp).astype(np.int32)
+        vals = rng.randint(-(1 << 20), 1 << 20, shp).astype(np.int32)
+        valid = rng.rand(*shp) < 0.7
+        s1, c1 = keyed_hist(jnp.asarray(keys), jnp.asarray(vals),
+                            jnp.asarray(valid), nk, force="pallas")
+        s2, c2 = keyed_hist(jnp.asarray(keys), jnp.asarray(vals),
+                            jnp.asarray(valid), nk, force="xla")
+        ok = valid & (keys >= 0) & (keys < nk)
+        rows = np.broadcast_to(
+            np.arange(int(np.prod(shp[:-1]))).reshape(shp[:-1] + (1,)),
+            shp)
+        s3 = np.zeros((rows.max() + 1, nk), np.int64)
+        np.add.at(s3, (rows[ok], keys[ok]), vals[ok])
+        same(s1, s2, f"histogram sums nk={nk} kernel vs scatter")
+        same(c1, c2, f"histogram counts nk={nk} kernel vs scatter")
+        same(np.asarray(s1).reshape(-1, nk), s3.astype(np.int32),
+             f"histogram sums nk={nk} kernel vs NumPy")
+        say(f"K histogram {shp} nk={nk}: kernel == scatter == NumPy")
+
+    # Exchange: each route against the per-step (sort) exchange. Shapes
+    # are A's (16 x 32 records, 16 targets); what differs picks the route.
+    def block(k):
+        shp = (k, 16, 32)
+        return zero_invalid(RecordBatch(
+            jnp.asarray(rng.randint(0, 499, shp), jnp.int32),
+            jnp.asarray(rng.randint(-1000, 1000, shp), jnp.int32),
+            jnp.asarray(rng.randint(0, 100, shp), jnp.int32),
+            jnp.asarray(rng.rand(*shp) < 0.7)))
+
+    budget = routing._count_route_budget()
+    sort_k = 1 << (budget // (512 * 17 * 12)).bit_length()
+    tracer = trace.get_tracer()
+    seen = set()
+    for want, k, cap in (("kernel", 64, 512), ("scatter", 64, 2048),
+                         ("sort", sort_k, 512)):
+        b = block(k)
+        n0 = len(tracer.records())
+        got = jax.jit(lambda x: routing.route_hash_block(
+            x, 16, 64, cap))(b)
+        ref = jax.jit(jax.vmap(lambda x: routing.route_hash(
+            x, 16, 64, cap)))(b)
+        for x, y in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(ref)):
+            same(x, y, f"exchange route {want}")
+        took = [r["args"]["route"] for r in tracer.records()[n0:]
+                if r["name"] == "exchange.route"]
+        if took != [want]:
+            raise AssertionError(
+                f"exchange K={k} cap={cap}: took {took}, meant {want} "
+                f"(budget {budget} bytes)")
+        seen.add(want)
+        say(f"K exchange K={k} T=16 cap={cap}: route {want} == per-step "
+            f"exchange")
+    say(f"K exchange count-route budget: {budget} bytes")
+
+    # MXU one-hot gather: exact over the whole int32 range.
+    table = rng.randint(-(1 << 31), (1 << 31) - 1, (512, 8, 997),
+                        dtype=np.int64).astype(np.int32)
+    idx = rng.randint(0, 512, (512, 8)).astype(np.int32)
+    got = jax.jit(onehot_gather_rows)(jnp.asarray(table), jnp.asarray(idx))
+    same(got, table[idx, np.arange(8)[None, :]], "one-hot gather")
+    say("K one-hot f32 HIGHEST gather [512, 8, 997]: bit-exact over int32")
+
+
+def check_block_until_ready() -> None:
+    """Show that ``jax.block_until_ready`` returns only when the work is
+    done: after it, a device->host read of the same result has nothing
+    left to wait for."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def work(x):
+        return jax.lax.fori_loop(
+            0, 2000, lambda _, a: jnp.tanh(a @ a) * 0.5, x)
+
+    x = jnp.full((4096, 4096), 1e-3, jnp.bfloat16)
+    np.asarray(work(x)[0, 0])          # compile + warm both programs
+    t0 = time.monotonic()
+    y = work(x)
+    t1 = time.monotonic()
+    jax.block_until_ready(y)
+    t2 = time.monotonic()
+    np.asarray(y[0, 0])
+    t3 = time.monotonic()
+    say(f"K block_until_ready: dispatch returned after {t1 - t0:.4f}s, "
+        f"block_until_ready after {t2 - t0:.4f}s, d2h read of the same "
+        f"result then took {t3 - t2:.4f}s")
+    if not (t2 - t0) > 5 * (t1 - t0) or not (t3 - t2) < 0.2 * (t2 - t0):
+        raise AssertionError(
+            "block_until_ready did not wait for the device: the read "
+            "after it still waited")
+
+
+# --- main --------------------------------------------------------------------
+
+
+def cache_entries(cache_dir: str) -> int:
+    if not os.path.isdir(cache_dir):
+        return 0
+    return sum(1 for f in os.listdir(cache_dir) if f.endswith("-cache"))
+
+
+def print_routes(tracer, since: int, part: str) -> int:
+    recs = tracer.records()
+    counts = {}
+    for r in recs[since:]:
+        if r["name"] == "exchange.route":
+            a = r["args"]
+            key = (a["route"], a["steps"], a["records"], a["targets"],
+                   a["capacity"])
+            counts[key] = counts.get(key, 0) + 1
+    for (route, k, n, t, cap), c in sorted(counts.items()):
+        say(f"{part} exchange K={k} n={n} T={t} cap={cap}: {route}"
+            f" (traced {c}x)")
+    return len(recs)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=21)
+    ap.add_argument("--parts", default="KABC",
+                    help="which of K, A, B, C to run (C needs A)")
+    args = ap.parse_args(argv)
+    t_start = time.monotonic()
+
+    from clonos_tpu.utils.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
+    import jax
+    import jaxlib
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"chip_smoke: JAX found no TPU (platform {dev.platform}, "
+              f"{dev.device_kind}); nothing was run", file=sys.stderr)
+        return 2
+    n_dev = len(jax.devices())
+    from importlib.metadata import version
+    say(f"device: {dev.device_kind} x{n_dev} (platform {dev.platform}); "
+        f"jax {jax.__version__}, jaxlib {jaxlib.__version__}, "
+        f"libtpu {version('libtpu')}")
+    entries0 = cache_entries(cache_dir)
+    say(f"compile cache: {cache_dir} ({entries0} entries at start)")
+    from clonos_tpu.ops import native
+    say(f"native.available(): {native.available()}")
+    os.makedirs(OUT_DIR, exist_ok=True)
+
+    from clonos_tpu.obs import trace
+    from clonos_tpu.obs.digest import diff_ledgers
+    tracer = trace.configure("chip-smoke", buffer=1 << 16)
+    mark = 0
+    parts = set(args.parts.upper())
+
+    if "K" in parts:
+        t0 = time.monotonic()
+        check_kernels(args.seed)
+        check_block_until_ready()
+        mark = len(tracer.records())
+        say(f"K pass ({time.monotonic() - t0:.1f}s)")
+
+    shape = ServedShape()
+    feed = make_feed(shape, args.seed)
+    served = None
+    if "A" in parts:
+        t0 = time.monotonic()
+        res = run_served(shape, feed, os.path.join(OUT_DIR, "ckpt-a"),
+                         args.seed)
+        check_served(shape, feed, res)
+        rep = res["report"]
+        say(f"A {res['runner'].job.total_subtasks()} subtasks, "
+            f"{shape.total_steps} steps: fed {feed.shape[0] * feed.shape[1]}"
+            f" records, committed {res['committed'].shape[0]}; killed "
+            f"{list(rep.failed_subtasks)}, replayed {rep.steps_replayed} "
+            f"steps / {rep.records_replayed} records")
+        say(f"A walls: build+warm epoch+prewarm {res['warm_s']:.1f}s "
+            f"(prewarm {res['prewarm_s']:.1f}s), recovery "
+            f"{res['recover_s']:.3f}s, part {time.monotonic() - t0:.1f}s")
+        mark = print_routes(tracer, mark, "A")
+        say("A pass: committed stream == NumPy reference, exactly once; "
+            "audit 0 divergences")
+        served = {"committed": res["committed"], "ledger": res["ledger"]}
+        del res, rep
+        gc.collect()
+
+    if "B" in parts:
+        t0 = time.monotonic()
+        res = run_headline()
+        runner, rep = res["runner"], res["report"]
+        check_audit(runner, rep, min_validated=2)
+        aot_failed = job_counter(runner, "recovery.aot-lower-failed")
+        if aot_failed:
+            raise AssertionError(
+                f"recovery.aot-lower-failed = {aot_failed}")
+        stats = dev.memory_stats()
+        say(f"B {runner.job.total_subtasks()} subtasks, carry "
+            f"{carry_bytes(runner.executor.carry) / 2**30:.2f} GiB on the "
+            f"device; killed {list(rep.failed_subtasks)}, replayed "
+            f"{rep.steps_replayed} steps / {rep.records_replayed} records "
+            f"from epoch {rep.from_epoch}")
+        say(f"B walls: build+warm epoch+prewarm {res['warm_s']:.1f}s "
+            f"(prewarm {res['prewarm_s']:.1f}s), recovery "
+            f"{res['recover_s']:.3f}s, part {time.monotonic() - t0:.1f}s")
+        say(f"B peak_bytes_in_use: {stats['peak_bytes_in_use']} "
+            f"({stats['peak_bytes_in_use'] / 2**30:.2f} GiB of "
+            f"{stats['bytes_limit'] / 2**30:.2f} GiB)")
+        mark = print_routes(tracer, mark, "B")
+        say("B pass: recovery verified bit-identical, audit 0 divergences,"
+            " recovery.aot-lower-failed 0")
+        del res, runner, rep
+        gc.collect()
+
+    if "C" in parts:
+        if n_dev < 4:
+            say(f"mesh: not run ({n_dev} device)")
+        else:
+            if served is None:
+                raise SystemExit("part C compares with part A: run both")
+            from clonos_tpu.parallel import distributed
+            t0 = time.monotonic()
+            res = run_served(shape, feed, os.path.join(OUT_DIR, "ckpt-c"),
+                             args.seed,
+                             mesh=distributed.task_mesh(max_devices=4))
+            check_served(shape, feed, res)
+            if not np.array_equal(res["committed"], served["committed"]):
+                raise AssertionError(
+                    "mesh: committed stream differs from part A's")
+            problems = diff_ledgers(served["ledger"], res["ledger"])
+            if problems:
+                raise AssertionError(f"mesh: ledgers differ: {problems[:4]}")
+            n_sharded = check_sharded(res["runner"], 4)
+            say(f"C walls: build+warm epoch+prewarm {res['warm_s']:.1f}s "
+                f"(prewarm {res['prewarm_s']:.1f}s), recovery "
+                f"{res['recover_s']:.3f}s, part "
+                f"{time.monotonic() - t0:.1f}s")
+            mark = print_routes(tracer, mark, "C")
+            say(f"C pass: 4-device mesh, committed stream byte-identical "
+                f"to A ({res['committed'].shape[0]} rows), "
+                f"diff_ledgers == [] over {len(res['ledger'])} epochs, "
+                f"{n_sharded} sharded carry leaves on 4 devices at a "
+                f"quarter each")
+            del res
+            gc.collect()
+
+    entries1 = cache_entries(cache_dir)
+    say(f"compile cache: {entries1} entries at end "
+        f"({entries1 - entries0} added by this run)")
+    say(f"total {time.monotonic() - t_start:.1f}s; parts run: "
+        f"{''.join(p for p in 'KABC' if p in parts)}")
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": n_dev}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -11,24 +11,85 @@ as chunked compare-accumulate instead: for each 128-record chunk, a
 ``[rows, chunk, key_lanes]`` one-hot compare and an axis reduce — no
 scatter anywhere, ~10x faster (tools/profile_block.py).
 
-On non-TPU backends (the CPU test lane) a bit-identical XLA scatter
-fallback runs instead; ``tests/test_pallas_kernels.py`` pins kernel ==
-fallback in interpret mode.
+Which form runs is decided by the platform the program is lowered for,
+never by a failure: the kernel on a TPU, a bit-identical XLA scatter
+elsewhere (the CPU test lane; ``tests/test_pallas_kernels.py`` pins
+kernel == scatter in interpret mode).
+
+Mosaic kernels cannot be partitioned automatically, so a program lowered
+over a device mesh has to say so: it traces inside :func:`kernel_mesh`
+and the kernel then runs per shard under ``shard_map`` (rows are
+independent, so no operand is gathered to one device).
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec
 
 #: rows per kernel program (VPU sublane count)
 _ROW_TILE = 8
 #: record columns per in-kernel chunk (VPU lane count)
 _COL_CHUNK = 128
+#: largest key count callers may hand the kernel (both variants compile
+#: for v5e up to here under _VMEM_LIMIT_BYTES — tests/test_tpu_aot.py).
+KERNEL_MAX_KEYS = 1 << 14
+#: scoped-VMEM limit requested for the kernel: the compiler's default
+#: (16 MiB on v5e) refuses the sums-and-counts variant from 12288 keys
+#: up; it needs 17.8 MiB at KERNEL_MAX_KEYS.
+_VMEM_LIMIT_BYTES = 32 << 20
+
+#: (mesh, task axis) of the program being traced, or None.
+_MESH_SCOPE: contextvars.ContextVar[
+    Optional[Tuple[jax.sharding.Mesh, str]]] = contextvars.ContextVar(
+        "clonos_kernel_mesh", default=None)
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh: Optional[jax.sharding.Mesh], axis: str):
+    """Trace-time scope for a program lowered over ``mesh``: inside it
+    :func:`keyed_hist` shards its kernel over ``axis``. A ``None`` mesh
+    is no scope (the single-device program)."""
+    if mesh is None:
+        yield
+        return
+    token = _MESH_SCOPE.set((mesh, axis))
+    try:
+        yield
+    finally:
+        _MESH_SCOPE.reset(token)
+
+
+def over_mesh(fn, mesh: Optional[jax.sharding.Mesh], axis: str):
+    """``fn`` wrapped so that tracing it happens inside
+    :func:`kernel_mesh` — what a ``jax.jit`` over mesh-resident
+    arguments is handed."""
+    if mesh is None:
+        return fn
+
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with kernel_mesh(mesh, axis):
+            return fn(*args, **kwargs)
+    return scoped
+
+
+def uses_kernel() -> bool:
+    """True when :func:`keyed_hist` traced here runs the Pallas kernel:
+    the program targets a TPU (the scope's mesh says which devices;
+    without one, the default backend)."""
+    scope = _MESH_SCOPE.get()
+    platform = (scope[0].devices.flat[0].platform if scope is not None
+                else jax.default_backend())
+    return platform == "tpu"
 
 
 def _hist_kernel(keys_ref, vals_ref, sum_ref, cnt_ref):
@@ -95,6 +156,7 @@ def _hist_pallas(keys, vals, valid, nk: int, interpret: bool,
     v = _pad_to(v, 0, _ROW_TILE)
     rp, bp = k.shape
     grid = (rp // _ROW_TILE,)
+    params = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
     spec_in = pl.BlockSpec((_ROW_TILE, bp), lambda i: (i, 0),
                            memory_space=pltpu.VMEM)
     spec_out = pl.BlockSpec((_ROW_TILE, nkp), lambda i: (i, 0),
@@ -106,6 +168,7 @@ def _hist_pallas(keys, vals, valid, nk: int, interpret: bool,
             grid=grid,
             in_specs=[spec_in, spec_in],
             out_specs=spec_out,
+            compiler_params=params,
             interpret=interpret,
         )(k, v)
         return sums[:r, :nk], None
@@ -116,13 +179,14 @@ def _hist_pallas(keys, vals, valid, nk: int, interpret: bool,
         grid=grid,
         in_specs=[spec_in, spec_in],
         out_specs=(spec_out, spec_out),
+        compiler_params=params,
         interpret=interpret,
     )(k, v)
     return sums[:r, :nk], cnts[:r, :nk]
 
 
 def _hist_xla(keys, vals, valid, nk: int):
-    """Scatter-add fallback (bit-identical; used off-TPU)."""
+    """Scatter-add form (bit-identical; what runs off-TPU)."""
     r, b = keys.shape
     row = jnp.broadcast_to(jnp.arange(r, dtype=jnp.int32)[:, None],
                            keys.shape)
@@ -133,6 +197,40 @@ def _hist_xla(keys, vals, valid, nk: int):
     return sums, cnts
 
 
+def _hist_pallas_sharded(keys, vals, valid, nk: int, interpret: bool,
+                         want_counts: bool, mesh, axis: str):
+    """The kernel per shard of ``mesh``'s ``axis``, over the middle dim
+    of ``[L, R, B]`` operands — the LAST leading dim of the caller's:
+    for an operator's ``[K, P, B]`` block that is the subtask axis the
+    carry is already sharded on (no data moves); for an exchange's
+    ``[K, P*B]`` it is the step axis (the reshard is the exchange).
+    R is padded up to the axis size. Returns ``[L, R, nk]`` arrays."""
+    r, b = keys.shape[1:]
+    pad = (-r) % mesh.shape[axis]
+    if pad:
+        keys, vals, valid = (jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+                             for x in (keys, vals, valid))
+
+    def local(k, v, m):
+        shp = k.shape[:2] + (nk,)
+        sums, cnts = _hist_pallas(
+            k.reshape(-1, b), v.reshape(-1, b), m.reshape(-1, b),
+            nk, interpret, want_counts)
+        return ((sums.reshape(shp), cnts.reshape(shp)) if want_counts
+                else sums.reshape(shp))
+
+    spec = PartitionSpec(None, axis, None)
+    # check_vma=False: pallas_call's out_shape carries no varying-axes
+    # annotation in this JAX.
+    out = jax.shard_map(
+        local, mesh=mesh, in_specs=(spec,) * 3,
+        out_specs=(spec, spec) if want_counts else spec,
+        check_vma=False)(keys, vals, valid)
+    if want_counts:
+        return out[0][:, :r], out[1][:, :r]
+    return out[:, :r], None
+
+
 def keyed_hist(keys: jnp.ndarray, vals: jnp.ndarray, valid: jnp.ndarray,
                nk: int, force: str = "", want_counts: bool = True):
     """Per-row keyed sums and counts.
@@ -141,29 +239,34 @@ def keyed_hist(keys: jnp.ndarray, vals: jnp.ndarray, valid: jnp.ndarray,
     Returns ``(sums, counts)`` of shape ``[..., nk]`` — for each row, the
     sum of ``vals`` and the count of records carrying each key in
     ``[0, nk)``. Out-of-range keys are dropped (scatter ``mode=drop``
-    parity). ``force``: "pallas" | "interpret" | "xla" | "" (auto: pallas
-    on TPU, xla elsewhere). ``want_counts=False`` skips the count output
-    (returned as None) — half the kernel work; the aggregation operators
-    only need sums.
+    parity). ``force``: "pallas" | "interpret" | "xla" | "" (by target
+    platform, :func:`uses_kernel`). ``want_counts=False`` skips the count
+    output (returned as None) — half the kernel work; the aggregation
+    operators only need sums. Inside :func:`kernel_mesh` the kernel runs
+    per mesh shard.
     """
     lead = keys.shape[:-1]
     b = keys.shape[-1]
-    r = 1
-    for d in lead:
-        r *= d
-    kf = keys.reshape(r, b)
-    vf = vals.reshape(r, b)
-    mf = valid.reshape(r, b)
-    mode = force or ("pallas" if jax.default_backend() == "tpu" else "xla")
-    if mode == "pallas":
-        sums, cnts = _hist_pallas(kf, vf, mf, nk, False, want_counts)
-    elif mode == "interpret":
-        sums, cnts = _hist_pallas(kf, vf, mf, nk, True, want_counts)
-    else:
+    mode = force or ("pallas" if uses_kernel() else "xla")
+    if mode == "xla":
+        kf, vf, mf = (x.reshape(-1, b) for x in (keys, vals, valid))
         # Out-of-range guard to mirror mode="drop" exactly.
         ok = mf & (kf >= 0) & (kf < nk)
         sums, cnts = _hist_xla(jnp.where(ok, kf, 0), vf, ok, nk)
         if not want_counts:
             cnts = None
+    else:
+        interpret = mode == "interpret"
+        scope = _MESH_SCOPE.get()
+        if scope is None:
+            sums, cnts = _hist_pallas(
+                keys.reshape(-1, b), vals.reshape(-1, b),
+                valid.reshape(-1, b), nk, interpret, want_counts)
+        else:
+            r = keys.shape[-2] if keys.ndim > 1 else 1
+            sums, cnts = _hist_pallas_sharded(
+                keys.reshape(-1, r, b), vals.reshape(-1, r, b),
+                valid.reshape(-1, r, b), nk, interpret, want_counts,
+                *scope)
     return (sums.reshape(lead + (nk,)),
             cnts.reshape(lead + (nk,)) if cnts is not None else None)

@@ -25,7 +25,6 @@ global mesh (SPMD); per-host Python only feeds host-local step inputs.
 from __future__ import annotations
 
 import dataclasses
-import hashlib
 import re
 from typing import Any, Optional, Sequence, Tuple
 
@@ -174,27 +173,6 @@ def named_shardings(tree: Any, mesh: jax.sharding.Mesh,
     return jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), specs,
         is_leaf=lambda s: isinstance(s, PartitionSpec))
-
-
-def mesh_fingerprint(mesh: Optional[jax.sharding.Mesh]) -> str:
-    """Stable short id of a mesh's topology: axis names, axis sizes, and
-    device kind — what XLA partitioning actually depends on (NOT device
-    ordinals, so equivalent meshes on different hosts key identically)."""
-    if mesh is None:
-        return "nomesh"
-    kinds = sorted({d.platform for d in mesh.devices.flat})
-    desc = f"{tuple(mesh.axis_names)}|{tuple(mesh.devices.shape)}|{kinds}"
-    return hashlib.blake2b(desc.encode(), digest_size=6).hexdigest()
-
-
-def spec_fingerprint(specs: Any) -> str:
-    """Stable short id of a PartitionSpec pytree (structure + every
-    spec), for compile-cache keying: sharded and unsharded lowerings of
-    the same HLO-shaped program must never collide."""
-    leaves, treedef = jax.tree_util.tree_flatten(
-        specs, is_leaf=lambda s: isinstance(s, PartitionSpec))
-    desc = repr(treedef) + "|" + "|".join(repr(s) for s in leaves)
-    return hashlib.blake2b(desc.encode(), digest_size=6).hexdigest()
 
 
 def standby_device_order(mesh: jax.sharding.Mesh,

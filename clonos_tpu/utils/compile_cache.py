@@ -1,25 +1,17 @@
-"""Persistent XLA compilation cache (jax_compilation_cache_dir).
+"""Persistent XLA compilation cache: one directory per process.
 
 The failure path is prewarm-compiled at job start; with this cache a
 RESTARTED job pays near-zero for those compiles (the reference's standby
-deploy analog survives process restarts). Safe to share across backends:
-JAX keys entries by HLO + compile-options hash.
+deploy analog survives process restarts), and the first-step executable
+:func:`aot_lower_first_step` produces at prewarm makes a rebooted
+standby's ``finalize.first-step-recompile`` a cache hit.
 
-Mesh-sharded programs get a cache *namespace* of their own: JAX's entry
-key covers HLO + compile options, but a program lowered under an
-8-device mesh and its single-device twin can share module text while
-their executables are incompatible across partitioner versions — so
-:func:`enable_compile_cache` accepts the mesh + PartitionSpec pytree
-and keys a per-sharding subdirectory from their fingerprints. Unsharded
-and sharded runs therefore never collide in the persistent cache.
-
-The standby/bootstrap path wires through here too
-(``ClusterRunner(compile_cache_dir=...)`` /
-``ClusterRunner.bootstrap_standby(compile_cache_dir=...)``): the
-first-step executable :func:`aot_lower_first_step` produces at prewarm
-persists across a process restart, so a rebooted standby's in-bootstrap
-AOT warm is a persistent-cache HIT instead of the full
-``finalize.first-step-recompile`` XLA compile.
+:func:`enable_compile_cache` is the only place the directory is chosen.
+Entry points call it once at start (``bench.py``, ``chip_smoke.py``,
+``cli run|worker|slotworker``, ``tests/conftest.py``); nothing below
+them re-points it. JAX's entry key covers the program, its compile
+options and its device assignment, so sharded and unsharded programs
+share the directory without colliding.
 """
 
 from __future__ import annotations
@@ -28,45 +20,29 @@ import os
 import time
 from typing import Any, Optional
 
-
-def sharding_cache_key(mesh: Optional[Any] = None,
-                       specs: Optional[Any] = None) -> str:
-    """Cache-namespace token for a (mesh, PartitionSpec pytree) pair.
-    ``None``/``None`` (the single-device program) gets its own stable
-    token, so turning sharding on or off switches namespaces."""
-    from clonos_tpu.parallel.distributed import (mesh_fingerprint,
-                                                 spec_fingerprint)
-    mk = mesh_fingerprint(mesh)
-    sk = spec_fingerprint(specs) if specs is not None else "nospec"
-    return f"{mk}-{sk}"
+#: the checkout this package sits in
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
-def enable_compile_cache(cache_dir: str, mesh: Optional[Any] = None,
-                         specs: Optional[Any] = None) -> str:
-    """Point JAX's persistent compile cache at ``cache_dir`` — suffixed
-    with :func:`sharding_cache_key` when a mesh (and optionally the
-    carry's PartitionSpec pytree) is given. Returns the directory used."""
+def enable_compile_cache() -> str:
+    """Turn JAX's persistent compile cache on and return its directory:
+    the one ``JAX_COMPILATION_CACHE_DIR`` names when it is set (JAX reads
+    the variable itself — no directory is set in code, so the cache can
+    be placed from outside), otherwise the fixed ``<checkout>/.jax_cache``.
+    Every compile is persisted, so a second run of the same program adds
+    no entry."""
     import jax
-    if mesh is not None or specs is not None:
-        cache_dir = os.path.join(cache_dir,
-                                 sharding_cache_key(mesh, specs))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # Only compiles past this wall are persisted (dodges churn from
-    # trivial jits). CLONOS_COMPILE_CACHE_MIN_S=0 forces everything in
-    # — small jobs whose block compiles beat 0.5 s still want their
-    # first-step executable to survive a restart.
-    min_s = float(os.environ.get("CLONOS_COMPILE_CACHE_MIN_S", "0.5"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_s)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:                              # pragma: no cover
-        pass  # knob name varies across jax versions
-    return cache_dir
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(_REPO_ROOT, ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir
 
 
 def aot_lower_first_step(executor, metric_group: Optional[Any] = None
-                         ) -> Optional[Any]:
+                         ) -> Any:
     """Ahead-of-time lower + compile the standby's FIRST-STEP program —
     the block program a rehydrating standby dispatches before anything
     else — so its executable is in the persistent cache (and XLA's
@@ -76,24 +52,22 @@ def aot_lower_first_step(executor, metric_group: Optional[Any] = None
 
     Lowering uses the executor's live carry avals + shardings (no
     execution, no donation — ``lower`` only traces). Returns the
-    compiled executable, or None when lowering is unsupported on this
-    backend/version (callers treat AOT warmup as best-effort) — the
-    fallback is NOT silent: it emits a ``recovery.aot-lower-failed``
-    trace instant and, when ``metric_group`` is given, bumps the
-    counter of the same name, so a standby that will pay the cold
-    recompile at failover is visible in ``clonos_tpu top`` now."""
+    compiled executable. A compile error is counted
+    (``recovery.aot-lower-failed`` trace instant and, when
+    ``metric_group`` is given, the counter of the same name) and
+    re-raised: a standby whose first program does not compile cannot
+    take over, and prewarm is where that has to surface."""
     from clonos_tpu.obs.trace import get_tracer
     t0 = time.monotonic()
     try:
         carry = executor.carry      # one read: stable vs concurrent swap
         exe = executor._jit_block.lower(
             carry, executor.first_step_inputs()).compile()
-        get_tracer().complete("recovery.aot-lower",
-                              time.monotonic() - t0)
-        return exe
     except Exception as err:
         get_tracer().event("recovery.aot-lower-failed",
                            error=repr(err)[:200])
         if metric_group is not None:
             metric_group.counter("recovery.aot-lower-failed").inc()
-        return None
+        raise
+    get_tracer().complete("recovery.aot-lower", time.monotonic() - t0)
+    return exe

@@ -1,23 +1,15 @@
-"""Reliable device-completion sync.
+"""Device-completion sync for timing code.
 
-``jax.block_until_ready`` can return before work completes on tunneled /
-experimental backends, so timing code must force a real device→host read.
-This is the single shared copy of that workaround (bench.py and the
-tools/ profilers import it).
+JAX dispatch is asynchronous: a timing that does not wait for the result
+measures the enqueue. ``jax.block_until_ready`` returns only when the
+work is done (``chip_smoke.py`` shows it once per run, against a
+device→host read of the same result), so that is the whole of it.
 """
 
 from __future__ import annotations
 
-import numpy as np
-
 
 def device_sync(tree) -> None:
-    """Block until ``tree``'s device work is actually finished by reading
-    one element of one leaf back to the host."""
+    """Block until every array in ``tree`` is computed."""
     import jax
-    leaves = [x for x in jax.tree_util.tree_leaves(tree)
-              if hasattr(x, "shape")]
-    if not leaves:
-        return
-    x = leaves[0]
-    np.asarray(x.ravel()[0] if getattr(x, "ndim", 0) else x)
+    jax.block_until_ready(tree)

@@ -1,0 +1,61 @@
+"""``chip_smoke.py`` on the CPU at a tiny size, so chip time is never
+spent on a Python error: part A's builders, its NumPy reference and its
+pass condition (kill, recover, reference equality), part B's sequence and
+audit check, and the refusal to run without a TPU."""
+
+import numpy as np
+import pytest
+
+import chip_smoke
+
+
+def test_reference_fold_by_hand():
+    """Two source subtasks, one record per step, windows of two steps:
+    batch s counts into window (s+1)//2; window w's rows are stamped
+    2(w+1) and carry the key's running sum."""
+    feed = np.array([[[0, 5], [0, 7], [1, 2], [0, 1], [0, 9], [1, 4]],
+                     [[1, 3], [0, 1], [1, 8], [1, 6], [0, 2], [0, 3]]],
+                    np.int32)
+    # windows: w0 <- step 0; w1 <- steps 1, 2; w2 <- steps 3, 4.
+    # 9 steps run: windows with 2(w+1) + 2 <= 8 are visible -> w0..w2.
+    got = chip_smoke.reference_committed(feed, batch=1, window_steps=2,
+                                         steps_run=9)
+    want = np.array([[0, 5, 2], [0, 13, 4], [0, 25, 6],
+                     [1, 3, 2], [1, 13, 4], [1, 19, 6]], np.int32)
+    np.testing.assert_array_equal(got, want)
+    # One step fewer and window 2's rows have not reached the sink.
+    got8 = chip_smoke.reference_committed(feed, 1, 2, steps_run=8)
+    np.testing.assert_array_equal(got8, want[[0, 1, 3, 4]])
+
+
+def test_part_a_tiny_kill_recover_matches_reference(tmp_path):
+    shape = chip_smoke.ServedShape(
+        parallelism=4, batch=4, num_keys=13, edge_capacity=16,
+        steps_per_epoch=16, window_steps=4, kill_after=8)
+    feed = chip_smoke.make_feed(shape, seed=3)
+    res = chip_smoke.run_served(shape, feed, str(tmp_path / "ck"), seed=3)
+    rep = res["report"]
+    assert len(rep.failed_subtasks) == 3 and rep.steps_replayed == 8
+    chip_smoke.check_served(shape, feed, res)
+    # The check has teeth: a lost and a duplicated record both fail it.
+    for bad in (res["committed"][1:],
+                np.concatenate([res["committed"], res["committed"][:1]])):
+        with pytest.raises(AssertionError, match="NumPy reference"):
+            chip_smoke.check_served(shape, feed, dict(res, committed=bad))
+
+
+def test_part_b_tiny_kill_recover_audited():
+    res = chip_smoke.run_headline(spe=16, fill=4, block_steps=8,
+                                  recovery_block_steps=32)
+    rep = res["report"]
+    assert rep.failed_subtasks == (chip_smoke.HEADLINE_PAR + 1,)
+    assert rep.steps_replayed == 32 and rep.from_epoch == 1
+    chip_smoke.check_audit(res["runner"], rep, min_validated=2)
+    assert chip_smoke.job_counter(res["runner"],
+                                  "recovery.aot-lower-failed") == 0
+
+
+def test_main_refuses_to_run_without_a_tpu(capsys):
+    assert chip_smoke.main([]) != 0
+    out = capsys.readouterr()
+    assert out.out == "" and "no TPU" in out.err

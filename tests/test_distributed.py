@@ -1,5 +1,4 @@
-"""Rule-driven carry partitioning (parallel/distributed.py), the
-sharding-aware compile-cache namespaces (utils/compile_cache.py),
+"""Rule-driven carry partitioning (parallel/distributed.py),
 per-shard snapshot slicing (runtime/checkpoint.py), and the lint's
 pjit/shard_map traced-scope detection — all host-side and fast."""
 
@@ -12,8 +11,6 @@ import numpy as np
 import pytest
 
 from clonos_tpu.parallel import distributed as dist
-from clonos_tpu.utils.compile_cache import (enable_compile_cache,
-                                            sharding_cache_key)
 
 P = jax.sharding.PartitionSpec
 
@@ -65,45 +62,6 @@ def test_named_shardings_wrap_the_specs():
     leaf = ns["op_states"][0]["acc"]
     assert isinstance(leaf, jax.sharding.NamedSharding)
     assert leaf.spec == P("tasks") and leaf.mesh.shape["tasks"] == 2
-
-
-def test_mesh_and_spec_fingerprints():
-    assert dist.mesh_fingerprint(None) == "nomesh"
-    m1 = dist.task_mesh(max_devices=1)
-    f1 = dist.mesh_fingerprint(m1)
-    assert f1 != "nomesh" and f1 == dist.mesh_fingerprint(m1), \
-        "fingerprint is deterministic"
-    if len(jax.devices()) >= 2:
-        m2 = dist.task_mesh(max_devices=2)
-        assert dist.mesh_fingerprint(m2) != f1
-        sa = dist.infer_partition_spec(_tree(8), m2)
-        sb = dist.infer_partition_spec({"epoch": jnp.zeros(())}, m2)
-        assert dist.spec_fingerprint(sa) != dist.spec_fingerprint(sb)
-
-
-def test_sharding_cache_key_namespaces(tmp_path):
-    assert sharding_cache_key() == "nomesh-nospec"
-    m1 = dist.task_mesh(max_devices=1)
-    k1 = sharding_cache_key(mesh=m1)
-    assert k1 != "nomesh-nospec"
-    keys = [sharding_cache_key(), k1]
-    if len(jax.devices()) >= 2:
-        m2 = dist.task_mesh(max_devices=2)
-        keys.append(sharding_cache_key(mesh=m2))
-        keys.append(sharding_cache_key(
-            mesh=m2, specs=dist.infer_partition_spec(_tree(8), m2)))
-    assert len(keys) == len(set(keys)), "namespaces never collide"
-
-    # enable_compile_cache namespaces the directory; restore the session
-    # cache dir afterwards (conftest owns it).
-    old = jax.config.jax_compilation_cache_dir
-    try:
-        used = enable_compile_cache(str(tmp_path / "cc"), mesh=m1)
-        assert used == str(tmp_path / "cc" / k1)
-        import os
-        assert os.path.isdir(used)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
 
 
 def test_snapshot_subtask_slice_and_nbytes():

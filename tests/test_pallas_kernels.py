@@ -1,6 +1,6 @@
 """Pallas log kernels: property equivalence against the XLA scatter path
 (interpret mode on the CPU mesh; the same kernel compiles via Mosaic on
-real TPU — exercised by bench/driver runs)."""
+a TPU — tests/test_tpu_aot.py compiles it, chip_smoke.py runs it)."""
 
 import numpy as np
 import jax
@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import pytest
 
 from clonos_tpu.causal import log as clog
-from clonos_tpu.ops.histogram import keyed_hist
+from clonos_tpu.ops.histogram import kernel_mesh, keyed_hist
 
 
 @pytest.mark.parametrize("b", [100, 128, 300])
@@ -26,6 +26,35 @@ def test_keyed_hist_kernel_matches_xla(b):
     s2, c2 = keyed_hist(keys, vals, valid, nk, force="xla")
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
+
+
+@pytest.mark.parametrize("shape", [(5, 4, 100), (3, 6, 128), (7, 300)])
+@pytest.mark.parametrize("want_counts", [True, False])
+def test_keyed_hist_kernel_per_mesh_shard_matches_xla(shape, want_counts,
+                                                      eight_devices):
+    """Inside ``kernel_mesh`` the kernel runs per shard of the last
+    leading dim (padded up to the mesh when it does not divide) and
+    still equals the scatter — the form every mesh-lowered program
+    takes on a TPU, where a bare Mosaic kernel cannot be partitioned."""
+    rng = np.random.RandomState(2)
+    nk = 13
+    mesh = jax.sharding.Mesh(np.array(eight_devices[:4]), ("tasks",))
+    keys = jnp.asarray(rng.randint(-3, nk + 4, shape), jnp.int32)
+    vals = jnp.asarray(rng.randint(-50, 50, shape), jnp.int32)
+    valid = jnp.asarray(rng.rand(*shape) < 0.7)
+
+    def sharded(k, v, m):
+        with kernel_mesh(mesh, "tasks"):
+            return keyed_hist(k, v, m, nk, force="interpret",
+                              want_counts=want_counts)
+
+    s1, c1 = jax.jit(sharded)(keys, vals, valid)
+    s2, c2 = keyed_hist(keys, vals, valid, nk, force="xla")
+    np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
+    if want_counts:
+        np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
+    else:
+        assert c1 is None
 
 
 @pytest.mark.parametrize("cap,sizes", [

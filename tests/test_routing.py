@@ -129,7 +129,7 @@ def test_block_routes_bit_identical_to_per_step(cap, K, P, B, force_sort,
     speed; semantics are pinned here)."""
     import jax
     if force_sort:   # shrink the scratch budget so the sort path runs
-        monkeypatch.setattr(routing, "_COUNT_ROUTE_MAX_BYTES", 0)
+        monkeypatch.setattr(routing, "_count_route_budget", lambda: 0)
     rng = np.random.RandomState(3)
     batch = _rand_block(rng, K, P, B)
     for T, G in [(4, 8), (1, 4), (5, 20)]:

@@ -1,0 +1,84 @@
+"""Compile for a v5e 2x2 without a chip: ``jax.experimental.topologies``
+describes the devices and ``jit(...).lower(abstract args).compile()``
+accepts them. A pre-check that saves chip time, not evidence — the
+evidence is ``chip_smoke.py`` on the chip. Pinned here are the two
+failures that bring-up found: a kernel shape the routing guard admits
+but the compiler refused for VMEM, and the Mosaic kernel inside a
+program partitioned over a mesh.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
+
+from clonos_tpu.ops import histogram
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as err:       # no libtpu / unknown topology name
+        pytest.skip(f"cannot describe a v5e topology here: {err!r}")
+    assert topo.devices[0].platform == "tpu" and len(topo.devices) == 4
+    # A compile-only client cannot load executables back, so an entry
+    # written here could never hit: keep these out of the persistent
+    # cache (the floor is read at write time).
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
+    yield topo.devices
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
+
+
+@pytest.mark.parametrize("want_counts", [True, False])
+def test_hist_kernel_compiles_at_widest_admitted_table(v5e, want_counts):
+    """Every slot-table width the exchange guard admits
+    (``nk <= KERNEL_MAX_KEYS``) must compile, counts and sums-only: the
+    compiler's default scoped-VMEM limit refused the counts kernel from
+    12288 keys up."""
+    sh = SingleDeviceSharding(v5e[0])
+    arg = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=sh)
+    mask = jax.ShapeDtypeStruct((8, 1024), jnp.bool_, sharding=sh)
+    histogram._hist_pallas.lower(
+        arg, arg, mask, histogram.KERNEL_MAX_KEYS, False,
+        want_counts).compile()
+
+
+def test_block_program_lowers_for_four_chip_mesh_with_kernel(v5e):
+    """The flagship block program, pinned over a 4-device v5e mesh,
+    compiles with the Mosaic kernel in it (bare ``pallas_call`` raised
+    'Mosaic kernels cannot be automatically partitioned')."""
+    from clonos_tpu.api.environment import StreamEnvironment
+    from clonos_tpu.obs import trace
+    from clonos_tpu.runtime.executor import BlockInputs, CompiledJob
+
+    mesh = Mesh(np.array(v5e), ("tasks",))
+    env = StreamEnvironment(name="flagship", num_key_groups=64,
+                            default_edge_capacity=128)
+    (env.synthetic_source(vocab=211, batch_size=32, parallelism=4)
+        .key_by().window_count(num_keys=211, window_size=64)
+        .key_by().reduce(num_keys=211).sink())
+    compiled = CompiledJob(env.build(), log_capacity=1 << 10, max_epochs=8,
+                           inflight_ring_steps=16, mesh=mesh)
+    carry = jax.eval_shape(compiled.init_carry)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    steps = jax.ShapeDtypeStruct((8,), jnp.int32)
+    tracer = trace.configure("aot-test")
+    try:
+        lowered = jax.jit(
+            compiled.run_block,
+            in_shardings=(compiled.carry_shardings(carry),
+                          NamedSharding(mesh, PartitionSpec()))
+        ).lower(carry, BlockInputs(steps, steps, scalar, scalar))
+        routes = [r["args"]["route"] for r in tracer.records()
+                  if r["name"] == "exchange.route"]
+    finally:
+        trace.reset()
+    assert routes == ["kernel"], "the exchange must take the kernel path"
+    assert "tpu_custom_call" in lowered.as_text()
+    lowered.compile()
