@@ -272,8 +272,11 @@ class LogReplayer:
     def __init__(self, operator: Operator, parallelism: int,
                  block_steps: int = 512, in_slot_keys=None,
                  pad_steps: Optional[int] = None, mesh=None,
-                 task_axis: str = "tasks"):
+                 task_axis: str = "tasks",
+                 vertex_name: Optional[str] = None):
         self.operator = operator
+        #: the ``vertex/<name>`` named scope of the replay program's ops
+        self.vertex_name = vertex_name or type(operator).__name__
         self.parallelism = parallelism
         self.block_steps = block_steps
         #: fixed upper bound to pad the uploaded time/rng streams to (the
@@ -319,20 +322,21 @@ class LogReplayer:
         bctx = BlockContext(
             times=times, rng_bits=rngs, epoch=jnp.zeros((), jnp.int32),
             step0=jnp.zeros((), jnp.int32), subtask=subtask[None])
-        if isinstance(self.operator, TwoInputOperator):
-            left, right = batches
-            new_state, out = self.operator.process_block(
-                op_state, (lift(left), lift(right)), bctx)
-            consumed = left.count().sum() + right.count().sum()
-        elif self.in_slot_keys is not None and hasattr(
-                self.operator, "process_block_static_keys"):
-            new_state, out = self.operator.process_block_static_keys(
-                op_state, lift(batches), bctx, self.in_slot_keys)
-            consumed = batches.count().sum()
-        else:
-            new_state, out = self.operator.process_block(
-                op_state, lift(batches), bctx)
-            consumed = batches.count().sum()
+        with jax.named_scope(f"vertex/{self.vertex_name}"):
+            if isinstance(self.operator, TwoInputOperator):
+                left, right = batches
+                new_state, out = self.operator.process_block(
+                    op_state, (lift(left), lift(right)), bctx)
+                consumed = left.count().sum() + right.count().sum()
+            elif self.in_slot_keys is not None and hasattr(
+                    self.operator, "process_block_static_keys"):
+                new_state, out = self.operator.process_block_static_keys(
+                    op_state, lift(batches), bctx, self.in_slot_keys)
+                consumed = batches.count().sum()
+            else:
+                new_state, out = self.operator.process_block(
+                    op_state, lift(batches), bctx)
+                consumed = batches.count().sum()
         # Drop the singleton P dim: out [k, 1, cap] -> [k, cap].
         out = jax.tree_util.tree_map(lambda x: x[:, 0], out)
         return (new_state, out, out.count(),

@@ -42,12 +42,14 @@ def _load_job(spec: str):
 
 
 def _setup_tracer(args, service: str):
-    """Opt-in tracing: ``--trace-dir`` installs the process tracer
-    writing trace-<service>.jsonl there. Returns the tracer or None."""
-    if getattr(args, "trace_dir", None) is None:
-        return None
-    import os
+    """Opt-in tracing: ``--trace-dir`` installs the full process tracer
+    writing trace-<service>.jsonl there. Without it the process keeps
+    its local flight recorder (ring only, nothing on the wire), which
+    is what a metrics endpoint's ``/trace`` then serves."""
     from clonos_tpu import obs
+    if getattr(args, "trace_dir", None) is None:
+        return obs.get_tracer()
+    import os
     os.makedirs(args.trace_dir, exist_ok=True)
     return obs.configure(service, path=os.path.join(
         args.trace_dir, f"trace-{service}.jsonl"))

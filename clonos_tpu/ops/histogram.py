@@ -248,25 +248,26 @@ def keyed_hist(keys: jnp.ndarray, vals: jnp.ndarray, valid: jnp.ndarray,
     lead = keys.shape[:-1]
     b = keys.shape[-1]
     mode = force or ("pallas" if uses_kernel() else "xla")
-    if mode == "xla":
-        kf, vf, mf = (x.reshape(-1, b) for x in (keys, vals, valid))
-        # Out-of-range guard to mirror mode="drop" exactly.
-        ok = mf & (kf >= 0) & (kf < nk)
-        sums, cnts = _hist_xla(jnp.where(ok, kf, 0), vf, ok, nk)
-        if not want_counts:
-            cnts = None
-    else:
-        interpret = mode == "interpret"
-        scope = _MESH_SCOPE.get()
-        if scope is None:
-            sums, cnts = _hist_pallas(
-                keys.reshape(-1, b), vals.reshape(-1, b),
-                valid.reshape(-1, b), nk, interpret, want_counts)
+    with jax.named_scope("hist"):      # metadata: groups the kernel's ops
+        if mode == "xla":
+            kf, vf, mf = (x.reshape(-1, b) for x in (keys, vals, valid))
+            # Out-of-range guard to mirror mode="drop" exactly.
+            ok = mf & (kf >= 0) & (kf < nk)
+            sums, cnts = _hist_xla(jnp.where(ok, kf, 0), vf, ok, nk)
+            if not want_counts:
+                cnts = None
         else:
-            r = keys.shape[-2] if keys.ndim > 1 else 1
-            sums, cnts = _hist_pallas_sharded(
-                keys.reshape(-1, r, b), vals.reshape(-1, r, b),
-                valid.reshape(-1, r, b), nk, interpret, want_counts,
-                *scope)
+            interpret = mode == "interpret"
+            scope = _MESH_SCOPE.get()
+            if scope is None:
+                sums, cnts = _hist_pallas(
+                    keys.reshape(-1, b), vals.reshape(-1, b),
+                    valid.reshape(-1, b), nk, interpret, want_counts)
+            else:
+                r = keys.shape[-2] if keys.ndim > 1 else 1
+                sums, cnts = _hist_pallas_sharded(
+                    keys.reshape(-1, r, b), vals.reshape(-1, r, b),
+                    valid.reshape(-1, r, b), nk, interpret, want_counts,
+                    *scope)
     return (sums.reshape(lead + (nk,)),
             cnts.reshape(lead + (nk,)) if cnts is not None else None)

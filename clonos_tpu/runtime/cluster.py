@@ -32,6 +32,7 @@ state — executor.canonical_carry.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -57,6 +58,28 @@ from clonos_tpu.storage import SegmentCorruptError, StorageError
 from clonos_tpu.runtime.executor import (DETS_PER_STEP, JobCarry,
                                          LeanSnapshot, LocalExecutor,
                                          LogicalTimeSource)
+
+
+def _scoped(name: str, fn):
+    """``fn`` traced inside ``jax.named_scope(name)``: metadata on the
+    lowered ops, so a profile groups them; the program does not change."""
+    def scoped(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return scoped
+
+
+@contextlib.contextmanager
+def _fence_phase(phases: Dict[str, float], key: str, prof=None,
+                 section: Optional[str] = None):
+    """One fence phase under one pair of stamps: the ``<key>`` span,
+    whose milliseconds become ``phases[key]`` and, where the phase is a
+    profiler section, its ``overhead.<section>-ms`` sample."""
+    with get_tracer().span(key) as sp:
+        yield sp
+    phases[key] = sp.ms
+    if section is not None:
+        prof.observe(section, sp.dur)
 
 
 class HeartbeatMonitor:
@@ -556,11 +579,28 @@ class ClusterRunner:
             reader.notify_checkpoint_complete([int(x) for x in off])
 
     def _absorb_sink_outputs(self, outs, epoch: int) -> None:
-        for vid, tl in self.txn_logs.items():
-            b = outs.sinks.get(vid)
-            if b is not None:
-                tl.absorb(epoch, np.asarray(b.keys), np.asarray(b.values),
+        """The sink tap after every block, in three spans: waiting out
+        the block program that produced ``outs`` (device busy, not
+        idle), the device-to-host copies, the per-subtask sharding
+        (``TransactionLog.absorb``)."""
+        sinks = {vid: outs.sinks[vid] for vid in self.txn_logs
+                 if vid in outs.sinks}
+        if not sinks:
+            return
+        tr = get_tracer()
+        with tr.span("block.sink.wait"):
+            # the first np.asarray below would wait the same time: the
+            # order of work is unchanged, only its name
+            jax.block_until_ready(sinks)
+        with tr.span("block.sink.d2h") as sp:
+            host = {vid: (np.asarray(b.keys), np.asarray(b.values),
                           np.asarray(b.timestamps), np.asarray(b.valid))
+                    for vid, b in sinks.items()}
+            nbytes = sum(a.nbytes for arrs in host.values() for a in arrs)
+            sp.set(bytes=nbytes)
+        tr.count("sink.d2h_bytes", nbytes)
+        for vid, arrs in host.items():
+            self.txn_logs[vid].absorb(epoch, *arrs)
 
     # --- live health gauges (heartbeat-piggybacked; runtime/remote.py) -------
 
@@ -812,7 +852,7 @@ class ClusterRunner:
             else:
                 r, _ = routing.route_broadcast_block(raw, dst_p, e.capacity)
             return r, raw.count().sum()
-        return body
+        return _scoped("exchange", body)
 
     def _route_body_lane(self, eidx: int, m: int):
         """Single-consumer-lane exchange replay: compute the routed lane
@@ -848,7 +888,7 @@ class ClusterRunner:
                 lane = routing.route_broadcast_block_lane(
                     raw, sub, e.capacity)
             return lane, raw.count().sum()
-        return body
+        return _scoped("exchange", body)
 
     def _route_raw_fn(self, eidx: int, m: int, all_lanes: bool = False):
         """Spill-path twin of :meth:`_route_chunk_fn`: routes a
@@ -994,18 +1034,11 @@ class ClusterRunner:
         # Rebuild-stage sub-attribution: the stages around recover() are
         # the standby-host analog of the finalize phase (everything that
         # must happen besides replay before the job resumes). Each stage
-        # emits a recovery.finalize.<stage> complete under the adopted
-        # recovery trace id and folds into the report's phase_ms.
-        tr = get_tracer()
+        # is a recovery.finalize.<stage> span under the adopted recovery
+        # trace id whose stamps also fold into the report's phase_ms.
         sub_ms: Dict[str, float] = {}
-        t_sub = _time.monotonic()
-
-        def _stage(name: str) -> None:
-            nonlocal t_sub
-            now = _time.monotonic()
-            sub_ms[name] = sub_ms.get(name, 0.0) + (now - t_sub) * 1e3
-            tr.complete(f"recovery.{name}", now - t_sub)
-            t_sub = now
+        stages = get_tracer().chain("recovery.", into=sub_ms)
+        stages.switch("finalize.state-rehydrate")
 
         runner = cls(job, checkpoint_dir=checkpoint_dir, **runner_kw)
         for vid, reader in (feed_readers or {}).items():
@@ -1092,7 +1125,7 @@ class ClusterRunner:
         with runner._ck_heads_lock:
             runner._ck_log_heads[ckpt.checkpoint_id] = np.asarray(
                 ckpt.carry.log_heads).astype(np.int64)
-        _stage("finalize.state-rehydrate")
+        stages.switch("finalize.ring-reregister")
 
         # Overlapped finalize (the tentpole restructure): the roll-gap /
         # async ledger derivation (listener-reattach) is a pure function
@@ -1203,7 +1236,7 @@ class ClusterRunner:
                 latest_epoch=jnp.asarray(from_epoch + k, jnp.int32),
                 epoch_base=jnp.asarray(from_epoch, jnp.int32)))
         runner.executor.carry = c._replace(out_rings=tuple(new_rings))
-        _stage("finalize.ring-reregister")
+        stages.close()
 
         # Everything is failed; recover() rebuilds it all from the
         # checkpoint + mirror rows, in topological order. The ledger
@@ -1214,7 +1247,7 @@ class ClusterRunner:
             runner.heartbeats.mark_dead(f)
         report = runner.recover(host_rows=mirror_rows,
                                 pre_patch_join=_join_ledgers)
-        t_sub = _time.monotonic()    # recover() attributes its own time
+        stages.switch("finalize.edge-rehydrate")  # recover() timed itself
 
         # The depth-1 edge buffers (the in-flight batch produced at step
         # fence+n-1, consumed by the NEXT live step) are not part of
@@ -1245,7 +1278,7 @@ class ClusterRunner:
             bufs = jax.tree_util.tree_map(
                 lambda x: jnp.asarray(x).copy(), ckpt.carry.edge_bufs)
             runner.executor.carry = c._replace(edge_bufs=tuple(bufs))
-        _stage("finalize.edge-rehydrate")
+        stages.close()
 
         # Join the overlap worker (host-RNG fast-forward + first-step
         # AOT warm) — the guarantee the first live step needs: the RNG
@@ -1673,30 +1706,25 @@ class ClusterRunner:
         n = self.executor.steps_per_epoch - self.executor.step_in_epoch
         tr = get_tracer()
         prof = self.profiler
-        epoch_span = tr.span("epoch", epoch=closed, steps=n)
-        epoch_span.__enter__()
-        try:
-            t0 = _time.monotonic()
-            self.executor.run_epoch()
-            if not overlap:
-                # Enabled profiler: fence the carry so "compute"
-                # measures execution, not dispatch (the fused block
-                # program = user compute + in-program causal/ring
-                # appends). Never on the overlapped path — this block
-                # would serialize exactly the window the pipeline
-                # hides, so overlapped "compute" is dispatch wall only.
-                prof.fence(self.executor.carry)
-            steps_s = _time.monotonic() - t0
-            self._m_epoch_steps_ms.update(steps_s * 1e3)
-            tr.complete("epoch.steps", steps_s, epoch=closed, steps=n)
-            prof.observe("compute", steps_s, kind="compute")
+        with tr.span("epoch", epoch=closed, steps=n):
+            with tr.span("epoch.steps") as steps:
+                self.executor.run_epoch()
+                if not overlap:
+                    # Enabled profiler: fence the carry so "compute"
+                    # measures execution, not dispatch (the fused block
+                    # program = user compute + in-program causal/ring
+                    # appends). Never on the overlapped path — this block
+                    # would serialize exactly the window the pipeline
+                    # hides, so overlapped "compute" is dispatch wall only.
+                    prof.fence(self.executor.carry)
+            self._m_epoch_steps_ms.update(steps.ms)
+            prof.observe("compute", steps.dur, kind="compute")
             # The PREVIOUS epoch's tail joins here: after this epoch's
             # compute is dispatched (the tail overlapped it), before any
             # of this fence's state is touched. The join re-raises
             # worker errors, runs the deferred overflow check, and
             # acks/truncates its checkpoint on this (the main) thread.
             self._join_fence_tail()
-            t_fence = _time.monotonic()
             self.global_step += n
             self._fence_step[self.executor.epoch_id] = self.global_step
             self.heartbeats.beat_all_except(self.failed)
@@ -1707,16 +1735,12 @@ class ClusterRunner:
             if overlap:
                 self._begin_fence_tail(closed, complete_checkpoint, prof)
             else:
-                self._run_fence_tail_inline(
-                    closed, complete_checkpoint, t_fence, tr, prof)
+                self._run_fence_tail_inline(closed, complete_checkpoint,
+                                            prof)
             # Close the attribution window: FT seconds / (FT + compute)
             # since the previous fence -> the overhead.ft-fraction
             # gauge (a no-op returning 0.0 on the NullProfiler).
             prof.rollup()
-        except BaseException as e:
-            epoch_span.__exit__(type(e), e, e.__traceback__)
-            raise
-        epoch_span.__exit__(None, None, None)
 
     def _absorb_fence_health(self, closed: int, vec: np.ndarray) -> int:
         """Fold one fence's drained health vector into the host mirrors
@@ -1762,16 +1786,14 @@ class ClusterRunner:
                or self.lineage.enabled else None)
         if self.auditor.enabled:
             from clonos_tpu.obs import audit as _audit_mod
-            t = _time.monotonic()
-            with prof.section("digest-seal"):
+            with _fence_phase(phases, "fence.digest-seal", prof,
+                                   "digest-seal"):
                 dg = _audit_mod.digest_epoch_window(
                     closed, win, layout=self._audit_layout)
                 self.auditor.seal(dg)
-            phases["fence.digest-seal"] = (_time.monotonic() - t) * 1e3
-            t = _time.monotonic()
-            with prof.section("ledger-write"):
+            with _fence_phase(phases, "fence.ledger-write", prof,
+                                   "ledger-write"):
                 self.coordinator.record_ledger(dg.to_entry())
-            phases["fence.ledger-write"] = (_time.monotonic() - t) * 1e3
             if self.executor.spill_logs is not None:
                 # Segment index entries inherit the ledger's channel
                 # fingerprints — spill/refill round-trips become
@@ -1790,35 +1812,30 @@ class ClusterRunner:
             tl.record("epoch.seal", epoch=int(closed),
                       audited=bool(self.auditor.enabled))
         if self.serve_feeds:
-            t = _time.monotonic()
-            for fn in list(self.serve_feeds):
-                fn(closed, win)
-            phases["fence.serve-feed"] = (_time.monotonic() - t) * 1e3
+            with _fence_phase(phases, "fence.serve-feed"):
+                for fn in list(self.serve_feeds):
+                    fn(closed, win)
         # Lineage capture at the seal (obs/lineage.py): scan the same
         # extracted window for dyed keys — plus the epoch's sink
         # transaction shards for termini (complete at the fence in
         # both modes; the pipelined path seals them on the main thread
         # before this worker starts). Null plane: no scan, no file.
         if self.lineage.enabled and win is not None:
-            t = _time.monotonic()
-            self.lineage.observe_epoch(
-                closed, win,
-                num_key_groups=self.job.num_key_groups,
-                topology=self._lineage_topology,
-                parts={vid: tl.pending_shards(closed)
-                       for vid, tl in self.txn_logs.items()})
-            phases["fence.lineage-observe"] = (
-                _time.monotonic() - t) * 1e3
+            with _fence_phase(phases, "fence.lineage-observe"):
+                self.lineage.observe_epoch(
+                    closed, win,
+                    num_key_groups=self.job.num_key_groups,
+                    topology=self._lineage_topology,
+                    parts={vid: tl.pending_shards(closed)
+                           for vid, tl in self.txn_logs.items()})
         # Checkpoint at the fence: the lean fence snapshot (op state
         # + offsets; logs/rings are truncated on completion, not
         # persisted).
-        t = _time.monotonic()
-        with prof.section("snapshot"):
+        with _fence_phase(phases, "fence.snapshot", prof, "snapshot"):
             self.coordinator.trigger(closed, snap_fn(),
                                      async_write=async_write, owned=True)
             if async_write:
                 self.coordinator.drain()
-        phases["fence.snapshot"] = (_time.monotonic() - t) * 1e3
 
     def _append_source_fence_determinant(self, closed: int,
                                          phases: Dict[str, float],
@@ -1834,65 +1851,76 @@ class ClusterRunner:
             return
         t_ms = (self.executor.step_input_history[-1][0]
                 if self.executor.step_input_history else 0)
-        t = _time.monotonic()
-        with prof.section("source-append"):
+        with _fence_phase(phases, "fence.source-append", prof,
+                               "source-append"):
             self.executor.append_async_many(
                 self._source_flats,
                 det.SourceCheckpointDeterminant(
                     record_count=self.executor.global_record_stamp(),
                     checkpoint_id=closed, timestamp=t_ms))
             prof.fence(self.executor.carry.logs)
-        phases["fence.source-append"] = (_time.monotonic() - t) * 1e3
 
     def _run_fence_tail_inline(self, closed: int,
-                               complete_checkpoint: bool,
-                               t_fence: float, tr, prof) -> None:
+                               complete_checkpoint: bool, prof) -> None:
         """Today's strict fence order, inline on the calling thread —
         the sequential control. Phases land in ``last_fence_phases``
         under the same ``fence.*`` keys as the pipelined path, minus
-        the overlap key (its absence marks the control run)."""
+        the overlap key (its absence marks the control run). Each key
+        is the duration of the span of the same name; ``fence-tail`` is
+        the ``fence`` span's."""
         phases: Dict[str, float] = {}
-        # One fused device read per epoch: overflow flags + record
-        # total + fence log heads (one device→host sync per fence).
-        t = _time.monotonic()
-        with prof.section("health-read"):
-            vec = self.executor.health_vector()
-        phases["fence.health-read"] = (_time.monotonic() - t) * 1e3
-        delta_records = self._absorb_fence_health(closed, vec)
-        # Overflow guards at every roll: an un-truncated ring that
-        # wrapped has silently clobbered recovery state — fail
-        # loudly, never limp.
-        violations = self.executor.overflow_messages(vec)
-        if violations:
-            raise OverflowError_("; ".join(violations))
-        # Host epoch control plane mirrors the fence.
-        self.epoch_tracker.inc_record_count(delta_records)
-        self.epoch_tracker.start_new_epoch(self.executor.epoch_id)
-        # Audit seal at the fence (obs/audit.py): digest the closed
-        # epoch's causal surface while its log/ring windows are
-        # still resident (completion below truncates them), persist
-        # the ledger entry next to the checkpoint, and fan out on
-        # the epoch tracker's seal bus. The SOURCE_CHECKPOINT
-        # appends after the snapshot land past this epoch's window
-        # end, so the seal is fence-exact.
-        self._seal_and_trigger(
-            closed, lambda: self.executor.epoch_window(closed),
-            self.executor.lean_snapshot, phases, prof, async_write=False)
-        self._append_source_fence_determinant(closed, phases, prof)
-        for tl in self.txn_logs.values():
-            tl.seal(closed)
-        # Before completion: ack_all truncates rings up to this
-        # fence, so anything reading their fresh steps (edge
-        # exports) goes now.
-        for hook in self.fence_hooks:
-            hook(closed)
-        if complete_checkpoint:
-            self.coordinator.ack_all(closed)
-        fence_s = _time.monotonic() - t_fence
-        phases["fence-tail"] = fence_s * 1e3
+        tr = get_tracer()
+        with tr.span("fence", epoch=closed, mode="inline") as fence:
+            # One fused device read per epoch: overflow flags + record
+            # total + fence log heads (one device→host sync per fence).
+            with _fence_phase(phases, "fence.health-read", prof,
+                                   "health-read"):
+                vec = self.executor.health_vector()
+            delta_records = self._absorb_fence_health(closed, vec)
+            # Overflow guards at every roll: an un-truncated ring that
+            # wrapped has silently clobbered recovery state — fail
+            # loudly, never limp.
+            violations = self.executor.overflow_messages(vec)
+            if violations:
+                raise OverflowError_("; ".join(violations))
+            # Host epoch control plane mirrors the fence.
+            self.epoch_tracker.inc_record_count(delta_records)
+            self.epoch_tracker.start_new_epoch(self.executor.epoch_id)
+            # Audit seal at the fence (obs/audit.py): digest the closed
+            # epoch's causal surface while its log/ring windows are
+            # still resident (completion below truncates them), persist
+            # the ledger entry next to the checkpoint, and fan out on
+            # the epoch tracker's seal bus. The SOURCE_CHECKPOINT
+            # appends after the snapshot land past this epoch's window
+            # end, so the seal is fence-exact.
+            self._seal_and_trigger(
+                closed, lambda: self.executor.epoch_window(closed),
+                self.executor.lean_snapshot, phases, prof,
+                async_write=False)
+            self._append_source_fence_determinant(closed, phases, prof)
+            self._seal_txns_and_run_hooks(closed, phases)
+            if complete_checkpoint:
+                # completion -> TransactionLog.commit -> log/ring
+                # truncation -> feed-offset commit: what a consumer of
+                # the sink waits for
+                with _fence_phase(phases, "fence.ack"):
+                    self.coordinator.ack_all(closed)
+        phases["fence-tail"] = fence.ms
         self.last_fence_phases = phases
-        self._m_epoch_fence_ms.update(fence_s * 1e3)
-        tr.complete("epoch.fence", fence_s, epoch=closed)
+        self._m_epoch_fence_ms.update(fence.ms)
+
+    def _seal_txns_and_run_hooks(self, closed: int,
+                                 phases: Dict[str, float]) -> None:
+        if self.txn_logs:
+            with _fence_phase(phases, "fence.txn-seal"):
+                for tl in self.txn_logs.values():
+                    tl.seal(closed)
+        # Before completion: ack_all truncates rings up to this fence,
+        # so anything reading their fresh steps (edge exports) goes now.
+        if self.fence_hooks:
+            with _fence_phase(phases, "fence.hooks"):
+                for hook in self.fence_hooks:
+                    hook(closed)
 
     def _check_fence_headroom(self) -> None:
         """One epoch of ring headroom, asserted once: the pipelined
@@ -1924,32 +1952,31 @@ class ClusterRunner:
         overlap window stays dispatch-only — no host synchronization
         (lint rule overlap-window enforces it), so the next epoch's
         compute can be dispatched immediately behind it."""
-        t = _time.monotonic()
         phases: Dict[str, float] = {}
-        # clonos: overlap-window-begin
-        handles = self.executor.capture_fence(
-            with_window=self.auditor.enabled or bool(self.serve_feeds)
-            or self.lineage.enabled)
-        snap = self.executor.lean_snapshot()
-        self._append_source_fence_determinant(closed, phases, prof)
-        # clonos: overlap-window-end
-        for tl in self.txn_logs.values():
-            tl.seal(closed)
-        for hook in self.fence_hooks:
-            hook(closed)
-        pre_ms = (_time.monotonic() - t) * 1e3
-        phases["fence.capture"] = max(
-            0.0, pre_ms - phases.get("fence.source-append", 0.0))
+        tr = get_tracer()
+        with tr.span("fence", epoch=closed, mode="pipelined",
+                     part="begin") as fence:
+            # clonos: overlap-window-begin
+            with _fence_phase(phases, "fence.capture"):
+                handles = self.executor.capture_fence(
+                    with_window=self.auditor.enabled
+                    or bool(self.serve_feeds) or self.lineage.enabled)
+                snap = self.executor.lean_snapshot()
+            self._append_source_fence_determinant(closed, phases, prof)
+            # clonos: overlap-window-end
+            self._seal_txns_and_run_hooks(closed, phases)
+            parent = tr.current_span()    # the worker's spans hang here
         tail = {"epoch": closed, "complete": complete_checkpoint,
                 "handles": handles, "snap": snap, "phases": phases,
-                "pre_ms": pre_ms, "vec": None, "err": None}
-        th = threading.Thread(target=self._fence_worker, args=(tail,),
+                "pre_ms": fence.ms, "vec": None, "err": None,
+                "parent": parent}
+        th = threading.Thread(target=self._fence_worker, args=(tail, prof),
                               name="fence-tail", daemon=True)
         tail["thread"] = th
         self._fence_tail = tail
         th.start()
 
-    def _fence_worker(self, tail: dict) -> None:
+    def _fence_worker(self, tail: dict, prof) -> None:
         """Fence-tail drain, off the critical path: drain the async
         health d2h, fold the host mirrors, advance the epoch control
         plane, then seal + ledger + checkpoint from the captured
@@ -1957,23 +1984,24 @@ class ClusterRunner:
         exit). Errors are held and re-raised at the join; the overflow
         check on the drained health vector is ALSO deferred to the join
         — it must run on the main thread, like the checkpoint ack whose
-        completion listeners mutate executor state."""
-        from clonos_tpu.obs import profile as _prof_mod
+        completion listeners mutate executor state. Its spans are
+        children of the ``fence`` span that started it."""
         closed = tail["epoch"]
         phases = tail["phases"]
         try:
-            t = _time.monotonic()
-            vec = tail["handles"].health()
-            phases["fence.health-read"] = (_time.monotonic() - t) * 1e3
-            tail["vec"] = vec
-            delta_records = self._absorb_fence_health(closed, vec)
-            self.epoch_tracker.inc_record_count(delta_records)
-            # By value, not executor.epoch_id: the main thread may have
-            # dispatched further epochs by the time this runs.
-            self.epoch_tracker.start_new_epoch(closed + 1)
-            self._seal_and_trigger(
-                closed, tail["handles"].window, lambda: tail["snap"],
-                phases, _prof_mod.NullProfiler(), async_write=True)
+            with get_tracer().attach(tail["parent"]):
+                with _fence_phase(phases, "fence.health-read", prof,
+                                       "health-read"):
+                    vec = tail["handles"].health()
+                tail["vec"] = vec
+                delta_records = self._absorb_fence_health(closed, vec)
+                self.epoch_tracker.inc_record_count(delta_records)
+                # By value, not executor.epoch_id: the main thread may
+                # have dispatched further epochs by the time this runs.
+                self.epoch_tracker.start_new_epoch(closed + 1)
+                self._seal_and_trigger(
+                    closed, tail["handles"].window, lambda: tail["snap"],
+                    phases, prof, async_write=True)
         except BaseException as e:      # re-raised at the join
             tail["err"] = e
 
@@ -1984,44 +2012,44 @@ class ClusterRunner:
         ``executor.carry`` — must interleave with steps, never with
         them. Also closes the tail's attribution: sub-spans keep their
         true walls, ``fence-tail`` is the critical-path wall actually
-        paid (capture + join), and the difference is credited to
+        paid (the two ``fence`` spans: capture, then join with its
+        ack), and the difference is credited to
         ``fence.overlap-saved``, preserving
         sum(fence.*) - overlap-saved == fence-tail."""
         tail = self._fence_tail
         if tail is None:
             return
         self._fence_tail = None
-        t = _time.monotonic()
-        tail["thread"].join()
-        joined_ms = (_time.monotonic() - t) * 1e3
+        tr = get_tracer()
         phases = tail["phases"]
-        tail_ms = tail["pre_ms"] + joined_ms
-        spans = sum(v for k, v in phases.items()
-                    if k.startswith("fence."))
-        saved = max(0.0, spans - tail_ms)
-        phases["fence-tail"] = tail_ms
-        phases["fence.overlap-saved"] = saved
-        self.fence_overlap_saved_total_ms += saved
-        self.last_fence_phases = phases
-        prof = self.profiler
-        for key, legacy in (("fence.health-read", "health-read"),
-                            ("fence.digest-seal", "digest-seal"),
-                            ("fence.ledger-write", "ledger-write"),
-                            ("fence.snapshot", "snapshot")):
-            if key in phases:
-                prof.observe(legacy, phases[key] / 1e3)
-        self._m_epoch_fence_ms.update(tail_ms)
-        get_tracer().complete("epoch.fence", tail_ms / 1e3,
-                              epoch=tail["epoch"])
+        violations: List[str] = []
+        try:
+            with tr.span("fence", epoch=tail["epoch"], mode="pipelined",
+                         part="join") as fence:
+                with tr.span("fence.join"):
+                    tail["thread"].join()
+                if tail["err"] is None:
+                    violations = self.executor.overflow_messages(
+                        tail["vec"])
+                    if not violations and tail["complete"]:
+                        with _fence_phase(phases, "fence.ack"):
+                            self.coordinator.ack_all(tail["epoch"])
+        finally:
+            tail_ms = tail["pre_ms"] + fence.ms
+            spans = sum(v for k, v in phases.items()
+                        if k.startswith("fence."))
+            saved = max(0.0, spans - tail_ms)
+            phases["fence-tail"] = tail_ms
+            phases["fence.overlap-saved"] = saved
+            self.fence_overlap_saved_total_ms += saved
+            self.last_fence_phases = phases
+            self._m_epoch_fence_ms.update(tail_ms)
         if tail["err"] is not None:
             raise tail["err"]
-        violations = self.executor.overflow_messages(tail["vec"])
         if violations:
             raise OverflowError_(
                 f"deferred fence check (pipelined fence, epoch "
                 f"{tail['epoch']}): " + "; ".join(violations))
-        if tail["complete"]:
-            self.coordinator.ack_all(tail["epoch"])
 
     def fence_tail_in_flight(self) -> bool:
         """True while a pipelined fence tail is still unjoined."""
@@ -2138,11 +2166,25 @@ class ClusterRunner:
         moment the forensic state (ledgers, determinant windows, HLC
         timeline) is about to become unreachable. No-op passthrough
         when the incident plane is disabled."""
+        tr = get_tracer()
+        # The phases run through one body, so they are a chain of
+        # consecutive spans (children of ``recovery``) whose stamps also
+        # fill ``RecoveryReport.phase_ms``.
+        phases: Dict[str, float] = {}
         try:
-            return self._recover_impl(
-                drill=drill, host_rows=host_rows,
-                overlap_finalize=overlap_finalize,
-                pre_patch_join=pre_patch_join)
+            with tr.span("recovery", drill=bool(drill),
+                         victims=sorted(self.failed)) as span, \
+                    tr.chain("recovery.", into=phases,
+                             drill=bool(drill)) as chain:
+                report = self._recover_impl(
+                    phases, chain, drill=drill, host_rows=host_rows,
+                    overlap_finalize=overlap_finalize,
+                    pre_patch_join=pre_patch_join)
+                span.set(from_epoch=report.from_epoch,
+                         steps_replayed=report.steps_replayed,
+                         records_replayed=report.records_replayed,
+                         recovery_ms=report.recovery_ms)
+                return report
         except Exception as e:
             from clonos_tpu.obs.incident import get_incidents
             get_incidents().signal(
@@ -2153,7 +2195,8 @@ class ClusterRunner:
                 failed=sorted(self.failed))
             raise
 
-    def _recover_impl(self, drill: bool = False,
+    def _recover_impl(self, phases: Dict[str, float], chain,
+                      drill: bool = False,
                       host_rows: Optional[Dict[int, Tuple[np.ndarray, int]]]
                       = None,
                       overlap_finalize: Optional[bool] = None,
@@ -2205,6 +2248,7 @@ class ClusterRunner:
             raise rec.RecoveryError(
                 "no completed checkpoint to restore standbys from")
         t0 = _time.monotonic()
+        chain.switch("restore")
         topo_pos = {vid: i for i, vid in
                     enumerate(self.executor.compiled.topo)}
         failed = tuple(sorted(
@@ -2242,15 +2286,7 @@ class ClusterRunner:
         restore_bytes = 0
         checkpoint_bytes = (int(getattr(ckpt, "size_bytes", 0) or 0)
                             or cp.carry_nbytes(ckpt.carry))
-        phases: Dict[str, float] = {}
-
-        def _clock(name: str, since: float) -> float:
-            now = _time.monotonic()
-            phases[name] = phases.get(name, 0.0) + (now - since) * 1e3
-            get_tracer().complete(f"recovery.{name}", now - since,
-                                  drill=drill)
-            return now
-
+        tr = get_tracer()
         patched = self.executor.carry
         # Ring bounds for routing coverage decisions: the host mirror
         # (tails move only at checkpoint completion, heads advance one
@@ -2278,7 +2314,7 @@ class ClusterRunner:
             v_of = self._vertex_of(flat)[0]
             vid_failed_counts[v_of] = vid_failed_counts.get(v_of, 0) + 1
         prev_vid = None
-        tp = _clock("restore", t0)
+        chain.switch("fetch_determinants")
 
         # ---- phase A: determinant metadata for ALL failed subtasks ----
         # Dispatch every per-subtask parse/meta program up front, then pay
@@ -2342,9 +2378,9 @@ class ClusterRunner:
                 slow_vals[(flat, kind)] = packed_a[
                     off_a: off_a + nsz].reshape(d.shape)
                 off_a += nsz
-        tp = _clock("fetch_determinants", tp)
 
         for flat in failed:
+            chain.switch("fetch_determinants")
             vid, sub = self._vertex_of(flat)
             if vid != prev_vid:
                 # Routed windows are valid only while the upstream rings
@@ -2477,7 +2513,7 @@ class ClusterRunner:
             else:
                 rows, start = mgr.merged_determinants()
             total_dets += clean_n if clean_n is not None else len(rows)
-            tp = _clock("fetch_determinants", tp)
+            chain.switch("inputs")
 
             # Lost inputs: the checkpointed edge buffer (the depth-1 batch
             # spanning the fence) + the upstream rings' raw outputs,
@@ -2498,7 +2534,7 @@ class ClusterRunner:
                                                   sub, fence, n_steps)
             elif isinstance(v.operator, HostFeedSource) and n_steps > 0:
                 input_steps = self._reread_feed(vid, sub, snap, rows, n_steps)
-            tp = _clock("inputs", tp)
+            chain.switch("replay")
 
             plan = rec.ReplayPlan(
                 vertex_id=vid, subtask=sub, flat_subtask=flat,
@@ -2529,7 +2565,7 @@ class ClusterRunner:
                 self.txn_logs[vid].drop_uncommitted_shards(sub)
                 self._rebuild_txn_shards(vid, sub, result, from_epoch,
                                          fence, n_steps)
-            tp = _clock("replay", tp)
+            chain.switch("patch")
 
             rebuilt = np.asarray(result.rebuilt_log_rows)
             # The regenerated determinant rows must equal the recovered ones
@@ -2550,13 +2586,9 @@ class ClusterRunner:
                 # _patch reads roll_gap_async; the blocked remainder is
                 # the non-overlapped listener-reattach cost (the rest
                 # rode inside the replay window above).
-                t_j = _time.monotonic()
+                chain.switch("finalize.listener-reattach")
                 pre_patch_join()
-                b_j = _time.monotonic() - t_j
-                phases["finalize.listener-reattach"] = (
-                    phases.get("finalize.listener-reattach", 0.0)
-                    + b_j * 1e3)
-                tp += b_j            # exclude the wait from "patch"
+                chain.switch("patch")    # the wait is not the patch's
                 pre_patch_join = None
             patched = self._patch(patched, snap, vid, sub, flat,
                                   result, rebuilt, from_epoch, fence,
@@ -2566,7 +2598,7 @@ class ClusterRunner:
                                   ck_head=(int(ck_heads[flat])
                                            if ck_heads is not None
                                            else None))
-            tp = _clock("patch", tp)
+        chain.switch("replica_rebuild")
 
         # Replica rows held by revived subtasks: replicas are identical to
         # their owner's log by construction (same bulk appends), so rebuild
@@ -2592,7 +2624,6 @@ class ClusterRunner:
         self.executor.carry = patched
         self._bounds_cache = None
         self._route_cache = {}     # free the held routed device buffers
-        tp = _clock("replica_rebuild", tp)
 
         # ---- final packed read: completion barrier + deferred asserts ----
         # ONE device->host transfer closes the protocol: the restored log
@@ -2619,28 +2650,39 @@ class ClusterRunner:
         # failure is retryable, never silently "healthy".
         overlap = (self.overlap_recovery if overlap_finalize is None
                    else bool(overlap_finalize))
-        t_fin0 = tp
+        # ``finalize`` is the chain's last span; its children below use
+        # their own spans (the barrier's on whichever thread drains it).
+        chain.switch("finalize")
+        fin_span = tr.current_span()
+        fin_before = phases.get("finalize", 0.0)
         fast_mgrs = [m for m in managers if prep[m.flat_subtask]["fast"]]
-        fl_d = jnp.asarray(list(failed), jnp.int32)
-        pieces = [patched.logs.head[fl_d].astype(jnp.int32)]
-        if nrings:
-            pieces.append(bounds_dev.reshape(-1).astype(jnp.int32))
-        for m in fast_mgrs:
-            pf = prep[m.flat_subtask]
-            pieces += [
-                pf["small_d"].astype(jnp.int32),
-                pf["meta_d"].reshape(-1).astype(jnp.int32),
-                m.result.verify_ok_d.astype(jnp.int32).reshape(1),
-                m.result.consumed_d.astype(jnp.int32).reshape(1)]
-        packed_f = jnp.concatenate(pieces)        # dispatch only
+        with tr.span("recovery.finalize.barrier-dispatch",
+                     drill=drill) as disp:
+            fl_d = jnp.asarray(list(failed), jnp.int32)
+            pieces = [patched.logs.head[fl_d].astype(jnp.int32)]
+            if nrings:
+                pieces.append(bounds_dev.reshape(-1).astype(jnp.int32))
+            for m in fast_mgrs:
+                pf = prep[m.flat_subtask]
+                pieces += [
+                    pf["small_d"].astype(jnp.int32),
+                    pf["meta_d"].reshape(-1).astype(jnp.int32),
+                    m.result.verify_ok_d.astype(jnp.int32).reshape(1),
+                    m.result.consumed_d.astype(jnp.int32).reshape(1)]
+            packed_f = jnp.concatenate(pieces)        # dispatch only
+        phases["finalize.barrier-dispatch"] = (
+            phases.get("finalize.barrier-dispatch", 0.0) + disp.ms)
         barrier: Dict[str, Any] = {"arr": None, "err": None, "ms": 0.0}
 
-        def _drain_barrier() -> None:
-            try:
-                barrier["arr"] = np.asarray(packed_f)
-            except Exception as err:      # surfaces at the join below
-                barrier["err"] = err
-            barrier["ms"] = (_time.monotonic() - t_fin0) * 1e3
+        def _drain_barrier(parent=None) -> None:
+            with tr.attach(parent):
+                with tr.span("recovery.finalize.barrier-read",
+                             drill=drill) as sp:
+                    try:
+                        barrier["arr"] = np.asarray(packed_f)
+                    except Exception as err:  # surfaces at the join below
+                        barrier["err"] = err
+            barrier["ms"] = sp.ms
 
         def _verify(arr_f: np.ndarray) -> int:
             verified_records = 0
@@ -2734,28 +2776,25 @@ class ClusterRunner:
             # the original execution.
             if not self.auditor.enabled:
                 return 0.0
-            t_a = _time.monotonic()
-            validator = rec.AuditValidator(
-                self.executor, self.coordinator.read_ledger(),
-                on_divergence=self.auditor.on_divergence)
-            try:
-                validator.validate(
-                    range(from_epoch, self.executor.epoch_id))
-            finally:
-                # evidence reaches the metrics plane even when the
-                # abort policy throws mid-validation
-                self._m_audit_matches.inc(validator.stats["match"])
-                self._m_audit_div.inc(validator.stats["divergence"])
-            a_ms = (_time.monotonic() - t_a) * 1e3
-            phases["audit"] = phases.get("audit", 0.0) + a_ms
-            get_tracer().complete("recovery.audit", a_ms / 1e3,
-                                  drill=drill)
-            return a_ms
+            with tr.span("recovery.audit", drill=drill) as sp:
+                validator = rec.AuditValidator(
+                    self.executor, self.coordinator.read_ledger(),
+                    on_divergence=self.auditor.on_divergence)
+                try:
+                    validator.validate(
+                        range(from_epoch, self.executor.epoch_id))
+                finally:
+                    # evidence reaches the metrics plane even when the
+                    # abort policy throws mid-validation
+                    self._m_audit_matches.inc(validator.stats["match"])
+                    self._m_audit_div.inc(validator.stats["divergence"])
+            phases["audit"] = phases.get("audit", 0.0) + sp.ms
+            return sp.ms
 
         audit_ms = 0.0
         audit_err: Optional[Exception] = None
         if overlap:
-            th = threading.Thread(target=_drain_barrier,
+            th = threading.Thread(target=_drain_barrier, args=(fin_span,),
                                   name="recovery-finalize-barrier")
             th.start()
             # Host-side finalize work folded into the barrier window:
@@ -2787,21 +2826,16 @@ class ClusterRunner:
             raise barrier["err"]
         phases["finalize.barrier-read"] = (
             phases.get("finalize.barrier-read", 0.0) + barrier["ms"])
-        get_tracer().complete("recovery.finalize.barrier-read",
-                              barrier["ms"] / 1e3, drill=drill)
-        t_v = _time.monotonic()
-        total_records += _verify(barrier["arr"])
-        now_v = _time.monotonic()
-        verify_ms = (now_v - t_v) * 1e3
+        with tr.span("recovery.finalize.state-verify", drill=drill) as sp:
+            total_records += _verify(barrier["arr"])
+        verify_ms = sp.ms
         phases["finalize.state-verify"] = (
             phases.get("finalize.state-verify", 0.0) + verify_ms)
-        get_tracer().complete("recovery.finalize.state-verify",
-                              verify_ms / 1e3, drill=drill)
-        fin_ms = (now_v - t_fin0) * 1e3 - audit_ms
-        phases["finalize"] = phases.get("finalize", 0.0) + fin_ms
-        get_tracer().complete("recovery.finalize", fin_ms / 1e3,
-                              drill=drill)
-        tp = now_v
+        # The chain adds the window's wall; the audit that ran inside it
+        # (overlapped mode) has its own key.
+        chain.close()
+        fin_ms = phases["finalize"] - fin_before - audit_ms
+        phases["finalize"] = fin_before + fin_ms
         if overlap:
             # Same safety order as the control: verify passed, NOW the
             # subtasks may be marked healthy; a deferred audit
@@ -2817,14 +2851,13 @@ class ClusterRunner:
             # (which only absorbs sub-ms thread-start jitter).
             phases["finalize.overlap-saved"] = (
                 phases.get("finalize.overlap-saved", 0.0)
-                + max(0.0, barrier["ms"] + verify_ms - fin_ms))
+                + max(0.0, disp.ms + barrier["ms"] + verify_ms - fin_ms))
         else:
             # Sequential control keeps the old order: barrier-read →
             # state-verify → revive → audit (and never writes the
             # overlap-saved key — its absence marks the control path).
             _revive()
             audit_ms = _audit()
-            tp = _time.monotonic()
         report = RecoveryReport(
             failed_subtasks=failed, from_epoch=from_epoch,
             steps_replayed=n_steps, determinants_replayed=total_dets,
@@ -2843,10 +2876,6 @@ class ClusterRunner:
             # etc.) — the tuning surface for the paper's headline claim.
             for pname, ms in phases.items():
                 self._mgroup.histogram(f"recovery.{pname}-ms").update(ms)
-        get_tracer().complete(
-            "recovery", report.recovery_ms / 1e3, drill=drill,
-            failed=list(failed), from_epoch=from_epoch,
-            steps_replayed=n_steps, records_replayed=total_records)
         return report
 
     def prewarm_recovery(self, vertex_ids: Optional[Sequence[int]] = None,
@@ -3401,7 +3430,7 @@ class ClusterRunner:
         slot_keys = self.executor.compiled.consumer_slot_keys(vid)
         compiled = self.executor.compiled
         return rec.LogReplayer(
-            v.operator, v.parallelism,
+            v.operator, v.parallelism, vertex_name=v.name,
             block_steps=self._recovery_ch,
             in_slot_keys=(slot_keys[sub:sub + 1]
                           if slot_keys is not None else None),

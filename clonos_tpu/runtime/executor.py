@@ -60,6 +60,7 @@ from clonos_tpu.causal import determinant as det
 from clonos_tpu.causal import replication as rep
 from clonos_tpu.graph.job_graph import JobGraph, PartitionType
 from clonos_tpu.inflight import log as ifl
+from clonos_tpu.obs.trace import get_tracer, install_compile_listener
 from clonos_tpu.ops.histogram import kernel_mesh
 from clonos_tpu.parallel import routing
 
@@ -391,13 +392,17 @@ class CompiledJob:
                 ins = empty((K, p, self.vertex_out_capacity(vid)))
                 consumed = None
             slot_keys = self.consumer_slot_keys(vid)
-            if slot_keys is not None and hasattr(
-                    v.operator, "process_block_static_keys"):
-                state, out = v.operator.process_block_static_keys(
-                    op_states[vid], ins, bctx, slot_keys)
-            else:
-                state, out = v.operator.process_block(op_states[vid], ins,
-                                                      bctx)
+            # Named scopes are metadata on the lowered ops (an xprof
+            # view groups ``%fusion.N`` under the vertex that made it);
+            # they move neither the program nor its cache key.
+            with jax.named_scope(f"vertex/{v.name}"):
+                if slot_keys is not None and hasattr(
+                        v.operator, "process_block_static_keys"):
+                    state, out = v.operator.process_block_static_keys(
+                        op_states[vid], ins, bctx, slot_keys)
+                else:
+                    state, out = v.operator.process_block(
+                        op_states[vid], ins, bctx)
             if consumed is None:
                 # Pure generators "consume" what they emit (their record
                 # count advances with generated records, like the
@@ -413,25 +418,26 @@ class CompiledJob:
             for eidx in job.out_edges(vid):
                 e = job.edges[eidx]
                 dst_p = job.vertices[e.dst].parallelism
-                if eidx in self.static_route:
-                    r, d = self.static_route[eidx].apply(out)
-                elif e.partition == PartitionType.HASH:
-                    r, d = routing.route_hash_block(
-                        out, dst_p, job.num_key_groups, e.capacity)
-                elif e.partition == PartitionType.FORWARD:
-                    r, d = routing.route_forward_block(out, e.capacity)
-                elif e.partition == PartitionType.REBALANCE:
-                    counts = out.count().sum(axis=1)             # [K]
-                    offs = (rr_offsets[eidx][0]
-                            + jnp.cumsum(counts) - counts)       # exclusive
-                    r, d = routing.route_rebalance_block(
-                        out, dst_p, e.capacity, offs)
-                    rr_offsets[eidx] = (
-                        (rr_offsets[eidx] + counts.sum())
-                        % jnp.asarray(dst_p, jnp.int32))
-                else:
-                    r, d = routing.route_broadcast_block(
-                        out, dst_p, e.capacity)
+                with jax.named_scope("exchange"):
+                    if eidx in self.static_route:
+                        r, d = self.static_route[eidx].apply(out)
+                    elif e.partition == PartitionType.HASH:
+                        r, d = routing.route_hash_block(
+                            out, dst_p, job.num_key_groups, e.capacity)
+                    elif e.partition == PartitionType.FORWARD:
+                        r, d = routing.route_forward_block(out, e.capacity)
+                    elif e.partition == PartitionType.REBALANCE:
+                        counts = out.count().sum(axis=1)         # [K]
+                        offs = (rr_offsets[eidx][0]
+                                + jnp.cumsum(counts) - counts)   # exclusive
+                        r, d = routing.route_rebalance_block(
+                            out, dst_p, e.capacity, offs)
+                        rr_offsets[eidx] = (
+                            (rr_offsets[eidx] + counts.sum())
+                            % jnp.asarray(dst_p, jnp.int32))
+                    else:
+                        r, d = routing.route_broadcast_block(
+                            out, dst_p, e.capacity)
                 routed[eidx] = self._shard_block(r)
                 dropped[eidx] = d
                 new_edge_bufs[eidx] = jax.tree_util.tree_map(
@@ -443,7 +449,8 @@ class CompiledJob:
                 # consumers re-derive their input by re-running the
                 # deterministic exchange during replay.
                 ri = self.ring_index[vid]
-                el = ifl.append_block(out_rings[ri], out)
+                with jax.named_scope("inflight-ring"):
+                    el = ifl.append_block(out_rings[ri], out)
                 # Re-pin the ring payload to its subtask axis (axis 1):
                 # append_block's scatter would otherwise let the
                 # partitioner re-layout the [S, P, cap] tensors along the
@@ -460,19 +467,21 @@ class CompiledJob:
             [emit_parts[v.vertex_id] for v in job.vertices], axis=1)  # [K, L]
         consumed_all = jnp.concatenate(
             [consumed_parts[v.vertex_id] for v in job.vertices], axis=1)
-        rows = self._det_rows(binputs, emits_all)                 # [L, 4K, 8]
-        logs = clog.v_append_full(carry.logs, rows)
-        logs = self._shard_tree(logs)
-        if self.plan.num_replicas > 0:
-            # Piggyback replication: the same block of determinants lands in
-            # every downstream replica before any of this block's outputs
-            # become externally visible (the per-message netty delta becomes
-            # one owner-indexed bulk append at the block fence).
-            replicas = clog.v_append_full(carry.replicas,
-                                          rows[self._owner_idx])
-            replicas = self._shard_tree(replicas)
-        else:
-            replicas = carry.replicas
+        with jax.named_scope("causal-log"):
+            rows = self._det_rows(binputs, emits_all)             # [L, 4K, 8]
+            logs = clog.v_append_full(carry.logs, rows)
+            logs = self._shard_tree(logs)
+            if self.plan.num_replicas > 0:
+                # Piggyback replication: the same block of determinants
+                # lands in every downstream replica before any of this
+                # block's outputs become externally visible (the
+                # per-message netty delta becomes one owner-indexed bulk
+                # append at the block fence).
+                replicas = clog.v_append_full(carry.replicas,
+                                              rows[self._owner_idx])
+                replicas = self._shard_tree(replicas)
+            else:
+                replicas = carry.replicas
 
         new_carry = JobCarry(
             tuple(op_states), tuple(new_edge_bufs), tuple(rr_offsets),
@@ -777,6 +786,7 @@ class LocalExecutor:
                     jax.sharding.PartitionSpec(self.compiled.task_axis))
             return self._repl_ns
 
+        install_compile_listener()
         # The carry is donated: the block program updates GB-scale log /
         # ring storage in place instead of copying it every call (the
         # carry's buffers are only ever referenced by the live executor;
@@ -793,29 +803,34 @@ class LocalExecutor:
             # equal owner heads by construction (the block program appends
             # both from the same tensor).
             replicas = carry.replicas
-            if plan.num_replicas > 0:
-                replicas = rep.sync_replica_epochs(replicas, e)
+            with jax.named_scope("causal-log"):
+                if plan.num_replicas > 0:
+                    replicas = rep.sync_replica_epochs(replicas, e)
+                logs = clog.v_start_epoch(carry.logs, e)
+            with jax.named_scope("inflight-ring"):
+                out_rings = tuple(ifl.start_epoch(el, e)
+                                  for el in carry.out_rings)
             return carry._replace(
-                logs=clog.v_start_epoch(carry.logs, e),
+                logs=logs,
                 # Ring markers sit exactly at the fence. The batch appended
                 # at the fence's last step is still in flight (its consumer
                 # reads it one step after the fence), but that batch rides
                 # the checkpoint as the depth-1 edge buffer of the
                 # LeanSnapshot — the ring copy is redundant, so truncation
                 # may drop it (and recovery never rebuilds it).
-                out_rings=tuple(ifl.start_epoch(el, e)
-                                for el in carry.out_rings),
-                replicas=replicas)
+                out_rings=out_rings, replicas=replicas)
 
         def _trunc(carry: JobCarry, e) -> JobCarry:
             replicas = carry.replicas
-            if plan.num_replicas > 0:
-                replicas = clog.v_truncate(replicas, e)
-            return carry._replace(
-                logs=clog.v_truncate(carry.logs, e),
-                out_rings=tuple(ifl.truncate(el, e)
-                                for el in carry.out_rings),
-                replicas=replicas)
+            with jax.named_scope("causal-log"):
+                if plan.num_replicas > 0:
+                    replicas = clog.v_truncate(replicas, e)
+                logs = clog.v_truncate(carry.logs, e)
+            with jax.named_scope("inflight-ring"):
+                out_rings = tuple(ifl.truncate(el, e)
+                                  for el in carry.out_rings)
+            return carry._replace(logs=logs, out_rings=out_rings,
+                                  replicas=replicas)
 
         self._jit_roll = jax.jit(
             _roll, donate_argnums=0,
@@ -875,6 +890,9 @@ class LocalExecutor:
         #: optional hook fed (BlockOutputs, epoch_id) after every block —
         #: the transactional-sink egress tap (runtime/txn.py).
         self.on_block_outputs: Optional[Any] = None
+        #: the newest block's outputs, until ``step``/``run_epoch`` hand
+        #: them to the caller
+        self._block_outs: Optional[BlockOutputs] = None
 
         owner_idx = self.compiled._owner_idx
         nrep = self.compiled.plan.num_replicas
@@ -943,60 +961,108 @@ class LocalExecutor:
         """Pull k steps' worth of records from every feed reader into
         stacked [k, P, B] batches (one device put per feed)."""
         from clonos_tpu.api.records import empty as empty_batch
+        if not self.compiled.feed_vertices:
+            return ()
+        tr = get_tracer()
+        pulled = []
+        with tr.span("block.feed.pull"):
+            for vid in self.compiled.feed_vertices:
+                v = self.job.vertices[vid]
+                b = v.operator.batch_size
+                reader = self.feed_readers.get(vid)
+                if reader is None:
+                    pulled.append(((k, v.parallelism, b), None))
+                    continue
+                rows_k = np.zeros((k, v.parallelism, b), np.int32)
+                rows_v = np.zeros((k, v.parallelism, b), np.int32)
+                counts = np.zeros((k, v.parallelism), np.int32)
+                for s in range(v.parallelism):
+                    ks, vs, cnt = reader.pull_block(s, b, k)
+                    rows_k[:, s, :], rows_v[:, s, :] = ks, vs
+                    counts[:, s] = cnt
+                valid = np.arange(b)[None, None, :] < counts[:, :, None]
+                tr.count("feed.records", int(counts.sum()))
+                pulled.append((rows_k.shape, (rows_k, rows_v, valid)))
         feeds = []
-        for vid in self.compiled.feed_vertices:
-            v = self.job.vertices[vid]
-            b = v.operator.batch_size
-            reader = self.feed_readers.get(vid)
-            if reader is None:
-                feeds.append(empty_batch((k, v.parallelism, b)))
-                continue
-            rows_k = np.zeros((k, v.parallelism, b), np.int32)
-            rows_v = np.zeros((k, v.parallelism, b), np.int32)
-            counts = np.zeros((k, v.parallelism), np.int32)
-            for s in range(v.parallelism):
-                ks, vs, cnt = reader.pull_block(s, b, k)
-                rows_k[:, s, :], rows_v[:, s, :] = ks, vs
-                counts[:, s] = cnt
-            valid = np.arange(b)[None, None, :] < counts[:, :, None]
-            feeds.append(RecordBatch(
-                jnp.asarray(rows_k), jnp.asarray(rows_v),
-                jnp.zeros((k, v.parallelism, b), jnp.int32),
-                jnp.asarray(valid)))
+        with tr.span("block.feed.put") as put:
+            nbytes = 0
+            for shape, host in pulled:
+                if host is None:
+                    feeds.append(empty_batch(shape))
+                    continue
+                rows_k, rows_v, valid = host
+                nbytes += rows_k.nbytes + rows_v.nbytes + valid.nbytes
+                feeds.append(RecordBatch(
+                    jnp.asarray(rows_k), jnp.asarray(rows_v),
+                    jnp.zeros(shape, jnp.int32), jnp.asarray(valid)))
+            put.set(bytes=nbytes)
+        tr.count("feed.h2d_bytes", nbytes)
         return tuple(feeds)
 
-    def _next_block_inputs(self, k: int) -> BlockInputs:
-        times = np.empty((k,), np.int32)
-        rngs = np.empty((k,), np.int32)
-        for i in range(k):
+    def _draw_causal_inputs(self, n: int) -> Tuple[np.ndarray, np.ndarray]:
+        """The next ``n`` steps' causal time and RNG draws, recorded in
+        ``step_input_history``."""
+        times = np.empty((n,), np.int32)
+        rngs = np.empty((n,), np.int32)
+        for i in range(n):
             t = self.time_source.now()
             r = int(self._rng.randint(0, 2 ** 31, dtype=np.int64))
             times[i], rngs[i] = t, r
             self.step_input_history.append((t, r))
-        return BlockInputs(
-            times=jnp.asarray(times), rng_bits=jnp.asarray(rngs),
-            epoch=jnp.asarray(self.epoch_id, jnp.int32),
-            step0=jnp.asarray(len(self.step_input_history) - k, jnp.int32),
-            feeds=self._pull_feeds(k))
+        return times, rngs
+
+    def _next_block_inputs(self, k: int) -> BlockInputs:
+        with get_tracer().span("block.causal-inputs"):
+            times, rngs = self._draw_causal_inputs(k)
+            times_d, rngs_d = jnp.asarray(times), jnp.asarray(rngs)
+            epoch = jnp.asarray(self.epoch_id, jnp.int32)
+            step0 = jnp.asarray(len(self.step_input_history) - k,
+                                jnp.int32)
+        return BlockInputs(times=times_d, rng_bits=rngs_d, epoch=epoch,
+                           step0=step0, feeds=self._pull_feeds(k))
 
     def _notify_block(self) -> None:
         # Uses the last EXECUTED step's time/stamp — the staged epoch path
         # pre-fills step_input_history, so [-1] would be the epoch end.
         if self.block_listeners and self._steps_executed:
-            t = self.step_input_history[self._steps_executed - 1][0]
-            stamp = self.global_record_stamp()
-            for fn in self.block_listeners:
-                fn(t, stamp)
+            with get_tracer().span("block.notify"):
+                t = self.step_input_history[self._steps_executed - 1][0]
+                stamp = self.global_record_stamp()
+                for fn in self.block_listeners:
+                    fn(t, stamp)
+
+    def _host_block(self, k: int) -> None:
+        """One host-staged block of ``k`` supersteps: causal inputs and
+        feeds up, the block program, the sink tap, the listeners — each
+        under its own span inside ``block``. Its outputs replace
+        ``_block_outs`` at the dispatch, so the previous block's (device
+        buffers and the host copies the sink tap cached on them) are
+        released there and never held across the next block's tap."""
+        tr = get_tracer()
+        with tr.span("block", epoch=self.epoch_id, k=k, program="run_block"):
+            inputs = self._next_block_inputs(k)
+            with tr.span("block.dispatch"):
+                self.carry, self._block_outs = self._jit_block(self.carry,
+                                                               inputs)
+            del inputs
+            tr.count("block.dispatches.run_block")
+            self._block_done(k)
+
+    def _block_done(self, k: int) -> None:
+        self.step_in_epoch += k
+        self._steps_executed += k
+        if self.on_block_outputs is not None:
+            self.on_block_outputs(self._block_outs, self.epoch_id)
+        self._notify_block()
+
+    def _take_block_outs(self) -> Optional[BlockOutputs]:
+        outs, self._block_outs = self._block_outs, None
+        return outs
 
     def step(self) -> StepOutputs:
         """Run one superstep on the live path (a K=1 block)."""
-        self.carry, outs = self._jit_block(self.carry,
-                                           self._next_block_inputs(1))
-        self.step_in_epoch += 1
-        self._steps_executed += 1
-        if self.on_block_outputs is not None:
-            self.on_block_outputs(outs, self.epoch_id)
-        self._notify_block()
+        self._host_block(1)
+        outs = self._take_block_outs()
         return StepOutputs(
             sinks={vid: jax.tree_util.tree_map(lambda x: x[0], b)
                    for vid, b in outs.sinks.items()},
@@ -1006,56 +1072,50 @@ class LocalExecutor:
     def run_epoch(self) -> Optional[BlockOutputs]:
         """Run the remainder of the current epoch in block programs, then
         roll the epoch (the checkpoint fence lands here)."""
-        outs = None
+        tr = get_tracer()
         remaining = self.steps_per_epoch - self.step_in_epoch
         full_blocks = remaining // self.block_steps
         if full_blocks > 1 and not self.compiled.feed_vertices:
             # Stage the full blocks' causal inputs in ONE upload and carry
             # the block cursor on device — no per-block host transfer.
             n = full_blocks * self.block_steps
-            g0 = len(self.step_input_history)
-            times = np.empty((n,), np.int32)
-            rngs = np.empty((n,), np.int32)
-            for i in range(n):
-                t = self.time_source.now()
-                r = int(self._rng.randint(0, 2 ** 31, dtype=np.int64))
-                times[i], rngs[i] = t, r
-                self.step_input_history.append((t, r))
-            t_all = jnp.asarray(times)
-            r_all = jnp.asarray(rngs)
-            lo = jnp.asarray(0, jnp.int32)
-            epoch = jnp.asarray(self.epoch_id, jnp.int32)
-            g0_d = jnp.asarray(g0, jnp.int32)
+            with tr.span("block.causal-inputs", staged=n):
+                g0 = len(self.step_input_history)
+                times, rngs = self._draw_causal_inputs(n)
+                t_all = jnp.asarray(times)
+                r_all = jnp.asarray(rngs)
+                lo = jnp.asarray(0, jnp.int32)
+                epoch = jnp.asarray(self.epoch_id, jnp.int32)
+                g0_d = jnp.asarray(g0, jnp.int32)
             for _ in range(full_blocks):
-                self.carry, outs, lo = self._jit_staged_run(
-                    self.carry, t_all, r_all, lo, epoch, g0_d)
-                self.step_in_epoch += self.block_steps
-                self._steps_executed += self.block_steps
-                if self.on_block_outputs is not None:
-                    self.on_block_outputs(outs, self.epoch_id)
-                self._notify_block()
+                with tr.span("block", epoch=self.epoch_id,
+                             k=self.block_steps, program="staged_run"):
+                    with tr.span("block.dispatch"):
+                        self.carry, self._block_outs, lo = \
+                            self._jit_staged_run(self.carry, t_all, r_all,
+                                                 lo, epoch, g0_d)
+                    tr.count("block.dispatches.staged_run")
+                    self._block_done(self.block_steps)
         while self.step_in_epoch < self.steps_per_epoch:
-            k = min(self.block_steps,
-                    self.steps_per_epoch - self.step_in_epoch)
-            self.carry, outs = self._jit_block(self.carry,
-                                               self._next_block_inputs(k))
-            self.step_in_epoch += k
-            self._steps_executed += k
-            if self.on_block_outputs is not None:
-                self.on_block_outputs(outs, self.epoch_id)
-            self._notify_block()
+            self._host_block(min(
+                self.block_steps, self.steps_per_epoch - self.step_in_epoch))
         closed = self.epoch_id
         self.epoch_id += 1
         self.step_in_epoch = 0
+        # One pair of stamps per interval: the span's, which also feed
+        # the overhead.<section>-ms histograms of an enabled profiler.
         from clonos_tpu.obs import get_profiler
         prof = get_profiler()
         if self.spill_logs is not None:
-            with prof.section("spill"):
+            with tr.span("epoch.spill") as sp:
                 self._spill_epoch(closed)
-        with prof.section("roll"):
+            prof.observe("spill", sp.dur)
+        with tr.span("epoch.roll") as sp:
             self.carry = self._jit_roll(self.carry, self.epoch_id)
             prof.fence(self.carry.logs)
-        return outs
+        tr.count("block.dispatches.roll")
+        prof.observe("roll", sp.dur)
+        return self._take_block_outs()
 
     def _spill_epoch(self, epoch: int) -> None:
         """Move the just-closed epoch's in-flight batches to the host spill
@@ -1132,14 +1192,12 @@ class LocalExecutor:
 
     def notify_checkpoint_complete(self, epoch: int) -> None:
         """Truncate determinant + in-flight logs for epochs <= ``epoch``."""
-        from clonos_tpu.obs import get_profiler, get_tracer
+        from clonos_tpu.obs import get_profiler
         tr = get_tracer()
-        if tr.enabled:
-            # checkpoint-cadence, not per-step: the epoch fence ->
-            # truncation leg of the epoch lifecycle
-            tr.event("epoch.inflight_truncate", epoch=epoch)
         prof = get_profiler()
-        with prof.section("truncate"):
+        # checkpoint-cadence, not per-step: the epoch fence -> truncation
+        # leg of the epoch lifecycle
+        with tr.span("ckpt.truncate", epoch=epoch) as sp:
             self.carry = self._jit_trunc(self.carry, epoch)
             prof.fence(self.carry.logs)
             if self.spill_logs is not None:
@@ -1147,6 +1205,8 @@ class LocalExecutor:
                     sl.truncate(epoch)
             if self.det_store is not None:
                 self.det_store.truncate(epoch)
+        tr.count("block.dispatches.trunc")
+        prof.observe("truncate", sp.dur)
         for i, pend in enumerate(self._pending_spill):
             self._pending_spill[i] = [(e, s, m) for (e, s, m) in pend
                                       if e > epoch]

@@ -24,6 +24,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from clonos_tpu.obs.trace import get_tracer
+
 
 @dataclasses.dataclass
 class _Txn:
@@ -61,13 +63,20 @@ class TransactionLog:
         txn = self._pending.setdefault(epoch, _Txn(epoch))
         if txn.sealed:
             raise RuntimeError(f"epoch {epoch} transaction already sealed")
-        p = keys.shape[1]
-        for sub in range(p):
-            m = valid[:, sub].reshape(-1)
-            flat = np.stack([keys[:, sub].reshape(-1)[m],
-                             values[:, sub].reshape(-1)[m],
-                             timestamps[:, sub].reshape(-1)[m]], axis=1)
-            txn.shards.setdefault(sub, []).append(flat)
+        tr = get_tracer()
+        with tr.span("block.sink.shard") as sp:
+            p = keys.shape[1]
+            rows = 0
+            for sub in range(p):
+                m = valid[:, sub].reshape(-1)
+                flat = np.stack([keys[:, sub].reshape(-1)[m],
+                                 values[:, sub].reshape(-1)[m],
+                                 timestamps[:, sub].reshape(-1)[m]],
+                                axis=1)
+                rows += flat.shape[0]
+                txn.shards.setdefault(sub, []).append(flat)
+            sp.set(rows=rows)
+        tr.count("sink.rows", rows)
 
     def seal(self, epoch: int) -> None:
         """Epoch fence: the transaction stops accepting records
@@ -89,17 +98,22 @@ class TransactionLog:
         """Checkpoint complete: externalize every sealed transaction up to
         ``epoch``, subtask-major within an epoch (commits are ordered;
         reference commit on notifyCheckpointComplete)."""
+        tr = get_tracer()
         for e in sorted(self._pending):
             if e > epoch:
                 break
-            txn = self._pending.pop(e)
-            parts = [np.concatenate(txn.shards[s], axis=0)
-                     for s in sorted(txn.shards) if txn.shards[s]]
-            recs = (np.concatenate(parts, axis=0) if parts
-                    else np.zeros((0, 3), np.int32))
-            self.committed.append((e, recs))
-            if self.committer is not None:
-                self.committer(e, recs)
+            # The span's end is the commit instant, seen from inside.
+            with tr.span("txn.commit", epoch=e) as sp:
+                txn = self._pending.pop(e)
+                parts = [np.concatenate(txn.shards[s], axis=0)
+                         for s in sorted(txn.shards) if txn.shards[s]]
+                recs = (np.concatenate(parts, axis=0) if parts
+                        else np.zeros((0, 3), np.int32))
+                self.committed.append((e, recs))
+                if self.committer is not None:
+                    self.committer(e, recs)
+                sp.set(rows=int(recs.shape[0]))
+            tr.count("txn.rows_committed", int(recs.shape[0]))
 
     def drop_uncommitted_shards(self, sub: int) -> List[int]:
         """Sink-subtask failure: its pending shards lived with the task

@@ -384,11 +384,15 @@ class MetricsEndpoint:
                     snap.update(extra())
                 except Exception as e:
                     snap["extra-error"] = repr(e)
-            if tracer is not None and getattr(tracer, "enabled", False):
+            if tracer is not None:
                 # ring-overflow visibility: nonzero means the in-memory
                 # flight recorder (and /trace) is TRUNCATED
                 snap["trace.dropped-records"] = getattr(
                     tracer, "dropped", 0)
+                # what the spans cannot carry as durations: bytes read
+                # back, rows committed, dispatches, programs compiled
+                for name, n in tracer.counters().items():
+                    snap[f"trace.count.{name}"] = n
             return snap
 
         self._history = history

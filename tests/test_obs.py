@@ -37,7 +37,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture(autouse=True)
 def _null_tracer_after():
-    """Every test leaves the process-global tracer disabled."""
+    """Every test leaves the process-global tracer the local recorder."""
     yield
     obs.reset()
 
@@ -97,19 +97,35 @@ def test_tracer_spans_nest_backdate_and_persist(tmp_path):
     assert [r["name"] for r in small.records()] == ["e5", "e6", "e7", "e8"]
 
 
-def test_wire_context_propagation_and_null_tracer_zero_overhead():
-    # Default: the NullTracer. attach_trace adds NO wire field, spans
-    # are no-ops, nothing is recorded.
+def test_wire_context_propagation_and_local_recorder_adds_nothing(tmp_path):
+    # Default: the local flight recorder. It records, but attach_trace
+    # adds NO wire field, no file is opened, and ``enabled`` is False so
+    # call sites that gate cross-process work on it skip that work.
     tr0 = obs.get_tracer()
-    assert isinstance(tr0, obs.NullTracer) and not tr0.enabled
+    assert isinstance(tr0, obs.Tracer) and not tr0.enabled
+    assert tr0._path is None and tr0._file is None
     hdr = tp.attach_trace({"group": 1})
-    assert hdr == {"group": 1}, "disabled tracer must add no wire fields"
+    assert hdr == {"group": 1}, "local recorder must add no wire fields"
+    before = tr0.trace_id
     tp.adopt_trace({"group": 1, "trace": {"trace_id": "deadbeef"}})  # no-op
+    assert tr0.trace_id == before
     with tr0.span("x") as s:
-        assert s.span_id is None
+        assert s.span_id is not None
     tr0.event("y")
     tr0.complete("z", 1.0)
-    assert tr0.records() == [] and tr0.wire_context() is None
+    assert [r["name"] for r in tr0.records()] == ["x", "y", "z"]
+    assert tr0.wire_context() is None and tr0._file is None
+    assert os.listdir(tmp_path) == []
+
+    # The NullTracer stays for callers that pass one in.
+    null = obs.NullTracer()
+    with null.span("x") as s:
+        assert s.span_id is None
+    null.event("y")
+    null.complete("z", 1.0)
+    null.count("c", 3)
+    assert null.records() == [] and null.wire_context() is None
+    assert null.counters() == {}
 
     # Opt-in: the sender's header carries {trace_id, span}; the
     # receiving process adopts it and lands under the SAME trace id.
@@ -376,15 +392,15 @@ def test_epoch_spans_and_histograms_in_process(tmp_path):
 
     recs = tr.records()
     names = [rec["name"] for rec in recs]
-    for want in ("epoch", "epoch.steps", "epoch.fence",
+    for want in ("epoch", "epoch.steps", "fence",
                  "checkpoint.trigger", "checkpoint", "checkpoint.truncate",
-                 "epoch.inflight_truncate"):
+                 "ckpt.truncate"):
         assert want in names, f"missing {want} in {sorted(set(names))}"
     # Phase records nest under their epoch span.
     epoch0 = next(rec for rec in recs if rec["name"] == "epoch")
     assert epoch0["args"]["epoch"] == 0
     steps0 = next(rec for rec in recs if rec["name"] == "epoch.steps")
-    fence0 = next(rec for rec in recs if rec["name"] == "epoch.fence")
+    fence0 = next(rec for rec in recs if rec["name"] == "fence")
     assert steps0["parent"] == epoch0["span"]
     assert fence0["parent"] == epoch0["span"]
     assert epoch0["dur"] >= steps0["dur"]

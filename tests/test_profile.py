@@ -201,7 +201,8 @@ def test_recover_finalize_subspans_partition_finalize(tmp_path):
     assert "finalize" in pm
     subs = {k: v for k, v in pm.items() if k.startswith("finalize.")}
     saved = subs.pop("finalize.overlap-saved")
-    assert set(subs) == {"finalize.barrier-read",
+    assert set(subs) == {"finalize.barrier-dispatch",
+                        "finalize.barrier-read",
                         "finalize.state-verify"}
     assert saved >= 0.0
     assert sum(subs.values()) - saved == pytest.approx(
