@@ -560,6 +560,11 @@ class ClusterRunner:
             for v in job.vertices
             if isinstance(v.operator, TransactionalSinkOperator)}
         if self.txn_logs:
+            from clonos_tpu.runtime.sinktap import SinkTap
+            compiled = self.executor.compiled
+            self._sink_taps = {
+                vid: SinkTap(compiled.mesh, compiled.task_axis)
+                for vid in self.txn_logs}
             self.executor.on_block_outputs = self._absorb_sink_outputs
             self.coordinator.subscribe_completion(
                 lambda e: [tl.commit(e) for tl in self.txn_logs.values()])
@@ -579,9 +584,11 @@ class ClusterRunner:
             reader.notify_checkpoint_complete([int(x) for x in off])
 
     def _absorb_sink_outputs(self, outs, epoch: int) -> None:
-        """The sink tap after every block, in three spans: waiting out
-        the block program that produced ``outs`` (device busy, not
-        idle), the device-to-host copies, the per-subtask sharding
+        """The sink tap after every block, in three spans: launching the
+        compaction of the sink's rows behind the block program that
+        produced ``outs`` and waiting both out (device busy, not idle),
+        the device-to-host copy of the counts and the packed rows
+        (runtime/sinktap.py), the per-subtask sharding
         (``TransactionLog.absorb``)."""
         sinks = {vid: outs.sinks[vid] for vid in self.txn_logs
                  if vid in outs.sinks}
@@ -589,18 +596,24 @@ class ClusterRunner:
             return
         tr = get_tracer()
         with tr.span("block.sink.wait"):
-            # the first np.asarray below would wait the same time: the
-            # order of work is unchanged, only its name
-            jax.block_until_ready(sinks)
+            packed = {vid: self._sink_taps[vid].dispatch(b)
+                      for vid, b in sinks.items()}
+            jax.block_until_ready([(pk.counts, pk.rows)
+                                   for pk in packed.values()])
         with tr.span("block.sink.d2h") as sp:
-            host = {vid: (np.asarray(b.keys), np.asarray(b.values),
-                          np.asarray(b.timestamps), np.asarray(b.valid))
-                    for vid, b in sinks.items()}
-            nbytes = sum(a.nbytes for arrs in host.values() for a in arrs)
-            sp.set(bytes=nbytes)
+            host = {vid: self._sink_taps[vid].read(pk)
+                    for vid, pk in packed.items()}
+            nbytes = sum(pk.nbytes for pk in packed.values())
+            sp.set(bytes=nbytes,
+                   rung=max(pk.rung for pk in packed.values()))
+        misses = sum(pk.missed for pk in packed.values())
         tr.count("sink.d2h_bytes", nbytes)
-        for vid, arrs in host.items():
-            self.txn_logs[vid].absorb(epoch, *arrs)
+        tr.count("sink.rung_reads", len(packed))
+        if misses:
+            tr.count("sink.rung_misses", misses)
+        tr.count("block.dispatches.sink_pack", len(packed) + misses)
+        for vid, (counts, rows) in host.items():
+            self.txn_logs[vid].absorb(epoch, counts, rows)
 
     # --- live health gauges (heartbeat-piggybacked; runtime/remote.py) -------
 

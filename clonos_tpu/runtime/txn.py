@@ -56,27 +56,26 @@ class TransactionLog:
 
     # --- pre-commit side -----------------------------------------------------
 
-    def absorb(self, epoch: int, keys: np.ndarray, values: np.ndarray,
-               timestamps: np.ndarray, valid: np.ndarray) -> None:
-        """Append one block's sink emissions ([K, P, cap] arrays) to the
-        epoch's pending transaction, sharded per subtask."""
+    def absorb(self, epoch: int, counts: np.ndarray,
+               rows: np.ndarray) -> None:
+        """Append one block's sink emissions to the epoch's pending
+        transaction, sharded per subtask: ``counts [P]`` valid rows a
+        subtask and ``rows [P, 3, R]`` their (key, value, timestamp)
+        planes in (step, slot) order, as the tap packed them on the
+        device (runtime/sinktap.py)."""
         txn = self._pending.setdefault(epoch, _Txn(epoch))
         if txn.sealed:
             raise RuntimeError(f"epoch {epoch} transaction already sealed")
         tr = get_tracer()
         with tr.span("block.sink.shard") as sp:
-            p = keys.shape[1]
-            rows = 0
-            for sub in range(p):
-                m = valid[:, sub].reshape(-1)
-                flat = np.stack([keys[:, sub].reshape(-1)[m],
-                                 values[:, sub].reshape(-1)[m],
-                                 timestamps[:, sub].reshape(-1)[m]],
-                                axis=1)
-                rows += flat.shape[0]
-                txn.shards.setdefault(sub, []).append(flat)
-            sp.set(rows=rows)
-        tr.count("sink.rows", rows)
+            for sub, n in enumerate(counts):
+                # a copy, so that a shard of a few rows does not keep
+                # the whole read-back alive until its commit
+                txn.shards.setdefault(sub, []).append(
+                    rows[sub, :, :n].T.copy())
+            total = int(counts.sum())
+            sp.set(rows=total)
+        tr.count("sink.rows", total)
 
     def seal(self, epoch: int) -> None:
         """Epoch fence: the transaction stops accepting records

@@ -13,6 +13,7 @@ import pytest
 
 from clonos_tpu import obs
 from clonos_tpu.obs import trace as trace_mod
+from clonos_tpu.runtime import sinktap
 
 #: the spans one host-fed block emits, in the order they close (the
 #: parent last) — PERF.md section 3 carries the same names
@@ -354,13 +355,20 @@ def test_sink_counters_are_the_bytes_read_and_the_rows_committed(tmp_path):
         runner.run_epoch(complete_checkpoint=True)
     tr = obs.get_tracer()
     c = tr.counters()
-    # [K, P, capacity] keys, values, timestamps (int32) and valid (bool)
+    # what the tap copies: [P] counts and [P, 3, rung] packed rows
+    # (int32), not the [K, P, capacity] output; a lane of 8 x 16 slots
+    # has the one rung
     k, p, cap = 8, 2, 16
-    per_block = k * p * cap * (3 * 4 + 1)
+    (rung,) = sinktap.ladder(k * cap)
+    per_block = p * 4 + p * 3 * rung * 4
     blocks = c["block.dispatches.run_block"]
     assert blocks == 6 and c["sink.d2h_bytes"] == blocks * per_block
     d2h = [r for r in tr.records() if r["name"] == "block.sink.d2h"]
-    assert [r["args"]["bytes"] for r in d2h] == [per_block] * blocks
+    assert [r["args"] for r in d2h] == [
+        {"bytes": per_block, "rung": rung}] * blocks
+    # every block read through a rung, none read again
+    assert c["sink.rung_reads"] == c["block.dispatches.sink_pack"] == blocks
+    assert "sink.rung_misses" not in c
     committed = txn.committed_stream().shape[0]
     assert committed > 0
     assert c["sink.rows"] == c["txn.rows_committed"] == committed
