@@ -23,8 +23,8 @@ from typing import Callable, Optional
 
 from clonos_tpu.api.operators import (
     FilterOperator, HostFeedSource, IntervalJoinOperator, KeyedReduceOperator,
-    MapOperator, Operator, SinkOperator, SyntheticSource,
-    TumblingWindowCountOperator, UnionOperator,
+    MapOperator, Operator, OperatorStateCountOperator, SinkOperator,
+    SyntheticSource, TumblingWindowCountOperator, UnionOperator,
 )
 from clonos_tpu.graph.job_graph import JobGraph, JobVertex, PartitionType
 
@@ -65,13 +65,20 @@ class DataStream:
 
     # --- transformations -----------------------------------------------------
 
-    def map(self, fn, name: str = "map",
-            parallelism: Optional[int] = None) -> "DataStream":
-        return self._attach(name, MapOperator(fn), parallelism)
+    def map(self, fn, name: str = "map", parallelism: Optional[int] = None,
+            capacity: Optional[int] = None) -> "DataStream":
+        return self._attach(name, MapOperator(fn), parallelism,
+                            capacity=capacity)
 
     def filter(self, pred, name: str = "filter",
                parallelism: Optional[int] = None) -> "DataStream":
         return self._attach(name, FilterOperator(pred), parallelism)
+
+    def count_through(self, name: str = "operator-state",
+                      parallelism: Optional[int] = None) -> "DataStream":
+        """Pass records on unchanged, counting them in per-subtask
+        operator state (operators.OperatorStateCountOperator)."""
+        return self._attach(name, OperatorStateCountOperator(), parallelism)
 
     def reduce(self, num_keys: int, reduce_fn=None, name: str = "reduce",
                parallelism: Optional[int] = None) -> "DataStream":
@@ -182,14 +189,16 @@ class DataStream:
 
     def sink(self, name: str = "sink",
              parallelism: Optional[int] = None,
-             transactional: bool = False) -> "DataStream":
+             transactional: bool = False,
+             capacity: Optional[int] = None) -> "DataStream":
         """``transactional=True`` routes emissions through the 2PC
         transaction log (exactly-once egress; runtime/txn.py)."""
         if transactional:
             from clonos_tpu.api.operators import TransactionalSinkOperator
             return self._attach(name, TransactionalSinkOperator(),
-                                parallelism)
-        return self._attach(name, SinkOperator(), parallelism)
+                                parallelism, capacity=capacity)
+        return self._attach(name, SinkOperator(), parallelism,
+                            capacity=capacity)
 
     @property
     def vertex(self) -> JobVertex:

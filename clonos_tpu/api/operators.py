@@ -511,12 +511,27 @@ class TumblingWindowCountOperator(Operator):
 _NO_WINDOW = -(2 ** 30)
 
 
-@dataclasses.dataclass
-class EventTimeTumblingWindowOperator(Operator):
-    """Event-time tumbling windowed sum per key with watermark-driven
-    firing (WindowOperator + EventTimeTrigger analog; reference
-    flink-streaming-java .../windowing/WindowOperator.java with
-    watermarks from StreamSourceContexts.java:180-187).
+#: "no valid record yet": the fold's identity for ``max_ts``
+_NO_TS = -(2 ** 31) + 1
+
+
+def _segmented_cumsum(values: jnp.ndarray, reset: jnp.ndarray
+                      ) -> jnp.ndarray:
+    """Inclusive running sum along axis 0 that restarts at every step
+    whose ``reset`` is set (that step's value opens the new segment).
+    One associative scan: int32 adds wrap and associate, so any
+    bracketing equals the step-by-step fold bit for bit."""
+    def combine(a, b):
+        fa, va = a
+        fb, vb = b
+        return fa | fb, jnp.where(fb, vb, va + vb)
+    return jax.lax.associative_scan(combine, (reset, values), axis=0)[1]
+
+
+class EventTimeWindow(Operator):
+    """Event-time windowed sum per key with watermark-driven firing: what
+    the tumbling and the sliding operator share (a tumbling window is a
+    sliding one whose slide is its size). Window id = start // slide.
 
     TPU-first watermark discipline: the watermark is a PURE FOLD over the
     record timestamps flowing through this operator —
@@ -536,13 +551,187 @@ class EventTimeTumblingWindowOperator(Operator):
     granularity).
 
     State per subtask: ``open_windows`` accumulator slots ``acc[W, nk]``
-    with absolute window ids ``win[W]`` (-1 = free), plus ``max_ts``.
-    A record with ts in a window older than every open slot (arrived
-    after its window fired, or slots exhausted) is a LATE DROP — counted
-    in ``late`` like the reference's lateness side-output. Windows whose
-    end <= watermark fire: one record per key with a nonzero sum,
-    timestamped with the window end.
+    with absolute window ids ``win[W]`` (``_NO_WINDOW`` = free), plus
+    ``max_ts``. A record whose window the watermark has passed is a LATE
+    DROP — counted in ``late`` like the reference's lateness side-output
+    (once per record, not per (record, window) pair). Windows whose
+    end <= watermark fire FIRST: one record per key with a nonzero sum,
+    timestamped with the window end and counted in ``fired`` (a window
+    completed by this step's records emits next step — deterministic
+    one-step emission latency).
+
+    Why a slot never holds the wrong window: the ids still open after the
+    fire lie in ``[wm_floor, max_ts // slide]``, at most
+    ``(out_of_orderness + size) // slide + 1`` consecutive values, fewer
+    than ``open_windows``; so of the ids congruent to a slot exactly one
+    can be open at a time, and whatever the slot holds is that one. Every
+    state reached from ``init_state`` keeps this, and ``process_block``
+    leans on it: a valid record is accepted iff its window is not closed.
     """
+
+    num_keys: int
+    window_size: int
+    out_of_orderness: int
+    open_windows: int
+
+    @property
+    def _slide(self) -> int:
+        raise NotImplementedError
+
+    @property
+    def out_capacity(self):  # type: ignore[override]
+        # All open windows may fire in one step.
+        return self.num_keys * self.open_windows
+
+    def static_out_keys(self) -> Optional[np.ndarray]:
+        # Dense table emission: slot (w, i) always carries key i.
+        return np.tile(np.arange(self.num_keys, dtype=np.int32),
+                       self.open_windows)
+
+    def init_state(self, parallelism: int):
+        w = self.open_windows
+        return {
+            "acc": jnp.zeros((parallelism, w, self.num_keys), jnp.int32),
+            "win": jnp.full((parallelism, w), _NO_WINDOW, jnp.int32),
+            "max_ts": jnp.full((parallelism,), _NO_TS, jnp.int32),
+            "late": jnp.zeros((parallelism,), jnp.int32),
+            "fired": jnp.zeros((parallelism,), jnp.int32),
+        }
+
+    def _window_end(self, win: jnp.ndarray) -> jnp.ndarray:
+        """End of window ``win``; a free slot reads as window 0 so that
+        the sentinel never enters the multiply."""
+        return (jnp.where(win != _NO_WINDOW, win, 0) * self._slide
+                + self.window_size)
+
+    def process(self, state, batch, ctx):
+        nk, w = self.num_keys, self.open_windows
+        size, slide = self.window_size, self._slide
+
+        def one(acc, win, max_ts, late, fired, b: RecordBatch):
+            # Advance the watermark from this step's data (pure fold).
+            step_max = jnp.max(jnp.where(b.valid, b.timestamps, _NO_TS))
+            max_ts = jnp.maximum(max_ts, step_max)
+            wm = max_ts - self.out_of_orderness
+            # FIRE FIRST: every open window with end <= wm closes, freeing
+            # its slot before this step's records are assigned.
+            win_end = self._window_end(win)                   # [W]
+            fire = (win != _NO_WINDOW) & (win_end <= wm)
+            out = RecordBatch(
+                keys=jnp.asarray(self.static_out_keys()),
+                values=acc.reshape(-1),
+                timestamps=jnp.repeat(win_end, nk),
+                valid=(fire[:, None] & (acc != 0)).reshape(-1))
+            fired = fired + jnp.sum(out.valid.astype(jnp.int32))
+            acc = jnp.where(fire[:, None], 0, acc)
+            win = jnp.where(fire, _NO_WINDOW, win)
+            # Newest window containing ts starts at floor(ts/slide)*slide
+            # (jnp // floors); the record is also in the size // slide - 1
+            # windows before it.
+            base = b.timestamps // slide
+            ok_any = jnp.zeros_like(b.valid)
+            for j in range(size // slide):
+                rw = base - j                              # window id
+                closed = rw * slide + size <= wm           # behind the wm
+                slot = rw % w
+                slot_win = win[slot]
+                ok = b.valid & ~closed & ((slot_win == rw)
+                                          | (slot_win == _NO_WINDOW))
+                ok_any = ok_any | ok
+                win = win.at[slot].max(jnp.where(ok, rw, _NO_WINDOW),
+                                       mode="drop")
+                acc = acc.at[slot, jnp.clip(b.keys, 0, nk - 1)].add(
+                    jnp.where(ok, b.values, 0), mode="drop")
+            late = late + jnp.sum((b.valid & ~ok_any).astype(jnp.int32))
+            return acc, win, max_ts, late, fired, zero_invalid(out)
+
+        acc, win, max_ts, late, fired, out = jax.vmap(one)(
+            state["acc"], state["win"], state["max_ts"], state["late"],
+            state["fired"], batch)
+        return ({"acc": acc, "win": win, "max_ts": max_ts, "late": late,
+                 "fired": fired}, out)
+
+    def process_block(self, state, batches, bctx):
+        # Step-batched form, no scan over the steps and no scatter: the
+        # watermark is a running maximum over the step axis; a record is
+        # accepted iff its window is not behind it (class docstring);
+        # the window a slot holds after step k is the largest id ever
+        # accepted into the slot, if that is still open; per-step sums
+        # per (slot, key) come from the keyed histogram over the
+        # composite lane ``slot * nk + key``; accumulators are those sums
+        # in a running sum that restarts where the slot fires; a fire
+        # emits the accumulator as the step before left it.
+        from clonos_tpu.ops.histogram import KERNEL_MAX_KEYS, keyed_hist
+        K, p, _ = batches.keys.shape
+        nk, w = self.num_keys, self.open_windows
+        size, slide = self.window_size, self._slide
+        valid, ts = batches.valid, batches.timestamps
+        step_max = jnp.max(jnp.where(valid, ts, _NO_TS), axis=2)  # [K, P]
+        max_ts = jnp.maximum(state["max_ts"][None],
+                             jax.lax.cummax(step_max, axis=0))
+        wm = max_ts - self.out_of_orderness                       # [K, P]
+
+        key = jnp.clip(batches.keys, 0, nk - 1)
+        slots = jnp.arange(w, dtype=jnp.int32)
+        base = ts // slide
+        ok_any = jnp.zeros_like(valid)
+        contrib = jnp.zeros((K, p, w * nk), jnp.int32)
+        taken = jnp.full((K, p, w), _NO_WINDOW, jnp.int32)
+        for j in range(size // slide):
+            rw = base - j
+            ok = valid & ~(rw * slide + size <= wm[:, :, None])
+            ok_any = ok_any | ok
+            slot = rw % w
+            if w * nk <= KERNEL_MAX_KEYS:
+                part, _ = keyed_hist(slot * nk + key, batches.values, ok,
+                                     w * nk, want_counts=False)
+            else:       # a table wider than the kernel takes: slot by slot
+                part = jnp.concatenate([
+                    keyed_hist(key, batches.values, ok & (slot == s), nk,
+                               want_counts=False)[0] for s in range(w)],
+                    axis=2)
+            contrib = contrib + part
+            hit = ok[..., None] & (slot[..., None] == slots)   # [K,P,B,W]
+            taken = jnp.maximum(taken, jnp.max(
+                jnp.where(hit, rw[..., None], _NO_WINDOW), axis=2))
+        late = state["late"] + jnp.sum(
+            (valid & ~ok_any).astype(jnp.int32), axis=(0, 2))
+
+        # win after each step, and before it (what that step's fire sees)
+        seen = jnp.maximum(state["win"][None],
+                           jax.lax.cummax(taken, axis=0))         # [K,P,W]
+        held = jnp.where(self._window_end(seen) <= wm[:, :, None],
+                         _NO_WINDOW, seen)
+        before = jnp.concatenate([state["win"][None], held[:-1]], axis=0)
+        win_end = self._window_end(before)
+        fire = (before != _NO_WINDOW) & (win_end <= wm[:, :, None])
+
+        lanes = lambda x: jnp.repeat(x, nk, axis=2)            # [K,P,W*nk]
+        fire_l = lanes(fire)
+        acc0 = state["acc"].reshape(p, w * nk)
+        first = jnp.where(fire_l[0], 0, acc0) + contrib[0]
+        acc = _segmented_cumsum(
+            jnp.concatenate([first[None], contrib[1:]], axis=0),
+            jnp.concatenate([jnp.ones_like(fire_l[:1]), fire_l[1:]],
+                            axis=0))
+        emit = jnp.concatenate([acc0[None], acc[:-1]], axis=0)
+        out = zero_invalid(RecordBatch(
+            keys=jnp.broadcast_to(jnp.asarray(self.static_out_keys()),
+                                  (K, p, w * nk)),
+            values=emit, timestamps=lanes(win_end),
+            valid=fire_l & (emit != 0)))
+        fired = state["fired"] + jnp.sum(
+            out.valid.astype(jnp.int32), axis=(0, 2))
+        return ({"acc": acc[-1].reshape(p, w, nk), "win": held[-1],
+                 "max_ts": max_ts[-1], "late": late, "fired": fired}, out)
+
+
+@dataclasses.dataclass
+class EventTimeTumblingWindowOperator(EventTimeWindow):
+    """Event-time tumbling windowed sum per key (WindowOperator +
+    EventTimeTrigger analog; reference flink-streaming-java
+    .../windowing/WindowOperator.java with watermarks from
+    StreamSourceContexts.java:180-187). See :class:`EventTimeWindow`."""
 
     num_keys: int
     window_size: int
@@ -557,72 +746,15 @@ class EventTimeTumblingWindowOperator(Operator):
         self.open_windows = max(self.open_windows, need)
 
     @property
-    def out_capacity(self):  # type: ignore[override]
-        # All open windows may fire in one step.
-        return self.num_keys * self.open_windows
-
-    def init_state(self, parallelism: int):
-        w = self.open_windows
-        return {
-            "acc": jnp.zeros((parallelism, w, self.num_keys), jnp.int32),
-            "win": jnp.full((parallelism, w), _NO_WINDOW, jnp.int32),
-            "max_ts": jnp.full((parallelism,), -(2 ** 31) + 1, jnp.int32),
-            "late": jnp.zeros((parallelism,), jnp.int32),
-        }
-
-    def process(self, state, batch, ctx):
-        nk, w, size = self.num_keys, self.open_windows, self.window_size
-
-        def one(acc, win, max_ts, late, b: RecordBatch):
-            # Advance the watermark from this step's data (pure fold).
-            step_max = jnp.max(jnp.where(b.valid, b.timestamps,
-                                         -(2 ** 31) + 1))
-            max_ts = jnp.maximum(max_ts, step_max)
-            wm = max_ts - self.out_of_orderness
-            # FIRE FIRST: every open window with end <= wm closes, freeing
-            # slots so this step's newest windows can't collide with
-            # stale ones (a window completed by this step's records emits
-            # next step — deterministic one-step emission latency).
-            open_ = win != _NO_WINDOW
-            win_end = (jnp.where(open_, win, 0) + 1) * size   # [W]
-            fire = open_ & (win_end <= wm)                # [W]
-            keys = jnp.broadcast_to(
-                jnp.arange(nk, dtype=jnp.int32)[None, :], (w, nk))
-            out = RecordBatch(
-                keys=keys.reshape(-1),
-                values=acc.reshape(-1),
-                timestamps=jnp.broadcast_to(
-                    win_end[:, None], (w, nk)).reshape(-1),
-                valid=(fire[:, None] & (acc != 0)).reshape(-1))
-            acc = jnp.where(fire[:, None], 0, acc)
-            win = jnp.where(fire, _NO_WINDOW, win)
-            # Assign records to absolute windows.
-            rw = b.timestamps // size          # jnp // floors already
-            closed = (rw + 1) * size <= wm                # behind the wm
-            slot = rw % w
-            slot_win = win[slot]                          # [B]
-            ok = b.valid & ~closed & ((slot_win == rw)
-                                      | (slot_win == _NO_WINDOW))
-            late = late + jnp.sum((b.valid & ~ok).astype(jnp.int32))
-            win = win.at[slot].max(jnp.where(ok, rw, _NO_WINDOW),
-                                   mode="drop")
-            acc = acc.at[slot, jnp.clip(b.keys, 0, nk - 1)].add(
-                jnp.where(ok, b.values, 0), mode="drop")
-            return acc, win, max_ts, late, zero_invalid(out)
-
-        acc, win, max_ts, late, out = jax.vmap(one)(
-            state["acc"], state["win"], state["max_ts"], state["late"],
-            batch)
-        return ({"acc": acc, "win": win, "max_ts": max_ts,
-                 "late": late}, out)
+    def _slide(self) -> int:
+        return self.window_size
 
 
 @dataclasses.dataclass
-class SlidingEventTimeWindowOperator(Operator):
+class SlidingEventTimeWindowOperator(EventTimeWindow):
     """Event-time SLIDING windowed sum per key: each record contributes to
     ``size // slide`` consecutive windows (WindowOperator +
-    SlidingEventTimeWindows analog). Window id = its start // slide.
-    Same pure-fold watermark discipline as the tumbling variant."""
+    SlidingEventTimeWindows analog). See :class:`EventTimeWindow`."""
 
     num_keys: int
     window_size: int
@@ -637,69 +769,8 @@ class SlidingEventTimeWindowOperator(Operator):
         self.open_windows = max(self.open_windows, need)
 
     @property
-    def out_capacity(self):  # type: ignore[override]
-        return self.num_keys * self.open_windows
-
-    def init_state(self, parallelism: int):
-        w = self.open_windows
-        return {
-            "acc": jnp.zeros((parallelism, w, self.num_keys), jnp.int32),
-            "win": jnp.full((parallelism, w), _NO_WINDOW, jnp.int32),
-            "max_ts": jnp.full((parallelism,), -(2 ** 31) + 1, jnp.int32),
-            "late": jnp.zeros((parallelism,), jnp.int32),
-        }
-
-    def process(self, state, batch, ctx):
-        nk, w = self.num_keys, self.open_windows
-        size, slide = self.window_size, self.slide
-        per = size // slide
-
-        def one(acc, win, max_ts, late, b: RecordBatch):
-            step_max = jnp.max(jnp.where(b.valid, b.timestamps,
-                                         -(2 ** 31) + 1))
-            max_ts = jnp.maximum(max_ts, step_max)
-            wm = max_ts - self.out_of_orderness
-            # Fire first (see the tumbling variant).
-            open_ = win != _NO_WINDOW
-            win_end = jnp.where(open_, win, 0) * slide + size   # [W]
-            fire = open_ & (win_end <= wm)
-            keys = jnp.broadcast_to(
-                jnp.arange(nk, dtype=jnp.int32)[None, :], (w, nk))
-            out = RecordBatch(
-                keys=keys.reshape(-1),
-                values=acc.reshape(-1),
-                timestamps=jnp.broadcast_to(
-                    win_end[:, None], (w, nk)).reshape(-1),
-                valid=(fire[:, None] & (acc != 0)).reshape(-1))
-            acc = jnp.where(fire[:, None], 0, acc)
-            win = jnp.where(fire, _NO_WINDOW, win)
-            # Newest window containing ts starts at floor(ts/slide)*slide;
-            # the record is in windows starting there minus j*slide.
-            base = b.timestamps // slide       # jnp // floors already
-            ok_any = jnp.zeros_like(b.valid)
-            for j in range(per):
-                rw = base - j                              # window id
-                closed = rw * slide + size <= wm
-                slot = rw % w
-                slot_win = win[slot]
-                ok = b.valid & ~closed & ((slot_win == rw)
-                                          | (slot_win == _NO_WINDOW))
-                ok_any = ok_any | ok
-                win = win.at[slot].max(jnp.where(ok, rw, _NO_WINDOW),
-                                       mode="drop")
-                acc = acc.at[slot, jnp.clip(b.keys, 0, nk - 1)].add(
-                    jnp.where(ok, b.values, 0), mode="drop")
-            # One late increment per record dropped from ALL its windows
-            # (reference numLateRecordsDropped counts elements, not
-            # (element, window) pairs).
-            late = late + jnp.sum((b.valid & ~ok_any).astype(jnp.int32))
-            return acc, win, max_ts, late, zero_invalid(out)
-
-        acc, win, max_ts, late, out = jax.vmap(one)(
-            state["acc"], state["win"], state["max_ts"], state["late"],
-            batch)
-        return ({"acc": acc, "win": win, "max_ts": max_ts,
-                 "late": late}, out)
+    def _slide(self) -> int:
+        return self.slide
 
 
 @dataclasses.dataclass
@@ -1016,6 +1087,24 @@ class IntervalJoinOperator(TwoInputOperator):
         out = jax.tree_util.tree_map(
             lambda x: x.reshape((K,) + x.shape[2:]), outs)
         return {"lv": lv, "lt": lt, "lm": lm, "cursor": cur}, out
+
+
+@dataclasses.dataclass
+class OperatorStateCountOperator(Operator):
+    """Pass-through with operator (non-keyed) state: each subtask counts
+    the records it has seen and hands them on unchanged (the shape of a
+    mapper that keeps per-subtask list state; reference
+    OperatorStateBackend ListState under a RichMapFunction)."""
+
+    def init_state(self, parallelism: int):
+        return {"seen": jnp.zeros((parallelism,), jnp.int32)}
+
+    def process(self, state, batch, ctx):
+        return {"seen": state["seen"] + batch.count()}, zero_invalid(batch)
+
+    def process_block(self, state, batches, bctx):
+        out = zero_invalid(batches)
+        return {"seen": state["seen"] + out.count().sum(axis=0)}, out
 
 
 @dataclasses.dataclass

@@ -108,7 +108,8 @@ _COUNT_ROUTE_MIN_BYTES = 256 << 20
 @functools.cache
 def _count_route_budget() -> int:
     """Cap on the counting exchange's ``[K, n, T+1]`` cumsum scratch
-    (priced at ~3 concurrent buffers); routes past it take the flat sort.
+    (priced at ~3 concurrent buffers); routes past it take the flat sort
+    or, long ones, count chunk by chunk (:func:`_block_to_targets`).
     ~2% of the device's memory limit, within [256 MiB, 2 GiB]: ~336 MB
     on a 16 GB v5e, so the ~0.9 GB whole-recovery-window route at bench
     shapes sorts there instead of crowding the GB-scale log state. A TPU
@@ -124,6 +125,79 @@ def _note_route(route: str, **shape) -> None:
     """Trace-time record of which form an exchange was lowered to
     (``exchange.route`` instant; chip_smoke.py prints them)."""
     get_tracer().event("exchange.route", route=route, **shape)
+
+
+#: longest block (in records) the flat sort still routes when the
+#: counting scratch is over budget. XLA compiles a TPU sort in time that
+#: grows with its length (compiled for the v5e: 133 s at 8.4 M records,
+#: a recovery window of 8,192 steps x 1,024; 445 s at 33.5 M), and
+#: recovery prewarms one such program per edge. Longer blocks count in
+#: chunks of steps.
+_SORT_ROUTE_MAX_RECORDS = 1 << 21
+
+#: records in one chunk of the chunked counting route, at most: what the
+#: blocks that count whole already hold (1,024 steps x 1,024 records).
+#: Past it the compiler's time for the one-hot running count is erratic
+#: (for the v5e: 6 s at [256, 4096] records, 85 s at [512, 4096]).
+_COUNT_CHUNK_MAX_RECORDS = 1 << 20
+
+
+def _step_chunk(K: int, limit: int) -> int:
+    """Largest divisor of ``K`` that is at most ``limit`` (0: none)."""
+    return next((c for c in range(min(K, limit), 0, -1) if K % c == 0), 0)
+
+
+def _count_to_targets(
+    batch: RecordBatch, target: jnp.ndarray, num_targets: int,
+    out_capacity: int
+) -> Tuple[RecordBatch, jnp.ndarray]:
+    """The counting route of :func:`_block_to_targets` (its docstring):
+    every step on its own, so any cut of the step axis gives the same
+    result."""
+    K, P, B = batch.keys.shape
+    T = num_targets
+    n = P * B
+    fl = lambda x: jnp.reshape(x, (K, n))
+    keys, vals, ts, valid = map(fl, batch)
+    tgt = jnp.where(valid, fl(target), T)
+    onehot = (tgt[:, :, None] ==
+              jnp.arange(T + 1, dtype=jnp.int32)[None, None, :])
+    pos_all = jnp.cumsum(onehot.astype(jnp.int32), axis=1)
+    pos = jnp.take_along_axis(
+        pos_all, tgt[:, :, None], axis=2)[:, :, 0] - 1
+    counts = pos_all[:, -1, :T]
+    keep = (tgt < T) & (pos < out_capacity)
+    dropped = jnp.maximum(counts - out_capacity, 0).astype(jnp.int32)
+    # Placement: (target, rank) pairs are UNIQUE per step, so a keyed
+    # histogram over the flattened slot id IS the routed batch (sum
+    # of one contribution = select) — the Pallas VPU kernel streams
+    # it where an XLA element scatter ran ~50ms/field at bench
+    # shapes (see _block_to_target_lane). Slot tables wider than the
+    # kernel compiles for are placed by an element scatter.
+    nk = T * out_capacity
+    via_hist = nk <= KERNEL_MAX_KEYS
+    _note_route("kernel" if via_hist and uses_kernel() else "scatter",
+                steps=K, records=n, targets=T, capacity=out_capacity)
+    if via_hist:
+        slot = jnp.where(keep, tgt * out_capacity + pos, -1)
+        out_k, cnt = keyed_hist(slot, keys, keep, nk)
+        out_v, _ = keyed_hist(slot, vals, keep, nk, want_counts=False)
+        out_t, _ = keyed_hist(slot, ts, keep, nk, want_counts=False)
+        sh = (K, T, out_capacity)
+        out = RecordBatch(out_k.reshape(sh), out_v.reshape(sh),
+                          out_t.reshape(sh), cnt.reshape(sh) > 0)
+        return zero_invalid(out), dropped
+    row = jnp.where(keep, tgt, T)
+    col = jnp.where(keep, pos, 0)
+    kidx = jnp.arange(K, dtype=jnp.int32)[:, None]
+    shape = (K, T + 1, out_capacity)
+    mk = lambda src, z: jnp.zeros(shape, z).at[kidx, row, col].set(
+        src, mode="drop")
+    out = RecordBatch(mk(keys, jnp.int32), mk(vals, jnp.int32),
+                      mk(ts, jnp.int32), mk(keep, jnp.bool_))
+    out = RecordBatch(out.keys[:, :T], out.values[:, :T],
+                      out.timestamps[:, :T], out.valid[:, :T])
+    return zero_invalid(out), dropped
 
 
 def _block_to_targets(
@@ -147,7 +221,9 @@ def _block_to_targets(
 
     Routes whose cumsum scratch would exceed :func:`_count_route_budget`
     (huge T or K) take one block-wide composite-key sort
-    (``step * (T+1) + target``, stable) with gather placement.
+    (``step * (T+1) + target``, stable) with gather placement, up to
+    ``_SORT_ROUTE_MAX_RECORDS``; longer blocks count chunk after chunk
+    of steps, each chunk within the budget.
     """
     K, P, B = batch.keys.shape
     T = num_targets
@@ -155,48 +231,19 @@ def _block_to_targets(
     # Price the ~3 concurrent [K, n, T+1] buffers this branch holds (the
     # one-hot's int32 cast, the cumsum output, and one fusion temp), not
     # just one — the cap must actually bound peak scratch.
-    if K * n * (T + 1) * 4 * 3 <= _count_route_budget():
-        fl = lambda x: jnp.reshape(x, (K, n))
-        keys, vals, ts, valid = map(fl, batch)
-        tgt = jnp.where(valid, fl(target), T)
-        onehot = (tgt[:, :, None] ==
-                  jnp.arange(T + 1, dtype=jnp.int32)[None, None, :])
-        pos_all = jnp.cumsum(onehot.astype(jnp.int32), axis=1)
-        pos = jnp.take_along_axis(
-            pos_all, tgt[:, :, None], axis=2)[:, :, 0] - 1
-        counts = pos_all[:, -1, :T]
-        keep = (tgt < T) & (pos < out_capacity)
-        dropped = jnp.maximum(counts - out_capacity, 0).astype(jnp.int32)
-        # Placement: (target, rank) pairs are UNIQUE per step, so a keyed
-        # histogram over the flattened slot id IS the routed batch (sum
-        # of one contribution = select) — the Pallas VPU kernel streams
-        # it where an XLA element scatter ran ~50ms/field at bench
-        # shapes (see _block_to_target_lane). Slot tables wider than the
-        # kernel compiles for are placed by an element scatter.
-        nk = T * out_capacity
-        via_hist = nk <= KERNEL_MAX_KEYS
-        _note_route("kernel" if via_hist and uses_kernel() else "scatter",
-                    steps=K, records=n, targets=T, capacity=out_capacity)
-        if via_hist:
-            slot = jnp.where(keep, tgt * out_capacity + pos, -1)
-            out_k, cnt = keyed_hist(slot, keys, keep, nk)
-            out_v, _ = keyed_hist(slot, vals, keep, nk, want_counts=False)
-            out_t, _ = keyed_hist(slot, ts, keep, nk, want_counts=False)
-            sh = (K, T, out_capacity)
-            out = RecordBatch(out_k.reshape(sh), out_v.reshape(sh),
-                              out_t.reshape(sh), cnt.reshape(sh) > 0)
-            return zero_invalid(out), dropped
-        row = jnp.where(keep, tgt, T)
-        col = jnp.where(keep, pos, 0)
-        kidx = jnp.arange(K, dtype=jnp.int32)[:, None]
-        shape = (K, T + 1, out_capacity)
-        mk = lambda src, z: jnp.zeros(shape, z).at[kidx, row, col].set(
-            src, mode="drop")
-        out = RecordBatch(mk(keys, jnp.int32), mk(vals, jnp.int32),
-                          mk(ts, jnp.int32), mk(keep, jnp.bool_))
-        out = RecordBatch(out.keys[:, :T], out.values[:, :T],
-                          out.timestamps[:, :T], out.valid[:, :T])
-        return zero_invalid(out), dropped
+    per_step = n * (T + 1) * 4 * 3
+    if K * per_step <= _count_route_budget():
+        return _count_to_targets(batch, target, T, out_capacity)
+    kc = _step_chunk(K, min(_count_route_budget() // per_step,
+                            _COUNT_CHUNK_MAX_RECORDS // n))
+    if K * n > _SORT_ROUTE_MAX_RECORDS and kc:
+        cut = lambda x: x.reshape((K // kc, kc) + x.shape[1:])
+        routed, dropped = jax.lax.map(
+            lambda bt: _count_to_targets(RecordBatch(*bt[:4]), bt[4], T,
+                                         out_capacity),
+            tuple(map(cut, batch)) + (cut(target),))
+        join = lambda x: x.reshape((K,) + x.shape[2:])
+        return RecordBatch(*map(join, routed)), join(dropped)
     # Flat sort (scratch over budget): one composite-key sort over the
     # block.
     _note_route("sort", steps=K, records=n, targets=T,
@@ -236,9 +283,8 @@ def _block_to_target_lane(batch: RecordBatch, target: jnp.ndarray,
     A record's slot within its target is its arrival rank; for a single
     lane that is a running count over a ``[K, n]`` membership mask — no
     ``[K, n, T+1]`` one-hot — so scratch and compute shrink by (T+1)x
-    and the single-failure replay exchange stays on the counting path
-    at whole-recovery-window K, where the full route falls back to the
-    flat 67M-record sort (~400ms at bench shapes; this is ~10x less)."""
+    and the single-failure replay exchange counts a whole recovery
+    window in one piece, where the full route goes chunk by chunk."""
     K, P, B = batch.keys.shape
     n = P * B
     _note_route("kernel" if uses_kernel() else "scatter", steps=K,

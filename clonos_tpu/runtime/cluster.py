@@ -509,6 +509,9 @@ class ClusterRunner:
         self._route_cache_enabled = False
         #: observability/test hook: cache hits in the last recover()
         self._route_cache_hits = 0
+        #: vertex id -> (late, fired) totals of an event-time window at
+        #: the last fence
+        self._window_totals: Dict[int, np.ndarray] = {}
         self._last_records_total = 0
         #: checkpoint id -> np [L] log heads at that fence, harvested from
         #: the per-epoch health read (recovery's patch phase reads them
@@ -871,9 +874,8 @@ class ClusterRunner:
         """Single-consumer-lane exchange replay: compute the routed lane
         ``sub`` DIRECTLY (routing._block_to_target_lane — a [m, n]
         running count instead of the [m, n, T+1] one-hot), bit-identical
-        to the full route's lane. Keeps the single-failure replay on the
-        counting path at whole-window m where the full exchange falls
-        back to the flat sort."""
+        to the full route's lane. Counts a whole window of m steps in
+        one piece, where the full exchange goes chunk by chunk."""
         e = self.job.edges[eidx]
         dst_p = self.job.vertices[e.dst].parallelism
         compiled = self.executor.compiled
@@ -925,7 +927,14 @@ class ClusterRunner:
             return f
         return self._jitted(("route_raw", eidx, m, all_lanes), make)
 
+    #: replica rows one call of the rebuild program copies: its scratch
+    #: is this many log rows, not the whole replica set (which, gathered
+    #: beside the carry, does not fit the chip once a job is deep)
+    REPLICA_COPY_ROWS = 64
+
     def _replica_copy_fn(self):
+        """``replicas[ri] = logs[oi]`` for ``REPLICA_COPY_ROWS`` pairs
+        (``ri`` past the end: no row), in place on the donated replicas."""
         return self._jitted(("replica_copy",), lambda: (
             lambda replicas, logs, ri, oi: jax.tree_util.tree_map(
                 lambda s, l: s.at[ri].set(l[oi], mode="drop"),
@@ -1771,14 +1780,28 @@ class ClusterRunner:
         # evicting in insertion order is oldest-first and O(1) — a
         # pruned-but-needed entry only costs the patch fallback's one
         # device read.
+        heads_end = nf + 1 + self.executor.compiled.L
         with self._ck_heads_lock:
-            self._ck_log_heads[closed] = vec[nf + 1:].astype(np.int64)
+            self._ck_log_heads[closed] = vec[nf + 1:heads_end].astype(
+                np.int64)
             while len(self._ck_log_heads) > 128:
                 self._ck_log_heads.pop(
                     next(iter(self._ck_log_heads)))
         delta_records = total_records - self._last_records_total
         self._m_records.mark(delta_records)
         self._last_records_total = total_records
+        # Event-time windows: what each dropped as late and fired during
+        # the epoch, from the totals the same read brought back.
+        tr = get_tracer()
+        totals = vec[heads_end:].astype(np.int64)
+        for i, vid in enumerate(
+                self.executor.compiled.event_window_vertices):
+            name = self.job.vertices[vid].name
+            late, fired = totals[2 * i:2 * i + 2] - self._window_totals.get(
+                vid, 0)
+            self._window_totals[vid] = totals[2 * i:2 * i + 2]
+            tr.count("window.late_records." + name, int(late))
+            tr.count("window.fired_rows." + name, int(fired))
         return delta_records
 
     def _seal_and_trigger(self, closed: int, window_fn, snap_fn,
@@ -2622,14 +2645,14 @@ class ClusterRunner:
             for r in self.plan.replicas_held_by(flat):
                 rs.append(r)
                 os_.append(self.plan.pairs[r][0])
-        if rs:
-            # Fixed-size scatter (pad with out-of-range rows, mode=drop)
-            # so one prewarmed program serves every failure-set size.
-            nr = self.plan.num_replicas
-            rs_p = np.full((nr,), nr, np.int32)
-            os_p = np.zeros((nr,), np.int32)
-            rs_p[:len(rs)] = rs
-            os_p[:len(os_)] = os_
+        # Fixed-size scatters (padded with out-of-range rows, mode=drop)
+        # so one prewarmed program serves every failure-set size.
+        n = self.REPLICA_COPY_ROWS
+        for lo in range(0, len(rs), n):
+            rs_p = np.full((n,), self.plan.num_replicas, np.int32)
+            os_p = np.zeros((n,), np.int32)
+            rs_p[:len(rs[lo:lo + n])] = rs[lo:lo + n]
+            os_p[:len(os_[lo:lo + n])] = os_[lo:lo + n]
             patched = patched._replace(replicas=self._replica_copy_fn()(
                 patched.replicas, patched.logs,
                 jnp.asarray(rs_p), jnp.asarray(os_p)))
@@ -2941,13 +2964,12 @@ class ClusterRunner:
                 jnp.asarray(0, jnp.int32), zero((compiled.max_epochs,)),
                 zero((compiled.max_epochs,), jnp.bool_),
                 jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32))
-            nr = compiled.plan.num_replicas
-            # Donated arg: hand the prewarm a disposable dummy, never the
-            # live carry (donation deletes the input buffers).
-            self._replica_copy_fn()(
-                jax.tree_util.tree_map(lambda x: jnp.zeros_like(x),
-                                       carry.replicas),
-                carry.logs, jnp.full((nr,), nr, jnp.int32), zero((nr,)))
+            # Donated arg: compiled against the live carry, not run (see
+            # the whole-carry programs below).
+            n = self.REPLICA_COPY_ROWS
+            self._replica_copy_fn().lower(
+                carry.replicas, carry.logs, zero((n,)), zero((n,))
+            ).compile()
         if carry.out_rings:
             self._ring_bounds()
         # Shared log-restore programs.

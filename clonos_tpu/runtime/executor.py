@@ -52,7 +52,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from clonos_tpu.api.operators import (BlockContext, HostFeedSource, OpContext,
+from clonos_tpu.api.operators import (BlockContext, EventTimeWindow,
+                                      HostFeedSource, OpContext,
                                       TwoInputOperator)
 from clonos_tpu.api.records import RecordBatch, empty, zero_invalid
 from clonos_tpu.causal import log as clog
@@ -172,6 +173,11 @@ class CompiledJob:
         self.ring_vertices = [v.vertex_id for v in self.job.vertices
                               if self.job.out_edges(v.vertex_id)]
         self.ring_index = {vid: i for i, vid in enumerate(self.ring_vertices)}
+        #: vertices whose state counts late-dropped records and fired
+        #: rows (the fence's health read carries both totals)
+        self.event_window_vertices = [
+            v.vertex_id for v in self.job.vertices
+            if isinstance(v.operator, EventTimeWindow)]
         #: HASH edges whose producer emits statically-keyed slots get a
         #: compile-time gather plan instead of the sort exchange.
         self.static_route: Dict[int, routing.StaticRoutePlan] = {}
@@ -876,9 +882,16 @@ class LocalExecutor:
         # buffer, which the donated block program rejects ("donate the
         # same buffer twice"). An eager copy per leaf guarantees distinct
         # buffers once; later programs keep them distinct (outputs alias
-        # donated inputs one-to-one).
-        self.carry = jax.tree_util.tree_map(
-            lambda x: jnp.asarray(x).copy(), self.carry)
+        # donated inputs one-to-one). Leaf by leaf, each original dropped
+        # as its copy replaces it: copying the tree in one expression
+        # holds two carries at once, which a carry past half the chip's
+        # memory cannot afford.
+        leaves, treedef = jax.tree_util.tree_flatten(self.carry)
+        self.carry = None
+        for i, leaf in enumerate(leaves):
+            leaves[i] = jnp.asarray(leaf).copy()
+        del leaf
+        self.carry = jax.tree_util.tree_unflatten(treedef, leaves)
         # Epoch 0 starts at log offset 0 for every log.
         self.carry = self._jit_roll(self.carry, 0)
         self.step_input_history: List[Tuple[int, int]] = []
@@ -1335,9 +1348,14 @@ class LocalExecutor:
         # Trailing: total record count, then the per-task log heads — at
         # an epoch fence these ARE the checkpoint's log heads, so the
         # control plane learns them inside the one read it already pays
-        # (recovery's patch phase then needs no head round-trip).
+        # (recovery's patch phase then needs no head round-trip) — then
+        # (late, fired) of every event-time window vertex.
+        windows = [carry.op_states[vid][k].sum()
+                   for vid in self.compiled.event_window_vertices
+                   for k in ("late", "fired")]
         return jnp.concatenate(
-            [vec, carry.record_counts.sum()[None], carry.logs.head])
+            [vec, carry.record_counts.sum()[None], carry.logs.head]
+            + ([jnp.stack(windows)] if windows else []))
 
     def health_vector(self) -> np.ndarray:
         if not hasattr(self, "_jit_health"):

@@ -109,6 +109,25 @@ def test_session_window_gap_merging_and_late():
     assert int(s2["late"][0]) == 1
 
 
+def _assert_block_equals_scan(op, batches, state=None):
+    K, P, _ = batches.keys.shape
+    bctx = BlockContext(
+        times=jnp.arange(K, dtype=jnp.int32),
+        rng_bits=jnp.zeros((K,), jnp.int32),
+        epoch=jnp.zeros((), jnp.int32), step0=jnp.zeros((), jnp.int32),
+        subtask=jnp.arange(P, dtype=jnp.int32))
+    state = op.init_state(P) if state is None else state
+    ref = jax.jit(lambda s, b, c: Operator.process_block(op, s, b, c))(
+        state, batches, bctx)
+    blk = jax.jit(op.process_block)(state, batches, bctx)
+    assert (jax.tree_util.tree_structure(ref)
+            == jax.tree_util.tree_structure(blk))
+    for xa, xb in zip(jax.tree_util.tree_leaves(ref),
+                      jax.tree_util.tree_leaves(blk)):
+        np.testing.assert_array_equal(np.asarray(xa), np.asarray(xb))
+    return blk
+
+
 @pytest.mark.parametrize("op", [
     EventTimeTumblingWindowOperator(num_keys=5, window_size=8,
                                     out_of_orderness=6),
@@ -127,18 +146,89 @@ def test_event_windows_block_equals_scan(op):
     batches = zero_invalid(RecordBatch(
         jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(base),
         jnp.asarray(valid)))
-    bctx = BlockContext(
-        times=jnp.arange(K, dtype=jnp.int32),
-        rng_bits=jnp.zeros((K,), jnp.int32),
-        epoch=jnp.zeros((), jnp.int32), step0=jnp.zeros((), jnp.int32),
-        subtask=jnp.arange(P, dtype=jnp.int32))
-    state = op.init_state(P)
-    ref = jax.jit(lambda s, b, c: Operator.process_block(op, s, b, c))(
-        state, batches, bctx)
-    blk = jax.jit(op.process_block)(state, batches, bctx)
-    for xa, xb in zip(jax.tree_util.tree_leaves(ref),
-                      jax.tree_util.tree_leaves(blk)):
-        np.testing.assert_array_equal(np.asarray(xa), np.asarray(xb))
+    _assert_block_equals_scan(op, batches)
+
+
+def _disordered(seed, K, P, B, nk, tick, lag, jump_at=None, empty=()):
+    """A clock that advances ``tick`` a step, each record behind it by up
+    to ``lag``; ``jump_at`` skips the clock far ahead at that step;
+    ``empty`` steps carry no valid record."""
+    rng = np.random.RandomState(seed)
+    clock = tick * np.arange(K)
+    if jump_at is not None:
+        clock[jump_at:] += 40 * tick
+    ts = clock[:, None, None] - rng.randint(0, lag + 1, (K, P, B))
+    valid = rng.rand(K, P, B) < 0.85
+    valid[list(empty)] = False
+    return zero_invalid(RecordBatch(
+        jnp.asarray(rng.randint(0, nk, (K, P, B)), jnp.int32),
+        jnp.asarray(rng.randint(-3, 1 << 18, (K, P, B)), jnp.int32),
+        jnp.asarray(ts, jnp.int32), jnp.asarray(valid)))
+
+
+EVENT_WINDOWS = {
+    "tumbling": lambda ooo: EventTimeTumblingWindowOperator(
+        num_keys=7, window_size=20, out_of_orderness=ooo),
+    "tumbling-many-open": lambda ooo: EventTimeTumblingWindowOperator(
+        num_keys=7, window_size=4, out_of_orderness=ooo),
+    "sliding": lambda ooo: SlidingEventTimeWindowOperator(
+        num_keys=7, window_size=15, slide=5, out_of_orderness=ooo),
+    "sliding-wide-table": lambda ooo: SlidingEventTimeWindowOperator(
+        num_keys=7, window_size=15, slide=5, out_of_orderness=ooo,
+        open_windows=9),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(EVENT_WINDOWS))
+@pytest.mark.parametrize("case", ["in-bound", "late", "jump", "gaps"])
+def test_event_window_block_form_is_the_scan(kind, case):
+    """The vectorised ``process_block`` against ``lax.scan`` over
+    ``process``: state, every output lane, ``late`` and ``fired`` —
+    over blocks that span many windows, records later than the bound
+    (dropped and counted), a clock that jumps (every open window fires
+    at once), steps with no record, and a second block that starts from
+    the first one's state."""
+    ooo, lag, jump, empty = {
+        "in-bound": (12, 12, None, ()),
+        "late": (6, 30, None, ()),
+        "jump": (12, 12, 17, ()),
+        "gaps": (12, 20, 30, (0, 1, 9, 10, 11, 39)),
+    }[case]
+    op = EVENT_WINDOWS[kind](ooo)
+    K, P, B = 40, 3, 16
+    first = _disordered(1, K, P, B, 7, tick=3, lag=lag, jump_at=jump,
+                        empty=empty)
+    state, out = _assert_block_equals_scan(op, first)
+    assert int(jnp.sum(out.valid)) > 0 and int(state["fired"].sum()) > 0
+    if case != "gaps":
+        assert (int(state["late"].sum()) > 0) == (case == "late")
+    second = _disordered(2, K, P, B, 7, tick=3, lag=lag)
+    second = second._replace(timestamps=jnp.where(
+        second.valid, second.timestamps + 3 * K, 0))
+    _assert_block_equals_scan(op, second, state)
+
+
+def test_event_window_table_wider_than_the_kernel_takes(monkeypatch):
+    """Past ``KERNEL_MAX_KEYS`` composite lanes the block form sums slot
+    by slot; same result."""
+    from clonos_tpu.ops import histogram
+    monkeypatch.setattr(histogram, "KERNEL_MAX_KEYS", 8)
+    op = EVENT_WINDOWS["sliding"](6)
+    assert op.open_windows * op.num_keys > 8
+    _assert_block_equals_scan(op, _disordered(4, 30, 2, 12, 7, tick=3,
+                                              lag=25))
+
+
+def test_event_windows_emit_statically_keyed_slots():
+    for op in (EVENT_WINDOWS["tumbling"](5), EVENT_WINDOWS["sliding"](5)):
+        sk = op.static_out_keys()
+        assert sk.shape == (op.out_capacity,)
+        state, out = op.process(op.init_state(1), _step_batch(
+            [(k, 1, 1) for k in range(7)]), _ctx())
+        _, out = op.process(state, _step_batch([(0, 1, 500)]), _ctx())
+        m = np.asarray(out.valid[0])
+        assert m.sum() >= 7
+        np.testing.assert_array_equal(np.asarray(out.keys[0])[m], sk[m])
 
 
 def test_event_time_job_recovers_bit_identically():

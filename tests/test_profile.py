@@ -186,8 +186,14 @@ def test_recover_finalize_subspans_partition_finalize(tmp_path):
     is surfaced as ``finalize.overlap-saved`` — so the identity is
     sum(sub-spans) - overlap-saved == finalize (overlap is attributed,
     never hidden)."""
+    import jax
     from clonos_tpu.runtime.cluster import ClusterRunner
 
+    # Start as a process that has recovered nothing yet: with recovery's
+    # programs already traced by an earlier test of the same worker,
+    # finalize shrinks to ~2 ms and the barrier thread's start and join
+    # (under 1 ms, in no sub-span) decide the identity below.
+    jax.clear_caches()
     tr = obs.configure("runner")
     r = ClusterRunner(_small_job("fin"), steps_per_epoch=8,
                       log_capacity=512, max_epochs=8,

@@ -239,6 +239,42 @@ def test_bench_topology_recovery_per_vertex_class(flat):
     _carries_equal(r.executor.carry, golden.executor.carry)
 
 
+@pytest.mark.parametrize("sinks,calls", [(2, 1), (3, 2)],
+                         ids=["48-replicas", "72-replicas"])
+def test_replica_rebuild_copies_a_bounded_number_of_rows_a_call(sinks, calls):
+    """A sink subtask of the bench topology at parallelism 8 holds 24
+    replica logs. The rebuild copies ``REPLICA_COPY_ROWS`` = 64 of them a
+    call of its one program: a failure set that holds more takes several
+    calls, and every replica row still equals its owner's log."""
+    def drive(r):
+        r.executor.time_source.now = lambda it=iter(TIMES): next(it)
+        r.run_epoch()
+        r.step()
+        r.step()
+        return r
+
+    make = lambda: drive(ClusterRunner(_bench_job(8), steps_per_epoch=3,
+                                       seed=11))
+    golden, r = make(), make()
+    assert ClusterRunner.REPLICA_COPY_ROWS == 64
+    failed = [3 * 8 + s for s in range(sinks)]
+    held = [x for f in failed for x in r.plan.replicas_held_by(f)]
+    assert len(held) == 24 * sinks
+    copy, seen = r._replica_copy_fn(), []
+    r._replica_copy_fn = lambda: lambda replicas, logs, ri, oi: (
+        seen.append(np.asarray(ri)), copy(replicas, logs, ri, oi))[1]
+    r.inject_failure(failed)
+    r.recover()
+    assert len(seen) == calls and all(len(ri) == 64 for ri in seen)
+    rows = np.concatenate(seen)
+    assert sorted(rows[rows < r.plan.num_replicas].tolist()) == sorted(held)
+    _carries_equal(r.executor.carry, golden.executor.carry)
+    heads = np.asarray(r.executor.carry.replicas.head)
+    owners = np.asarray(r.executor.carry.logs.head)[
+        [r.plan.pairs[x][0] for x in held]]
+    assert (heads[held] == owners).all() and owners.min() > 0
+
+
 def test_failure_with_pending_checkpoint_ignores_it():
     r = _runner(TIMES, steps_per_epoch=2)
     r.run_epoch()                      # ckpt 0 completes

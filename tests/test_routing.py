@@ -163,6 +163,44 @@ def test_block_routes_bit_identical_to_per_step(cap, K, P, B, force_sort,
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("cap,K,P,B", [
+    (4, 12, 3, 16), (64, 12, 3, 16), (32, 6, 8, 600),
+])
+def test_chunked_count_route_bit_identical_to_per_step(cap, K, P, B,
+                                                       monkeypatch):
+    """A block longer than the flat sort may route, with a counting
+    scratch over budget, counts chunk after chunk of steps: equal to the
+    per-step exchange, drops included, and no sort is traced."""
+    import jax
+    from clonos_tpu.obs import trace
+    T, G = 4, 8
+    # room for 3 steps' scratch: K = 12 is cut into chunks of 3, K = 6 too
+    monkeypatch.setattr(routing, "_count_route_budget",
+                        lambda: 3 * P * B * (T + 1) * 12)
+    monkeypatch.setattr(routing, "_SORT_ROUTE_MAX_RECORDS", 0)
+    batch = _rand_block(np.random.RandomState(5), K, P, B)
+    tracer = trace.configure("chunked-route-test")
+    try:
+        r2, d2 = routing.route_hash_block(batch, T, G, cap)
+        took = [(r["args"]["route"], r["args"]["steps"])
+                for r in tracer.records() if r["name"] == "exchange.route"]
+    finally:
+        trace.reset()
+    assert took == [("scatter", 3)]
+    r1, d1 = jax.vmap(lambda b: routing.route_hash(b, T, G, cap))(batch)
+    for a, b in zip(jax.tree_util.tree_leaves((r1, d1)),
+                    jax.tree_util.tree_leaves((r2, d2))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_step_chunk_is_a_divisor_within_the_limit():
+    assert routing._step_chunk(8192, 3034) == 2048
+    assert routing._step_chunk(1024, 277) == 256
+    assert routing._step_chunk(12, 5) == 4
+    assert routing._step_chunk(7, 3) == 1
+    assert routing._step_chunk(7, 0) == 0
+
+
 def test_static_route_plan_matches_dynamic_multiset():
     """StaticRoutePlan routes the same per-(step,target) record multiset
     as the dynamic hash exchange (layout differs: static slots keep holes
