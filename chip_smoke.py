@@ -441,14 +441,18 @@ def print_routes(tracer, since: int, part: str) -> int:
     recs = tracer.records()
     counts = {}
     for r in recs[since:]:
-        if r["name"] == "exchange.route":
-            a = r["args"]
-            key = (a["route"], a["steps"], a["records"], a["targets"],
-                   a["capacity"])
-            counts[key] = counts.get(key, 0) + 1
-    for (route, k, n, t, cap), c in sorted(counts.items()):
-        say(f"{part} exchange K={k} n={n} T={t} cap={cap}: {route}"
-            f" (traced {c}x)")
+        if r["name"] != "exchange.route":
+            continue
+        a = r["args"]
+        if "edge" in a:        # planned, once per HASH edge and job built
+            line = (f"edge {a['edge']}: {a['route']}, {a['width']} wide, "
+                    f"{a['pairs_kept']} of {a['pairs_total']} pairs kept")
+        else:                  # a dynamic exchange, as it was lowered
+            line = (f"K={a['steps']} n={a['records']} T={a['targets']} "
+                    f"cap={a['capacity']}: {a['route']}")
+        counts[line] = counts.get(line, 0) + 1
+    for line, c in sorted(counts.items()):
+        say(f"{part} exchange {line} (traced {c}x)")
     return len(recs)
 
 

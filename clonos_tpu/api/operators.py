@@ -91,6 +91,16 @@ class Operator:
     #: to checkpoint-only freshness on the read path.
     emits_running_value: bool = False
 
+    #: Own-keys contract, first fact (what the planner may assume of an
+    #: operator; ``CompiledJob._plan_edges`` defines "own keys" and is
+    #: its only reader): True iff every VALID record a subtask emits
+    #: from ``process_block`` carries the key of a valid record that
+    #: subtask received, unchanged — for EVERY input. False where that
+    #: cannot be shown (a map's function may rewrite keys; a source
+    #: receives nothing); the vertex's out-edges are then planned from
+    #: "any key anywhere".
+    emits_received_keys: bool = False
+
     def init_state(self, parallelism: int) -> Any:
         return ()
 
@@ -123,15 +133,27 @@ class Operator:
         (routing.StaticRoutePlan) — no sort, no scatter."""
         return None
 
+    def static_clamp_keys(self) -> Optional[np.ndarray]:
+        """Own-keys contract, second fact, for emitters that have
+        :meth:`static_out_keys`: the keys whose columns a key outside
+        the table is folded into, such that a subtask emits a valid
+        record in slot ``i`` only if it received key
+        ``static_out_keys()[i]`` or that key is listed here — for EVERY
+        input (``process_block`` is what runs). Empty where out-of-range
+        keys are dropped; None (the default) declares nothing, and every
+        slot counts as live on every subtask."""
+        return None
+
     def rescale_keyed_state(self, state: Any, new_parallelism: int,
                             num_key_groups: int) -> Any:
         """Remap checkpointed state to a DIFFERENT parallelism by key
         ownership (reference StateAssignmentOperation +
         KeyGroupRangeAssignment: state is split/merged along key-group
         ranges). Dense-table operators implement it as sum-then-remask:
-        per-key rows are disjoint across old subtasks (each only ever saw
-        its own keys), so the global table is the subtask sum and each
-        new subtask keeps the keys the new assignment routes to it.
+        per-key rows are disjoint across old subtasks (each holds only
+        its own keys: ``CompiledJob._plan_edges``), so the global table
+        is the subtask sum and each new subtask keeps the keys the new
+        assignment routes to it.
         Operators without a keyed rescaling story raise."""
         raise NotImplementedError(
             f"{type(self).__name__} does not support rescaling")
@@ -141,8 +163,10 @@ def rescale_dense_table(table: jnp.ndarray, new_parallelism: int,
                         num_key_groups: int,
                         fill: int = 0) -> jnp.ndarray:
     """Remap a dense keyed table ``[P, ..., K]`` to ``new_parallelism``:
-    sum over the old subtask axis (rows are disjoint by key ownership)
-    and keep, per new subtask, only the keys the new key-group
+    sum over the old subtask axis (rows are disjoint by key ownership,
+    "own keys" as ``CompiledJob._plan_edges`` defines it; a column that
+    out-of-range keys were clamped into sums over the subtasks that
+    received them) and keep, per new subtask, only the keys the new key-group
     assignment routes to it (``fill`` elsewhere — the operator's init
     value, what an untouched key holds)."""
     from clonos_tpu.parallel.routing import (key_group,
@@ -215,6 +239,9 @@ class FilterOperator(Operator):
     compaction happens at the next exchange (StreamFilter equivalent)."""
 
     pred: Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray]
+
+    # a mask update: what stays valid keeps its key
+    emits_received_keys = True
 
     def process(self, state, batch, ctx):
         keep = batch.valid & self.pred(batch.keys, batch.values, batch.timestamps)
@@ -292,9 +319,10 @@ class KeyedReduceOperator(Operator):
     """Running keyed reduce over a dense key table (keyed-state analog of the
     reference's HeapKeyedStateBackend ValueState + ReduceFunction).
 
-    State is ``acc[P, num_keys]``; each subtask only ever sees keys routed to
-    it by the upstream HASH exchange, so tables never conflict. Emits the
-    updated running value for every input record (Flink reduce semantics).
+    State is ``acc[P, num_keys]``; behind a HASH exchange each subtask holds
+    only its own keys (``CompiledJob._plan_edges`` defines the property and
+    plans from it), so tables never conflict. Emits the updated running
+    value for every input record (Flink reduce semantics).
     """
 
     num_keys: int
@@ -303,6 +331,10 @@ class KeyedReduceOperator(Operator):
     # out_vals = new_acc[b.keys] for valid records below — the running
     # value — so read replicas can tail this operator's output rings.
     emits_running_value = True
+    # The output is the input batch with its values replaced: keys, stamps
+    # and validity pass through (a key past ``num_keys`` too; only its sum
+    # is not kept).
+    emits_received_keys = True
 
     def init_state(self, parallelism: int):
         return {"acc": jnp.full((parallelism, self.num_keys), self.init_value,
@@ -505,6 +537,11 @@ class TumblingWindowCountOperator(Operator):
         # Dense table emission: slot i always carries key i.
         return np.arange(self.num_keys, dtype=np.int32)
 
+    def static_clamp_keys(self) -> Optional[np.ndarray]:
+        # process_block sums through keyed_hist, which drops a key
+        # outside [0, num_keys): column i holds key i's records alone.
+        return np.zeros((0,), np.int32)
+
 
 #: free-slot sentinel for open-window tables; far below any reachable
 #: window id (ids are event_ts // size), and safe in guarded arithmetic.
@@ -587,6 +624,12 @@ class EventTimeWindow(Operator):
         # Dense table emission: slot (w, i) always carries key i.
         return np.tile(np.arange(self.num_keys, dtype=np.int32),
                        self.open_windows)
+
+    def static_clamp_keys(self) -> Optional[np.ndarray]:
+        # ``jnp.clip(keys, 0, nk - 1)``: a negative key is summed under
+        # key 0 and a key at or past num_keys under the last key, by
+        # the subtask that received it.
+        return np.asarray([0, self.num_keys - 1], np.int32)
 
     def init_state(self, parallelism: int):
         w = self.open_windows
@@ -857,6 +900,9 @@ class UnionOperator(TwoInputOperator):
 
     capacity: int
 
+    # a compaction of both inputs' records, as they came
+    emits_received_keys = True
+
     @property
     def out_capacity(self):  # type: ignore[override]
         return self.capacity
@@ -1095,6 +1141,8 @@ class OperatorStateCountOperator(Operator):
     the records it has seen and hands them on unchanged (the shape of a
     mapper that keeps per-subtask list state; reference
     OperatorStateBackend ListState under a RichMapFunction)."""
+
+    emits_received_keys = True
 
     def init_state(self, parallelism: int):
         return {"seen": jnp.zeros((parallelism,), jnp.int32)}

@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -121,9 +121,12 @@ def _count_route_budget() -> int:
                min(2 << 30, dev.memory_stats()["bytes_limit"] // 48))
 
 
-def _note_route(route: str, **shape) -> None:
-    """Trace-time record of which form an exchange was lowered to
-    (``exchange.route`` instant; chip_smoke.py prints them)."""
+def note_route(route: str, **shape) -> None:
+    """Record of which form an exchange took (``exchange.route``
+    instant; chip_smoke.py prints them): ``kernel`` / ``scatter`` /
+    ``sort`` when a dynamic exchange is lowered (trace time), and
+    ``identity`` / ``static`` once per HASH edge the planner took off
+    the dynamic exchange (``CompiledJob._plan_edges``, plan time)."""
     get_tracer().event("exchange.route", route=route, **shape)
 
 
@@ -176,8 +179,8 @@ def _count_to_targets(
     # kernel compiles for are placed by an element scatter.
     nk = T * out_capacity
     via_hist = nk <= KERNEL_MAX_KEYS
-    _note_route("kernel" if via_hist and uses_kernel() else "scatter",
-                steps=K, records=n, targets=T, capacity=out_capacity)
+    note_route("kernel" if via_hist and uses_kernel() else "scatter",
+               steps=K, records=n, targets=T, capacity=out_capacity)
     if via_hist:
         slot = jnp.where(keep, tgt * out_capacity + pos, -1)
         out_k, cnt = keyed_hist(slot, keys, keep, nk)
@@ -246,8 +249,8 @@ def _block_to_targets(
         return RecordBatch(*map(join, routed)), join(dropped)
     # Flat sort (scratch over budget): one composite-key sort over the
     # block.
-    _note_route("sort", steps=K, records=n, targets=T,
-                capacity=out_capacity)
+    note_route("sort", steps=K, records=n, targets=T,
+               capacity=out_capacity)
     if K * (T + 1) >= (1 << 31):
         raise ValueError(f"composite sort key overflow: K={K} T={T}")
     flat = lambda x: jnp.reshape(x, (K * n,))
@@ -287,8 +290,8 @@ def _block_to_target_lane(batch: RecordBatch, target: jnp.ndarray,
     window in one piece, where the full route goes chunk by chunk."""
     K, P, B = batch.keys.shape
     n = P * B
-    _note_route("kernel" if uses_kernel() else "scatter", steps=K,
-                records=n, targets=1, capacity=out_capacity)
+    note_route("kernel" if uses_kernel() else "scatter", steps=K,
+               records=n, targets=1, capacity=out_capacity)
     fl = lambda x: jnp.reshape(x, (K, n))
     keys, vals, ts, valid = map(fl, batch)
     tgt = jnp.where(valid, fl(target), -1)
@@ -448,14 +451,25 @@ class StaticRoutePlan:
     drop_slot: np.ndarray  # int32 [D]
     drop_t: np.ndarray     # int32 [D]: target the overflow belonged to
 
+    @property
+    def width(self) -> int:
+        """Mapped out slots of the fullest target (a target's mapped
+        slots are its first ones)."""
+        return int(self.ok.sum(axis=1).max(initial=0))
+
     def apply(self, out: RecordBatch) -> Tuple[RecordBatch, jnp.ndarray]:
         """Route a producer block ``[K, P, B]`` -> ``[K, T, cap]``."""
         K = out.keys.shape[0]
-        T = self.src_p.shape[0]
-        g = lambda x: x[:, self.src_p, self.src_slot]
-        valid = g(out.valid) & self.ok[None]
+        T, cap = self.src_p.shape
+        # Gather the mapped columns only (a gather costs per index); the
+        # rest of the receive window is never written.
+        w = self.width
+        g = lambda x: x[:, self.src_p[:, :w], self.src_slot[:, :w]]
+        valid = g(out.valid) & self.ok[None, :, :w]
         routed = zero_invalid(RecordBatch(
             g(out.keys), g(out.values), g(out.timestamps), valid))
+        routed = RecordBatch(*(jnp.pad(
+            x, ((0, 0), (0, 0), (0, cap - w))) for x in routed))
         if len(self.drop_p):
             dv = out.valid[:, self.drop_p, self.drop_slot]  # [K, D]
             dropped = jnp.zeros((K, T), jnp.int32).at[
@@ -473,51 +487,65 @@ def _static_targets(slot_keys: np.ndarray, parallelism: int,
     return (kg * parallelism) // num_key_groups
 
 
+def own_slots(slot_keys: np.ndarray, parallelism: int,
+              num_key_groups: int, clamp_keys=()) -> np.ndarray:
+    """``live[p, slot]`` of a dense-table emitter whose every subtask
+    has received only its own keys (``CompiledJob._plan_edges`` says
+    when): slot ``i`` can hold a record on subtask ``p`` only if ``p``
+    owns key ``slot_keys[i]``, or the key is one of ``clamp_keys``, the
+    columns the operator folds out-of-range keys into — on whichever
+    subtask received them, so those columns are live everywhere."""
+    slot_keys = np.asarray(slot_keys, np.int64)
+    own = (_static_targets(slot_keys, parallelism, num_key_groups)[None, :]
+           == np.arange(parallelism)[:, None])
+    return own | np.isin(slot_keys, np.asarray(clamp_keys, np.int64))[None, :]
+
+
 def static_hash_capacity(slot_keys: np.ndarray, src_parallelism: int,
-                         parallelism: int, num_key_groups: int) -> int:
+                         parallelism: int, num_key_groups: int,
+                         live: Optional[np.ndarray] = None) -> int:
     """Smallest per-target receive capacity for which
-    :func:`plan_static_hash` has no overflow (drop) slots: the densest
-    target's key count times the producer parallelism."""
+    :func:`plan_static_hash` has no overflow (drop) slots: the most
+    live (producer, slot) pairs any one target is sent — without
+    ``live`` (bool ``[src_parallelism, slots]``, :func:`own_slots`)
+    the densest target's key count times the producer parallelism."""
     slot_keys = np.asarray(slot_keys, np.int64)
     tgt = _static_targets(slot_keys, parallelism, num_key_groups)
-    return int(np.bincount(tgt, minlength=parallelism).max()) \
-        * src_parallelism
+    pairs = (np.full(slot_keys.shape, src_parallelism) if live is None
+             else np.asarray(live, bool).sum(axis=0))
+    return int(np.bincount(tgt, weights=pairs, minlength=parallelism).max())
 
 
 def plan_static_hash(slot_keys: np.ndarray, src_parallelism: int,
                      parallelism: int, num_key_groups: int,
-                     out_capacity: int) -> StaticRoutePlan:
+                     out_capacity: int,
+                     live: Optional[np.ndarray] = None) -> StaticRoutePlan:
     """Build a :class:`StaticRoutePlan` for a HASH edge whose producer
-    emits key ``slot_keys[i]`` in slot ``i`` on every subtask."""
+    emits key ``slot_keys[i]`` in slot ``i`` on every subtask. With
+    ``live`` (:func:`own_slots`) only the (producer, slot) pairs it
+    marks get a slot: the others never hold a record."""
     slot_keys = np.asarray(slot_keys, np.int64)
-    B = slot_keys.shape[0]
+    if live is None:
+        live = np.ones((src_parallelism, slot_keys.shape[0]), bool)
     tgt = _static_targets(slot_keys, parallelism, num_key_groups)
     T, cap = parallelism, out_capacity
     src_p = np.zeros((T, cap), np.int32)
     src_slot = np.zeros((T, cap), np.int32)
     ok = np.zeros((T, cap), bool)
     keys_out = np.full((T, cap), -1, np.int32)
-    drop_p, drop_slot, drop_t = [], [], []
+    drops = []
     for t in range(T):
-        slots = np.nonzero(tgt == t)[0]
-        c = 0
-        for p in range(src_parallelism):      # p-major = arrival order
-            for s in slots:
-                if c < cap:
-                    src_p[t, c] = p
-                    src_slot[t, c] = s
-                    ok[t, c] = True
-                    keys_out[t, c] = slot_keys[s]
-                    c += 1
-                else:
-                    drop_p.append(p)
-                    drop_slot.append(s)
-                    drop_t.append(t)
+        # p-major, slot ascending = the dynamic exchange's arrival order
+        ps, ss = np.nonzero(live & (tgt == t)[None, :])
+        n = min(len(ps), cap)
+        src_p[t, :n], src_slot[t, :n] = ps[:n], ss[:n]
+        ok[t, :n] = True
+        keys_out[t, :n] = slot_keys[ss[:n]]
+        drops.append(np.stack([ps[n:], ss[n:], np.full(len(ps) - n, t)]))
+    drop_p, drop_slot, drop_t = np.concatenate(drops, axis=1).astype(np.int32)
     return StaticRoutePlan(
         src_p=src_p, src_slot=src_slot, ok=ok, slot_keys=keys_out,
-        drop_p=np.asarray(drop_p, np.int32),
-        drop_slot=np.asarray(drop_slot, np.int32),
-        drop_t=np.asarray(drop_t, np.int32))
+        drop_p=drop_p, drop_slot=drop_slot, drop_t=drop_t)
 
 
 def route_rebalance(batch: RecordBatch, parallelism: int, out_capacity: int,

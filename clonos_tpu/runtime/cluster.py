@@ -815,17 +815,14 @@ class ClusterRunner:
         steps past the replay range invalid (the replay-padding
         contract); ``lead`` masks the leading dead slot of window 0."""
         def make():
+            body = self._route_body(eidx, m)
             if all_lanes:
-                body = self._route_body(eidx, m)
-
                 def f(el, start, rr0, need_left, lead):
                     raw = ifl.slice_steps_at(el, start, m)
-                    routed, cnt = body(raw, rr0, need_left, lead)
+                    routed, cnt = body(raw, None, rr0, need_left, lead)
                     return (routed, start + m, rr0 + cnt, need_left - m,
                             jnp.zeros_like(lead))
             else:
-                body = self._route_body_lane(eidx, m)
-
                 def f(el, start, sub, rr0, need_left, lead):
                     raw = ifl.slice_steps_at(el, start, m)
                     lane, cnt = body(raw, sub, rr0, need_left, lead)
@@ -842,42 +839,14 @@ class ClusterRunner:
 
     def _route_body(self, eidx: int, m: int):
         """The shared exchange-replay body: mask the ``lead`` leading
-        slots and steps past ``need_left`` invalid, then route to all
-        destination lanes."""
-        e = self.job.edges[eidx]
-        dst_p = self.job.vertices[e.dst].parallelism
-        compiled = self.executor.compiled
-
-        def body(raw, rr0, need_left, lead):
-            need = jnp.clip(need_left, 0, m)
-            idx = jnp.arange(m, dtype=jnp.int32)
-            live = (idx >= lead) & (idx < need)
-            raw = raw._replace(valid=raw.valid & live[:, None, None])
-            if eidx in compiled.static_route:
-                r, _ = compiled.static_route[eidx].apply(raw)
-            elif e.partition == PartitionType.HASH:
-                r, _ = routing.route_hash_block(
-                    raw, dst_p, self.job.num_key_groups, e.capacity)
-            elif e.partition == PartitionType.FORWARD:
-                r, _ = routing.route_forward_block(raw, e.capacity)
-            elif e.partition == PartitionType.REBALANCE:
-                counts = raw.count().sum(axis=1)
-                offs = rr0 + jnp.cumsum(counts) - counts
-                r, _ = routing.route_rebalance_block(
-                    raw, dst_p, e.capacity, offs)
-            else:
-                r, _ = routing.route_broadcast_block(raw, dst_p, e.capacity)
-            return r, raw.count().sum()
-        return _scoped("exchange", body)
-
-    def _route_body_lane(self, eidx: int, m: int):
-        """Single-consumer-lane exchange replay: compute the routed lane
-        ``sub`` DIRECTLY (routing._block_to_target_lane — a [m, n]
-        running count instead of the [m, n, T+1] one-hot), bit-identical
-        to the full route's lane. Counts a whole window of m steps in
-        one piece, where the full exchange goes chunk by chunk."""
-        e = self.job.edges[eidx]
-        dst_p = self.job.vertices[e.dst].parallelism
+        slots and steps past ``need_left`` invalid, then take the
+        edge's route (``CompiledJob.route_edge``, the block program's
+        own) — to all destination lanes (``sub`` None), or to the
+        single consumer lane ``sub`` DIRECTLY, bit-identical to the full
+        route's lane: a dynamic exchange then counts a [m, n] membership
+        mask (routing._block_to_target_lane) instead of the [m, n, T+1]
+        one-hot, a whole window of m steps in one piece where the full
+        exchange goes chunk by chunk."""
         compiled = self.executor.compiled
 
         def body(raw, sub, rr0, need_left, lead):
@@ -885,24 +854,8 @@ class ClusterRunner:
             idx = jnp.arange(m, dtype=jnp.int32)
             live = (idx >= lead) & (idx < need)
             raw = raw._replace(valid=raw.valid & live[:, None, None])
-            if eidx in compiled.static_route:
-                r, _ = compiled.static_route[eidx].apply(raw)
-                lane = jax.tree_util.tree_map(lambda x: x[:, sub], r)
-            elif e.partition == PartitionType.HASH:
-                lane = routing.route_hash_block_lane(
-                    raw, sub, dst_p, self.job.num_key_groups, e.capacity)
-            elif e.partition == PartitionType.FORWARD:
-                lane = routing.route_forward_block_lane(
-                    raw, sub, e.capacity)
-            elif e.partition == PartitionType.REBALANCE:
-                counts = raw.count().sum(axis=1)
-                offs = rr0 + jnp.cumsum(counts) - counts
-                lane = routing.route_rebalance_block_lane(
-                    raw, sub, dst_p, e.capacity, offs)
-            else:
-                lane = routing.route_broadcast_block_lane(
-                    raw, sub, e.capacity)
-            return lane, raw.count().sum()
+            r, _ = compiled.route_edge(eidx, raw, rr0, lane=sub)
+            return r, raw.count().sum()
         return _scoped("exchange", body)
 
     def _route_raw_fn(self, eidx: int, m: int, all_lanes: bool = False):
@@ -910,16 +863,13 @@ class ClusterRunner:
         host-assembled raw chunk instead of reading the device ring,
         advancing the same device-carried loop state."""
         def make():
+            body = self._route_body(eidx, m)
             if all_lanes:
-                body = self._route_body(eidx, m)
-
                 def f(raw, start, rr0, need_left, lead):
-                    routed, cnt = body(raw, rr0, need_left, lead)
+                    routed, cnt = body(raw, None, rr0, need_left, lead)
                     return (routed, start + m, rr0 + cnt, need_left - m,
                             jnp.zeros_like(lead))
             else:
-                body = self._route_body_lane(eidx, m)
-
                 def f(raw, start, sub, rr0, need_left, lead):
                     lane, cnt = body(raw, sub, rr0, need_left, lead)
                     return (lane, start + m, rr0 + cnt, need_left - m,

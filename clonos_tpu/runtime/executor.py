@@ -143,6 +143,16 @@ class BlockOutputs(NamedTuple):
     consumed: jnp.ndarray               # int32[K, L]
 
 
+class EdgePlan(NamedTuple):
+    """What ``CompiledJob._plan_edges`` decided for one HASH edge."""
+
+    route: str          # "identity" | "static" | "dynamic"
+    width: int          # receive capacity per target subtask
+    pairs_kept: int     # (producer, slot) pairs the plan holds a slot
+    pairs_total: int    # for, of those "any key anywhere" would need;
+                        # identity/dynamic: (producer, target) lanes
+
+
 @dataclasses.dataclass
 class CompiledJob:
     """A job graph lowered to (init_carry, run_block) pure functions."""
@@ -178,68 +188,131 @@ class CompiledJob:
         self.event_window_vertices = [
             v.vertex_id for v in self.job.vertices
             if isinstance(v.operator, EventTimeWindow)]
+        self._plan_edges()
+
+    def _plan_edges(self) -> None:
+        """Decide every HASH edge's route, and the widths that follow.
+
+        **Own keys** (defined here, and nowhere else): a vertex *holds
+        own keys* when every valid record subtask ``p`` receives has a
+        key that ``routing._static_targets`` sends to ``p`` (at the
+        vertex's parallelism and the job's ``num_key_groups``). That is
+        so when each input edge is a HASH edge — however it is routed —
+        or a FORWARD edge from a vertex that *emits* own keys: one that
+        holds them and declares ``Operator.emits_received_keys``. A
+        dense-table emitter (``static_out_keys``) that holds own keys
+        and declares ``static_clamp_keys`` has ``routing.own_slots`` for
+        its live (subtask, slot) pairs, and emits own keys where no
+        pair lies outside its owner. The planner reads those two
+        declarations and the graph; never an operator's class, never a
+        job's name. Two consequences, one per kind of producer:
+
+        - a static gather plan (``routing.StaticRoutePlan``) holds a
+          slot only for the live pairs: one producer a key, plus the
+          clamp columns from every producer;
+        - a HASH edge between equal parallelisms whose producer emits
+          own keys moves nothing — every record is on its target — and
+          is routed in place (``identity``: ``route_forward_block``).
+          Such a record stays in its slot, so the edge is as wide as
+          its producer at least.
+
+        ``edge_plans`` keeps the decision per HASH edge (``route`` is
+        ``identity``, ``static`` or ``dynamic``) and :meth:`route_edge`
+        acts on it; each planned route is noted once as an
+        ``exchange.route`` instant."""
+        job = self.job
+        G = job.num_key_groups
         #: HASH edges whose producer emits statically-keyed slots get a
         #: compile-time gather plan instead of the sort exchange.
         self.static_route: Dict[int, routing.StaticRoutePlan] = {}
-        for eidx, e in enumerate(self.job.edges):
-            if e.partition != PartitionType.HASH:
-                continue
-            sk = self.job.vertices[e.src].operator.static_out_keys()
-            if sk is None:
-                continue
-            src_p = self.job.vertices[e.src].parallelism
-            dst_p = self.job.vertices[e.dst].parallelism
-            # The static plan reserves a slot for EVERY (producer, key)
-            # pair, so a hash-skewed target can need more than the
-            # requested receive window even though the dynamic exchange
-            # never drops (it only sees per-step live arrivals). The
-            # edge capacity is a lower-bound request — widen it to fit
-            # the densest target (rounded to the 128 TPU lane width):
-            # total extra memory is bounded by the hash imbalance times
-            # the producer's own output width, and it buys the gather
-            # plan (~50x cheaper than the sort exchange at bench shapes).
-            need = routing.static_hash_capacity(
-                sk, src_p, dst_p, self.job.num_key_groups)
-            if need > max(4 * e.capacity, 1024):
-                # The static plan would need far more receive memory than
-                # the user asked for (very dense key table or extreme
-                # hash skew into a narrow edge): keep the dynamic
-                # exchange rather than silently multiplying the edge and
-                # downstream buffers.
-                continue
-            if need > e.capacity:
-                e.capacity = -(-need // 128) * 128
-            plan = routing.plan_static_hash(
-                sk, src_p, dst_p, self.job.num_key_groups, e.capacity)
-            if len(plan.drop_p):                       # pragma: no cover
-                raise RuntimeError(
-                    f"static plan for edge {eidx} still has "
-                    f"{len(plan.drop_p)} overflow slots at capacity "
-                    f"{e.capacity} — static_hash_capacity disagrees "
-                    f"with plan_static_hash")
-            self.static_route[eidx] = plan
+        self.edge_plans: Dict[int, EdgePlan] = {}
+        emits_own = set()     # vertices that emit own keys
         # A static route leaves holes (slots are bound to (producer, key)
-        # pairs, not compacted), and operators without a width of their
-        # own pass slots through in place. A FORWARD edge narrower than
-        # such a producer would cut live records off by POSITION, so it
-        # inherits the widening too.
+        # pairs, not compacted), an identity route keeps its producer's,
+        # and operators without a width of their own pass slots through
+        # in place. A FORWARD edge narrower than such a producer would
+        # cut live records off by POSITION, so it inherits the widening.
         sparse = set()
         for vid in self.topo:
-            ins = self.job.in_edges(vid)
-            if len(ins) != 1 or \
-                    self.job.vertices[vid].operator.out_capacity is not None:
-                continue
-            e_in = self.job.edges[ins[0]]
-            if ins[0] not in self.static_route and not (
-                    e_in.partition == PartitionType.FORWARD
-                    and e_in.src in sparse):
-                continue
-            sparse.add(vid)
-            for eidx in self.job.out_edges(vid):
-                e = self.job.edges[eidx]
-                if e.partition == PartitionType.FORWARD:
-                    e.capacity = max(e.capacity,
-                                     self.vertex_out_capacity(vid))
+            v = job.vertices[vid]
+            op, p = v.operator, v.parallelism
+            ins = job.in_edges(vid)
+            holds_own = bool(ins) and all(
+                job.edges[i].partition == PartitionType.HASH
+                or (job.edges[i].partition == PartitionType.FORWARD
+                    and job.edges[i].src in emits_own) for i in ins)
+            if len(ins) == 1 and op.out_capacity is None:
+                e_in = job.edges[ins[0]]
+                planned = self.edge_plans.get(ins[0])
+                if (planned and planned.route != "dynamic") or (
+                        e_in.partition == PartitionType.FORWARD
+                        and e_in.src in sparse):
+                    sparse.add(vid)
+            sk, clamp = op.static_out_keys(), op.static_clamp_keys()
+            live = None
+            if sk is None:
+                if holds_own and op.emits_received_keys:
+                    emits_own.add(vid)
+            elif holds_own and clamp is not None:
+                live = routing.own_slots(sk, p, G, clamp)
+                if live.sum() == len(sk):     # every slot on its owner only
+                    emits_own.add(vid)
+            width = self.vertex_out_capacity(vid)
+            for eidx in job.out_edges(vid):
+                e = job.edges[eidx]
+                if e.partition == PartitionType.FORWARD and vid in sparse:
+                    e.capacity = max(e.capacity, width)
+                if e.partition != PartitionType.HASH:
+                    continue
+                dst_p = job.vertices[e.dst].parallelism
+                plan = None
+                if sk is not None:
+                    plan = self._plan_static(eidx, sk, p, dst_p, live)
+                elif vid in emits_own and dst_p == p:
+                    e.capacity = max(e.capacity, width)
+                    plan = EdgePlan("identity", e.capacity, p, p * p)
+                self.edge_plans[eidx] = plan = plan or EdgePlan(
+                    "dynamic", e.capacity, p * dst_p, p * dst_p)
+                if plan.route != "dynamic":
+                    routing.note_route(
+                        plan.route, edge=eidx, width=plan.width,
+                        pairs_kept=plan.pairs_kept,
+                        pairs_total=plan.pairs_total)
+
+    def _plan_static(self, eidx: int, sk: np.ndarray, src_p: int,
+                     dst_p: int, live: Optional[np.ndarray]
+                     ) -> Optional["EdgePlan"]:
+        """The gather plan of a HASH edge behind a dense-table emitter
+        (None: it would be too wide, the edge stays dynamic)."""
+        e = self.job.edges[eidx]
+        G = self.job.num_key_groups
+        # The plan reserves a slot for every live (producer, slot) pair,
+        # so a hash-skewed target can need more than the requested
+        # receive window even though the dynamic exchange never drops
+        # (it only sees per-step live arrivals). The edge capacity is a
+        # lower-bound request — widen it to fit the fullest target
+        # (rounded to the 128 TPU lane width): it buys the gather plan
+        # (~50x cheaper than the sort exchange at bench shapes).
+        need = routing.static_hash_capacity(sk, src_p, dst_p, G, live)
+        if need > max(4 * e.capacity, 1024):
+            # The static plan would need far more receive memory than
+            # the user asked for (very dense key table or extreme hash
+            # skew into a narrow edge): keep the dynamic exchange rather
+            # than silently multiplying the edge and downstream buffers.
+            return None
+        if need > e.capacity:
+            e.capacity = -(-need // 128) * 128
+        plan = routing.plan_static_hash(sk, src_p, dst_p, G, e.capacity,
+                                        live)
+        if len(plan.drop_p):                           # pragma: no cover
+            raise RuntimeError(
+                f"static plan for edge {eidx} still has "
+                f"{len(plan.drop_p)} overflow slots at capacity "
+                f"{e.capacity} — static_hash_capacity disagrees "
+                f"with plan_static_hash")
+        self.static_route[eidx] = plan
+        return EdgePlan("static", e.capacity, int(plan.ok.sum()),
+                        src_p * len(sk))
 
     def consumer_slot_keys(self, vid: int) -> Optional[np.ndarray]:
         """Static per-slot input keys of vertex ``vid`` ([P, cap], -1 =
@@ -248,6 +321,48 @@ class CompiledJob:
         if len(ins) == 1 and ins[0] in self.static_route:
             return self.static_route[ins[0]].slot_keys
         return None
+
+    def route_edge(self, eidx: int, out: RecordBatch, rr0,
+                   lane=None) -> Tuple[RecordBatch, Optional[jnp.ndarray]]:
+        """THE route of edge ``eidx`` over a producer block ``[K, P, B]``
+        — the block program and both of recovery's re-routes ask here.
+        ``rr0`` is the edge's round-robin cursor at the block's first
+        step (REBALANCE reads it). All consumer lanes by default:
+        (``[K, T, cap]``, dropped ``[K, T]``); with ``lane`` that one
+        consumer's ``[K, cap]`` (bit-identical to the full route's
+        lane) and no drop count."""
+        e = self.job.edges[eidx]
+        T, cap = self.job.vertices[e.dst].parallelism, e.capacity
+        G = self.job.num_key_groups
+        route = (self.edge_plans[eidx].route if eidx in self.edge_plans
+                 else e.partition.value)
+
+        def offsets():                  # [K] exclusive round-robin cursor
+            counts = out.count().sum(axis=1)
+            return rr0 + jnp.cumsum(counts) - counts
+
+        in_place = lambda: routing.route_forward_block(out, cap)
+        in_place_lane = lambda: routing.route_forward_block_lane(
+            out, lane, cap)
+        if lane is None:
+            return {
+                "static": lambda: self.static_route[eidx].apply(out),
+                "identity": in_place, "forward": in_place,
+                "dynamic": lambda: routing.route_hash_block(out, T, G, cap),
+                "rebalance": lambda: routing.route_rebalance_block(
+                    out, T, cap, offsets()),
+                "broadcast": lambda: routing.route_broadcast_block(
+                    out, T, cap)}[route]()
+        return {
+            "static": lambda: jax.tree_util.tree_map(
+                lambda x: x[:, lane], self.static_route[eidx].apply(out)[0]),
+            "identity": in_place_lane, "forward": in_place_lane,
+            "dynamic": lambda: routing.route_hash_block_lane(
+                out, lane, T, G, cap),
+            "rebalance": lambda: routing.route_rebalance_block_lane(
+                out, lane, T, cap, offsets()),
+            "broadcast": lambda: routing.route_broadcast_block_lane(
+                out, lane, cap)}[route](), None
 
     # --- shapes -------------------------------------------------------------
 
@@ -423,27 +538,13 @@ class CompiledJob:
 
             for eidx in job.out_edges(vid):
                 e = job.edges[eidx]
-                dst_p = job.vertices[e.dst].parallelism
                 with jax.named_scope("exchange"):
-                    if eidx in self.static_route:
-                        r, d = self.static_route[eidx].apply(out)
-                    elif e.partition == PartitionType.HASH:
-                        r, d = routing.route_hash_block(
-                            out, dst_p, job.num_key_groups, e.capacity)
-                    elif e.partition == PartitionType.FORWARD:
-                        r, d = routing.route_forward_block(out, e.capacity)
-                    elif e.partition == PartitionType.REBALANCE:
-                        counts = out.count().sum(axis=1)         # [K]
-                        offs = (rr_offsets[eidx][0]
-                                + jnp.cumsum(counts) - counts)   # exclusive
-                        r, d = routing.route_rebalance_block(
-                            out, dst_p, e.capacity, offs)
-                        rr_offsets[eidx] = (
-                            (rr_offsets[eidx] + counts.sum())
-                            % jnp.asarray(dst_p, jnp.int32))
-                    else:
-                        r, d = routing.route_broadcast_block(
-                            out, dst_p, e.capacity)
+                    r, d = self.route_edge(eidx, out, rr_offsets[eidx][0])
+                if e.partition == PartitionType.REBALANCE:
+                    rr_offsets[eidx] = (
+                        (rr_offsets[eidx] + out.count().sum())
+                        % jnp.asarray(job.vertices[e.dst].parallelism,
+                                      jnp.int32))
                 routed[eidx] = self._shard_block(r)
                 dropped[eidx] = d
                 new_edge_bufs[eidx] = jax.tree_util.tree_map(

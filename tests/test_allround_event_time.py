@@ -9,6 +9,7 @@ control."""
 
 import os
 import sys
+import zlib
 
 import numpy as np
 import pytest
@@ -49,11 +50,15 @@ def ref():
     return module_at(job.topology_file(config(), "reference.py"))
 
 
-def run_job(cfg, seed, epochs, tmp_path, kill=None):
+def run_job(cfg, seed, epochs, tmp_path, kill=None, feed_keys=None):
     """``epochs`` completed epochs; ``kill = (vertex, subtask)`` fails
     that subtask half-way, behind two epochs whose checkpoints stay
-    pending. Returns (runner, stream, epoch -> committed row arrays)."""
-    stream = job.make_stream(cfg, {"table_epochs": 2}, seed)
+    pending; the feed draws its keys from ``[0, feed_keys)`` (the job's
+    ``num_keys`` by default). Returns (runner, stream, epoch ->
+    committed row arrays)."""
+    stream = job.make_stream(
+        dict(cfg, num_keys=feed_keys or cfg["num_keys"]),
+        {"table_epochs": 2}, seed)
     runner = job.make_runner(cfg, stream, seed, str(tmp_path / "ck"), 1)
     (txn,) = runner.txn_logs.values()
     got = {}
@@ -71,6 +76,19 @@ def run_job(cfg, seed, epochs, tmp_path, kill=None):
     runner.drain_fence()
     assert runner.executor.check_overflow() == []
     return runner, stream, got
+
+
+def digest(got):
+    """(rows, checksum) of a committed stream, each epoch's rows as a
+    multiset (sorted), the epochs in order."""
+    crc, n = 0, 0
+    for e in sorted(got):
+        rows = np.concatenate(got[e]).astype(np.int32)
+        rows = rows[np.lexsort(rows.T[::-1])]
+        crc = zlib.crc32(np.ascontiguousarray(rows).tobytes(),
+                         zlib.crc32(np.int32(e).tobytes(), crc))
+        n += len(rows)
+    return n, crc
 
 
 def late_of(runner, vid):
@@ -174,29 +192,84 @@ def test_check_counts_missing_duplicated_and_foreign_rows(ref):
     assert sorted(failed) == [1, 2, 3, 9]
 
 
-def test_window_edges_take_static_routes_that_lose_no_row(tmp_path):
-    """Both windows emit statically keyed slots, so the edges behind the
-    tumbling window take the gather plan; the plan reserves a slot for
-    every (producer subtask, slot) pair, so a row reaches its key's
-    owner from whichever subtask fired it: a key at or past ``num_keys``
-    is summed under the last key by the subtask that *received* it, not
-    by that key's owner."""
+def _build(cfg):
+    return module_at(job.topology_file(cfg, "job.py")).build(cfg)
+
+
+def test_planner_routes_each_hash_edge_by_its_producers_own_keys():
+    """Which route ``CompiledJob`` plans for every HASH edge, how wide,
+    and how many (producer, slot) pairs it keeps. Behind the first keyBy
+    every subtask holds own keys, so the second keyBy (operator-state ->
+    tumbling: same key, same parallelism) is routed in place and the
+    three window edges are gather plans over the pairs that can occur:
+    a key's owner, and the two clamp columns (keys 0 and 19) on every
+    subtask. An edge behind a source or a map stays dynamic."""
+    from clonos_tpu.runtime.executor import CompiledJob
+
+    tracer = obs.get_tracer()
+    seen = len(tracer.records())
+    compiled = CompiledJob(_build(config()))
+    names = [v.name for v in compiled.job.vertices]
+    plans = {(names[e.src], names[e.dst]): tuple(compiled.edge_plans[i])
+             for i, e in enumerate(compiled.job.edges)
+             if i in compiled.edge_plans}
+    assert plans == {
+        ("event-time", "keyed-state"): ("dynamic", 32, 16, 16),
+        ("operator-state", "tumbling"): ("identity", 32, 4, 16),
+        # 2 open windows x (20 keys + 2 clamp keys x 3 other subtasks)
+        ("tumbling", "sliding"): ("static", 32, 52, 160),
+        ("tumbling", "union"): ("static", 64, 52, 160),
+        # 7 open windows x the same 26 pairs
+        ("sliding", "union"): ("static", 64, 182, 560)}
+    assert set(compiled.static_route) == {4, 5, 6}
+    assert all(compiled.static_route[i].ok.sum() == plans[k][2]
+               for i, k in ((4, ("tumbling", "sliding")),
+                            (6, ("sliding", "union"))))
+    noted = [r["args"] for r in tracer.records()[seen:]
+             if r["name"] == "exchange.route"]
+    assert [(n["route"], n["edge"], n["width"], n["pairs_kept"],
+             n["pairs_total"]) for n in noted] == [
+        ("identity", 3, 32, 4, 16), ("static", 4, 32, 52, 160),
+        ("static", 5, 64, 52, 160), ("static", 6, 64, 182, 560)]
+
+    older = CompiledJob(_build(dict(
+        config(), topology="source-window-reduce-sink", window_steps=8)))
+    assert [tuple(older.edge_plans[i]) for i in (0, 1)] == [
+        ("dynamic", 32, 16, 16),      # behind a source: any key anywhere
+        ("static", 32, 20, 80)]       # one producer a key, no clamp
+    assert 2 not in older.edge_plans  # reduce -> sink is a FORWARD edge
+
+
+@pytest.mark.parametrize("declared", [True, False],
+                         ids=["clamp-declared", "clamp-left-out"])
+def test_keys_past_num_keys_commit_what_the_parent_commits(
+        tmp_path, monkeypatch, declared):
+    """The feed carries keys 0..29 into a job over 20 keys: the windows
+    sum a key at or past ``num_keys`` under key 19 on the subtask that
+    received it, mostly not key 19's owner. The rows pinned here are
+    what the parent of PR 27 (unpruned plans, a full exchange in front
+    of the tumbling window) commits through a kill of the tumbling
+    window's subtask 1. A window that left its clamp columns out of the
+    contract would have those rows pruned away."""
+    from clonos_tpu.api.operators import EventTimeWindow
+    if not declared:
+        monkeypatch.setattr(EventTimeWindow, "static_clamp_keys",
+                            lambda self: np.zeros((0,), np.int32))
+    runner, stream, got = run_job(config(), 7, 6, tmp_path,
+                                  kill=(TUMBLING, 1), feed_keys=30)
+    past = np.asarray(stream.keys)[np.asarray(stream.keys) >= 20]
+    assert len(set(routing._static_targets(past, 4, 64).tolist())) > 1
+    assert (digest(got) == (1975, 3156005661)) == declared
+    assert late_of(runner, TUMBLING) == late_of(runner, SLIDING) == 0
+
+
+def test_rows_summed_past_num_keys_reach_the_sink():
+    """Keys 0..29 into a window over 20 keys, through the runner: what a
+    non-owner of key 19 sums under it is fired there and reaches the
+    sink (the pruned plan keeps the clamp column from every subtask)."""
     from clonos_tpu.api.environment import StreamEnvironment
     from clonos_tpu.runtime.cluster import ClusterRunner
 
-    cfg = config()
-    stream = job.make_stream(cfg, {"table_epochs": 2}, 1)
-    compiled = job.make_runner(cfg, stream, 1, str(tmp_path / "ck"),
-                               1).executor.compiled
-    edges = {(e.src, e.dst): i for i, e in enumerate(compiled.job.edges)}
-    assert {edges[TUMBLING, SLIDING], edges[TUMBLING, 6]} <= set(
-        compiled.static_route)
-    assert edges[3, TUMBLING] not in compiled.static_route
-    plan = compiled.static_route[edges[TUMBLING, SLIDING]]
-    sk = compiled.job.vertices[TUMBLING].operator.static_out_keys()
-    assert plan.ok.sum() == cfg["parallelism"] * len(sk)
-
-    # keys 0..29 into windows over 20 keys, through the runner
     env = StreamEnvironment(name="past-num-keys", num_key_groups=64)
     (env.synthetic_source(vocab=30, batch_size=6, parallelism=4)
         .key_by().window_event_time(num_keys=20, window_size=64,
@@ -207,7 +280,8 @@ def test_window_edges_take_static_routes_that_lose_no_row(tmp_path):
     for _ in range(4):
         r.run_epoch()
     assert r.executor.check_overflow() == []
-    assert 1 in r.executor.compiled.static_route
+    assert r.executor.compiled.edge_plans[1].route == "static"
+    assert r.executor.compiled.edge_plans[1].pairs_kept == 2 * (20 + 2 * 3)
     fired = int(np.asarray(r.executor.vertex_state(1)["fired"]).sum())
     r.step()                    # the sink takes a step's rows the step after
     base = r.job.subtask_base(2)
@@ -218,3 +292,115 @@ def test_window_edges_take_static_routes_that_lose_no_row(tmp_path):
         r.executor.vertex_state(1)["acc"])[:, :, 19], owner_of_last, axis=0)
     assert fired > 100 and elsewhere.any()
     assert at_sink == fired
+
+
+# --- the own-keys contract, operator by operator ---------------------------
+
+def _contract_cases():
+    import jax.numpy as jnp
+    from clonos_tpu.api import operators as ops
+    return [
+        ("filter", ops.FilterOperator(lambda k, v, t: (k + v) % 3 != 0)),
+        ("reduce", ops.KeyedReduceOperator(num_keys=13)),
+        ("reduce-max", ops.KeyedReduceOperator(num_keys=13,
+                                               reduce_fn=jnp.maximum)),
+        ("operator-state", ops.OperatorStateCountOperator()),
+        ("union", ops.UnionOperator(capacity=12)),
+        ("count-window", ops.TumblingWindowCountOperator(
+            num_keys=13, window_size=3)),
+        ("tumbling", ops.EventTimeTumblingWindowOperator(
+            num_keys=13, window_size=400, out_of_orderness=100)),
+        ("sliding", ops.SlidingEventTimeWindowOperator(
+            num_keys=13, window_size=300, slide=100,
+            out_of_orderness=100)),
+        ("map-rewrites-keys", ops.MapOperator(
+            lambda k, v, t: (k + 1, v, t))),
+    ]
+
+
+def _violations(op, seed, blocks=4, K=6, P=3, B=10):
+    """Feed ``op`` random blocks (keys from -4 to past every table, any
+    key on any subtask) and return the valid records it emits that its
+    declarations do not allow: a key the emitting subtask never
+    received, or for a dense-table emitter a filled slot whose key the
+    subtask neither received nor lists as a clamp column."""
+    import jax.numpy as jnp
+    from clonos_tpu.api import operators as ops
+    from clonos_tpu.api.records import RecordBatch, zero_invalid
+    rng = np.random.RandomState(seed)
+    state = op.init_state(P)
+    sk, clamp = op.static_out_keys(), op.static_clamp_keys()
+    received = [set() for _ in range(P)]
+    bad = emitted = 0
+
+    def draw(step0):
+        steps = step0 + np.arange(K)
+        keys = rng.randint(-4, 22, (K, P, B)).astype(np.int32)
+        ts = (100 * steps[:, None, None]
+              - rng.randint(0, 100, (K, P, B))).astype(np.int32)
+        return zero_invalid(RecordBatch(
+            jnp.asarray(keys), jnp.asarray(rng.randint(1, 9, (K, P, B)),
+                                           jnp.int32),
+            jnp.asarray(ts), jnp.asarray(rng.rand(K, P, B) < 0.6)))
+
+    for blk in range(blocks):
+        two = isinstance(op, ops.TwoInputOperator)
+        ins = (draw(blk * K), draw(blk * K)) if two else draw(blk * K)
+        for b in (ins if two else (ins,)):
+            k, m = np.asarray(b.keys), np.asarray(b.valid)
+            for p in range(P):
+                received[p] |= set(k[:, p][m[:, p]].tolist())
+        bctx = ops.BlockContext(
+            times=jnp.arange(blk * K, (blk + 1) * K, dtype=jnp.int32),
+            rng_bits=jnp.zeros((K,), jnp.int32),
+            epoch=jnp.zeros((), jnp.int32),
+            step0=jnp.asarray(blk * K, jnp.int32),
+            subtask=jnp.arange(P, dtype=jnp.int32))
+        state, out = op.process_block(state, ins, bctx)
+        k, m = np.asarray(out.keys), np.asarray(out.valid)
+        for p in range(P):
+            if sk is None:
+                keys = k[:, p][m[:, p]]
+            else:                       # the slot's key, not the lane's
+                keys = np.broadcast_to(sk, m[:, p].shape)[m[:, p]]
+                assert (keys == k[:, p][m[:, p]]).all()
+            emitted += len(keys)
+            allowed = received[p] | set(
+                [] if clamp is None else clamp.tolist())
+            bad += sum(int(x) not in allowed for x in keys)
+    assert emitted > 50
+    return bad
+
+
+CONTRACT_CASES = _contract_cases()
+
+
+@pytest.mark.parametrize("name,op", CONTRACT_CASES,
+                         ids=[n for n, _ in CONTRACT_CASES])
+def test_operators_keep_what_they_declare_of_their_keys(name, op):
+    """``emits_received_keys`` and ``static_clamp_keys`` are what the
+    planner prunes routes by: each operator that declares one is held to
+    it on random input, keys outside every table included. A map that
+    rewrites keys declares nothing, and the same check catches it."""
+    declares = op.emits_received_keys or (
+        op.static_out_keys() is not None
+        and op.static_clamp_keys() is not None)
+    assert declares == (name != "map-rewrites-keys")
+    bad = sum(_violations(op, seed) for seed in (1, 2))
+    assert (bad == 0) == declares
+
+
+def test_every_operator_that_declares_own_keys_is_held_to_it():
+    from clonos_tpu.api import operators as ops
+
+    def subclasses(c):
+        return [c] + [s for d in c.__subclasses__() for s in subclasses(d)]
+    declaring = {c for c in subclasses(ops.Operator)
+                 if c.emits_received_keys
+                 or c.static_clamp_keys is not ops.Operator.static_clamp_keys}
+    tested = {type(op) for _, op in CONTRACT_CASES}
+    abstract = {ops.EventTimeWindow}
+    assert declaring - abstract <= tested
+    assert not ops.MapOperator.emits_received_keys
+    assert not ops.HostFeedSource.emits_received_keys
+    assert ops.SessionWindowOperator(5, 3).static_out_keys() is None

@@ -233,6 +233,103 @@ def test_static_route_plan_matches_dynamic_multiset():
     assert np.all((plan.slot_keys >= 0) == plan.ok)
 
 
+def _per_target(routed):
+    """[step][target] -> the valid records there, in slot order."""
+    k, v, t, m = (np.asarray(x) for x in routed)
+    return [[list(zip(k[s, q][m[s, q]].tolist(), v[s, q][m[s, q]].tolist(),
+                      t[s, q][m[s, q]].tolist()))
+             for q in range(k.shape[1])] for s in range(k.shape[0])]
+
+
+@pytest.mark.parametrize("P,T,G,nk,W,clamp", [
+    (8, 8, 128, 30, 2, (0, 29)),    # the review's case: 24 of 30 at P = 8
+    (8, 8, 128, 200, 7, (0, 199)),  # the sliding window's table
+    (16, 16, 64, 499, 1, ()),       # a count window drops what it clips
+    (4, 6, 64, 20, 3, (19,)),       # another parallelism downstream
+    (3, 5, 7, 11, 2, (0, 10)),
+] + [tuple(np.random.RandomState(s).randint(lo, hi) for lo, hi in
+           ((1, 9), (1, 9), (1, 40), (2, 60), (1, 4))) + ((0,),)
+     for s in range(5)])
+def test_pruned_plan_routes_what_the_dynamic_exchange_routes(
+        P, T, G, nk, W, clamp):
+    """A dense emitter that holds own keys fills only its live slots
+    (``own_slots``: its own keys, and the clamp columns on every
+    subtask). The plan pruned to those routes, step by step, the
+    multiset the hash exchange routes to every target, with no drop slot
+    at the capacity ``static_hash_capacity`` gives."""
+    rng = np.random.RandomState(P * 1000 + nk)
+    sk = np.tile(np.arange(nk, dtype=np.int32), W)
+    live = routing.own_slots(sk, P, G, clamp)
+    own = routing.own_slots(sk, P, G)
+    assert (live >= own).all() and own.sum() == len(sk)
+    if clamp and P > 1:
+        # the clamp column is live on subtasks that do not own it
+        assert (live & ~own).sum() == (P - 1) * W * len(set(clamp))
+    cap = routing.static_hash_capacity(sk, P, T, G, live)
+    assert cap <= routing.static_hash_capacity(sk, P, T, G)
+    plan = routing.plan_static_hash(sk, P, T, G, cap, live)
+    assert len(plan.drop_p) == 0 and plan.ok.sum() == live.sum()
+    assert plan.width == cap
+    tight = routing.plan_static_hash(sk, P, T, G, cap - 1, live)
+    assert len(tight.drop_p) >= 1
+    K, B = 6, len(sk)
+    valid = (rng.rand(K, P, B) < 0.7) & live[None]
+    valid[0] = live                       # every live pair at once
+    batch = records.zero_invalid(records.RecordBatch(
+        jnp.asarray(np.broadcast_to(sk, (K, P, B))),
+        jnp.asarray(rng.randint(1, 100, (K, P, B)).astype(np.int32)),
+        jnp.asarray(rng.randint(0, 50, (K, P, B)).astype(np.int32)),
+        jnp.asarray(valid)))
+    wide = cap + 5                        # unmapped columns stay empty
+    r_static, d_static = routing.plan_static_hash(
+        sk, P, T, G, wide, live).apply(batch)
+    r_dyn, d_dyn = routing.route_hash_block(batch, T, G, wide)
+    assert r_static.keys.shape == (K, T, wide)
+    assert int(jnp.sum(d_static)) == 0 == int(jnp.sum(d_dyn))
+    # the same records in the same (arrival) order, holes apart
+    assert _per_target(r_static) == _per_target(r_dyn)
+    assert int(r_dyn.valid.sum()) == int(valid.sum())
+
+
+@pytest.mark.parametrize("P,G,B,cap", [(8, 128, 24, 24), (8, 128, 24, 40),
+                                       (4, 64, 16, 16), (5, 9, 12, 30)])
+def test_identity_route_equals_the_hash_exchange_on_own_keys(P, G, B, cap):
+    """Where every record sits on the subtask its key hashes to, the
+    exchange moves nothing: routed in place (``route_forward_block``, the
+    planner's ``identity``) it equals ``route_hash_block`` record for
+    record — array for array when the producer's records are packed to
+    the front, as behind a dynamic exchange; in slot order where they
+    are not (the exchange packs them, in place they keep their holes)."""
+    rng = np.random.RandomState(B * P)
+    K = 5
+    pool = rng.randint(0, 10_000, size=4000).astype(np.int64)
+    owner = routing._static_targets(pool, P, G)
+    keys = np.zeros((K, P, B), np.int32)
+    for p in range(P):
+        mine = pool[owner == p]
+        keys[:, p] = mine[rng.randint(0, len(mine), size=(K, B))]
+    vals = rng.randint(-99, 99, (K, P, B)).astype(np.int32)
+    ts = rng.randint(0, 50, (K, P, B)).astype(np.int32)
+    counts = rng.randint(0, B + 1, (K, P))
+    for name, valid in (
+            ("packed", np.arange(B)[None, None, :] < counts[:, :, None]),
+            ("holes", rng.rand(K, P, B) < 0.6)):
+        batch = records.zero_invalid(records.RecordBatch(
+            *(jnp.asarray(x) for x in (keys, vals, ts, valid))))
+        r_id, d_id = routing.route_forward_block(batch, cap)
+        r_dyn, d_dyn = routing.route_hash_block(batch, P, G, cap)
+        assert int(jnp.sum(d_id)) == 0 == int(jnp.sum(d_dyn)), name
+        assert _per_target(r_id) == _per_target(r_dyn), name
+        if name == "packed":
+            for a, b in zip(r_id, r_dyn):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for lane in range(P):
+            one = routing.route_forward_block_lane(batch, lane, cap)
+            for a, b in zip(one, r_id):
+                np.testing.assert_array_equal(np.asarray(a),
+                                              np.asarray(b[:, lane]))
+
+
 def test_static_route_plan_drop_accounting():
     """Capacity overflow drops whole static slots and counts them."""
     NK, P, T, G, CAP = 16, 2, 1, 4, 8
