@@ -13,12 +13,12 @@ it pass off the device:
      gather against NumPy over the whole int32 range, and that
      ``jax.block_until_ready`` returns only when the work is done.
   A  the served path, host-fed, at config4's recorded width
-     (``BASELINE.json.configs[3]``, ``bench.bench_config4``): 64 subtasks,
+     (``BASELINE.json.configs[3]``): 64 subtasks,
      a cascading kill of one source, one window and one reduce subtask
      mid-epoch, recovery. Pass = the committed stream equals a NumPy fold
      of the same fed records written here, every record exactly once,
      and the audit ledger shows no divergence.
-  B  the headline deployment as ``bench.py`` builds it (32 subtasks,
+  B  the device-source headline deployment (32 subtasks,
      5.24 GiB of carry on the device, wall-clock causal time, pipelined
      fence): kill one window subtask over two un-truncated epochs,
      recover. Pass = recovery's bit-identity verification and the audit
@@ -55,10 +55,10 @@ def say(msg: str) -> None:
 
 @dataclasses.dataclass(frozen=True)
 class ServedShape:
-    """config4's width (bench.bench_config4). Two differences from the
-    bench so that answers exist to be checked: the window closes every
-    ``window_steps`` (the bench's 1 << 30 never fires, so its reduce and
-    sink see nothing) and values are drawn from the seed."""
+    """config4's width (``BASELINE.json.configs[3]``). Two differences
+    from that record so that answers exist to be checked: the window
+    closes every ``window_steps`` (its 1 << 30 never fires, so its reduce
+    and sink see nothing) and values are drawn from the seed."""
 
     parallelism: int = 16
     batch: int = 32
@@ -161,7 +161,7 @@ def run_served(shape: ServedShape, feed: np.ndarray, ckpt_dir: str,
         runner.run_epoch(complete_checkpoint=True)
     for _ in range(shape.kill_after):
         runner.step()
-    # One subtask of every class on one path (bench.py's cascading kill).
+    # One subtask of every class on one path (a cascading kill).
     victims = [2 % p, job.subtask_base(1) + (3 % p),
                job.subtask_base(2) + (7 % p)]
     runner.inject_failure(victims)
@@ -229,7 +229,8 @@ HEADLINE_SPE, HEADLINE_FILL = 4096, 4
 
 
 def build_headline_job():
-    """bench.build_job, verbatim."""
+    """The headline job: an on-device synthetic source, a count window
+    that never closes, a reduce and a sink."""
     from clonos_tpu.api.environment import StreamEnvironment
 
     env = StreamEnvironment(name="bench-allround", num_key_groups=64,
@@ -247,7 +248,7 @@ def build_headline_job():
 def run_headline(spe: int = HEADLINE_SPE, fill: int = HEADLINE_FILL,
                  block_steps: int = 1024,
                  recovery_block_steps: int = 8192) -> dict:
-    """bench.main's runner (sizes from FILL x SPE as there) with the
+    """The headline runner (log and ring sized from FILL x SPE) with the
     audit on: warm epoch, prewarm, two un-truncated epochs, kill window
     subtask 1, recover, one more epoch."""
     from clonos_tpu.runtime.cluster import ClusterRunner
@@ -523,9 +524,18 @@ def main(argv=None) -> int:
 
     if "B" in parts:
         t0 = time.monotonic()
+        ran0 = tracer.counters().get("block.dispatches.run_block", 0)
         res = run_headline()
         runner, rep = res["runner"], res["report"]
         check_audit(runner, rep, min_validated=2)
+        # four epochs (warm, two un-truncated, one after recovery), each
+        # block of each one run_block
+        ex = runner.executor
+        blocks = 4 * ex.steps_per_epoch // ex.block_steps
+        ran = tracer.counters()["block.dispatches.run_block"] - ran0
+        if ran != blocks:
+            raise AssertionError(
+                f"B ran {blocks} blocks in {ran} run_block dispatches")
         aot_failed = job_counter(runner, "recovery.aot-lower-failed")
         if aot_failed:
             raise AssertionError(
@@ -542,10 +552,12 @@ def main(argv=None) -> int:
         say(f"B peak_bytes_in_use: {stats['peak_bytes_in_use']} "
             f"({stats['peak_bytes_in_use'] / 2**30:.2f} GiB of "
             f"{stats['bytes_limit'] / 2**30:.2f} GiB)")
+        say(f"B block loop: {blocks} blocks of {ex.block_steps} steps, "
+            f"block.dispatches.run_block {ran}")
         mark = print_routes(tracer, mark, "B")
         say("B pass: recovery verified bit-identical, audit 0 divergences,"
             " recovery.aot-lower-failed 0")
-        del res, runner, rep
+        del res, runner, rep, ex
         gc.collect()
 
     if "C" in parts:

@@ -21,10 +21,10 @@ set, sharing the lint's registry/waiver/CLI conventions
   registry proving each rule bites.
 - ``census``     — FT call-site census folded with serde encoding
   widths into a static bytes-per-epoch cost model; its blake2b
-  fingerprint is recorded in BENCH/SOAK artifacts.
-- ``ablate``     — the no-FT ablation twin ``bench.py --ablate`` runs
-  head-to-head against the real executor to *measure* the ft-fraction
-  the static model predicts.
+  fingerprint is recorded in soak artifacts.
+- ``ablate``     — the no-FT ablation twin, to run head-to-head
+  against the real executor and *measure* the ft-fraction the static
+  model predicts.
 
 Importing this package registers the analysis rules (``nondet-reach``,
 ``lock-order``, ``thread-race``, ``join-discipline``) in the shared

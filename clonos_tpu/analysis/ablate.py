@@ -12,8 +12,8 @@ are untouched (XLA dead-code-eliminates the orphaned determinant-row
 construction), so under ``logical_time=True`` with a fixed seed the
 twin's sink outputs, record counts, and operator states are
 bit-identical to the real executor's — only logs/rings/replicas stay
-empty. ``bench.py --ablate`` times the two head-to-head; the wall
-delta IS the measured ft-fraction.
+empty. Timed head-to-head on the chip, the wall delta of the two IS
+the measured ft-fraction (ROADMAP R-a: the input of a ``-noft`` cell).
 
 Why the twin stays *semantics-preserving*: the causal inputs
 (times/rng_bits) still flow to operators, they are just no longer

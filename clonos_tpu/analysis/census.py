@@ -22,11 +22,11 @@ census enumerates it from source:
 bytes-per-epoch and calls-per-step, and predicts an ft-fraction as a
 bytes-moved ratio: determinant + replica + in-flight-ring traffic over
 total traffic (FT + record flow). It is a bandwidth model — on a
-bandwidth-bound fused pipeline that is the first-order driver — and
-``bench.py --ablate`` reports its relative error against the measured
-ablation diff rather than pretending it is exact.
+bandwidth-bound fused pipeline that is the first-order driver — to be
+cross-checked against the measured diff of the ablation twin
+(``analysis/ablate.py``), not taken as exact.
 
-``census_fingerprint`` is the blake2b of the census JSON: BENCH/SOAK
+``census_fingerprint`` is the blake2b of the census JSON: soak
 artifacts record it so a perf number is traceable to the exact FT
 call-site population that produced it.
 """
@@ -165,10 +165,25 @@ def census_json(census: Dict) -> str:
     return json.dumps(census, sort_keys=True, separators=(",", ":"))
 
 
+def pinned_shape(census: Dict) -> Dict:
+    """The census without its line numbers: a call site is (module,
+    qualified function, callee, determinant), a step function (module,
+    qualified name, what it reads); a site that occurs twice in one
+    function counts twice. Stable across edits that only shift lines,
+    as the thread census's pinned shape is."""
+    def strip(rows: List[Dict]) -> List[Dict]:
+        return sorted(({k: v for k, v in r.items() if k != "line"}
+                       for r in rows), key=census_json)
+    return dict(census,
+                step_functions=strip(census["step_functions"]),
+                service_call_sites=strip(census["service_call_sites"]))
+
+
 def fingerprint(census: Dict) -> str:
-    """blake2b over the canonical census JSON, 16 hex chars — the FT
-    call-site population id recorded in BENCH/SOAK artifacts."""
-    return hashlib.blake2b(census_json(census).encode(),
+    """blake2b over the canonical JSON of the census's pinned shape, 16
+    hex chars — the FT call-site population id ``.clonos-census`` pins
+    and soak artifacts record."""
+    return hashlib.blake2b(census_json(pinned_shape(census)).encode(),
                            digest_size=8).hexdigest()
 
 
@@ -208,15 +223,15 @@ def static_cost_model(census: Dict, *, steps_per_epoch: int,
     (topology depth); ``record_bytes`` is the RecordBatch footprint per
     record (4 int32 fields: key, value, timestamp, valid). The
     predicted ft-fraction is FT bytes moved / total bytes moved per
-    epoch — a bandwidth model, cross-checked against the measured
-    ablation diff by ``bench.py --ablate``.
+    epoch — a bandwidth model, to be cross-checked against the measured
+    diff of the ablation twin (``analysis/ablate.py``).
 
     With ``spill=True`` the ledger grows the tiered-storage lanes
     (storage/tiered.py): every sealed epoch's ring slices AND
     determinant windows cross the d2h lane into the host tier, then the
     host→disk lane as checksummed segments — two extra moves of the
     same bytes, but on the writer thread, so they cost *bandwidth*
-    (modeled here), not fence latency (measured by ``bench --spill``).
+    (modeled here), not fence latency.
     """
     enc = census["encoding"]
     dets = census["dets_per_step"] or 0

@@ -25,7 +25,7 @@ architecture (`.clonos-threads`) the same way it pins the call-site
 population (`.clonos-census`).
 
 The census (analysis/census.py) rides along in the result and the JSON
-report, fingerprinted, so CI and the bench artifacts agree on exactly
+report, fingerprinted, so CI and the soak artifacts agree on exactly
 which FT call-site population they describe.
 
 Waiver semantics mirror the lint, with one addition: staleness is only

@@ -142,9 +142,8 @@ class AuditValidator:
         and return them as ledger entries (``EpochDigest.to_entry``
         dicts) WITHOUT validating against the persisted ledger — the
         raw material for a ``diff_ledgers`` comparison between two
-        recovery modes (bench proves the overlapped finalize pipeline
-        bit-identical to a sequential-recovery control this way:
-        ``diff_ledgers(seq_entries, overlap_entries) == []``)."""
+        runs (``tests/test_pipelined_fence.py`` shows the pipelined
+        fence bit-identical to the inline one this way)."""
         from clonos_tpu.obs import audit as _audit
         return [_audit.digest_epoch_window(
                     int(e), self.executor.epoch_window(int(e))).to_entry()

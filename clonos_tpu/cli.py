@@ -10,13 +10,11 @@ entrypoint under ``jax.distributed`` (see parallel/distributed.py).
 Usage:
     python -m clonos_tpu run <module:function> [--steps N] [--epochs N] ...
     python -m clonos_tpu info <module:function>
-    python -m clonos_tpu bench [--jobs N] [--multichip [N]]
     python -m clonos_tpu dryrun [--devices N]
     python -m clonos_tpu dispatcher --lease DIR [--quota TENANT=N ...]
     python -m clonos_tpu submit <module:function> --dispatcher HOST:PORT
     python -m clonos_tpu jobs --dispatcher HOST:PORT
     python -m clonos_tpu audit <checkpoint-dir> [--diff DIR2] [--job ID]
-    python -m clonos_tpu dissect [--trials N]
 """
 
 from __future__ import annotations
@@ -160,17 +158,6 @@ def cmd_info(args) -> int:
     }
     print(json.dumps(info, indent=2))
     return 0
-
-
-def cmd_bench(args) -> int:
-    import bench
-    rc = bench.main(jobs=getattr(args, "jobs", None),
-                    multichip=getattr(args, "multichip", None),
-                    soak=getattr(args, "soak", None),
-                    ablate=getattr(args, "ablate", False),
-                    serve=getattr(args, "serve", None),
-                    rescale=getattr(args, "rescale", None))
-    return int(rc or 0)
 
 
 def cmd_dryrun(args) -> int:
@@ -948,116 +935,6 @@ def cmd_top(args) -> int:
         return 0
 
 
-def cmd_dissect(args) -> int:
-    """Dissect the warm replay at full bench shapes: what the min-of-N
-    ``replayer.replay(plan)`` wall actually spends — dispatch-chain
-    compute (amortized over a chained loop, host sync excluded) vs the
-    single d2h sync. Optimization must target whichever dominates.
-    (Absorbed from tools/replay_dissect.py.)"""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import bench
-    from clonos_tpu.runtime.cluster import ClusterRunner
-    from clonos_tpu.runtime.executor import DETS_PER_STEP
-    from clonos_tpu.utils.devsync import device_sync
-
-    SPE = bench.STEPS_PER_EPOCH
-    job = bench.build_job()
-    need = bench.FILL_EPOCHS * SPE * DETS_PER_STEP
-    cap = 1 << need.bit_length()
-    runner = ClusterRunner(job, steps_per_epoch=SPE, log_capacity=cap,
-                           max_epochs=16,
-                           inflight_ring_steps=1 << max(
-                               bench.FILL_EPOCHS * SPE, 2).bit_length(),
-                           recovery_block_steps=8192, block_steps=1024,
-                           seed=7)
-    t0 = time.monotonic()
-    runner.run_epoch(complete_checkpoint=True)
-    device_sync(runner.executor.carry)
-    print("epoch0:", round(time.monotonic() - t0, 1), "s", flush=True)
-    t0 = time.monotonic()
-    for _ in range(bench.FILL_EPOCHS):
-        runner.run_epoch(complete_checkpoint=False)
-    device_sync(runner.executor.carry)
-    print("fill:", round(time.monotonic() - t0, 1), "s", flush=True)
-
-    failed = bench.PAR + 1
-    runner.inject_failure([failed])
-    t0 = time.monotonic()
-    report = runner.recover()
-    device_sync(runner.executor.carry)
-    print("cold recover:", round(time.monotonic() - t0, 1), "s",
-          {k: round(v, 1) for k, v in report.phase_ms.items()}, flush=True)
-
-    mgr = report.managers[0]
-    replayer = mgr.replayer
-    plan = mgr.plan
-
-    # (a) bench's exact warm-replay measurement
-    for trial in range(args.trials):
-        t1 = time.monotonic()
-        result = replayer.replay(plan)
-        device_sync(result.emit_counts)
-        print(f"warm replay #{trial}: "
-              f"{(time.monotonic() - t1) * 1e3:.1f}ms  phases:",
-              {k: round(v, 1) for k, v in result.phase_ms.items()},
-              flush=True)
-
-    # (b) amortized compute of the core block program alone: chain N
-    # dispatches, one sync at the end.
-    dev = plan.det_device is not None
-    print("clean device path:", dev, "n_steps:", plan.n_steps, flush=True)
-    if dev:
-        t_dev, r_dev, _exp = plan.det_device
-        chunk = plan.input_steps[0] if isinstance(plan.input_steps, list) \
-            else plan.input_steps
-        state0 = jax.tree_util.tree_map(
-            lambda x: x[plan.subtask][None], plan.checkpoint_op_state)
-        sub = jnp.asarray(plan.subtask, jnp.int32)
-        N = 10
-        jb = replayer._jit_block
-
-        def chained():
-            acc = jnp.zeros((), jnp.int32)
-            for _ in range(N):
-                st, out, counts, acc = jb(
-                    state0, chunk, t_dev[:replayer.block_steps],
-                    r_dev[:replayer.block_steps], sub, acc)
-            return counts
-        r = chained()
-        np.asarray(r.ravel()[0])
-        ts = []
-        for _ in range(3):
-            t1 = time.monotonic()
-            r = chained()
-            np.asarray(r.ravel()[0])
-            ts.append((time.monotonic() - t1) * 1e3)
-        print(f"block program amortized: {min(ts) / N:.2f}ms per call "
-              f"(chain of {N}: {min(ts):.1f}ms)", flush=True)
-
-        # (c) tail ops: tslice + concat cost
-        def tail():
-            acc = jnp.zeros((), jnp.int32)
-            st, out, counts, acc = jb(state0, chunk,
-                                      t_dev[:replayer.block_steps],
-                                      r_dev[:replayer.block_steps], sub, acc)
-            packed = jnp.concatenate(
-                [counts, acc.reshape(1), _exp[:plan.n_steps]], axis=0)
-            return packed
-        p = tail()
-        np.asarray(p.ravel()[0])
-        ts = []
-        for _ in range(5):
-            t1 = time.monotonic()
-            p = tail()
-            np.asarray(p.ravel()[0])
-            ts.append((time.monotonic() - t1) * 1e3)
-        print(f"block+concat+sync single: min={min(ts):.1f}ms "
-              f"p50={sorted(ts)[2]:.1f}ms", flush=True)
-    return 0
-
-
 def cmd_trace(args) -> int:
     """Dump / convert recorded trace files (``clonos_tpu trace``):
     summary by default, Chrome trace_event JSON with ``--chrome`` (the
@@ -1631,45 +1508,6 @@ def main(argv=None) -> int:
     pi.add_argument("job")
     pi.set_defaults(fn=cmd_info)
 
-    pb = sub.add_parser("bench", help="run the headline benchmark")
-    pb.add_argument("--jobs", type=int, default=None, metavar="N",
-                    help="run ONLY the multi-job throughput probe with "
-                         "N concurrent in-process jobs (per-tenant "
-                         "steady-state records/sec + fairness ratio)")
-    pb.add_argument("--multichip", type=int, nargs="?", const=8,
-                    default=None, metavar="N",
-                    help="run ONLY the mesh-sharding probe over N "
-                         "devices (per-shard throughput, scaling "
-                         "efficiency, sealed-digest equality vs the "
-                         "1-device run)")
-    pb.add_argument("--soak", type=float, nargs="?", const=30.0,
-                    default=None, metavar="SECONDS",
-                    help="run ONLY the open-loop soak probe: paced "
-                         "fixed-rate load + seeded chaos + exactly-"
-                         "once audit (see `clonos_tpu soak` for the "
-                         "full-control version)")
-    pb.add_argument("--serve", type=float, nargs="?", const=20.0,
-                    default=None, metavar="SECONDS",
-                    help="run ONLY the read-path probe: batched "
-                         "replica reads vs sequential point queries, "
-                         "bit-identity vs the owner, and mixed "
-                         "read/ingest load with a replica-kill "
-                         "(writes SERVE_r0N.json)")
-    pb.add_argument("--rescale", type=float, nargs="?", const=12.0,
-                    default=None, metavar="SECONDS",
-                    help="run ONLY the elastic-repartition probe: a "
-                         "live 2->4 keyed re-cut at a checkpoint fence "
-                         "under load — throughput before/after, fence-"
-                         "stall cost, exactly-once handoff evidence, "
-                         "cross-layout ledger diff vs a never-rescaled "
-                         "control (writes RESCALE_r0N.json)")
-    pb.add_argument("--ablate", action="store_true",
-                    help="run ONLY the no-FT ablation probe: the "
-                         "semantics-preserving twin head-to-head "
-                         "against the real executor (measured vs "
-                         "static ft-fraction + model relative error)")
-    pb.set_defaults(fn=cmd_bench)
-
     pd = sub.add_parser("dryrun", help="multichip sharding dry run")
     pd.add_argument("--devices", type=int, default=8)
     pd.set_defaults(fn=cmd_dryrun)
@@ -2194,12 +2032,6 @@ def main(argv=None) -> int:
     pp.add_argument("--interval", type=float, default=2.0,
                     help="redraw period in live mode (seconds)")
     pp.set_defaults(fn=cmd_top)
-
-    px = sub.add_parser("dissect", help="dissect warm-replay wall time "
-                                        "at bench shapes")
-    px.add_argument("--trials", type=int, default=5,
-                    help="warm replay trials to time")
-    px.set_defaults(fn=cmd_dissect)
 
     args = p.parse_args(argv)
     return args.fn(args)

@@ -3,8 +3,7 @@
 Covers the reference's Clonos-specific keys (SURVEY §2.3 config row:
 flink-runtime .../configuration/JobManagerOptions.java:111-135, NettyConfig
 .java:82-98, ExecutionConfig.java:297-310, InFlightLogConfig.java:42-71) plus
-the TPU-native knobs this framework adds (log capacities, batch shapes, mesh
-axes).
+the TPU-native knobs this framework adds (log capacities, spill budgets).
 """
 
 from __future__ import annotations
@@ -23,11 +22,6 @@ NUM_STANDBY_TASKS = ConfigOption(
     description="Passive standby replicas per subtask, state-synced via "
                 "checkpoint pushes.")
 
-CHECKPOINT_BACKOFF_BASE_MS = ConfigOption(
-    "jobmanager.execution.checkpoint-backoff-base", 1000,
-    description="Base backoff (ms) applied to the checkpoint interval while "
-                "a recovery is in progress.")
-
 CHECKPOINT_BACKOFF_MULTIPLIER = ConfigOption(
     "jobmanager.execution.checkpoint-backoff-multiplier", 2.0,
     description="Multiplier on the checkpoint interval during recovery.")
@@ -39,12 +33,6 @@ DETERMINANT_SHARING_DEPTH = ConfigOption(
     description="How many hops downstream determinants are replicated. "
                 "-1 = full sharing (survive any number of connected "
                 "failures); k = survive up to k connected failures.")
-
-DELTA_ENCODING_STRATEGY = ConfigOption(
-    "causal.delta-encoding-strategy", "grouped",
-    validator=lambda v: v in ("flat", "grouped"),
-    description="Piggyback delta layout: 'flat' (one entry per thread log) "
-                "or 'grouped' (vertex->partition->subpartition hierarchy).")
 
 # --- determinant log memory (reference: NettyConfig.java:82-98) -------------
 
@@ -59,11 +47,6 @@ DETERMINANT_MAX_EPOCHS = ConfigOption(
     description="Maximum concurrently-retained (un-truncated) epochs per log.",
     validator=lambda v: v > 0)
 
-DETERMINANT_MAX_DELTA = ConfigOption(
-    "causal.log.max-delta", 4096,
-    description="Static upper bound on determinants shipped per piggyback "
-                "delta (one superstep's worth).")
-
 # --- in-flight log (reference: InFlightLogConfig.java:42-71) ----------------
 
 INFLIGHT_TYPE = ConfigOption(
@@ -75,15 +58,6 @@ INFLIGHT_SPILL_POLICY = ConfigOption(
     "taskmanager.inflight.spill.policy", "eager",
     validator=lambda v: v in ("eager", "availability", "epoch"),
     description="When to spill epochs from HBM to host memory/disk.")
-
-INFLIGHT_PREFETCH_BUFFERS = ConfigOption(
-    "taskmanager.inflight.spill.num-prefetch-buffers", 50,
-    description="Replay prefetch depth for spilled epochs.")
-
-INFLIGHT_AVAILABILITY_TRIGGER = ConfigOption(
-    "taskmanager.inflight.spill.availability-trigger", 0.3,
-    description="Pool availability fraction below which 'availability' "
-                "policy spills.")
 
 INFLIGHT_HOST_BUDGET_EPOCHS = ConfigOption(
     "taskmanager.inflight.spill.host-budget-epochs", 2,
@@ -106,24 +80,7 @@ CHECKPOINT_DIR = ConfigOption(
     "checkpoint.dir", "/tmp/clonos_tpu/checkpoints",
     description="Durable storage root for snapshots and spilled epochs.")
 
-# --- execution / batching (TPU-native) --------------------------------------
-
-BATCH_SIZE = ConfigOption(
-    "execution.batch-size", 256,
-    description="Records per batch flowing along each edge per superstep. "
-                "The TPU analog of the reference's network buffer.")
-
-RECORD_WIDTH = ConfigOption(
-    "execution.record-width", 8,
-    description="int32 lanes per record in the packed record layout.")
-
-MESH_TASK_AXIS = ConfigOption(
-    "parallel.mesh-task-axis", "tasks",
-    description="Mesh axis name over which parallel subtasks are sharded.")
-
-HEARTBEAT_INTERVAL_MS = ConfigOption(
-    "heartbeat.interval", 1000,
-    description="Heartbeat cadence between control plane and task plane.")
+# --- heartbeats --------------------------------------------------------------
 
 HEARTBEAT_TIMEOUT_MS = ConfigOption(
     "heartbeat.timeout", 5000,
@@ -131,24 +88,6 @@ HEARTBEAT_TIMEOUT_MS = ConfigOption(
                 "failed.")
 
 # --- observability (clonos_tpu/obs) -----------------------------------------
-
-TRACING_ENABLED = ConfigOption(
-    "observability.tracing.enabled", False,
-    description="Record distributed trace spans (epoch/checkpoint/recovery "
-                "lifecycles) and propagate trace context on control-wire "
-                "headers. Off = the NullTracer: no wire fields, no "
-                "per-record work.")
-
-TRACE_DIR = ConfigOption(
-    "observability.tracing.dir", "/tmp/clonos_tpu/traces",
-    description="Directory for per-process trace-<service>.jsonl files "
-                "(convert with `clonos_tpu trace`).")
-
-TRACE_BUFFER_EVENTS = ConfigOption(
-    "observability.tracing.buffer-events", 8192,
-    validator=lambda v: v > 0,
-    description="Flight-recorder ring size: most recent trace records kept "
-                "in memory and served on the metrics endpoint's /trace.")
 
 AUDIT_ENABLED = ConfigOption(
     "observability.audit.enabled", False,
@@ -174,16 +113,3 @@ PROFILE_ENABLED = ConfigOption(
                 "overhead.ft-fraction gauge) with device-fenced section "
                 "timers in the hot paths. Off = the NullProfiler: no "
                 "fencing, no per-step host work.")
-
-METRICS_HISTORY_INTERVAL_S = ConfigOption(
-    "observability.metrics-history.interval-s", 2.0,
-    validator=lambda v: v > 0,
-    description="Seconds between metrics-history samples taken by the "
-                "metrics endpoint's sampler thread (served at "
-                "/metrics/history.json).")
-
-METRICS_HISTORY_WINDOW = ConfigOption(
-    "observability.metrics-history.window", 512,
-    validator=lambda v: v > 0,
-    description="Samples retained in the metrics-history ring (memory and "
-                "the bounded history JSONL file alike).")

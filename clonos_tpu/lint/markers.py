@@ -7,8 +7,7 @@ module the single source of truth). Unregistered markers are silent
 no-ops under ``-m`` filters — a test tagged with a typo'd ``slow``
 would run in tier-1 forever.
 
-``tools/check_markers.py`` remains as a thin shim over this module
-(the ``replay_dissect`` -> ``dissect`` precedent), so both
+``tools/check_markers.py`` remains as a thin shim over this module, so both
 ``python tools/check_markers.py`` and ``clonos_tpu lint tests/``
 enforce the same registry.
 """

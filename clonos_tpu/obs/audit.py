@@ -283,8 +283,8 @@ def diff_ledgers_cross(expected: List[dict],
     exactly (obs/digest.diff — every channel, bit for bit); epochs
     sealed under DIFFERENT cuts of the same topology compare through
     the group directory on the layout-invariant channels. The
-    ``clonos_tpu audit A --diff B`` surface, and the post-re-cut
-    acceptance check of ``bench --rescale``."""
+    ``clonos_tpu audit A --diff B`` surface, and the acceptance check
+    after a re-cut."""
     from clonos_tpu.obs import digest as _digest
 
     ea = {int(e["epoch"]): e for e in expected}

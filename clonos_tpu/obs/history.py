@@ -45,12 +45,14 @@ class MetricsHistory:
                  = None, path: Optional[str] = None,
                  interval_s: float = 2.0, window: int = 512,
                  # clonos: allow(wallclock): sample timestamps, obs-only
-                 clock=time.time):
+                 clock=time.time, mono=None):
         self.sample_fn = sample_fn
         self._path = path
         self.interval_s = float(interval_s)
         self.window = int(window)
         self._clock = clock
+        #: what the sampling loop paces itself by; a test substitutes it
+        self._mono = time.monotonic if mono is None else mono
         self._ring: Deque[dict] = collections.deque(maxlen=self.window)
         #: sampling slots skipped because a sample overran its whole
         #: interval (the loop re-anchors instead of bursting catch-up
@@ -105,11 +107,11 @@ class MetricsHistory:
         # interval_s after the previous DEADLINE, not after the sample
         # finished; a sample that overruns whole intervals skips the
         # missed slots (counted) rather than firing a catch-up burst.
-        next_due = time.monotonic() + self.interval_s
-        while not self._stop.wait(max(next_due - time.monotonic(), 0.0)):
+        next_due = self._mono() + self.interval_s
+        while not self._stop.wait(max(next_due - self._mono(), 0.0)):
             self.sample_once()
             next_due += self.interval_s
-            now = time.monotonic()
+            now = self._mono()
             if next_due <= now:
                 missed = int((now - next_due) / self.interval_s) + 1
                 self.missed_slots += missed

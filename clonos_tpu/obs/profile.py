@@ -217,8 +217,7 @@ class Profiler:
         return round(self._last_fraction, 6)
 
     def lifetime_ft_fraction(self) -> float:
-        """FT / total over the whole process lifetime (bench
-        reporting)."""
+        """FT / total over the whole process lifetime."""
         with self._lock:
             ft = sum(v for n, v in self._life.items()
                      if self._kind.get(n, FT) == FT)
@@ -233,8 +232,7 @@ class Profiler:
     def snapshot(self) -> Dict[str, Any]:
         """One structured view of the profiler's state: the gauge
         value, the lifetime fraction, and per-section lifetime seconds
-        with kinds — what ``bench.py --ablate`` records as the runtime
-        side of the FT-cost cross-check."""
+        with kinds — the runtime side of the FT-cost cross-check."""
         with self._lock:
             sections = {n: {"seconds": round(v, 6),
                             "kind": self._kind.get(n, FT)}

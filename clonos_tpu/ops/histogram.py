@@ -9,7 +9,7 @@ XLA's scatter-add serializes its updates on TPU (60-120ms at bench shapes
 for ~4M updates). This Pallas kernel streams the records through the VPU
 as chunked compare-accumulate instead: for each 128-record chunk, a
 ``[rows, chunk, key_lanes]`` one-hot compare and an axis reduce — no
-scatter anywhere, ~10x faster (tools/profile_block.py).
+scatter anywhere.
 
 Which form runs is decided by the platform the program is lowered for,
 never by a failure: the kernel on a TPU, a bit-identical XLA scatter

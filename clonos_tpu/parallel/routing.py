@@ -214,9 +214,8 @@ def _block_to_targets(
     same-target records before it in (p-major, slot) order. With T
     targets that is a running per-bucket count — one cumsum over a
     ``[K, n, T+1]`` one-hot (invalid records get bucket T), no argsort.
-    The TPU executes the cumsum as a few vector passes where the sort
-    this replaced cost ~2x more at bench shapes (tools/ab_route.py, 49ms -> 24ms
-    per 512-step block); placement is then ONE flat scatter of the K*n
+    The TPU executes the cumsum as a few vector passes, cheaper than
+    the sort this replaced; placement is then ONE flat scatter of the K*n
     records into ``[K, T+1, cap]`` (the +1 row swallows drops).
     Bit-identical to vmapping :func:`_scatter_to_targets` per step,
     including overflow accounting (first ``cap`` arrivals per target

@@ -268,6 +268,13 @@ class OverflowError_(RuntimeError):
     longer recoverable and the control plane must not keep running."""
 
 
+def _exposed_ms(start: float, end: float, wait_from: float) -> float:
+    """Milliseconds of a worker's interval ``[start, end]`` that ran
+    after the thread it works for began waiting on it at ``wait_from``:
+    the part on the critical path (the rest ran under other work)."""
+    return max(0.0, end - max(start, wait_from)) * 1e3
+
+
 class ClusterRunner:
     """Single-process cluster (MiniCluster analog) with failure injection.
 
@@ -286,25 +293,19 @@ class ClusterRunner:
                  audit: Optional[bool] = None,
                  audit_on_divergence: Optional[str] = None,
                  lineage=None,
-                 overlap_recovery: bool = True,
                  overlap_epoch: bool = False,
                  **executor_kw):
         self.job = job
         self.executor = LocalExecutor(job, steps_per_epoch=steps_per_epoch,
                                       **executor_kw)
-        #: overlapped finalize pipeline default for recover() — the
-        #: sequential escape hatch (False) is the bit-identity control
-        #: bench/soak diff the overlapped path against.
-        self.overlap_recovery = overlap_recovery
-        #: pipelined fence default for run_epoch(): True hands the
-        #: fence tail (health drain, audit seal, ledger append,
+        #: fence mode of run_epoch(), fixed for the runner's life: True
+        #: hands the fence tail (health drain, audit seal, ledger append,
         #: checkpoint write) to a worker thread that overlaps the next
         #: epoch's compute, joining before the next fence — at most one
         #: tail in flight. Defaults to False (today's strict order):
         #: overlap defers checkpoint completion/truncation and ledger
         #: visibility by one fence, which callers must opt into. The
-        #: sequential control run never writes the fence.overlap-saved
-        #: key — its absence marks the control.
+        #: inline fence never writes the fence.overlap-saved key.
         self.overlap_epoch = overlap_epoch
         #: in-flight fence tail (pipelined fence): None, or a dict with
         #: the worker thread + its captured handles/results. Joined at
@@ -317,7 +318,7 @@ class ClusterRunner:
         #: "fence.overlap-saved", preserving
         #: sum(fence.*) - overlap-saved == fence-tail.
         self.last_fence_phases: Dict[str, float] = {}
-        #: cumulative fence.overlap-saved milliseconds (bench reads it)
+        #: cumulative fence.overlap-saved milliseconds
         self.fence_overlap_saved_total_ms = 0.0
         self._fence_headroom_checked = False
         if incremental_checkpoints:
@@ -1072,7 +1073,6 @@ class ClusterRunner:
 
         # Control-plane bookkeeping the dead worker would have had.
         runner.global_step = fence + n_steps
-        runner.executor._steps_executed = fence + n_steps
         # Step-input ledger: per-step (time, rng) inputs are global
         # across the lockstep supersteps, so any subtask's recorded
         # stream reproduces them; pre-fence entries are placeholders
@@ -1113,7 +1113,11 @@ class ClusterRunner:
         # carry and dispatches its ring-bounds read at entry, and the
         # final packed read asserts those device bounds — the offsets
         # must already be in place.
-        ov: Dict[str, Any] = {"derive_ms": 0.0, "warm_ms": 0.0,
+        # Both pieces of work stamp their (start, end), and the two
+        # joins when they began to wait, on one clock: the report's
+        # blocked remainders come from these stamps alone.
+        ov: Dict[str, Any] = {"derive": (0.0, 0.0), "warm": (0.0, 0.0),
+                              "derive_wait": 0.0,
                               "rg": {}, "ac": {}, "err": None}
         derived = threading.Event()
 
@@ -1157,7 +1161,7 @@ class ClusterRunner:
             except Exception as err:          # re-raised at the join
                 ov["err"] = err
             finally:
-                ov["derive_ms"] = (_time.monotonic() - t_d) * 1e3
+                ov["derive"] = (t_d, _time.monotonic())
                 derived.set()
             if ov["err"] is not None:
                 return
@@ -1177,13 +1181,14 @@ class ClusterRunner:
                 aot_lower_first_step(runner.executor, runner._mgroup)
             except Exception as err:
                 ov["err"] = err
-            ov["warm_ms"] = (_time.monotonic() - t_w) * 1e3
+            ov["warm"] = (t_w, _time.monotonic())
 
         worker = threading.Thread(target=_overlap_work,
                                   name="bootstrap-finalize-overlap")
         worker.start()
 
         def _join_ledgers() -> None:
+            ov["derive_wait"] = _time.monotonic()
             derived.wait()
             if ov["err"] is not None:
                 raise ov["err"]
@@ -1261,7 +1266,6 @@ class ClusterRunner:
         worker.join()
         if ov["err"] is not None:
             raise ov["err"]
-        warm_blocked_ms = (_time.monotonic() - t_j2) * 1e3
 
         # Fold the rebuild stages into the report: they extend the
         # finalize phase (everything-after-replay). Overlap is
@@ -1275,19 +1279,21 @@ class ClusterRunner:
             report.phase_ms["finalize"] = (
                 report.phase_ms.get("finalize", 0.0) + ms)
             runner._mgroup.histogram(f"recovery.{name}-ms").update(ms)
-        reattach_blocked_ms = report.phase_ms.get(
-            "finalize.listener-reattach", 0.0)   # recover()'s join wait
-        report.phase_ms["finalize.listener-reattach"] = ov["derive_ms"]
+        # (recover()'s own listener-reattach entry, the wall of its
+        # join, is replaced by the derivation's true wall.)
+        derive_ms = (ov["derive"][1] - ov["derive"][0]) * 1e3
+        warm_ms = (ov["warm"][1] - ov["warm"][0]) * 1e3
+        blocked_ms = (_exposed_ms(*ov["derive"], ov["derive_wait"])
+                      + _exposed_ms(*ov["warm"], t_j2))
+        report.phase_ms["finalize.listener-reattach"] = derive_ms
         report.phase_ms["finalize.first-step-recompile"] = (
             report.phase_ms.get("finalize.first-step-recompile", 0.0)
-            + ov["warm_ms"])
+            + warm_ms)
         report.phase_ms["finalize"] = (
-            report.phase_ms.get("finalize", 0.0)
-            + reattach_blocked_ms + warm_blocked_ms)
+            report.phase_ms.get("finalize", 0.0) + blocked_ms)
         report.phase_ms["finalize.overlap-saved"] = (
             report.phase_ms.get("finalize.overlap-saved", 0.0)
-            + max(ov["derive_ms"] - reattach_blocked_ms, 0.0)
-            + max(ov["warm_ms"] - warm_blocked_ms, 0.0))
+            + derive_ms + warm_ms - blocked_ms)
         for name in ("finalize.listener-reattach",
                      "finalize.first-step-recompile",
                      "finalize.overlap-saved"):
@@ -1501,7 +1507,6 @@ class ClusterRunner:
         else:
             fence = from_epoch * spe
         runner.global_step = fence
-        runner.executor._steps_executed = fence
         runner.executor.step_input_history = [(0, 0)] * fence
         if runner.latency is not None:
             runner.latency._seen = fence
@@ -1635,8 +1640,7 @@ class ClusterRunner:
 
     # --- steady state --------------------------------------------------------
 
-    def run_epoch(self, complete_checkpoint: bool = True,
-                  overlap_fence: Optional[bool] = None) -> None:
+    def run_epoch(self, complete_checkpoint: bool = True) -> None:
         """Run to the next epoch fence and trigger its checkpoint.
 
         ``complete_checkpoint=False`` leaves the checkpoint pending (no
@@ -1644,8 +1648,8 @@ class ClusterRunner:
         interval regime the spillable in-flight log exists for, and the
         setup for multi-epoch recovery gaps.
 
-        ``overlap_fence`` (default: the runner's ``overlap_epoch``)
-        selects the pipelined fence: the closed epoch's fence state is
+        A runner built with ``overlap_epoch=True`` runs the pipelined
+        fence: the closed epoch's fence state is
         captured as device-side handles (async health d2h, epoch-window
         copies, lean snapshot) and the tail — health drain, audit seal,
         group-committed ledger append, async checkpoint write, spill
@@ -1656,23 +1660,22 @@ class ClusterRunner:
         read before the ring can wrap twice; one epoch of ring headroom
         is asserted once), checkpoint completion/truncation, and ledger
         visibility — ``drain_fence()`` settles all of it on demand.
-        ``overlap_fence=False`` keeps today's strict order and never
-        writes the ``fence.overlap-saved`` attribution key — its
-        absence marks a sequential control run."""
+        ``overlap_epoch=False`` keeps the strict order, runs the tail
+        inline and never writes the ``fence.overlap-saved``
+        attribution key."""
         if self.failed:
             raise rec.RecoveryError(
                 f"cannot run with failed subtasks {sorted(self.failed)}; "
                 f"call recover() first")
-        overlap = (self.overlap_epoch if overlap_fence is None
-                   else overlap_fence)
+        overlap = self.overlap_epoch
         if overlap and not self._fence_headroom_checked:
             self._check_fence_headroom()
-        # A mode switch settles strictly — and so does spill, whose
-        # host store the in-flight worker (attach_spill_digests) and
-        # this epoch's spill hook would otherwise race: join BEFORE
-        # dispatching this epoch's compute.
-        if self._fence_tail is not None and (
-                not overlap or self.executor.spill_logs is not None):
+        # Spill settles strictly: the in-flight worker
+        # (attach_spill_digests) and this epoch's spill hook would
+        # otherwise race on the host store, so join BEFORE dispatching
+        # this epoch's compute.
+        if (self._fence_tail is not None
+                and self.executor.spill_logs is not None):
             self._join_fence_tail()
         closed = self.executor.epoch_id
         n = self.executor.steps_per_epoch - self.executor.step_in_epoch
@@ -2143,7 +2146,6 @@ class ClusterRunner:
     def recover(self, drill: bool = False,
                 host_rows: Optional[Dict[int, Tuple[np.ndarray, int]]]
                 = None,
-                overlap_finalize: Optional[bool] = None,
                 pre_patch_join: Optional[Callable[[], None]] = None
                 ) -> RecoveryReport:
         """Public entry for :meth:`_recover_impl` that additionally
@@ -2164,7 +2166,6 @@ class ClusterRunner:
                              drill=bool(drill)) as chain:
                 report = self._recover_impl(
                     phases, chain, drill=drill, host_rows=host_rows,
-                    overlap_finalize=overlap_finalize,
                     pre_patch_join=pre_patch_join)
                 span.set(from_epoch=report.from_epoch,
                          steps_replayed=report.steps_replayed,
@@ -2185,7 +2186,6 @@ class ClusterRunner:
                       drill: bool = False,
                       host_rows: Optional[Dict[int, Tuple[np.ndarray, int]]]
                       = None,
-                      overlap_finalize: Optional[bool] = None,
                       pre_patch_join: Optional[Callable[[], None]] = None
                       ) -> RecoveryReport:
         """Run the full causal-recovery protocol for all failed subtasks,
@@ -2207,17 +2207,12 @@ class ClusterRunner:
         DeterminantResponseEvent arriving over the wire instead of the
         local piggyback channel).
 
-        ``overlap_finalize`` selects the finalize pipeline: overlapped
-        (the default, via ``self.overlap_recovery``) drains the final
-        packed barrier-read on a worker thread while the main thread
-        runs the audit validator, with an explicit join +
-        deferred-assert check before returning; revive bookkeeping
-        runs only after the join and state-verify pass (the same
-        safety order as the control — a failed verify leaves the
-        subtasks marked dead, and an audit divergence is re-raised
-        after verify). ``False`` is the strictly-sequential control
-        (barrier-read → state-verify → revive → audit) that bench/soak
-        diff the overlapped path's ledger against.
+        The finalize drains the final packed barrier-read on a worker
+        thread while the main thread runs the audit validator, with an
+        explicit join + deferred-assert check before returning; revive
+        bookkeeping runs only after the join and state-verify pass (a
+        failed verify leaves the subtasks marked dead, and an audit
+        divergence is re-raised after verify and revive).
 
         ``pre_patch_join`` is the bootstrap-overlap hook: a callable
         joined (once) immediately before the FIRST ``_patch`` call —
@@ -2283,8 +2278,8 @@ class ClusterRunner:
         nrings = len(patched.out_rings)
         if self._ring_mirror_valid:
             # Heads advance once per superstep wherever the executor is
-            # driven from; its own step counter is the authoritative one.
-            head_m = self.executor._steps_executed
+            # driven from; its own step ledger is the authoritative one.
+            head_m = len(self.executor.step_input_history)
             self._bounds_cache = {
                 ri: (self._ring_tail_mirror, head_m)
                 for ri in range(nrings)}
@@ -2618,11 +2613,10 @@ class ClusterRunner:
         # on-device output-cut verification flag, and its consumed total.
         # TPU programs execute in dispatch order, so this read — dispatched
         # last — is also the barrier the old device_sync(patched) was.
-        # Sub-attribution (the bench's one-number "finalize" mystery):
-        # ``finalize.barrier-read`` = the packed concatenate + d2h
-        # transfer (dispatch-order barrier: it pays for every program
-        # still in flight), ``finalize.state-verify`` = the host-side
-        # deferred asserts. Overlapped mode drains the transfer on a
+        # Sub-attribution: ``finalize.barrier-read`` = the packed
+        # concatenate + d2h transfer (dispatch-order barrier: it pays
+        # for every program still in flight), ``finalize.state-verify``
+        # = the host-side deferred asserts. The transfer drains on a
         # worker thread while the main thread runs the audit validator
         # inside the same window; the sub-spans keep their true walls
         # and ``finalize.overlap-saved`` carries the credit, so
@@ -2631,11 +2625,9 @@ class ClusterRunner:
         # before recover() returns — a mis-speculated fast-path replay
         # raises here, before any live step, with the audit validator
         # as an independent gate on the replayed state. Revive
-        # bookkeeping runs after verify in BOTH modes: a failed
-        # barrier/verify/audit leaves the subtasks marked dead so the
-        # failure is retryable, never silently "healthy".
-        overlap = (self.overlap_recovery if overlap_finalize is None
-                   else bool(overlap_finalize))
+        # bookkeeping runs after verify: a failed barrier/verify leaves
+        # the subtasks marked dead so the failure is retryable, never
+        # silently "healthy".
         # ``finalize`` is the chain's last span; its children below use
         # their own spans (the barrier's on whichever thread drains it).
         chain.switch("finalize")
@@ -2658,9 +2650,9 @@ class ClusterRunner:
             packed_f = jnp.concatenate(pieces)        # dispatch only
         phases["finalize.barrier-dispatch"] = (
             phases.get("finalize.barrier-dispatch", 0.0) + disp.ms)
-        barrier: Dict[str, Any] = {"arr": None, "err": None, "ms": 0.0}
+        barrier: Dict[str, Any] = {"arr": None, "err": None, "span": None}
 
-        def _drain_barrier(parent=None) -> None:
+        def _drain_barrier(parent) -> None:
             with tr.attach(parent):
                 with tr.span("recovery.finalize.barrier-read",
                              drill=drill) as sp:
@@ -2668,7 +2660,7 @@ class ClusterRunner:
                         barrier["arr"] = np.asarray(packed_f)
                     except Exception as err:  # surfaces at the join below
                         barrier["err"] = err
-            barrier["ms"] = sp.ms
+            barrier["span"] = sp
 
         def _verify(arr_f: np.ndarray) -> int:
             verified_records = 0
@@ -2681,7 +2673,7 @@ class ClusterRunner:
                 if self._ring_mirror_valid:
                     for ri in range(nrings):
                         want = (self._ring_tail_mirror,
-                                self.executor._steps_executed)
+                                len(self.executor.step_input_history))
                         got = (int(bounds_np[ri, 0]),
                                int(bounds_np[ri, 1]))
                         if got != want:
@@ -2752,7 +2744,7 @@ class ClusterRunner:
             if not drill:
                 self.coordinator.reset_interval()
 
-        def _audit() -> float:
+        def _audit():
             # Audit validation (obs/audit.py): recompute every replayed
             # closed epoch's digest from the patched carry and compare
             # against the sealed ledger — one match/divergence instant
@@ -2760,8 +2752,9 @@ class ClusterRunner:
             # policy raises AuditDivergenceError here: fail loudly
             # before the job resumes on state that did not reproduce
             # the original execution.
+            # Returns its span (None with the audit off).
             if not self.auditor.enabled:
-                return 0.0
+                return None
             with tr.span("recovery.audit", drill=drill) as sp:
                 validator = rec.AuditValidator(
                     self.executor, self.coordinator.read_ledger(),
@@ -2775,75 +2768,63 @@ class ClusterRunner:
                     self._m_audit_matches.inc(validator.stats["match"])
                     self._m_audit_div.inc(validator.stats["divergence"])
             phases["audit"] = phases.get("audit", 0.0) + sp.ms
-            return sp.ms
+            return sp
 
-        audit_ms = 0.0
+        audit_span = None
         audit_err: Optional[Exception] = None
-        if overlap:
-            th = threading.Thread(target=_drain_barrier, args=(fin_span,),
-                                  name="recovery-finalize-barrier")
-            th.start()
-            # Host-side finalize work folded into the barrier window:
-            # the audit validator's digest recompute reads the same
-            # patched carry the packed read waits on (its transfers
-            # interleave with the barrier d2h instead of queuing after
-            # it). Revive bookkeeping does NOT fold in: it must stay
-            # after the join + state-verify below, exactly as in the
-            # sequential control — if the packed read or a deferred
-            # assert raises, self.failed and the heartbeat table must
-            # still mark the subtasks dead so a retry of recover()
-            # sees them. An audit divergence is held and re-raised
-            # after verify (the control's diagnostic order: a verify
-            # failure wins), and the join runs unconditionally so the
-            # barrier thread never outlives this call.
-            t_a0 = _time.monotonic()
-            try:
-                audit_ms = _audit()
-            except Exception as err:
-                audit_err = err
-                audit_ms = (_time.monotonic() - t_a0) * 1e3
-            finally:
-                # KeyboardInterrupt/SystemExit skip the deferral but
-                # still land here: the thread never leaks.
-                th.join()
-        else:
-            _drain_barrier()
+        th = threading.Thread(target=_drain_barrier, args=(fin_span,),
+                              name="recovery-finalize-barrier")
+        th.start()
+        # Host-side finalize work folded into the barrier window: the
+        # audit validator's digest recompute reads the same patched
+        # carry the packed read waits on (its transfers interleave with
+        # the barrier d2h instead of queuing after it). Revive
+        # bookkeeping does NOT fold in: it must stay after the join +
+        # state-verify below — if the packed read or a deferred assert
+        # raises, self.failed and the heartbeat table must still mark
+        # the subtasks dead so a retry of recover() sees them. An audit
+        # divergence is held and re-raised after verify (a verify
+        # failure wins), and the join runs unconditionally so the
+        # barrier thread never outlives this call.
+        try:
+            audit_span = _audit()
+        except Exception as err:
+            audit_err = err
+        finally:
+            # KeyboardInterrupt/SystemExit skip the deferral but
+            # still land here: the thread never leaks.
+            th.join()
         if barrier["err"] is not None:
             raise barrier["err"]
+        read = barrier["span"]
         phases["finalize.barrier-read"] = (
-            phases.get("finalize.barrier-read", 0.0) + barrier["ms"])
+            phases.get("finalize.barrier-read", 0.0) + read.ms)
         with tr.span("recovery.finalize.state-verify", drill=drill) as sp:
             total_records += _verify(barrier["arr"])
         verify_ms = sp.ms
         phases["finalize.state-verify"] = (
             phases.get("finalize.state-verify", 0.0) + verify_ms)
-        # The chain adds the window's wall; the audit that ran inside it
-        # (overlapped mode) has its own key.
         chain.close()
-        fin_ms = phases["finalize"] - fin_before - audit_ms
-        phases["finalize"] = fin_before + fin_ms
-        if overlap:
-            # Same safety order as the control: verify passed, NOW the
-            # subtasks may be marked healthy; a deferred audit
-            # divergence propagates after revive, exactly where the
-            # sequential path would raise it.
-            _revive()
-            if audit_err is not None:
-                raise audit_err
-            # Unclamped, this is min(audit wall, barrier wall) — both
-            # sub-spans keep their true walls while the window paid
-            # only the longer of the two; revive runs outside the
-            # window in both modes so no wall hides in the clamp
-            # (which only absorbs sub-ms thread-start jitter).
-            phases["finalize.overlap-saved"] = (
-                phases.get("finalize.overlap-saved", 0.0)
-                + max(0.0, disp.ms + barrier["ms"] + verify_ms - fin_ms))
-        else:
-            # Sequential control keeps the old order: barrier-read →
-            # state-verify → revive → audit (and never writes the
-            # overlap-saved key — its absence marks the control path).
-            _revive()
-            audit_ms = _audit()
+        # ``finalize`` and ``finalize.overlap-saved`` are derived from
+        # the sub-spans' own stamps, not from the window's wall, so
+        # sum(finalize.*) - overlap-saved == finalize holds exactly: the
+        # barrier read is on the critical path only for the part that
+        # ran after the audit (the main thread's work in the window)
+        # had ended; what ran under the audit is the saving. The audit
+        # has its own key.
+        exposed_ms = _exposed_ms(
+            read.mono, read.mono + read.dur,
+            read.mono if audit_span is None
+            else audit_span.mono + audit_span.dur)
+        phases["finalize"] = fin_before + disp.ms + exposed_ms + verify_ms
+        # Verify passed, NOW the subtasks may be marked healthy; a held
+        # audit divergence propagates after revive.
+        _revive()
+        if audit_err is not None:
+            raise audit_err
+        phases["finalize.overlap-saved"] = (
+            phases.get("finalize.overlap-saved", 0.0)
+            + read.ms - exposed_ms)
         report = RecoveryReport(
             failed_subtasks=failed, from_epoch=from_epoch,
             steps_replayed=n_steps, determinants_replayed=total_dets,
@@ -3047,10 +3028,9 @@ class ClusterRunner:
                 pass
         # AOT-lower the standby's first-step (block) program into the
         # persistent compile cache too. A rehydrated standby's first
-        # dispatch after restore is then a cache hit, not the
-        # finalize-tail recompile BENCH_r05 attributes ~448 ms to; a
-        # program that does not compile fails the prewarm here, not
-        # the failover later.
+        # dispatch after restore is then a cache hit, not a recompile
+        # in the finalize tail; a program that does not compile fails
+        # the prewarm here, not the failover later.
         from clonos_tpu.utils.compile_cache import aot_lower_first_step
         aot_lower_first_step(self.executor, self._mgroup)
         return _time.monotonic() - t0

@@ -855,9 +855,6 @@ class LocalExecutor:
         #: metadata round-trip — the device parse still validates it, but
         #: as a deferred assert folded into recovery's final read.
         self.async_counts: Dict[Tuple[int, int], int] = {}
-        #: supersteps actually executed (the staged epoch path pre-fills
-        #: step_input_history, so len(history) over-counts mid-epoch).
-        self._steps_executed = 0
         # Explicit shardings for every jitted entry point when a mesh is
         # attached: the carry rides its rule-driven NamedSharding tree
         # (parallel/distributed.py rules — the SAME table the in-trace
@@ -1046,24 +1043,6 @@ class LocalExecutor:
                 (_ns0(c0.logs.rows), _ns0(c0.logs.head),
                  _ns0(c0.replicas.rows), _ns0(c0.replicas.head))))
 
-        bs = self.block_steps
-
-        def _staged_run(carry, t_all, r_all, lo, epoch, g0):
-            # Staging (slice this block's inputs from the epoch-wide
-            # uploaded time/rng streams, cursor carried on device) FUSED
-            # with the block program itself: one dispatch per block, not
-            # two — the staged epoch loop is the steady-state hot path.
-            bi = BlockInputs(
-                times=jax.lax.dynamic_slice(t_all, (lo,), (bs,)),
-                rng_bits=jax.lax.dynamic_slice(r_all, (lo,), (bs,)),
-                epoch=epoch, step0=g0 + lo, feeds=())
-            carry, outs = self.compiled.run_block(carry, bi)
-            return carry, outs, lo + bs
-
-        self._jit_staged_run = jax.jit(
-            _staged_run, donate_argnums=0,
-            **_mesh_kw((self._carry_ns,) + (self._repl_ns,) * 5))
-
     def register_feed(self, vertex_id: int, reader) -> None:
         """Attach a rewindable reader (api/feeds.py) to a HostFeedSource
         vertex — the external-system ingestion boundary."""
@@ -1136,11 +1115,10 @@ class LocalExecutor:
                            step0=step0, feeds=self._pull_feeds(k))
 
     def _notify_block(self) -> None:
-        # Uses the last EXECUTED step's time/stamp — the staged epoch path
-        # pre-fills step_input_history, so [-1] would be the epoch end.
-        if self.block_listeners and self._steps_executed:
+        # The time/stamp of the last executed step.
+        if self.block_listeners and self.step_input_history:
             with get_tracer().span("block.notify"):
-                t = self.step_input_history[self._steps_executed - 1][0]
+                t = self.step_input_history[-1][0]
                 stamp = self.global_record_stamp()
                 for fn in self.block_listeners:
                     fn(t, stamp)
@@ -1164,7 +1142,6 @@ class LocalExecutor:
 
     def _block_done(self, k: int) -> None:
         self.step_in_epoch += k
-        self._steps_executed += k
         if self.on_block_outputs is not None:
             self.on_block_outputs(self._block_outs, self.epoch_id)
         self._notify_block()
@@ -1187,29 +1164,6 @@ class LocalExecutor:
         """Run the remainder of the current epoch in block programs, then
         roll the epoch (the checkpoint fence lands here)."""
         tr = get_tracer()
-        remaining = self.steps_per_epoch - self.step_in_epoch
-        full_blocks = remaining // self.block_steps
-        if full_blocks > 1 and not self.compiled.feed_vertices:
-            # Stage the full blocks' causal inputs in ONE upload and carry
-            # the block cursor on device — no per-block host transfer.
-            n = full_blocks * self.block_steps
-            with tr.span("block.causal-inputs", staged=n):
-                g0 = len(self.step_input_history)
-                times, rngs = self._draw_causal_inputs(n)
-                t_all = jnp.asarray(times)
-                r_all = jnp.asarray(rngs)
-                lo = jnp.asarray(0, jnp.int32)
-                epoch = jnp.asarray(self.epoch_id, jnp.int32)
-                g0_d = jnp.asarray(g0, jnp.int32)
-            for _ in range(full_blocks):
-                with tr.span("block", epoch=self.epoch_id,
-                             k=self.block_steps, program="staged_run"):
-                    with tr.span("block.dispatch"):
-                        self.carry, self._block_outs, lo = \
-                            self._jit_staged_run(self.carry, t_all, r_all,
-                                                 lo, epoch, g0_d)
-                    tr.count("block.dispatches.staged_run")
-                    self._block_done(self.block_steps)
         while self.step_in_epoch < self.steps_per_epoch:
             self._host_block(min(
                 self.block_steps, self.steps_per_epoch - self.step_in_epoch))
@@ -1386,7 +1340,7 @@ class LocalExecutor:
 
     def spill_stats(self) -> Dict[str, Any]:
         """Cumulative spill/refill movement counters summed across
-        stores (bench ``--spill`` fields)."""
+        stores."""
         agg: Dict[str, Any] = {}
         for st in self._tier_stores():
             for k, v in st.stats().items():
@@ -1589,7 +1543,7 @@ class LocalExecutor:
 
     def global_record_stamp(self) -> int:
         """Monotone nonzero stamp for async rows (1 + supersteps run)."""
-        return self._steps_executed + 1
+        return len(self.step_input_history) + 1
 
     def async_rows_since(self, flat_subtask: int, from_epoch: int) -> int:
         """How many async determinant rows this task's log holds in epochs

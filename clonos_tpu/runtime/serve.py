@@ -901,7 +901,6 @@ class ServeTier:
 def build_serve_tier(runner, vertex_id: int, n_replicas: int = 2,
                      staleness_bound: int = 2,
                      state: str = "acc") -> ServeTier:
-    """Convenience assembly used by bench --serve, the soak serve load,
-    and tests."""
+    """Convenience assembly used by the soak serve load and tests."""
     return ServeTier(runner, vertex_id, n_replicas=n_replicas,
                      staleness_bound=staleness_bound, state=state)

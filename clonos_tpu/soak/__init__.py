@@ -21,8 +21,7 @@ from .slo import (SLOSpec, SLOTracker, Window,  # noqa: F401
 from .driver import (SoakConfig, SoakDriver, SoakHarness,  # noqa: F401
                      build_soak_fixture, default_kill_targets,
                      next_autoscale_artifact_path,
-                     next_rescale_artifact_path,
-                     next_serve_artifact_path, next_soak_artifact_path)
+                     next_soak_artifact_path)
 from .serveload import ServeLoad  # noqa: F401
 
 __all__ = ["ChaosEvent", "ChaosSchedule", "parse_schedule",
@@ -30,6 +29,5 @@ __all__ = ["ChaosEvent", "ChaosSchedule", "parse_schedule",
            "corrected_closed_loop",
            "SoakConfig", "SoakDriver", "SoakHarness",
            "build_soak_fixture", "default_kill_targets",
-           "next_soak_artifact_path", "next_serve_artifact_path",
-           "next_rescale_artifact_path",
+           "next_soak_artifact_path",
            "next_autoscale_artifact_path", "ServeLoad"]

@@ -105,8 +105,7 @@ class SoakHarness:
         self.faults_survived = 0
         self.by_kind: Dict[str, int] = {}
         self.recoveries_ms: List[float] = []
-        #: per-kill overlapped-recovery evidence: every chaos kill runs
-        #: the overlapped finalize tail, so each appends its
+        #: per-kill recovery evidence: each chaos kill appends its
         #: finalize.overlap-saved attribution and the immediate
         #: post-recovery ledger re-diff vs the control twin (must stay
         #: empty — a mis-speculated replay is caught HERE, before the
@@ -174,12 +173,12 @@ class SoakHarness:
         ms = (_time.monotonic() - t0) * 1e3
         self.recoveries_ms.append(ms)
         self.faults_survived += 1
-        # Overlapped-tail acceptance, under fire: record the kill's
-        # finalize.overlap-saved attribution (present == the overlapped
-        # pipeline ran) and re-diff the ledger against the fault-free
-        # control twin IMMEDIATELY — not only at the next fence — so a
-        # mis-speculated replay is caught before the job resumes.
-        saved = report.phase_ms.get("finalize.overlap-saved", 0.0)
+        # Finalize acceptance, under fire: record the kill's
+        # finalize.overlap-saved attribution and re-diff the ledger
+        # against the fault-free control twin IMMEDIATELY — not only at
+        # the next fence — so a mis-speculated replay is caught before
+        # the job resumes.
+        saved = report.phase_ms["finalize.overlap-saved"]
         self.kill_overlap_saved_ms.append(round(saved, 1))
         rediff = self.audit_check()
         self.kill_rediff_problems += len(rediff)
@@ -736,7 +735,7 @@ class SoakDriver:
         return verdict
 
     def _run_paced(self, cfg, r, h, ex, spe, max_epochs):
-        # Warmup epoch 0 via run_epoch (staged program + restore point),
+        # Warmup epoch 0 via run_epoch (block program + restore point),
         # epoch 1 via step() chunks (the K=1 live program the paced loop
         # uses compiles here, off the measured clock).
         r.run_epoch(complete_checkpoint=True)
@@ -1150,7 +1149,7 @@ def default_kill_targets(job) -> List[int]:
 
 
 def next_soak_artifact_path(root: Optional[str] = None) -> str:
-    """Next free ``SOAK_r0N.json`` slot next to the BENCH artifacts."""
+    """Next free ``SOAK_r0N.json`` slot."""
     root = root or os.getcwd()
     n = 1
     while os.path.exists(os.path.join(root, f"SOAK_r{n:02d}.json")):
@@ -1158,29 +1157,9 @@ def next_soak_artifact_path(root: Optional[str] = None) -> str:
     return os.path.join(root, f"SOAK_r{n:02d}.json")
 
 
-def next_serve_artifact_path(root: Optional[str] = None) -> str:
-    """Next free ``SERVE_r0N.json`` slot (the ``bench --serve``
-    verdict artifact, sibling of SOAK/BENCH)."""
-    root = root or os.getcwd()
-    n = 1
-    while os.path.exists(os.path.join(root, f"SERVE_r{n:02d}.json")):
-        n += 1
-    return os.path.join(root, f"SERVE_r{n:02d}.json")
-
-
-def next_rescale_artifact_path(root: Optional[str] = None) -> str:
-    """Next free ``RESCALE_r0N.json`` slot (the ``bench --rescale``
-    verdict artifact, sibling of SOAK/BENCH/SERVE)."""
-    root = root or os.getcwd()
-    n = 1
-    while os.path.exists(os.path.join(root, f"RESCALE_r{n:02d}.json")):
-        n += 1
-    return os.path.join(root, f"RESCALE_r{n:02d}.json")
-
-
 def next_autoscale_artifact_path(root: Optional[str] = None) -> str:
     """Next free ``AUTOSCALE_r0N.json`` slot (the ``soak --autoscale``
-    closed-loop verdict artifact, sibling of SOAK/BENCH/SERVE)."""
+    closed-loop verdict artifact, sibling of SOAK)."""
     root = root or os.getcwd()
     n = 1
     while os.path.exists(os.path.join(root,

@@ -23,7 +23,7 @@ The writer is double-buffered by construction: the bounded queue lets
 the fence stage epoch N+1 while the thread is still flushing epoch N;
 ``drain`` joins the queue for tests/shutdown. Spill and refill time is
 attributed to the profiler's ``ft`` sections (``spill-write``,
-``refill``) so ``bench --ablate`` prices the tiers, and the bandwidth
+``refill``) so an ablation run prices the tiers, and the bandwidth
 counters feed the ``spill.*`` gauges ``clonos_tpu top`` renders.
 
 Audit composition: sealed epochs are already digest-chained into the
@@ -95,7 +95,7 @@ class TieredEpochStore:
             os.makedirs(spool_dir, exist_ok=True)
         self._epochs: Dict[int, _Epoch] = {}
         self._lock = threading.Lock()
-        # Bandwidth/occupancy counters (spill.* gauges; bench --spill).
+        # Bandwidth/occupancy counters (spill.* gauges).
         self.bytes_spilled = 0
         self.bytes_refilled = 0
         self.spill_seconds = 0.0
@@ -348,7 +348,7 @@ class TieredEpochStore:
                 "disk_epochs": disk_e, "disk_bytes": disk_b}
 
     def stats(self) -> Dict[str, Any]:
-        """Cumulative movement counters (bench --spill fields)."""
+        """Cumulative movement counters."""
         with self._lock:
             return {
                 "bytes_spilled": self.bytes_spilled,

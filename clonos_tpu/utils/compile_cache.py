@@ -7,8 +7,8 @@ deploy analog survives process restarts), and the first-step executable
 standby's ``finalize.first-step-recompile`` a cache hit.
 
 :func:`enable_compile_cache` is the only place the directory is chosen.
-Entry points call it once at start (``bench.py``, ``chip_smoke.py``,
-``cli run|worker|slotworker``, ``tests/conftest.py``); nothing below
+Entry points call it once at start (``benchmark/run.py``,
+``chip_smoke.py``, ``cli run|worker|slotworker``, ``tests/conftest.py``); nothing below
 them re-points it. JAX's entry key covers the program, its compile
 options and its device assignment, so sharded and unsharded programs
 share the directory without colliding.
@@ -46,9 +46,8 @@ def aot_lower_first_step(executor, metric_group: Optional[Any] = None
     """Ahead-of-time lower + compile the standby's FIRST-STEP program —
     the block program a rehydrating standby dispatches before anything
     else — so its executable is in the persistent cache (and XLA's
-    in-process cache) before any failure happens. BENCH_r05 puts
-    first-step-recompile inside the dominant ~448 ms finalize tail; a
-    cache hit removes it.
+    in-process cache) before any failure happens: the recovery's
+    ``finalize.first-step-recompile`` is then a cache hit.
 
     Lowering uses the executor's live carry avals + shardings (no
     execution, no donation — ``lower`` only traces). Returns the

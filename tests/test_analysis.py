@@ -375,6 +375,33 @@ def test_census_fingerprint_tracks_source_changes(tmp_path):
     assert fingerprint(c1) != fingerprint(c2)
 
 
+def test_census_fingerprint_survives_a_shifted_line(tmp_path):
+    """A blank line above a call site moves its line number, in the
+    census, and nothing in what the pin hashes."""
+    src = """\
+        class Op:
+            def process_block(self, state, ins, ctx):
+                return state + ctx.times
+
+        def fence(services):
+            return services.current_time_millis()
+        """
+    c1 = build_census([_ctx(tmp_path, "m.py", src)])
+    c2 = build_census([_ctx(tmp_path, "m.py", src.replace(
+        "        def fence", "\n\n        def fence"))])
+    (s1,), (s2,) = c1["service_call_sites"], c2["service_call_sites"]
+    assert s1["callee"] == "current_time_millis"
+    assert s2["line"] == s1["line"] + 2
+    assert c1 != c2 and fingerprint(c1) == fingerprint(c2)
+    # ... while a second call in the same function is a new site
+    c3 = build_census([_ctx(tmp_path, "m.py", src.replace(
+        "return services.current_time_millis()",
+        "return (services.current_time_millis(),\n"
+        "                    services.current_time_millis())"))])
+    assert len(c3["service_call_sites"]) == 2
+    assert fingerprint(c3) != fingerprint(c1)
+
+
 def test_static_cost_model_scales_linearly():
     census = run_analysis().census
     m1 = static_cost_model(census, steps_per_epoch=100, subtasks=8,

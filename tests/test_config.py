@@ -43,3 +43,28 @@ def test_merge_and_raw():
     assert m.to_dict() == {"x": 2, "y": 3}
     opt = ConfigOption("x", 0)
     assert m.get(opt) == 2
+
+
+def test_every_default_option_has_a_reader():
+    """An option someone can set and nothing reads is a lie in the API:
+    every ``ConfigOption`` of ``config/defaults.py`` is named, as an
+    attribute (``D.<NAME>``), by program code under ``clonos_tpu/``."""
+    import os
+    import re
+
+    root = os.path.dirname(os.path.abspath(defaults.__file__))
+    pkg = os.path.dirname(root)
+    names = [n for n, v in vars(defaults).items()
+             if isinstance(v, ConfigOption)]
+    assert names
+    source = []
+    for d, _dirs, files in os.walk(pkg):
+        for f in files:
+            path = os.path.join(d, f)
+            if f.endswith(".py") and path != defaults.__file__:
+                with open(path) as fh:
+                    source.append(fh.read())
+    source = "\n".join(source)
+    unread = [n for n in names
+              if not re.search(r"\.%s\b" % n, source)]
+    assert not unread, f"ConfigOptions nothing reads: {unread}"

@@ -147,3 +147,31 @@ def test_scan_epoch_equals_stepwise():
     flat_b, _ = jax.tree_util.tree_flatten(b)
     for xa, xb in zip(flat_a, flat_b):
         np.testing.assert_array_equal(np.asarray(xa), np.asarray(xb))
+
+
+@pytest.mark.parametrize("block_steps", [2, 3], ids=["quarter", "remainder"])
+def test_device_source_job_is_the_same_job_at_any_block_size(
+        tmp_path, block_steps):
+    """One block loop for every job: a job without a feed vertex draws
+    its causal inputs per block, from the same stream in the same order,
+    so under logical time its state, its determinant logs and their heads
+    after two epochs do not depend on how an epoch is cut into blocks
+    (whole epoch / four full blocks / blocks with a remainder)."""
+    from clonos_tpu.runtime.cluster import ClusterRunner
+
+    def run(tag, bs):
+        r = ClusterRunner(_build_wordcount(parallelism=2, window=5),
+                          steps_per_epoch=8, block_steps=bs,
+                          log_capacity=512, max_epochs=8,
+                          inflight_ring_steps=32, seed=3, logical_time=True,
+                          checkpoint_dir=str(tmp_path / tag))
+        r.run_epoch(complete_checkpoint=True)
+        r.run_epoch(complete_checkpoint=False)
+        logs = r.executor.carry.logs
+        return (r.state_digest(), np.asarray(logs.head).tolist(),
+                list(r.executor.step_input_history))
+
+    whole, cut = run("whole", 8), run("cut", block_steps)
+    assert whole[1] == cut[1] and min(whole[1]) >= 16 * DETS_PER_STEP
+    assert whole[2] == cut[2] and len(cut[2]) == 16
+    assert whole[0] == cut[0]

@@ -5,12 +5,12 @@ every RecoveryReport.phase_ms:
     sum(finalize.* sub-spans) - finalize.overlap-saved == finalize
 
 Sub-spans keep their true wall durations (what each piece of work
-cost); ``finalize`` is the critical-path wall the job actually waited;
-``finalize.overlap-saved`` is the difference the worker-thread overlap
-bought. The sequential control path (``overlap_finalize=False``) keeps
-the strict partition and never writes the overlap key — its absence
-marks a control run. Wired next to the conftest lint/analyze gates:
-this file is tier-1, so any accounting regression fails CI fast.
+cost); ``finalize`` is the part of them on the critical path;
+``finalize.overlap-saved`` is the part that ran under other work. Both
+are derived from the sub-spans' own stamps, so the identity is exact:
+no tolerance on a wall-clock sum here. Wired next to the conftest
+lint/analyze gates: this file is tier-1, so any accounting regression
+fails CI fast.
 """
 
 import numpy as np
@@ -19,13 +19,15 @@ import pytest
 from clonos_tpu import obs
 
 
-def _finalize_identity(pm, rel=0.15, abs_ms=2.0):
+def _finalize_identity(pm):
     subs = {k: v for k, v in pm.items()
             if k.startswith("finalize.") and k != "finalize.overlap-saved"}
-    saved = pm.get("finalize.overlap-saved", 0.0)
+    saved = pm["finalize.overlap-saved"]
     assert saved >= 0.0
+    assert all(v >= 0.0 for v in subs.values())
+    # exact by construction; 1e-6 ms is float rounding, not a tolerance
     assert sum(subs.values()) - saved == pytest.approx(
-        pm["finalize"], rel=rel, abs=abs_ms), (
+        pm["finalize"], rel=0, abs=1e-6), (
         f"finalize attribution broke: subs={subs} saved={saved} "
         f"finalize={pm['finalize']}")
     return subs, saved
@@ -41,27 +43,49 @@ def _window_job(name):
     return env.build()
 
 
-def test_recover_overlap_and_sequential_keep_the_identity(tmp_path):
+def _two_epoch_runner(name, tmp_path, **kw):
     from clonos_tpu.runtime.cluster import ClusterRunner
 
-    obs.configure("phases")
-    r = ClusterRunner(_window_job("ph"), steps_per_epoch=8,
+    r = ClusterRunner(_window_job(name), steps_per_epoch=8,
                       log_capacity=512, max_epochs=8,
                       inflight_ring_steps=32, seed=3,
-                      checkpoint_dir=str(tmp_path / "ck"))
+                      checkpoint_dir=str(tmp_path / "ck"), **kw)
     r.run_epoch(complete_checkpoint=True)
     r.run_epoch(complete_checkpoint=False)
+    return r
 
-    r.inject_failure([2 + 1])
-    pm = r.recover().phase_ms                  # overlapped (the default)
-    assert "finalize.overlap-saved" in pm
-    subs, _saved = _finalize_identity(pm)
-    assert {"finalize.barrier-read", "finalize.state-verify"} <= set(subs)
 
+def test_recover_keeps_the_identity_and_repeats_it(tmp_path):
+    obs.configure("phases")
+    r = _two_epoch_runner("ph", tmp_path)
+    for _ in range(2):
+        r.inject_failure([2 + 1])
+        subs, _saved = _finalize_identity(r.recover().phase_ms)
+        assert set(subs) == {"finalize.barrier-dispatch",
+                             "finalize.barrier-read",
+                             "finalize.state-verify"}
+
+
+@pytest.mark.parametrize("drill", [False, True], ids=["live", "drill"])
+@pytest.mark.parametrize("audit", [False, True], ids=["plain", "audited"])
+def test_every_report_carries_overlap_saved_and_the_exact_identity(
+        tmp_path, drill, audit):
+    """A live ``recover()`` and a rehearsal, with and without the audit
+    validator inside the barrier's window: the report always has
+    ``finalize.overlap-saved``, the saving is never more than the
+    barrier read it is a part of, and without an audit there is nothing
+    the read could have run under."""
+    r = _two_epoch_runner("phid", tmp_path, audit=audit)
     r.inject_failure([2 + 1])
-    cm = r.recover(overlap_finalize=False).phase_ms   # sequential control
-    assert "finalize.overlap-saved" not in cm
-    _finalize_identity(cm)
+    report = r.recover(drill=drill)
+    assert report.drill is drill
+    pm = report.phase_ms
+    _subs, saved = _finalize_identity(pm)
+    assert saved <= pm["finalize.barrier-read"] + 1e-9
+    if audit:
+        assert pm["audit"] > 0.0
+    else:
+        assert saved == 0.0 and "audit" not in pm
 
 
 def test_bootstrap_standby_folds_overlap_into_the_identity(tmp_path):

@@ -287,45 +287,66 @@ def test_standby_pool_completion_is_monotonic():
 # --- metrics history: pacing under load + torn tail --------------------------
 
 
-def test_history_interval_holds_under_slow_sampler(tmp_path):
-    """Absolute-deadline pacing: a sample_fn that takes a large slice
-    of the interval must NOT stretch the period (the old wait-then-
-    sample loop ran at interval + sample_time)."""
+class _VirtualTime:
+    """The sampling loop's clock and its stop event in one: time passes
+    only where the loop waits (``wait``) or the sampler works
+    (``spend``), and the loop is told to stop once ``until`` is
+    reached. The loop then runs on the test's own thread, to its end."""
+
+    def __init__(self, until):
+        self.now, self.until = 0.0, until
+
+    def mono(self):
+        return self.now
+
+    def spend(self, seconds):
+        self.now += seconds
+
+    def wait(self, timeout):            # threading.Event.wait
+        self.now += timeout
+        return self.now >= self.until
+
+    def set(self):                      # threading.Event.set, by close()
+        self.until = self.now
+
+
+def _paced_history(vt, sample_s, interval_s=0.05):
     from clonos_tpu.obs.history import MetricsHistory
 
-    def slow_sample():
-        time.sleep(0.03)
+    def sample():
+        vt.spend(sample_s)
         return {"x": 1}
 
-    h = MetricsHistory(sample_fn=slow_sample, interval_s=0.05,
-                       window=64)
-    h.start()
-    time.sleep(0.53)
+    h = MetricsHistory(sample_fn=sample, interval_s=interval_s, window=64,
+                       clock=vt.mono, mono=vt.mono)
+    h._stop = vt
+    h._loop()
     h.close()
-    n = len(h.query())
-    # drift pacing would deliver ~6 samples in 0.53s (0.08s period);
-    # deadline pacing ~10. Assert safely above the drifted count.
-    assert n >= 8, f"only {n} samples: interval drifted under load"
+    return h
+
+
+def test_history_interval_holds_under_slow_sampler():
+    """Absolute-deadline pacing: a sample_fn that takes a large slice
+    of the interval must NOT stretch the period (the old wait-then-
+    sample loop ran at interval + sample_time). On a virtual clock: the
+    count is exact, and no real time passes."""
+    h = _paced_history(_VirtualTime(until=0.53), sample_s=0.03)
+    ts = [r["ts"] for r in h.query()]
+    # drift pacing would deliver 6 samples in 0.53 s (a 0.08 s period);
+    # deadline pacing 10, one per 0.05 s slot
+    assert len(ts) == 10
+    assert ts == pytest.approx([0.05 * (i + 1) + 0.03 for i in range(10)])
     assert h.missed_slots == 0
 
 
 def test_history_counts_missed_slots_instead_of_bursting():
-    from clonos_tpu.obs.history import MetricsHistory
-
-    def very_slow_sample():
-        time.sleep(0.12)
-        return {}
-
-    h = MetricsHistory(sample_fn=very_slow_sample, interval_s=0.05,
-                       window=64)
-    h.start()
-    time.sleep(0.5)
-    h.close()
-    samples = h.query()
-    assert h.missed_slots >= 2
+    h = _paced_history(_VirtualTime(until=0.5), sample_s=0.12)
+    # every 0.12 s sample overruns two 0.05 s slots: re-anchored on the
+    # next slot, so one sample per 0.15 s
+    ts = [r["ts"] for r in h.query()]
+    assert h.missed_slots == 2 * len(ts) and len(ts) == 3
     # no catch-up burst: consecutive samples stay >= one sample time
-    ts = [r["ts"] for r in samples]
-    assert all(b - a >= 0.1 for a, b in zip(ts, ts[1:]))
+    assert all(b - a >= 0.12 for a, b in zip(ts, ts[1:]))
 
 
 def test_history_file_torn_tail_readable_mid_run(tmp_path):

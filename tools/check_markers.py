@@ -1,6 +1,5 @@
 #!/usr/bin/env python
-"""Shim over ``clonos_tpu.lint.markers`` (the ``replay_dissect`` ->
-``dissect`` precedent): the marker registry and the scan both moved
+"""Shim over ``clonos_tpu.lint.markers``: the marker registry and the scan both moved
 into the lint package as the ``markers`` rule, where
 ``clonos_tpu lint tests/`` and tests/conftest.py share them. This file
 keeps the historical entry point — ``python tools/check_markers.py``
