@@ -8,10 +8,11 @@ committed sink out — on whatever TPU JAX finds, with nothing that lets
 it pass off the device:
 
   K  the kernels alone at deployment width: the Pallas histogram against
-     the XLA scatter and NumPy, the three exchange routes (kernel /
-     scatter / sort) against the per-step exchange, the MXU one-hot
-     gather against NumPy over the whole int32 range, and that
-     ``jax.block_until_ready`` returns only when the work is done.
+     the XLA scatter and NumPy at the cells' call shapes (and which form
+     each call took: the ``hist.kernel`` instants), the three exchange
+     routes (kernel / scatter / sort) against the per-step exchange, the
+     MXU one-hot gather against NumPy over the whole int32 range, and
+     that ``jax.block_until_ready`` returns only when the work is done.
   A  the served path, host-fed, at config4's recorded width
      (``BASELINE.json.configs[3]``): 64 subtasks,
      a cascading kill of one source, one window and one reduce subtask
@@ -33,6 +34,7 @@ stdout is ``{"ok": true, "device": {...}}``.
 """
 
 import argparse
+import collections
 import dataclasses
 import gc
 import json
@@ -332,27 +334,36 @@ def check_kernels(seed: int) -> None:
         if not np.array_equal(np.asarray(a), np.asarray(b)):
             raise AssertionError(f"{what}: results differ")
 
-    # Histogram: kernel == scatter == NumPy, at the headline's key count
-    # and at the widest table the exchange may hand the kernel.
-    for shp, nk in (((64, 8, 300), 997), ((16, 1024), KERNEL_MAX_KEYS)):
+    # Histogram: kernel == scatter == NumPy modulo 2**32, values over
+    # the whole int32 range, at the call shapes of the benchmark's cells
+    # (a step's fold, an exchange's placement, the event-time windows'
+    # slot x key lanes) and at the widest table callers may hand it.
+    for shp, nk in (((64, 8, 300), 997), ((16, 1024), KERNEL_MAX_KEYS),
+                    ((1024, 8, 640), 499), ((512, 512), 8192),
+                    ((1024, 8, 1152), 997), ((1024, 1024), 8192),
+                    ((1024, 8, 1408), 1400), ((1024, 1024), 4096)):
         keys = rng.randint(-3, nk + 5, shp).astype(np.int32)
-        vals = rng.randint(-(1 << 20), 1 << 20, shp).astype(np.int32)
+        vals = rng.randint(-(1 << 31), 1 << 31, shp,
+                           dtype=np.int64).astype(np.int32)
         valid = rng.rand(*shp) < 0.7
-        s1, c1 = keyed_hist(jnp.asarray(keys), jnp.asarray(vals),
-                            jnp.asarray(valid), nk, force="pallas")
-        s2, c2 = keyed_hist(jnp.asarray(keys), jnp.asarray(vals),
-                            jnp.asarray(valid), nk, force="xla")
+        args = tuple(map(jnp.asarray, (keys, vals, valid)))
+        s1, c1 = keyed_hist(*args, nk, force="pallas")
+        s2, c2 = keyed_hist(*args, nk, force="xla")
+        s4, _ = keyed_hist(*args, nk, force="pallas", want_counts=False)
         ok = valid & (keys >= 0) & (keys < nk)
         rows = np.broadcast_to(
             np.arange(int(np.prod(shp[:-1]))).reshape(shp[:-1] + (1,)),
             shp)
         s3 = np.zeros((rows.max() + 1, nk), np.int64)
-        np.add.at(s3, (rows[ok], keys[ok]), vals[ok])
+        np.add.at(s3, (rows[ok], keys[ok]), vals[ok].astype(np.int64))
+        s3 = (s3 & 0xFFFFFFFF).astype(np.uint32).view(np.int32)
         same(s1, s2, f"histogram sums nk={nk} kernel vs scatter")
         same(c1, c2, f"histogram counts nk={nk} kernel vs scatter")
-        same(np.asarray(s1).reshape(-1, nk), s3.astype(np.int32),
+        same(s4, s2, f"histogram sums nk={nk} sums-only kernel vs scatter")
+        same(np.asarray(s1).reshape(-1, nk), s3,
              f"histogram sums nk={nk} kernel vs NumPy")
-        say(f"K histogram {shp} nk={nk}: kernel == scatter == NumPy")
+        say(f"K histogram {shp} nk={nk}: kernel == scatter == NumPy, "
+            f"values over int32")
 
     # Exchange: each route against the per-step (sort) exchange. Shapes
     # are A's (16 x 32 records, 16 targets); what differs picks the route.
@@ -454,6 +465,13 @@ def print_routes(tracer, since: int, part: str) -> int:
         counts[line] = counts.get(line, 0) + 1
     for line, c in sorted(counts.items()):
         say(f"{part} exchange {line} (traced {c}x)")
+    kernels = collections.Counter(
+        f"[{a['rows']}, {a['cols']}] -> {a['lanes']} lanes, hi={a['hi']} "
+        f"planes={a['planes']}: {a['form']}"
+        for a in (r["args"] for r in recs[since:]
+                  if r["name"] == "hist.kernel"))
+    for line, c in sorted(kernels.items()):
+        say(f"{part} histogram {line} (traced {c}x)")
     return len(recs)
 
 
@@ -495,7 +513,7 @@ def main(argv=None) -> int:
         t0 = time.monotonic()
         check_kernels(args.seed)
         check_block_until_ready()
-        mark = len(tracer.records())
+        mark = print_routes(tracer, mark, "K")
         say(f"K pass ({time.monotonic() - t0:.1f}s)")
 
     shape = ServedShape()

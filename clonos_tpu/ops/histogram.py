@@ -6,15 +6,25 @@ The reference aggregates record-at-a-time into hash-keyed state
 update per record). The dense-table TPU design turns that into a per-step
 histogram ``contrib[row, key] = sum(values where keys == key)`` — but
 XLA's scatter-add serializes its updates on TPU (60-120ms at bench shapes
-for ~4M updates). This Pallas kernel streams the records through the VPU
-as chunked compare-accumulate instead: for each 128-record chunk, a
-``[rows, chunk, key_lanes]`` one-hot compare and an axis reduce — no
-scatter anywhere.
+for ~4M updates). This Pallas kernel factors the one-hot instead:
+``key = hi * 128 + lo``, so a row's table is the matrix product of a
+``[hi, records]`` one-hot that carries the values and a
+``[records, lo]`` one-hot. The VPU builds the two factors (``hi + 128``
+compares a record, where a compare per key lane made the kernel's time
+go with records x key lanes) and the MXU does the products, bit-exact
+modulo 2**32 over the whole int32 range through byte planes
+(:func:`_hist_kernel_mxu`) — no scatter anywhere.
+
+The kernel's traced body does not grow with the table: planes and key
+blocks are axes of one operand, column chunks a loop. Every program that
+holds the kernel traces, lowers and loads it again, so an unrolled body
+is paid in every process's set-up, compile cache or not.
 
 Which form runs is decided by the platform the program is lowered for,
 never by a failure: the kernel on a TPU, a bit-identical XLA scatter
 elsewhere (the CPU test lane; ``tests/test_pallas_kernels.py`` pins
-kernel == scatter in interpret mode).
+kernel == scatter == NumPy in interpret mode). :func:`keyed_hist` leaves
+a ``hist.kernel`` instant per call site traced, saying which.
 
 Mosaic kernels cannot be partitioned automatically, so a program lowered
 over a device mesh has to say so: it traces inside :func:`kernel_mesh`
@@ -27,6 +37,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -35,17 +46,30 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec
 
-#: rows per kernel program (VPU sublane count)
+from clonos_tpu.obs.trace import get_tracer
+
+#: rows of records one kernel program folds (sublanes of an int32 tile)
 _ROW_TILE = 8
-#: record columns per in-kernel chunk (VPU lane count)
-_COL_CHUNK = 128
+#: lanes of a tile: the ``lo`` half of a key, and the MXU's width
+_LANES = 128
+#: record columns one matrix product contracts, at most. A step's cost
+#: is mostly fixed (the MXU's fill and drain), so fewer and fatter steps
+#: win: on the v5e 1,024 read 0.55 ms where 512 read 0.72 and 256 0.85
+#: at ``[1024, 1024] -> 8192``; the stacked operand of the widest table
+#: (``[8, 5 x 128, 1024]`` bf16, 10 MiB) still fits the default scoped
+#: VMEM, at 2,048 it would not.
+_COL_CHUNK = 1024
+#: record columns one kernel program folds in f32 before its sums go to
+#: int32 (a byte plane's partial sum stays under 255 * 2,048 < 2**24, so
+#: it is exact). Longer rows walk the grid's second axis.
+_COL_BLOCK = 2048
+#: the ``hi`` half of the table is padded to this many rows a plane (the
+#: sublanes of an f32 tile, in which the planes are stacked)
+_HI_ALIGN = 8
 #: largest key count callers may hand the kernel (both variants compile
-#: for v5e up to here under _VMEM_LIMIT_BYTES — tests/test_tpu_aot.py).
+#: for the v5e up to here under the compiler's default scoped-VMEM
+#: limit — tests/test_tpu_aot.py).
 KERNEL_MAX_KEYS = 1 << 14
-#: scoped-VMEM limit requested for the kernel: the compiler's default
-#: (16 MiB on v5e) refuses the sums-and-counts variant from 12288 keys
-#: up; it needs 17.8 MiB at KERNEL_MAX_KEYS.
-_VMEM_LIMIT_BYTES = 32 << 20
 
 #: (mesh, task axis) of the program being traced, or None.
 _MESH_SCOPE: contextvars.ContextVar[
@@ -92,44 +116,70 @@ def uses_kernel() -> bool:
     return platform == "tpu"
 
 
-def _hist_kernel(keys_ref, vals_ref, sum_ref, cnt_ref):
-    rt, b = keys_ref.shape
-    nkp = sum_ref.shape[1]
-    nchunks = b // _COL_CHUNK
+def _hist_kernel_mxu(keys_ref, vals_ref, *outs, hi_rows: int):
+    """One ``[_ROW_TILE, cols]`` block of records against every key lane.
 
-    def body(i, carry):
-        sums, cnts = carry
-        kc = keys_ref[:, pl.ds(i * _COL_CHUNK, _COL_CHUNK)]   # [RT, C]
-        vc = vals_ref[:, pl.ds(i * _COL_CHUNK, _COL_CHUNK)]
-        iota = jax.lax.broadcasted_iota(jnp.int32, (rt, _COL_CHUNK, nkp), 2)
-        oh = kc[:, :, None] == iota
-        sums = sums + jnp.sum(jnp.where(oh, vc[:, :, None], 0), axis=1)
-        cnts = cnts + jnp.sum(oh.astype(jnp.int32), axis=1)
-        return sums, cnts
+    ``key = hi * 128 + lo``, so a row's table is a matrix product over
+    its records ``j``::
 
-    sums, cnts = jax.lax.fori_loop(
-        0, nchunks, body,
-        (jnp.zeros((rt, nkp), jnp.int32), jnp.zeros((rt, nkp), jnp.int32)))
-    sum_ref[:] = sums
-    cnt_ref[:] = cnts
+        out[hi, lo] = sum_j ([hi_j == hi] * vals_j) * [lo_j == lo]
 
+    The VPU builds the two one-hots (``hi_rows + 128`` compares a
+    record, not one per key lane) and the MXU does the ``records x key
+    lanes`` products, the tile's rows as one batched product so that
+    they overlap in its pipeline. ``vals`` goes in as the four unsigned
+    byte planes of its two's-complement word, stacked on the ``hi`` axis
+    with a fifth plane of ones for the counts: a byte is exact in bf16,
+    the one-hot is 0/1, the product accumulates in f32 and a plane's sum
+    over one ``_COL_BLOCK`` is under 2**24, so nothing rounds; the
+    planes recombine with shifts in int32 and wrap like the scatter-add.
+    A key outside ``[0, 128 * hi_rows)`` (-1 of an invalid record among
+    them) has no row in the first factor, so whatever lane its ``lo``
+    matches carries nothing: ``mode="drop"`` parity.
 
-def _hist_kernel_sums(keys_ref, vals_ref, sum_ref):
-    # Sums-only variant: half the vector work of _hist_kernel (the keyed
-    # aggregation operators never use the counts).
-    rt, b = keys_ref.shape
-    nkp = sum_ref.shape[1]
-    nchunks = b // _COL_CHUNK
+    The planes are one stacked operand and the key blocks one axis of
+    it; full column chunks are a loop and the remainder one more step:
+    the traced body is at most two chunk steps whatever the table's
+    width (tests/test_pallas_kernels.py counts them).
+    """
+    want_counts = len(outs) == 2
+    rt, cols = keys_ref.shape
+    f32, bf16 = jnp.float32, jnp.bfloat16
 
-    def body(i, sums):
-        kc = keys_ref[:, pl.ds(i * _COL_CHUNK, _COL_CHUNK)]
-        vc = vals_ref[:, pl.ds(i * _COL_CHUNK, _COL_CHUNK)]
-        iota = jax.lax.broadcasted_iota(jnp.int32, (rt, _COL_CHUNK, nkp), 2)
-        oh = kc[:, :, None] == iota
-        return sums + jnp.sum(jnp.where(oh, vc[:, :, None], 0), axis=1)
+    def fold(c0, width, acc):
+        k = keys_ref[:, pl.ds(c0, width)][:, None, :]         # [rt, 1, W]
+        v = vals_ref[:, pl.ds(c0, width)][:, None, :]
+        at_hi = (k >> 7) == jax.lax.broadcasted_iota(
+            jnp.int32, (rt, hi_rows, width), 1)
+        planes = [jnp.where(at_hi, ((v >> s) & 255).astype(f32), 0.0)
+                  for s in (0, 8, 16, 24)]
+        if want_counts:
+            planes.append(at_hi.astype(f32))
+        a = jnp.concatenate(planes, axis=1).astype(bf16)
+        at_lo = (k & 127) == jax.lax.broadcasted_iota(
+            jnp.int32, (rt, _LANES, width), 1)
+        return acc + jax.lax.dot_general(           # [rt, planes * hi, 128]
+            a, at_lo.astype(f32).astype(bf16),
+            (((2,), (2,)), ((0,), (0,))), preferred_element_type=f32)
 
-    sum_ref[:] = jax.lax.fori_loop(
-        0, nchunks, body, jnp.zeros((rt, nkp), jnp.int32))
+    full, rest = divmod(cols, _COL_CHUNK)
+    acc = jnp.zeros((rt, (4 + want_counts) * hi_rows, _LANES), f32)
+    if full:
+        acc = jax.lax.fori_loop(
+            0, full,
+            lambda c, acc: fold(pl.multiple_of(c * _COL_CHUNK, _COL_CHUNK),
+                                _COL_CHUNK, acc), acc)
+    if rest:
+        acc = fold(full * _COL_CHUNK, rest, acc)
+    p = acc.astype(jnp.int32).reshape(rt, -1, hi_rows, _LANES)
+    tables = [p[:, 0] + (p[:, 1] << 8) + (p[:, 2] << 16) + (p[:, 3] << 24)]
+    if want_counts:
+        tables.append(p[:, 4])
+    later = pl.program_id(1) > 0
+    for out_ref, tb in zip(outs, tables):
+        h = out_ref.shape[1] // _LANES
+        tb = tb[:, :h, :].reshape(rt, h * _LANES)
+        out_ref[...] = tb + jnp.where(later, out_ref[...], 0)
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int,
@@ -147,42 +197,34 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int,
 def _hist_pallas(keys, vals, valid, nk: int, interpret: bool,
                  want_counts: bool = True):
     r, b = keys.shape
-    nkp = -(-nk // _COL_CHUNK) * _COL_CHUNK
+    nkp = -(-nk // _LANES) * _LANES
+    blocks = -(-b // _COL_BLOCK)
+    cols = -(-b // (blocks * _LANES)) * _LANES      # a block's, lane-aligned
     # Invalid records AND pad slots get key -1 (matches nothing) — a 0-pad
     # would count phantom records of key 0.
-    k = _pad_to(jnp.where(valid, keys, -1), 1, _COL_CHUNK, fill=-1)
+    k = _pad_to(jnp.where(valid, keys, -1), 1, blocks * cols, fill=-1)
     k = _pad_to(k, 0, _ROW_TILE, fill=-1)
-    v = _pad_to(jnp.where(valid, vals, 0), 1, _COL_CHUNK)
+    v = _pad_to(jnp.where(valid, vals, 0), 1, blocks * cols)
     v = _pad_to(v, 0, _ROW_TILE)
-    rp, bp = k.shape
-    grid = (rp // _ROW_TILE,)
-    params = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
-    spec_in = pl.BlockSpec((_ROW_TILE, bp), lambda i: (i, 0),
+    rp = k.shape[0]
+    nout = 1 + want_counts
+    spec_in = pl.BlockSpec((_ROW_TILE, cols), lambda i, j: (i, j),
                            memory_space=pltpu.VMEM)
-    spec_out = pl.BlockSpec((_ROW_TILE, nkp), lambda i: (i, 0),
+    spec_out = pl.BlockSpec((_ROW_TILE, nkp), lambda i, j: (i, 0),
                             memory_space=pltpu.VMEM)
-    if not want_counts:
-        sums = pl.pallas_call(
-            _hist_kernel_sums,
-            out_shape=jax.ShapeDtypeStruct((rp, nkp), jnp.int32),
-            grid=grid,
-            in_specs=[spec_in, spec_in],
-            out_specs=spec_out,
-            compiler_params=params,
-            interpret=interpret,
-        )(k, v)
-        return sums[:r, :nk], None
-    sums, cnts = pl.pallas_call(
-        _hist_kernel,
-        out_shape=(jax.ShapeDtypeStruct((rp, nkp), jnp.int32),
-                   jax.ShapeDtypeStruct((rp, nkp), jnp.int32)),
-        grid=grid,
+    out = pl.pallas_call(
+        functools.partial(
+            _hist_kernel_mxu,
+            hi_rows=-(-nkp // (_LANES * _HI_ALIGN)) * _HI_ALIGN),
+        out_shape=(jax.ShapeDtypeStruct((rp, nkp), jnp.int32),) * nout,
+        grid=(rp // _ROW_TILE, blocks),
         in_specs=[spec_in, spec_in],
-        out_specs=(spec_out, spec_out),
-        compiler_params=params,
+        out_specs=(spec_out,) * nout,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(k, v)
-    return sums[:r, :nk], cnts[:r, :nk]
+    return out[0][:r, :nk], out[1][:r, :nk] if want_counts else None
 
 
 def _hist_xla(keys, vals, valid, nk: int):
@@ -241,13 +283,19 @@ def keyed_hist(keys: jnp.ndarray, vals: jnp.ndarray, valid: jnp.ndarray,
     ``[0, nk)``. Out-of-range keys are dropped (scatter ``mode=drop``
     parity). ``force``: "pallas" | "interpret" | "xla" | "" (by target
     platform, :func:`uses_kernel`). ``want_counts=False`` skips the count
-    output (returned as None) — half the kernel work; the aggregation
-    operators only need sums. Inside :func:`kernel_mesh` the kernel runs
-    per mesh shard.
+    output (returned as None) — one plane of five and one output less;
+    the aggregation operators only need sums. Inside :func:`kernel_mesh`
+    the kernel runs per mesh shard. Each call leaves a ``hist.kernel``
+    instant (form ``mxu`` / ``scatter``, rows, cols, lanes, hi, planes)
+    in the process tracer, at trace time.
     """
     lead = keys.shape[:-1]
     b = keys.shape[-1]
     mode = force or ("pallas" if uses_kernel() else "xla")
+    get_tracer().event(
+        "hist.kernel", form="scatter" if mode == "xla" else "mxu",
+        rows=math.prod(lead), cols=b, lanes=nk, hi=-(-nk // _LANES),
+        planes=4 + want_counts)
     with jax.named_scope("hist"):      # metadata: groups the kernel's ops
         if mode == "xla":
             kf, vf, mf = (x.reshape(-1, b) for x in (keys, vals, valid))

@@ -173,8 +173,8 @@ def _count_to_targets(
     dropped = jnp.maximum(counts - out_capacity, 0).astype(jnp.int32)
     # Placement: (target, rank) pairs are UNIQUE per step, so a keyed
     # histogram over the flattened slot id IS the routed batch (sum
-    # of one contribution = select) — the Pallas VPU kernel streams
-    # it where an XLA element scatter ran ~50ms/field at bench
+    # of one contribution = select) — the Pallas kernel folds it
+    # on the MXU where an XLA element scatter ran ~50ms/field at bench
     # shapes (see _block_to_target_lane). Slot tables wider than the
     # kernel compiles for are placed by an element scatter.
     nk = T * out_capacity
@@ -299,9 +299,9 @@ def _block_to_target_lane(batch: RecordBatch, target: jnp.ndarray,
     keep = hit & (pos < out_capacity)
     # Placement is "field value at the record whose rank == c" — ranks
     # are UNIQUE per step, so a keyed histogram over them IS the routed
-    # batch (sum of one contribution = select). The Pallas VPU kernel
-    # streams it in compare-accumulate chunks; an XLA element scatter
-    # here ran ~50ms/field at bench shapes, the kernel ~5ms.
+    # batch (sum of one contribution = select). The Pallas kernel
+    # (ops/histogram.py) folds it as a factored one-hot product; an XLA
+    # element scatter here ran ~50ms/field at bench shapes.
     slot = jnp.where(keep, pos, -1)
     out_k, cnt = keyed_hist(slot, keys, keep, out_capacity)
     out_v, _ = keyed_hist(slot, vals, keep, out_capacity,

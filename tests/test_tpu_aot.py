@@ -4,7 +4,8 @@ accepts them. A pre-check that saves chip time, not evidence — the
 evidence is ``chip_smoke.py`` on the chip. Pinned here are the two
 failures that bring-up found: a kernel shape the routing guard admits
 but the compiler refused for VMEM, and the Mosaic kernel inside a
-program partitioned over a mesh.
+program partitioned over a mesh; and what refused PR 29: how many
+kernels a program holds.
 """
 
 import jax
@@ -38,15 +39,36 @@ def v5e():
 @pytest.mark.parametrize("want_counts", [True, False])
 def test_hist_kernel_compiles_at_widest_admitted_table(v5e, want_counts):
     """Every slot-table width the exchange guard admits
-    (``nk <= KERNEL_MAX_KEYS``) must compile, counts and sums-only: the
-    compiler's default scoped-VMEM limit refused the counts kernel from
-    12288 keys up."""
+    (``nk <= KERNEL_MAX_KEYS``) must compile, counts and sums-only, under
+    the compiler's default scoped-VMEM limit: the factored MXU kernel's
+    stacked operand is ``[8, 5 x 128, 1024]`` bf16 there, its largest."""
     sh = SingleDeviceSharding(v5e[0])
     arg = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=sh)
     mask = jax.ShapeDtypeStruct((8, 1024), jnp.bool_, sharding=sh)
-    histogram._hist_pallas.lower(
-        arg, arg, mask, histogram.KERNEL_MAX_KEYS, False,
-        want_counts).compile()
+    lowered = histogram._hist_pallas.lower(
+        arg, arg, mask, histogram.KERNEL_MAX_KEYS, False, want_counts)
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    lowered.compile()
+
+
+def test_program_lowers_one_kernel_for_calls_of_one_shape(v5e):
+    """An exchange places keys, values and timestamps over the same
+    slots: three ``keyed_hist`` calls of one shape lower to one function
+    with one Mosaic kernel in it, called three times — what a program
+    pays per kernel at every ``lower`` (the module is built and
+    serialised again, cache hit or not) it pays once."""
+    sh = SingleDeviceSharding(v5e[0])
+    arg = jax.ShapeDtypeStruct((64, 512), jnp.int32, sharding=sh)
+    mask = jax.ShapeDtypeStruct((64, 512), jnp.bool_, sharding=sh)
+
+    def place(slot, k, v, t, keep):
+        return [histogram.keyed_hist(slot, x, keep, 8192, force="pallas",
+                                     want_counts=False)[0]
+                for x in (k, v, t)]
+
+    text = jax.jit(place).lower(arg, arg, arg, arg, mask).as_text()
+    assert text.count("call @_hist_pallas") == 3
+    assert text.count("tpu_custom_call") == 1
 
 
 def test_block_program_lowers_for_four_chip_mesh_with_kernel(v5e):
