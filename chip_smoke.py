@@ -24,6 +24,11 @@ it pass off the device:
      fence): kill one window subtask over two un-truncated epochs,
      recover. Pass = recovery's bit-identity verification and the audit
      validator; peak HBM is printed.
+  J  the window join alone at a deployment's lanes (4,096 keys x 2 open
+     windows a side): its block form — placements and compaction through
+     the histogram kernel — against its step form (scatter-adds, step by
+     step), state and rows bit for bit, over blocks that split windows
+     and a stretch with one input silent. Not in the default parts.
   C  job A again under a four-chip task mesh, when there are four chips.
      Pass = committed stream byte-identical to A's, ledgers equal, every
      sharded carry leaf on four devices at a quarter each.
@@ -410,6 +415,62 @@ def check_kernels(seed: int) -> None:
     say("K one-hot f32 HIGHEST gather [512, 8, 997]: bit-exact over int32")
 
 
+def check_window_join(seed: int, blocks: int = 3, K: int = 24, P: int = 8,
+                      B: int = 96, num_keys: int = 4096) -> int:
+    """The window join's block form against its step form on this
+    device; returns the rows compared."""
+    import jax
+    import jax.numpy as jnp
+    from clonos_tpu.api import operators as ops
+    from clonos_tpu.api.records import RecordBatch, zero_invalid
+
+    rng = np.random.RandomState(seed)
+    op = ops.EventTimeWindowJoinOperator(
+        num_keys=num_keys, window_size=10000, out_of_orderness=1280,
+        capacity=320)
+
+    def draw(blk, silent):
+        steps = blk * K + np.arange(K)
+        ts = 1280 * steps[:, None, None] + rng.randint(0, 1280, (K, P, B))
+        return zero_invalid(RecordBatch(
+            jnp.asarray(rng.randint(-2, num_keys // 8, (K, P, B)),
+                        jnp.int32),
+            jnp.asarray(rng.randint(-2 ** 31, 2 ** 31 - 1, (K, P, B)),
+                        jnp.int32),
+            jnp.asarray(ts, jnp.int32),
+            jnp.asarray((rng.rand(K, P, B) < 0.7) & (not silent))))
+
+    step = jax.jit(op.process2)
+    block = jax.jit(op.process_block)
+    by_block = by_step = op.init_state(P)
+    rows = 0
+    for blk in range(blocks):
+        left, right = draw(blk, False), draw(blk, blk == 1)
+        bctx = ops.BlockContext(
+            times=jnp.arange(K, dtype=jnp.int32),
+            rng_bits=jnp.zeros((K,), jnp.int32),
+            epoch=jnp.zeros((), jnp.int32), step0=jnp.zeros((), jnp.int32),
+            subtask=jnp.arange(P, dtype=jnp.int32))
+        by_block, out = block(by_block, (left, right), bctx)
+        outs = []
+        for k in range(K):
+            at = lambda b: jax.tree_util.tree_map(lambda x: x[k], b)
+            by_step, o = step(by_step, at(left), at(right), bctx.at_step(k))
+            outs.append(o)
+        stacked = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *outs)
+        for what, a, b in list(zip(out._fields, out, stacked)) + [
+                (k, by_block[k], by_step[k]) for k in by_block]:
+            if not np.array_equal(np.asarray(a), np.asarray(b)):
+                raise AssertionError(
+                    f"window join, block {blk}: {what} differs between "
+                    f"the block form and the step form")
+        rows += int(out.count().sum())
+    if rows == 0 or int(np.asarray(by_block["late"]).sum()) == 0:
+        raise AssertionError(f"window join: {rows} rows, no record refused:"
+                             f" the case exercises nothing")
+    return rows
+
+
 def check_block_until_ready() -> None:
     """Show that ``jax.block_until_ready`` returns only when the work is
     done: after it, a device->host read of the same result has nothing
@@ -458,7 +519,8 @@ def print_routes(tracer, since: int, part: str) -> int:
         a = r["args"]
         if "edge" in a:        # planned, once per HASH edge and job built
             line = (f"edge {a['edge']}: {a['route']}, {a['width']} wide, "
-                    f"{a['pairs_kept']} of {a['pairs_total']} pairs kept")
+                    f"{a['pairs_kept']} of {a['pairs_total']} pairs kept"
+                    + (f" ({a['reason']})" if "reason" in a else ""))
         else:                  # a dynamic exchange, as it was lowered
             line = (f"K={a['steps']} n={a['records']} T={a['targets']} "
                     f"cap={a['capacity']}: {a['route']}")
@@ -479,7 +541,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=21)
     ap.add_argument("--parts", default="KABC",
-                    help="which of K, A, B, C to run (C needs A)")
+                    help="which of K, J, A, B, C to run (C needs A)")
     args = ap.parse_args(argv)
     t_start = time.monotonic()
 
@@ -515,6 +577,13 @@ def main(argv=None) -> int:
         check_block_until_ready()
         mark = print_routes(tracer, mark, "K")
         say(f"K pass ({time.monotonic() - t0:.1f}s)")
+
+    if "J" in parts:
+        t0 = time.monotonic()
+        rows = check_window_join(args.seed)
+        mark = print_routes(tracer, mark, "J")
+        say(f"J pass: window join, block form == step form over {rows} "
+            f"rows ({time.monotonic() - t0:.1f}s)")
 
     shape = ServedShape()
     feed = make_feed(shape, args.seed)
@@ -614,7 +683,7 @@ def main(argv=None) -> int:
     say(f"compile cache: {entries1} entries at end "
         f"({entries1 - entries0} added by this run)")
     say(f"total {time.monotonic() - t_start:.1f}s; parts run: "
-        f"{''.join(p for p in 'KABC' if p in parts)}")
+        f"{''.join(p for p in 'KJABC' if p in parts)}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": n_dev}}), flush=True)

@@ -182,6 +182,29 @@ class DataStream:
                                   interval=interval, capacity=cap)
         return self._attach2(other, name, op, parallelism, cap)
 
+    def window_join(self, other: "DataStream", num_keys: int,
+                    window_size: int, out_of_orderness: int = 0,
+                    capacity: Optional[int] = None,
+                    edge_capacity: Optional[int] = None,
+                    name: str = "window-join",
+                    parallelism: Optional[int] = None) -> "DataStream":
+        """Tumbling event-time window join on equal key and equal window
+        (``a.join(b).where(..).equalTo(..).window(
+        TumblingEventTimeWindows.of(window_size))``): one row per (key,
+        window) in which both inputs had a record, carrying the sum of
+        ``other``'s values (operators.EventTimeWindowJoinOperator). Both
+        inputs must be key_by()'d. ``capacity``: rows a subtask may emit
+        a step; ``edge_capacity``: the receive window of BOTH input
+        edges (a two-input vertex takes one)."""
+        from clonos_tpu.api.operators import EventTimeWindowJoinOperator
+        if not (self._keyed and other._keyed):
+            raise ValueError("window_join requires key_by() on both inputs")
+        op = EventTimeWindowJoinOperator(
+            num_keys=num_keys, window_size=window_size,
+            out_of_orderness=out_of_orderness,
+            capacity=capacity or self._env.default_edge_capacity)
+        return self._attach2(other, name, op, parallelism, edge_capacity)
+
     def rebalance(self) -> "DataStream":
         s = DataStream(self._env, self._vertex)
         s._force_rebalance = True

@@ -101,6 +101,11 @@ class Operator:
     #: "any key anywhere".
     emits_received_keys: bool = False
 
+    #: per-subtask int32 totals in operator state that the fence's one
+    #: health read brings back, and the counter each feeds
+    #: (``<counter>.<vertex name>``): ``((state key, counter), ...)``
+    fence_totals: Tuple[Tuple[str, str], ...] = ()
+
     def init_state(self, parallelism: int) -> Any:
         return ()
 
@@ -565,7 +570,186 @@ def _segmented_cumsum(values: jnp.ndarray, reset: jnp.ndarray
     return jax.lax.associative_scan(combine, (reset, values), axis=0)[1]
 
 
-class EventTimeWindow(Operator):
+class _EventTimeSlots:
+    """The arithmetic every event-time window operator shares — the
+    single-input windows (:class:`EventTimeWindow`) and the two-input
+    window join (:class:`EventTimeWindowJoinOperator`) — in a step form
+    and a block form each: the running maximum behind a watermark, the
+    placement of records in ``open_windows`` slots by window id, the
+    fire of the slots the watermark has passed, and the accumulators'
+    running sums that restart at a fire. One implementation: an operator
+    differs in what it folds and what a fire emits, not in this.
+
+    Why a slot never holds the wrong window: the ids still open after
+    the fire lie in ``[wm_floor, max_ts // slide]``, at most
+    ``(out_of_orderness + size) // slide + 1`` consecutive values, fewer
+    than ``open_windows``; so of the ids congruent to a slot exactly one
+    can be open at a time, and whatever the slot holds is that one.
+    Every state reached from ``init_state`` keeps this, and the block
+    form leans on it: a valid record is accepted iff its window is not
+    closed. An operator whose watermark can trail its newest record by
+    more than that (two inputs, the slower one holding the watermark)
+    asks for ``bounded`` placement: a record ``open_windows`` or more
+    windows ahead of the first open one has no slot and is refused like
+    a late one, which keeps the same fact by construction.
+    """
+
+    num_keys: int
+    window_size: int
+    out_of_orderness: int
+    open_windows: int
+
+    @property
+    def _slide(self) -> int:
+        raise NotImplementedError
+
+    def _window_end(self, win: jnp.ndarray) -> jnp.ndarray:
+        """End of window ``win``; a free slot reads as window 0 so that
+        the sentinel never enters the multiply."""
+        return (jnp.where(win != _NO_WINDOW, win, 0) * self._slide
+                + self.window_size)
+
+    def _accepts(self, valid, rw, wm, bounded: bool):
+        """Valid records whose window ``rw`` the watermark ``wm`` has
+        not passed (and, ``bounded``, that has a slot: class docstring)."""
+        size, slide = self.window_size, self._slide
+        ok = valid & ~(rw * slide + size <= wm)
+        if bounded:
+            ok = ok & (rw < (wm - size) // slide + 1 + self.open_windows)
+        return ok
+
+    # --- step form (one subtask, under ``jax.vmap``) -------------------------
+
+    def _fire_step(self, win, wm):
+        """``(win_end, fire)`` of the slots ``win [W]``: every open
+        window with end <= wm closes, before the step's records."""
+        win_end = self._window_end(win)
+        return win_end, (win != _NO_WINDOW) & (win_end <= wm)
+
+    def _place_step(self, win, wm, valid, ts, bounded: bool = False):
+        """Place one step's records: ``(win, [(slot, ok), ...], ok_any)``
+        — per window a record falls in (``size // slide`` of them) its
+        slot and whether it was accepted there; ``win`` with the ids
+        accepted. The newest window containing ts starts at
+        floor(ts / slide) * slide (jnp // floors); the record is also in
+        the ``size // slide - 1`` windows before it."""
+        w = self.open_windows
+        base = ts // self._slide
+        ok_any = jnp.zeros_like(valid)
+        placed = []
+        for j in range(self.window_size // self._slide):
+            rw = base - j                              # window id
+            slot = rw % w
+            slot_win = win[slot]
+            ok = self._accepts(valid, rw, wm, bounded) & (
+                (slot_win == rw) | (slot_win == _NO_WINDOW))
+            ok_any = ok_any | ok
+            win = win.at[slot].max(jnp.where(ok, rw, _NO_WINDOW),
+                                   mode="drop")
+            placed.append((slot, ok))
+        return win, placed, ok_any
+
+    # --- block form ([K, P, ...], no scan over the steps, no scatter) --------
+
+    def _block_max_ts(self, max_ts0, valid, ts):
+        """``[K, P]``: the largest valid timestamp seen through each
+        step, this step's included (a running maximum over the steps)."""
+        step_max = jnp.max(jnp.where(valid, ts, _NO_TS), axis=2)  # [K, P]
+        return jnp.maximum(max_ts0[None], jax.lax.cummax(step_max, axis=0))
+
+    def _block_place(self, valid, key, values, ts, wm,
+                     want_counts: bool = False, bounded: bool = False):
+        """Place a block's records by the watermark ``wm [K, P]`` alone
+        (class docstring): ``(sums, counts, taken, ok_any)`` — per-step
+        sums of ``values`` (and record counts, ``want_counts``) per
+        (slot, key) through the keyed histogram over the composite lane
+        ``slot * nk + key`` (``[K, P, W * nk]``), the largest window id
+        accepted into each slot at each step (``[K, P, W]``), and which
+        records were accepted at all."""
+        from clonos_tpu.ops.histogram import KERNEL_MAX_KEYS, keyed_hist
+        K, p, _ = valid.shape
+        nk, w = self.num_keys, self.open_windows
+        slots = jnp.arange(w, dtype=jnp.int32)
+        base = ts // self._slide
+        ok_any = jnp.zeros_like(valid)
+        contrib = jnp.zeros((K, p, w * nk), jnp.int32)
+        counts = contrib if want_counts else None
+        taken = jnp.full((K, p, w), _NO_WINDOW, jnp.int32)
+        for j in range(self.window_size // self._slide):
+            rw = base - j
+            ok = self._accepts(valid, rw, wm[:, :, None], bounded)
+            ok_any = ok_any | ok
+            slot = rw % w
+            if w * nk <= KERNEL_MAX_KEYS:
+                part, cnt = keyed_hist(slot * nk + key, values, ok,
+                                       w * nk, want_counts=want_counts)
+            else:       # a table wider than the kernel takes: slot by slot
+                parts = [keyed_hist(key, values, ok & (slot == s), nk,
+                                    want_counts=want_counts)
+                         for s in range(w)]
+                part = jnp.concatenate([x[0] for x in parts], axis=2)
+                cnt = (jnp.concatenate([x[1] for x in parts], axis=2)
+                       if want_counts else None)
+            contrib = contrib + part
+            if want_counts:
+                counts = counts + cnt
+            hit = ok[..., None] & (slot[..., None] == slots)   # [K,P,B,W]
+            taken = jnp.maximum(taken, jnp.max(
+                jnp.where(hit, rw[..., None], _NO_WINDOW), axis=2))
+        return contrib, counts, taken, ok_any
+
+    def _block_slots(self, win0, taken, wm):
+        """``(held, win_end, fire)``, each ``[K, P, W]``: the window a
+        slot holds after each step (the largest id ever accepted into
+        it, if that is still open), and what each step's fire sees — the
+        end of the window the step before left there, and whether the
+        watermark has passed it."""
+        seen = jnp.maximum(win0[None], jax.lax.cummax(taken, axis=0))
+        held = jnp.where(self._window_end(seen) <= wm[:, :, None],
+                         _NO_WINDOW, seen)
+        before = jnp.concatenate([win0[None], held[:-1]], axis=0)
+        win_end = self._window_end(before)
+        fire = (before != _NO_WINDOW) & (win_end <= wm[:, :, None])
+        return held, win_end, fire
+
+    def _block_lanes(self, x):
+        """``[K, P, W]`` -> ``[K, P, W * nk]``: a slot's value on each of
+        its key lanes."""
+        return jnp.repeat(x, self.num_keys, axis=2)
+
+    def _block_accumulate(self, acc0, contrib, fire_l):
+        """``(acc, emit)``, ``[K, P, W * nk]``: the accumulators after
+        each step — ``contrib`` in a running sum that restarts where the
+        lane's slot fires (``fire_l``), from ``acc0 [P, W * nk]`` — and
+        as the step before left them, which is what a fire emits."""
+        first = jnp.where(fire_l[0], 0, acc0) + contrib[0]
+        acc = _segmented_cumsum(
+            jnp.concatenate([first[None], contrib[1:]], axis=0),
+            jnp.concatenate([jnp.ones_like(fire_l[:1]), fire_l[1:]],
+                            axis=0))
+        return acc, jnp.concatenate([acc0[None], acc[:-1]], axis=0)
+
+
+def _running_count(mask: jnp.ndarray, group: int = 128) -> jnp.ndarray:
+    """Inclusive running count of ``mask`` along its last axis, int32
+    (``cumsum`` bit for bit). A scan along the lanes is strided slices
+    of the minor dimension; this counts inside groups of ``group`` lanes
+    by a product with a triangular matrix (0/1 operands, sums of at most
+    ``group``: exact) and runs the sum over the groups' totals only."""
+    n = mask.shape[-1]
+    pad = (-n) % group
+    m = jnp.pad(mask, [(0, 0)] * (mask.ndim - 1) + [(0, pad)])
+    m = m.reshape(m.shape[:-1] + (-1, group)).astype(jnp.bfloat16)
+    upto = (jnp.arange(group)[:, None] <= jnp.arange(group)[None, :])
+    within = jnp.dot(m, upto.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32).astype(jnp.int32)
+    totals = within[..., -1]
+    before = jnp.cumsum(totals, axis=-1) - totals
+    return (within + before[..., None]).reshape(
+        mask.shape[:-1] + (-1,))[..., :n]
+
+
+class EventTimeWindow(_EventTimeSlots, Operator):
     """Event-time windowed sum per key with watermark-driven firing: what
     the tumbling and the sliding operator share (a tumbling window is a
     sliding one whose slide is its size). Window id = start // slide.
@@ -595,25 +779,13 @@ class EventTimeWindow(Operator):
     end <= watermark fire FIRST: one record per key with a nonzero sum,
     timestamped with the window end and counted in ``fired`` (a window
     completed by this step's records emits next step — deterministic
-    one-step emission latency).
-
-    Why a slot never holds the wrong window: the ids still open after the
-    fire lie in ``[wm_floor, max_ts // slide]``, at most
-    ``(out_of_orderness + size) // slide + 1`` consecutive values, fewer
-    than ``open_windows``; so of the ids congruent to a slot exactly one
-    can be open at a time, and whatever the slot holds is that one. Every
-    state reached from ``init_state`` keeps this, and ``process_block``
-    leans on it: a valid record is accepted iff its window is not closed.
+    one-step emission latency). The watermark, slot and fire arithmetic
+    is :class:`_EventTimeSlots`'s, which also says why a slot never
+    holds the wrong window.
     """
 
-    num_keys: int
-    window_size: int
-    out_of_orderness: int
-    open_windows: int
-
-    @property
-    def _slide(self) -> int:
-        raise NotImplementedError
+    fence_totals = (("late", "window.late_records"),
+                    ("fired", "window.fired_rows"))
 
     @property
     def out_capacity(self):  # type: ignore[override]
@@ -641,15 +813,8 @@ class EventTimeWindow(Operator):
             "fired": jnp.zeros((parallelism,), jnp.int32),
         }
 
-    def _window_end(self, win: jnp.ndarray) -> jnp.ndarray:
-        """End of window ``win``; a free slot reads as window 0 so that
-        the sentinel never enters the multiply."""
-        return (jnp.where(win != _NO_WINDOW, win, 0) * self._slide
-                + self.window_size)
-
     def process(self, state, batch, ctx):
-        nk, w = self.num_keys, self.open_windows
-        size, slide = self.window_size, self._slide
+        nk = self.num_keys
 
         def one(acc, win, max_ts, late, fired, b: RecordBatch):
             # Advance the watermark from this step's data (pure fold).
@@ -658,8 +823,7 @@ class EventTimeWindow(Operator):
             wm = max_ts - self.out_of_orderness
             # FIRE FIRST: every open window with end <= wm closes, freeing
             # its slot before this step's records are assigned.
-            win_end = self._window_end(win)                   # [W]
-            fire = (win != _NO_WINDOW) & (win_end <= wm)
+            win_end, fire = self._fire_step(win, wm)           # [W]
             out = RecordBatch(
                 keys=jnp.asarray(self.static_out_keys()),
                 values=acc.reshape(-1),
@@ -668,21 +832,9 @@ class EventTimeWindow(Operator):
             fired = fired + jnp.sum(out.valid.astype(jnp.int32))
             acc = jnp.where(fire[:, None], 0, acc)
             win = jnp.where(fire, _NO_WINDOW, win)
-            # Newest window containing ts starts at floor(ts/slide)*slide
-            # (jnp // floors); the record is also in the size // slide - 1
-            # windows before it.
-            base = b.timestamps // slide
-            ok_any = jnp.zeros_like(b.valid)
-            for j in range(size // slide):
-                rw = base - j                              # window id
-                closed = rw * slide + size <= wm           # behind the wm
-                slot = rw % w
-                slot_win = win[slot]
-                ok = b.valid & ~closed & ((slot_win == rw)
-                                          | (slot_win == _NO_WINDOW))
-                ok_any = ok_any | ok
-                win = win.at[slot].max(jnp.where(ok, rw, _NO_WINDOW),
-                                       mode="drop")
+            win, placed, ok_any = self._place_step(win, wm, b.valid,
+                                                   b.timestamps)
+            for slot, ok in placed:
                 acc = acc.at[slot, jnp.clip(b.keys, 0, nk - 1)].add(
                     jnp.where(ok, b.values, 0), mode="drop")
             late = late + jnp.sum((b.valid & ~ok_any).astype(jnp.int32))
@@ -695,73 +847,32 @@ class EventTimeWindow(Operator):
                  "fired": fired}, out)
 
     def process_block(self, state, batches, bctx):
-        # Step-batched form, no scan over the steps and no scatter: the
-        # watermark is a running maximum over the step axis; a record is
-        # accepted iff its window is not behind it (class docstring);
-        # the window a slot holds after step k is the largest id ever
-        # accepted into the slot, if that is still open; per-step sums
-        # per (slot, key) come from the keyed histogram over the
-        # composite lane ``slot * nk + key``; accumulators are those sums
-        # in a running sum that restarts where the slot fires; a fire
-        # emits the accumulator as the step before left it.
-        from clonos_tpu.ops.histogram import KERNEL_MAX_KEYS, keyed_hist
+        # Step-batched form, no scan over the steps and no scatter
+        # (:class:`_EventTimeSlots`): the watermark is a running maximum
+        # over the step axis; a record is accepted iff its window is not
+        # behind it; per-step sums per (slot, key) come from the keyed
+        # histogram; accumulators are those sums in a running sum that
+        # restarts where the slot fires; a fire emits the accumulator as
+        # the step before left it.
         K, p, _ = batches.keys.shape
         nk, w = self.num_keys, self.open_windows
-        size, slide = self.window_size, self._slide
-        valid, ts = batches.valid, batches.timestamps
-        step_max = jnp.max(jnp.where(valid, ts, _NO_TS), axis=2)  # [K, P]
-        max_ts = jnp.maximum(state["max_ts"][None],
-                             jax.lax.cummax(step_max, axis=0))
+        valid = batches.valid
+        max_ts = self._block_max_ts(state["max_ts"], valid,
+                                    batches.timestamps)
         wm = max_ts - self.out_of_orderness                       # [K, P]
-
-        key = jnp.clip(batches.keys, 0, nk - 1)
-        slots = jnp.arange(w, dtype=jnp.int32)
-        base = ts // slide
-        ok_any = jnp.zeros_like(valid)
-        contrib = jnp.zeros((K, p, w * nk), jnp.int32)
-        taken = jnp.full((K, p, w), _NO_WINDOW, jnp.int32)
-        for j in range(size // slide):
-            rw = base - j
-            ok = valid & ~(rw * slide + size <= wm[:, :, None])
-            ok_any = ok_any | ok
-            slot = rw % w
-            if w * nk <= KERNEL_MAX_KEYS:
-                part, _ = keyed_hist(slot * nk + key, batches.values, ok,
-                                     w * nk, want_counts=False)
-            else:       # a table wider than the kernel takes: slot by slot
-                part = jnp.concatenate([
-                    keyed_hist(key, batches.values, ok & (slot == s), nk,
-                               want_counts=False)[0] for s in range(w)],
-                    axis=2)
-            contrib = contrib + part
-            hit = ok[..., None] & (slot[..., None] == slots)   # [K,P,B,W]
-            taken = jnp.maximum(taken, jnp.max(
-                jnp.where(hit, rw[..., None], _NO_WINDOW), axis=2))
+        contrib, _, taken, ok_any = self._block_place(
+            valid, jnp.clip(batches.keys, 0, nk - 1), batches.values,
+            batches.timestamps, wm)
         late = state["late"] + jnp.sum(
             (valid & ~ok_any).astype(jnp.int32), axis=(0, 2))
-
-        # win after each step, and before it (what that step's fire sees)
-        seen = jnp.maximum(state["win"][None],
-                           jax.lax.cummax(taken, axis=0))         # [K,P,W]
-        held = jnp.where(self._window_end(seen) <= wm[:, :, None],
-                         _NO_WINDOW, seen)
-        before = jnp.concatenate([state["win"][None], held[:-1]], axis=0)
-        win_end = self._window_end(before)
-        fire = (before != _NO_WINDOW) & (win_end <= wm[:, :, None])
-
-        lanes = lambda x: jnp.repeat(x, nk, axis=2)            # [K,P,W*nk]
-        fire_l = lanes(fire)
-        acc0 = state["acc"].reshape(p, w * nk)
-        first = jnp.where(fire_l[0], 0, acc0) + contrib[0]
-        acc = _segmented_cumsum(
-            jnp.concatenate([first[None], contrib[1:]], axis=0),
-            jnp.concatenate([jnp.ones_like(fire_l[:1]), fire_l[1:]],
-                            axis=0))
-        emit = jnp.concatenate([acc0[None], acc[:-1]], axis=0)
+        held, win_end, fire = self._block_slots(state["win"], taken, wm)
+        fire_l = self._block_lanes(fire)                       # [K,P,W*nk]
+        acc, emit = self._block_accumulate(
+            state["acc"].reshape(p, w * nk), contrib, fire_l)
         out = zero_invalid(RecordBatch(
             keys=jnp.broadcast_to(jnp.asarray(self.static_out_keys()),
                                   (K, p, w * nk)),
-            values=emit, timestamps=lanes(win_end),
+            values=emit, timestamps=self._block_lanes(win_end),
             valid=fire_l & (emit != 0)))
         fired = state["fired"] + jnp.sum(
             out.valid.astype(jnp.int32), axis=(0, 2))
@@ -1133,6 +1244,243 @@ class IntervalJoinOperator(TwoInputOperator):
         out = jax.tree_util.tree_map(
             lambda x: x.reshape((K,) + x.shape[2:]), outs)
         return {"lv": lv, "lt": lt, "lm": lm, "cursor": cur}, out
+
+
+@dataclasses.dataclass
+class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
+    """Tumbling event-time window join of two keyed streams on equal key
+    and equal window (the DataStream API's ``a.join(b).where(..)
+    .equalTo(..).window(TumblingEventTimeWindows.of(size))``; NEXmark
+    query 8 is its textbook use: persons joined with the auctions they
+    opened in the window they registered in). One row per (key, window)
+    in which BOTH inputs had a record: ``(key, sum of the right input's
+    values in the window wrapped to int32, window end)``.
+
+    Semantics, per subtask (every edge one step deep, as everywhere; the
+    watermark, slot and fire arithmetic is :class:`_EventTimeSlots`'s,
+    shared with :class:`EventTimeWindow`). A record whose key lies
+    outside ``[0, num_keys)`` is no record here (what the keyed
+    histogram does with one). ``max_ts_left`` and ``max_ts_right`` are
+    the running maxima of each input's valid timestamps, this step's
+    included. The watermark is the smaller of the two less the bound —
+    Flink's rule for a two-input operator — and advances once a step,
+    before the step's records are assigned:
+    ``wm = max(min(max_ts_left, max_ts_right), anchor) - out_of_orderness``.
+    ``anchor`` only matters while an input is still silent: it is what
+    ``min`` read at the first step that brought any record, a silent
+    input not counted (so: that step's ``min`` if both inputs spoke,
+    else the maximum of the one that did), and it never moves again.
+    Until both inputs have delivered a record nothing fires (no window
+    with a record ends at or behind ``anchor - out_of_orderness``), and
+    the slots are placed from ``anchor`` on; from then on
+    ``min(...) >= anchor`` unless the late starter begins behind it, in
+    which case its records older than the anchor's window are late.
+
+    Each step: windows whose end is at or behind ``wm`` fire FIRST — for
+    every key whose left count and right count in that window are both
+    non-zero one row, compacted in (slot, key) order into ``capacity``
+    rows a subtask a step (rows past it are dropped and counted in
+    ``dropped``: size ``capacity`` for the keys a subtask owns) — then
+    the fired slots clear; then the step's left and right records are
+    assigned by timestamp to window ``ts // window_size``. A record whose
+    window is already closed is dropped and counted in ``late`` (once,
+    whichever side). So is one ``open_windows`` or more windows ahead of
+    the first open one: with two inputs the watermark can trail the
+    faster input without bound, and the slots cannot (``bounded``
+    placement). ``fired`` counts rows emitted, ``left_records`` and
+    ``right_records`` the records each side accepted.
+
+    The block form has no scan over the steps and no scatter: both
+    inputs' histograms over ``slot x key`` lanes, three running sums that
+    restart at a fire, and the compaction itself a keyed histogram over
+    the rows' ranks (a row's place is its rank: no gather either).
+
+    Every row carries a key this subtask received, on both inputs
+    (``emits_received_keys``): behind two ``key_by()`` inputs the out
+    edge is routed in place.
+    """
+
+    num_keys: int
+    window_size: int
+    out_of_orderness: int = 0
+    capacity: int = 256
+    open_windows: int = 2
+
+    emits_received_keys = True
+
+    fence_totals = EventTimeWindow.fence_totals + (
+        ("left_records", "join.left_records"),
+        ("right_records", "join.right_records"),
+        ("dropped", "join.dropped_rows"))
+
+    def __post_init__(self):
+        # as the tumbling window: the open ids span at most
+        # out_of_orderness // window_size + 1 values, and one spare slot
+        need = self.out_of_orderness // self.window_size + 2
+        self.open_windows = max(self.open_windows, need)
+
+    @property
+    def _slide(self) -> int:
+        return self.window_size
+
+    @property
+    def out_capacity(self):  # type: ignore[override]
+        return self.capacity
+
+    _TABLES = ("left", "right", "sum")
+
+    def init_state(self, parallelism: int):
+        p, w = parallelism, self.open_windows
+        state = {k: jnp.zeros((p, w, self.num_keys), jnp.int32)
+                 for k in self._TABLES}       # counts, counts, right sums
+        state.update({k: jnp.zeros((p,), jnp.int32)
+                      for k, _ in self.fence_totals})
+        state.update({k: jnp.full((p,), _NO_TS, jnp.int32)
+                      for k in ("max_ts_left", "max_ts_right", "anchor")})
+        state["win"] = jnp.full((p, w), _NO_WINDOW, jnp.int32)
+        return state
+
+    def _in_range(self, b: RecordBatch):
+        return b.valid & (b.keys >= 0) & (b.keys < self.num_keys)
+
+    @staticmethod
+    def _anchor_reading(max_l, max_r):
+        """The smaller of both running maxima, a silent input left out
+        (``_NO_TS`` while both are): what an unset anchor is set to."""
+        lo = jnp.minimum(max_l, max_r)      # _NO_TS while an input is silent
+        return jnp.where(lo != _NO_TS, lo, jnp.maximum(max_l, max_r))
+
+    def _watermark(self, max_l, max_r, anchor):
+        """``(wm, anchor)`` from both inputs' running maxima and the
+        anchor as the step found it (class docstring); elementwise."""
+        anchor = jnp.where(anchor != _NO_TS, anchor,
+                           self._anchor_reading(max_l, max_r))
+        base = jnp.maximum(jnp.minimum(max_l, max_r), anchor)
+        return jnp.where(base != _NO_TS, base - self.out_of_orderness,
+                         _NO_TS), anchor
+
+    def _emit(self, match, sums, win_end):
+        """The rows of one or many steps: ``match [..., W * nk]`` lanes
+        compacted, in lane order, into ``[..., capacity]`` rows (key,
+        right sum, window end), and how many did not fit ``[...]``. A
+        row's place is its rank among the matches, so the compaction is
+        a keyed histogram over ranks: one call carries the sums, one the
+        lanes (a lane is its slot and its key)."""
+        from clonos_tpu.ops.histogram import keyed_hist
+        nk, w, cap = self.num_keys, self.open_windows, self.capacity
+        rank = _running_count(match) - 1
+        total = rank[..., -1] + 1
+        lane = jnp.broadcast_to(jnp.arange(w * nk, dtype=jnp.int32),
+                                match.shape)
+        values, _ = keyed_hist(rank, sums, match, cap, want_counts=False)
+        lanes, _ = keyed_hist(rank, lane, match, cap, want_counts=False)
+        valid = jnp.arange(cap, dtype=jnp.int32) < total[..., None]
+        slot = lanes // nk
+        ts = sum(jnp.where(slot == s, win_end[..., s:s + 1], 0)
+                 for s in range(w))
+        return (zero_invalid(RecordBatch(lanes % nk, values, ts, valid)),
+                jnp.maximum(total - cap, 0))
+
+    def process2(self, state, left, right, ctx):
+        nk = self.num_keys
+        lv, rv = self._in_range(left), self._in_range(right)
+
+        def fire_first(tables, win, max_l, max_r, anchor, l, lok, r, rok):
+            max_l = jnp.maximum(max_l, jnp.max(
+                jnp.where(lok, l.timestamps, _NO_TS)))
+            max_r = jnp.maximum(max_r, jnp.max(
+                jnp.where(rok, r.timestamps, _NO_TS)))
+            wm, anchor = self._watermark(max_l, max_r, anchor)
+            win_end, fire = self._fire_step(win, wm)              # [W]
+            cl, cr, sr = tables
+            match = (fire[:, None] & (cl > 0) & (cr > 0)).reshape(-1)
+            tables = tuple(jnp.where(fire[:, None], 0, t) for t in tables)
+            win = jnp.where(fire, _NO_WINDOW, win)
+            return (tables, win, max_l, max_r, anchor, wm, win_end, match,
+                    sr.reshape(-1))
+
+        def assign(tables, win, wm, l, lok, r, rok):
+            cl, cr, sr = tables
+            win, placed, l_any = self._place_step(
+                win, wm, lok, l.timestamps, bounded=True)
+            for slot, ok in placed:
+                cl = cl.at[slot, l.keys].add(ok.astype(jnp.int32),
+                                             mode="drop")
+            win, placed, r_any = self._place_step(
+                win, wm, rok, r.timestamps, bounded=True)
+            for slot, ok in placed:
+                cr = cr.at[slot, r.keys].add(ok.astype(jnp.int32),
+                                             mode="drop")
+                sr = sr.at[slot, r.keys].add(jnp.where(ok, r.values, 0),
+                                             mode="drop")
+            n = lambda m: jnp.sum(m.astype(jnp.int32))
+            return ((cl, cr, sr), win, n(lok & ~l_any) + n(rok & ~r_any),
+                    n(l_any), n(r_any))
+
+        tables = tuple(state[k] for k in self._TABLES)
+        (tables, win, max_l, max_r, anchor, wm, win_end, match,
+         sums) = jax.vmap(fire_first)(
+            tables, state["win"], state["max_ts_left"],
+            state["max_ts_right"], state["anchor"], left, lv, right, rv)
+        out, dropped = self._emit(match, sums, win_end)
+        tables, win, late, n_left, n_right = jax.vmap(assign)(
+            tables, win, wm, left, lv, right, rv)
+        new = dict(zip(self._TABLES, tables))
+        new.update(
+            win=win, max_ts_left=max_l, max_ts_right=max_r, anchor=anchor,
+            late=state["late"] + late, dropped=state["dropped"] + dropped,
+            fired=state["fired"] + out.count(),
+            left_records=state["left_records"] + n_left,
+            right_records=state["right_records"] + n_right)
+        return new, out
+
+    def process_block(self, state, batches, bctx):
+        left, right = batches
+        K, p, _ = left.keys.shape
+        nk, w = self.num_keys, self.open_windows
+        lv, rv = self._in_range(left), self._in_range(right)
+        max_l = self._block_max_ts(state["max_ts_left"], lv,
+                                   left.timestamps)               # [K, P]
+        max_r = self._block_max_ts(state["max_ts_right"], rv,
+                                   right.timestamps)
+        # the anchor a step finds: the state's, or what the first step
+        # that brought a record set (``_watermark`` on it sets the same)
+        first = self._anchor_reading(max_l, max_r)
+        at = jnp.argmax(first != _NO_TS, axis=0)                  # [P]
+        since = jnp.arange(K, dtype=jnp.int32)[:, None] > at[None]
+        anchor = jnp.where(
+            state["anchor"] != _NO_TS, state["anchor"], jnp.where(
+                since, jnp.take_along_axis(first, at[None], axis=0),
+                _NO_TS))
+        wm, anchor = self._watermark(max_l, max_r, anchor)
+
+        cl, _, taken_l, l_any = self._block_place(
+            lv, left.keys, jnp.ones_like(left.values), left.timestamps, wm,
+            bounded=True)
+        sr, cr, taken_r, r_any = self._block_place(
+            rv, right.keys, right.values, right.timestamps, wm,
+            want_counts=True, bounded=True)
+        held, win_end, fire = self._block_slots(
+            state["win"], jnp.maximum(taken_l, taken_r), wm)
+        fire_l = self._block_lanes(fire)                       # [K,P,W*nk]
+        acc, emit = {}, {}
+        for k, contrib in zip(self._TABLES, (cl, cr, sr)):
+            acc[k], emit[k] = self._block_accumulate(
+                state[k].reshape(p, w * nk), contrib, fire_l)
+        out, dropped = self._emit(
+            fire_l & (emit["left"] > 0) & (emit["right"] > 0), emit["sum"],
+            win_end)
+        n = lambda m: jnp.sum(m.astype(jnp.int32), axis=(0, 2))
+        new = {k: acc[k][-1].reshape(p, w, nk) for k in self._TABLES}
+        new.update(
+            win=held[-1], max_ts_left=max_l[-1], max_ts_right=max_r[-1],
+            anchor=anchor[-1],
+            late=state["late"] + n(lv & ~l_any) + n(rv & ~r_any),
+            dropped=state["dropped"] + dropped.sum(axis=0),
+            fired=state["fired"] + out.count().sum(axis=0),
+            left_records=state["left_records"] + n(l_any),
+            right_records=state["right_records"] + n(r_any))
+        return new, out
 
 
 @dataclasses.dataclass

@@ -124,9 +124,10 @@ def _count_route_budget() -> int:
 def note_route(route: str, **shape) -> None:
     """Record of which form an exchange took (``exchange.route``
     instant; chip_smoke.py prints them): ``kernel`` / ``scatter`` /
-    ``sort`` when a dynamic exchange is lowered (trace time), and
-    ``identity`` / ``static`` once per HASH edge the planner took off
-    the dynamic exchange (``CompiledJob._plan_edges``, plan time)."""
+    ``sort`` when a dynamic exchange is lowered (trace time), and once
+    per HASH edge what the planner decided (``CompiledJob._plan_edges``,
+    plan time): ``identity`` / ``static`` off the dynamic exchange, or
+    ``dynamic`` with the ``reason`` it stays there."""
     get_tracer().event("exchange.route", route=route, **shape)
 
 

@@ -1743,18 +1743,21 @@ class ClusterRunner:
         delta_records = total_records - self._last_records_total
         self._m_records.mark(delta_records)
         self._last_records_total = total_records
-        # Event-time windows: what each dropped as late and fired during
-        # the epoch, from the totals the same read brought back.
+        # Event-time windows (and the window join): what each dropped as
+        # late, fired, accepted a side during the epoch, from the totals
+        # the same read brought back (the operator's ``fence_totals``).
         tr = get_tracer()
         totals = vec[heads_end:].astype(np.int64)
-        for i, vid in enumerate(
-                self.executor.compiled.event_window_vertices):
-            name = self.job.vertices[vid].name
-            late, fired = totals[2 * i:2 * i + 2] - self._window_totals.get(
-                vid, 0)
-            self._window_totals[vid] = totals[2 * i:2 * i + 2]
-            tr.count("window.late_records." + name, int(late))
-            tr.count("window.fired_rows." + name, int(fired))
+        at = 0
+        for vid in self.executor.compiled.event_window_vertices:
+            v = self.job.vertices[vid]
+            names = [c for _, c in v.operator.fence_totals]
+            now = totals[at:at + len(names)]
+            at += len(names)
+            since = now - self._window_totals.get(vid, 0)
+            self._window_totals[vid] = now
+            for counter, n in zip(names, since):
+                tr.count(f"{counter}.{v.name}", int(n))
         return delta_records
 
     def _seal_and_trigger(self, closed: int, window_fn, snap_fn,

@@ -52,9 +52,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from clonos_tpu.api.operators import (BlockContext, EventTimeWindow,
-                                      HostFeedSource, OpContext,
-                                      TwoInputOperator)
+from clonos_tpu.api.operators import (BlockContext, HostFeedSource,
+                                      OpContext, TwoInputOperator)
 from clonos_tpu.api.records import RecordBatch, empty, zero_invalid
 from clonos_tpu.causal import log as clog
 from clonos_tpu.causal import determinant as det
@@ -183,11 +182,12 @@ class CompiledJob:
         self.ring_vertices = [v.vertex_id for v in self.job.vertices
                               if self.job.out_edges(v.vertex_id)]
         self.ring_index = {vid: i for i, vid in enumerate(self.ring_vertices)}
-        #: vertices whose state counts late-dropped records and fired
-        #: rows (the fence's health read carries both totals)
+        #: vertices whose state keeps totals the fence's health read
+        #: carries (``fence_totals``: the event-time windows' late-dropped
+        #: records and fired rows, the window join's records a side too)
         self.event_window_vertices = [
             v.vertex_id for v in self.job.vertices
-            if isinstance(v.operator, EventTimeWindow)]
+            if v.operator.fence_totals]
         self._plan_edges()
 
     def _plan_edges(self) -> None:
@@ -218,8 +218,16 @@ class CompiledJob:
 
         ``edge_plans`` keeps the decision per HASH edge (``route`` is
         ``identity``, ``static`` or ``dynamic``) and :meth:`route_edge`
-        acts on it; each planned route is noted once as an
-        ``exchange.route`` instant."""
+        acts on it; each decision is noted once as an ``exchange.route``
+        instant, and one that stays ``dynamic`` says why (``reason``):
+        ``feed-keys`` — the producer does not hold own keys, its
+        records' keys are whatever the data says (behind a source, a
+        map, a FORWARD chain from one) and no declaration would help;
+        ``undeclared`` — the producer holds own keys and states nothing
+        the planner could use (``SessionWindowOperator``,
+        ``IntervalJoinOperator``, a map behind a keyBy: ROADMAP D16);
+        ``rescale`` — it emits own keys into another parallelism;
+        ``too-wide`` — a gather plan would multiply the edge."""
         job = self.job
         G = job.num_key_groups
         #: HASH edges whose producer emits statically-keyed slots get a
@@ -271,13 +279,20 @@ class CompiledJob:
                 elif vid in emits_own and dst_p == p:
                     e.capacity = max(e.capacity, width)
                     plan = EdgePlan("identity", e.capacity, p, p * p)
-                self.edge_plans[eidx] = plan = plan or EdgePlan(
-                    "dynamic", e.capacity, p * dst_p, p * dst_p)
-                if plan.route != "dynamic":
-                    routing.note_route(
-                        plan.route, edge=eidx, width=plan.width,
-                        pairs_kept=plan.pairs_kept,
-                        pairs_total=plan.pairs_total)
+                why = {}
+                if plan is None:
+                    plan = EdgePlan("dynamic", e.capacity, p * dst_p,
+                                    p * dst_p)
+                    why["reason"] = (
+                        "too-wide" if sk is not None
+                        else "feed-keys" if not holds_own
+                        else "undeclared" if vid not in emits_own
+                        else "rescale")
+                self.edge_plans[eidx] = plan
+                routing.note_route(
+                    plan.route, edge=eidx, width=plan.width,
+                    pairs_kept=plan.pairs_kept,
+                    pairs_total=plan.pairs_total, **why)
 
     def _plan_static(self, eidx: int, sk: np.ndarray, src_p: int,
                      dst_p: int, live: Optional[np.ndarray]
@@ -1404,10 +1419,10 @@ class LocalExecutor:
         # an epoch fence these ARE the checkpoint's log heads, so the
         # control plane learns them inside the one read it already pays
         # (recovery's patch phase then needs no head round-trip) — then
-        # (late, fired) of every event-time window vertex.
+        # ``fence_totals`` of every event-time window vertex.
         windows = [carry.op_states[vid][k].sum()
                    for vid in self.compiled.event_window_vertices
-                   for k in ("late", "fired")]
+                   for k, _ in self.job.vertices[vid].operator.fence_totals]
         return jnp.concatenate(
             [vec, carry.record_counts.sum()[None], carry.logs.head]
             + ([jnp.stack(windows)] if windows else []))

@@ -229,8 +229,12 @@ def test_planner_routes_each_hash_edge_by_its_producers_own_keys():
              if r["name"] == "exchange.route"]
     assert [(n["route"], n["edge"], n["width"], n["pairs_kept"],
              n["pairs_total"]) for n in noted] == [
+        ("dynamic", 1, 32, 16, 16),
         ("identity", 3, 32, 4, 16), ("static", 4, 32, 52, 160),
         ("static", 5, 64, 52, 160), ("static", 6, 64, 182, 560)]
+    # the one edge left on the dynamic exchange says why: its producer
+    # is a map behind the source, whose keys are the feed's
+    assert [n.get("reason") for n in noted] == ["feed-keys"] + [None] * 4
 
     older = CompiledJob(_build(dict(
         config(), topology="source-window-reduce-sink", window_steps=8)))
@@ -313,6 +317,9 @@ def _contract_cases():
         ("sliding", ops.SlidingEventTimeWindowOperator(
             num_keys=13, window_size=300, slide=100,
             out_of_orderness=100)),
+        ("window-join", ops.EventTimeWindowJoinOperator(
+            num_keys=13, window_size=400, out_of_orderness=100,
+            capacity=16)),
         ("map-rewrites-keys", ops.MapOperator(
             lambda k, v, t: (k + 1, v, t))),
     ]

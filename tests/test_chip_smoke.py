@@ -55,6 +55,11 @@ def test_part_b_tiny_kill_recover_audited():
                                   "recovery.aot-lower-failed") == 0
 
 
+def test_part_j_tiny_window_join_block_form_against_step_form():
+    assert chip_smoke.check_window_join(21, K=20, P=2, B=32,
+                                        num_keys=256) > 100
+
+
 def test_main_refuses_to_run_without_a_tpu(capsys):
     assert chip_smoke.main([]) != 0
     out = capsys.readouterr()
