@@ -490,8 +490,9 @@ class CheckpointCoordinator:
         with self._state_lock:
             if self._pending.get(checkpoint_id):
                 return
+        tr = get_tracer()
         try:
-            with self._writer_lock:
+            with tr.span("ckpt.read", cid=checkpoint_id), self._writer_lock:
                 ckpt = self.storage.read(checkpoint_id)
         except (KeyError, FileNotFoundError):
             return  # write not durable yet; _on_written will retry
@@ -511,13 +512,13 @@ class CheckpointCoordinator:
         # _writer_lock. The ledger group commit settles first: a
         # durable completion marker must never outrun the sealed
         # entries it certifies.
-        with self._writer_lock:
+        with tr.span("ckpt.mark-complete", cid=checkpoint_id), \
+                self._writer_lock:
             self.storage.flush_ledger()
             try:
                 self.storage.mark_complete(checkpoint_id)
             except NotImplementedError:      # custom storages
                 pass
-        tr = get_tracer()
         if trig is not None:
             # clonos: allow(wallclock): completion latency metric
             lat = time.time() - trig
@@ -541,12 +542,13 @@ class CheckpointCoordinator:
         # append-only, never mutated after start.
         for fn in self._listeners:
             fn(ckpt)
-        self._retain()
-        # Completion == truncation time: collapse re-sealed ledger
-        # duplicates below this fence so the ledger stays one line
-        # per epoch for the life of the job.
-        with self._writer_lock:
-            self.storage.compact_ledger(checkpoint_id)
+        with tr.span("ckpt.retain", cid=checkpoint_id):
+            self._retain()
+            # Completion == truncation time: collapse re-sealed ledger
+            # duplicates below this fence so the ledger stays one line
+            # per epoch for the life of the job.
+            with self._writer_lock:
+                self.storage.compact_ledger(checkpoint_id)
 
     def _retain(self) -> None:
         with self._state_lock:

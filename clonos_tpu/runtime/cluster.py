@@ -563,6 +563,10 @@ class ClusterRunner:
             v.vertex_id: TransactionLog(v.vertex_id)
             for v in job.vertices
             if isinstance(v.operator, TransactionalSinkOperator)}
+        #: the newest block's compaction in flight, with the epoch the
+        #: block ran in: ``(epoch, {sink vertex: PackedBlock})``, None
+        #: once read (runtime/sinktap.py: the tap trails by one block)
+        self._tap_pending: Optional[Tuple[int, Dict[int, Any]]] = None
         if self.txn_logs:
             from clonos_tpu.runtime.sinktap import SinkTap
             compiled = self.executor.compiled
@@ -570,6 +574,7 @@ class ClusterRunner:
                 vid: SinkTap(compiled.mesh, compiled.task_axis)
                 for vid in self.txn_logs}
             self.executor.on_block_outputs = self._absorb_sink_outputs
+            self.executor.drain_block_outputs = self._read_sink_tap
             self.coordinator.subscribe_completion(
                 lambda e: [tl.commit(e) for tl in self.txn_logs.values()])
         #: recovery chunk size: larger than the live block trades a bigger
@@ -588,20 +593,31 @@ class ClusterRunner:
             reader.notify_checkpoint_complete([int(x) for x in off])
 
     def _absorb_sink_outputs(self, outs, epoch: int) -> None:
-        """The sink tap after every block, in three spans: launching the
-        compaction of the sink's rows behind the block program that
-        produced ``outs`` and waiting both out (device busy, not idle),
-        the device-to-host copy of the counts and the packed rows
-        (runtime/sinktap.py), the per-subtask sharding
-        (``TransactionLog.absorb``)."""
-        sinks = {vid: outs.sinks[vid] for vid in self.txn_logs
-                 if vid in outs.sinks}
-        if not sinks:
+        """The sink tap, once a block and one block behind the block
+        program (runtime/sinktap.py). The program that produced ``outs``
+        has just been dispatched: first read the *previous* block's
+        rows, whose compaction was queued ahead of it, then launch this
+        block's compaction behind it, at the rung the count just read
+        suggests. Nothing here waits for ``outs``."""
+        self._read_sink_tap(trailing=1)
+        packed = {vid: self._sink_taps[vid].dispatch(outs.sinks[vid])
+                  for vid in self.txn_logs if vid in outs.sinks}
+        if packed:
+            self._tap_pending = (epoch, packed)
+
+    def _read_sink_tap(self, trailing: int = 0) -> None:
+        """Read the block whose compaction is in flight, if any, into
+        the transaction log of the epoch it ran in, in three spans:
+        waiting the compaction out (device busy, not idle), what is left
+        of the device-to-host copy of the counts and the packed rows,
+        the per-subtask sharding (``TransactionLog.absorb``).
+        ``trailing``: 1 when the block's successor was dispatched before
+        this wait began, 0 for a drain (nothing queued behind it)."""
+        if self._tap_pending is None:
             return
+        (epoch, packed), self._tap_pending = self._tap_pending, None
         tr = get_tracer()
-        with tr.span("block.sink.wait"):
-            packed = {vid: self._sink_taps[vid].dispatch(b)
-                      for vid, b in sinks.items()}
+        with tr.span("block.sink.wait", trailing=trailing):
             jax.block_until_ready([(pk.counts, pk.rows)
                                    for pk in packed.values()])
         with tr.span("block.sink.d2h") as sp:
@@ -613,6 +629,8 @@ class ClusterRunner:
         misses = sum(pk.missed for pk in packed.values())
         tr.count("sink.d2h_bytes", nbytes)
         tr.count("sink.rung_reads", len(packed))
+        if trailing:
+            tr.count("sink.taps_trailing", len(packed))
         if misses:
             tr.count("sink.rung_misses", misses)
         tr.count("block.dispatches.sink_pack", len(packed) + misses)
@@ -2118,8 +2136,11 @@ class ClusterRunner:
         # checkpoint ack all land) makes the post-kill storage state a
         # pure function of the kill point — recovery then sees either a
         # completed fence or a cleanly pending one, never a half-sealed
-        # epoch.
+        # epoch. So does the sink tap: a block still in flight (only
+        # where an exception abandoned a block loop) is read before the
+        # kill decides which pending shards are lost.
         self._join_fence_tail()
+        self._read_sink_tap()
         carry = self.executor.carry
         nr = self.executor.compiled.plan.num_replicas
         for flat in flat_subtasks:

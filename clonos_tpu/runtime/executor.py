@@ -1014,8 +1014,14 @@ class LocalExecutor:
         #: the superstep-boundary hook timer services advance on.
         self.block_listeners: List[Any] = []
         #: optional hook fed (BlockOutputs, epoch_id) after every block —
-        #: the transactional-sink egress tap (runtime/txn.py).
+        #: the transactional-sink egress tap (runtime/txn.py). It may
+        #: trail the block program (runtime/sinktap.py): what it still
+        #: holds when a block loop ends, ``drain_block_outputs`` reads.
         self.on_block_outputs: Optional[Any] = None
+        #: optional hook, no arguments, called where a block loop ends:
+        #: after an epoch's last block (before the roll) and after a
+        #: ``step()``.
+        self.drain_block_outputs: Optional[Any] = None
         #: the newest block's outputs, until ``step``/``run_epoch`` hand
         #: them to the caller
         self._block_outs: Optional[BlockOutputs] = None
@@ -1141,10 +1147,14 @@ class LocalExecutor:
     def _host_block(self, k: int) -> None:
         """One host-staged block of ``k`` supersteps: causal inputs and
         feeds up, the block program, the sink tap, the listeners — each
-        under its own span inside ``block``. Its outputs replace
-        ``_block_outs`` at the dispatch, so the previous block's (device
-        buffers and the host copies the sink tap cached on them) are
-        released there and never held across the next block's tap."""
+        under its own span inside ``block``. Nothing here waits for the
+        program it dispatched: the tap (``on_block_outputs``) reads the
+        *previous* block's rows and launches this one's compaction, so
+        the next call prepares and dispatches its block while this one
+        runs. Its outputs replace ``_block_outs`` at the dispatch, so
+        the previous block's are released there; only the dense sink
+        batch the tap kept for a read-again lives on, until that tap
+        reads it a few lines further down."""
         tr = get_tracer()
         with tr.span("block", epoch=self.epoch_id, k=k, program="run_block"):
             inputs = self._next_block_inputs(k)
@@ -1165,9 +1175,14 @@ class LocalExecutor:
         outs, self._block_outs = self._block_outs, None
         return outs
 
+    def _drain_block_outputs(self) -> None:
+        if self.drain_block_outputs is not None:
+            self.drain_block_outputs()
+
     def step(self) -> StepOutputs:
         """Run one superstep on the live path (a K=1 block)."""
         self._host_block(1)
+        self._drain_block_outputs()
         outs = self._take_block_outs()
         return StepOutputs(
             sinks={vid: jax.tree_util.tree_map(lambda x: x[0], b)
@@ -1182,6 +1197,9 @@ class LocalExecutor:
         while self.step_in_epoch < self.steps_per_epoch:
             self._host_block(min(
                 self.block_steps, self.steps_per_epoch - self.step_in_epoch))
+        # the tap never trails across an epoch boundary: the fence seals
+        # the transaction with the epoch's last block in it
+        self._drain_block_outputs()
         closed = self.epoch_id
         self.epoch_id += 1
         self.step_in_epoch = 0

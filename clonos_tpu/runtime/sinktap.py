@@ -15,6 +15,36 @@ exceeds its rung is read again through the first rung that holds it
 (counted: ``sink.rung_misses``). Every rung of a block shape is built
 when that shape is first seen, so nothing is built later, whichever rung
 a count lands on.
+
+**The tap trails the block program by one block.** The device queue of an
+epoch is ``run_block(k)``, ``sink_pack(k)``, ``run_block(k+1)``,
+``sink_pack(k+1)``, ... with no gap: ``dispatch`` only launches, and
+``ClusterRunner._absorb_sink_outputs`` — called once a block, right after
+block ``k+1`` is dispatched — first waits out and reads ``pack(k)``
+(``block.sink.wait`` with ``trailing=1``, counted in
+``sink.taps_trailing``), shards it under block ``k``'s epoch, and only
+then launches ``pack(k+1)``, so the rung of a block still comes from the
+count of the block before it. The host therefore draws, pulls, puts and
+dispatches block ``k+1`` while the chip runs block ``k``. The dense
+``PackedBlock.batch`` kept for a read-again lives across that one
+dispatch and no longer.
+
+The tap never trails out of a block loop. It is drained (the same three
+spans, ``trailing=0``: nothing is queued behind the block waited for)
+
+- by ``LocalExecutor.run_epoch`` after an epoch's last block, before the
+  roll and so before the fence of either mode: ``fence.txn-seal``, the
+  lineage plane's ``TransactionLog.pending_shards`` and the checkpoint
+  see every block of the closed epoch, sharded under *its* id;
+- by ``LocalExecutor.step`` after its one-step block: whoever steps may
+  read the sink next;
+- at entry to ``ClusterRunner.inject_failure``: a no-op unless an
+  exception abandoned a block loop (both loops above drain themselves),
+  and then the block is read before the kill decides which pending
+  shards are lost.
+
+``recover`` and ``failover_drill`` start from a kill, and ``rescale_live``
+from a completed fence: nothing is in flight where they begin.
 """
 
 from __future__ import annotations
@@ -103,9 +133,10 @@ class _Ladder:
 
 @dataclasses.dataclass
 class PackedBlock:
-    """One block's compaction in flight: the device arrays of the rung
-    it was speculated at; once read, the rung it was read through, the
-    bytes copied and whether it had to be read again."""
+    """One block's compaction in flight, from its launch behind the
+    block program to its read one block later: the device arrays of the
+    rung it was speculated at; once read, the rung it was read through,
+    the bytes copied and whether it had to be read again."""
     ladder: _Ladder
     batch: RecordBatch
     rung: int

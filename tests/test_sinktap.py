@@ -186,6 +186,7 @@ def test_a_count_over_the_speculated_rung_is_read_again_and_counted():
         before = tr.counters().get("sink.rung_misses", 0)
         runner._absorb_sink_outputs(
             types.SimpleNamespace(sinks={vid: batch}), 0)
+        runner._read_sink_tap()          # the tap only launched: drain it
         d2h = [r for r in tr.records() if r["name"] == "block.sink.d2h"][-1]
         assert d2h["args"]["rung"] == rung
         assert tr.counters().get("sink.rung_misses", 0) - before == missed

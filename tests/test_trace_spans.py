@@ -16,10 +16,16 @@ from clonos_tpu.obs import trace as trace_mod
 from clonos_tpu.runtime import sinktap
 
 #: the spans one host-fed block emits, in the order they close (the
-#: parent last) — PERF.md section 3 carries the same names
-BLOCK_SPANS = ["block.causal-inputs", "block.feed.pull", "block.feed.put",
-               "block.dispatch", "block.sink.wait", "block.sink.d2h",
-               "block.sink.shard", "block.notify", "block"]
+#: parent last) — PERF.md section 3 carries the same names. The sink
+#: tap trails by one block: the three ``block.sink.*`` spans inside a
+#: ``block`` read the block BEFORE it, so an epoch's (or a step's) first
+#: block has none, and the last block is read by the same three spans
+#: right behind its ``block``, as its siblings (the drain).
+SINK_SPANS = ["block.sink.wait", "block.sink.d2h", "block.sink.shard"]
+FIRST_BLOCK_SPANS = ["block.causal-inputs", "block.feed.pull",
+                     "block.feed.put", "block.dispatch", "block.notify",
+                     "block"]
+BLOCK_SPANS = FIRST_BLOCK_SPANS[:4] + SINK_SPANS + FIRST_BLOCK_SPANS[4:]
 
 
 @pytest.fixture(autouse=True)
@@ -29,7 +35,7 @@ def _fresh_recorder():
     obs.reset()
 
 
-def _served_runner(tmp_path, overlap=False):
+def _served_runner(tmp_path, overlap=False, block_steps=8):
     """A tiny host-fed job of the served shape: host source -> keyBy ->
     count window -> keyBy -> reduce -> transactional sink."""
     import chip_smoke as cs
@@ -43,7 +49,7 @@ def _served_runner(tmp_path, overlap=False):
     runner = ClusterRunner(
         job, steps_per_epoch=16, log_capacity=512, max_epochs=8,
         inflight_ring_steps=64, seed=1, logical_time=True, audit=False,
-        checkpoint_dir=str(tmp_path / "ck"), block_steps=8,
+        checkpoint_dir=str(tmp_path / "ck"), block_steps=block_steps,
         overlap_epoch=overlap)
     runner.executor.register_feed(
         0, ListFeedReader(list(cs.make_feed(shape, 3))))
@@ -231,10 +237,11 @@ def test_a_host_fed_block_emits_exactly_its_spans_in_order(tmp_path):
     recs = tr.records()
     blocks = [r for r in recs if r["name"] == "block"]
     assert len(blocks) == 4                         # two a 16-step epoch
-    for b in blocks:
+    for i, b in enumerate(blocks):
         assert b["args"]["k"] == 8 and b["args"]["program"] == "run_block"
         kids = _children(recs, b)
-        assert [k["name"] for k in kids] + ["block"] == BLOCK_SPANS
+        assert [k["name"] for k in kids] + ["block"] == (
+            BLOCK_SPANS if i % 2 else FIRST_BLOCK_SPANS)
         assert all(_inside(k, b) for k in kids)
         starts = [k["mono"] for k in kids]
         assert starts == sorted(starts)
@@ -250,17 +257,42 @@ def test_a_host_fed_block_emits_exactly_its_spans_in_order(tmp_path):
         assert names == ["epoch.steps", "fence"]
         steps = _children(recs, e)[0]
         assert [k["name"] for k in _children(recs, steps)] == [
-            "block", "block", "epoch.roll"]
+            "block", "block"] + SINK_SPANS + ["epoch.roll"]
+    # one wait, one copy, one sharding a block read, whoever reads it:
+    # inside the next block (trailing) or at the drain
+    waits = [r for r in recs if r["name"] == "block.sink.wait"]
+    assert [w["args"] for w in waits] == [{"trailing": 1},
+                                          {"trailing": 0}] * 2
+    for name in SINK_SPANS:
+        assert sum(r["name"] == name for r in recs) == len(blocks)
     # at most 16 records a block and 16 a fence
     per_epoch = len(recs) / len(epochs)
     assert per_epoch <= 2 * 16 + 16 + 3
-    # a single step is a block of one, with the same spans
+    # a single step is a block of one, drained at once
     obs.reset()
     runner.step()
     recs = [r for r in obs.get_tracer().records()
             if r["ph"] == "X"]          # its first call compiles: instants
-    assert [r["name"] for r in recs] == BLOCK_SPANS
-    assert recs[-1]["args"]["k"] == 1
+    assert [r["name"] for r in recs] == FIRST_BLOCK_SPANS + SINK_SPANS
+    assert recs[5]["args"]["k"] == 1
+    assert recs[6]["args"] == {"trailing": 0}
+
+
+@pytest.mark.parametrize("block_steps", [16, 8, 4, 2])
+def test_the_share_of_trailing_taps_is_all_but_an_epochs_last_block(
+        tmp_path, block_steps):
+    runner = _served_runner(tmp_path, block_steps=block_steps)
+    for _ in range(3):
+        runner.run_epoch(complete_checkpoint=True)
+    tr = obs.get_tracer()
+    b = 16 // block_steps
+    c = tr.counters()
+    assert c["sink.rung_reads"] == 3 * b
+    assert c.get("sink.taps_trailing", 0) * b == c["sink.rung_reads"] * (b - 1)
+    waits = [r["args"] for r in tr.records()
+             if r["name"] == "block.sink.wait"]
+    assert len(waits) == 3 * b and all(set(w) == {"trailing"} for w in waits)
+    assert sum(w["trailing"] for w in waits) == c.get("sink.taps_trailing", 0)
 
 
 def test_block_notify_span_only_with_listeners(tmp_path):
