@@ -520,7 +520,8 @@ def print_routes(tracer, since: int, part: str) -> int:
         if "edge" in a:        # planned, once per HASH edge and job built
             line = (f"edge {a['edge']}: {a['route']}, {a['width']} wide, "
                     f"{a['pairs_kept']} of {a['pairs_total']} pairs kept"
-                    + (f" ({a['reason']})" if "reason" in a else ""))
+                    + (f" ({a['reason']}; {a['between']}, capacity "
+                       f"{a['capacity']})" if "reason" in a else ""))
         else:                  # a dynamic exchange, as it was lowered
             line = (f"K={a['steps']} n={a['records']} T={a['targets']} "
                     f"cap={a['capacity']}: {a['route']}")
@@ -534,6 +535,11 @@ def print_routes(tracer, since: int, part: str) -> int:
                   if r["name"] == "hist.kernel"))
     for line, c in sorted(kernels.items()):
         say(f"{part} histogram {line} (traced {c}x)")
+    # what the fences have read of the exchange so far (totals, which
+    # only grow: the fullest step of a dynamic edge, records dropped)
+    for name, n in sorted(tracer.counters().items()):
+        if name.startswith("exchange."):
+            say(f"{part} counter {name} = {n}")
     return len(recs)
 
 

@@ -205,6 +205,43 @@ class DataStream:
             capacity=capacity or self._env.default_edge_capacity)
         return self._attach2(other, name, op, parallelism, edge_capacity)
 
+    def window_top(self, num_keys: int, window_size: int,
+                   slide: Optional[int] = None, out_of_orderness: int = 0,
+                   capacity: Optional[int] = None,
+                   own_columns: Optional[int] = None,
+                   edge_capacity: Optional[int] = None,
+                   name: str = "window-top",
+                   parallelism: Optional[int] = None) -> "DataStream":
+        """Event-time windowed sum per key — sliding by ``slide``,
+        tumbling without it — of which a window that fires emits only
+        its largest: one row ``(key, sum, window end - 1)`` per key whose
+        sum equals the largest this subtask holds for the window, ties kept
+        (operators.EventTimeWindowTopOperator). Two of them make NEXmark
+        query 5, "Hot Items": behind ``key_by()`` a sliding count per
+        item at the job's parallelism (values of 1 summed), whose rows —
+        each subtask's own leaders — a second, tumbling by the first
+        one's slide at ``parallelism=1``, sums per item and cuts to the
+        leaders over all subtasks. Requires key_by().
+
+        ``capacity``: rows a subtask may emit a step (rows past it are
+        counted, and stop the run at the next fence). ``own_columns``:
+        hold a table column only for the keys a subtask owns, that many
+        a subtask; the planner binds them and refuses the job if a
+        subtask owns more. Without it every subtask holds ``num_keys``
+        columns. ``edge_capacity``: the receive window of the input
+        edge — under skewed keys, the records the fullest subtask is
+        sent in a step; the exchange counts what it drops, and a drop
+        stops the run too."""
+        from clonos_tpu.api.operators import EventTimeWindowTopOperator
+        if not self._keyed:
+            raise ValueError("window_top requires key_by() first")
+        op = EventTimeWindowTopOperator(
+            num_keys=num_keys, window_size=window_size,
+            slide=slide or window_size, out_of_orderness=out_of_orderness,
+            capacity=capacity or self._env.default_edge_capacity,
+            own_columns=own_columns)
+        return self._attach(name, op, parallelism, capacity=edge_capacity)
+
     def rebalance(self) -> "DataStream":
         s = DataStream(self._env, self._vertex)
         s._force_rebalance = True

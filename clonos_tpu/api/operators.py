@@ -106,8 +106,28 @@ class Operator:
     #: (``<counter>.<vertex name>``): ``((state key, counter), ...)``
     fence_totals: Tuple[Tuple[str, str], ...] = ()
 
+    #: the state keys among ``fence_totals`` that count LOSSES the job's
+    #: guarantees do not allow (a record refused, a row past a
+    #: capacity): a non-zero one is an overflow message at the next
+    #: fence (``LocalExecutor.overflow_messages``), in every run.
+    fence_losses: Tuple[str, ...] = ()
+
+    #: columns a subtask holds of a table keyed over ``num_keys`` ids,
+    #: where it holds a column only for the ids it owns (None: no such
+    #: table). The planner binds them: ``CompiledJob._plan_edges`` works
+    #: out which ids each subtask owns and ``init_carry`` hands them to
+    #: :meth:`bind_own_columns`, so the binding is a leaf of the
+    #: vertex's state and the operator object knows nothing of a plan.
+    own_columns: Optional[int] = None
+
     def init_state(self, parallelism: int) -> Any:
         return ()
+
+    def bind_own_columns(self, state: Any, cols: np.ndarray) -> Any:
+        """``state`` with ``cols`` (int32 ``[P, own_columns]``: the ids
+        subtask ``p`` owns, ascending, then :data:`NO_KEY`) bound."""
+        raise NotImplementedError(
+            f"{type(self).__name__} holds no own columns")
 
     def process(self, state: Any, batch: RecordBatch,
                 ctx: OpContext) -> Tuple[Any, RecordBatch]:
@@ -555,6 +575,9 @@ _NO_WINDOW = -(2 ** 30)
 
 #: "no valid record yet": the fold's identity for ``max_ts``
 _NO_TS = -(2 ** 31) + 1
+#: an own column bound to no key (``Operator.own_columns``): past every
+#: key, so that the bound ones stay an ascending prefix
+NO_KEY = 2 ** 31 - 1
 
 
 def _segmented_cumsum(values: jnp.ndarray, reset: jnp.ndarray
@@ -658,17 +681,20 @@ class _EventTimeSlots:
         return jnp.maximum(max_ts0[None], jax.lax.cummax(step_max, axis=0))
 
     def _block_place(self, valid, key, values, ts, wm,
-                     want_counts: bool = False, bounded: bool = False):
+                     want_counts: bool = False, bounded: bool = False,
+                     nk: Optional[int] = None):
         """Place a block's records by the watermark ``wm [K, P]`` alone
         (class docstring): ``(sums, counts, taken, ok_any)`` — per-step
         sums of ``values`` (and record counts, ``want_counts``) per
         (slot, key) through the keyed histogram over the composite lane
         ``slot * nk + key`` (``[K, P, W * nk]``), the largest window id
         accepted into each slot at each step (``[K, P, W]``), and which
-        records were accepted at all."""
+        records were accepted at all. ``nk``: the key lanes a slot has,
+        where that is not ``num_keys`` (own columns; ``key`` is then the
+        column)."""
         from clonos_tpu.ops.histogram import KERNEL_MAX_KEYS, keyed_hist
         K, p, _ = valid.shape
-        nk, w = self.num_keys, self.open_windows
+        nk, w = self.num_keys if nk is None else nk, self.open_windows
         slots = jnp.arange(w, dtype=jnp.int32)
         base = ts // self._slide
         ok_any = jnp.zeros_like(valid)
@@ -712,10 +738,10 @@ class _EventTimeSlots:
         fire = (before != _NO_WINDOW) & (win_end <= wm[:, :, None])
         return held, win_end, fire
 
-    def _block_lanes(self, x):
+    def _block_lanes(self, x, nk: Optional[int] = None):
         """``[K, P, W]`` -> ``[K, P, W * nk]``: a slot's value on each of
         its key lanes."""
-        return jnp.repeat(x, self.num_keys, axis=2)
+        return jnp.repeat(x, self.num_keys if nk is None else nk, axis=2)
 
     def _block_accumulate(self, acc0, contrib, fire_l):
         """``(acc, emit)``, ``[K, P, W * nk]``: the accumulators after
@@ -1481,6 +1507,209 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
             left_records=state["left_records"] + n(l_any),
             right_records=state["right_records"] + n(r_any))
         return new, out
+
+
+@dataclasses.dataclass
+class EventTimeWindowTopOperator(_EventTimeSlots, Operator):
+    """Event-time windowed sum per key, sliding or tumbling, of which a
+    window that fires emits only its LARGEST: one row ``(key, sum,
+    window end - 1)`` for every key whose non-zero sum equals the largest
+    non-zero sum the subtask holds for that window, ties kept (NEXmark
+    query 5, "Hot Items": ``num >= max(num)`` per window — a first stage
+    behind ``key_by()`` sums per key and passes on each subtask's
+    largest, a second at parallelism 1 sums those rows per key, each key
+    owned by one subtask, and keeps the largest over all of them:
+    Flink's ``windowAll``, Beam's ``Combine.globally``). A row is
+    stamped with its window's last millisecond — Flink's
+    ``maxTimestamp()`` — so that a window of the same grid downstream
+    takes it for the same window, which is what lets the second stage
+    be this operator again, tumbling by the first one's slide.
+
+    Watermark, slots and fire are :class:`_EventTimeSlots`'s, the
+    batched-watermark rule and the late count :class:`EventTimeWindow`'s
+    (``wm = max(event_ts seen) - out_of_orderness``, advanced once a
+    step before the step's records are assigned; windows with end <= wm
+    fire FIRST). Each fire's rows are compacted in (slot, column) order
+    into ``capacity`` rows a subtask a step; rows past it are dropped
+    and counted in ``dropped``. ``late`` counts the records no window
+    took — behind the watermark, or with a key this subtask holds no
+    column for (below) — and ``fired`` the rows emitted. ``late`` and
+    ``dropped`` are ``fence_losses``: a non-zero one stops the run at
+    the next fence.
+
+    **Own columns.** The table is ``[open_windows, columns]`` a subtask,
+    and ``state["cols"]`` (int32 ``[P, columns]``, ascending, then
+    :data:`NO_KEY`) says which key each column holds. Without
+    ``own_columns`` there is a column for every key in ``[0, num_keys)``
+    on every subtask (dense). With it a subtask holds ``own_columns``
+    columns, bound by the planner to the keys that subtask owns
+    (``CompiledJob._plan_edges``; the job must reach this vertex through
+    ``key_by()``): a table over 8,192 ids at parallelism 16 is 640
+    columns wide, not 8,192. The binding is state — one operator object
+    serves any number of plans, a checkpoint carries it — and a record
+    whose key has no column on its subtask is counted in ``late``, never
+    read as no record. A record's column is its key's rank among the
+    subtask's bound keys, by comparison with all of them (no gather by a
+    computed index); the block form has no scan over the steps and no
+    scatter, and agrees with the step form bit for bit.
+    """
+
+    num_keys: int
+    window_size: int
+    slide: int
+    out_of_orderness: int = 0
+    capacity: int = 16
+    own_columns: Optional[int] = None
+    open_windows: int = 2
+
+    emits_received_keys = True
+
+    fence_totals = EventTimeWindow.fence_totals + (
+        ("dropped", "window.dropped_rows"),)
+    fence_losses = ("late", "dropped")
+
+    def __post_init__(self):
+        if self.window_size % self.slide:
+            raise ValueError("window_size must be a multiple of slide")
+        need = (self.out_of_orderness + self.window_size) // self.slide + 2
+        self.open_windows = max(self.open_windows, need)
+
+    @property
+    def _slide(self) -> int:
+        return self.slide
+
+    @property
+    def out_capacity(self):  # type: ignore[override]
+        return self.capacity
+
+    @property
+    def _columns(self) -> int:
+        return self.num_keys if self.own_columns is None else self.own_columns
+
+    def init_state(self, parallelism: int):
+        p, w, c = parallelism, self.open_windows, self._columns
+        # dense: a column a key; own columns: none bound yet, every
+        # record is refused until the planner binds them
+        cols = (jnp.arange(c, dtype=jnp.int32) if self.own_columns is None
+                else jnp.full((c,), NO_KEY, jnp.int32))
+        state = {k: jnp.zeros((p,), jnp.int32) for k, _ in self.fence_totals}
+        state.update(
+            acc=jnp.zeros((p, w, c), jnp.int32),
+            win=jnp.full((p, w), _NO_WINDOW, jnp.int32),
+            max_ts=jnp.full((p,), _NO_TS, jnp.int32),
+            cols=jnp.broadcast_to(cols, (p, c)))
+        return state
+
+    def bind_own_columns(self, state, cols):
+        cols = jnp.asarray(cols, jnp.int32)
+        if cols.shape != state["cols"].shape:
+            raise ValueError(
+                f"own columns {cols.shape} for a table of "
+                f"{state['cols'].shape}")
+        return dict(state, cols=cols)
+
+    def rescale_keyed_state(self, state, new_parallelism, num_key_groups):
+        raise NotImplementedError(
+            "EventTimeWindowTopOperator does not support rescaling: its "
+            "columns are bound to the keys each subtask owns when the job "
+            "is planned, and a live rescale would have to bind them anew")
+
+    def _column(self, cols, keys):
+        """``(column, held)`` of each record's key on its subtask:
+        ``cols [P, C]`` against ``keys [..., P, B]``. The bound keys are
+        an ascending prefix, so a held key's column is the count of
+        bound keys below it."""
+        k, c = keys[..., None], cols[:, None, :]
+        return (jnp.sum((k > c).astype(jnp.int32), axis=-1),
+                jnp.any(k == c, axis=-1))
+
+    def _emit(self, acc, fire, win_end, cols):
+        """The rows of one or many steps: of the accumulators ``acc
+        [..., P, W * C]`` as the fire finds them, the lanes of a firing
+        slot (``fire``, ``win_end``: ``[..., P, W]``) that hold its
+        largest non-zero sum, compacted in lane order into ``[..., P,
+        capacity]`` rows, and how many did not fit ``[..., P]``. A row's
+        place is its rank among those lanes, so the compaction is one
+        keyed histogram over ranks that carries ``slot * num_keys + key``
+        (a lane is its slot and its column, a column its key); sum and
+        stamp are its slot's."""
+        from clonos_tpu.ops.histogram import keyed_hist
+        nk, w, c, cap = (self.num_keys, self.open_windows, self._columns,
+                         self.capacity)
+        by_slot = acc.reshape(acc.shape[:-1] + (w, c))
+        none = jnp.iinfo(jnp.int32).min           # a slot with no sum
+        top = jnp.max(jnp.where(by_slot != 0, by_slot, none),
+                      axis=-1)                                # [..., P, W]
+        match = ((fire & (top != none))[..., None]
+                 & (by_slot == top[..., None])).reshape(acc.shape)
+        word = (jnp.arange(w, dtype=jnp.int32)[None, :, None] * nk
+                + jnp.where(cols != NO_KEY, cols, 0)[:, None, :]
+                ).reshape(-1, w * c)                          # [P, W * C]
+        rank = _running_count(match) - 1
+        total = rank[..., -1] + 1
+        words, _ = keyed_hist(rank, jnp.broadcast_to(word, match.shape),
+                              match, cap, want_counts=False)
+        valid = jnp.arange(cap, dtype=jnp.int32) < total[..., None]
+        slot = words // nk
+        of_slot = lambda x: sum(
+            jnp.where(slot == s, x[..., s:s + 1], 0) for s in range(w))
+        return (zero_invalid(RecordBatch(words % nk, of_slot(top),
+                                         of_slot(win_end - 1), valid)),
+                jnp.maximum(total - cap, 0))
+
+    def process(self, state, batch, ctx):
+        p, w, c = batch.keys.shape[0], self.open_windows, self._columns
+        col, held = self._column(state["cols"], batch.keys)
+
+        def fire_first(acc, win, max_ts, b: RecordBatch):
+            max_ts = jnp.maximum(max_ts, jnp.max(
+                jnp.where(b.valid, b.timestamps, _NO_TS)))
+            wm = max_ts - self.out_of_orderness
+            win_end, fire = self._fire_step(win, wm)              # [W]
+            return (jnp.where(fire[:, None], 0, acc),
+                    jnp.where(fire, _NO_WINDOW, win), max_ts, wm, win_end,
+                    fire)
+
+        def assign(acc, win, wm, b: RecordBatch, col, held):
+            win, placed, ok_any = self._place_step(
+                win, wm, b.valid & held, b.timestamps)
+            for slot, ok in placed:
+                acc = acc.at[slot, col].add(jnp.where(ok, b.values, 0),
+                                            mode="drop")
+            return acc, win, jnp.sum((b.valid & ~ok_any).astype(jnp.int32))
+
+        acc, win, max_ts, wm, win_end, fire = jax.vmap(fire_first)(
+            state["acc"], state["win"], state["max_ts"], batch)
+        out, dropped = self._emit(state["acc"].reshape(p, w * c), fire,
+                                  win_end, state["cols"])
+        acc, win, late = jax.vmap(assign)(acc, win, wm, batch, col, held)
+        return dict(
+            state, acc=acc, win=win, max_ts=max_ts,
+            late=state["late"] + late, fired=state["fired"] + out.count(),
+            dropped=state["dropped"] + dropped), out
+
+    def process_block(self, state, batches, bctx):
+        p = batches.keys.shape[1]
+        w, c = self.open_windows, self._columns
+        valid = batches.valid
+        col, held = self._column(state["cols"], batches.keys)
+        max_ts = self._block_max_ts(state["max_ts"], valid,
+                                    batches.timestamps)
+        wm = max_ts - self.out_of_orderness                       # [K, P]
+        contrib, _, taken, ok_any = self._block_place(
+            valid & held, col, batches.values, batches.timestamps, wm,
+            nk=c)
+        held_win, win_end, fire = self._block_slots(state["win"], taken, wm)
+        acc, found = self._block_accumulate(
+            state["acc"].reshape(p, w * c), contrib,
+            self._block_lanes(fire, c))
+        out, dropped = self._emit(found, fire, win_end, state["cols"])
+        n = lambda m: jnp.sum(m.astype(jnp.int32), axis=(0, 2))
+        return dict(
+            state, acc=acc[-1].reshape(p, w, c), win=held_win[-1],
+            max_ts=max_ts[-1], late=state["late"] + n(valid & ~ok_any),
+            fired=state["fired"] + out.count().sum(axis=0),
+            dropped=state["dropped"] + dropped.sum(axis=0)), out
 
 
 @dataclasses.dataclass

@@ -109,6 +109,9 @@ CARRY_PARTITION_RULES: Tuple[Tuple[str, Optional[int]], ...] = (
     # Operator state / depth-1 edge buffers / record counts lead with
     # the (destination) subtask axis.
     (r"(^|/)(op_states|edge_bufs|record_counts)($|/)", 0),
+    # The exchange's counters are per destination subtask, like the
+    # edge's buffer.
+    (r"(^|/)exchange/", 0),
 )
 
 

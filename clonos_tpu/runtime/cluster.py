@@ -510,9 +510,10 @@ class ClusterRunner:
         self._route_cache_enabled = False
         #: observability/test hook: cache hits in the last recover()
         self._route_cache_hits = 0
-        #: vertex id -> (late, fired) totals of an event-time window at
-        #: the last fence
-        self._window_totals: Dict[int, np.ndarray] = {}
+        #: counter fed from the fence's health read (an event-time
+        #: window's ``fence_totals``, ``exchange.*``) -> its total at the
+        #: last fence
+        self._fence_counter_totals: Dict[str, int] = {}
         self._last_records_total = 0
         #: checkpoint id -> np [L] log heads at that fence, harvested from
         #: the per-epoch health read (recovery's patch phase reads them
@@ -1739,8 +1740,8 @@ class ClusterRunner:
         """Fold one fence's drained health vector into the host mirrors
         (runs inline on the sequential path, on the fence worker when
         pipelined). Returns the epoch's record delta."""
-        nf = 4 + len(self.executor.carry.out_rings)
-        total_records = int(vec[nf])
+        parts = self.executor.health_parts(vec)
+        total_records = int(parts["records"][0])
         # The heads at this fence ARE checkpoint ``closed``'s log
         # heads (the SOURCE_CHECKPOINT appends come after and belong
         # to the new epoch) — recovery's patch phase reads them from
@@ -1751,31 +1752,33 @@ class ClusterRunner:
         # evicting in insertion order is oldest-first and O(1) — a
         # pruned-but-needed entry only costs the patch fallback's one
         # device read.
-        heads_end = nf + 1 + self.executor.compiled.L
         with self._ck_heads_lock:
-            self._ck_log_heads[closed] = vec[nf + 1:heads_end].astype(
-                np.int64)
+            self._ck_log_heads[closed] = parts["heads"].astype(np.int64)
             while len(self._ck_log_heads) > 128:
                 self._ck_log_heads.pop(
                     next(iter(self._ck_log_heads)))
         delta_records = total_records - self._last_records_total
         self._m_records.mark(delta_records)
         self._last_records_total = total_records
-        # Event-time windows (and the window join): what each dropped as
-        # late, fired, accepted a side during the epoch, from the totals
-        # the same read brought back (the operator's ``fence_totals``).
+        # Counters the same read feeds, each its growth since the last
+        # fence: what every event-time window (and the window join)
+        # dropped as late, fired, accepted a side (the operator's
+        # ``fence_totals``), then the exchange — records an edge has
+        # dropped, and the most a target of a dynamic edge has been sent
+        # in one step; both only grow, and stay absent while 0.
+        compiled = self.executor.compiled
+        seen = [(f"{counter}.{v.name}", n, True) for (v, _, counter), n
+                in zip(compiled.fence_total_slots(), parts["totals"])]
+        seen += [(f"exchange.dropped_records.{compiled.edge_name(e)}", n,
+                  False) for e, n in enumerate(parts["dropped"])]
+        seen += [(f"exchange.peak_records.{compiled.edge_name(e)}", n, False)
+                 for e, n in zip(compiled.peak_edges(), parts["peak"])]
         tr = get_tracer()
-        totals = vec[heads_end:].astype(np.int64)
-        at = 0
-        for vid in self.executor.compiled.event_window_vertices:
-            v = self.job.vertices[vid]
-            names = [c for _, c in v.operator.fence_totals]
-            now = totals[at:at + len(names)]
-            at += len(names)
-            since = now - self._window_totals.get(vid, 0)
-            self._window_totals[vid] = now
-            for counter, n in zip(names, since):
-                tr.count(f"{counter}.{v.name}", int(n))
+        for counter, n, even_zero in seen:
+            grown = int(n) - self._fence_counter_totals.get(counter, 0)
+            self._fence_counter_totals[counter] = int(n)
+            if grown or even_zero:
+                tr.count(counter, grown)
         return delta_records
 
     def _seal_and_trigger(self, closed: int, window_fn, snap_fn,

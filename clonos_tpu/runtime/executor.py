@@ -109,6 +109,16 @@ class JobCarry(NamedTuple):
                                         # producer's subtask shards
     replicas: clog.ThreadLogState       # stacked [R, cap, lanes] downstream
                                         # determinant replicas
+    exchange: Tuple[Dict[str, jnp.ndarray], ...] = ()
+                                        # per edge, int32[P_dst] each and
+                                        # sharded like the edge's buffer:
+                                        # "dropped", the records the edge
+                                        # has dropped past its capacity,
+                                        # and on a dynamic HASH edge
+                                        # "peak", the most a target has
+                                        # been sent in one step; monotone,
+                                        # reduced only in the fence's
+                                        # health read
 
 
 class LeanSnapshot(NamedTuple):
@@ -234,6 +244,10 @@ class CompiledJob:
         #: compile-time gather plan instead of the sort exchange.
         self.static_route: Dict[int, routing.StaticRoutePlan] = {}
         self.edge_plans: Dict[int, EdgePlan] = {}
+        #: vertex -> the ids each subtask owns, for an operator that
+        #: holds a table column only for those (``Operator.own_columns``):
+        #: int32 [P, own_columns], ascending, then ``NO_KEY``
+        self.own_columns: Dict[int, np.ndarray] = {}
         emits_own = set()     # vertices that emit own keys
         # A static route leaves holes (slots are bound to (producer, key)
         # pairs, not compacted), an identity route keeps its producer's,
@@ -256,6 +270,13 @@ class CompiledJob:
                         e_in.partition == PartitionType.FORWARD
                         and e_in.src in sparse):
                     sparse.add(vid)
+            if op.own_columns is not None:
+                if not holds_own:
+                    raise ValueError(
+                        f"vertex {v.name!r} holds table columns only for "
+                        f"the keys its subtasks own, so every input must "
+                        f"be keyed: key_by() before it")
+                self.own_columns[vid] = self._bind_own_columns(vid)
             sk, clamp = op.static_out_keys(), op.static_clamp_keys()
             live = None
             if sk is None:
@@ -288,11 +309,57 @@ class CompiledJob:
                         else "feed-keys" if not holds_own
                         else "undeclared" if vid not in emits_own
                         else "rescale")
+                    why["capacity"] = e.capacity
+                    why["between"] = self.edge_name(eidx)
                 self.edge_plans[eidx] = plan
                 routing.note_route(
                     plan.route, edge=eidx, width=plan.width,
                     pairs_kept=plan.pairs_kept,
                     pairs_total=plan.pairs_total, **why)
+
+    def _bind_own_columns(self, vid: int) -> np.ndarray:
+        """The columns of a vertex that holds own keys: per subtask the
+        ids ``routing.own_slots`` gives it, ascending; a subtask that
+        owns more than the operator has columns refuses the plan."""
+        from clonos_tpu.api.operators import NO_KEY
+        v = self.job.vertices[vid]
+        op, p = v.operator, v.parallelism
+        own = routing.own_slots(np.arange(op.num_keys), p,
+                                self.job.num_key_groups)
+        most = int(own.sum(axis=1).max())
+        if most > op.own_columns:
+            raise ValueError(
+                f"vertex {v.name!r}: subtask {int(own.sum(axis=1).argmax())}"
+                f" owns {most} of the {op.num_keys} keys, more than the "
+                f"{op.own_columns} own columns a subtask has")
+        cols = np.full((p, op.own_columns), NO_KEY, np.int32)
+        for q in range(p):
+            keys = np.nonzero(own[q])[0]
+            cols[q, :len(keys)] = keys
+        return cols
+
+    def edge_name(self, eidx: int) -> str:
+        """``<producer>-><consumer>``: how counters and overflow
+        messages name an edge."""
+        e = self.job.edges[eidx]
+        return (f"{self.job.vertices[e.src].name}->"
+                f"{self.job.vertices[e.dst].name}")
+
+    def fence_total_slots(self) -> List[Tuple[Any, str, str]]:
+        """``(vertex, state key, counter)`` of each total the fence's
+        health read carries, in the vector's order (the operators'
+        ``fence_totals``)."""
+        vertices = [self.job.vertices[vid]
+                    for vid in self.event_window_vertices]
+        return [(v, key, counter) for v in vertices
+                for key, counter in v.operator.fence_totals]
+
+    def peak_edges(self) -> List[int]:
+        """The edges whose fill the carry follows (``exchange[e]
+        ["peak"]``): the HASH edges that stay on the dynamic exchange,
+        the only ones on which the data decides how full a target is."""
+        return [e for e, plan in sorted(self.edge_plans.items())
+                if plan.route == "dynamic"]
 
     def _plan_static(self, eidx: int, sk: np.ndarray, src_p: int,
                      dst_p: int, live: Optional[np.ndarray]
@@ -448,8 +515,13 @@ class CompiledJob:
             # but the block path appends 4K rows per block and requires
             # block <= capacity; enforced in run_block.
             pass
-        op_states = tuple(
-            v.operator.init_state(v.parallelism) for v in self.job.vertices)
+        def init_state(v):
+            state = v.operator.init_state(v.parallelism)
+            cols = self.own_columns.get(v.vertex_id)
+            return (state if cols is None
+                    else v.operator.bind_own_columns(state, cols))
+
+        op_states = tuple(init_state(v) for v in self.job.vertices)
         edge_bufs = tuple(
             empty((self.job.vertices[e.dst].parallelism, e.capacity))
             for e in self.job.edges)
@@ -463,9 +535,14 @@ class CompiledJob:
             for vid in self.ring_vertices)
         replicas = rep.create_replicas(self.plan, self.log_capacity,
                                        self.max_epochs)
+        peak = set(self.peak_edges())
+        exchange = tuple(
+            {k: jnp.zeros((self.job.vertices[e.dst].parallelism,), jnp.int32)
+             for k in (("dropped", "peak") if i in peak else ("dropped",))}
+            for i, e in enumerate(self.job.edges))
         carry = JobCarry(op_states, edge_bufs, rr,
                          jnp.zeros((self.L,), jnp.int32), logs, out_rings,
-                         replicas)
+                         replicas, exchange)
         return self.constrain_carry(carry)
 
     # --- the block program --------------------------------------------------
@@ -493,6 +570,7 @@ class CompiledJob:
         rr_offsets = list(carry.rr_offsets)
         out_rings = list(carry.out_rings)
         new_edge_bufs = list(carry.edge_bufs)
+        exchange = list(carry.exchange)
         routed: Dict[int, RecordBatch] = {}
         sinks: Dict[int, RecordBatch] = {}
         dropped: Dict[int, jnp.ndarray] = {}
@@ -562,6 +640,14 @@ class CompiledJob:
                                       jnp.int32))
                 routed[eidx] = self._shard_block(r)
                 dropped[eidx] = d
+                # per target, so nothing crosses a shard here: the sums
+                # over targets are the fence's (``_health_vector``)
+                stats = {"dropped": exchange[eidx]["dropped"] + d.sum(axis=0)}
+                if "peak" in exchange[eidx]:
+                    stats["peak"] = jnp.maximum(
+                        exchange[eidx]["peak"],
+                        (routed[eidx].count() + d).max(axis=0))
+                exchange[eidx] = self._shard_tree(stats)
                 new_edge_bufs[eidx] = jax.tree_util.tree_map(
                     lambda x: x[-1], routed[eidx])
 
@@ -608,7 +694,7 @@ class CompiledJob:
         new_carry = JobCarry(
             tuple(op_states), tuple(new_edge_bufs), tuple(rr_offsets),
             carry.record_counts + consumed_all.sum(axis=0), logs,
-            tuple(out_rings), replicas)
+            tuple(out_rings), replicas, tuple(exchange))
         new_carry = self.constrain_carry(new_carry)
         return new_carry, BlockOutputs(sinks, dropped, consumed_all)
 
@@ -1437,13 +1523,35 @@ class LocalExecutor:
         # an epoch fence these ARE the checkpoint's log heads, so the
         # control plane learns them inside the one read it already pays
         # (recovery's patch phase then needs no head round-trip) — then
-        # ``fence_totals`` of every event-time window vertex.
-        windows = [carry.op_states[vid][k].sum()
-                   for vid in self.compiled.event_window_vertices
-                   for k, _ in self.job.vertices[vid].operator.fence_totals]
+        # ``fence_totals`` of every event-time window vertex, then every
+        # edge's dropped records and the dynamic edges' fullest step
+        # (the one place the exchange's per-target counters are reduced).
+        tail = [carry.op_states[v.vertex_id][k].sum()
+                for v, k, _ in self.compiled.fence_total_slots()]
+        tail += [x["dropped"].sum() for x in carry.exchange]
+        tail += [carry.exchange[e]["peak"].max()
+                 for e in self.compiled.peak_edges()]
         return jnp.concatenate(
             [vec, carry.record_counts.sum()[None], carry.logs.head]
-            + ([jnp.stack(windows)] if windows else []))
+            + ([jnp.stack(tail)] if tail else []))
+
+    def health_parts(self, vec: np.ndarray) -> Dict[str, np.ndarray]:
+        """A health vector by what its entries are: ``flags``,
+        ``records`` (one total), ``heads`` (a log head a task),
+        ``totals`` (the ``fence_totals`` of each vertex that has any, in
+        ``event_window_vertices`` order), ``dropped`` (an entry an edge)
+        and ``peak`` (one a ``peak_edges()`` edge)."""
+        c = self.compiled
+        sizes = (("flags", 4 + len(self.carry.out_rings)), ("records", 1),
+                 ("heads", c.L),
+                 ("totals", len(c.fence_total_slots())),
+                 ("dropped", len(self.job.edges)),
+                 ("peak", len(c.peak_edges())))
+        parts, at = {}, 0
+        for name, n in sizes:
+            parts[name] = vec[at:at + n]
+            at += n
+        return parts
 
     def health_vector(self) -> np.ndarray:
         if not hasattr(self, "_jit_health"):
@@ -1508,6 +1616,19 @@ class LocalExecutor:
                            f"with spill disabled")
         if vec[3 + len(self.carry.out_rings)]:
             out.append("replica log ring overflow")
+        # Losses, loud in every run: records an edge dropped past its
+        # capacity, and what an operator counts as lost (``fence_losses``).
+        parts = self.health_parts(vec)
+        for eidx, n in enumerate(parts["dropped"]):
+            if n:
+                out.append(
+                    f"edge {self.compiled.edge_name(eidx)} dropped {int(n)} "
+                    f"records past its capacity "
+                    f"{self.job.edges[eidx].capacity}")
+        for (v, key, counter), n in zip(self.compiled.fence_total_slots(),
+                                        parts["totals"]):
+            if key in v.operator.fence_losses and n:
+                out.append(f"vertex {v.name!r} lost {int(n)} ({counter})")
         return out
 
     def check_overflow(self) -> List[str]:

@@ -30,7 +30,9 @@ def build_job():
                           name="words")
         .key_by()
         .window_count(num_keys=VOCAB, window_size=WINDOW_MS, name="window")
-        .sink(name="print"))
+        # the window emits a slot a word: an edge of the default 256
+        # would drop words 256-999, and a drop stops the run
+        .sink(name="print", capacity=VOCAB))
     return env.build()
 
 
@@ -41,7 +43,7 @@ def build_socket_job(host: str = "localhost", port: int = 9999):
     (env.host_source(batch_size=64, parallelism=1, name="socket")
         .key_by()
         .window_count(num_keys=VOCAB, window_size=WINDOW_MS, name="window")
-        .sink(name="print"))
+        .sink(name="print", capacity=VOCAB))
     return env.build()
 
 

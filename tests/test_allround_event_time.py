@@ -320,6 +320,9 @@ def _contract_cases():
         ("window-join", ops.EventTimeWindowJoinOperator(
             num_keys=13, window_size=400, out_of_orderness=100,
             capacity=16)),
+        ("window-top", ops.EventTimeWindowTopOperator(
+            num_keys=13, window_size=300, slide=100, out_of_orderness=100,
+            capacity=16)),
         ("map-rewrites-keys", ops.MapOperator(
             lambda k, v, t: (k + 1, v, t))),
     ]

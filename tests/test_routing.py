@@ -390,3 +390,89 @@ def test_lane_routes_bit_identical_to_full_route_lane(cap, K, P, B):
         for a, b in zip(got, full_fw):
             np.testing.assert_array_equal(np.asarray(a),
                                           np.asarray(b[:, lane]))
+
+
+# --- the exchange's counters in the carry, and drops that are loud -----------
+
+
+def _fed_runner(build, keys, B, steps_per_epoch=8):
+    """A ``ClusterRunner`` over ``build(env)``'s job whose host source
+    (one partition a row of ``keys [P, steps * B]``) is fed that table."""
+    from clonos_tpu.api.environment import StreamEnvironment
+    from clonos_tpu.api.feeds import ListFeedReader
+    from clonos_tpu.runtime.cluster import ClusterRunner
+    env = StreamEnvironment(name="edges", num_key_groups=16,
+                            default_edge_capacity=B)
+    build(env, env.host_source(batch_size=B, parallelism=len(keys)))
+    runner = ClusterRunner(env.build(), steps_per_epoch=steps_per_epoch,
+                           block_steps=4, log_capacity=512,
+                           inflight_ring_steps=32, logical_time=True,
+                           audit=False, overlap_epoch=False)
+    runner.executor.register_feed(0, ListFeedReader(
+        [[(int(k), 1) for k in row] for row in keys]))
+    return runner
+
+
+def test_exchange_counters_hold_each_targets_peak_and_drops():
+    """A dynamic HASH edge of capacity 3 into four subtasks: per target,
+    the carry's ``peak`` is the most records a step sent it and
+    ``dropped`` the records past the capacity, as NumPy counts them from
+    the table; both only grow, and the fence's read reduces them."""
+    from clonos_tpu.api.operators import KeyedReduceOperator
+    P, B, steps, cap, T = 2, 8, 12, 3, 4
+    keys = np.random.RandomState(5).randint(0, 40, (P, steps * B))
+    runner = _fed_runner(
+        lambda env, src: src.key_by()._attach(
+            "reduce", KeyedReduceOperator(num_keys=40), T,
+            capacity=cap).sink(capacity=cap), keys, B)
+    compiled = runner.executor.compiled
+    assert compiled.peak_edges() == [0] and compiled.edge_name(0) == \
+        "host-source->reduce"
+    sent = np.zeros((steps, T), np.int64)
+    for s in range(steps):
+        k = keys[:, s * B:(s + 1) * B].ravel()
+        tgt = (_np_hash32(k) % 16).astype(np.int64) * T // 16
+        sent[s] = np.bincount(tgt, minlength=T)
+    seen = []
+    for s in range(steps):
+        runner.step()
+        ex = runner.executor.carry.exchange[0]
+        seen.append((np.asarray(ex["peak"]), np.asarray(ex["dropped"])))
+        np.testing.assert_array_equal(seen[-1][0], sent[:s + 1].max(axis=0))
+        np.testing.assert_array_equal(
+            seen[-1][1], np.maximum(sent[:s + 1] - cap, 0).sum(axis=0))
+    assert all((b[0] >= a[0]).all() and (b[1] >= a[1]).all()
+               for a, b in zip(seen, seen[1:]))
+    ex = runner.executor
+    parts = ex.health_parts(ex.health_vector())
+    assert parts["peak"].tolist() == [sent.max()]
+    assert parts["dropped"].tolist() == [
+        np.maximum(sent - cap, 0).sum(), 0]
+
+
+@pytest.mark.parametrize("kind", ["forward", "rebalance", "hash"])
+def test_a_drop_on_any_edge_stops_the_run_and_names_the_edge(kind):
+    """16 records a step into an edge that holds 4 a target: whatever
+    the partitioner, ``check_overflow()`` has a line for the edge and
+    the fence raises it; the job with room enough has none."""
+    from clonos_tpu.runtime.cluster import OverflowError_
+    keys = np.random.RandomState(9).randint(0, 8, (2, 8 * 8))
+
+    def build(cap):
+        def job(env, src):
+            stream = {"forward": src, "rebalance": src.rebalance(),
+                      "hash": src.key_by()}[kind]
+            stream.map(lambda k, v, t: (k, v, t), name="narrow",
+                       capacity=cap).sink(capacity=16)
+        return job
+
+    roomy = _fed_runner(build(16), keys, 8)
+    roomy.run_epoch()
+    assert roomy.executor.check_overflow() == []
+    runner = _fed_runner(build(4), keys, 8)
+    with pytest.raises(OverflowError_,
+                       match="edge host-source->narrow dropped"):
+        runner.run_epoch()
+    (line,) = runner.executor.check_overflow()
+    assert line.startswith("edge host-source->narrow dropped ") \
+        and line.endswith(" records past its capacity 4")

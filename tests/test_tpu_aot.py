@@ -104,3 +104,41 @@ def test_block_program_lowers_for_four_chip_mesh_with_kernel(v5e):
     assert routes == ["kernel"], "the exchange must take the kernel path"
     assert "tpu_custom_call" in lowered.as_text()
     lowered.compile()
+
+
+def test_window_top_block_form_compiles_at_the_cells_widths(v5e):
+    """``nexmark-q5``'s ``count`` vertex at its own widths — 8,192 ids in
+    640 own columns a subtask, 7 open windows, 768 records a subtask a
+    step — over 64 steps of 16 subtasks: its five placements and its
+    emission take the Mosaic kernel (two bodies: ``[., 768] -> 4,480``
+    lanes and ``[., 4,480] -> 32``), and the column lookup (every record
+    against 640 keys) fuses without a ``[K, P, B, 640]`` array."""
+    from clonos_tpu.api.operators import (BlockContext,
+                                          EventTimeWindowTopOperator)
+    from clonos_tpu.api.records import RecordBatch
+    mesh = Mesh(np.array(v5e[:1]), ("tasks",))
+    sh = NamedSharding(mesh, PartitionSpec())
+    op = EventTimeWindowTopOperator(
+        num_keys=8192, window_size=10000, slide=2000, out_of_orderness=111,
+        capacity=32, own_columns=640)
+    K, P, B = 64, 16, 768
+    shaped = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
+    state = jax.tree_util.tree_map(
+        shaped, jax.eval_shape(lambda: op.init_state(P)))
+    lane = lambda dt: jax.ShapeDtypeStruct((K, P, B), dt, sharding=sh)
+    batches = RecordBatch(lane(jnp.int32), lane(jnp.int32), lane(jnp.int32),
+                          lane(jnp.bool_))
+    steps = jax.ShapeDtypeStruct((K,), jnp.int32, sharding=sh)
+
+    def block(state, batches, times):
+        with histogram.kernel_mesh(mesh, "tasks"):
+            return op.process_block(state, batches, BlockContext(
+                times=times, rng_bits=times, epoch=jnp.int32(0),
+                step0=jnp.int32(0),
+                subtask=jnp.arange(P, dtype=jnp.int32)))
+
+    lowered = jax.jit(block).lower(state, batches, steps)
+    assert lowered.as_text().count("tpu_custom_call") == 2
+    compiled = lowered.compile()
+    # the [K, P, B, 640] comparison never exists as an array
+    assert compiled.memory_analysis().temp_size_in_bytes < K * P * B * 640
