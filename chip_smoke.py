@@ -524,7 +524,8 @@ def print_routes(tracer, since: int, part: str) -> int:
                        f"{a['capacity']})" if "reason" in a else ""))
         else:                  # a dynamic exchange, as it was lowered
             line = (f"K={a['steps']} n={a['records']} T={a['targets']} "
-                    f"cap={a['capacity']}: {a['route']}")
+                    f"cap={a['capacity']}: {a['route']}"
+                    + (f", rank {a['rank']}" if "rank" in a else ""))
         counts[line] = counts.get(line, 0) + 1
     for line, c in sorted(counts.items()):
         say(f"{part} exchange {line} (traced {c}x)")

@@ -756,25 +756,6 @@ class _EventTimeSlots:
         return acc, jnp.concatenate([acc0[None], acc[:-1]], axis=0)
 
 
-def _running_count(mask: jnp.ndarray, group: int = 128) -> jnp.ndarray:
-    """Inclusive running count of ``mask`` along its last axis, int32
-    (``cumsum`` bit for bit). A scan along the lanes is strided slices
-    of the minor dimension; this counts inside groups of ``group`` lanes
-    by a product with a triangular matrix (0/1 operands, sums of at most
-    ``group``: exact) and runs the sum over the groups' totals only."""
-    n = mask.shape[-1]
-    pad = (-n) % group
-    m = jnp.pad(mask, [(0, 0)] * (mask.ndim - 1) + [(0, pad)])
-    m = m.reshape(m.shape[:-1] + (-1, group)).astype(jnp.bfloat16)
-    upto = (jnp.arange(group)[:, None] <= jnp.arange(group)[None, :])
-    within = jnp.dot(m, upto.astype(jnp.bfloat16),
-                     preferred_element_type=jnp.float32).astype(jnp.int32)
-    totals = within[..., -1]
-    before = jnp.cumsum(totals, axis=-1) - totals
-    return (within + before[..., None]).reshape(
-        mask.shape[:-1] + (-1,))[..., :n]
-
-
 class EventTimeWindow(_EventTimeSlots, Operator):
     """Event-time windowed sum per key with watermark-driven firing: what
     the tumbling and the sliding operator share (a tumbling window is a
@@ -1393,8 +1374,9 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
         a keyed histogram over ranks: one call carries the sums, one the
         lanes (a lane is its slot and its key)."""
         from clonos_tpu.ops.histogram import keyed_hist
+        from clonos_tpu.ops.matops import running_count
         nk, w, cap = self.num_keys, self.open_windows, self.capacity
-        rank = _running_count(match) - 1
+        rank = running_count(match) - 1
         total = rank[..., -1] + 1
         lane = jnp.broadcast_to(jnp.arange(w * nk, dtype=jnp.int32),
                                 match.shape)
@@ -1634,6 +1616,7 @@ class EventTimeWindowTopOperator(_EventTimeSlots, Operator):
         (a lane is its slot and its column, a column its key); sum and
         stamp are its slot's."""
         from clonos_tpu.ops.histogram import keyed_hist
+        from clonos_tpu.ops.matops import running_count
         nk, w, c, cap = (self.num_keys, self.open_windows, self._columns,
                          self.capacity)
         by_slot = acc.reshape(acc.shape[:-1] + (w, c))
@@ -1645,7 +1628,7 @@ class EventTimeWindowTopOperator(_EventTimeSlots, Operator):
         word = (jnp.arange(w, dtype=jnp.int32)[None, :, None] * nk
                 + jnp.where(cols != NO_KEY, cols, 0)[:, None, :]
                 ).reshape(-1, w * c)                          # [P, W * C]
-        rank = _running_count(match) - 1
+        rank = running_count(match) - 1
         total = rank[..., -1] + 1
         words, _ = keyed_hist(rank, jnp.broadcast_to(word, match.shape),
                               match, cap, want_counts=False)

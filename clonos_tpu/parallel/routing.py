@@ -35,6 +35,7 @@ from clonos_tpu.api.records import RecordBatch, zero_invalid
 from clonos_tpu.obs.trace import get_tracer
 from clonos_tpu.ops.histogram import (KERNEL_MAX_KEYS, keyed_hist,
                                       uses_kernel)
+from clonos_tpu.ops.matops import running_count
 
 
 def hash32(x: jnp.ndarray) -> jnp.ndarray:
@@ -107,9 +108,11 @@ _COUNT_ROUTE_MIN_BYTES = 256 << 20
 
 @functools.cache
 def _count_route_budget() -> int:
-    """Cap on the counting exchange's ``[K, n, T+1]`` cumsum scratch
-    (priced at ~3 concurrent buffers); routes past it take the flat sort
-    or, long ones, count chunk by chunk (:func:`_block_to_targets`).
+    """Cap on the counting exchange's scratch, priced at 12 bytes an
+    entry of ``[K, n, T+1]``; it holds 10 an entry of ``[K, T, n]`` (the
+    bf16 one-hot, the f32 product with the triangle, the int32 running
+    count). Routes past it take the flat sort or, long ones, count chunk
+    by chunk (:func:`_block_to_targets`).
     ~2% of the device's memory limit, within [256 MiB, 2 GiB]: ~336 MB
     on a 16 GB v5e, so the ~0.9 GB whole-recovery-window route at bench
     shapes sorts there instead of crowding the GB-scale log state. A TPU
@@ -141,8 +144,9 @@ _SORT_ROUTE_MAX_RECORDS = 1 << 21
 
 #: records in one chunk of the chunked counting route, at most: what the
 #: blocks that count whole already hold (1,024 steps x 1,024 records).
-#: Past it the compiler's time for the one-hot running count is erratic
-#: (for the v5e: 6 s at [256, 4096] records, 85 s at [512, 4096]).
+#: Set when the running count was a ``cumsum`` over the records, whose
+#: compile time past it was erratic (for the v5e: 6 s at [256, 4096]
+#: records, 85 s at [512, 4096]).
 _COUNT_CHUNK_MAX_RECORDS = 1 << 20
 
 
@@ -164,12 +168,13 @@ def _count_to_targets(
     fl = lambda x: jnp.reshape(x, (K, n))
     keys, vals, ts, valid = map(fl, batch)
     tgt = jnp.where(valid, fl(target), T)
-    onehot = (tgt[:, :, None] ==
-              jnp.arange(T + 1, dtype=jnp.int32)[None, None, :])
-    pos_all = jnp.cumsum(onehot.astype(jnp.int32), axis=1)
-    pos = jnp.take_along_axis(
-        pos_all, tgt[:, :, None], axis=2)[:, :, 0] - 1
-    counts = pos_all[:, -1, :T]
+    # [K, T, n]: an invalid record is in no row, so its rank reads -1 and
+    # ``keep`` is false. No index is computed from the counts: a gather
+    # on the v5e costs ~11 ns an element, whatever T is.
+    onehot = tgt[:, None, :] == jnp.arange(T, dtype=jnp.int32)[None, :, None]
+    count = running_count(onehot)
+    pos = jnp.sum(jnp.where(onehot, count, 0), axis=1) - 1
+    counts = count[:, :, -1]
     keep = (tgt < T) & (pos < out_capacity)
     dropped = jnp.maximum(counts - out_capacity, 0).astype(jnp.int32)
     # Placement: (target, rank) pairs are UNIQUE per step, so a keyed
@@ -181,7 +186,8 @@ def _count_to_targets(
     nk = T * out_capacity
     via_hist = nk <= KERNEL_MAX_KEYS
     note_route("kernel" if via_hist and uses_kernel() else "scatter",
-               steps=K, records=n, targets=T, capacity=out_capacity)
+               steps=K, records=n, targets=T, capacity=out_capacity,
+               rank="tri")
     if via_hist:
         slot = jnp.where(keep, tgt * out_capacity + pos, -1)
         out_k, cnt = keyed_hist(slot, keys, keep, nk)
@@ -213,16 +219,20 @@ def _block_to_targets(
 
     A record's slot within its target is its *arrival rank*: the count of
     same-target records before it in (p-major, slot) order. With T
-    targets that is a running per-bucket count — one cumsum over a
-    ``[K, n, T+1]`` one-hot (invalid records get bucket T), no argsort.
-    The TPU executes the cumsum as a few vector passes, cheaper than
-    the sort this replaced; placement is then ONE flat scatter of the K*n
-    records into ``[K, T+1, cap]`` (the +1 row swallows drops).
+    targets that is a running count per target over a ``[K, T, n]``
+    one-hot, the records on the lanes (an invalid record is in no row) —
+    products with a 128 x 128 triangle on the MXU plus the tiles'
+    offsets (``matops.running_count``), no argsort — and a record reads
+    its own rank as the sum over the T rows of the count under its
+    one-hot: no index is computed, so no gather. Placement is a keyed
+    histogram over ``target * cap + rank`` a field (one contribution a
+    slot), or past ``KERNEL_MAX_KEYS`` slots one element scatter a field
+    into ``[K, T+1, cap]`` (the +1 row swallows drops).
     Bit-identical to vmapping :func:`_scatter_to_targets` per step,
     including overflow accounting (first ``cap`` arrivals per target
     survive, the rest count as dropped).
 
-    Routes whose cumsum scratch would exceed :func:`_count_route_budget`
+    Routes whose counting scratch would exceed :func:`_count_route_budget`
     (huge T or K) take one block-wide composite-key sort
     (``step * (T+1) + target``, stable) with gather placement, up to
     ``_SORT_ROUTE_MAX_RECORDS``; longer blocks count chunk after chunk
@@ -231,9 +241,8 @@ def _block_to_targets(
     K, P, B = batch.keys.shape
     T = num_targets
     n = P * B
-    # Price the ~3 concurrent [K, n, T+1] buffers this branch holds (the
-    # one-hot's int32 cast, the cumsum output, and one fusion temp), not
-    # just one — the cap must actually bound peak scratch.
+    # Priced above what the branch holds (_count_route_budget) — the cap
+    # must actually bound peak scratch.
     per_step = n * (T + 1) * 4 * 3
     if K * per_step <= _count_route_budget():
         return _count_to_targets(batch, target, T, out_capacity)
@@ -285,7 +294,7 @@ def _block_to_target_lane(batch: RecordBatch, target: jnp.ndarray,
 
     A record's slot within its target is its arrival rank; for a single
     lane that is a running count over a ``[K, n]`` membership mask — no
-    ``[K, n, T+1]`` one-hot — so scratch and compute shrink by (T+1)x
+    ``[K, T, n]`` one-hot — so scratch and compute shrink T-fold
     and the single-failure replay exchange counts a whole recovery
     window in one piece, where the full route goes chunk by chunk."""
     K, P, B = batch.keys.shape

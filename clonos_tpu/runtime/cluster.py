@@ -864,7 +864,7 @@ class ClusterRunner:
         own) — to all destination lanes (``sub`` None), or to the
         single consumer lane ``sub`` DIRECTLY, bit-identical to the full
         route's lane: a dynamic exchange then counts a [m, n] membership
-        mask (routing._block_to_target_lane) instead of the [m, n, T+1]
+        mask (routing._block_to_target_lane) instead of the [m, T, n]
         one-hot, a whole window of m steps in one piece where the full
         exchange goes chunk by chunk."""
         compiled = self.executor.compiled

@@ -116,13 +116,24 @@ def _rand_block(rng, K, P, B, vocab=37, fill=0.7):
         jnp.asarray(valid)))
 
 
+#: blocks the counting route's rank must not get wrong: every record of a
+#: step on one target (ranks run past the capacity, ``dropped`` is exact),
+#: and no valid record at all. The shapes below bring a step shorter than
+#: one 128-record tile of the running count (3 x 16) and one of 37 1/2
+#: tiles (8 x 600); T = 1 is the broadcast's route and the second
+#: ``(T, G)`` pair.
+_TRAFFIC = {"mixed": {}, "one-target": dict(vocab=1, fill=1.0),
+            "none-valid": dict(fill=0.0)}
+
+
 @pytest.mark.parametrize("cap,K,P,B", [
     (4, 7, 3, 16), (16, 7, 3, 16), (64, 7, 3, 16),
     (32, 5, 8, 600),
 ])
 @pytest.mark.parametrize("force_sort", [False, True])
+@pytest.mark.parametrize("traffic", list(_TRAFFIC))
 def test_block_routes_bit_identical_to_per_step(cap, K, P, B, force_sort,
-                                                monkeypatch):
+                                                traffic, monkeypatch):
     """The block exchange (both the counting branch and the flat-sort
     fallback) must equal vmapping the per-step exchange, including
     overflow-drop accounting (the executor switched to the block form for
@@ -131,7 +142,7 @@ def test_block_routes_bit_identical_to_per_step(cap, K, P, B, force_sort,
     if force_sort:   # shrink the scratch budget so the sort path runs
         monkeypatch.setattr(routing, "_count_route_budget", lambda: 0)
     rng = np.random.RandomState(3)
-    batch = _rand_block(rng, K, P, B)
+    batch = _rand_block(rng, K, P, B, **_TRAFFIC[traffic])
     for T, G in [(4, 8), (1, 4), (5, 20)]:
         r1, d1 = jax.vmap(
             lambda b: routing.route_hash(b, T, G, cap))(batch)
@@ -166,7 +177,8 @@ def test_block_routes_bit_identical_to_per_step(cap, K, P, B, force_sort,
 @pytest.mark.parametrize("cap,K,P,B", [
     (4, 12, 3, 16), (64, 12, 3, 16), (32, 6, 8, 600),
 ])
-def test_chunked_count_route_bit_identical_to_per_step(cap, K, P, B,
+@pytest.mark.parametrize("traffic", list(_TRAFFIC))
+def test_chunked_count_route_bit_identical_to_per_step(cap, K, P, B, traffic,
                                                        monkeypatch):
     """A block longer than the flat sort may route, with a counting
     scratch over budget, counts chunk after chunk of steps: equal to the
@@ -178,19 +190,34 @@ def test_chunked_count_route_bit_identical_to_per_step(cap, K, P, B,
     monkeypatch.setattr(routing, "_count_route_budget",
                         lambda: 3 * P * B * (T + 1) * 12)
     monkeypatch.setattr(routing, "_SORT_ROUTE_MAX_RECORDS", 0)
-    batch = _rand_block(np.random.RandomState(5), K, P, B)
+    batch = _rand_block(np.random.RandomState(5), K, P, B,
+                        **_TRAFFIC[traffic])
     tracer = trace.configure("chunked-route-test")
     try:
         r2, d2 = routing.route_hash_block(batch, T, G, cap)
-        took = [(r["args"]["route"], r["args"]["steps"])
+        took = [(r["args"]["route"], r["args"]["steps"], r["args"]["rank"])
                 for r in tracer.records() if r["name"] == "exchange.route"]
     finally:
         trace.reset()
-    assert took == [("scatter", 3)]
+    assert took == [("scatter", 3, "tri")]
     r1, d1 = jax.vmap(lambda b: routing.route_hash(b, T, G, cap))(batch)
     for a, b in zip(jax.tree_util.tree_leaves((r1, d1)),
                     jax.tree_util.tree_leaves((r2, d2))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("cap", [16, 4096])
+def test_counting_route_lowers_without_a_gather(cap):
+    """A gather on the v5e costs per index (~11 ns an element, whatever
+    it reads): the counting route reads a record's arrival rank by a
+    masked sum over the targets' running counts, and neither placement
+    (the keyed histogram up to ``KERNEL_MAX_KEYS`` slots, the element
+    scatter past them) brings one back."""
+    import jax
+    batch = _rand_block(np.random.RandomState(2), 4, 8, 160)
+    text = jax.jit(lambda b: routing.route_hash_block(b, 8, 64, cap)
+                   ).lower(batch).as_text()
+    assert "dot_general" in text and "gather" not in text
 
 
 def test_step_chunk_is_a_divisor_within_the_limit():

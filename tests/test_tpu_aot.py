@@ -97,11 +97,12 @@ def test_block_program_lowers_for_four_chip_mesh_with_kernel(v5e):
             in_shardings=(compiled.carry_shardings(carry),
                           NamedSharding(mesh, PartitionSpec()))
         ).lower(carry, BlockInputs(steps, steps, scalar, scalar))
-        routes = [r["args"]["route"] for r in tracer.records()
-                  if r["name"] == "exchange.route"]
+        routes = [(r["args"]["route"], r["args"]["rank"])
+                  for r in tracer.records() if r["name"] == "exchange.route"]
     finally:
         trace.reset()
-    assert routes == ["kernel"], "the exchange must take the kernel path"
+    assert routes == [("kernel", "tri")], \
+        "the exchange must take the kernel path, its rank the triangle's"
     assert "tpu_custom_call" in lowered.as_text()
     lowered.compile()
 
