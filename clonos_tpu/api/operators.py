@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from clonos_tpu.api.records import RecordBatch, empty, zero_invalid
+from clonos_tpu.obs.scopes import scoped
 from clonos_tpu.parallel import routing
 
 
@@ -399,14 +400,16 @@ class KeyedReduceOperator(Operator):
         contrib, _ = keyed_hist(batches.keys, batches.values,
                                 batches.valid, nk,
                                 want_counts=False)        # [K, P, nk]
-        cum = jnp.cumsum(contrib, axis=0)                 # inclusive prefix
-        acc_end = acc0[None] + cum                        # [K, P, nk]
-        out_vals = jnp.where(
-            batches.valid,
-            jnp.take_along_axis(
-                acc_end.reshape(K * p, nk),
-                batches.keys.reshape(K * p, -1), axis=1
-            ).reshape(batches.keys.shape), 0)
+        with jax.named_scope("segsum"):
+            cum = jnp.cumsum(contrib, axis=0)             # inclusive prefix
+            acc_end = acc0[None] + cum                    # [K, P, nk]
+        with jax.named_scope("readback"):
+            out_vals = jnp.where(
+                batches.valid,
+                jnp.take_along_axis(
+                    acc_end.reshape(K * p, nk),
+                    batches.keys.reshape(K * p, -1), axis=1
+                ).reshape(batches.keys.shape), 0)
         return ({"acc": acc0 + cum[-1]},
                 zero_invalid(batches._replace(values=out_vals)))
 
@@ -447,13 +450,15 @@ class KeyedReduceOperator(Operator):
         contrib = vpad[:, pp, idx[:, :, 0]]
         for s in range(1, S):
             contrib = contrib + vpad[:, pp, idx[:, :, s]]  # [K, P, nk]
-        cum = jnp.cumsum(contrib, axis=0)
         acc0 = state["acc"]
-        acc_end = acc0[None] + cum
+        with jax.named_scope("segsum"):
+            cum = jnp.cumsum(contrib, axis=0)
+            acc_end = acc0[None] + cum
         key_of_slot = np.clip(sk, 0, nk - 1)
-        out_vals = jnp.where(
-            batches.valid,
-            acc_end[:, pp, key_of_slot], 0)
+        with jax.named_scope("readback"):
+            out_vals = jnp.where(
+                batches.valid,
+                acc_end[:, pp, key_of_slot], 0)
         return ({"acc": acc0 + cum[-1]},
                 zero_invalid(batches._replace(values=out_vals)))
 
@@ -527,17 +532,17 @@ class TumblingWindowCountOperator(Operator):
         contrib, _ = keyed_hist(batches.keys, batches.values,
                                 batches.valid, nk,
                                 want_counts=False)                # [K, P, nk]
-        cum = jnp.cumsum(contrib, axis=0)                         # [K, P, nk]
-        cum_excl = cum - contrib
-
-        kidx = jnp.arange(K, dtype=jnp.int32)[:, None]
-        lf = jax.lax.associative_scan(                            # [K, P]
-            jnp.maximum, jnp.where(fire, kidx, -1), axis=0)
         from clonos_tpu.ops.matops import onehot_gather_rows
-        seg_base = onehot_gather_rows(cum_excl, jnp.clip(lf, 0, K - 1))
-        acc_end = jnp.where(lf[:, :, None] >= 0, cum - seg_base,
-                            acc0[None] + cum)                     # [K, P, nk]
-        emit = jnp.concatenate([acc0[None], acc_end[:-1]], axis=0)
+        with jax.named_scope("segsum"):
+            cum = jnp.cumsum(contrib, axis=0)                     # [K, P, nk]
+            cum_excl = cum - contrib
+            kidx = jnp.arange(K, dtype=jnp.int32)[:, None]
+            lf = jax.lax.associative_scan(                        # [K, P]
+                jnp.maximum, jnp.where(fire, kidx, -1), axis=0)
+            seg_base = onehot_gather_rows(cum_excl, jnp.clip(lf, 0, K - 1))
+            acc_end = jnp.where(lf[:, :, None] >= 0, cum - seg_base,
+                                acc0[None] + cum)                 # [K, P, nk]
+            emit = jnp.concatenate([acc0[None], acc_end[:-1]], axis=0)
 
         keys = jnp.broadcast_to(jnp.arange(nk, dtype=jnp.int32)[None, None, :],
                                 (K, p, nk))
@@ -680,6 +685,7 @@ class _EventTimeSlots:
         step_max = jnp.max(jnp.where(valid, ts, _NO_TS), axis=2)  # [K, P]
         return jnp.maximum(max_ts0[None], jax.lax.cummax(step_max, axis=0))
 
+    @scoped("place")
     def _block_place(self, valid, key, values, ts, wm,
                      want_counts: bool = False, bounded: bool = False,
                      nk: Optional[int] = None):
@@ -743,6 +749,7 @@ class _EventTimeSlots:
         its key lanes."""
         return jnp.repeat(x, self.num_keys if nk is None else nk, axis=2)
 
+    @scoped("segsum")
     def _block_accumulate(self, acc0, contrib, fire_l):
         """``(acc, emit)``, ``[K, P, W * nk]``: the accumulators after
         each step — ``contrib`` in a running sum that restarts where the
@@ -1045,7 +1052,8 @@ class UnionOperator(TwoInputOperator):
         K, p = left.keys.shape[:2]
         rs = lambda b: jax.tree_util.tree_map(
             lambda x: x.reshape((K * p,) + x.shape[2:]), b)
-        _, out = self.process2(state, rs(left), rs(right), None)
+        with jax.named_scope("compact"):
+            _, out = self.process2(state, rs(left), rs(right), None)
         return state, jax.tree_util.tree_map(
             lambda x: x.reshape((K, p) + x.shape[1:]), out)
 
@@ -1366,6 +1374,7 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
         return jnp.where(base != _NO_TS, base - self.out_of_orderness,
                          _NO_TS), anchor
 
+    @scoped("emit")
     def _emit(self, match, sums, win_end):
         """The rows of one or many steps: ``match [..., W * nk]`` lanes
         compacted, in lane order, into ``[..., capacity]`` rows (key,
@@ -1596,6 +1605,7 @@ class EventTimeWindowTopOperator(_EventTimeSlots, Operator):
             "columns are bound to the keys each subtask owns when the job "
             "is planned, and a live rescale would have to bind them anew")
 
+    @scoped("lookup")
     def _column(self, cols, keys):
         """``(column, held)`` of each record's key on its subtask:
         ``cols [P, C]`` against ``keys [..., P, B]``. The bound keys are
@@ -1605,6 +1615,7 @@ class EventTimeWindowTopOperator(_EventTimeSlots, Operator):
         return (jnp.sum((k > c).astype(jnp.int32), axis=-1),
                 jnp.any(k == c, axis=-1))
 
+    @scoped("emit")
     def _emit(self, acc, fire, win_end, cols):
         """The rows of one or many steps: of the accumulators ``acc
         [..., P, W * C]`` as the fire finds them, the lanes of a firing

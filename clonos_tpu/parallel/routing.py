@@ -171,12 +171,14 @@ def _count_to_targets(
     # [K, T, n]: an invalid record is in no row, so its rank reads -1 and
     # ``keep`` is false. No index is computed from the counts: a gather
     # on the v5e costs ~11 ns an element, whatever T is.
-    onehot = tgt[:, None, :] == jnp.arange(T, dtype=jnp.int32)[None, :, None]
-    count = running_count(onehot)
-    pos = jnp.sum(jnp.where(onehot, count, 0), axis=1) - 1
-    counts = count[:, :, -1]
-    keep = (tgt < T) & (pos < out_capacity)
-    dropped = jnp.maximum(counts - out_capacity, 0).astype(jnp.int32)
+    with jax.named_scope("rank"):
+        onehot = (tgt[:, None, :]
+                  == jnp.arange(T, dtype=jnp.int32)[None, :, None])
+        count = running_count(onehot)
+        pos = jnp.sum(jnp.where(onehot, count, 0), axis=1) - 1
+        counts = count[:, :, -1]
+        keep = (tgt < T) & (pos < out_capacity)
+        dropped = jnp.maximum(counts - out_capacity, 0).astype(jnp.int32)
     # Placement: (target, rank) pairs are UNIQUE per step, so a keyed
     # histogram over the flattened slot id IS the routed batch (sum
     # of one contribution = select) — the Pallas kernel folds it
@@ -188,26 +190,27 @@ def _count_to_targets(
     note_route("kernel" if via_hist and uses_kernel() else "scatter",
                steps=K, records=n, targets=T, capacity=out_capacity,
                rank="tri")
-    if via_hist:
-        slot = jnp.where(keep, tgt * out_capacity + pos, -1)
-        out_k, cnt = keyed_hist(slot, keys, keep, nk)
-        out_v, _ = keyed_hist(slot, vals, keep, nk, want_counts=False)
-        out_t, _ = keyed_hist(slot, ts, keep, nk, want_counts=False)
-        sh = (K, T, out_capacity)
-        out = RecordBatch(out_k.reshape(sh), out_v.reshape(sh),
-                          out_t.reshape(sh), cnt.reshape(sh) > 0)
+    with jax.named_scope("place"):
+        if via_hist:
+            slot = jnp.where(keep, tgt * out_capacity + pos, -1)
+            out_k, cnt = keyed_hist(slot, keys, keep, nk)
+            out_v, _ = keyed_hist(slot, vals, keep, nk, want_counts=False)
+            out_t, _ = keyed_hist(slot, ts, keep, nk, want_counts=False)
+            sh = (K, T, out_capacity)
+            out = RecordBatch(out_k.reshape(sh), out_v.reshape(sh),
+                              out_t.reshape(sh), cnt.reshape(sh) > 0)
+            return zero_invalid(out), dropped
+        row = jnp.where(keep, tgt, T)
+        col = jnp.where(keep, pos, 0)
+        kidx = jnp.arange(K, dtype=jnp.int32)[:, None]
+        shape = (K, T + 1, out_capacity)
+        mk = lambda src, z: jnp.zeros(shape, z).at[kidx, row, col].set(
+            src, mode="drop")
+        out = RecordBatch(mk(keys, jnp.int32), mk(vals, jnp.int32),
+                          mk(ts, jnp.int32), mk(keep, jnp.bool_))
+        out = RecordBatch(out.keys[:, :T], out.values[:, :T],
+                          out.timestamps[:, :T], out.valid[:, :T])
         return zero_invalid(out), dropped
-    row = jnp.where(keep, tgt, T)
-    col = jnp.where(keep, pos, 0)
-    kidx = jnp.arange(K, dtype=jnp.int32)[:, None]
-    shape = (K, T + 1, out_capacity)
-    mk = lambda src, z: jnp.zeros(shape, z).at[kidx, row, col].set(
-        src, mode="drop")
-    out = RecordBatch(mk(keys, jnp.int32), mk(vals, jnp.int32),
-                      mk(ts, jnp.int32), mk(keep, jnp.bool_))
-    out = RecordBatch(out.keys[:, :T], out.values[:, :T],
-                      out.timestamps[:, :T], out.valid[:, :T])
-    return zero_invalid(out), dropped
 
 
 def _block_to_targets(
@@ -264,27 +267,29 @@ def _block_to_targets(
         raise ValueError(f"composite sort key overflow: K={K} T={T}")
     flat = lambda x: jnp.reshape(x, (K * n,))
     keys, vals, ts, valid = map(flat, batch)
-    tgt = jnp.where(valid, flat(target), T)
-    step = jnp.repeat(jnp.arange(K, dtype=jnp.int32), n,
-                      total_repeat_length=K * n)
-    composite = step * (T + 1) + tgt
-    order = jnp.argsort(composite, stable=True)
-    sc = composite[order]
-    # Boundary of every (step, target) run: [K*(T+1)] starts.
-    bounds = jnp.arange(K * (T + 1), dtype=jnp.int32)
-    run_start = jnp.searchsorted(sc, bounds,
-                                 side="left").astype(jnp.int32)
-    run_end = jnp.concatenate(
-        [run_start[1:], jnp.asarray([K * n], jnp.int32)])
-    run_len = (run_end - run_start).reshape(K, T + 1)[:, :T]  # [K, T]
-    dropped = jnp.maximum(run_len - out_capacity, 0).astype(jnp.int32)
-    c = jnp.arange(out_capacity, dtype=jnp.int32)
-    src = run_start.reshape(K, T + 1)[:, :T, None] + c[None, None, :]
-    ok = (c[None, None, :]
-          < jnp.minimum(run_len, out_capacity)[:, :, None])
-    pick = order[jnp.clip(src, 0, K * n - 1)]                # [K, T, cap]
-    out = RecordBatch(keys[pick], vals[pick], ts[pick], ok)
-    return zero_invalid(out), dropped
+    with jax.named_scope("rank"):
+        tgt = jnp.where(valid, flat(target), T)
+        step = jnp.repeat(jnp.arange(K, dtype=jnp.int32), n,
+                          total_repeat_length=K * n)
+        composite = step * (T + 1) + tgt
+        order = jnp.argsort(composite, stable=True)
+        sc = composite[order]
+        # Boundary of every (step, target) run: [K*(T+1)] starts.
+        bounds = jnp.arange(K * (T + 1), dtype=jnp.int32)
+        run_start = jnp.searchsorted(sc, bounds,
+                                     side="left").astype(jnp.int32)
+        run_end = jnp.concatenate(
+            [run_start[1:], jnp.asarray([K * n], jnp.int32)])
+        run_len = (run_end - run_start).reshape(K, T + 1)[:, :T]  # [K, T]
+        dropped = jnp.maximum(run_len - out_capacity, 0).astype(jnp.int32)
+    with jax.named_scope("place"):
+        c = jnp.arange(out_capacity, dtype=jnp.int32)
+        src = run_start.reshape(K, T + 1)[:, :T, None] + c[None, None, :]
+        ok = (c[None, None, :]
+              < jnp.minimum(run_len, out_capacity)[:, :, None])
+        pick = order[jnp.clip(src, 0, K * n - 1)]            # [K, T, cap]
+        out = RecordBatch(keys[pick], vals[pick], ts[pick], ok)
+        return zero_invalid(out), dropped
 
 
 def _block_to_target_lane(batch: RecordBatch, target: jnp.ndarray,
@@ -303,22 +308,24 @@ def _block_to_target_lane(batch: RecordBatch, target: jnp.ndarray,
                records=n, targets=1, capacity=out_capacity)
     fl = lambda x: jnp.reshape(x, (K, n))
     keys, vals, ts, valid = map(fl, batch)
-    tgt = jnp.where(valid, fl(target), -1)
-    hit = tgt == lane
-    pos = jnp.cumsum(hit.astype(jnp.int32), axis=1) - 1
-    keep = hit & (pos < out_capacity)
+    with jax.named_scope("rank"):
+        tgt = jnp.where(valid, fl(target), -1)
+        hit = tgt == lane
+        pos = jnp.cumsum(hit.astype(jnp.int32), axis=1) - 1
+        keep = hit & (pos < out_capacity)
     # Placement is "field value at the record whose rank == c" — ranks
     # are UNIQUE per step, so a keyed histogram over them IS the routed
     # batch (sum of one contribution = select). The Pallas kernel
     # (ops/histogram.py) folds it as a factored one-hot product; an XLA
     # element scatter here ran ~50ms/field at bench shapes.
-    slot = jnp.where(keep, pos, -1)
-    out_k, cnt = keyed_hist(slot, keys, keep, out_capacity)
-    out_v, _ = keyed_hist(slot, vals, keep, out_capacity,
-                          want_counts=False)
-    out_t, _ = keyed_hist(slot, ts, keep, out_capacity,
-                          want_counts=False)
-    return zero_invalid(RecordBatch(out_k, out_v, out_t, cnt > 0))
+    with jax.named_scope("place"):
+        slot = jnp.where(keep, pos, -1)
+        out_k, cnt = keyed_hist(slot, keys, keep, out_capacity)
+        out_v, _ = keyed_hist(slot, vals, keep, out_capacity,
+                              want_counts=False)
+        out_t, _ = keyed_hist(slot, ts, keep, out_capacity,
+                              want_counts=False)
+        return zero_invalid(RecordBatch(out_k, out_v, out_t, cnt > 0))
 
 
 def route_hash_block_lane(batch: RecordBatch, lane, parallelism: int,
@@ -474,18 +481,19 @@ class StaticRoutePlan:
         # rest of the receive window is never written.
         w = self.width
         g = lambda x: x[:, self.src_p[:, :w], self.src_slot[:, :w]]
-        valid = g(out.valid) & self.ok[None, :, :w]
-        routed = zero_invalid(RecordBatch(
-            g(out.keys), g(out.values), g(out.timestamps), valid))
-        routed = RecordBatch(*(jnp.pad(
-            x, ((0, 0), (0, 0), (0, cap - w))) for x in routed))
-        if len(self.drop_p):
-            dv = out.valid[:, self.drop_p, self.drop_slot]  # [K, D]
-            dropped = jnp.zeros((K, T), jnp.int32).at[
-                :, self.drop_t].add(dv.astype(jnp.int32))
-        else:
-            dropped = jnp.zeros((K, T), jnp.int32)
-        return routed, dropped
+        with jax.named_scope("plan"):
+            valid = g(out.valid) & self.ok[None, :, :w]
+            routed = zero_invalid(RecordBatch(
+                g(out.keys), g(out.values), g(out.timestamps), valid))
+            routed = RecordBatch(*(jnp.pad(
+                x, ((0, 0), (0, 0), (0, cap - w))) for x in routed))
+            if len(self.drop_p):
+                dv = out.valid[:, self.drop_p, self.drop_slot]  # [K, D]
+                dropped = jnp.zeros((K, T), jnp.int32).at[
+                    :, self.drop_t].add(dv.astype(jnp.int32))
+            else:
+                dropped = jnp.zeros((K, T), jnp.int32)
+            return routed, dropped
 
 
 def _static_targets(slot_keys: np.ndarray, parallelism: int,

@@ -54,19 +54,11 @@ from clonos_tpu.ops.histogram import over_mesh
 from clonos_tpu.parallel import routing
 from clonos_tpu.runtime import checkpoint as cp
 from clonos_tpu.obs import get_tracer
+from clonos_tpu.obs.scopes import scoped
 from clonos_tpu.storage import SegmentCorruptError, StorageError
 from clonos_tpu.runtime.executor import (DETS_PER_STEP, JobCarry,
                                          LeanSnapshot, LocalExecutor,
                                          LogicalTimeSource)
-
-
-def _scoped(name: str, fn):
-    """``fn`` traced inside ``jax.named_scope(name)``: metadata on the
-    lowered ops, so a profile groups them; the program does not change."""
-    def scoped(*args):
-        with jax.named_scope(name):
-            return fn(*args)
-    return scoped
 
 
 @contextlib.contextmanager
@@ -876,7 +868,7 @@ class ClusterRunner:
             raw = raw._replace(valid=raw.valid & live[:, None, None])
             r, _ = compiled.route_edge(eidx, raw, rr0, lane=sub)
             return r, raw.count().sum()
-        return _scoped("exchange", body)
+        return scoped("exchange")(body)
 
     def _route_raw_fn(self, eidx: int, m: int, all_lanes: bool = False):
         """Spill-path twin of :meth:`_route_chunk_fn`: routes a

@@ -606,9 +606,10 @@ class CompiledJob:
                 ins = empty((K, p, self.vertex_out_capacity(vid)))
                 consumed = None
             slot_keys = self.consumer_slot_keys(vid)
-            # Named scopes are metadata on the lowered ops (an xprof
-            # view groups ``%fusion.N`` under the vertex that made it);
-            # they move neither the program nor its cache key.
+            # Named scopes are metadata on the lowered ops: an xprof
+            # view, and the benchmark's per-layer device times, group
+            # ``%fusion.N`` by them. ``obs/scopes.py`` is the vocabulary;
+            # the program is the same with or without them.
             with jax.named_scope(f"vertex/{v.name}"):
                 if slot_keys is not None and hasattr(
                         v.operator, "process_block_static_keys"):
@@ -676,18 +677,21 @@ class CompiledJob:
         consumed_all = jnp.concatenate(
             [consumed_parts[v.vertex_id] for v in job.vertices], axis=1)
         with jax.named_scope("causal-log"):
-            rows = self._det_rows(binputs, emits_all)             # [L, 4K, 8]
-            logs = clog.v_append_full(carry.logs, rows)
-            logs = self._shard_tree(logs)
+            with jax.named_scope("rows"):
+                rows = self._det_rows(binputs, emits_all)         # [L, 4K, 8]
+            with jax.named_scope("own"):
+                logs = clog.v_append_full(carry.logs, rows)
+                logs = self._shard_tree(logs)
             if self.plan.num_replicas > 0:
                 # Piggyback replication: the same block of determinants
                 # lands in every downstream replica before any of this
                 # block's outputs become externally visible (the
                 # per-message netty delta becomes one owner-indexed bulk
                 # append at the block fence).
-                replicas = clog.v_append_full(carry.replicas,
-                                              rows[self._owner_idx])
-                replicas = self._shard_tree(replicas)
+                with jax.named_scope("replicas"):
+                    replicas = clog.v_append_full(carry.replicas,
+                                                  rows[self._owner_idx])
+                    replicas = self._shard_tree(replicas)
             else:
                 replicas = carry.replicas
 
@@ -1010,8 +1014,10 @@ class LocalExecutor:
             replicas = carry.replicas
             with jax.named_scope("causal-log"):
                 if plan.num_replicas > 0:
-                    replicas = rep.sync_replica_epochs(replicas, e)
-                logs = clog.v_start_epoch(carry.logs, e)
+                    with jax.named_scope("replicas"):
+                        replicas = rep.sync_replica_epochs(replicas, e)
+                with jax.named_scope("own"):
+                    logs = clog.v_start_epoch(carry.logs, e)
             with jax.named_scope("inflight-ring"):
                 out_rings = tuple(ifl.start_epoch(el, e)
                                   for el in carry.out_rings)
@@ -1029,8 +1035,10 @@ class LocalExecutor:
             replicas = carry.replicas
             with jax.named_scope("causal-log"):
                 if plan.num_replicas > 0:
-                    replicas = clog.v_truncate(replicas, e)
-                logs = clog.v_truncate(carry.logs, e)
+                    with jax.named_scope("replicas"):
+                        replicas = clog.v_truncate(replicas, e)
+                with jax.named_scope("own"):
+                    logs = clog.v_truncate(carry.logs, e)
             with jax.named_scope("inflight-ring"):
                 out_rings = tuple(ifl.truncate(el, e)
                                   for el in carry.out_rings)
