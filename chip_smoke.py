@@ -536,6 +536,13 @@ def print_routes(tracer, since: int, part: str) -> int:
                   if r["name"] == "hist.kernel"))
     for line, c in sorted(kernels.items()):
         say(f"{part} histogram {line} (traced {c}x)")
+    appends = collections.Counter(
+        f"{a['logs']} logs x {a['rows']} rows into {a['capacity']}: "
+        f"{a['form']}" + (f", {a['runs']} runs" if a["runs"] else "")
+        for a in (r["args"] for r in recs[since:]
+                  if r["name"] == "log.append"))
+    for line, c in sorted(appends.items()):
+        say(f"{part} log append {line} (traced {c}x)")
     # what the fences have read of the exchange so far (totals, which
     # only grow: the fullest step of a dynamic edge, records dropped)
     for name, n in sorted(tracer.counters().items()):

@@ -4,6 +4,7 @@ The census says what fault tolerance *should* cost; this module makes
 the cost measurable. It rewrites the executor module's AST so every
 fault-tolerance lane becomes the identity on its storage argument —
 ``clog.v_append_full(carry.logs, rows)`` -> ``carry.logs``,
+``rep.append_block(carry.replicas, rows, ...)`` -> ``carry.replicas``,
 ``ifl.append_block(ring, out)`` -> ``ring``, and likewise the epoch
 fence's start/truncate/replica-sync — then compiles the transformed
 source as a twin module. The twin's ``LocalExecutor`` runs the same
@@ -44,6 +45,7 @@ FT_IDENTITY_CALLS = {
     "clonos_tpu.inflight.log.append_block",
     "clonos_tpu.inflight.log.start_epoch",
     "clonos_tpu.inflight.log.truncate",
+    "clonos_tpu.causal.replication.append_block",
     "clonos_tpu.causal.replication.sync_replica_epochs",
 }
 

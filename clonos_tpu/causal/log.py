@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from clonos_tpu.causal.determinant import NUM_LANES
+from clonos_tpu.obs.trace import get_tracer
 
 
 class ThreadLogState(NamedTuple):
@@ -124,49 +125,83 @@ def append(state: ThreadLogState, rows: jnp.ndarray, count) -> ThreadLogState:
     return state._replace(rows=new_rows, head=state.head + count)
 
 
+def note_append(form: str, *, logs: int, runs: int, rows: int,
+                capacity: int) -> None:
+    """Record of which form a block's bulk append took (``log.append``
+    instant, at trace time; chip_smoke.py prints them): ``runs`` (a
+    replica stack appended run by run, :func:`append_runs`) or what
+    :func:`append_form` names, for ``logs`` logs of ``capacity`` taking
+    ``rows`` rows each (``runs``: how many runs; 0 on the batched
+    forms)."""
+    get_tracer().event("log.append", form=form, logs=logs, runs=runs,
+                       rows=rows, capacity=capacity)
+
+
+def append_form(n: int, cap: int) -> str:
+    """Which form :func:`append_full` takes for ``n`` rows into a ring of
+    ``cap`` (static, from the shapes alone; the ``log.append`` instant
+    reports it):
+
+    - ``scatter`` (``64 n < cap``): a row scatter, ~0.1 us a row on the
+      TPU and nothing per capacity: single steps (``n`` = 4) and short
+      blocks into a long ring.
+    - ``window`` (``4 w <= cap``, ``w`` the power of two >= ``2 n``): the
+      chunk spans at most two ``w``-aligned windows of the ring; roll it
+      within a ``[2 w]`` strip and read, merge and write those two
+      windows. O(n) work. What each task's own log takes in every
+      configuration but ``kafka-window-64`` (``n`` = 4,096, ``cap`` =
+      65,536 or 131,072).
+    - ``dense`` (otherwise): pad the chunk to the capacity, roll it into
+      ring position, select. O(capacity); ``kafka-window-64``'s own logs
+      (``n`` = 2,048 of 8,192).
+
+    Batched over a stack with a head per log (``v_append_full``) the
+    ``window`` form's slices lower to a loop over the logs and its roll
+    to a doubled strip per log: fine for a job's 32-128 own logs
+    (0.3-1.4 ms a block), 30-80x the copy's cost for 1,536-1,792 replica
+    logs, which therefore go run by run (:func:`append_runs`)."""
+    if n * 64 < cap:
+        return "scatter"
+    return "window" if 4 * _window(n) <= cap else "dense"
+
+
+def _window(n: int) -> int:
+    """Rows of one ring window of the ``window`` form: the power of two
+    >= ``2 n``."""
+    return 1 << (2 * n - 1).bit_length()
+
+
 def append_full(state: ThreadLogState, rows: jnp.ndarray) -> ThreadLogState:
     """Append ALL rows of ``[n, NUM_LANES]`` at head — the block-fence bulk
-    path (n is static and <= capacity, so ring positions are unique).
-
-    Large appends use a DENSE formulation — pad the chunk to capacity,
-    roll it into ring position, select — because the TPU executes a
-    general row scatter ~row-at-a-time (~0.1us/row: the replica bulk
-    append was the single hottest op of the whole live block program,
-    tools/ab_append A/B: 171ms -> 47ms at [384, 65536] x 4096 rows).
-    Small appends keep the scatter (the dense form's cost is O(capacity)
-    regardless of n)."""
+    path (n is static and <= capacity, so ring positions are unique), in
+    the form :func:`append_form` names."""
     n = rows.shape[0]
     cap = state.capacity
     if n > cap:
         raise ValueError(f"bulk append of {n} rows > capacity {cap}")
-    if n * 64 >= cap:
-        w = 1 << (2 * n - 1).bit_length()     # pow2 window >= 2n
-        if 4 * w <= cap:
-            # Windowed RMW: the chunk spans at most two W-aligned ring
-            # windows; roll it within a [2W] strip and read-merge-write
-            # those two windows at their (traced, aligned) starts. Work
-            # is O(W) = O(n) per append — the whole-capacity
-            # pad/roll/select below costs O(capacity), which doubled the
-            # live append bill when log capacities grew to 1<<17.
-            o = state.head & (cap - 1)
-            r = o & (w - 1)
-            base = o - r                        # W-aligned, traced
-            strip = jnp.pad(rows, ((0, 2 * w - n), (0, 0)))
-            strip = jnp.roll(strip, r, axis=0)
-            idx2 = jnp.arange(2 * w, dtype=jnp.int32)
-            mask = (idx2 >= r) & (idx2 < r + n)
-            out = state.rows
-            for half in (0, 1):
-                start = (base + half * w) & (cap - 1)
-                seg = jax.lax.dynamic_slice_in_dim(strip, half * w, w)
-                m = jax.lax.dynamic_slice_in_dim(mask, half * w, w)
-                win = jax.lax.dynamic_slice(
-                    out, (start, jnp.zeros((), jnp.int32)),
-                    (w, NUM_LANES))
-                merged = jnp.where(m[:, None], seg, win)
-                out = jax.lax.dynamic_update_slice(
-                    out, merged, (start, jnp.zeros((), jnp.int32)))
-            return state._replace(rows=out, head=state.head + n)
+    form = append_form(n, cap)
+    if form == "window":
+        w = _window(n)
+        o = state.head & (cap - 1)
+        r = o & (w - 1)
+        base = o - r                        # W-aligned, traced
+        strip = jnp.pad(rows, ((0, 2 * w - n), (0, 0)))
+        strip = jnp.roll(strip, r, axis=0)
+        idx2 = jnp.arange(2 * w, dtype=jnp.int32)
+        mask = (idx2 >= r) & (idx2 < r + n)
+        out = state.rows
+        for half in (0, 1):
+            start = (base + half * w) & (cap - 1)
+            seg = jax.lax.dynamic_slice_in_dim(strip, half * w, w)
+            m = jax.lax.dynamic_slice_in_dim(mask, half * w, w)
+            win = jax.lax.dynamic_slice(
+                out, (start, jnp.zeros((), jnp.int32)),
+                (w, NUM_LANES))
+            merged = jnp.where(m[:, None], seg, win)
+            out = jax.lax.dynamic_update_slice(
+                out, merged, (start, jnp.zeros((), jnp.int32)))
+        return state._replace(rows=out, head=state.head + n)
+    if form == "dense":
         o = state.head & (cap - 1)
         padded = jnp.pad(rows, ((0, cap - n), (0, 0)))
         rolled = jnp.roll(padded, o, axis=0)
@@ -179,6 +214,63 @@ def append_full(state: ThreadLogState, rows: jnp.ndarray) -> ThreadLogState:
     return state._replace(rows=state.rows.at[pos].set(rows,
                                                       unique_indices=True),
                           head=state.head + n)
+
+
+def runs_appendable(n: int, cap: int) -> bool:
+    """:func:`append_runs` needs ring slots of ``n`` rows: ``n`` a power
+    of two that divides the capacity."""
+    return n & (n - 1) == 0 and cap % n == 0
+
+
+def append_runs(stack: ThreadLogState, rows: jnp.ndarray,
+                heads: jnp.ndarray, runs) -> ThreadLogState:
+    """Append, to every log of a stack ``[R, cap, NUM_LANES]``, the block
+    of the log it copies: ``runs`` are static ``(start, k, owner)``
+    triples, the stack's rows ``[start, start + k)`` all copies of
+    ``rows[owner]`` (``[L, n, NUM_LANES]``) appended at ``heads[owner]``
+    (``[L]``, the owners' heads BEFORE this block) — what
+    ``v_append_full(stack, rows[owner_of_row])`` computes when every
+    copy's head equals its owner's, which is how replicas are kept
+    (``ReplicationPlan.runs``).
+
+    One offset per run, so nothing is batched over a head per log: with
+    ``o = head & (cap - 1)``, ``s = o % n``, ``q = o // n`` the chunk
+    covers ring slot ``q`` from ``s`` on and slot ``q + 1`` below ``s``.
+    The owner's ``[n, lanes]`` block is rolled by ``s`` once, and the two
+    slots of the run's ``[k, n, lanes]`` rows are read, merged and
+    written at their aligned starts, in place on the (donated) stack: a
+    loop over the runs of each length, its body the same for all of
+    them. O(n) rows written per log whatever the capacity."""
+    _, cap, lanes = stack.rows.shape
+    n = rows.shape[1]
+    if not runs_appendable(n, cap):
+        raise ValueError(f"run-wise append of {n} rows into rings of {cap}")
+    slots = cap // n
+    idx = jnp.arange(n, dtype=jnp.int32)[None, :, None]
+    zero = jnp.zeros((), jnp.int32)
+    by_len = {}
+    for start, k, owner in runs:
+        by_len.setdefault(k, []).append((start, owner))
+    out = stack.rows
+    for k, group in sorted(by_len.items()):
+        starts = jnp.asarray([s for s, _ in group], jnp.int32)
+        owners = jnp.asarray([o for _, o in group], jnp.int32)
+
+        def body(i, out):
+            start, owner = starts[i], owners[i]
+            o = heads[owner] & (cap - 1)
+            s, q = o % n, o // n
+            rolled = jnp.roll(
+                jax.lax.dynamic_index_in_dim(rows, owner, 0), s, axis=1)
+            for slot, mine in ((q, idx >= s), ((q + 1) % slots, idx < s)):
+                at = (start, slot * n, zero)
+                old = jax.lax.dynamic_slice(out, at, (k, n, lanes))
+                out = jax.lax.dynamic_update_slice(
+                    out, jnp.where(mine, rolled, old), at)
+            return out
+
+        out = jax.lax.fori_loop(0, len(group), body, out)
+    return stack._replace(rows=out, head=stack.head + n)
 
 
 def append_one(state: ThreadLogState, row: jnp.ndarray) -> ThreadLogState:

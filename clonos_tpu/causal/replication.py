@@ -12,12 +12,14 @@ TPU-native re-design: replication is a **block-boundary collective**, not a
 per-message payload. Every (owner subtask -> holder subtask) pair within the
 sharing-depth cut is one row of a stacked replica log
 ``int32[R, capacity, lanes]``. The executor's block program appends the
-same determinant tensor to owners and (owner-indexed) replicas in one fused
-gather+scatter — replica heads therefore equal owner heads *by
-construction* at every block fence, and the determinants describing a
-block's outputs are on their holders before those outputs become externally
-visible (the piggyback guarantee, NettyMessage.java:156-242). Under pjit
-over a device mesh the owner-indexed gather lowers to the ICI all-gather
+same determinant tensor to owners and to their replicas
+(:func:`append_block`: run by run at the owner's ring offset, in place) —
+replica heads therefore equal owner heads *by construction* at every
+block fence, and the determinants describing a block's outputs are on
+their holders before those outputs become externally visible (the
+piggyback guarantee, NettyMessage.java:156-242). Under pjit over a device
+mesh the stack is sharded on its leading axis and appended in the batched
+form, whose owner-indexed gather of the rows lowers to the ICI all-gather
 this design targets (SURVEY.md §2.6).
 
 :func:`replicate_step` (pull + offset-dedup merge) remains the
@@ -94,6 +96,23 @@ class ReplicationPlan:
     def owner_index(self) -> jnp.ndarray:
         return jnp.asarray([o for o, _ in self.pairs], jnp.int32)
 
+    @property
+    def runs(self) -> Tuple[Tuple[int, int, int], ...]:
+        """``(start, k, owner)``: the maximal stretches of consecutive
+        replica rows that copy one owner's log. ``from_job`` lays
+        ``pairs`` out owner vertex, holder vertex, owner subtask, ``j``,
+        so the ``k`` replicas of one owner towards one holder vertex are
+        adjacent rows of the stack (recovery indexes replicas by row:
+        nothing is reordered here)."""
+        runs: List[Tuple[int, int, int]] = []
+        for r, (owner, _) in enumerate(self.pairs):
+            if runs and runs[-1][2] == owner:
+                start, k, _ = runs[-1]
+                runs[-1] = (start, k + 1, owner)
+            else:
+                runs.append((r, 1, owner))
+        return tuple(runs)
+
     def replicas_held_by(self, holder_flat: int) -> List[int]:
         """Replica row indices held by one subtask (its share of the stacked
         replica log — what it answers determinant requests from)."""
@@ -108,6 +127,41 @@ def create_replicas(plan: ReplicationPlan, capacity: int,
     """Stacked replica logs [R, capacity, lanes]."""
     return jax.vmap(lambda _: clog.create(capacity, max_epochs))(
         jnp.arange(max(plan.num_replicas, 1)))
+
+
+def append_block(replicas: clog.ThreadLogState, rows: jnp.ndarray,
+                 owner_heads: jnp.ndarray, plan: ReplicationPlan,
+                 sharded: bool = False) -> clog.ThreadLogState:
+    """Piggyback replication at the block fence: the block's determinant
+    rows ``[L, n, lanes]`` land on every replica of their owner, at the
+    replica's head, in the block program (before any of the block's
+    outputs is visible).
+
+    A replica's head equals its owner's (``owner_heads``: the owners'
+    heads before this block; both are appended from the same tensor,
+    recovery copies an owner's log whole onto its replicas, and
+    ``tests/test_replica_append.py`` holds it through blocks, single
+    rows, fences, kills and recoveries), and the replicas of one owner
+    lie in runs of adjacent rows (``plan.runs``). So where the ring has
+    whole slots of ``n`` rows and the append is long enough to be bulk
+    (``clog.append_form`` not ``scatter``), the stack is appended run by
+    run at the owner's offset, in place (``clog.append_runs``). A stack
+    sharded over a mesh keeps the batched form, a head per log and the
+    owner-indexed gather of the rows (the run-wise loop slices the
+    sharded axis at traced starts, which the partitioner answers with
+    collectives), as does a block whose ``n`` leaves no whole slots."""
+    if plan.num_replicas == 0:
+        return replicas
+    n, cap = rows.shape[1], replicas.rows.shape[1]
+    form = clog.append_form(n, cap)
+    runs = plan.runs
+    if form != "scatter" and not sharded and clog.runs_appendable(n, cap):
+        clog.note_append("runs", logs=plan.num_replicas, runs=len(runs),
+                         rows=n, capacity=cap)
+        return clog.append_runs(replicas, rows, owner_heads, runs)
+    clog.note_append(form, logs=plan.num_replicas, runs=0, rows=n,
+                     capacity=cap)
+    return clog.v_append_full(replicas, rows[plan.owner_index()])
 
 
 def replicate_step(replicas: clog.ThreadLogState,

@@ -680,20 +680,20 @@ class CompiledJob:
             with jax.named_scope("rows"):
                 rows = self._det_rows(binputs, emits_all)         # [L, 4K, 8]
             with jax.named_scope("own"):
+                n, cap = rows.shape[1], self.log_capacity
+                clog.note_append(clog.append_form(n, cap), logs=self.L,
+                                 runs=0, rows=n, capacity=cap)
                 logs = clog.v_append_full(carry.logs, rows)
                 logs = self._shard_tree(logs)
-            if self.plan.num_replicas > 0:
-                # Piggyback replication: the same block of determinants
-                # lands in every downstream replica before any of this
-                # block's outputs become externally visible (the
-                # per-message netty delta becomes one owner-indexed bulk
-                # append at the block fence).
-                with jax.named_scope("replicas"):
-                    replicas = clog.v_append_full(carry.replicas,
-                                                  rows[self._owner_idx])
-                    replicas = self._shard_tree(replicas)
-            else:
-                replicas = carry.replicas
+            # Piggyback replication: the same block of determinants
+            # lands in every downstream replica before any of this
+            # block's outputs become externally visible (the per-message
+            # netty delta becomes one bulk append per run of an owner's
+            # replicas at the block fence).
+            with jax.named_scope("replicas"):
+                replicas = self._shard_tree(rep.append_block(
+                    carry.replicas, rows, carry.logs.head, self.plan,
+                    sharded=self.mesh is not None))
 
         new_carry = JobCarry(
             tuple(op_states), tuple(new_edge_bufs), tuple(rr_offsets),

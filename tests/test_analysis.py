@@ -482,21 +482,24 @@ def test_analysis_rules_registered_for_waiver_validation():
 def test_transform_strips_ft_lanes(tmp_path):
     src = textwrap.dedent("""\
         from clonos_tpu.causal import log as clog
+        from clonos_tpu.causal import replication as rep
         from clonos_tpu.inflight import log as ifl
 
-        def run(logs, ring, rows, out):
+        def run(logs, replicas, ring, rows, out, plan):
+            replicas = rep.append_block(replicas, rows, logs.head, plan)
             logs = clog.v_append_full(logs, rows)
             ring = ifl.append_block(ring, out)
-            return logs, ring
+            return logs, replicas, ring
         """)
     tree, report = transform_source("twin.py", src)
     assert {c for _l, c in report.stripped} == {
         "clonos_tpu.causal.log.v_append_full",
+        "clonos_tpu.causal.replication.append_block",
         "clonos_tpu.inflight.log.append_block"}
     import ast
     code = ast.unparse(tree)
-    assert "v_append_full" not in code
-    assert "logs = logs" in code
+    assert "v_append_full" not in code and "append_block" not in code
+    assert "logs = logs" in code and "replicas = replicas" in code
 
 
 def test_ablation_refused_on_load_bearing_nondet(monkeypatch):
@@ -517,6 +520,10 @@ def test_ablated_twin_bit_identical_outputs():
 
     twin_mod, report = ablated_executor()
     assert len(report.stripped) >= 7, report.to_dict()
+    # the block's replica append goes with the tasks' own
+    assert {"clonos_tpu.causal.log.v_append_full",
+            "clonos_tpu.causal.replication.append_block"} <= {
+        c for _l, c in report.stripped}
 
     def build():
         env = StreamEnvironment(name="ablate-golden", num_key_groups=16)
@@ -537,14 +544,16 @@ def test_ablated_twin_bit_identical_outputs():
         leaves = [np.asarray(x) for x in jax.tree_util.tree_leaves(
             (ex.carry.op_states, ex.carry.edge_bufs,
              ex.carry.record_counts, outs.sinks))]
-        return leaves, int(np.asarray(ex.carry.logs.head).max())
+        return leaves, max(int(np.asarray(ex.carry.logs.head).max()),
+                           int(np.asarray(ex.carry.replicas.head).max()))
 
     real_leaves, real_head = drive(real_ex)
     twin_leaves, twin_head = drive(twin_mod)
     assert len(real_leaves) == len(twin_leaves)
     for a, b in zip(real_leaves, twin_leaves):
         np.testing.assert_array_equal(a, b)
-    # Only the FT side differs: real logged, twin logged nothing.
+    # Only the FT side differs: real logged, twin logged nothing, on
+    # the tasks' own logs and on their replicas.
     assert real_head > 0
     assert twin_head == 0
 

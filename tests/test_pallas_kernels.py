@@ -221,11 +221,17 @@ def test_calls_of_one_shape_share_one_kernel_body():
     (512, (4, 6, 3)),        # small-append scatter branch (n * 64 < cap)
     (512, (4, 200, 3, 380)), # mixed: scatter resumes at a head the
                              # dense branch advanced, and wraps
+    (64, (16,) * 6),         # cap == 4n (``kafka-window-64``): dense,
+                             # the ring wrapped once
+    (512, (3, 16, 16, 16)),  # cap == 32n (``allround-32``): windowed
+                             # read-merge-write, off the window grid
 ])
 def test_bulk_append_full_matches_masked_append(cap, sizes):
-    """The block executor's bulk path (append_full — dense pad/roll for
-    large appends, unique-index scatter for small ones) must agree with
-    the general masked append, including ring wraps."""
+    """The block executor's bulk path (append_full — dense pad/roll or
+    the windowed read-merge-write for large appends, unique-index
+    scatter for small ones: ``append_form``) must agree with the general
+    masked append, including ring wraps. (The replica stack's run-wise
+    form: tests/test_replica_append.py.)"""
     rng = np.random.RandomState(3)
     L = 4
     a = jax.vmap(lambda _: clog.create(cap, 8))(jnp.arange(L))

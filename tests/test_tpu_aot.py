@@ -5,8 +5,11 @@ evidence is ``chip_smoke.py`` on the chip. Pinned here are the two
 failures that bring-up found: a kernel shape the routing guard admits
 but the compiler refused for VMEM, and the Mosaic kernel inside a
 program partitioned over a mesh; and what refused PR 29: how many
-kernels a program holds.
+kernels a program holds; and what PR 39 took out of the block program:
+everything the size of the replica stack but its in-place update.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,40 +74,107 @@ def test_program_lowers_one_kernel_for_calls_of_one_shape(v5e):
     assert text.count("tpu_custom_call") == 1
 
 
-def test_block_program_lowers_for_four_chip_mesh_with_kernel(v5e):
-    """The flagship block program, pinned over a 4-device v5e mesh,
-    compiles with the Mosaic kernel in it (bare ``pallas_call`` raised
-    'Mosaic kernels cannot be automatically partitioned')."""
+def flagship_job(log_capacity, mesh=None):
     from clonos_tpu.api.environment import StreamEnvironment
-    from clonos_tpu.obs import trace
-    from clonos_tpu.runtime.executor import BlockInputs, CompiledJob
-
-    mesh = Mesh(np.array(v5e), ("tasks",))
+    from clonos_tpu.runtime.executor import CompiledJob
     env = StreamEnvironment(name="flagship", num_key_groups=64,
                             default_edge_capacity=128)
     (env.synthetic_source(vocab=211, batch_size=32, parallelism=4)
         .key_by().window_count(num_keys=211, window_size=64)
         .key_by().reduce(num_keys=211).sink())
-    compiled = CompiledJob(env.build(), log_capacity=1 << 10, max_epochs=8,
-                           inflight_ring_steps=16, mesh=mesh)
+    return CompiledJob(env.build(), log_capacity=log_capacity, max_epochs=8,
+                       inflight_ring_steps=16, mesh=mesh)
+
+
+def lower_block(compiled, steps, sharding=None, **jit_kw):
+    """(lowered block program of ``steps`` steps, the instants its trace
+    left)."""
+    from clonos_tpu.obs import trace
+    from clonos_tpu.runtime.executor import BlockInputs
     carry = jax.eval_shape(compiled.init_carry)
     scalar = jax.ShapeDtypeStruct((), jnp.int32)
-    steps = jax.ShapeDtypeStruct((8,), jnp.int32)
+    steps = jax.ShapeDtypeStruct((steps,), jnp.int32)
+    args = (carry, BlockInputs(steps, steps, scalar, scalar))
+    if sharding is not None:
+        args = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding), args)
     tracer = trace.configure("aot-test")
     try:
-        lowered = jax.jit(
-            compiled.run_block,
-            in_shardings=(compiled.carry_shardings(carry),
-                          NamedSharding(mesh, PartitionSpec()))
-        ).lower(carry, BlockInputs(steps, steps, scalar, scalar))
-        routes = [(r["args"]["route"], r["args"]["rank"])
-                  for r in tracer.records() if r["name"] == "exchange.route"]
+        lowered = jax.jit(compiled.run_block, donate_argnums=0,
+                          **jit_kw).lower(*args)
+        return lowered, tracer.records()
     finally:
         trace.reset()
+
+
+def test_block_program_lowers_for_four_chip_mesh_with_kernel(v5e):
+    """The flagship block program, pinned over a 4-device v5e mesh,
+    compiles with the Mosaic kernel in it (bare ``pallas_call`` raised
+    'Mosaic kernels cannot be automatically partitioned'), and to the
+    collectives it had before the replica logs went run by run: a stack
+    sharded over the mesh keeps the batched append."""
+    mesh = Mesh(np.array(v5e), ("tasks",))
+    compiled = flagship_job(1 << 10, mesh)
+    carry = jax.eval_shape(compiled.init_carry)
+    lowered, records = lower_block(
+        compiled, 8, in_shardings=(compiled.carry_shardings(carry),
+                                   NamedSharding(mesh, PartitionSpec())))
+    routes = [(r["args"]["route"], r["args"]["rank"])
+              for r in records if r["name"] == "exchange.route"]
     assert routes == [("kernel", "tri")], \
         "the exchange must take the kernel path, its rank the triangle's"
+    assert [r["args"]["form"] for r in records
+            if r["name"] == "log.append"] == ["window", "window"]
     assert "tpu_custom_call" in lowered.as_text()
-    lowered.compile()
+    text = lowered.compile().as_text()
+    assert {kind: len(re.findall(rf" {kind}(?:-start)?\(", text))
+            for kind in ("all-reduce", "all-gather", "all-to-all",
+                         "collective-permute")} == MESH_COLLECTIVES
+
+
+#: of the flagship block program over the 2x2 mesh, as the parent of
+#: PR 39 compiled it
+MESH_COLLECTIVES = {"all-reduce": 3, "all-gather": 7, "all-to-all": 12,
+                    "collective-permute": 0}
+
+
+@pytest.mark.parametrize("log_capacity,own_form", [
+    (128, "dense"),        # cap == 4n, ``kafka-window-64``'s ratio
+    (1024, "window"),      # cap == 32n, ``allround-32``'s
+])
+def test_replica_append_touches_the_stack_only_in_place(
+        v5e, log_capacity, own_form):
+    """The one-chip block program appends the replica logs run by run:
+    nothing in it has the size of the stack ``[R, cap, 8]`` — no select
+    over it, no zero state of a loop over logs, no copy — but the stack
+    itself passing through the loops that update it in place, and
+    nothing under ``causal-log/replicas`` is larger than one run's slot
+    ``[k, n, 8]``: no ``rows[owner_idx]`` of ``[R, n, 8]``, no strip of
+    ``[R, 4w, 8]``."""
+    compiled = flagship_job(log_capacity)
+    plan, n = compiled.plan, 4 * 8
+    R, k = plan.num_replicas, max(k for _, k, _ in plan.runs)
+    lowered, records = lower_block(compiled, 8, SingleDeviceSharding(v5e[0]))
+    assert [(r["args"]["form"], r["args"]["logs"], r["args"]["runs"])
+            for r in records if r["name"] == "log.append"] == [
+        (own_form, compiled.L, 0), ("runs", R, len(plan.runs))]
+    exe = lowered.compile()
+    stack = f"s32[{R},{log_capacity},8]"
+    in_place = ("parameter", "while", "get-tuple-element",
+                "dynamic-update-slice", "tuple", "bitcast")
+    for line in exe.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(",
+                     line)
+        if m is None:
+            continue
+        dtype, dims, opcode = m.groups()
+        if f"{dtype}[{dims}]" == stack:
+            assert opcode in in_place, line
+        elif "causal-log/replicas" in line and dims:
+            assert np.prod([int(d) for d in dims.split(",")]) <= k * n * 8, \
+                line
+    assert exe.memory_analysis().temp_size_in_bytes < R * log_capacity * 32
 
 
 def test_window_top_block_form_compiles_at_the_cells_widths(v5e):
