@@ -29,6 +29,15 @@ it pass off the device:
      the histogram kernel — against its step form (scatter-adds, step by
      step), state and rows bit for bit, over blocks that split windows
      and a stretch with one input silent. Not in the default parts.
+  S  session windows inside a job's block program: bids of a moving hot
+     bidder and 1,010 cold ones cut into sessions by a gap of 1,000 ms
+     (splits, two open sessions a bidder, bridges), once in blocks of
+     1,024 steps and once in blocks of 16. Pass = the two committed
+     streams are equal, no loss. The forms agree alone on the CPU
+     (tests/test_user_sessions.py); what only the chip's compiler can get
+     wrong is the block form fused into a whole block program (PR 40: a
+     restarting sum came out wrong there from step 640 on, and not
+     alone). Not in the default parts.
   C  job A again under a four-chip task mesh, when there are four chips.
      Pass = committed stream byte-identical to A's, ledgers equal, every
      sharded carry leaf on four devices at a quarter each.
@@ -501,6 +510,67 @@ def check_block_until_ready() -> None:
             "after it still waited")
 
 
+def check_sessions_in_a_job(seed: int, spe: int = 2048, epochs: int = 3
+                            ) -> int:
+    """Part S: the committed stream of a session-window job run in
+    blocks of 1,024 steps against the same job run in blocks of 16;
+    returns the rows compared."""
+    import jax.numpy as jnp
+    from clonos_tpu.api.environment import StreamEnvironment
+    from clonos_tpu.api.feeds import ListFeedReader
+    from clonos_tpu.runtime.cluster import ClusterRunner
+
+    p, batch, nk = 4, 16, 2048
+    feed = np.random.RandomState(seed).randint(
+        1, 1 << 28, (p, epochs * spe * batch, 2)).astype(np.int32)
+
+    def parse(keys, vals, step):
+        # NEXmark's bidders at a step of 7 ms: a person every 5 ms, three
+        # bids in four by a hot bidder that changes every 100 persons,
+        # the rest by the last 1,000 persons and 10 ahead
+        ts = 7 * step + ((vals >> 2) & 1023) % 7
+        last = ts // 5
+        bidder = jnp.where((vals & 3) != 0, last // 100 * 100 + 1,
+                           last - 999 + (vals >> 12) % 1010)
+        return bidder % nk, jnp.ones_like(vals), ts
+
+    def committed(block_steps: int):
+        env = StreamEnvironment(name="smoke-sessions", num_key_groups=64,
+                                default_edge_capacity=batch)
+        (env.host_source(batch_size=batch, parallelism=p)
+            .map(parse, name="parse", capacity=batch)
+            .key_by().window_session(
+                num_keys=nk, gap=1000, out_of_orderness=7, capacity=16,
+                own_columns=640, edge_capacity=p * batch, name="sessions")
+            .key_by().sink(parallelism=p, transactional=True, capacity=16))
+        runner = ClusterRunner(
+            env.build(), steps_per_epoch=spe, block_steps=block_steps,
+            log_capacity=1 << (spe * 8 - 1).bit_length(), max_epochs=16,
+            inflight_ring_steps=2 * spe, seed=seed, logical_time=True,
+            audit=False)
+        runner.executor.register_feed(0, ListFeedReader(list(feed)))
+        for _ in range(epochs):
+            runner.run_epoch(complete_checkpoint=True)
+        runner.drain_fence()
+        lost = runner.executor.check_overflow()
+        if lost:
+            raise AssertionError(f"sessions, blocks of {block_steps}: {lost}")
+        (txn,) = runner.txn_logs.values()
+        return sort_rows(np.asarray(txn.committed_stream(), np.int32))
+
+    wide, narrow = committed(1024), committed(16)
+    if wide.shape != narrow.shape or not np.array_equal(wide, narrow):
+        raise AssertionError(
+            f"sessions: {wide.shape[0]} rows committed in blocks of 1,024 "
+            f"steps, {narrow.shape[0]} in blocks of 16"
+            + (f"; first difference at sorted row "
+               f"{int(np.nonzero((wide != narrow).any(axis=1))[0][0])}"
+               if wide.shape == narrow.shape else ""))
+    if wide.shape[0] < epochs * spe // 8:
+        raise AssertionError(f"sessions: only {wide.shape[0]} rows")
+    return int(wide.shape[0])
+
+
 # --- main --------------------------------------------------------------------
 
 
@@ -543,10 +613,12 @@ def print_routes(tracer, since: int, part: str) -> int:
                   if r["name"] == "log.append"))
     for line, c in sorted(appends.items()):
         say(f"{part} log append {line} (traced {c}x)")
-    # what the fences have read of the exchange so far (totals, which
-    # only grow: the fullest step of a dynamic edge, records dropped)
+    # what the fences have read so far of the exchange (totals, which
+    # only grow: the fullest step of a dynamic edge, records dropped) and
+    # of the event-time windows (fired, late, dropped; the most sessions
+    # a subtask has held open)
     for name, n in sorted(tracer.counters().items()):
-        if name.startswith("exchange."):
+        if name.startswith(("exchange.", "window.")):
             say(f"{part} counter {name} = {n}")
     return len(recs)
 
@@ -555,7 +627,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=21)
     ap.add_argument("--parts", default="KABC",
-                    help="which of K, J, A, B, C to run (C needs A)")
+                    help="which of K, J, S, A, B, C to run (C needs A)")
     args = ap.parse_args(argv)
     t_start = time.monotonic()
 
@@ -598,6 +670,13 @@ def main(argv=None) -> int:
         mark = print_routes(tracer, mark, "J")
         say(f"J pass: window join, block form == step form over {rows} "
             f"rows ({time.monotonic() - t0:.1f}s)")
+
+    if "S" in parts:
+        t0 = time.monotonic()
+        rows = check_sessions_in_a_job(args.seed)
+        mark = print_routes(tracer, mark, "S")
+        say(f"S pass: session windows, blocks of 1,024 steps == blocks of "
+            f"16 over {rows} rows ({time.monotonic() - t0:.1f}s)")
 
     shape = ServedShape()
     feed = make_feed(shape, args.seed)
@@ -697,7 +776,7 @@ def main(argv=None) -> int:
     say(f"compile cache: {entries1} entries at end "
         f"({entries1 - entries0} added by this run)")
     say(f"total {time.monotonic() - t_start:.1f}s; parts run: "
-        f"{''.join(p for p in 'KJABC' if p in parts)}")
+        f"{''.join(p for p in 'KJSABC' if p in parts)}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": n_dev}}), flush=True)

@@ -132,16 +132,32 @@ class DataStream:
 
     def window_session(self, num_keys: int, gap: int,
                        out_of_orderness: int = 0,
+                       capacity: Optional[int] = None,
+                       own_columns: Optional[int] = None,
+                       edge_capacity: Optional[int] = None,
                        name: str = "session-window",
                        parallelism: Optional[int] = None) -> "DataStream":
-        """Event-time session window (EventTimeSessionWindows analog)."""
+        """Event-time session windows per key, merging as Flink's
+        ``EventTimeSessionWindows.withGap(gap)`` do: one row ``(key, sum,
+        last timestamp + gap)`` a session, when the watermark reaches its
+        end; two open sessions a key, merged when a record bridges them
+        (operators.SessionWindowOperator; NEXmark query 11, "User
+        Sessions", with values of 1). Requires key_by(), and ``gap >
+        out_of_orderness``.
+
+        ``capacity``: rows a subtask may emit a step (default: two a
+        column, which drops none; rows past it are counted, and stop the
+        run at the next fence, as a late record does). ``own_columns``
+        and ``edge_capacity``: as :meth:`window_top` takes them."""
         from clonos_tpu.api.operators import SessionWindowOperator
         if not self._keyed:
             raise ValueError("window_session requires key_by() first")
         return self._attach(
             name, SessionWindowOperator(
                 num_keys=num_keys, gap=gap,
-                out_of_orderness=out_of_orderness), parallelism)
+                out_of_orderness=out_of_orderness, capacity=capacity,
+                own_columns=own_columns), parallelism,
+            capacity=edge_capacity)
 
     def _attach2(self, other: "DataStream", name: str, op: Operator,
                  parallelism: Optional[int],

@@ -113,6 +113,12 @@ class Operator:
     #: fence (``LocalExecutor.overflow_messages``), in every run.
     fence_losses: Tuple[str, ...] = ()
 
+    #: per-subtask int32 high-water marks in operator state that ride
+    #: the same read, reduced by their maximum over the subtasks, and
+    #: the counter each feeds (it only grows; the counter is fed its
+    #: growth and reads the mark): ``((state key, counter), ...)``
+    fence_peaks: Tuple[Tuple[str, str], ...] = ()
+
     #: columns a subtask holds of a table keyed over ``num_keys`` ids,
     #: where it holds a column only for the ids it owns (None: no such
     #: table). The planner binds them: ``CompiledJob._plan_edges`` works
@@ -942,81 +948,6 @@ class SlidingEventTimeWindowOperator(EventTimeWindow):
 
 
 @dataclasses.dataclass
-class SessionWindowOperator(Operator):
-    """Event-time session windows per key: a session absorbs records
-    within ``gap`` of its current end and fires when the watermark passes
-    end + gap (EventTimeSessionWindows analog, dense single-open-session
-    form: one open session per key — a late record for a closed session
-    is a late drop)."""
-
-    num_keys: int
-    gap: int
-    out_of_orderness: int = 0
-
-    @property
-    def out_capacity(self):  # type: ignore[override]
-        return self.num_keys
-
-    def init_state(self, parallelism: int):
-        nk = self.num_keys
-        return {
-            "acc": jnp.zeros((parallelism, nk), jnp.int32),
-            "end": jnp.full((parallelism, nk), -(2 ** 31) + 1, jnp.int32),
-            "max_ts": jnp.full((parallelism,), -(2 ** 31) + 1, jnp.int32),
-            "late": jnp.zeros((parallelism,), jnp.int32),
-        }
-
-    def process(self, state, batch, ctx):
-        nk = self.num_keys
-
-        def one(acc, end, max_ts, late, b: RecordBatch):
-            step_max = jnp.max(jnp.where(b.valid, b.timestamps,
-                                         -(2 ** 31) + 1))
-            max_ts = jnp.maximum(max_ts, step_max)
-            wm = max_ts - self.out_of_orderness
-            # FIRE FIRST: sessions whose (end + gap) the watermark passed
-            # close now, so a later record more than ``gap`` past a stale
-            # end starts a FRESH session instead of merging across the
-            # gap (the docstring's absorb-within-gap contract).
-            live = end > -(2 ** 31) + 1
-            # A session CLOSES whenever the watermark passes end+gap —
-            # even with a zero-sum accumulator (which merely emits
-            # nothing); gating the slot reset on acc != 0 would wedge the
-            # key forever after a zero-valued session.
-            fire = live & (end + self.gap <= wm)
-            out = RecordBatch(
-                keys=jnp.arange(nk, dtype=jnp.int32),
-                values=acc,
-                timestamps=end + self.gap,
-                valid=fire & (acc != 0))
-            acc = jnp.where(fire, 0, acc)
-            end = jnp.where(fire, -(2 ** 31) + 1, end)
-            live = live & ~fire
-            k = jnp.clip(b.keys, 0, nk - 1)
-            # Absorb: within ``gap`` of the open session's end, or into an
-            # empty slot if the record's own session wouldn't already have
-            # closed (end+gap = ts+gap must still be ahead of the
-            # watermark). Anything else — behind the closed frontier, or
-            # racing ahead of its key's un-fired session within one
-            # superstep — is a late drop.
-            ok = b.valid & jnp.where(
-                live[k],
-                b.timestamps - end[k] <= self.gap,
-                b.timestamps + self.gap > wm)
-            late = late + jnp.sum((b.valid & ~ok).astype(jnp.int32))
-            acc = acc.at[k].add(jnp.where(ok, b.values, 0), mode="drop")
-            end = end.at[k].max(jnp.where(ok, b.timestamps,
-                                          -(2 ** 31) + 1), mode="drop")
-            return acc, end, max_ts, late, zero_invalid(out)
-
-        acc, end, max_ts, late, out = jax.vmap(one)(
-            state["acc"], state["end"], state["max_ts"], state["late"],
-            batch)
-        return ({"acc": acc, "end": end, "max_ts": max_ts,
-                 "late": late}, out)
-
-
-@dataclasses.dataclass
 class UnionOperator(TwoInputOperator):
     """Merge two streams: left records first, then right, compacted into a
     fixed output capacity (the union / ConnectedStreams.map-same-type
@@ -1704,6 +1635,363 @@ class EventTimeWindowTopOperator(_EventTimeSlots, Operator):
             max_ts=max_ts[-1], late=state["late"] + n(valid & ~ok_any),
             fired=state["fired"] + out.count().sum(axis=0),
             dropped=state["dropped"] + dropped.sum(axis=0)), out
+
+
+#: "no record yet": the fold's identity for an earliest timestamp
+_NO_LO = 2 ** 31 - 1
+
+
+def _in_front(x: jnp.ndarray, *first: jnp.ndarray) -> jnp.ndarray:
+    """``x`` with the steps ``first`` in front of it along axis 0, as an
+    array of its own: the barrier keeps the concatenation out of the
+    fusions of the scan it feeds. Compiled for the v5e inside a job's
+    block program — never alone — a restarting sum over ``[state's two
+    steps, 1,024 steps]`` came out wrong from step 640 on (a restart
+    missed) when the compiler fused the concatenation into the scan's
+    first level; with the operand materialised it is right (PR 40:
+    PERF.md section 6 has the chip runs that found it)."""
+    return jax.lax.optimization_barrier(
+        jnp.concatenate([f[None] for f in first] + [x], axis=0))
+
+
+def _shifted(x: jnp.ndarray, first: jnp.ndarray) -> jnp.ndarray:
+    """``x`` one step later along axis 0, ``first`` in front: what the
+    step before left."""
+    return _in_front(x[:-1], first)
+
+
+def _running_max(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive running maximum along axis 0, as an associative scan:
+    ``lax.cummax`` over the 8,194 steps of a recovery block with the
+    state's two in front took the v5e's compiler 96 s, this 2 (PR 40)."""
+    return jax.lax.associative_scan(jnp.maximum, x, axis=0)
+
+
+def _last_flagged(flag: jnp.ndarray, values: jnp.ndarray) -> jnp.ndarray:
+    """Along axis 0, the value at the latest step whose ``flag`` is set,
+    this step's included (0 before the first)."""
+    def combine(a, b):
+        fa, va = a
+        fb, vb = b
+        return fa | fb, jnp.where(fb, vb, va)
+    return jax.lax.associative_scan(
+        combine, (flag, jnp.where(flag, values, 0)), axis=0)[1]
+
+
+def _segmented_any_min(reset: jnp.ndarray, hit: jnp.ndarray,
+                       low: jnp.ndarray):
+    """Along axis 0, within the segment a set ``reset`` opens: whether
+    ``hit`` was set so far, and the smallest ``low`` so far (both this
+    step's included)."""
+    def combine(a, b):
+        fa, ha, la = a
+        fb, hb, lb = b
+        return (fa | fb, jnp.where(fb, hb, ha | hb),
+                jnp.where(fb, lb, jnp.minimum(la, lb)))
+    return jax.lax.associative_scan(combine, (reset, hit, low), axis=0)[1:]
+
+
+@dataclasses.dataclass
+class SessionWindowOperator(Operator):
+    """Event-time session windows per key, Flink's merging sessions
+    (``EventTimeSessionWindows.withGap``; NEXmark query 11, "User
+    Sessions"): a record opens ``[ts, ts + gap)``, windows of one key
+    that touch or overlap merge (``|ts2 - ts1| <= gap`` merges), and a
+    session fires at the first step whose watermark reaches its end —
+    one row ``(key, sum of values, last timestamp + gap)``, none for a
+    sum of 0.
+
+    Watermark: :class:`EventTimeWindow`'s batched rule — ``wm = max(
+    event_ts seen) - out_of_orderness``, advanced once a step BEFORE the
+    step's records are taken; sessions whose end the watermark has
+    reached fire FIRST, so a record within ``gap`` of a session that
+    fires this step opens a new one (Flink: a fired window merges no
+    more).
+
+    **Two open sessions a key.** A record may open a session while its
+    key's older one still waits for the watermark, and a record that
+    touches both merges them into one (the bridge). A third cannot
+    exist: an open session's last timestamp lies above ``wm - gap``, the
+    next session starts more than ``gap`` above that, so above ``wm``,
+    and a third more than ``gap`` above that, so above ``wm + gap >
+    max_ts`` when ``gap > out_of_orderness`` — the constructor refuses
+    ``gap <= out_of_orderness``.
+
+    **What a step takes.** A record whose own window ``[ts, ts + gap)``
+    ends at or behind the watermark is late (Flink would still take it
+    into an open session it touches; within the bound, ``ts >= max_ts -
+    out_of_orderness``, there is no such record), as is one whose key
+    this subtask holds no column for. The records of one key taken in
+    one step are one *arrival* ``[earliest, latest]``. An arrival more
+    than ``gap`` above everything its key has seen, or behind a newest
+    session that has fired, opens a session; any other joins the key's
+    newest session, and merges the older open one into it if it reaches
+    that too. For records within the bound this is Flink's result
+    exactly (they lie within ``out_of_orderness < gap`` of each other
+    and no further below the newest session). An arrival that spreads
+    over more than ``gap``, or lies more than ``gap`` below the earliest
+    arrival of its newest session's current run, is merged all the same
+    and counted in ``disordered``: Flink might have kept two sessions.
+    ``late``, ``disordered`` and ``dropped`` (rows past ``capacity``)
+    are ``fence_losses``: a non-zero one stops the run at the next
+    fence.
+
+    **Table.** Per subtask and column the older session's ``(sum,
+    latest)`` and the newest one's ``(sum, earliest of its run,
+    latest)``; ``state["cols"]`` says which key a column holds, dense
+    (every key on every subtask) or, with ``own_columns``, the keys the
+    planner binds (:class:`EventTimeWindowTopOperator` says how). Rows
+    are compacted, older sessions' in column order and then newest
+    ones', into ``capacity`` rows a subtask a step (default: two a
+    column, which drops none). ``open_peak`` is the most sessions a
+    subtask has held open after a step.
+
+    The block form has no scan over the steps, no scatter and no gather
+    by a computed index: an arrival's count, sum, earliest and latest
+    come from the comparison of the step's records with the subtask's
+    columns; the newest session's latest is a running maximum along the
+    steps, a session starts where an arrival lies more than ``gap``
+    above it (a *break*) or the watermark has passed it, a break is
+    undone when a later arrival reaches back across it while the older
+    session is still open, sums are running sums that restart at the
+    breaks that stand, and a session's row leaves at the one step where
+    the watermark crosses its end. It agrees with the step form bit for
+    bit.
+    """
+
+    num_keys: int
+    gap: int
+    out_of_orderness: int = 0
+    capacity: Optional[int] = None
+    own_columns: Optional[int] = None
+
+    emits_received_keys = True
+
+    fence_totals = EventTimeWindow.fence_totals + (
+        ("dropped", "window.dropped_rows"),
+        ("disordered", "window.disordered_arrivals"))
+    fence_losses = ("late", "dropped", "disordered")
+    fence_peaks = (("open_peak", "window.open_sessions"),)
+
+    def __post_init__(self):
+        if self.gap <= self.out_of_orderness:
+            raise ValueError(
+                f"a session gap of {self.gap} within the out-of-orderness "
+                f"bound {self.out_of_orderness}: a key could hold a third "
+                f"open session, and this operator keeps two")
+
+    @property
+    def _columns(self) -> int:
+        return self.num_keys if self.own_columns is None else self.own_columns
+
+    @property
+    def out_capacity(self):  # type: ignore[override]
+        return (2 * self._columns if self.capacity is None
+                else self.capacity)
+
+    def init_state(self, parallelism: int):
+        p, c = parallelism, self._columns
+        cols = (jnp.arange(c, dtype=jnp.int32) if self.own_columns is None
+                else jnp.full((c,), NO_KEY, jnp.int32))
+        state = {k: jnp.zeros((p,), jnp.int32)
+                 for k, _ in self.fence_totals + self.fence_peaks}
+        state.update(
+            a_sum=jnp.zeros((p, c), jnp.int32),
+            a_hi=jnp.full((p, c), _NO_TS, jnp.int32),
+            b_sum=jnp.zeros((p, c), jnp.int32),
+            b_lo=jnp.full((p, c), _NO_LO, jnp.int32),
+            b_hi=jnp.full((p, c), _NO_TS, jnp.int32),
+            max_ts=jnp.full((p,), _NO_TS, jnp.int32),
+            cols=jnp.broadcast_to(cols, (p, c)))
+        return state
+
+    bind_own_columns = EventTimeWindowTopOperator.bind_own_columns
+
+    def rescale_keyed_state(self, state, new_parallelism, num_key_groups):
+        raise NotImplementedError(
+            "SessionWindowOperator does not support rescaling: its columns "
+            "are bound to the keys each subtask owns when the job is "
+            "planned, and a live rescale would have to bind them anew")
+
+    def _watermark(self, max_ts):
+        return jnp.where(max_ts == _NO_TS, _NO_TS,
+                         max_ts - self.out_of_orderness)
+
+    @scoped("lookup")
+    def _arrivals(self, cols, b: RecordBatch, wm):
+        """``(sum, earliest, latest)`` per column ``[..., P, C]`` of the
+        records of ``b [..., P, B]`` a step takes under the watermark
+        ``wm [..., P]`` (no record: 0, ``_NO_LO``, ``_NO_TS``), and how
+        many it does not take ``[..., P]``. One comparison of every
+        record with every column carries all four."""
+        took = b.valid & (b.timestamps + self.gap > wm[..., None])
+        m = took[..., None] & (b.keys[..., None] == cols[:, None, :])
+        ts = b.timestamps[..., None]                    # [..., P, B, C]
+        # one pass over the pairs: a reduction with four results (four
+        # reductions of their own each compare every pair again: on the
+        # v5e 128 ms a block of the cell's 9.4e9 pairs, half of it the
+        # count)
+        n, s, lo, hi = jax.lax.reduce(
+            (m.astype(jnp.int32), jnp.where(m, b.values[..., None], 0),
+             jnp.where(m, ts, _NO_LO), jnp.where(m, ts, _NO_TS)),
+            (jnp.int32(0), jnp.int32(0), jnp.int32(_NO_LO),
+             jnp.int32(_NO_TS)),
+            lambda x, y: (x[0] + y[0], x[1] + y[1],
+                          jnp.minimum(x[2], y[2]), jnp.maximum(x[3], y[3])),
+            (m.ndim - 2,))
+        # a column bound to no key holds nothing, whatever key matched it
+        bound = cols != NO_KEY
+        late = (jnp.sum(b.valid.astype(jnp.int32), axis=-1)
+                - jnp.sum(jnp.where(bound, n, 0), axis=-1))
+        return (jnp.where(bound, s, 0), jnp.where(bound, lo, _NO_LO),
+                jnp.where(bound, hi, _NO_TS), late)
+
+    @scoped("emit")
+    def _emit(self, cols, fire, sums, ends):
+        """The rows of one or many steps: the lanes ``fire [..., P, 2 *
+        C]`` (the older sessions' columns, then the newest ones') with
+        their ``sums`` and ``ends``, compacted in lane order into
+        ``[..., P, capacity]`` rows, and how many did not fit ``[...,
+        P]``. A row's place is its rank among the firing lanes: three
+        keyed histograms over ranks carry key, sum and end."""
+        from clonos_tpu.ops.histogram import keyed_hist
+        from clonos_tpu.ops.matops import running_count
+        cap = self.out_capacity
+        rank = running_count(fire) - 1
+        total = rank[..., -1] + 1
+        key = jnp.where(cols != NO_KEY, cols, 0)
+        key = jnp.broadcast_to(jnp.concatenate([key, key], axis=-1),
+                               fire.shape)
+        keys, values, stamps = (
+            keyed_hist(rank, x, fire, cap, want_counts=False)[0]
+            for x in (key, sums, ends))
+        valid = jnp.arange(cap, dtype=jnp.int32) < total[..., None]
+        return (zero_invalid(RecordBatch(keys, values, stamps, valid)),
+                jnp.maximum(total - cap, 0))
+
+    def process(self, state, batch, ctx):
+        gap = self.gap
+        both = lambda a, b: jnp.concatenate([a, b], axis=-1)
+        max_ts = jnp.maximum(state["max_ts"], jnp.max(
+            jnp.where(batch.valid, batch.timestamps, _NO_TS), axis=-1))
+        wm = self._watermark(max_ts)                              # [P]
+        a_sum, a_hi = state["a_sum"], state["a_hi"]
+        b_sum, b_lo, b_hi = state["b_sum"], state["b_lo"], state["b_hi"]
+        # FIRE FIRST: every open session whose end the watermark has
+        # reached, the older ones' rows before the newest ones'
+        fire_a = (a_hi != _NO_TS) & (a_hi + gap <= wm[:, None])
+        fire_b = (b_hi != _NO_TS) & (b_hi + gap <= wm[:, None])
+        out, dropped = self._emit(
+            state["cols"], both(fire_a & (a_sum != 0), fire_b & (b_sum != 0)),
+            both(a_sum, b_sum), both(a_hi, b_hi) + gap)
+        a_sum, a_hi = jnp.where(fire_a, 0, a_sum), jnp.where(fire_a, _NO_TS,
+                                                             a_hi)
+        b_sum, b_lo, b_hi = (jnp.where(fire_b, 0, b_sum),
+                             jnp.where(fire_b, _NO_LO, b_lo),
+                             jnp.where(fire_b, _NO_TS, b_hi))
+        # then the step's arrivals, a column at a time
+        s, lo, hi, late = self._arrivals(state["cols"], batch, wm)
+        some = hi != _NO_TS
+        opens = some & ((b_hi == _NO_TS) | (lo > b_hi + gap))
+        joins = some & ~opens
+        bridges = joins & (a_hi != _NO_TS) & (lo <= a_hi + gap)
+        disordered = some & ((hi - lo > gap) | (joins & (hi + gap < b_lo)))
+        # a session that opens makes the newest one the older (which, by
+        # the class docstring, has fired); a bridge folds the older in
+        new = dict(
+            a_sum=jnp.where(opens, b_sum, jnp.where(bridges, 0, a_sum)),
+            a_hi=jnp.where(opens, b_hi, jnp.where(bridges, _NO_TS, a_hi)),
+            b_sum=jnp.where(opens, s, jnp.where(
+                joins, b_sum + s + jnp.where(bridges, a_sum, 0), b_sum)),
+            b_lo=jnp.where(opens, lo, jnp.minimum(b_lo, lo)),
+            b_hi=jnp.where(opens, hi, jnp.maximum(b_hi, hi)))
+        n = lambda m: jnp.sum(m.astype(jnp.int32), axis=-1)
+        held = n(new["a_hi"] != _NO_TS) + n(new["b_hi"] != _NO_TS)
+        return dict(
+            state, **new, max_ts=max_ts, late=state["late"] + late,
+            fired=state["fired"] + out.count(),
+            dropped=state["dropped"] + dropped,
+            disordered=state["disordered"] + n(disordered),
+            open_peak=jnp.maximum(state["open_peak"], held)), out
+
+    def process_block(self, state, batches, bctx):
+        gap = self.gap
+        both = lambda a, b: jnp.concatenate([a, b], axis=-1)
+        n = lambda m: jnp.sum(m.astype(jnp.int32), axis=-1)
+        max_ts = _EventTimeSlots._block_max_ts(
+            self, state["max_ts"], batches.valid, batches.timestamps)
+        wm, wm0 = self._watermark(max_ts), self._watermark(state["max_ts"])
+        s, lo, hi, late = self._arrivals(state["cols"], batches, wm)
+        # Two steps that stand for the state go in front — the older
+        # open session's sum and latest as an arrival, then the newest
+        # one's — so every array from here on is [K + 2, P, C].
+        s = _in_front(s, state["a_sum"], state["b_sum"])
+        lo = _in_front(lo, state["a_hi"], state["b_lo"])
+        hi = _in_front(hi, state["a_hi"], state["b_hi"])
+        w = _in_front(wm, wm0, wm0)[:, :, None]
+        none = jnp.full_like(hi[0], _NO_TS)
+        some = hi != _NO_TS
+        with jax.named_scope("place"):
+            # the newest session's latest after each step: a running
+            # maximum (a newer record is in it, or in a newer session)
+            top = _running_max(hi)
+            before = _shifted(top, none)
+            # a run of arrivals starts where one lies more than ``gap``
+            # above everything before it, or the watermark has passed
+            # that ...
+            run = some & ((before == _NO_TS) | (lo > before + gap)
+                          | (w >= before + gap))
+            a_top = _running_max(jnp.where(run, before, _NO_TS))
+            # ... and is one session with the run before it once an
+            # arrival reaches back to that while it is still open
+            reach = (some & ~run & (a_top != _NO_TS) & (lo <= a_top + gap)
+                     & (w < a_top + gap))
+            bridged, run_lo = _segmented_any_min(run, reach, lo)
+            # the starts no later arrival undoes: a pass from the
+            # block's end, run by run, for whether any arrival of the
+            # run reached back
+            ahead = _segmented_cumsum(
+                reach[::-1].astype(jnp.int32),
+                _shifted(run[::-1], jnp.ones_like(run[0])))[::-1]
+            starts = run & (ahead == 0)
+        with jax.named_scope("segsum"):
+            total = _segmented_cumsum(s, starts)
+            # the session before the newest one: what the newest was
+            # when the last start that stands came
+            a_sum = _last_flagged(
+                starts, _shifted(total, jnp.zeros_like(s[0])))
+            a_hi = _running_max(jnp.where(starts, before, _NO_TS))
+        # a session's row leaves at the one step at which the watermark
+        # crosses its end, as the step before left the session
+        crossed = lambda h: ((h[1:-1] != _NO_TS) & (w[2:] >= h[1:-1] + gap)
+                             & (h[1:-1] + gap > w[1:-1]))
+        fire_a, fire_b = crossed(a_hi), crossed(top)
+        out, dropped = self._emit(
+            state["cols"],
+            both(fire_a & (a_sum[1:-1] != 0), fire_b & (total[1:-1] != 0)),
+            both(a_sum[1:-1], total[1:-1]),
+            both(a_hi[1:-1], top[1:-1]) + gap)
+        # after each step the newest session is open while the watermark
+        # has not reached its end, and the run before it while that
+        # holds for it too and no arrival has merged the two
+        open_b = (top != _NO_TS) & (top + gap > w)
+        open_a = (a_top != _NO_TS) & (a_top + gap > w) & ~bridged
+        disordered = some[2:] & (
+            (hi[2:] - lo[2:] > gap)
+            | (~run[2:] & (hi[2:] + gap < run_lo[1:-1])))
+        held = jnp.max(n(open_a[2:]) + n(open_b[2:]), axis=0)
+        return dict(
+            state,
+            a_sum=jnp.where(open_a[-1], a_sum[-1], 0),
+            a_hi=jnp.where(open_a[-1], a_top[-1], _NO_TS),
+            b_sum=jnp.where(open_b[-1], total[-1], 0),
+            b_lo=jnp.where(open_b[-1], run_lo[-1], _NO_LO),
+            b_hi=jnp.where(open_b[-1], top[-1], _NO_TS),
+            max_ts=max_ts[-1], late=state["late"] + late.sum(axis=0),
+            fired=state["fired"] + out.count().sum(axis=0),
+            dropped=state["dropped"] + dropped.sum(axis=0),
+            disordered=state["disordered"] + n(disordered).sum(axis=0),
+            open_peak=jnp.maximum(state["open_peak"], held)), out
 
 
 @dataclasses.dataclass

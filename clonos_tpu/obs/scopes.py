@@ -18,13 +18,18 @@ same names where they run the same code. A path reads
                     ``_replay_block``); beneath it, where the operator
                     has such a part:
 ``.../lookup``      a record's own column (``EventTimeWindowTopOperator
-                    ._column``)
+                    ._column``); with it, a step's sum, earliest and
+                    latest per own column (``SessionWindowOperator
+                    ._arrivals``)
 ``.../place``       records into ``slot x key`` lanes (``_EventTimeSlots
-                    ._block_place``)
+                    ._block_place``); a step's arrivals into sessions: the
+                    running latest, where a session starts, where two
+                    merge (``SessionWindowOperator.process_block``)
 ``.../segsum``      the accumulators' running sum that restarts at a fire
-                    (``_block_accumulate``)
-``.../emit``        a fire's rows, compacted (``_emit`` of the window join
-                    and of the windowed top)
+                    (``_block_accumulate``), or where a session starts
+                    (``SessionWindowOperator.process_block``)
+``.../emit``        a fire's rows, compacted (``_emit`` of the window join,
+                    the windowed top and the session window)
 ``.../readback``    the running value read back per record
                     (``KeyedReduceOperator.process_block*``)
 ``.../compact``     both inputs' records packed to the front

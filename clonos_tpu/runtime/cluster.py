@@ -1757,7 +1757,8 @@ class ClusterRunner:
         # dropped as late, fired, accepted a side (the operator's
         # ``fence_totals``), then the exchange — records an edge has
         # dropped, and the most a target of a dynamic edge has been sent
-        # in one step; both only grow, and stay absent while 0.
+        # in one step; both only grow, and stay absent while 0; last the
+        # operators' high-water marks (``fence_peaks``), fed alike.
         compiled = self.executor.compiled
         seen = [(f"{counter}.{v.name}", n, True) for (v, _, counter), n
                 in zip(compiled.fence_total_slots(), parts["totals"])]
@@ -1765,6 +1766,8 @@ class ClusterRunner:
                   False) for e, n in enumerate(parts["dropped"])]
         seen += [(f"exchange.peak_records.{compiled.edge_name(e)}", n, False)
                  for e, n in zip(compiled.peak_edges(), parts["peak"])]
+        seen += [(f"{counter}.{v.name}", n, False) for (v, _, counter), n
+                 in zip(compiled.fence_peak_slots(), parts["marks"])]
         tr = get_tracer()
         for counter, n, even_zero in seen:
             grown = int(n) - self._fence_counter_totals.get(counter, 0)

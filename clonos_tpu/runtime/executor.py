@@ -194,10 +194,11 @@ class CompiledJob:
         self.ring_index = {vid: i for i, vid in enumerate(self.ring_vertices)}
         #: vertices whose state keeps totals the fence's health read
         #: carries (``fence_totals``: the event-time windows' late-dropped
-        #: records and fired rows, the window join's records a side too)
+        #: records and fired rows, the window join's records a side too;
+        #: ``fence_peaks``: the most sessions a subtask has held open)
         self.event_window_vertices = [
             v.vertex_id for v in self.job.vertices
-            if v.operator.fence_totals]
+            if v.operator.fence_totals or v.operator.fence_peaks]
         self._plan_edges()
 
     def _plan_edges(self) -> None:
@@ -234,8 +235,8 @@ class CompiledJob:
         records' keys are whatever the data says (behind a source, a
         map, a FORWARD chain from one) and no declaration would help;
         ``undeclared`` — the producer holds own keys and states nothing
-        the planner could use (``SessionWindowOperator``,
-        ``IntervalJoinOperator``, a map behind a keyBy: ROADMAP D16);
+        the planner could use (``IntervalJoinOperator``, a map behind
+        a keyBy: ROADMAP D16);
         ``rescale`` — it emits own keys into another parallelism;
         ``too-wide`` — a gather plan would multiply the edge."""
         job = self.job
@@ -345,14 +346,22 @@ class CompiledJob:
         return (f"{self.job.vertices[e.src].name}->"
                 f"{self.job.vertices[e.dst].name}")
 
+    def _fence_slots(self, declared: str) -> List[Tuple[Any, str, str]]:
+        vertices = [self.job.vertices[vid]
+                    for vid in self.event_window_vertices]
+        return [(v, key, counter) for v in vertices
+                for key, counter in getattr(v.operator, declared)]
+
     def fence_total_slots(self) -> List[Tuple[Any, str, str]]:
         """``(vertex, state key, counter)`` of each total the fence's
         health read carries, in the vector's order (the operators'
         ``fence_totals``)."""
-        vertices = [self.job.vertices[vid]
-                    for vid in self.event_window_vertices]
-        return [(v, key, counter) for v in vertices
-                for key, counter in v.operator.fence_totals]
+        return self._fence_slots("fence_totals")
+
+    def fence_peak_slots(self) -> List[Tuple[Any, str, str]]:
+        """The same for the operators' ``fence_peaks``: high-water marks
+        the read reduces by their maximum over the subtasks."""
+        return self._fence_slots("fence_peaks")
 
     def peak_edges(self) -> List[int]:
         """The edges whose fill the carry follows (``exchange[e]
@@ -1539,6 +1548,8 @@ class LocalExecutor:
         tail += [x["dropped"].sum() for x in carry.exchange]
         tail += [carry.exchange[e]["peak"].max()
                  for e in self.compiled.peak_edges()]
+        tail += [carry.op_states[v.vertex_id][k].max()
+                 for v, k, _ in self.compiled.fence_peak_slots()]
         return jnp.concatenate(
             [vec, carry.record_counts.sum()[None], carry.logs.head]
             + ([jnp.stack(tail)] if tail else []))
@@ -1547,14 +1558,16 @@ class LocalExecutor:
         """A health vector by what its entries are: ``flags``,
         ``records`` (one total), ``heads`` (a log head a task),
         ``totals`` (the ``fence_totals`` of each vertex that has any, in
-        ``event_window_vertices`` order), ``dropped`` (an entry an edge)
-        and ``peak`` (one a ``peak_edges()`` edge)."""
+        ``event_window_vertices`` order), ``dropped`` (an entry an edge),
+        ``peak`` (one a ``peak_edges()`` edge) and ``marks`` (the
+        operators' ``fence_peaks``, in the same vertex order)."""
         c = self.compiled
         sizes = (("flags", 4 + len(self.carry.out_rings)), ("records", 1),
                  ("heads", c.L),
                  ("totals", len(c.fence_total_slots())),
                  ("dropped", len(self.job.edges)),
-                 ("peak", len(c.peak_edges())))
+                 ("peak", len(c.peak_edges())),
+                 ("marks", len(c.fence_peak_slots())))
         parts, at = {}, 0
         for name, n in sizes:
             parts[name] = vec[at:at + n]

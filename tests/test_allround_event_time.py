@@ -323,6 +323,8 @@ def _contract_cases():
         ("window-top", ops.EventTimeWindowTopOperator(
             num_keys=13, window_size=300, slide=100, out_of_orderness=100,
             capacity=16)),
+        ("sessions", ops.SessionWindowOperator(
+            num_keys=13, gap=300, out_of_orderness=100)),
         ("map-rewrites-keys", ops.MapOperator(
             lambda k, v, t: (k + 1, v, t))),
     ]

@@ -60,6 +60,10 @@ def test_part_j_tiny_window_join_block_form_against_step_form():
                                         num_keys=256) > 100
 
 
+def test_part_s_tiny_sessions_in_wide_blocks_against_narrow_ones():
+    assert chip_smoke.check_sessions_in_a_job(21, spe=128, epochs=5) > 200
+
+
 def test_main_refuses_to_run_without_a_tpu(capsys):
     assert chip_smoke.main([]) != 0
     out = capsys.readouterr()

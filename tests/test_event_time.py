@@ -107,6 +107,28 @@ def test_session_window_gap_merging_and_late():
     ])
     assert (1, 1, 10) in fired2
     assert int(s2["late"][0]) == 1
+    # The race the single-open-session form lost as a late drop: key 1's
+    # session [0, 3] still waits for the watermark (bound 5) when a
+    # record 14 past it arrives. It opens a second session beside the
+    # first (Flink's merging windows), and a record between the two,
+    # within gap of both, merges them into one.
+    op = SessionWindowOperator(num_keys=4, gap=10, out_of_orderness=5)
+    s3, fired3 = _run_steps(op, [
+        [(1, 1, 0), (1, 1, 3)],          # [0, 3], ends at 13
+        [(1, 2, 17)],                    # wm=12: first still open; 17-3>gap
+        [(2, 1, 19)],                    # wm=14: the first fires alone
+        [(2, 1, 60)],
+    ])
+    assert fired3[:2] == [(1, 2, 13), (1, 2, 27)]
+    assert int(s3["late"][0]) == 0
+    s4, fired4 = _run_steps(op, [
+        [(1, 1, 0), (1, 1, 3)],
+        [(1, 2, 17)],                    # two open sessions for key 1
+        [(1, 4, 12)],                    # within gap of both: one session
+        [(2, 1, 60)],
+    ])
+    assert (1, 8, 27) in fired4 and len([r for r in fired4 if r[0] == 1]) == 1
+    assert int(s4["late"][0]) == 0
 
 
 def _assert_block_equals_scan(op, batches, state=None):

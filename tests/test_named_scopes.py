@@ -60,6 +60,11 @@ TOPOLOGIES = {
         f"vertex/{v}{part}" for v in ("count", "max")
         for part in ("", "/lookup", "/place", "/place/hist", "/segsum",
                      "/emit", "/emit/hist")}),
+    "nexmark-user-sessions": ("tiny-nexmark-q11", RANKED | {
+        "vertex/host-source", "vertex/parse", "vertex/sink"} | {
+        "vertex/sessions" + part
+        for part in ("", "/lookup", "/place", "/segsum", "/emit",
+                     "/emit/hist")}),
 }
 
 

@@ -213,3 +213,53 @@ def test_window_top_block_form_compiles_at_the_cells_widths(v5e):
     compiled = lowered.compile()
     # the [K, P, B, 640] comparison never exists as an array
     assert compiled.memory_analysis().temp_size_in_bytes < K * P * B * 640
+
+
+def test_session_window_block_form_compiles_at_the_cells_widths(v5e):
+    """``nexmark-q11``'s ``sessions`` vertex at its own widths — 8,192 ids
+    in 640 own columns a subtask, 896 records a subtask a step, 32 rows —
+    over a whole block of 1,024 steps of 16 subtasks, inside the job's
+    block program: under ``vertex/sessions`` no scan of 1,024 trips (no
+    ``while`` at all), no scatter and no gather; its compaction takes the
+    Mosaic kernel (one body: ``[., 1,280] -> 32``); the comparison of
+    every record with every own column fuses without a ``[K, P, B, 640]``
+    array; and ``sessions -> sink`` is planned ``identity``."""
+    import json
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    for p in (bench, root):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    from benchlib import job
+    from benchlib.byname import module_at
+    from clonos_tpu.runtime.executor import CompiledJob
+    with open(os.path.join(bench, "configs", "nexmark-q11.json")) as f:
+        cfg = json.load(f)
+    # the logs and rings at a size this test can describe quickly; the
+    # vertex, its edges and the block's steps as the cell has them
+    compiled = CompiledJob(
+        module_at(job.topology_file(cfg, "job.py")).build(cfg),
+        log_capacity=8192, max_epochs=8, inflight_ring_steps=2048)
+    assert [p.route for _, p in sorted(compiled.edge_plans.items())] == [
+        "dynamic", "identity"]
+    K, P = cfg["block_steps"], cfg["parallelism"]
+    mesh = Mesh(np.array(v5e[:1]), ("tasks",))
+    with histogram.kernel_mesh(mesh, "tasks"):
+        lowered, records = lower_block(compiled, K,
+                                       SingleDeviceSharding(v5e[0]))
+    forms = [r["args"] for r in records if r["name"] == "hist.kernel"]
+    assert {f["form"] for f in forms} == {"mxu"}
+    assert (K * P, 2 * cfg["own_columns"], cfg["session_capacity"]) in {
+        (f["rows"], f["cols"], f["lanes"]) for f in forms}
+    exe = lowered.compile()
+    mine = [line for line in exe.as_text().splitlines()
+            if "vertex/sessions" in line]
+    assert len(mine) > 500
+    for op in ("while", "scatter", "gather", "sort"):
+        assert not [line for line in mine
+                    if re.search(rf"= \S+ {op}\(", line)], op
+    # the [K, P, B, 640] comparison never exists as an array
+    assert exe.memory_analysis().temp_size_in_bytes < (
+        K * P * cfg["edge_capacity"] * cfg["own_columns"]) // 4

@@ -325,23 +325,36 @@ def test_planner_routes_the_joins_edges():
 
 
 def test_a_dynamic_edge_behind_an_undeclared_operator_says_so():
-    """``SessionWindowOperator`` holds own keys behind its keyBy and
+    """``IntervalJoinOperator`` holds own keys behind its two keyBys and
     declares nothing the planner could use: its out-edge stays dynamic,
     and reads ``undeclared`` where a feed-keyed edge reads
-    ``feed-keys``."""
+    ``feed-keys``. ``SessionWindowOperator``, the example until it
+    declared ``emits_received_keys``, is routed in place now."""
     from clonos_tpu.api.environment import StreamEnvironment
     from clonos_tpu.runtime.executor import CompiledJob
 
-    env = StreamEnvironment(name="session", num_key_groups=64,
-                            default_edge_capacity=16)
-    (env.host_source(batch_size=8, parallelism=2).key_by()
+    def plans(build):
+        env = StreamEnvironment(name="undeclared", num_key_groups=64,
+                                default_edge_capacity=16)
+        build(env)
+        tracer = obs.get_tracer()
+        seen = len(tracer.records())
+        compiled = CompiledJob(env.build())
+        return ([p.route for p in compiled.edge_plans.values()],
+                [r["args"].get("reason") for r in tracer.records()[seen:]
+                 if r["name"] == "exchange.route"])
+
+    def joined(env):
+        left = env.host_source(batch_size=8, parallelism=2, name="left")
+        right = env.host_source(batch_size=8, parallelism=2, name="right")
+        (left.key_by().join(right.key_by(), num_keys=8, window=4,
+                            interval=50)
+         .key_by().reduce(num_keys=8).sink())
+
+    assert plans(joined) == (["dynamic"] * 3,
+                             ["feed-keys", "feed-keys", "undeclared"])
+    assert plans(lambda env: (
+        env.host_source(batch_size=8, parallelism=2).key_by()
         .window_session(num_keys=8, gap=50)
-        .key_by().reduce(num_keys=8).sink())
-    tracer = obs.get_tracer()
-    seen = len(tracer.records())
-    compiled = CompiledJob(env.build())
-    assert [p.route for p in compiled.edge_plans.values()] == [
-        "dynamic", "dynamic"]
-    noted = [r["args"] for r in tracer.records()[seen:]
-             if r["name"] == "exchange.route"]
-    assert [n["reason"] for n in noted] == ["feed-keys", "undeclared"]
+        .key_by().reduce(num_keys=8).sink())) == (
+            ["dynamic", "identity"], ["feed-keys", None])
