@@ -9,10 +9,13 @@ it pass off the device:
 
   K  the kernels alone at deployment width: the Pallas histogram against
      the XLA scatter and NumPy at the cells' call shapes (and which form
-     each call took: the ``hist.kernel`` instants), the three exchange
-     routes (kernel / scatter / sort) against the per-step exchange, the
-     MXU one-hot gather against NumPy over the whole int32 range, and
-     that ``jax.block_until_ready`` returns only when the work is done.
+     each call took: the ``hist.kernel`` instants), the counting
+     exchange (both placements, kernel and scatter, and a block over the
+     scratch budget counted in chunks of steps; with four chips also the
+     mesh cell's own block, sharded over the task mesh) against the
+     per-step exchange, the MXU one-hot gather against NumPy over the
+     whole int32 range, and that ``jax.block_until_ready`` returns only
+     when the work is done.
   A  the served path, host-fed, at config4's recorded width
      (``BASELINE.json.configs[3]``): 64 subtasks,
      a cascading kill of one source, one window and one reduce subtask
@@ -380,9 +383,10 @@ def check_kernels(seed: int) -> None:
             f"values over int32")
 
     # Exchange: each route against the per-step (sort) exchange. Shapes
-    # are A's (16 x 32 records, 16 targets); what differs picks the route.
-    def block(k):
-        shp = (k, 16, 32)
+    # are A's (16 x 32 records, 16 targets); what differs picks the
+    # placement, and whether the block is counted whole or in chunks.
+    def block(k, b=32):
+        shp = (k, 16, b)
         return zero_invalid(RecordBatch(
             jnp.asarray(rng.randint(0, 499, shp), jnp.int32),
             jnp.asarray(rng.randint(-1000, 1000, shp), jnp.int32),
@@ -390,29 +394,56 @@ def check_kernels(seed: int) -> None:
             jnp.asarray(rng.rand(*shp) < 0.7)))
 
     budget = routing._count_route_budget()
-    sort_k = 1 << (budget // (512 * 17 * 12)).bit_length()
+    over_k = 1 << (budget // (512 * 17 * 12)).bit_length()
     tracer = trace.get_tracer()
-    seen = set()
-    for want, k, cap in (("kernel", 64, 512), ("scatter", 64, 2048),
-                         ("sort", sort_k, 512)):
-        b = block(k)
+
+    def against_per_step(b, cap, want, chunked, mesh=None):
+        route = lambda x: routing.route_hash_block(x, 16, 64, cap)
+        if mesh is None:
+            run = jax.jit(route)
+        else:                         # as the mesh's block program has it:
+            from jax.sharding import NamedSharding, PartitionSpec
+            from clonos_tpu.ops.histogram import over_mesh
+            by_task = NamedSharding(mesh, PartitionSpec(None, "tasks"))
+            b = jax.device_put(b, by_task)      # subtasks in, targets out
+            run = jax.jit(over_mesh(route, mesh, "tasks"),
+                          out_shardings=by_task)
         n0 = len(tracer.records())
-        got = jax.jit(lambda x: routing.route_hash_block(
-            x, 16, 64, cap))(b)
+        got = jax.block_until_ready(run(b))
+        took = [(r["args"]["route"], r["args"]["steps"], r["args"]["chunks"])
+                for r in tracer.records()[n0:]
+                if r["name"] == "exchange.route"]
+        K = b.keys.shape[0]
+        if (len(took) != 1 or took[0][0] != want
+                or took[0][1] * took[0][2] != K
+                or (took[0][2] > 1) != chunked):
+            raise AssertionError(
+                f"exchange K={K} cap={cap}: took {took}, meant {want}, "
+                f"{'chunks' if chunked else 'whole'} (budget {budget} "
+                f"bytes)")
+        t0 = time.monotonic()
+        jax.block_until_ready(run(b))
+        wall = time.monotonic() - t0
         ref = jax.jit(jax.vmap(lambda x: routing.route_hash(
             x, 16, 64, cap)))(b)
         for x, y in zip(jax.tree_util.tree_leaves(got),
                         jax.tree_util.tree_leaves(ref)):
             same(x, y, f"exchange route {want}")
-        took = [r["args"]["route"] for r in tracer.records()[n0:]
-                if r["name"] == "exchange.route"]
-        if took != [want]:
-            raise AssertionError(
-                f"exchange K={k} cap={cap}: took {took}, meant {want} "
-                f"(budget {budget} bytes)")
-        seen.add(want)
-        say(f"K exchange K={k} T=16 cap={cap}: route {want} == per-step "
-            f"exchange")
+        _, steps, chunks = took[0]
+        say(f"K exchange K={K} n={b.keys[0].size} T=16 cap={cap}"
+            f"{'' if mesh is None else ' over the 4-chip task mesh'}: "
+            f"route {want}, {chunks} x {steps} steps == per-step exchange "
+            f"(second call {wall * 1e3:.1f} ms of host wall)")
+
+    against_per_step(block(64), 512, "kernel", chunked=False)
+    against_per_step(block(64), 2048, "scatter", chunked=False)
+    against_per_step(block(over_k), 512, "kernel", chunked=True)
+    if len(jax.devices()) >= 4:
+        # allround-64's first exchange: 1,024 steps of 16 x 128 records
+        # to 16 targets at capacity 1,024, over budget on one chip's price
+        from clonos_tpu.parallel import distributed
+        against_per_step(block(1024, 128), 1024, "kernel", chunked=True,
+                         mesh=distributed.task_mesh(max_devices=4))
     say(f"K exchange count-route budget: {budget} bytes")
 
     # MXU one-hot gather: exact over the whole int32 range.
@@ -595,7 +626,8 @@ def print_routes(tracer, since: int, part: str) -> int:
         else:                  # a dynamic exchange, as it was lowered
             line = (f"K={a['steps']} n={a['records']} T={a['targets']} "
                     f"cap={a['capacity']}: {a['route']}"
-                    + (f", rank {a['rank']}" if "rank" in a else ""))
+                    + (f", {a['chunks']} chunk(s) of steps, rank "
+                       f"{a['rank']}" if "rank" in a else ""))
         counts[line] = counts.get(line, 0) + 1
     for line, c in sorted(counts.items()):
         say(f"{part} exchange {line} (traced {c}x)")

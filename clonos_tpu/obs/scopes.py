@@ -36,11 +36,9 @@ same names where they run the same code. A path reads
                     (``UnionOperator``)
 ``exchange``        an edge's route (``CompiledJob.route_edge``);
 ``exchange/rank``   a record's arrival rank at its target (the running
-                    count, ``matops.running_count``; on the sort route
-                    the sort and the runs' bounds)
+                    count, ``matops.running_count``)
 ``exchange/place``  the fields moved to ``target x rank`` (three keyed
-                    histograms, an element scatter, or the sort route's
-                    gathers)
+                    histograms, or an element scatter)
 ``exchange/plan``   a static gather plan (``StaticRoutePlan.apply``)
 ``causal-log``      the determinant logs;
 ``causal-log/rows``      the block's determinant rows (``_det_rows``)

@@ -111,12 +111,13 @@ def _count_route_budget() -> int:
     """Cap on the counting exchange's scratch, priced at 12 bytes an
     entry of ``[K, n, T+1]``; it holds 10 an entry of ``[K, T, n]`` (the
     bf16 one-hot, the f32 product with the triangle, the int32 running
-    count). Routes past it take the flat sort or, long ones, count chunk
-    by chunk (:func:`_block_to_targets`).
+    count). Routes past it count chunk by chunk of steps
+    (:func:`_block_to_targets`).
     ~2% of the device's memory limit, within [256 MiB, 2 GiB]: ~336 MB
     on a 16 GB v5e, so the ~0.9 GB whole-recovery-window route at bench
-    shapes sorts there instead of crowding the GB-scale log state. A TPU
-    that reports no ``memory_stats()`` is an error, not a default."""
+    shapes goes in chunks there instead of crowding the GB-scale log
+    state. A TPU that reports no ``memory_stats()`` is an error, not a
+    default."""
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         return _COUNT_ROUTE_MIN_BYTES
@@ -126,21 +127,14 @@ def _count_route_budget() -> int:
 
 def note_route(route: str, **shape) -> None:
     """Record of which form an exchange took (``exchange.route``
-    instant; chip_smoke.py prints them): ``kernel`` / ``scatter`` /
-    ``sort`` when a dynamic exchange is lowered (trace time), and once
+    instant; chip_smoke.py prints them): ``kernel`` / ``scatter`` when
+    a dynamic exchange is lowered (trace time; a counting exchange also
+    says into how many ``chunks`` of ``steps`` its block was cut), and once
     per HASH edge what the planner decided (``CompiledJob._plan_edges``,
     plan time): ``identity`` / ``static`` off the dynamic exchange, or
     ``dynamic`` with the ``reason`` it stays there."""
     get_tracer().event("exchange.route", route=route, **shape)
 
-
-#: longest block (in records) the flat sort still routes when the
-#: counting scratch is over budget. XLA compiles a TPU sort in time that
-#: grows with its length (compiled for the v5e: 133 s at 8.4 M records,
-#: a recovery window of 8,192 steps x 1,024; 445 s at 33.5 M), and
-#: recovery prewarms one such program per edge. Longer blocks count in
-#: chunks of steps.
-_SORT_ROUTE_MAX_RECORDS = 1 << 21
 
 #: records in one chunk of the chunked counting route, at most: what the
 #: blocks that count whole already hold (1,024 steps x 1,024 records).
@@ -157,11 +151,12 @@ def _step_chunk(K: int, limit: int) -> int:
 
 def _count_to_targets(
     batch: RecordBatch, target: jnp.ndarray, num_targets: int,
-    out_capacity: int
+    out_capacity: int, chunks: int = 1
 ) -> Tuple[RecordBatch, jnp.ndarray]:
     """The counting route of :func:`_block_to_targets` (its docstring):
     every step on its own, so any cut of the step axis gives the same
-    result."""
+    result. ``chunks``: how many such cuts the caller made of its block
+    (this is one of them); only the ``exchange.route`` instant reads it."""
     K, P, B = batch.keys.shape
     T = num_targets
     n = P * B
@@ -188,8 +183,8 @@ def _count_to_targets(
     nk = T * out_capacity
     via_hist = nk <= KERNEL_MAX_KEYS
     note_route("kernel" if via_hist and uses_kernel() else "scatter",
-               steps=K, records=n, targets=T, capacity=out_capacity,
-               rank="tri")
+               steps=K, chunks=chunks, records=n, targets=T,
+               capacity=out_capacity, rank="tri")
     with jax.named_scope("place"):
         if via_hist:
             slot = jnp.where(keep, tgt * out_capacity + pos, -1)
@@ -235,11 +230,11 @@ def _block_to_targets(
     including overflow accounting (first ``cap`` arrivals per target
     survive, the rest count as dropped).
 
-    Routes whose counting scratch would exceed :func:`_count_route_budget`
-    (huge T or K) take one block-wide composite-key sort
-    (``step * (T+1) + target``, stable) with gather placement, up to
-    ``_SORT_ROUTE_MAX_RECORDS``; longer blocks count chunk after chunk
-    of steps, each chunk within the budget.
+    A block whose counting scratch would exceed
+    :func:`_count_route_budget` (huge T or K) counts chunk after chunk
+    of steps, each chunk within the budget and at most
+    ``_COUNT_CHUNK_MAX_RECORDS`` long: the same route whatever the
+    block's length, chosen by that one observable.
     """
     K, P, B = batch.keys.shape
     T = num_targets
@@ -247,49 +242,20 @@ def _block_to_targets(
     # Priced above what the branch holds (_count_route_budget) — the cap
     # must actually bound peak scratch.
     per_step = n * (T + 1) * 4 * 3
-    if K * per_step <= _count_route_budget():
+    budget = _count_route_budget()
+    if K * per_step <= budget:
         return _count_to_targets(batch, target, T, out_capacity)
-    kc = _step_chunk(K, min(_count_route_budget() // per_step,
-                            _COUNT_CHUNK_MAX_RECORDS // n))
-    if K * n > _SORT_ROUTE_MAX_RECORDS and kc:
-        cut = lambda x: x.reshape((K // kc, kc) + x.shape[1:])
-        routed, dropped = jax.lax.map(
-            lambda bt: _count_to_targets(RecordBatch(*bt[:4]), bt[4], T,
-                                         out_capacity),
-            tuple(map(cut, batch)) + (cut(target),))
-        join = lambda x: x.reshape((K,) + x.shape[2:])
-        return RecordBatch(*map(join, routed)), join(dropped)
-    # Flat sort (scratch over budget): one composite-key sort over the
-    # block.
-    note_route("sort", steps=K, records=n, targets=T,
-               capacity=out_capacity)
-    if K * (T + 1) >= (1 << 31):
-        raise ValueError(f"composite sort key overflow: K={K} T={T}")
-    flat = lambda x: jnp.reshape(x, (K * n,))
-    keys, vals, ts, valid = map(flat, batch)
-    with jax.named_scope("rank"):
-        tgt = jnp.where(valid, flat(target), T)
-        step = jnp.repeat(jnp.arange(K, dtype=jnp.int32), n,
-                          total_repeat_length=K * n)
-        composite = step * (T + 1) + tgt
-        order = jnp.argsort(composite, stable=True)
-        sc = composite[order]
-        # Boundary of every (step, target) run: [K*(T+1)] starts.
-        bounds = jnp.arange(K * (T + 1), dtype=jnp.int32)
-        run_start = jnp.searchsorted(sc, bounds,
-                                     side="left").astype(jnp.int32)
-        run_end = jnp.concatenate(
-            [run_start[1:], jnp.asarray([K * n], jnp.int32)])
-        run_len = (run_end - run_start).reshape(K, T + 1)[:, :T]  # [K, T]
-        dropped = jnp.maximum(run_len - out_capacity, 0).astype(jnp.int32)
-    with jax.named_scope("place"):
-        c = jnp.arange(out_capacity, dtype=jnp.int32)
-        src = run_start.reshape(K, T + 1)[:, :T, None] + c[None, None, :]
-        ok = (c[None, None, :]
-              < jnp.minimum(run_len, out_capacity)[:, :, None])
-        pick = order[jnp.clip(src, 0, K * n - 1)]            # [K, T, cap]
-        out = RecordBatch(keys[pick], vals[pick], ts[pick], ok)
-        return zero_invalid(out), dropped
+    # Over budget: chunk after chunk of steps, each within the budget (a
+    # step too wide for it even alone still counts, one step a chunk).
+    kc = _step_chunk(K, max(1, min(budget // per_step,
+                                   _COUNT_CHUNK_MAX_RECORDS // n)))
+    cut = lambda x: x.reshape((K // kc, kc) + x.shape[1:])
+    routed, dropped = jax.lax.map(
+        lambda bt: _count_to_targets(RecordBatch(*bt[:4]), bt[4], T,
+                                     out_capacity, chunks=K // kc),
+        tuple(map(cut, batch)) + (cut(target),))
+    join = lambda x: x.reshape((K,) + x.shape[2:])
+    return RecordBatch(*map(join, routed)), join(dropped)
 
 
 def _block_to_target_lane(batch: RecordBatch, target: jnp.ndarray,

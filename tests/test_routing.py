@@ -130,16 +130,17 @@ _TRAFFIC = {"mixed": {}, "one-target": dict(vocab=1, fill=1.0),
     (4, 7, 3, 16), (16, 7, 3, 16), (64, 7, 3, 16),
     (32, 5, 8, 600),
 ])
-@pytest.mark.parametrize("force_sort", [False, True])
+@pytest.mark.parametrize("over_budget", [False, True])
 @pytest.mark.parametrize("traffic", list(_TRAFFIC))
-def test_block_routes_bit_identical_to_per_step(cap, K, P, B, force_sort,
+def test_block_routes_bit_identical_to_per_step(cap, K, P, B, over_budget,
                                                 traffic, monkeypatch):
-    """The block exchange (both the counting branch and the flat-sort
-    fallback) must equal vmapping the per-step exchange, including
-    overflow-drop accounting (the executor switched to the block form for
-    speed; semantics are pinned here)."""
+    """The block exchange (counting the block whole, and one step a
+    chunk where even a single step's scratch is over the budget) must
+    equal vmapping the per-step exchange, including overflow-drop
+    accounting (the executor switched to the block form for speed;
+    semantics are pinned here)."""
     import jax
-    if force_sort:   # shrink the scratch budget so the sort path runs
+    if over_budget:   # no room for one step: K chunks of one step each
         monkeypatch.setattr(routing, "_count_route_budget", lambda: 0)
     rng = np.random.RandomState(3)
     batch = _rand_block(rng, K, P, B, **_TRAFFIC[traffic])
@@ -180,26 +181,26 @@ def test_block_routes_bit_identical_to_per_step(cap, K, P, B, force_sort,
 @pytest.mark.parametrize("traffic", list(_TRAFFIC))
 def test_chunked_count_route_bit_identical_to_per_step(cap, K, P, B, traffic,
                                                        monkeypatch):
-    """A block longer than the flat sort may route, with a counting
-    scratch over budget, counts chunk after chunk of steps: equal to the
-    per-step exchange, drops included, and no sort is traced."""
+    """A block whose counting scratch is over budget counts chunk after
+    chunk of steps: equal to the per-step exchange, drops included, and
+    the instant says how the block was cut."""
     import jax
     from clonos_tpu.obs import trace
     T, G = 4, 8
     # room for 3 steps' scratch: K = 12 is cut into chunks of 3, K = 6 too
     monkeypatch.setattr(routing, "_count_route_budget",
                         lambda: 3 * P * B * (T + 1) * 12)
-    monkeypatch.setattr(routing, "_SORT_ROUTE_MAX_RECORDS", 0)
     batch = _rand_block(np.random.RandomState(5), K, P, B,
                         **_TRAFFIC[traffic])
     tracer = trace.configure("chunked-route-test")
     try:
         r2, d2 = routing.route_hash_block(batch, T, G, cap)
-        took = [(r["args"]["route"], r["args"]["steps"], r["args"]["rank"])
+        took = [(r["args"]["route"], r["args"]["steps"],
+                 r["args"]["chunks"], r["args"]["rank"])
                 for r in tracer.records() if r["name"] == "exchange.route"]
     finally:
         trace.reset()
-    assert took == [("scatter", 3, "tri")]
+    assert took == [("scatter", 3, K // 3, "tri")]
     r1, d1 = jax.vmap(lambda b: routing.route_hash(b, T, G, cap))(batch)
     for a, b in zip(jax.tree_util.tree_leaves((r1, d1)),
                     jax.tree_util.tree_leaves((r2, d2))):
@@ -218,6 +219,38 @@ def test_counting_route_lowers_without_a_gather(cap):
     text = jax.jit(lambda b: routing.route_hash_block(b, 8, 64, cap)
                    ).lower(batch).as_text()
     assert "dot_general" in text and "gather" not in text
+
+
+# Both blocks are over a budget of four steps. Blocks up to 2,097,152
+# records used to take a flat sort there and only longer ones counted in
+# chunks; scaled down (n = 1,280), K = 16 stands for the shorter kind and
+# K = 64 for the longer.
+@pytest.mark.parametrize("K", [16, 64])
+@pytest.mark.parametrize("cap", [16, 4096])
+def test_over_budget_route_lowers_without_a_sort_or_a_gather(
+        K, cap, monkeypatch):
+    """One route past the budget, whatever the block's length: the
+    chunked count lowers to the triangle's products under a loop over
+    the chunks, with no sort (the flat composite-key sort is gone) and
+    no gather (the twin of the test above), by either placement."""
+    import jax
+    from clonos_tpu.obs import trace
+    P, B, T = 8, 160, 8
+    monkeypatch.setattr(routing, "_count_route_budget",
+                        lambda: 4 * P * B * (T + 1) * 12)
+    batch = _rand_block(np.random.RandomState(2), K, P, B)
+    tracer = trace.configure("over-budget-route-test")
+    try:
+        text = jax.jit(lambda b: routing.route_hash_block(b, T, 64, cap)
+                       ).lower(batch).as_text()
+        took = [(r["args"]["route"], r["args"]["steps"], r["args"]["chunks"])
+                for r in tracer.records() if r["name"] == "exchange.route"]
+    finally:
+        trace.reset()
+    assert took == [("scatter", 4, K // 4)]
+    assert "dot_general" in text and "while" in text
+    # (a scatter's ``indices_are_sorted`` attribute is not a sort)
+    assert "stablehlo.sort" not in text and "gather" not in text
 
 
 def test_step_chunk_is_a_divisor_within_the_limit():

@@ -139,6 +139,63 @@ MESH_COLLECTIVES = {"all-reduce": 3, "all-gather": 7, "all-to-all": 12,
                     "collective-permute": 0}
 
 
+#: of ``allround-64``'s first exchange alone over the 2x2 mesh, counted
+#: in two chunks of 512 steps: the fields' reshard from the subtask axis
+#: to the step axis on the way in (the chip's own 128 steps of a chunk
+#: are ranked and placed there), the routed chunk's reshard to the
+#: target axis on the way out, and the drop counts gathered. An
+#: all-gather of the ``[steps, T, n]`` one-hot, or of a field, would
+#: show here.
+MESH_EXCHANGE_COLLECTIVES = {"all-reduce": 0, "all-gather": 1,
+                             "all-to-all": 11, "collective-permute": 0}
+
+
+def test_mesh_cells_exchange_counts_in_chunks_by_step(v5e):
+    """``allround64x4.backlog``'s first exchange at its own shape — 1,024
+    steps of 16 x 128 records to 16 targets at capacity 1,024, the
+    producer block sharded on the subtask axis, the routed block on the
+    target axis — is over the counting budget and counts in chunks of
+    steps through the kernel: no sort and no gather in the compiled
+    program, the rank on a chip's own steps, and no collective beyond
+    the reshards."""
+    from clonos_tpu.api.records import RecordBatch
+    from clonos_tpu.obs import trace
+    from clonos_tpu.parallel import routing
+    mesh = Mesh(np.array(v5e), ("tasks",))
+    by_task = NamedSharding(mesh, PartitionSpec(None, "tasks", None))
+    K, P, B, T, cap = 1024, 16, 128, 16, 1024
+    lane = lambda dt: jax.ShapeDtypeStruct((K, P, B), dt, sharding=by_task)
+    batch = RecordBatch(lane(jnp.int32), lane(jnp.int32), lane(jnp.int32),
+                        lane(jnp.bool_))
+
+    def exchange(b):
+        with histogram.kernel_mesh(mesh, "tasks"):
+            routed, dropped = routing.route_hash_block(b, T, 64, cap)
+        return jax.tree_util.tree_map(
+            lambda x: jax.lax.with_sharding_constraint(x, by_task),
+            routed), dropped
+
+    tracer = trace.configure("aot-test")
+    try:
+        lowered = jax.jit(exchange).lower(batch)
+        routes = [r["args"] for r in tracer.records()
+                  if r["name"] == "exchange.route"]
+    finally:
+        trace.reset()
+    assert [(r["route"], r["rank"]) for r in routes] == [("kernel", "tri")]
+    assert routes[0]["steps"] * routes[0]["chunks"] == K
+    assert routes[0]["chunks"] > 1
+    text = lowered.compile().as_text()
+    for op in ("sort", "gather"):
+        assert not re.findall(rf" {op}\(", text), op
+    assert {kind: len(re.findall(rf" {kind}(?:-start)?\(", text))
+            for kind in MESH_EXCHANGE_COLLECTIVES} == MESH_EXCHANGE_COLLECTIVES
+    # the running count's products on a chip's own quarter of a chunk
+    steps = routes[0]["steps"] // len(v5e)
+    assert re.search(rf"f32\[{steps},{T},{P * B // 128},128\]\S* "
+                     rf"convolution\(", text)
+
+
 @pytest.mark.parametrize("log_capacity,own_form", [
     (128, "dense"),        # cap == 4n, ``kafka-window-64``'s ratio
     (1024, "window"),      # cap == 32n, ``allround-32``'s
