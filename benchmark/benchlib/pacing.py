@@ -34,19 +34,26 @@ def fence_aligned_rate(stamps: Dict[int, float], t0: float, t1: float,
     return (last - first) * records_per_epoch / span, last - first, span
 
 
-def rates_by_part(stamps: Dict[int, float], t0: float, t1: float,
-                  records_per_epoch: int, parts: int = 4) -> List[float]:
-    """The fence-aligned rate of each of ``parts`` runs of consecutive
-    epochs, as equal in number as they come: shows whether a run's rate
-    drifted inside its window. Empty with fewer than ``parts`` epochs
-    between the first and the last stamp inside."""
+def parts_of(stamps: Dict[int, float], t0: float, t1: float,
+             parts: int = 4) -> List[Tuple[int, int]]:
+    """The window's committed epochs cut into ``parts`` runs of
+    consecutive epochs, as equal in number as they come: the (first,
+    last) epoch of each, a run ending on the stamp the next begins on.
+    Empty with fewer than ``parts`` epochs between the first and the
+    last stamp inside."""
     inside = stamps_in(stamps, t0, t1)
-    cuts = np.linspace(0, len(inside) - 1, parts + 1).astype(int)
     if len(inside) <= parts:
         return []
-    return [(inside[b] - inside[a]) * records_per_epoch
-            / (stamps[inside[b]] - stamps[inside[a]])
-            for a, b in zip(cuts[:-1], cuts[1:])]
+    cuts = np.linspace(0, len(inside) - 1, parts + 1).astype(int)
+    return [(inside[a], inside[b]) for a, b in zip(cuts[:-1], cuts[1:])]
+
+
+def rates_by_part(stamps: Dict[int, float], t0: float, t1: float,
+                  records_per_epoch: int, parts: int = 4) -> List[float]:
+    """The fence-aligned rate of each part of :func:`parts_of`: shows
+    whether a run's rate drifted inside its window."""
+    return [(b - a) * records_per_epoch / (stamps[b] - stamps[a])
+            for a, b in parts_of(stamps, t0, t1, parts)]
 
 
 class Schedule:

@@ -17,7 +17,15 @@ Three phases in this order, then a check:
 4. the whole committed stream against the NumPy fold of the
    configuration's topology, and the count of compilations since set-up.
 
-The last line of stdout is the result object. Exit code 0 means the run
+Before the result, every run prints the window by quarter
+(``benchlib/quarters.py``, computed after the check from what the run
+holds): each quarter's rate beside the host's spans per block — a
+``--trace 1`` run with the harness's own wrappers over the whole window,
+any run with the program's recorder as far back as its ring reaches.
+
+The last line of stdout is the result object; its last key, ``checks``,
+holds each number compared beside its limit, and the same lines are the
+last on stderr. Exit code 0 means the run
 reached that line; ``correct`` says whether it may be believed. Without a
 TPU (or with fewer chips than the cell asks for) nothing is printed on
 stdout and the exit code is 2.
@@ -46,7 +54,7 @@ for _p in (HERE, ROOT):
 
 import numpy as np
 
-from benchlib import job, pacing, trace_reduce
+from benchlib import job, pacing, quarters, trace_reduce
 from benchlib.byname import module_at
 from benchlib.spans import Spans
 
@@ -145,8 +153,14 @@ class Run:
 def read_metric(name: str, run: Run) -> Optional[float]:
     """``readers/<name>.py`` holds ``read(run)`` for the metric ``name``,
     end-to-end or per-layer; a later PR adds a metric by adding its file.
-    A reader that finds nothing to read returns None."""
-    return module_at(os.path.join(HERE, "readers", name + ".py")).read(run)
+    One quantity split by the end-to-end metric its cells report
+    (``<metric>.mesh``) is read by ``readers/<metric>.py`` unless the
+    split name has a file of its own. A reader that finds nothing to
+    read returns None."""
+    path = os.path.join(HERE, "readers", name + ".py")
+    if not os.path.isfile(path):
+        path = os.path.join(HERE, "readers", name.split(".")[0] + ".py")
+    return module_at(path).read(run)
 
 
 # --- the run -----------------------------------------------------------------
@@ -366,7 +380,8 @@ class Harness:
     def check(self, counter: CompileCounter, control: Optional[str]):
         """Hold the whole committed stream to the fold; print each number
         compared beside its limit. Returns (correct, epochs offered,
-        epochs whose commit is missing or wrong)."""
+        epochs whose commit is missing or wrong, the numbers compared:
+        as the result's ``checks`` and as the lines printed)."""
         run, cfg, ex, ref = self.run, self.cfg, self.ex, self.run.reference
         # before the check itself asks the program for anything new
         compiled, names = counter.programs, list(counter.names)
@@ -401,12 +416,17 @@ class Harness:
         say(f"check {who}: {compared} committed rows over {epochs_offered} "
             f"epochs ({in_window} in the window) against the NumPy fold, in "
             f"{time.monotonic() - c0:.3f} s")
-        for name, got, kind, limit, ok in checks:
-            say(f"check {name}={got} {kind}={limit} "
-                f"{'ok' if ok else 'FAILED'}")
+        lines = [f"check {name}={got} {kind}={limit} "
+                 f"{'ok' if ok else 'FAILED'}"
+                 for name, got, kind, limit, ok in checks]
+        for line in lines:
+            say(line)
         if names:
             say(f"built or fetched after set-up: {names}")
-        return all(ok for *_, ok in checks), epochs_offered, bad_epochs
+        numbers = {name: {"value": got, kind: limit, "ok": ok}
+                   for name, got, kind, limit, ok in checks}
+        return (all(ok for *_, ok in checks), epochs_offered, bad_epochs,
+                (numbers, lines))
 
 
 def job_flats(runner, victims) -> List[int]:
@@ -451,6 +471,17 @@ def untraced_metrics(run: Run, setup_s: float, result: dict) -> None:
             continue
         result["metrics"][m["name"]] = {"value": float(value),
                                         "unit": m["unit"]}
+
+
+def say_by_quarter(run: Run) -> None:
+    """The window by quarter, for the reader; it feeds no metric, so a
+    fault in it may not cost a sound run its result."""
+    try:
+        parts = quarters.rounded(quarters.by_quarter(run))
+    except Exception as e:      # noqa: BLE001 - a diagnostic, not a check
+        say(f"by quarter not computed: {e!r}")
+        return
+    say(quarters.LINE + json.dumps(parts))
 
 
 def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
@@ -503,7 +534,8 @@ def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
         harness.kill_phase()
         if tracing:
             jax.profiler.stop_trace()
-        correct, attempted, bad_epochs = harness.check(counter, control)
+        correct, attempted, bad_epochs, checks = harness.check(counter,
+                                                               control)
         device = {"platform": used[0].platform, "kind": used[0].device_kind,
                   "count": len(used),
                   "memory_peak_bytes": max(
@@ -516,7 +548,10 @@ def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
             traced_metrics(run, trace_dir, device, result)
         else:
             untraced_metrics(run, setup_s, result)
+        say_by_quarter(run)
+        result["checks"], check_lines = checks      # last in the line
         say(json.dumps(result))
+        print("\n".join(check_lines), file=sys.stderr, flush=True)
         return result
     finally:
         shutil.rmtree(work, ignore_errors=True)

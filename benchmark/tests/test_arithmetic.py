@@ -5,7 +5,7 @@ import os
 import numpy as np
 import pytest
 
-from benchlib import pacing, trace_reduce
+from benchlib import pacing, program_spans, quarters, trace_reduce
 from benchlib.byname import module_at
 from benchlib.stream import TableFeedReader, TableStream, draw_keys
 
@@ -157,6 +157,47 @@ def test_fence_aligned_rate_counts_whole_epochs_between_stamps():
     assert pacing.rates_by_part(stamps, 10.0, 14.0, 1000, parts=2) \
         == pytest.approx([2000.0, 1000.0])
     assert pacing.rates_by_part(stamps, 10.0, 11.2, 1000, parts=2) == []
+
+
+def test_window_by_quarter_by_hand():
+    """Five stamps a second apart but the last (two seconds), two blocks
+    an epoch, in halves. The recorder's ring has evicted what lies before
+    12.0, so the first half says so and gives no span; the second half
+    holds one draw alone and one beside another thread's span."""
+    class FakeRun:
+        stamps = {4: 10.0, 5: 11.0, 6: 12.0, 7: 13.0, 8: 15.0}
+        window = (9.5, 15.5)
+        cfg = {"steps_per_epoch": 8, "block_steps": 4}
+        records_per_epoch = 1000
+        spans = None
+    span = lambda name, tid, mono, dur: {
+        "name": name, "tid": tid, "mono": mono, "dur": dur}
+    run = FakeRun()
+    run._program_spans = program_spans.Program(
+        spans=[span("epoch", 1, 12.0, 1.0), span("epoch", 1, 13.0, 2.0),
+               span(quarters.DRAW, 1, 12.1, 0.004),
+               span(quarters.DRAW, 1, 13.1, 0.020),
+               span("fence.snapshot", 2, 13.09, 0.025),
+               span(quarters.DRAW, 1, 15.2, 0.004)],   # past the last stamp
+        counters={}, dropped=7, oldest=12.0)
+    first, second = quarters.by_quarter(run, parts=2)
+    assert (first["epochs"], first["blocks"], first["seconds"]) == (2, 4, 2.0)
+    assert first["records_per_s"] == pytest.approx(1000.0)
+    assert first["program"] == "evicted"
+    assert "program_ms_per_block" not in first and "draws" not in first
+    assert second["records_per_s"] == pytest.approx(2000 / 3.0)
+    assert second["program"] == "whole"
+    assert second["program_ms_per_block"] == pytest.approx({
+        "epoch": 3000 / 4, quarters.DRAW: 24 / 4, "fence.snapshot": 25 / 4})
+    assert second["draws"]["alone"] == {"n": 1, "mean_ms": pytest.approx(4.0)}
+    beside = second["draws"]["beside_another_thread"]
+    assert beside["n"] == 1 and beside["mean_ms"] == pytest.approx(20.0)
+    assert beside["mean_overlap_ms"] == pytest.approx(15.0)  # 13.10-13.115
+    # too few commits inside for the cut: no quarters, as rates_by_part
+    assert quarters.by_quarter(run, parts=5) == []
+    run._program_spans = program_spans.Program([], {}, 0, 0.0)
+    assert [q["program"] for q in quarters.by_quarter(run, parts=2)] \
+        == ["no recorder"] * 2
 
 
 def test_latency_from_intended_send_by_hand():

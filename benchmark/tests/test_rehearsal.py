@@ -8,6 +8,7 @@ import json
 import pytest
 
 import run as harness
+from benchlib import quarters
 
 CELLS = ["kafka64.backlog", "allround32.backlog", "kafka64.paced",
          "allround64x4.backlog"]
@@ -21,14 +22,27 @@ def rehearse(tiny_bench, cell, seed=2**31 + 11, trace=False, **kw):
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_runs_to_a_correct_last_line(tiny_bench, cell, capsys):
     result = rehearse(tiny_bench, cell)
-    last = capsys.readouterr().out.strip().splitlines()[-1]
+    captured = capsys.readouterr()
+    last = captured.out.strip().splitlines()[-1]
     assert json.loads(last) == result
+    # each number compared beside its limit: the result's last key, and
+    # the last lines on stderr
+    assert list(result)[-1] == "checks"
+    assert result["checks"]["mismatched_rows"] == {
+        "value": 0, "limit": 0, "ok": True}
+    assert captured.err.strip().splitlines()[-len(result["checks"]):] == [
+        f"check {name}={c['value']} {kind}={c[kind]} ok"
+        for name, c in result["checks"].items()
+        for kind in c if kind not in ("value", "ok")]
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] >= 8
     assert result["device"]["count"] == (4 if "x4" in cell else 1)
     want = {"setup_s", "time_to_resume_ms"} | (
         {"commit_latency_p50_ms", "commit_latency_p95_ms"}
-        if cell.endswith(".paced") else {"served_records_per_s"})
+        if cell.endswith(".paced")
+        # the mesh's rate is an end-to-end metric of its own (PR 43)
+        else {"served_records_per_s.mesh"} if "x4" in cell
+        else {"served_records_per_s"})
     assert set(result["metrics"]) == want
     assert all(m["value"] > 0 for m in result["metrics"].values())
 
@@ -36,13 +50,58 @@ def test_cell_runs_to_a_correct_last_line(tiny_bench, cell, capsys):
 @pytest.mark.parametrize("cell", ["kafka64.backlog", "kafka64.paced"])
 def test_traced_run_reports_what_its_readers_find(tiny_bench, cell):
     """No device plane on the CPU, so the device-trace readers find
-    nothing and are left out; the span readers report."""
+    nothing and are left out; the outside span readers report, and
+    whatever readers of the program's own spans later PRs have added."""
     result = rehearse(tiny_bench, cell, trace=True)
     assert result["correct"] is True
     want = ({"commit_service_ms", "fence_tail_ms"}
             if cell.endswith(".paced")
             else {"feed_pull_ms_per_block", "sink_absorb_ms_per_block"})
-    assert set(result["metrics"]) == want
+    assert set(result["metrics"]) >= want
+    per_layer = {m["name"] for m in json.load(open(tiny_bench))["per_layer"]}
+    assert set(result["metrics"]) <= per_layer
+
+
+def by_quarter_line(out: str) -> list:
+    (line,) = [ln for ln in out.splitlines()
+               if ln.startswith(quarters.LINE)]
+    return json.loads(line[len(quarters.LINE):])
+
+
+@pytest.mark.parametrize("cell,trace", [("kafka64.backlog", True),
+                                        ("allround64x4.backlog", True),
+                                        ("kafka64.backlog", False)])
+def test_window_by_quarter_is_printed_and_parses(tiny_bench, cell, trace,
+                                                 capsys):
+    """One line before the result: the cut of ``rates_by_part``, each
+    quarter's rate beside the host's spans per block. A traced run has
+    the harness's wrappers over the whole window; any run the program's
+    recorder."""
+    result = rehearse(tiny_bench, cell, trace=trace)
+    out = capsys.readouterr().out
+    parts = by_quarter_line(out)
+    assert result["correct"] is True and len(parts) == 4
+    if not trace:      # the rate's reader prints the same cut's rates
+        (window,) = [ln for ln in out.splitlines()
+                     if ln.startswith("window:")]
+        printed = window.split("by quarter ")[1].split(" records/s")[0]
+        assert [p["records_per_s"] for p in parts] == pytest.approx(
+            [float(r) for r in printed.split()], abs=1)
+    for p in parts:
+        assert p["epochs"] >= 1 and p["blocks"] == 2 * p["epochs"]
+        assert p["program"] == "whole"
+        assert {"block.causal-inputs", "block.dispatch", "block.feed.pull",
+                "block.feed.put"} <= set(p["program_ms_per_block"])
+        assert all(v > 0 for v in p["program_ms_per_block"].values())
+        draws = p["draws"]
+        # a commit stamp is not a block's edge: a draw more or less
+        assert abs(draws["alone"]["n"] + draws["beside_another_thread"]["n"]
+                   - p["blocks"]) <= 2
+        if trace:
+            assert {"epoch", "feed_pull", "sink_absorb"} <= set(
+                p["harness_ms_per_block"])
+        else:
+            assert "harness_ms_per_block" not in p
 
 
 @pytest.mark.parametrize("control", ["f32", "at-least-once"])
