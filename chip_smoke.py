@@ -41,6 +41,15 @@ it pass off the device:
      wrong is the block form fused into a whole block program (PR 40: a
      restarting sum came out wrong there from step 640 on, and not
      alone). Not in the default parts.
+  I  the incremental join (NEXmark query 3) inside a job's block program:
+     the benchmark's ``nexmark-local-items`` job at its tiny stand-in's
+     sizes (persons that expire inside the run, auctions that come after
+     them and wait, auctions flushed by a person a step later; chunks that are
+     quiet and chunks that run step by step), once in blocks of 1,024
+     steps and once in blocks of 16. Pass = the two committed streams
+     are equal, and equal to the topology's NumPy reference, no loss.
+     Run it on the chip after any change to the join's block form (D17:
+     a block form right alone is not verified). Not in the default parts.
   C  job A again under a four-chip task mesh, when there are four chips.
      Pass = committed stream byte-identical to A's, ledgers equal, every
      sharded carry leaf on four devices at a quarter each.
@@ -602,6 +611,67 @@ def check_sessions_in_a_job(seed: int, spe: int = 2048, epochs: int = 3
     return int(wide.shape[0])
 
 
+def check_incremental_join_in_a_job(seed: int, spe: int = 2048,
+                                    epochs: int = 3):
+    """Part I: the committed stream of the ``nexmark-local-items`` job
+    run in blocks of 1,024 steps against the same job run in blocks of
+    16 and against the topology's plain reference; returns (rows
+    compared, of them flushed out of the bag, chunks run step by step)."""
+    import json
+    bench = os.path.join(HERE, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from benchlib import job as bench_job
+    from benchlib.byname import module_at
+    from benchlib.stream import TableFeedReader
+    from clonos_tpu.runtime.cluster import ClusterRunner
+
+    with open(os.path.join(bench, "tests", "tiny", "bench", "configs",
+                           "tiny-nexmark-q3.json")) as f:
+        cfg = json.load(f)
+    cfg["steps_per_epoch"] = spe
+    stream = bench_job.make_stream(cfg, {"table_epochs": 2}, seed)
+    ref = module_at(bench_job.topology_file(cfg, "reference.py"))
+    build = module_at(bench_job.topology_file(cfg, "job.py")).build
+
+    def committed(block_steps: int):
+        runner = ClusterRunner(
+            build(cfg), steps_per_epoch=spe, block_steps=block_steps,
+            log_capacity=1 << (spe * 8 - 1).bit_length(), max_epochs=16,
+            inflight_ring_steps=2 * spe, seed=seed, logical_time=True,
+            audit=False)
+        runner.executor.register_feed(0, TableFeedReader(stream))
+        (txn,) = runner.txn_logs.values()
+        got = {}
+        txn.committer = lambda e, rows: got.setdefault(e, []).append(
+            np.asarray(rows))
+        for _ in range(epochs):
+            runner.run_epoch(complete_checkpoint=True)
+        runner.drain_fence()
+        lost = runner.executor.check_overflow()
+        if lost:
+            raise AssertionError(
+                f"incremental join, blocks of {block_steps}: {lost}")
+        state = runner.executor.vertex_state(4)
+        return got, int(np.asarray(state["step_chunks"]).sum())
+
+    (wide, stepped), (narrow, _) = committed(1024), committed(16)
+    want = ref.expected(cfg, stream.keys, stream.vals, epochs)
+    for name, got in (("1,024", wide), ("16", narrow)):
+        bad, failed, compared = ref.check(got, want, cfg, epochs)
+        if bad:
+            raise AssertionError(
+                f"incremental join, blocks of {name} steps: {bad} rows "
+                f"differ from the reference in epochs {failed}")
+    if min(want.flushed, want.bag_expired) < 100 \
+            or not 0 < stepped < 4 * epochs * spe // 32:
+        raise AssertionError(
+            f"incremental join: the traffic left a branch out "
+            f"({want.flushed} flushed, {want.bag_expired} expired in the "
+            f"bag, {stepped} chunks step by step)")
+    return compared, want.flushed, stepped
+
+
 # --- main --------------------------------------------------------------------
 
 
@@ -650,7 +720,7 @@ def print_routes(tracer, since: int, part: str) -> int:
     # of the event-time windows (fired, late, dropped; the most sessions
     # a subtask has held open)
     for name, n in sorted(tracer.counters().items()):
-        if name.startswith(("exchange.", "window.")):
+        if name.startswith(("exchange.", "window.", "join.")):
             say(f"{part} counter {name} = {n}")
     return len(recs)
 
@@ -659,7 +729,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=21)
     ap.add_argument("--parts", default="KABC",
-                    help="which of K, J, S, A, B, C to run (C needs A)")
+                    help="which of K, J, S, I, A, B, C to run (C needs A)")
     args = ap.parse_args(argv)
     t_start = time.monotonic()
 
@@ -709,6 +779,15 @@ def main(argv=None) -> int:
         mark = print_routes(tracer, mark, "S")
         say(f"S pass: session windows, blocks of 1,024 steps == blocks of "
             f"16 over {rows} rows ({time.monotonic() - t0:.1f}s)")
+
+    if "I" in parts:
+        t0 = time.monotonic()
+        rows, flushed, stepped = check_incremental_join_in_a_job(args.seed)
+        mark = print_routes(tracer, mark, "I")
+        say(f"I pass: incremental join, blocks of 1,024 steps == blocks of "
+            f"16 == the reference over {rows} rows, {flushed} of them "
+            f"flushed, {stepped} chunks by the step form "
+            f"({time.monotonic() - t0:.1f}s)")
 
     shape = ServedShape()
     feed = make_feed(shape, args.seed)
@@ -808,7 +887,7 @@ def main(argv=None) -> int:
     say(f"compile cache: {entries1} entries at end "
         f"({entries1 - entries0} added by this run)")
     say(f"total {time.monotonic() - t_start:.1f}s; parts run: "
-        f"{''.join(p for p in 'KJSABC' if p in parts)}")
+        f"{''.join(p for p in 'KJSIABC' if p in parts)}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": n_dev}}), flush=True)

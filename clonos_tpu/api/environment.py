@@ -221,6 +221,39 @@ class DataStream:
             capacity=capacity or self._env.default_edge_capacity)
         return self._attach2(other, name, op, parallelism, edge_capacity)
 
+    def join_incremental(self, other: "DataStream", num_keys: int, ttl: int,
+                         out_of_orderness: int = 0,
+                         capacity: Optional[int] = None,
+                         own_columns: Optional[int] = None,
+                         bag_capacity: int = 1024,
+                         edge_capacity: Optional[int] = None,
+                         name: str = "incremental-join",
+                         parallelism: Optional[int] = None) -> "DataStream":
+        """Join over the whole history of two keyed streams, no window:
+        ``self`` builds — a key's first record registers it, for ``ttl``
+        of event time — and ``other`` probes: a record whose key is
+        registered is a row ``(key, value, timestamp)`` at once, any
+        other waits for its key, ``ttl`` at most (Beam's NEXmark
+        ``Query3``, "Local Item Suggestion": persons and the auctions
+        they sell; operators.IncrementalJoinOperator has the rule). Both
+        inputs must be key_by()'d.
+
+        ``capacity``: rows a subtask may emit a step; ``bag_capacity``:
+        records a subtask may keep waiting; ``own_columns``: hold a
+        column only for the keys a subtask owns (as :meth:`window_top`;
+        required past the keys a dense table holds); ``edge_capacity``:
+        the receive window of BOTH input edges. What passes a capacity
+        is counted and stops the run at the next fence."""
+        from clonos_tpu.api.operators import IncrementalJoinOperator
+        if not (self._keyed and other._keyed):
+            raise ValueError(
+                "join_incremental requires key_by() on both inputs")
+        op = IncrementalJoinOperator(
+            num_keys=num_keys, ttl=ttl, out_of_orderness=out_of_orderness,
+            capacity=capacity or self._env.default_edge_capacity,
+            own_columns=own_columns, bag_capacity=bag_capacity)
+        return self._attach2(other, name, op, parallelism, edge_capacity)
+
     def window_top(self, num_keys: int, window_size: int,
                    slide: Optional[int] = None, out_of_orderness: int = 0,
                    capacity: Optional[int] = None,

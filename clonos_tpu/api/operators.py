@@ -1431,8 +1431,47 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
         return new, out
 
 
+class _OwnColumns:
+    """A table with a column a key, on every subtask (dense) or, with
+    ``own_columns``, only for the keys a subtask owns: what every such
+    operator shares. ``state["cols"]`` (int32 ``[P, columns]``,
+    ascending, then :data:`NO_KEY`) says which key each column holds;
+    the planner binds it (``CompiledJob._bind_own_columns``), so the
+    binding is state — one operator object serves any number of plans,
+    a checkpoint carries it."""
+
+    num_keys: int
+    own_columns: Optional[int]
+
+    @property
+    def _columns(self) -> int:
+        return self.num_keys if self.own_columns is None else self.own_columns
+
+    def _init_cols(self, parallelism: int) -> jnp.ndarray:
+        """Dense: a column a key; own columns: none bound yet, every
+        record is refused until the planner binds them."""
+        c = self._columns
+        cols = (jnp.arange(c, dtype=jnp.int32) if self.own_columns is None
+                else jnp.full((c,), NO_KEY, jnp.int32))
+        return jnp.broadcast_to(cols, (parallelism, c))
+
+    def bind_own_columns(self, state, cols):
+        cols = jnp.asarray(cols, jnp.int32)
+        if cols.shape != state["cols"].shape:
+            raise ValueError(
+                f"own columns {cols.shape} for a table of "
+                f"{state['cols'].shape}")
+        return dict(state, cols=cols)
+
+    def rescale_keyed_state(self, state, new_parallelism, num_key_groups):
+        raise NotImplementedError(
+            f"{type(self).__name__} does not support rescaling: its "
+            f"columns are bound to the keys each subtask owns when the job "
+            f"is planned, and a live rescale would have to bind them anew")
+
+
 @dataclasses.dataclass
-class EventTimeWindowTopOperator(_EventTimeSlots, Operator):
+class EventTimeWindowTopOperator(_OwnColumns, _EventTimeSlots, Operator):
     """Event-time windowed sum per key, sliding or tumbling, of which a
     window that fires emits only its LARGEST: one row ``(key, sum,
     window end - 1)`` for every key whose non-zero sum equals the largest
@@ -1504,37 +1543,15 @@ class EventTimeWindowTopOperator(_EventTimeSlots, Operator):
     def out_capacity(self):  # type: ignore[override]
         return self.capacity
 
-    @property
-    def _columns(self) -> int:
-        return self.num_keys if self.own_columns is None else self.own_columns
-
     def init_state(self, parallelism: int):
         p, w, c = parallelism, self.open_windows, self._columns
-        # dense: a column a key; own columns: none bound yet, every
-        # record is refused until the planner binds them
-        cols = (jnp.arange(c, dtype=jnp.int32) if self.own_columns is None
-                else jnp.full((c,), NO_KEY, jnp.int32))
         state = {k: jnp.zeros((p,), jnp.int32) for k, _ in self.fence_totals}
         state.update(
             acc=jnp.zeros((p, w, c), jnp.int32),
             win=jnp.full((p, w), _NO_WINDOW, jnp.int32),
             max_ts=jnp.full((p,), _NO_TS, jnp.int32),
-            cols=jnp.broadcast_to(cols, (p, c)))
+            cols=self._init_cols(p))
         return state
-
-    def bind_own_columns(self, state, cols):
-        cols = jnp.asarray(cols, jnp.int32)
-        if cols.shape != state["cols"].shape:
-            raise ValueError(
-                f"own columns {cols.shape} for a table of "
-                f"{state['cols'].shape}")
-        return dict(state, cols=cols)
-
-    def rescale_keyed_state(self, state, new_parallelism, num_key_groups):
-        raise NotImplementedError(
-            "EventTimeWindowTopOperator does not support rescaling: its "
-            "columns are bound to the keys each subtask owns when the job "
-            "is planned, and a live rescale would have to bind them anew")
 
     @scoped("lookup")
     def _column(self, cols, keys):
@@ -1692,7 +1709,7 @@ def _segmented_any_min(reset: jnp.ndarray, hit: jnp.ndarray,
 
 
 @dataclasses.dataclass
-class SessionWindowOperator(Operator):
+class SessionWindowOperator(_OwnColumns, Operator):
     """Event-time session windows per key, Flink's merging sessions
     (``EventTimeSessionWindows.withGap``; NEXmark query 11, "User
     Sessions"): a record opens ``[ts, ts + gap)``, windows of one key
@@ -1781,18 +1798,12 @@ class SessionWindowOperator(Operator):
                 f"open session, and this operator keeps two")
 
     @property
-    def _columns(self) -> int:
-        return self.num_keys if self.own_columns is None else self.own_columns
-
-    @property
     def out_capacity(self):  # type: ignore[override]
         return (2 * self._columns if self.capacity is None
                 else self.capacity)
 
     def init_state(self, parallelism: int):
         p, c = parallelism, self._columns
-        cols = (jnp.arange(c, dtype=jnp.int32) if self.own_columns is None
-                else jnp.full((c,), NO_KEY, jnp.int32))
         state = {k: jnp.zeros((p,), jnp.int32)
                  for k, _ in self.fence_totals + self.fence_peaks}
         state.update(
@@ -1802,16 +1813,8 @@ class SessionWindowOperator(Operator):
             b_lo=jnp.full((p, c), _NO_LO, jnp.int32),
             b_hi=jnp.full((p, c), _NO_TS, jnp.int32),
             max_ts=jnp.full((p,), _NO_TS, jnp.int32),
-            cols=jnp.broadcast_to(cols, (p, c)))
+            cols=self._init_cols(p))
         return state
-
-    bind_own_columns = EventTimeWindowTopOperator.bind_own_columns
-
-    def rescale_keyed_state(self, state, new_parallelism, num_key_groups):
-        raise NotImplementedError(
-            "SessionWindowOperator does not support rescaling: its columns "
-            "are bound to the keys each subtask owns when the job is "
-            "planned, and a live rescale would have to bind them anew")
 
     def _watermark(self, max_ts):
         return jnp.where(max_ts == _NO_TS, _NO_TS,
@@ -1992,6 +1995,442 @@ class SessionWindowOperator(Operator):
             dropped=state["dropped"] + dropped.sum(axis=0),
             disordered=state["disordered"] + n(disordered).sum(axis=0),
             open_peak=jnp.maximum(state["open_peak"], held)), out
+
+
+def _pack_by_rank(mask: jnp.ndarray, fields, width: int):
+    """``fields [..., N]`` of the lanes ``mask`` marks, packed to the
+    front of ``[..., width]`` in lane order, and how many were marked
+    ``[...]`` (past ``width`` they do not fit). A lane's place is its
+    rank among the marked, so the packing is one keyed histogram over
+    ranks a field: no sort, no gather."""
+    from clonos_tpu.ops.histogram import keyed_hist
+    from clonos_tpu.ops.matops import running_count
+    rank = running_count(mask) - 1
+    return (tuple(keyed_hist(rank, x, mask, width, want_counts=False)[0]
+                  for x in fields), rank[..., -1] + 1)
+
+
+@dataclasses.dataclass
+class IncrementalJoinOperator(_OwnColumns, TwoInputOperator):
+    """Join of two keyed streams over their whole history, with no
+    window: the left input BUILDS — a key's first record registers it —
+    and the right input PROBES; a probe whose key is not registered
+    waits for it. Apache Beam's NEXmark ``Query3`` ("Local Item
+    Suggestion": category-10 auctions joined with the persons of three
+    states on ``seller = id``) writes the rule per key as a stateful
+    ``DoFn`` — the person in a value state, the auctions waiting for
+    their person in a bag, the person cleared ``maxAuctionsWaitingTime``
+    after it registered — and this operator is that rule, per key,
+    exactly. "Person" and "auction" below are Query3's words for a left
+    and a right record.
+
+    **Watermark.** Flink's rule for a two-input operator, as
+    :class:`EventTimeWindowJoinOperator` has it: the smaller of the two
+    inputs' running maxima of valid timestamps less
+    ``out_of_orderness``, advanced once a step BEFORE the step's
+    records; while an input has delivered nothing there is none, and
+    nothing expires. A person is *live* while ``its timestamp + ttl >
+    watermark``; an auction waits as long, by its own timestamp.
+
+    **A step**, per subtask, in this order: (1) the auctions that have
+    waited ``ttl`` leave the bag (``bag_expired``); (2) the step's
+    persons, key by key: if the key holds a live person they are
+    duplicates (``duplicates``; nothing is emitted), else the one with
+    the earliest timestamp becomes the key's person (the others are
+    duplicates) and every auction waiting for the key leaves the bag as
+    a row (``flushed``); (3) the step's auctions: one whose key holds a
+    live person is a row at once, any other waits (``bagged``) — also
+    one that arrives after its person expired (``expired_probes``
+    counts, among those that wait, the ones whose key holds an expired
+    person that was live at the auction's own timestamp: rows that
+    lateness cost; an id ring's earlier lap is not one). A row is the auction: ``(key, its value,
+    its timestamp)``. A step's rows are the flushed ones in the order
+    they waited, then the auctions' in arrival order, cut to
+    ``capacity`` a subtask a step.
+
+    **What is lost is counted and loud** (``fence_losses``: a non-zero
+    one stops the run at the next fence): a row past ``capacity``
+    (``dropped``), an auction that finds ``bag_capacity`` auctions
+    waiting on its subtask (``bag_overflow``; Beam's bag is unbounded
+    because its run ends), a record whose key this subtask holds no
+    column for (``unplaced``) and one whose key lies outside ``[0,
+    num_keys)`` — an id ring smaller than the ids alive in ``ttl``
+    would show there or as wrong rows (``ring_too_small``).
+
+    **State.** Per own column (:class:`_OwnColumns`) the person's
+    timestamp (it stays after it expired, until the next one
+    registers); per subtask the waiting auctions ``(key, value,
+    timestamp)`` in the order they came, ``bag_capacity`` of them —
+    one pool a subtask and not a bag a column: Beam bounds neither, and
+    a hot key may fill what a thousand quiet ones leave. No dense form
+    past what the keyed histogram holds: the table exists for
+    ``own_columns`` (``num_keys`` 131,072 in NEXmark's cell).
+    ``live_peak`` is the most live persons a subtask has held after a
+    step.
+
+    **The block form** takes the block in chunks of 32 steps at most — a loop over the chunks, none over the steps, no scatter, no
+    gather. A chunk's records are packed to the front first (a keyed
+    histogram over their ranks: the receive windows of a skewed edge
+    are mostly empty), then one comparison of the chunk's persons with
+    the subtask's columns gives each column its first arrival, one of
+    its auctions with the columns gives each auction its key's person,
+    one of the bag with the persons says what a registration flushes,
+    and a row's place is a histogram lane ``step x capacity + rank``.
+    That is exact while a chunk is quiet in the way chunks are — no key
+    whose person expires inside the chunk receives another, a person
+    that registers outlives the chunk, the packed records and the bag
+    fit; the chunk that is not (``step_chunks`` counts it) runs step by
+    step under a ``lax.cond``, so the block form is the step form bit
+    for bit on any input (``step_chunks`` aside, which depends on where
+    the chunks fall).
+
+    Every row carries a key this subtask received
+    (``emits_received_keys``): behind two ``key_by()`` inputs the out
+    edge is routed in place.
+    """
+
+    num_keys: int
+    ttl: int
+    out_of_orderness: int = 0
+    capacity: int = 256
+    own_columns: Optional[int] = None
+    bag_capacity: int = 1024
+
+    emits_received_keys = True
+
+    fence_totals = (
+        ("rows", "join.rows"), ("flushed", "join.flushed_rows"),
+        ("bagged", "join.bagged"), ("bag_expired", "join.bag_expired"),
+        ("duplicates", "join.duplicate_persons"),
+        ("expired_probes", "join.expired_probes"),
+        ("step_chunks", "join.step_form_chunks"),
+        ("bag_overflow", "join.bag_overflow"),
+        ("dropped", "join.dropped_rows"),
+        ("unplaced", "join.unplaced_records"),
+        ("ring_too_small", "join.ring_too_small"))
+    fence_losses = ("bag_overflow", "dropped", "unplaced", "ring_too_small")
+    fence_peaks = (("live_peak", "join.live_persons"),)
+
+    #: steps of a chunk, at most (short enough that a ttl outlasts it)
+    _CHUNK_STEPS = 32
+    #: a chunk's records are packed into this many receive windows a
+    #: side (left, right); a chunk that brings more runs step by step
+    _PACKED_WINDOWS = (2, 4)
+
+    def __post_init__(self):
+        from clonos_tpu.ops.histogram import KERNEL_MAX_KEYS
+        if self.own_columns is None and self.num_keys > KERNEL_MAX_KEYS:
+            raise ValueError(
+                f"an incremental join over {self.num_keys} keys needs "
+                f"own_columns: a dense table a subtask is only kept up to "
+                f"{KERNEL_MAX_KEYS} keys")
+        if min(self.ttl, self.capacity, self.bag_capacity) < 1:
+            raise ValueError("ttl, capacity and bag_capacity must be "
+                             "positive")
+
+    @property
+    def out_capacity(self):  # type: ignore[override]
+        return self.capacity
+
+    _COUNTERS = tuple(k for k, _ in fence_totals)
+
+    def init_state(self, parallelism: int):
+        p, c, g = parallelism, self._columns, self.bag_capacity
+        state = {k: jnp.zeros((p,), jnp.int32)
+                 for k, _ in self.fence_totals + self.fence_peaks}
+        state.update(
+            cols=self._init_cols(p),
+            person_ts=jnp.full((p, c), _NO_TS, jnp.int32),
+            bag_key=jnp.zeros((p, g), jnp.int32),
+            bag_val=jnp.zeros((p, g), jnp.int32),
+            bag_ts=jnp.zeros((p, g), jnp.int32),
+            bag_n=jnp.zeros((p,), jnp.int32),
+            max_ts_left=jnp.full((p,), _NO_TS, jnp.int32),
+            max_ts_right=jnp.full((p,), _NO_TS, jnp.int32))
+        return state
+
+    # --- what both forms share ------------------------------------------------
+
+    def _in_range(self, b: RecordBatch):
+        return b.valid & (b.keys >= 0) & (b.keys < self.num_keys)
+
+    def _watermark(self, max_l, max_r):
+        lo = jnp.minimum(max_l, max_r)       # _NO_TS while an input is silent
+        return jnp.where(lo != _NO_TS, lo - self.out_of_orderness, _NO_TS)
+
+    def _live(self, ts, wm):
+        return (ts != _NO_TS) & (ts + self.ttl > wm)
+
+    def _chunk_of(self, steps: int) -> int:
+        """Steps a chunk of a block of ``steps`` takes: they divide the
+        block, and a chunk's rows fit the histogram's lanes."""
+        from clonos_tpu.ops.histogram import KERNEL_MAX_KEYS
+        most = max(1, min(self._CHUNK_STEPS,
+                          KERNEL_MAX_KEYS // self.capacity))
+        return max(s for s in range(1, most + 1) if steps % s == 0)
+
+    def _chunk(self, core, left, right, wm):
+        """One chunk of ``S`` steps over every subtask, exact if the
+        chunk is quiet (class docstring; always for ``S = 1``).
+
+        ``core``: the state's tables and counters; ``left`` / ``right``:
+        the chunk's records in (step, slot) order as ``(key, value,
+        timestamp, step, valid)`` of ``[P, M]``; ``wm [S, P]``. Returns
+        the new ``core``, the chunk's rows as histogram operands —
+        ``(lane, key, value, timestamp, valid) [P, N]``, lane ``step x
+        capacity + rank`` — with the rows a step emits ``[P, S]``, and
+        which subtasks the chunk was not quiet for ``[P]``."""
+        from clonos_tpu.ops.matops import running_count
+        S, cap, g, ttl = wm.shape[0], self.capacity, self.bag_capacity, \
+            self.ttl
+        cols, pts0 = core["cols"], core["person_ts"]
+        lk, _, lt, ls, lok = left
+        rk, rvl, rt, rs, rok = right
+        n = lambda m, axis=-1: jnp.sum(m.astype(jnp.int32), axis=axis)
+        steps = jnp.arange(S, dtype=jnp.int32)
+        wm_p = wm.T                                                # [P, S]
+        at_step = lambda s, x: jnp.sum(                 # x[p, s[p, m]]
+            jnp.where(s[..., None] == steps, x[:, None, :], 0), axis=-1)
+
+        with jax.named_scope("lookup"):
+            # each column's persons of the chunk: how many, and of the
+            # earliest step's the earliest (one pass over the pairs)
+            m = lok[:, :, None] & (lk[:, :, None] == cols[:, None, :])
+
+            def first(x, y):
+                early = (x[1] < y[1]) | ((x[1] == y[1]) & (x[2] <= y[2]))
+                return (x[0] + y[0], jnp.where(early, x[1], y[1]),
+                        jnp.where(early, x[2], y[2]))
+
+            came, fs, ft = jax.lax.reduce(
+                (m.astype(jnp.int32), jnp.where(m, ls[:, :, None], S),
+                 jnp.where(m, lt[:, :, None], _NO_LO)),
+                (jnp.int32(0), jnp.int32(S), jnp.int32(_NO_LO)), first, (1,))
+            live0 = self._live(pts0, wm_p[:, :1])
+            live1 = self._live(pts0, wm_p[:, -1:])
+            reg = (came > 0) & ~live0
+            # not quiet: a key's person expires inside the chunk and the
+            # key receives another; a person that registers and does not
+            # outlive the chunk
+            loud = (jnp.any((came > 0) & live0 & ~live1, axis=-1)
+                    | jnp.any(reg & ~self._live(ft, wm_p[:, -1:]), axis=-1))
+            person_ts = jnp.where(reg, ft, pts0)
+            reg_at = jnp.where(reg, fs, S)
+            reg_ts = jnp.where(reg, ft, _NO_TS)
+            # each auction's column: the person it held when the chunk
+            # began, and the one that registers in it
+            m2 = rok[:, :, None] & (rk[:, :, None] == cols[:, None, :])
+            held, p0, r_at, r_ts = jax.lax.reduce(
+                (m2.astype(jnp.int32), jnp.where(m2, pts0[:, None, :], 0),
+                 jnp.where(m2, reg_at[:, None, :], 0),
+                 jnp.where(m2, reg_ts[:, None, :], 0)),
+                (jnp.int32(0),) * 4,
+                lambda x, y: tuple(a + b for a, b in zip(x, y)), (2,))
+            held = held > 0
+        with jax.named_scope("place"):
+            wm_r = at_step(rs, wm_p)                               # [P, M]
+            after = held & (r_at < S) & (rs >= r_at)   # persons come first
+            match = held & jnp.where(after, self._live(r_ts, wm_r),
+                                     self._live(p0, wm_r))
+            bagged = held & ~match
+            # of those that wait, the ones their key's person would have
+            # taken, had they come before the watermark passed its ttl
+            holds = jnp.where(after, r_ts, p0)
+            probes = (bagged & (holds != _NO_TS) & (holds <= rt)
+                      & (rt < holds + ttl))
+            # the bag, then the chunk's waiting auctions, in the order
+            # they came: when each expires, and when a person flushes it
+            slot = jnp.arange(g, dtype=jnp.int32)
+            b_ok = slot[None, :] < core["bag_n"][:, None]
+            first_person = jnp.min(jnp.where(
+                b_ok[:, :, None] & lok[:, None, :]
+                & (core["bag_key"][:, :, None] == lk[:, None, :]),
+                ls[:, None, :], S), axis=-1)                       # [P, G]
+            cat = lambda a, b: jax.lax.optimization_barrier(
+                jnp.concatenate([a, b], axis=-1))
+            e_key, e_val, e_ts = (cat(core["bag_key"], rk),
+                                  cat(core["bag_val"], rvl),
+                                  cat(core["bag_ts"], rt))
+            e_ok = cat(b_ok, bagged)
+            born = cat(jnp.full_like(core["bag_key"], -1), rs)
+            flush_at = cat(first_person,
+                           jnp.where((r_at < S) & (rs < r_at), r_at, S))
+            gone_at = jnp.maximum(born + 1, n(
+                wm_p[:, None, :] < (e_ts + ttl)[:, :, None]))
+            flushed = e_ok & (flush_at < S) & (flush_at < gone_at)
+            expired = e_ok & ~flushed & (gone_at < S)
+            waits = e_ok & ~flushed & ~expired
+            (bag_key, bag_val, bag_ts), w_total = _pack_by_rank(
+                waits, (e_key, e_val, e_ts), g)
+            loud = loud | (core["bag_n"] + n(bagged) > g)
+        with jax.named_scope("emit"):
+            # a step's rows: the flushed ones in the order they waited,
+            # then its auctions in arrival order
+            f_hot = flushed[:, None, :] & (flush_at[:, None, :]
+                                           == steps[None, :, None])
+            f_rank = jnp.sum(jnp.where(f_hot, running_count(f_hot) - 1, 0),
+                             axis=1)                               # [P, N]
+            n_flush = n(f_hot)                                     # [P, S]
+            m_hot = match[:, None, :] & (rs[:, None, :]
+                                         == steps[None, :, None])
+            n_match = n(m_hot)
+            before = jnp.cumsum(n_match, axis=-1) - n_match
+            m_rank = (running_count(match) - 1
+                      + at_step(rs, n_flush - before))
+            emitted = jnp.minimum(n_flush + n_match, cap)
+            rank = cat(m_rank, f_rank)
+            rows = (cat(rs, flush_at) * cap + rank, cat(rk, e_key),
+                    cat(rvl, e_val), cat(rt, e_ts),
+                    cat(match, flushed) & (rank < cap))
+        # the most live persons after a step of the chunk
+        step_live = jnp.where(
+            reg[:, None, :] & (steps[None, :, None] >= fs[:, None, :]),
+            self._live(ft[:, None, :], wm_p[:, :, None]),
+            self._live(pts0[:, None, :], wm_p[:, :, None]))
+        new = dict(
+            core, person_ts=person_ts, bag_key=bag_key, bag_val=bag_val,
+            bag_ts=bag_ts, bag_n=jnp.minimum(w_total, g),
+            rows=core["rows"] + n(emitted),
+            flushed=core["flushed"] + n(flushed),
+            bagged=core["bagged"] + n(bagged),
+            bag_expired=core["bag_expired"] + n(expired),
+            duplicates=core["duplicates"] + n(came) - n(reg),
+            expired_probes=core["expired_probes"] + n(probes),
+            bag_overflow=core["bag_overflow"] + jnp.maximum(w_total - g, 0),
+            dropped=core["dropped"] + n(n_flush + n_match - emitted),
+            unplaced=(core["unplaced"] + n(lok) - n(came)
+                      + n(rok & ~held)),
+            live_peak=jnp.maximum(core["live_peak"],
+                                  jnp.max(n(step_live), axis=-1)))
+        return new, rows, emitted, loud
+
+    _CORE = ("cols", "person_ts", "bag_key", "bag_val", "bag_ts", "bag_n",
+             "live_peak") + _COUNTERS
+
+    @scoped("emit")
+    def _rows_of(self, rows, emitted, steps: int) -> RecordBatch:
+        """The rows ``_chunk`` hands back as batches ``[..., P, steps,
+        capacity]``: a row's place is its lane."""
+        from clonos_tpu.ops.histogram import keyed_hist
+        lane, key, val, ts, ok = rows
+        cap = self.capacity
+        key, val, ts = (
+            keyed_hist(lane, x, ok, steps * cap, want_counts=False)[0]
+            .reshape(lane.shape[:-1] + (steps, cap)) for x in (key, val, ts))
+        valid = jnp.arange(cap, dtype=jnp.int32) < emitted[..., None]
+        return zero_invalid(RecordBatch(key, val, ts, valid))
+
+    def _step(self, core, left, right, wm):
+        """One step, exactly: ``core``, the two receive windows ``[P,
+        B]`` and ``wm [P]`` -> ``(core, rows [P, capacity])``."""
+        zero = jnp.zeros_like(left.keys)
+        as_chunk = lambda b: (b.keys, b.values, b.timestamps, zero,
+                              self._in_range(b))
+        core, rows, emitted, _ = self._chunk(
+            core, as_chunk(left), as_chunk(right), wm[None])
+        out = self._rows_of(rows, emitted, 1)
+        return core, jax.tree_util.tree_map(lambda x: x[:, 0], out)
+
+    def _top_ts(self, b: RecordBatch):
+        """The largest timestamp among a receive window's records."""
+        return jnp.max(jnp.where(self._in_range(b), b.timestamps, _NO_TS),
+                       axis=-1)
+
+    def _core(self, state, left, right, steps_axis=()):
+        """The part of ``state`` a chunk works on, with the records whose
+        key lies off the ring counted."""
+        core = {k: state[k] for k in self._CORE}
+        core["ring_too_small"] = core["ring_too_small"] + sum(
+            jnp.sum((b.valid & ~self._in_range(b)).astype(jnp.int32),
+                    axis=steps_axis + (-1,)) for b in (left, right))
+        return core
+
+    def process2(self, state, left, right, ctx):
+        max_l = jnp.maximum(state["max_ts_left"], self._top_ts(left))
+        max_r = jnp.maximum(state["max_ts_right"], self._top_ts(right))
+        core, out = self._step(self._core(state, left, right), left, right,
+                               self._watermark(max_l, max_r))
+        return dict(state, **core, max_ts_left=max_l,
+                    max_ts_right=max_r), out
+
+    @scoped("compact")
+    def _packed(self, b: RecordBatch, chunks: int, width: int):
+        """A block's records chunk by chunk, packed to the front in
+        (step, slot) order: ``(key, value, timestamp, step, valid)`` of
+        ``[chunks, P, width]``, and how many a chunk brought ``[chunks,
+        P]`` (past ``width`` they do not fit)."""
+        K, p, e = b.keys.shape
+        s = K // chunks
+        flat = lambda x: x.reshape(chunks, s, p, e).transpose(
+            0, 2, 1, 3).reshape(chunks, p, s * e)
+        ok = flat(self._in_range(b))
+        step = jnp.broadcast_to(
+            jnp.repeat(jnp.arange(s, dtype=jnp.int32), e), ok.shape)
+        fields = (flat(b.keys), flat(b.values), flat(b.timestamps), step)
+        if s * e <= width:                   # as they lie: nothing to pack
+            return fields + (ok,), jnp.sum(ok.astype(jnp.int32), axis=-1)
+        packed, total = _pack_by_rank(ok, fields, width)
+        return packed + (jnp.arange(width, dtype=jnp.int32)
+                         < total[..., None],), total
+
+    def process_block(self, state, batches, bctx):
+        left, right = batches
+        K, p, e = left.keys.shape
+        S, cap = self._chunk_of(K), self.capacity
+        chunks = K // S
+        max_l = jnp.maximum(state["max_ts_left"][None],
+                            _running_max(self._top_ts(left)))     # [K, P]
+        max_r = jnp.maximum(state["max_ts_right"][None],
+                            _running_max(self._top_ts(right)))
+        wm = self._watermark(max_l, max_r).reshape(chunks, S, p)
+        widths = tuple(min(S * e, w * e) for w in self._PACKED_WINDOWS)
+        (l_pack, l_total), (r_pack, r_total) = (
+            self._packed(b, chunks, w)
+            for b, w in zip((left, right), widths))
+        full = (l_total > widths[0]) | (r_total > widths[1])   # [chunks, P]
+        by_chunk = lambda b: jax.tree_util.tree_map(
+            lambda x: x.reshape((chunks, S) + x.shape[1:]), b)
+        core = self._core(state, left, right, steps_axis=(0,))
+        # a chunk's rows as histogram operands, as wide as either way of
+        # running it makes them
+        wide = max(widths[1] + self.bag_capacity + widths[1], S * cap)
+
+        def quiet(core, xs):
+            l_pack, r_pack, _, _, wm = xs
+            core, rows, emitted, loud = self._chunk(core, l_pack, r_pack, wm)
+            pad = lambda x: jnp.pad(x, ((0, 0), (0, wide - x.shape[-1])))
+            return core, tuple(pad(x) for x in rows), emitted, loud
+
+        def by_steps(core, xs):
+            _, _, l_raw, r_raw, wm = xs
+            core, out = jax.lax.scan(
+                lambda c, x: self._step(c, *x), core, (l_raw, r_raw, wm))
+            lanes = lambda x: jnp.pad(
+                x.transpose(1, 0, 2).reshape(p, S * cap),
+                ((0, 0), (0, wide - S * cap)))
+            lane = jnp.broadcast_to(jnp.arange(wide, dtype=jnp.int32),
+                                    (p, wide))
+            return core, (lane, lanes(out.keys), lanes(out.values),
+                          lanes(out.timestamps), lanes(out.valid)), \
+                out.count().T
+
+        def chunk(core, xs):
+            fast, rows, emitted, loud = quiet(core, xs[:-1])
+            loud = loud | xs[-1]
+            core, rows, emitted = jax.lax.cond(
+                jnp.any(loud), lambda: by_steps(core, xs[:-1]),
+                lambda: (fast, rows, emitted))
+            core["step_chunks"] = core["step_chunks"] + loud.astype(jnp.int32)
+            return core, (rows, emitted)
+
+        core, (rows, emitted) = jax.lax.scan(
+            chunk, core, (l_pack, r_pack, by_chunk(left), by_chunk(right),
+                          wm, full))
+        out = self._rows_of(rows, emitted, S)         # [chunks, P, S, cap]
+        out = jax.tree_util.tree_map(
+            lambda x: x.transpose(0, 2, 1, 3).reshape(K, p, cap), out)
+        return dict(state, **core, max_ts_left=max_l[-1],
+                    max_ts_right=max_r[-1]), out
 
 
 @dataclasses.dataclass

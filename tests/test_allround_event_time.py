@@ -325,6 +325,9 @@ def _contract_cases():
             capacity=16)),
         ("sessions", ops.SessionWindowOperator(
             num_keys=13, gap=300, out_of_orderness=100)),
+        ("incremental-join", ops.IncrementalJoinOperator(
+            num_keys=13, ttl=300, out_of_orderness=100, capacity=16,
+            bag_capacity=32)),
         ("map-rewrites-keys", ops.MapOperator(
             lambda k, v, t: (k + 1, v, t))),
     ]

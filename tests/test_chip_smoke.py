@@ -64,6 +64,12 @@ def test_part_s_tiny_sessions_in_wide_blocks_against_narrow_ones():
     assert chip_smoke.check_sessions_in_a_job(21, spe=128, epochs=5) > 200
 
 
+def test_part_i_tiny_incremental_join_in_wide_blocks_against_narrow_ones():
+    rows, flushed, stepped = chip_smoke.check_incremental_join_in_a_job(
+        21, spe=128, epochs=6)
+    assert rows > 1000 and flushed > 100 and stepped > 0
+
+
 def test_main_refuses_to_run_without_a_tpu(capsys):
     assert chip_smoke.main([]) != 0
     out = capsys.readouterr()

@@ -65,6 +65,12 @@ TOPOLOGIES = {
         "vertex/sessions" + part
         for part in ("", "/lookup", "/place", "/segsum", "/emit",
                      "/emit/hist")}),
+    "nexmark-local-items": ("tiny-nexmark-q3", RANKED | {
+        "vertex/host-source", "vertex/parse", "vertex/persons",
+        "vertex/auctions", "vertex/sink"} | {
+        "vertex/join" + part
+        for part in ("", "/compact", "/compact/hist", "/lookup", "/place",
+                     "/place/hist", "/emit", "/emit/hist")}),
 }
 
 
@@ -86,9 +92,10 @@ def lower_block(runner, cfg):
 
 def scopes_in(text):
     """The vocabulary's paths among the debug locations of a lowered
-    text, as the metric readers parse them."""
+    text or the ``op_name`` of a compiled one, as the metric readers
+    parse them."""
     found = {scope_times.scope_of(name)
-             for name in re.findall(r'loc\("([^"]+)"', text)}
+             for name in re.findall(r'(?:loc\(|op_name=)"([^"]+)"', text)}
     return {"/".join(s) for s in found if s}
 
 
@@ -132,8 +139,24 @@ def test_benchmark_reads_the_programs_vocabulary():
 
 
 def test_block_names_every_scope_its_topology_should_produce(lowered):
-    topology, _, _, text, _ = lowered
-    assert scopes_in(text) == COMMON | TOPOLOGIES[topology][1]
+    """By the compiled program's ``op_name``, which is what a trace's
+    device plane carries: a loop whose body is a function of its own (the
+    incremental join's chunks) starts its debug locations anew inside
+    the body, and only the compiled program joins them to the caller's
+    (``vertex/join/while/body/closed_call/lookup/...``)."""
+    topology, runner, cfg, text, _ = lowered
+    found = scopes_in(lower_block(runner, cfg).compile().as_text())
+    if topology == "nexmark-local-items":
+        # the chunk that runs step by step is a loop in a conditional in
+        # a loop, and XLA:CPU calls the inner body where the TPU's
+        # compiler inlines it: its kernels may show without (part of)
+        # their caller's path here, by whichever trace of the jitted
+        # histogram came first (tests/test_tpu_aot.py reads the TPU's
+        # program)
+        found -= {"hist", "vertex/join/hist"}
+    else:
+        assert scopes_in(text) == found
+    assert found == COMMON | TOPOLOGIES[topology][1]
 
 
 def test_program_enters_no_scope_outside_the_vocabulary(lowered):

@@ -320,3 +320,65 @@ def test_session_window_block_form_compiles_at_the_cells_widths(v5e):
     # the [K, P, B, 640] comparison never exists as an array
     assert exe.memory_analysis().temp_size_in_bytes < (
         K * P * cfg["edge_capacity"] * cfg["own_columns"]) // 4
+
+
+def test_incremental_join_block_form_compiles_at_the_cells_widths(v5e):
+    """``nexmark-q3``'s ``join`` vertex at its own widths — 131,072 ids in
+    8,448 own columns a subtask, 256 records a subtask a step from each
+    input, 4,608 waiting auctions, 256 rows — over a whole block of 1,024
+    steps of 16 subtasks, inside the job's block program: under
+    ``vertex/join`` no scatter, no gather and no sort; no loop over the
+    block's steps (the loops are the 32 chunks, and the 32 steps of a
+    chunk that is not quiet, under a conditional); the packing, the bag
+    and the rows take the Mosaic kernel; the comparison of a chunk's
+    packed records with every own column fuses without a ``[P, M, 8,448]``
+    array; and ``join -> sink`` is planned ``identity``."""
+    import json
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    for p in (bench, root):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    from benchlib import job
+    from benchlib.byname import module_at
+    from clonos_tpu.runtime.executor import CompiledJob
+    with open(os.path.join(bench, "configs", "nexmark-q3.json")) as f:
+        cfg = json.load(f)
+    compiled = CompiledJob(
+        module_at(job.topology_file(cfg, "job.py")).build(cfg),
+        log_capacity=8192, max_epochs=8, inflight_ring_steps=2048)
+    assert [p.route for _, p in sorted(compiled.edge_plans.items())] == [
+        "dynamic", "dynamic", "identity"]
+    K, P, E = cfg["block_steps"], cfg["parallelism"], cfg["edge_capacity"]
+    op = compiled.job.vertices[4].operator
+    S = op._chunk_of(K)
+    assert (S, K // S) == (32, 32)
+    mesh = Mesh(np.array(v5e[:1]), ("tasks",))
+    with histogram.kernel_mesh(mesh, "tasks"):
+        lowered, records = lower_block(compiled, K,
+                                       SingleDeviceSharding(v5e[0]))
+    forms = [r["args"] for r in records if r["name"] == "hist.kernel"]
+    assert {f["form"] for f in forms} == {"mxu"}
+    shapes = {(f["rows"], f["cols"], f["lanes"]) for f in forms}
+    chunks, bag, cap = K // S, cfg["bag_capacity"], cfg["join_capacity"]
+    assert {(chunks * P, S * E, 2 * E), (chunks * P, S * E, 4 * E),   # packed
+            (P, bag + 4 * E, bag),                                  # the bag
+            (chunks * P, S * cap, S * cap)} <= shapes               # the rows
+    exe = lowered.compile()
+    text = exe.as_text()
+    mine = [line for line in text.splitlines() if "vertex/join" in line]
+    assert len(mine) > 500
+    for op_name in ("scatter", "gather", "sort"):
+        assert not [line for line in mine
+                    if re.search(rf"= .* {op_name}\(", line)], op_name
+    # two loops, by the scope they were traced under: the chunks, and —
+    # in the conditional's branch — the steps of a chunk that is not quiet
+    loops = sorted(m.group(1) for line in mine if " while(" in line
+                   for m in [re.search(r'op_name="[^"]*?vertex/join/([^"]*)"',
+                                       line)] if m)
+    assert loops == ["while", "while/body/closed_call/cond/branch_1_fun/while"]
+    # the [P, M, 8,448] comparisons never exist as arrays
+    assert exe.memory_analysis().temp_size_in_bytes < (
+        P * 4 * E * cfg["own_columns"]) * 4
