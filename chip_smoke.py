@@ -27,11 +27,20 @@ it pass off the device:
      fence): kill one window subtask over two un-truncated epochs,
      recover. Pass = recovery's bit-identity verification and the audit
      validator; peak HBM is printed.
-  J  the window join alone at a deployment's lanes (4,096 keys x 2 open
-     windows a side): its block form — placements and compaction through
-     the histogram kernel — against its step form (scatter-adds, step by
-     step), state and rows bit for bit, over blocks that split windows
-     and a stretch with one input silent. Not in the default parts.
+  J  the window join, its tables on own columns. Alone, at a
+     deployment's ids (4,096 keys x 2 open windows a side, 640 own
+     columns a subtask of 8, bound as the planner binds them): its
+     block form — lookup, placements and compaction through the
+     histogram kernel — against its step form (scatter-adds, step by
+     step), state and rows bit for bit, over blocks that split windows,
+     a stretch with one input silent and records of keys the subtask
+     does not own. Then inside a job's block program (D17: a block
+     form right alone is not verified): the benchmark's
+     ``nexmark-window-join`` job at its tiny stand-in's sizes over
+     1,024 ids (384 own columns a subtask, derived by ``window_join``),
+     once in blocks of 1,024 steps and once in blocks of 16. Pass = both
+     committed streams equal the topology's NumPy reference, nothing
+     late, nothing dropped. Not in the default parts.
   S  session windows inside a job's block program: bids of a moving hot
      bidder and 1,010 cold ones cut into sessions by a gap of 1,000 ms
      (splits, two open sessions a bidder, bridges), once in blocks of
@@ -467,31 +476,47 @@ def check_kernels(seed: int) -> None:
 def check_window_join(seed: int, blocks: int = 3, K: int = 24, P: int = 8,
                       B: int = 96, num_keys: int = 4096) -> int:
     """The window join's block form against its step form on this
-    device; returns the rows compared."""
+    device, its tables on own columns bound as the planner binds them
+    (a subtask's ids under 128 key groups, ascending, then ``NO_KEY``);
+    returns the rows compared."""
     import jax
     import jax.numpy as jnp
     from clonos_tpu.api import operators as ops
     from clonos_tpu.api.records import RecordBatch, zero_invalid
+    from clonos_tpu.parallel import routing
 
     rng = np.random.RandomState(seed)
+    groups = 128
     op = ops.EventTimeWindowJoinOperator(
         num_keys=num_keys, window_size=10000, out_of_orderness=1280,
-        capacity=320)
+        capacity=320,
+        own_columns=routing.own_columns_width(num_keys, P, groups))
+    if op.own_columns is None:
+        raise AssertionError(f"window join: no own columns for {num_keys} "
+                             f"keys over {P} subtasks")
+    own = routing.own_slots(np.arange(num_keys), P, groups)     # [P, nk]
+    cols = np.sort(np.where(own, np.arange(num_keys), ops.NO_KEY),
+                   axis=1)[:, :op.own_columns].astype(np.int32)
 
     def draw(blk, silent):
         steps = blk * K + np.arange(K)
         ts = 1280 * steps[:, None, None] + rng.randint(0, 1280, (K, P, B))
+        keys = rng.randint(-2, num_keys // 8, (K, P, B))
+        # a keyed edge delivers a subtask its own keys; one record in 16
+        # of the others stays, and must be no record
+        here = own[np.arange(P)[None, :, None],
+                   np.clip(keys, 0, num_keys - 1)] & (keys >= 0)
+        keep = here | (rng.rand(K, P, B) < 1 / 16)
         return zero_invalid(RecordBatch(
-            jnp.asarray(rng.randint(-2, num_keys // 8, (K, P, B)),
-                        jnp.int32),
+            jnp.asarray(keys, jnp.int32),
             jnp.asarray(rng.randint(-2 ** 31, 2 ** 31 - 1, (K, P, B)),
                         jnp.int32),
             jnp.asarray(ts, jnp.int32),
-            jnp.asarray((rng.rand(K, P, B) < 0.7) & (not silent))))
+            jnp.asarray((rng.rand(K, P, B) < 0.7) & keep & (not silent))))
 
     step = jax.jit(op.process2)
     block = jax.jit(op.process_block)
-    by_block = by_step = op.init_state(P)
+    by_block = by_step = op.bind_own_columns(op.init_state(P), cols)
     rows = 0
     for blk in range(blocks):
         left, right = draw(blk, False), draw(blk, blk == 1)
@@ -513,11 +538,64 @@ def check_window_join(seed: int, blocks: int = 3, K: int = 24, P: int = 8,
                 raise AssertionError(
                     f"window join, block {blk}: {what} differs between "
                     f"the block form and the step form")
-        rows += int(out.count().sum())
+        m = np.asarray(out.valid)
+        at = np.broadcast_to(np.arange(P)[None, :, None], m.shape)[m]
+        if not own[at, np.asarray(out.keys)[m]].all():
+            raise AssertionError(f"window join, block {blk}: a row of a "
+                                 f"key its subtask does not own")
+        rows += int(m.sum())
     if rows == 0 or int(np.asarray(by_block["late"]).sum()) == 0:
         raise AssertionError(f"window join: {rows} rows, no record refused:"
                              f" the case exercises nothing")
     return rows
+
+
+def check_window_join_in_a_job(seed: int, spe: int = 2048, epochs: int = 3,
+                               num_keys: int = 1024) -> int:
+    """Part J, second half: the committed stream of the
+    ``nexmark-window-join`` job — its tiny stand-in with ``num_keys``
+    ids, so that ``window_join`` derives own columns — run in blocks of
+    1,024 steps against the same job run in blocks of 16 and against
+    the topology's plain reference; returns the rows compared."""
+    import json
+    bench = os.path.join(HERE, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from benchlib import job as bench_job
+    from benchlib.byname import module_at
+
+    with open(os.path.join(bench, "tests", "tiny", "bench", "configs",
+                           "tiny-nexmark-q8.json")) as f:
+        cfg = json.load(f)
+    cfg.update(steps_per_epoch=spe, num_keys=num_keys)
+    stream = bench_job.make_stream(cfg, {"table_epochs": 2}, seed)
+    ref = module_at(bench_job.topology_file(cfg, "reference.py"))
+    build = module_at(bench_job.topology_file(cfg, "job.py")).build
+
+    def committed(block_steps: int):
+        graph = build(cfg)
+        (join,) = (v for v in graph.vertices if v.name == "join")
+        if join.operator.own_columns is None:
+            raise AssertionError(f"window join in a job: no own columns "
+                                 f"for {num_keys} keys")
+        got, runner = committed_by_epoch(graph, stream, seed, spe, epochs,
+                                         block_steps, "window join")
+        state = runner.executor.vertex_state(join.vertex_id)
+        return got, {k: int(np.asarray(state[k]).sum())
+                     for k in ("late", "fired", "dropped")}
+
+    want = ref.expected(cfg, stream.keys, stream.vals, epochs)
+    for name, steps in (("1,024", 1024), ("16", 16)):
+        got, totals = committed(steps)
+        bad, failed, compared = ref.check(got, want, cfg, epochs)
+        if bad or totals != {"late": 0, "fired": want.fired, "dropped": 0}:
+            raise AssertionError(
+                f"window join, blocks of {name} steps: {bad} rows differ "
+                f"from the reference in epochs {failed}; {totals} against "
+                f"{want.fired} rows fired")
+    if compared < epochs * spe // 8:
+        raise AssertionError(f"window join in a job: only {compared} rows")
+    return compared
 
 
 def check_block_until_ready() -> None:
@@ -611,6 +689,32 @@ def check_sessions_in_a_job(seed: int, spe: int = 2048, epochs: int = 3
     return int(wide.shape[0])
 
 
+def committed_by_epoch(graph, stream, seed: int, spe: int, epochs: int,
+                       block_steps: int, what: str):
+    """``epochs`` epochs of a benchmark topology's job over ``stream`` in
+    blocks of ``block_steps`` steps, nothing lost on any edge; returns
+    (epoch -> committed row arrays, the runner)."""
+    from benchlib.stream import TableFeedReader
+    from clonos_tpu.runtime.cluster import ClusterRunner
+    runner = ClusterRunner(
+        graph, steps_per_epoch=spe, block_steps=block_steps,
+        log_capacity=1 << (spe * 8 - 1).bit_length(), max_epochs=16,
+        inflight_ring_steps=2 * spe, seed=seed, logical_time=True,
+        audit=False)
+    runner.executor.register_feed(0, TableFeedReader(stream))
+    (txn,) = runner.txn_logs.values()
+    got = {}
+    txn.committer = lambda e, rows: got.setdefault(e, []).append(
+        np.asarray(rows))
+    for _ in range(epochs):
+        runner.run_epoch(complete_checkpoint=True)
+    runner.drain_fence()
+    lost = runner.executor.check_overflow()
+    if lost:
+        raise AssertionError(f"{what}, blocks of {block_steps}: {lost}")
+    return got, runner
+
+
 def check_incremental_join_in_a_job(seed: int, spe: int = 2048,
                                     epochs: int = 3):
     """Part I: the committed stream of the ``nexmark-local-items`` job
@@ -623,8 +727,6 @@ def check_incremental_join_in_a_job(seed: int, spe: int = 2048,
         sys.path.insert(0, bench)
     from benchlib import job as bench_job
     from benchlib.byname import module_at
-    from benchlib.stream import TableFeedReader
-    from clonos_tpu.runtime.cluster import ClusterRunner
 
     with open(os.path.join(bench, "tests", "tiny", "bench", "configs",
                            "tiny-nexmark-q3.json")) as f:
@@ -635,23 +737,9 @@ def check_incremental_join_in_a_job(seed: int, spe: int = 2048,
     build = module_at(bench_job.topology_file(cfg, "job.py")).build
 
     def committed(block_steps: int):
-        runner = ClusterRunner(
-            build(cfg), steps_per_epoch=spe, block_steps=block_steps,
-            log_capacity=1 << (spe * 8 - 1).bit_length(), max_epochs=16,
-            inflight_ring_steps=2 * spe, seed=seed, logical_time=True,
-            audit=False)
-        runner.executor.register_feed(0, TableFeedReader(stream))
-        (txn,) = runner.txn_logs.values()
-        got = {}
-        txn.committer = lambda e, rows: got.setdefault(e, []).append(
-            np.asarray(rows))
-        for _ in range(epochs):
-            runner.run_epoch(complete_checkpoint=True)
-        runner.drain_fence()
-        lost = runner.executor.check_overflow()
-        if lost:
-            raise AssertionError(
-                f"incremental join, blocks of {block_steps}: {lost}")
+        got, runner = committed_by_epoch(build(cfg), stream, seed, spe,
+                                         epochs, block_steps,
+                                         "incremental join")
         state = runner.executor.vertex_state(4)
         return got, int(np.asarray(state["step_chunks"]).sum())
 
@@ -701,6 +789,10 @@ def print_routes(tracer, since: int, part: str) -> int:
         counts[line] = counts.get(line, 0) + 1
     for line, c in sorted(counts.items()):
         say(f"{part} exchange {line} (traced {c}x)")
+    for a in (r["args"] for r in recs[since:]
+              if r["name"] == "plan.own-columns"):
+        say(f"{part} plan {a['vertex']}: {a['columns']} own columns, "
+            f"{a['bound']} bound, of {a['num_keys']}")
     kernels = collections.Counter(
         f"[{a['rows']}, {a['cols']}] -> {a['lanes']} lanes, hi={a['hi']} "
         f"planes={a['planes']}: {a['form']}"
@@ -769,9 +861,14 @@ def main(argv=None) -> int:
     if "J" in parts:
         t0 = time.monotonic()
         rows = check_window_join(args.seed)
-        mark = print_routes(tracer, mark, "J")
-        say(f"J pass: window join, block form == step form over {rows} "
+        say(f"J window join alone, block form == step form over {rows} "
             f"rows ({time.monotonic() - t0:.1f}s)")
+        in_job = check_window_join_in_a_job(args.seed)
+        mark = print_routes(tracer, mark, "J")
+        say(f"J pass: window join on own columns, block form == step form "
+            f"over {rows} rows; in a job, blocks of 1,024 steps == blocks "
+            f"of 16 == the reference over {in_job} rows "
+            f"({time.monotonic() - t0:.1f}s)")
 
     if "S" in parts:
         t0 = time.monotonic()

@@ -211,14 +211,28 @@ class DataStream:
         ``other``'s values (operators.EventTimeWindowJoinOperator). Both
         inputs must be key_by()'d. ``capacity``: rows a subtask may emit
         a step; ``edge_capacity``: the receive window of BOTH input
-        edges (a two-input vertex takes one)."""
+        edges (a two-input vertex takes one).
+
+        Both inputs are keyed, so a subtask only ever receives the keys
+        it owns and the join's tables hold a column for those alone: the
+        width is read off the plan — the most of the ``num_keys`` ids
+        any of the vertex's subtasks owns under the environment's key
+        groups, up to the next 128 lanes
+        (``routing.own_columns_width``: 384 for 4,096 ids at parallelism
+        16 and 128 groups) — and the planner binds the columns; where
+        that is no narrower than ``num_keys`` the tables keep a column a
+        key."""
         from clonos_tpu.api.operators import EventTimeWindowJoinOperator
+        from clonos_tpu.parallel.routing import own_columns_width
         if not (self._keyed and other._keyed):
             raise ValueError("window_join requires key_by() on both inputs")
         op = EventTimeWindowJoinOperator(
             num_keys=num_keys, window_size=window_size,
             out_of_orderness=out_of_orderness,
-            capacity=capacity or self._env.default_edge_capacity)
+            capacity=capacity or self._env.default_edge_capacity,
+            own_columns=own_columns_width(
+                num_keys, parallelism or self._vertex.parallelism,
+                self._env.graph.num_key_groups))
         return self._attach2(other, name, op, parallelism, edge_capacity)
 
     def join_incremental(self, other: "DataStream", num_keys: int, ttl: int,

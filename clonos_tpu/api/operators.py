@@ -1192,8 +1192,69 @@ class IntervalJoinOperator(TwoInputOperator):
         return {"lv": lv, "lt": lt, "lm": lm, "cursor": cur}, out
 
 
+class _OwnColumns:
+    """A table with a column a key, on every subtask (dense) or, with
+    ``own_columns``, only for the keys a subtask owns: what every such
+    operator shares. ``state["cols"]`` (int32 ``[P, columns]``,
+    ascending, then :data:`NO_KEY`) says which key each column holds;
+    the planner binds it (``CompiledJob._bind_own_columns``), so the
+    binding is state — one operator object serves any number of plans,
+    a checkpoint carries it. A record finds its column by its key's
+    rank among the bound keys (:meth:`_column`)."""
+
+    num_keys: int
+    own_columns: Optional[int]
+
+    @property
+    def _columns(self) -> int:
+        return self.num_keys if self.own_columns is None else self.own_columns
+
+    def _init_cols(self, parallelism: int) -> jnp.ndarray:
+        """Dense: a column a key; own columns: none bound yet, every
+        record is refused until the planner binds them."""
+        c = self._columns
+        cols = (jnp.arange(c, dtype=jnp.int32) if self.own_columns is None
+                else jnp.full((c,), NO_KEY, jnp.int32))
+        return jnp.broadcast_to(cols, (parallelism, c))
+
+    def bind_own_columns(self, state, cols):
+        cols = jnp.asarray(cols, jnp.int32)
+        if cols.shape != state["cols"].shape:
+            raise ValueError(
+                f"own columns {cols.shape} for a table of "
+                f"{state['cols'].shape}")
+        return dict(state, cols=cols)
+
+    @scoped("lookup")
+    def _column(self, cols, keys):
+        """``(column, held)`` of each record's key on its subtask:
+        ``cols [P, C]`` against ``keys [..., P, B]``. The bound keys are
+        an ascending prefix, so a held key's column is the count of
+        bound keys below it."""
+        k, c = keys[..., None], cols[:, None, :]
+        return (jnp.sum((k > c).astype(jnp.int32), axis=-1),
+                jnp.any(k == c, axis=-1))
+
+    def _lane_words(self, cols, slots: int):
+        """``slot * num_keys + key`` of every lane of a subtask's
+        ``[slots, columns]`` table, ``[P, slots * C]``: what a compaction
+        carries so that a row names its slot and its key (a column bound
+        to no key reads key 0; no row comes from one)."""
+        return (jnp.arange(slots, dtype=jnp.int32)[None, :, None]
+                * self.num_keys
+                + jnp.where(cols != NO_KEY, cols, 0)[:, None, :]
+                ).reshape(-1, slots * self._columns)
+
+    def rescale_keyed_state(self, state, new_parallelism, num_key_groups):
+        raise NotImplementedError(
+            f"{type(self).__name__} does not support rescaling: its "
+            f"columns are bound to the keys each subtask owns when the job "
+            f"is planned, and a live rescale would have to bind them anew")
+
+
 @dataclasses.dataclass
-class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
+class EventTimeWindowJoinOperator(_OwnColumns, _EventTimeSlots,
+                                  TwoInputOperator):
     """Tumbling event-time window join of two keyed streams on equal key
     and equal window (the DataStream API's ``a.join(b).where(..)
     .equalTo(..).window(TumblingEventTimeWindows.of(size))``; NEXmark
@@ -1202,11 +1263,28 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
     in which BOTH inputs had a record: ``(key, sum of the right input's
     values in the window wrapped to int32, window end)``.
 
+    **Tables: own columns.** Three tables a subtask — the left input's
+    counts, the right input's counts, the right input's sums — each
+    ``[open_windows, columns]``, and ``state["cols"]`` (int32 ``[P,
+    columns]``, ascending, then :data:`NO_KEY`) says which key each
+    column holds (:class:`_OwnColumns`). Behind its two ``key_by()``
+    inputs a subtask only ever receives the keys it owns, so
+    ``DataStream.window_join`` sets ``own_columns`` to what the fullest
+    subtask owns, up to the next 128 lanes, and the planner binds each
+    subtask's columns to its own ids (``CompiledJob._bind_own_columns``):
+    4,096 ids at parallelism 16 are 384 columns a subtask, not 4,096.
+    Without ``own_columns`` (a direct construction) the same code runs
+    over a column for every key in ``[0, num_keys)``. A record's column
+    is its key's rank among the subtask's bound keys, by comparison with
+    all of them (no gather by a computed index).
+
     Semantics, per subtask (every edge one step deep, as everywhere; the
     watermark, slot and fire arithmetic is :class:`_EventTimeSlots`'s,
-    shared with :class:`EventTimeWindow`). A record whose key lies
-    outside ``[0, num_keys)`` is no record here (what the keyed
-    histogram does with one). ``max_ts_left`` and ``max_ts_right`` are
+    shared with :class:`EventTimeWindow`). A record whose key the
+    subtask holds no column for — any key outside ``[0, num_keys)``,
+    and with own columns a key another subtask owns, which no keyed
+    edge delivers — is no record here: no counter sees it, ``late``
+    included. ``max_ts_left`` and ``max_ts_right`` are
     the running maxima of each input's valid timestamps, this step's
     included. The watermark is the smaller of the two less the bound —
     Flink's rule for a two-input operator — and advances once a step,
@@ -1224,7 +1302,7 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
 
     Each step: windows whose end is at or behind ``wm`` fire FIRST — for
     every key whose left count and right count in that window are both
-    non-zero one row, compacted in (slot, key) order into ``capacity``
+    non-zero one row, compacted in (slot, column) order into ``capacity``
     rows a subtask a step (rows past it are dropped and counted in
     ``dropped``: size ``capacity`` for the keys a subtask owns) — then
     the fired slots clear; then the step's left and right records are
@@ -1237,9 +1315,9 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
     ``right_records`` the records each side accepted.
 
     The block form has no scan over the steps and no scatter: both
-    inputs' histograms over ``slot x key`` lanes, three running sums that
-    restart at a fire, and the compaction itself a keyed histogram over
-    the rows' ranks (a row's place is its rank: no gather either).
+    inputs' histograms over ``slot x column`` lanes, three running sums
+    that restart at a fire, and the compaction itself a keyed histogram
+    over the rows' ranks (a row's place is its rank: no gather either).
 
     Every row carries a key this subtask received, on both inputs
     (``emits_received_keys``): behind two ``key_by()`` inputs the out
@@ -1250,6 +1328,7 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
     window_size: int
     out_of_orderness: int = 0
     capacity: int = 256
+    own_columns: Optional[int] = None
     open_windows: int = 2
 
     emits_received_keys = True
@@ -1277,17 +1356,22 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
 
     def init_state(self, parallelism: int):
         p, w = parallelism, self.open_windows
-        state = {k: jnp.zeros((p, w, self.num_keys), jnp.int32)
+        state = {k: jnp.zeros((p, w, self._columns), jnp.int32)
                  for k in self._TABLES}       # counts, counts, right sums
         state.update({k: jnp.zeros((p,), jnp.int32)
                       for k, _ in self.fence_totals})
         state.update({k: jnp.full((p,), _NO_TS, jnp.int32)
                       for k in ("max_ts_left", "max_ts_right", "anchor")})
         state["win"] = jnp.full((p, w), _NO_WINDOW, jnp.int32)
+        state["cols"] = self._init_cols(p)
         return state
 
-    def _in_range(self, b: RecordBatch):
-        return b.valid & (b.keys >= 0) & (b.keys < self.num_keys)
+    def _placed(self, cols, b: RecordBatch):
+        """``(column, record)``: each record's column on its subtask,
+        and whether it is a record here — valid, its key one the subtask
+        holds a column for (a key of :data:`NO_KEY` names no column)."""
+        col, held = self._column(cols, b.keys)
+        return col, b.valid & held & (b.keys < self.num_keys)
 
     @staticmethod
     def _anchor_reading(max_l, max_r):
@@ -1306,32 +1390,33 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
                          _NO_TS), anchor
 
     @scoped("emit")
-    def _emit(self, match, sums, win_end):
-        """The rows of one or many steps: ``match [..., W * nk]`` lanes
-        compacted, in lane order, into ``[..., capacity]`` rows (key,
-        right sum, window end), and how many did not fit ``[...]``. A
+    def _emit(self, match, sums, win_end, cols):
+        """The rows of one or many steps: ``match [..., P, W * C]`` lanes
+        compacted, in lane order, into ``[..., P, capacity]`` rows (key,
+        right sum, window end), and how many did not fit ``[..., P]``. A
         row's place is its rank among the matches, so the compaction is
-        a keyed histogram over ranks: one call carries the sums, one the
-        lanes (a lane is its slot and its key)."""
+        a keyed histogram over ranks: one call carries the sums, one
+        ``slot * num_keys + key`` (a lane is its slot and its column, a
+        column its key)."""
         from clonos_tpu.ops.histogram import keyed_hist
         from clonos_tpu.ops.matops import running_count
         nk, w, cap = self.num_keys, self.open_windows, self.capacity
         rank = running_count(match) - 1
         total = rank[..., -1] + 1
-        lane = jnp.broadcast_to(jnp.arange(w * nk, dtype=jnp.int32),
-                                match.shape)
         values, _ = keyed_hist(rank, sums, match, cap, want_counts=False)
-        lanes, _ = keyed_hist(rank, lane, match, cap, want_counts=False)
+        words, _ = keyed_hist(
+            rank, jnp.broadcast_to(self._lane_words(cols, w), match.shape),
+            match, cap, want_counts=False)
         valid = jnp.arange(cap, dtype=jnp.int32) < total[..., None]
-        slot = lanes // nk
+        slot = words // nk
         ts = sum(jnp.where(slot == s, win_end[..., s:s + 1], 0)
                  for s in range(w))
-        return (zero_invalid(RecordBatch(lanes % nk, values, ts, valid)),
+        return (zero_invalid(RecordBatch(words % nk, values, ts, valid)),
                 jnp.maximum(total - cap, 0))
 
     def process2(self, state, left, right, ctx):
-        nk = self.num_keys
-        lv, rv = self._in_range(left), self._in_range(right)
+        lcol, lv = self._placed(state["cols"], left)
+        rcol, rv = self._placed(state["cols"], right)
 
         def fire_first(tables, win, max_l, max_r, anchor, l, lok, r, rok):
             max_l = jnp.maximum(max_l, jnp.max(
@@ -1347,20 +1432,20 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
             return (tables, win, max_l, max_r, anchor, wm, win_end, match,
                     sr.reshape(-1))
 
-        def assign(tables, win, wm, l, lok, r, rok):
+        def assign(tables, win, wm, l, lcol, lok, r, rcol, rok):
             cl, cr, sr = tables
             win, placed, l_any = self._place_step(
                 win, wm, lok, l.timestamps, bounded=True)
             for slot, ok in placed:
-                cl = cl.at[slot, l.keys].add(ok.astype(jnp.int32),
-                                             mode="drop")
+                cl = cl.at[slot, lcol].add(ok.astype(jnp.int32),
+                                           mode="drop")
             win, placed, r_any = self._place_step(
                 win, wm, rok, r.timestamps, bounded=True)
             for slot, ok in placed:
-                cr = cr.at[slot, r.keys].add(ok.astype(jnp.int32),
-                                             mode="drop")
-                sr = sr.at[slot, r.keys].add(jnp.where(ok, r.values, 0),
-                                             mode="drop")
+                cr = cr.at[slot, rcol].add(ok.astype(jnp.int32),
+                                           mode="drop")
+                sr = sr.at[slot, rcol].add(jnp.where(ok, r.values, 0),
+                                           mode="drop")
             n = lambda m: jnp.sum(m.astype(jnp.int32))
             return ((cl, cr, sr), win, n(lok & ~l_any) + n(rok & ~r_any),
                     n(l_any), n(r_any))
@@ -1370,10 +1455,10 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
          sums) = jax.vmap(fire_first)(
             tables, state["win"], state["max_ts_left"],
             state["max_ts_right"], state["anchor"], left, lv, right, rv)
-        out, dropped = self._emit(match, sums, win_end)
+        out, dropped = self._emit(match, sums, win_end, state["cols"])
         tables, win, late, n_left, n_right = jax.vmap(assign)(
-            tables, win, wm, left, lv, right, rv)
-        new = dict(zip(self._TABLES, tables))
+            tables, win, wm, left, lcol, lv, right, rcol, rv)
+        new = dict(state, **dict(zip(self._TABLES, tables)))
         new.update(
             win=win, max_ts_left=max_l, max_ts_right=max_r, anchor=anchor,
             late=state["late"] + late, dropped=state["dropped"] + dropped,
@@ -1385,8 +1470,9 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
     def process_block(self, state, batches, bctx):
         left, right = batches
         K, p, _ = left.keys.shape
-        nk, w = self.num_keys, self.open_windows
-        lv, rv = self._in_range(left), self._in_range(right)
+        w, c = self.open_windows, self._columns
+        lcol, lv = self._placed(state["cols"], left)
+        rcol, rv = self._placed(state["cols"], right)
         max_l = self._block_max_ts(state["max_ts_left"], lv,
                                    left.timestamps)               # [K, P]
         max_r = self._block_max_ts(state["max_ts_right"], rv,
@@ -1403,23 +1489,24 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
         wm, anchor = self._watermark(max_l, max_r, anchor)
 
         cl, _, taken_l, l_any = self._block_place(
-            lv, left.keys, jnp.ones_like(left.values), left.timestamps, wm,
-            bounded=True)
+            lv, lcol, jnp.ones_like(left.values), left.timestamps, wm,
+            bounded=True, nk=c)
         sr, cr, taken_r, r_any = self._block_place(
-            rv, right.keys, right.values, right.timestamps, wm,
-            want_counts=True, bounded=True)
+            rv, rcol, right.values, right.timestamps, wm,
+            want_counts=True, bounded=True, nk=c)
         held, win_end, fire = self._block_slots(
             state["win"], jnp.maximum(taken_l, taken_r), wm)
-        fire_l = self._block_lanes(fire)                       # [K,P,W*nk]
+        fire_l = self._block_lanes(fire, c)                    # [K,P,W*C]
         acc, emit = {}, {}
         for k, contrib in zip(self._TABLES, (cl, cr, sr)):
             acc[k], emit[k] = self._block_accumulate(
-                state[k].reshape(p, w * nk), contrib, fire_l)
+                state[k].reshape(p, w * c), contrib, fire_l)
         out, dropped = self._emit(
             fire_l & (emit["left"] > 0) & (emit["right"] > 0), emit["sum"],
-            win_end)
+            win_end, state["cols"])
         n = lambda m: jnp.sum(m.astype(jnp.int32), axis=(0, 2))
-        new = {k: acc[k][-1].reshape(p, w, nk) for k in self._TABLES}
+        new = dict(state, **{k: acc[k][-1].reshape(p, w, c)
+                             for k in self._TABLES})
         new.update(
             win=held[-1], max_ts_left=max_l[-1], max_ts_right=max_r[-1],
             anchor=anchor[-1],
@@ -1429,45 +1516,6 @@ class EventTimeWindowJoinOperator(_EventTimeSlots, TwoInputOperator):
             left_records=state["left_records"] + n(l_any),
             right_records=state["right_records"] + n(r_any))
         return new, out
-
-
-class _OwnColumns:
-    """A table with a column a key, on every subtask (dense) or, with
-    ``own_columns``, only for the keys a subtask owns: what every such
-    operator shares. ``state["cols"]`` (int32 ``[P, columns]``,
-    ascending, then :data:`NO_KEY`) says which key each column holds;
-    the planner binds it (``CompiledJob._bind_own_columns``), so the
-    binding is state — one operator object serves any number of plans,
-    a checkpoint carries it."""
-
-    num_keys: int
-    own_columns: Optional[int]
-
-    @property
-    def _columns(self) -> int:
-        return self.num_keys if self.own_columns is None else self.own_columns
-
-    def _init_cols(self, parallelism: int) -> jnp.ndarray:
-        """Dense: a column a key; own columns: none bound yet, every
-        record is refused until the planner binds them."""
-        c = self._columns
-        cols = (jnp.arange(c, dtype=jnp.int32) if self.own_columns is None
-                else jnp.full((c,), NO_KEY, jnp.int32))
-        return jnp.broadcast_to(cols, (parallelism, c))
-
-    def bind_own_columns(self, state, cols):
-        cols = jnp.asarray(cols, jnp.int32)
-        if cols.shape != state["cols"].shape:
-            raise ValueError(
-                f"own columns {cols.shape} for a table of "
-                f"{state['cols'].shape}")
-        return dict(state, cols=cols)
-
-    def rescale_keyed_state(self, state, new_parallelism, num_key_groups):
-        raise NotImplementedError(
-            f"{type(self).__name__} does not support rescaling: its "
-            f"columns are bound to the keys each subtask owns when the job "
-            f"is planned, and a live rescale would have to bind them anew")
 
 
 @dataclasses.dataclass
@@ -1553,16 +1601,6 @@ class EventTimeWindowTopOperator(_OwnColumns, _EventTimeSlots, Operator):
             cols=self._init_cols(p))
         return state
 
-    @scoped("lookup")
-    def _column(self, cols, keys):
-        """``(column, held)`` of each record's key on its subtask:
-        ``cols [P, C]`` against ``keys [..., P, B]``. The bound keys are
-        an ascending prefix, so a held key's column is the count of
-        bound keys below it."""
-        k, c = keys[..., None], cols[:, None, :]
-        return (jnp.sum((k > c).astype(jnp.int32), axis=-1),
-                jnp.any(k == c, axis=-1))
-
     @scoped("emit")
     def _emit(self, acc, fire, win_end, cols):
         """The rows of one or many steps: of the accumulators ``acc
@@ -1584,9 +1622,7 @@ class EventTimeWindowTopOperator(_OwnColumns, _EventTimeSlots, Operator):
                       axis=-1)                                # [..., P, W]
         match = ((fire & (top != none))[..., None]
                  & (by_slot == top[..., None])).reshape(acc.shape)
-        word = (jnp.arange(w, dtype=jnp.int32)[None, :, None] * nk
-                + jnp.where(cols != NO_KEY, cols, 0)[:, None, :]
-                ).reshape(-1, w * c)                          # [P, W * C]
+        word = self._lane_words(cols, w)                      # [P, W * C]
         rank = running_count(match) - 1
         total = rank[..., -1] + 1
         words, _ = keyed_hist(rank, jnp.broadcast_to(word, match.shape),

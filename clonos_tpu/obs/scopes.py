@@ -17,10 +17,10 @@ same names where they run the same code. A path reads
 ``vertex/<name>``   an operator's ``process_block*`` (``run_block``,
                     ``_replay_block``); beneath it, where the operator
                     has such a part:
-``.../lookup``      a record's own column (``EventTimeWindowTopOperator
-                    ._column``); with it, a step's sum, earliest and
-                    latest per own column (``SessionWindowOperator
-                    ._arrivals``)
+``.../lookup``      a record's own column (``_OwnColumns._column``: the
+                    windowed top and the window join); with it, a
+                    step's sum, earliest and latest per own column
+                    (``SessionWindowOperator._arrivals``)
 ``.../place``       records into ``slot x key`` lanes (``_EventTimeSlots
                     ._block_place``); a step's arrivals into sessions: the
                     running latest, where a session starts, where two

@@ -484,6 +484,29 @@ def own_slots(slot_keys: np.ndarray, parallelism: int,
     return own | np.isin(slot_keys, np.asarray(clamp_keys, np.int64))[None, :]
 
 
+def own_columns_width(num_keys: int, parallelism: int,
+                      num_key_groups: int) -> Optional[int]:
+    """The columns a subtask needs to hold a table over ``[0,
+    num_keys)`` for its own keys alone (``Operator.own_columns``): the
+    most ids any subtask owns (:func:`own_slots`), up to the next tile
+    of 128 lanes — or None where that is no narrower than a column a
+    key."""
+    most = int(own_slots(np.arange(num_keys), parallelism,
+                         num_key_groups).sum(axis=1).max())
+    width = -(-most // 128) * 128
+    return width if width < num_keys else None
+
+
+def note_own_columns(vertex: str, columns: int, bound: int,
+                     num_keys: int) -> None:
+    """Record of a vertex whose tables hold own columns (``plan.
+    own-columns`` instant, once per such vertex and job planned, beside
+    :func:`note_route`'s; chip_smoke.py prints them): the ``columns`` a
+    subtask has, the most ids ``bound`` to one, of ``num_keys``."""
+    get_tracer().event("plan.own-columns", vertex=vertex, columns=columns,
+                       bound=bound, num_keys=num_keys)
+
+
 def static_hash_capacity(slot_keys: np.ndarray, src_parallelism: int,
                          parallelism: int, num_key_groups: int,
                          live: Optional[np.ndarray] = None) -> int:

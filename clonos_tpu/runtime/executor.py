@@ -337,6 +337,7 @@ class CompiledJob:
         for q in range(p):
             keys = np.nonzero(own[q])[0]
             cols[q, :len(keys)] = keys
+        routing.note_own_columns(v.name, op.own_columns, most, op.num_keys)
         return cols
 
     def edge_name(self, eidx: int) -> str:

@@ -56,8 +56,14 @@ def test_part_b_tiny_kill_recover_audited():
 
 
 def test_part_j_tiny_window_join_block_form_against_step_form():
+    # 600 ids over 2 subtasks: 384 own columns each, 294 and 306 bound
     assert chip_smoke.check_window_join(21, K=20, P=2, B=32,
-                                        num_keys=256) > 100
+                                        num_keys=600) > 100
+
+
+def test_part_j_tiny_window_join_in_wide_blocks_against_narrow_ones():
+    assert chip_smoke.check_window_join_in_a_job(21, spe=128,
+                                                 epochs=6) > 200
 
 
 def test_part_s_tiny_sessions_in_wide_blocks_against_narrow_ones():

@@ -387,13 +387,14 @@ def test_what_the_operator_and_the_api_refuse():
 
 
 def test_own_columns_are_written_once():
-    """ROADMAP D18: the three operators that hold own columns share one
-    initialiser, one binding and one refusal."""
+    """ROADMAP D18: the four operators that hold own columns share one
+    initialiser, one binding, one lookup by rank and one refusal."""
     from clonos_tpu.api import operators as ops
     for cls in (ops.EventTimeWindowTopOperator, ops.SessionWindowOperator,
-                ops.IncrementalJoinOperator):
+                ops.IncrementalJoinOperator,
+                ops.EventTimeWindowJoinOperator):
         for name in ("_columns", "_init_cols", "bind_own_columns",
-                     "rescale_keyed_state"):
+                     "_column", "rescale_keyed_state"):
             assert name not in vars(cls), (cls.__name__, name)
             assert getattr(cls, name) is getattr(ops._OwnColumns, name)
 

@@ -52,9 +52,10 @@ TOPOLOGIES = {
         "vertex/union", "vertex/union/compact", "vertex/sink"}),
     "nexmark-window-join": ("tiny-nexmark-q8", RANKED | {
         "vertex/host-source", "vertex/parse", "vertex/persons",
-        "vertex/auctions", "vertex/join", "vertex/join/place",
-        "vertex/join/place/hist", "vertex/join/segsum", "vertex/join/emit",
-        "vertex/join/emit/hist", "vertex/sink"}),
+        "vertex/auctions", "vertex/join", "vertex/join/lookup",
+        "vertex/join/place", "vertex/join/place/hist",
+        "vertex/join/segsum", "vertex/join/emit", "vertex/join/emit/hist",
+        "vertex/sink"}),
     "nexmark-hot-items": ("tiny-nexmark-q5", RANKED | {
         "vertex/host-source", "vertex/parse", "vertex/sink"} | {
         f"vertex/{v}{part}" for v in ("count", "max")

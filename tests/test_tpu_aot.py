@@ -382,3 +382,59 @@ def test_incremental_join_block_form_compiles_at_the_cells_widths(v5e):
     # the [P, M, 8,448] comparisons never exist as arrays
     assert exe.memory_analysis().temp_size_in_bytes < (
         P * 4 * E * cfg["own_columns"]) * 4
+
+
+def test_window_join_block_form_compiles_at_the_cells_widths(v5e):
+    """``nexmark-q8``'s ``join`` vertex at its own widths — 4,096 ids in
+    384 own columns a subtask (``window_join`` derived them: 280 ids at
+    most), 2 open windows, 192 records a subtask a step from each input,
+    320 rows — over a whole block of 1,024 steps of 16 subtasks, inside
+    the job's block program: under ``vertex/join`` no loop, no scatter
+    and no sort; the placements take the Mosaic kernel over ``2 x 384``
+    lanes and the emission over the same 768, nothing is 8,192 lanes
+    wide; the comparison of every record with every own column fuses
+    without a ``[K, P, B, 384]`` array; and ``join -> sink`` is planned
+    ``identity``."""
+    import json
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    for p in (bench, root):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    from benchlib import job
+    from benchlib.byname import module_at
+    from clonos_tpu.runtime.executor import CompiledJob
+    with open(os.path.join(bench, "configs", "nexmark-q8.json")) as f:
+        cfg = json.load(f)
+    assert "own_columns" not in cfg         # derived, not configured
+    compiled = CompiledJob(
+        module_at(job.topology_file(cfg, "job.py")).build(cfg),
+        log_capacity=8192, max_epochs=8, inflight_ring_steps=2048)
+    assert [p.route for _, p in sorted(compiled.edge_plans.items())] == [
+        "dynamic", "dynamic", "identity"]
+    (vid,) = compiled.own_columns
+    op = compiled.job.vertices[vid].operator
+    W, C = op.open_windows, op.own_columns
+    assert (compiled.job.vertices[vid].name, W, C) == ("join", 2, 384)
+    assert compiled.own_columns[vid].shape == (cfg["parallelism"], C)
+    K, P, E = cfg["block_steps"], cfg["parallelism"], cfg["edge_capacity"]
+    mesh = Mesh(np.array(v5e[:1]), ("tasks",))
+    with histogram.kernel_mesh(mesh, "tasks"):
+        lowered, records = lower_block(compiled, K,
+                                       SingleDeviceSharding(v5e[0]))
+    forms = [r["args"] for r in records if r["name"] == "hist.kernel"]
+    assert {f["form"] for f in forms} == {"mxu"}
+    assert {(K * P, E, W * C), (K * P, W * C, cfg["join_capacity"])} <= {
+        (f["rows"], f["cols"], f["lanes"]) for f in forms}
+    exe = lowered.compile()
+    mine = [line for line in exe.as_text().splitlines()
+            if "vertex/join" in line]
+    assert len(mine) > 500
+    for op_name in ("while", "scatter", "sort"):
+        assert not [line for line in mine
+                    if re.search(rf"= \S+ {op_name}\(", line)], op_name
+    assert not [line for line in mine if f",{W * cfg['num_keys']}]" in line]
+    # the [K, P, B, 384] comparisons never exist as arrays
+    assert exe.memory_analysis().temp_size_in_bytes < K * P * E * C

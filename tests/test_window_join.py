@@ -71,14 +71,39 @@ def _blocks(seed, n_blocks, K, P, B, silent_right=(), spread=100,
             for b in range(n_blocks)]
 
 
-def _step_and_block(op, blocks, K, P):
+def _own(num_keys, P, columns, unbound=()):
+    """A binding as the planner makes one: key ``k`` on subtask ``k % P``,
+    ascending, then ``NO_KEY``; the keys in ``unbound`` on no subtask."""
+    from clonos_tpu.api.operators import NO_KEY
+    cols = np.full((P, columns), NO_KEY, np.int32)
+    for q in range(P):
+        keys = [k for k in range(q, num_keys, P) if k not in unbound]
+        cols[q, :len(keys)] = keys
+    return cols
+
+
+def _to_owners(blocks, P):
+    """``blocks`` with every record on the subtask that owns its key
+    under :func:`_own` (what two ``key_by()`` inputs deliver): a record
+    elsewhere is cleared."""
+    import jax.numpy as jnp
+    from clonos_tpu.api.records import zero_invalid
+    at = jnp.arange(P, dtype=jnp.int32)[None, :, None]
+    mine = lambda b: zero_invalid(b._replace(
+        valid=b.valid & (b.keys % P == at)))
+    return [(mine(l), mine(r)) for l, r in blocks]
+
+
+def _step_and_block(op, blocks, K, P, cols=None):
     """Run ``blocks`` through ``process_block`` and, step by step, through
     ``process2``; assert both agree after every block and return the
-    final state and all rows."""
+    final state and all rows. ``cols``: the own columns to bind first."""
     import jax
     import jax.numpy as jnp
     from clonos_tpu.api import operators as ops
     by_block = by_step = op.init_state(P)
+    if cols is not None:
+        by_block = by_step = op.bind_own_columns(by_block, cols)
     rows = []
     for blk, (left, right) in enumerate(blocks):
         bctx = ops.BlockContext(
@@ -107,7 +132,7 @@ def _step_and_block(op, blocks, K, P):
     return by_block, np.concatenate(rows)
 
 
-@pytest.mark.parametrize("case,silent,spread,oo", [
+_CASES = [
     ("in-order", (), 100, 100),
     # the right input says nothing for three blocks: the watermark stands,
     # the left input runs ahead of the slots and is refused
@@ -115,23 +140,94 @@ def _step_and_block(op, blocks, K, P):
     # nothing on the right from the start: placed from the anchor
     ("one-input-silent-at-the-start", (0, 1), 100, 100),
     ("spread-past-the-bound", (), 350, 100),
-    ("three-open-windows", (2,), 300, 450)])
-def test_step_form_equals_block_form_bit_for_bit(case, silent, spread, oo):
+    ("three-open-windows", (2,), 300, 450)]
+
+
+@pytest.mark.parametrize("columns", [None, 5, 8], ids=[
+    # a column a key on every subtask (a direct construction)
+    "a-column-a-key",
+    # own columns, every one bound on the fullest subtask: 13 keys over
+    # 3 subtasks are 5, 4 and 4
+    "own-columns-full",
+    # own columns with a tail of NO_KEY on every subtask
+    "own-columns-unbound-tail"])
+@pytest.mark.parametrize("case,silent,spread,oo", _CASES,
+                         ids=[c[0] for c in _CASES])
+def test_step_form_equals_block_form_bit_for_bit(case, silent, spread, oo,
+                                                 columns):
     """State and output, over blocks of 6 steps against windows of 4
-    (every window is split by a block boundary sooner or later)."""
+    (every window is split by a block boundary sooner or later). With own
+    columns every record sits on the subtask that owns its key, and the
+    rows, the totals and the tables' bound columns are those of the
+    binding with a column a key, bit for bit."""
     from clonos_tpu.api import operators as ops
-    op = ops.EventTimeWindowJoinOperator(
-        num_keys=13, window_size=400, out_of_orderness=oo, capacity=16)
-    assert op.open_windows == (3 if oo == 450 else 2)
-    K, P = 6, 3
-    state, rows = _step_and_block(
-        op, _blocks(7, 9, K, P, 10, silent_right=silent, spread=spread),
-        K, P)
+    K, P, nk = 6, 3, 13
+    blocks = _blocks(7, 9, K, P, 10 if columns is None else 30,
+                     silent_right=silent, spread=spread)
+    if columns is not None:
+        blocks = _to_owners(blocks, P)
+    dense = ops.EventTimeWindowJoinOperator(
+        num_keys=nk, window_size=400, out_of_orderness=oo, capacity=16)
+    assert dense.open_windows == (3 if oo == 450 else 2)
+    state, rows = _step_and_block(dense, blocks, K, P)
     total = lambda k: int(np.asarray(state[k]).sum())
     assert len(rows) == total("fired") > 40
     assert total("dropped") == 0
-    assert (rows[:, 2] % 400 == 0).all() and (rows[:, 0] < 13).all()
+    assert (rows[:, 2] % 400 == 0).all() and (rows[:, 0] < nk).all()
     assert (total("late") > 0) == (case != "in-order")
+    if columns is None:
+        assert state["left"].shape == (P, dense.open_windows, nk)
+        return
+    op = ops.EventTimeWindowJoinOperator(
+        num_keys=nk, window_size=400, out_of_orderness=oo, capacity=16,
+        own_columns=columns)
+    cols = _own(nk, P, columns)
+    own, own_rows = _step_and_block(op, blocks, K, P, cols)
+    assert own["left"].shape == (P, op.open_windows, columns)
+    assert np.array_equal(own_rows, rows)
+    for name in state:
+        a, b = np.asarray(state[name]), np.asarray(own[name])
+        if name in op._TABLES:      # column c of q holds key cols[q, c]
+            for q in range(P):
+                n = int((cols[q] < nk).sum())
+                assert np.array_equal(a[q][:, cols[q, :n]], b[q][:, :n])
+                assert not b[q][:, n:].any()
+        elif name != "cols":
+            assert np.array_equal(a, b), name
+
+
+@pytest.mark.parametrize("sent", ["unbound-keys", "keys-of-NO_KEY"])
+def test_a_key_no_column_holds_is_no_record(sent):
+    """Keys 4 and 9 are in range and bound on no subtask: their records
+    are no records — not late, not counted, no row — and the rest of the
+    stream joins as if they had not been sent. So is a key of NO_KEY,
+    which names no column, bound or not. Both forms (``_step_and_block``
+    holds one to the other)."""
+    import jax.numpy as jnp
+    from clonos_tpu.api import operators as ops
+    from clonos_tpu.api.records import zero_invalid
+    K, P, nk, unbound = 6, 3, 13, (4, 9)
+    blocks = _to_owners(_blocks(7, 6, K, P, 30), P)
+    without = [tuple(zero_invalid(b._replace(
+        valid=b.valid & ~jnp.isin(b.keys, jnp.asarray(unbound))))
+        for b in pair) for pair in blocks]
+    if sent == "keys-of-NO_KEY":    # every empty slot 0 holds one, valid
+        blocks = [tuple(b._replace(
+            keys=jnp.where(b.valid, b.keys, ops.NO_KEY),
+            valid=b.valid.at[:, :, 0].set(True)) for b in pair)
+            for pair in blocks]
+    op = ops.EventTimeWindowJoinOperator(
+        num_keys=nk, window_size=400, out_of_orderness=100, capacity=16,
+        own_columns=8)
+    cols = _own(nk, P, 8, unbound)
+    want, want_rows = _step_and_block(op, without, K, P, cols)
+    got, got_rows = _step_and_block(op, blocks, K, P, cols)
+    assert np.array_equal(got_rows, want_rows) and len(want_rows) > 20
+    assert not np.isin(got_rows[:, 0], unbound).any()
+    for name in want:
+        assert np.array_equal(np.asarray(got[name]),
+                              np.asarray(want[name])), name
+    assert int(np.asarray(got["late"]).sum()) == 0
 
 
 def test_rows_past_the_capacity_are_dropped_and_counted():
@@ -209,16 +305,24 @@ def totals_of(runner):
                       "right_records")}
 
 
-@pytest.mark.parametrize("victim", [(JOIN, 1), (AUCTIONS, 2)],
-                         ids=["join", "auctions"])
+@pytest.mark.parametrize("victim,num_keys,columns", [
+    ((JOIN, 1), 24, None), ((AUCTIONS, 2), 24, None),
+    # 1,024 ids over 4 subtasks, 276 at most: 384 own columns
+    ((JOIN, 1), 1024, 384)],
+    ids=["join", "auctions", "join-on-own-columns"])
 def test_committed_stream_equals_the_reference_through_a_kill(
-        ref, tmp_path, victim):
+        ref, tmp_path, victim, num_keys, columns):
     """The victim is rebuilt by the two-input replay over a two-epoch gap
-    (``join``), or feeds it from a rebuilt ring (``auctions``)."""
-    cfg = config()
+    (``join``), or feeds it from a rebuilt ring (``auctions``). The
+    tables hold a column a key where the tile of 128 covers every id,
+    own columns where it does not: what ``window_join`` derived."""
+    cfg = config(num_keys=num_keys)
     tracer = obs.get_tracer()
     before = tracer.counters()
     runner, stream, got = run_job(cfg, 11, 8, tmp_path, kill=victim)
+    state = runner.executor.vertex_state(JOIN)
+    assert state["left"].shape == (4, 2, columns or num_keys)
+    assert runner.job.vertices[JOIN].operator.own_columns == columns
     epochs = runner.executor.epoch_id
     assert epochs == 10
     want = ref.expected(cfg, stream.keys, stream.vals, epochs)
@@ -292,6 +396,85 @@ def test_reference_reads_windows_off_as_they_close(ref):
 
 
 # --- the planner -------------------------------------------------------------
+
+
+def _joined(num_keys, p, groups):
+    """A job of two keyed host sources into ``window_join``, and the
+    join's operator."""
+    from clonos_tpu.api.environment import StreamEnvironment
+    env = StreamEnvironment(name="own", num_key_groups=groups,
+                            default_edge_capacity=16)
+    left = env.host_source(batch_size=8, parallelism=p, name="left")
+    right = env.host_source(batch_size=8, parallelism=p, name="right")
+    joined = left.key_by().window_join(right.key_by(), num_keys=num_keys,
+                                       window_size=400, name="join")
+    joined.key_by().sink()
+    return env.build(), joined.vertex.operator
+
+
+@pytest.mark.parametrize("num_keys,p,groups,most,columns", [
+    # NEXmark Q8's cell: 229-280 ids a subtask, up to the next 128 lanes
+    (4096, 16, 128, 280, 384),
+    (1024, 4, 64, 276, 384),
+    # the tile reaches the table: a column a key
+    (24, 4, 64, 7, None), (256, 2, 64, 136, None), (256, 1, 64, 256, None)])
+def test_window_join_derives_its_own_columns_from_the_plan(
+        num_keys, p, groups, most, columns):
+    """``window_join`` takes no width: the most ids any subtask owns
+    under the planner's own hash, up to the next 128 lanes, or a column
+    a key where that is no narrower."""
+    from clonos_tpu.parallel import routing
+    owned = routing.own_slots(np.arange(num_keys), p, groups).sum(axis=1)
+    assert owned.sum() == num_keys and owned.max() == most
+    assert routing.own_columns_width(num_keys, p, groups) == columns
+    _, op = _joined(num_keys, p, groups)
+    assert op.own_columns == columns
+    assert op.init_state(p)["sum"].shape == (p, 2, columns or num_keys)
+
+
+def test_planner_binds_the_joins_columns_ascending_and_says_so():
+    """Every id on exactly one subtask, the one ``routing`` sends it to,
+    ascending, then ``NO_KEY``; the plan's note reads ``384 columns, 276
+    bound, of 1,024``."""
+    from clonos_tpu.api.operators import NO_KEY
+    from clonos_tpu.parallel import routing
+    from clonos_tpu.runtime.executor import CompiledJob
+    tracer = obs.get_tracer()
+    seen = len(tracer.records())
+    graph, op = _joined(1024, 4, 64)
+    compiled = CompiledJob(graph, log_capacity=256, max_epochs=8,
+                           inflight_ring_steps=8)
+    vid = [v.name for v in graph.vertices].index("join")
+    cols = np.asarray(compiled.init_carry().op_states[vid]["cols"])
+    assert cols.shape == (4, 384)
+    owner = np.asarray(routing.subtask_for_key_group(
+        routing.key_group(np.arange(1024, dtype=np.int32), 64), 4, 64))
+    for q in range(4):
+        mine = cols[q][cols[q] != NO_KEY]
+        assert mine.tolist() == np.nonzero(owner == q)[0].tolist()
+        assert (cols[q][len(mine):] == NO_KEY).all()
+    assert [r["args"] for r in tracer.records()[seen:]
+            if r["name"] == "plan.own-columns"] == [
+        {"vertex": "join", "columns": 384, "bound": 276, "num_keys": 1024}]
+
+
+def test_planner_refuses_a_join_whose_subtask_owns_more_than_its_columns():
+    """The width is the plan's: under another plan — fewer subtasks, so
+    more ids each — the same operator is refused, not run short."""
+    from clonos_tpu.runtime.executor import CompiledJob
+    kw = dict(log_capacity=256, max_epochs=8, inflight_ring_steps=8)
+    graph, op = _joined(1024, 4, 64)
+    op.own_columns = 256
+    with pytest.raises(ValueError, match="more than the 256 own columns"):
+        CompiledJob(graph, **kw)
+    narrow, _ = _joined(1024, 4, 64)
+    wide, _ = _joined(1024, 2, 64)
+    vid = [v.name for v in wide.vertices].index("join")
+    wide.vertices[vid].operator = narrow.vertices[vid].operator
+    with pytest.raises(ValueError, match="owns 5.. of the 1024 keys"):
+        CompiledJob(wide, **kw)
+    with pytest.raises(NotImplementedError, match="bound to the keys"):
+        op.rescale_keyed_state(op.init_state(4), 2, 64)
 
 
 def test_planner_routes_the_joins_edges():
