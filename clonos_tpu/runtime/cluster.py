@@ -622,11 +622,18 @@ class ClusterRunner:
         misses = sum(pk.missed for pk in packed.values())
         tr.count("sink.d2h_bytes", nbytes)
         tr.count("sink.rung_reads", len(packed))
+        tr.count("sink.pack_slots", sum(pk.slots for pk in packed.values()))
         if trailing:
             tr.count("sink.taps_trailing", len(packed))
         if misses:
             tr.count("sink.rung_misses", misses)
-        tr.count("block.dispatches.sink_pack", len(packed) + misses)
+        launches = len(packed) + misses
+        tr.count("block.dispatches.sink_pack", launches)
+        shifted = sum(pk.shifted for pk in packed.values())
+        if shifted:
+            tr.count("sink.packs_by_shifts", shifted)
+        if launches > shifted:
+            tr.count("sink.packs_by_rank", launches - shifted)
         for vid, (counts, rows) in host.items():
             self.txn_logs[vid].absorb(epoch, counts, rows)
 

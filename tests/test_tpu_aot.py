@@ -438,3 +438,32 @@ def test_window_join_block_form_compiles_at_the_cells_widths(v5e):
     assert not [line for line in mine if f",{W * cfg['num_keys']}]" in line]
     # the [K, P, B, 384] comparisons never exist as arrays
     assert exe.memory_analysis().temp_size_in_bytes < K * P * E * C
+
+
+@pytest.mark.parametrize("shape,rung,gathers", [
+    ((1024, 16, 320), 20480, 0), ((1024, 16, 320), 5120, 4),
+    ((1024, 16, 256), 8192, 0), ((512, 16, 640), 320, 4)],
+    ids=["nexmark-q8", "nexmark-q8-sparse", "nexmark-q3", "kafka-window-64"])
+def test_sink_tap_compiles_without_sort_or_scatter_at_the_cells_shapes(
+        v5e, shape, rung, gathers):
+    """The sink tap's compaction of a cell's sink block (``[K, P,
+    capacity]``) as the chip's compiler leaves it: the two densest cells
+    at the rung their ~9,970 and ~3,900 rows a subtask take (packed by
+    shifts: no gather), a sparser block of the first and the sparsest
+    cell's (packed by rank: four gathers a slot); never a scatter or a
+    sort, and scratch a small share of what the carry leaves free."""
+    from clonos_tpu.api.records import RecordBatch
+    from clonos_tpu.runtime import sinktap
+    k, _, cap = shape
+    assert rung in sinktap.ladder(k * cap)
+    assert sinktap.packs_by_shifts(rung, k * cap) is (gathers == 0)
+    sh = SingleDeviceSharding(v5e[0])
+    batch = RecordBatch(*[jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+                          for dt in (jnp.int32,) * 3 + (jnp.bool_,)])
+    exe = jax.jit(lambda b: sinktap.pack_lanes(b, rung)).lower(
+        batch).compile()
+    text = exe.as_text()
+    count = lambda op: len(re.findall(rf"= \S+ {op}\(", text))
+    assert (count("gather"), count("scatter"), count("sort")) == (
+        gathers, 0, 0)
+    assert exe.memory_analysis().temp_size_in_bytes < 512 << 20

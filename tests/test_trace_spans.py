@@ -402,6 +402,10 @@ def test_sink_counters_are_the_bytes_read_and_the_rows_committed(tmp_path):
     # every block read through a rung, none read again
     assert c["sink.rung_reads"] == c["block.dispatches.sink_pack"] == blocks
     assert "sink.rung_misses" not in c
+    # the budget slots compacted for them: rows / slots is the tap's fill
+    assert c["sink.pack_slots"] == blocks * p * rung
+    # a lane's one rung is the whole lane: packed by shifts, none by rank
+    assert c["sink.packs_by_shifts"] == blocks and "sink.packs_by_rank" not in c
     committed = txn.committed_stream().shape[0]
     assert committed > 0
     assert c["sink.rows"] == c["txn.rows_committed"] == committed
