@@ -160,11 +160,11 @@ class ReplayPlan:
     from_epoch: int                 # first lost epoch (checkpoint + 1 ...)
     #: the lost input batches: a LIST of block_steps-sized chunks (each a
     #: RecordBatch [CH, cap] for single-input vertices, a (left, right)
-    #: pair for TwoInputOperator vertices), or a legacy stacked [n, cap]
-    #: batch, or None for self-generating sources. Chunked form keeps every
-    #: device program shape-static so the whole replay runs on programs
-    #: compiled at job start (warm standby — no XLA in the failure path).
-    input_steps: Optional[Any]
+    #: pair for TwoInputOperator vertices), or None for self-generating
+    #: sources. Chunks keep every device program shape-static so the
+    #: whole replay runs on programs compiled at job start (warm standby
+    #: — no XLA in the failure path).
+    input_steps: Optional[List[Any]]
     det_rows: np.ndarray            # int32[m, lanes] merged determinant rows
     det_start: int                  # absolute offset of det_rows[0]
     checkpoint_op_state: Any        # failed vertex's op state [P, ...] slice
@@ -178,8 +178,8 @@ class ReplayPlan:
     #: (consistent replica, pure sync rows): (times, rngs, expected)
     #: int32 device arrays padded to the replayer's ``pad_steps``. When
     #: set, ``det_rows`` stays empty — the multi-MB log body never
-    #: crosses the host link (it was parsed ON DEVICE; cluster
-    #: _device_parse_fn).
+    #: crosses the host link (it was parsed ON DEVICE;
+    #: runtime/recovery_programs.py ``device_parse``).
     det_device: Optional[Any] = None
 
 
@@ -205,8 +205,6 @@ class ReplayResult:
     #: their effects; services replay their values).
     async_events: List[Tuple[int, det.Determinant]] = dataclasses.field(
         default_factory=list)
-    #: wall-clock breakdown of the replay call (parse / device / rebuild).
-    phase_ms: Dict[str, float] = dataclasses.field(default_factory=dict)
     #: True when rebuilt_log_rows is a view of the recovered rows (the
     #: clean fast path, where verify() already establishes equality) —
     #: callers must not "re-verify" it against the same buffer.
@@ -391,16 +389,6 @@ class LogReplayer:
         flag and the consumed total stays a device scalar, both folded
         into the cluster's single end-of-recovery read (ReplayResult
         fields ``verify_ok_d`` / ``consumed_d``)."""
-        import time as _time
-        phases: Dict[str, float] = {}
-        t_last = _time.monotonic()
-
-        def _clock(name: str) -> None:
-            nonlocal t_last
-            now = _time.monotonic()
-            phases[name] = phases.get(name, 0.0) + (now - t_last) * 1e3
-            t_last = now
-
         n = plan.n_steps
         k = len(self.LAYOUT)
         dev = plan.det_device is not None
@@ -418,23 +406,21 @@ class LogReplayer:
         else:
             rows = np.asarray(plan.det_rows)
             ts_idx, used, async_events = self._parse(rows, n)
-        _clock("parse")
-        if not dev:
             times_np = rows[ts_idx, det.LANE_P + 1].astype(np.int32)
             rngs_np = rows[ts_idx + 1, det.LANE_P].astype(np.int32)
             expected = rows[ts_idx + 3, det.LANE_P].astype(np.int32)
 
-        # Chunked inputs arrive as a plain list (one element per replay
-        # block); legacy stacked inputs are a RecordBatch or a (left,
-        # right) tuple of stacked RecordBatches.
-        chunked = isinstance(plan.input_steps, list)
-        inputs = None if chunked else plan.input_steps
         if plan.input_steps is None:
             # Source vertex: regenerates its records; inputs are empty.
             cap = self.operator.out_capacity or 1
             zc = jnp.zeros((self.block_steps, cap), jnp.int32)
             self._zero_chunk = RecordBatch(
                 zc, zc, zc, jnp.zeros((self.block_steps, cap), jnp.bool_))
+        elif not isinstance(plan.input_steps, list):
+            raise RecoveryError(
+                f"ReplayPlan.input_steps is a list of {self.block_steps}"
+                f"-step chunks, one per replay block, or None; a stacked "
+                f"{type(plan.input_steps).__name__} is not replayed")
 
         state = jax.tree_util.tree_map(
             lambda x: x[plan.subtask][None], plan.checkpoint_op_state)
@@ -468,16 +454,10 @@ class LogReplayer:
             # n; pad-unsafe operators (pure generators) run the exact tail
             # and pay one small compile. The device stream is pad-safe by
             # construction (the clean-path guard requires it).
-            pad = dev or (kk < ch and self.operator.replay_pad_safe
-                          and (chunked or plan.input_steps is None))
-            if chunked:
-                chunk = plan.input_steps[ci]
-            elif plan.input_steps is None:
-                chunk = self._zero_chunk
-            else:
-                chunk = jax.tree_util.tree_map(lambda x: x[lo:hi], inputs)
-            if kk < ch and not pad and (chunked or
-                                        plan.input_steps is None):
+            pad = dev or (kk < ch and self.operator.replay_pad_safe)
+            chunk = (self._zero_chunk if plan.input_steps is None
+                     else plan.input_steps[ci])
+            if kk < ch and not pad:
                 chunk = jax.tree_util.tree_map(lambda x: x[:kk], chunk)
             if pad or kk == ch:
                 lo_j = jnp.asarray(lo, jnp.int32)
@@ -501,14 +481,13 @@ class LogReplayer:
             emit_d = jnp.concatenate(emit_chunks, axis=0)[:n]
             exp_d = expected_d[:n]
             ok_d = jnp.all(emit_d == exp_d)
-            _clock("device_replay")
             return ReplayResult(
                 op_state=final_state,
                 rebuilt_log_rows=rows[:0], emit_counts=emit_d,
                 expected_emits=exp_d,
                 out_chunks=out_chunks if out_chunks else None,
                 records_replayed=-1, async_events=[],
-                phase_ms=phases, rebuilt_is_view=True,
+                rebuilt_is_view=True,
                 deferred=True, verify_ok_d=ok_d, consumed_d=consumed_acc)
         # ONE concat dispatch + ONE d2h for the emit counts, the
         # in-program consumed total, and (device path) the expected cuts.
@@ -522,7 +501,6 @@ class LogReplayer:
         consumed_total = int(packed_np[n_emit])
         if dev:
             expected = packed_np[n_emit + 1:][:n]
-        _clock("device_replay")
 
         # Regenerate the determinant rows the replayed run would log — the
         # rebuilt log must extend the recovered one bit-for-bit. Sync blocks
@@ -552,13 +530,12 @@ class LogReplayer:
 
         consumed = (consumed_total if plan.input_steps is not None
                     else int(emit_np.sum()))
-        _clock("rebuild_rows")
         return ReplayResult(
             op_state=final_state, rebuilt_log_rows=rebuilt,
             emit_counts=emit_np, expected_emits=expected,
             out_chunks=out_chunks if out_chunks else None,
             records_replayed=consumed, async_events=async_events,
-            phase_ms=phases, rebuilt_is_view=rebuilt_is_view)
+            rebuilt_is_view=rebuilt_is_view)
 
 
 class RecoveryManager:

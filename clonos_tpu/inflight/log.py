@@ -146,7 +146,7 @@ def slice_steps_at(state: EdgeLogState, abs_step, max_out: int
     holds there (stale or clobbered) — the caller must mask them. Used
     by recovery's uniform replay windows, whose first window starts one
     slot before the fence (that dead slot is replaced by the
-    checkpointed edge buffer; see cluster._replay_inputs)."""
+    checkpointed edge buffer; see failover._replay_inputs)."""
     start = jnp.asarray(abs_step, jnp.int32)
     count = jnp.clip(state.head - start, 0, max_out)
     idx = jnp.arange(max_out, dtype=jnp.int32)

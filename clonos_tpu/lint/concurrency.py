@@ -1,7 +1,7 @@
 """Rule family 3: lock discipline in the threaded runtime.
 
-runtime/cluster.py, runtime/checkpoint.py, runtime/dispatcher.py and
-obs/history.py document shared attributes as lock-guarded
+runtime/recovery_programs.py, runtime/checkpoint.py, runtime/dispatcher.py
+and obs/history.py document shared attributes as lock-guarded
 (``_writer_lock``, ``_lock``, ``_rjit_lock``): every mutation of the
 guarded state is supposed to happen inside ``with self.<lock>:``. The guard set is inferred rather
 than declared: an attribute counts as guarded once any method mutates
@@ -18,8 +18,8 @@ Approximations, chosen to keep the rule quiet on correct code:
   from lock-held contexts (a fixed point over the intra-class call
   graph).
 - Reads are not flagged — the runtime deliberately does lock-free
-  reads of monotonic state (double-checked ``_jitted`` cache); only
-  stores and mutating method calls count.
+  reads of monotonic state (runtime/recovery_programs.py's double-checked
+  ``_rjit`` cache); only stores and mutating method calls count.
 """
 
 from __future__ import annotations

@@ -1,64 +1,38 @@
-"""Cluster runner: failure detection, standby management, causal recovery.
+"""Cluster runner: epochs and their fences, failure detection, standbys.
 
-This is the control-plane layer tying the executor, checkpoint coordinator,
-replication plan, and recovery FSM together — capability parity with the
-reference's JobMaster-side machinery:
+This is the control-plane layer tying the executor, checkpoint coordinator
+and replication plan together — capability parity with the reference's
+JobMaster-side machinery:
 
 - ``HeartbeatMonitor``   <-  runtime/heartbeat (JobMaster.java:258-266)
 - ``StandbyPool``        <-  ExecutionVertex.addStandbyExecution /
                              CheckpointCoordinator state dispatch (:1226)
-- ``ClusterRunner``      <-  RunStandbyTaskStrategy.onTaskFailure
-                             (failover/RunStandbyTaskStrategy.java:85):
-                             remove failed, ignore unacked checkpoints,
-                             back off the checkpoint interval, run the
-                             standby through the recovery FSM (§3.4)
-
-Failure model (TPU deployment semantics): the unit of loss is a subtask's
-device-resident state — its operator-state slice, its thread causal log
-row, the replica rows it holds for others, AND its shard of its vertex's
-in-flight output ring (the producer's subpartition log dies with the
-producer, exactly the reference's PipelinedSubpartition ownership).
-Recovery rebuilds the lost ring shard from the replayed operator's
-re-emitted batches — reconstruction, not just verification (reference
-buildAndLogBuffer, PipelinedSubpartition.java:536-599).
-
-"Local recovery instead of global rollback" (README.md:13-20): healthy
-subtasks are never rolled back — the failed subtask alone is rebuilt from
-the last checkpoint plus determinant replay, then patched into the live
-carry. The proof obligation (and the test): the patched carry is
-bit-identical to a never-failed run on the canonical (logically-live)
-state — executor.canonical_carry.
+- ``ClusterRunner``      <-  the JobMaster's epoch loop; what it does
+                             on a task failure is runtime/failover.py
 """
 
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import os
 import threading
 import time as _time
-from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
-                    Tuple)
+from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from clonos_tpu.causal import determinant as det
-from clonos_tpu.causal import log as clog
 from clonos_tpu.causal import recovery as rec
-from clonos_tpu.causal import replication as rep
 from clonos_tpu.graph.job_graph import JobGraph, PartitionType
-from clonos_tpu.inflight import log as ifl
-from clonos_tpu.ops.histogram import over_mesh
 from clonos_tpu.parallel import routing
 from clonos_tpu.runtime import checkpoint as cp
 from clonos_tpu.obs import get_tracer
-from clonos_tpu.obs.scopes import scoped
-from clonos_tpu.storage import SegmentCorruptError, StorageError
-from clonos_tpu.runtime.executor import (DETS_PER_STEP, JobCarry,
-                                         LeanSnapshot, LocalExecutor,
+from clonos_tpu.runtime.executor import (DETS_PER_STEP, LocalExecutor,
                                          LogicalTimeSource)
+from clonos_tpu.runtime.failover import (Failover, RecoveryReport,
+                                         _exposed_ms)
 
 
 @contextlib.contextmanager
@@ -226,45 +200,9 @@ class LatencyMarkers:
         self._seen = max(self._seen, upto, 0)
 
 
-@dataclasses.dataclass
-class RecoveryReport:
-    """What one failure's recovery did (metrics + test surface)."""
-
-    failed_subtasks: Tuple[int, ...]
-    from_epoch: int
-    steps_replayed: int
-    determinants_replayed: int
-    records_replayed: int
-    ignored_checkpoints: Tuple[int, ...]
-    recovery_ms: float
-    managers: Tuple[rec.RecoveryManager, ...]
-    #: wall-clock per recovery phase (fetch_determinants / inputs / replay /
-    #: patch / replica_rebuild) — the cold-recovery cost breakdown.
-    phase_ms: Dict[str, float] = dataclasses.field(default_factory=dict)
-    #: True for failover rehearsals (failover_drill): excluded from the
-    #: recovery metrics and the reports ledger.
-    drill: bool = False
-    #: bytes the shard-local restore actually moved: the failed subtasks'
-    #: checkpoint slices + fetched determinant rows + replayed input
-    #: windows. The paper's local-recovery claim in one number —
-    #: ``restore_bytes < checkpoint_bytes`` says healthy shards kept
-    #: their live buffers instead of rolling back.
-    restore_bytes: int = 0
-    #: bytes of the FULL checkpointed carry a global rollback would have
-    #: re-loaded (the denominator for restore_bytes).
-    checkpoint_bytes: int = 0
-
-
 class OverflowError_(RuntimeError):
     """An un-checkpointed log/ring overflow was detected — the state is no
     longer recoverable and the control plane must not keep running."""
-
-
-def _exposed_ms(start: float, end: float, wait_from: float) -> float:
-    """Milliseconds of a worker's interval ``[start, end]`` that ran
-    after the thread it works for began waiting on it at ``wait_from``:
-    the part on the critical path (the rest ran under other work)."""
-    return max(0.0, end - max(start, wait_from)) * 1e3
 
 
 class ClusterRunner:
@@ -279,7 +217,6 @@ class ClusterRunner:
                  checkpoint_dir: Optional[str] = None,
                  incremental_checkpoints: bool = False,
                  incremental_base_every: int = 8,
-                 prewarm: bool = False,
                  recovery_block_steps: Optional[int] = None,
                  latency_marker_every: Optional[int] = None,
                  audit: Optional[bool] = None,
@@ -376,8 +313,6 @@ class ClusterRunner:
             "checkpoint.latest-bytes",
             lambda: (self.standbys.latest.size_bytes
                      if self.standbys.latest else 0))
-        self._m_recovery_ms = g.histogram("recovery.duration-ms")
-        self._m_recovered_records = g.counter("recovery.records-replayed")
         self._m_epoch_steps_ms = g.histogram("epoch.steps-ms")
         self._m_epoch_fence_ms = g.histogram("epoch.fence-ms")
         self._m_ckpt_latency_ms = g.histogram(
@@ -406,8 +341,6 @@ class ClusterRunner:
         else:
             self.auditor = _audit_mod.NullAuditor()
         self._m_audit_sealed = g.counter("audit.epochs-sealed")
-        self._m_audit_matches = g.counter("audit.epochs-validated")
-        self._m_audit_div = g.counter("audit.divergences")
         # Overhead attribution (obs/profile.py): the runner inherits the
         # process-global profiler (set by config/CLI). Binding routes
         # the overhead.<section>-ms histograms and overhead.ft-fraction
@@ -486,22 +419,6 @@ class ClusterRunner:
                         lambda i=i: int(self.per_shard_health()[i, 1]))
                 g.gauge(f"shard.{i}.ring-slots",
                         lambda i=i: int(self.per_shard_health()[i, 2]))
-        #: compiled recovery programs, keyed by (kind, params) — populated
-        #: lazily and by prewarm_recovery() (warm standby: no XLA compile
-        #: in the failure path).
-        self._rjit: Dict[Any, Any] = {}
-        import threading as _threading
-        self._rjit_lock = _threading.Lock()
-        #: routed edge-window cache, scoped to one vertex's failed
-        #: subtasks within one recover() call (the exchange output is
-        #: consumer-independent; see _replay_inputs). Populated only
-        #: when the current vertex has >= 2 failed subtasks — the
-        #: all-lane blocks are P-times a lane's size, so caching buys
-        #: nothing for the common single-subtask failure.
-        self._route_cache: Dict[Any, Any] = {}
-        self._route_cache_enabled = False
-        #: observability/test hook: cache hits in the last recover()
-        self._route_cache_hits = 0
         #: counter fed from the fence's health read (an event-time
         #: window's ``fence_totals``, ``exchange.*``) -> its total at the
         #: last fence
@@ -570,15 +487,12 @@ class ClusterRunner:
             self.executor.drain_block_outputs = self._read_sink_tap
             self.coordinator.subscribe_completion(
                 lambda e: [tl.commit(e) for tl in self.txn_logs.values()])
-        #: recovery chunk size: larger than the live block trades a bigger
-        #: prewarm compile for fewer per-chunk dispatches on the failure
-        #: path.
-        self._recovery_ch = min(
+        #: the failure path (runtime/failover.py): kill, recovery, the
+        #: warm standby's programs, at the recovery chunk size
+        self.failover = Failover(self, g, min(
             recovery_block_steps or self.executor.block_steps,
             self.executor.compiled.inflight_ring_steps,
-            self.executor.compiled.log_capacity // DETS_PER_STEP)
-        if prewarm:
-            self.prewarm_recovery()
+            self.executor.compiled.log_capacity // DETS_PER_STEP))
 
     def _commit_feed_offsets(self, ckpt) -> None:
         for vid, reader in self.executor.feed_readers.items():
@@ -673,118 +587,6 @@ class ClusterRunner:
             self._shard_health_epoch = self.executor.epoch_id
         return self._shard_health
 
-    # --- compiled recovery programs ------------------------------------------
-
-    def _jitted(self, key, make, donate=()):
-        f = self._rjit.get(key)
-        if f is None:
-            with self._rjit_lock:
-                f = self._rjit.get(key)
-                if f is None:
-                    compiled = self.executor.compiled
-                    f = jax.jit(over_mesh(make(), compiled.mesh,
-                                          compiled.task_axis),
-                                donate_argnums=donate)
-                    self._rjit[key] = f
-        return f
-
-    def _chunk(self) -> int:
-        return self._recovery_ch
-
-    def _fetch_fn(self):
-        cap = self.executor.compiled.log_capacity
-        return self._jitted(("fetch",), lambda: (
-            lambda replicas, r, from_epoch: clog.get_determinants(
-                jax.tree_util.tree_map(lambda x: x[r], replicas),
-                from_epoch, cap)))
-
-    def _fetch_meta_fn(self, h: int):
-        """(count, start) of every holder's response in one device call —
-        holders are bit-identical replicas by construction, so the host
-        merge reduces to verifying the counts agree and pulling ONE body."""
-        cap = self.executor.compiled.log_capacity
-
-        def make():
-            def f(replicas, rs, from_epoch):
-                def one(r):
-                    rep_one = jax.tree_util.tree_map(
-                        lambda x: x[r], replicas)
-                    off = clog.epoch_start_offset(rep_one, from_epoch)
-                    cnt = jnp.clip(rep_one.head - off, 0, cap)
-                    return jnp.stack([cnt, off])
-                return jax.vmap(one)(rs)          # [h, 2]
-            return f
-        return self._jitted(("fetch_meta", h), make)
-
-    def _pad_steps(self) -> int:
-        ch = self._recovery_ch
-        return -(-self.executor.compiled.inflight_ring_steps // ch) * ch
-
-    def _device_parse_fn(self):
-        """Parse a consistent replica's determinant stream ON DEVICE:
-        locate the per-step sync anchors, extract the time/rng/expected
-        lanes (padded to the replayer's fixed stream length), and report
-        whether the stream is 'clean' (pure sync rows, exact layout).
-        Only ~16 bytes of metadata cross the host link — the multi-MB
-        log body stays on device (it IS the replica; the restore path
-        copies it device-side too). Reference contrast: the JVM replayer
-        walks the byte log on-heap (LogReplayerImpl.java:36-157)."""
-        cap = self.executor.compiled.log_capacity
-        maxn = self._pad_steps()
-        k = DETS_PER_STEP
-
-        def make():
-            def f(replicas, r, from_epoch):
-                buf, count, start = clog.get_determinants(
-                    jax.tree_util.tree_map(lambda x: x[r], replicas),
-                    from_epoch, cap)
-                tags = buf[:, det.LANE_TAG]
-                rowmask = jnp.arange(cap) < count
-                cond = (rowmask & (tags == det.TIMESTAMP)
-                        & (buf[:, det.LANE_RC] == 0))
-                n_anchors = cond.sum().astype(jnp.int32)
-                ids = jnp.nonzero(cond, size=maxn,
-                                  fill_value=cap - k)[0].astype(jnp.int32)
-                amask = jnp.arange(maxn) < n_anchors
-                layout = jnp.all(
-                    ~amask
-                    | ((tags[ids + 1] == det.RNG)
-                       & (tags[ids + 2] == det.ORDER)
-                       & (tags[ids + 3] == det.BUFFER_BUILT)))
-                clean = layout & (count == n_anchors * k)
-                last = jnp.maximum(n_anchors - 1, 0)
-                t_raw = buf[ids, det.LANE_P + 1]
-                r_raw = buf[ids + 1, det.LANE_P]
-                times = jnp.where(amask, t_raw, t_raw[last])
-                rngs = jnp.where(amask, r_raw, r_raw[last])
-                expected = jnp.where(amask, buf[ids + 3, det.LANE_P], 0)
-                small = jnp.stack([count, start, n_anchors,
-                                   clean.astype(jnp.int32)])
-                return times, rngs, expected, small
-            return f
-        return self._jitted(("device_parse",), make)
-
-    def _ring_bounds_dev(self):
-        """Device [R, 2] (tail, head) of every in-flight ring — dispatch
-        only; recover() folds the transfer into its packed reads."""
-        if not self.executor.carry.out_rings:
-            return None
-        fn = self._jitted(("ring_bounds",), lambda: (
-            lambda rings: jnp.stack(
-                [jnp.stack([el.tail, el.head]) for el in rings])))
-        return fn(self.executor.carry.out_rings)
-
-    def _ring_bounds(self) -> Dict[int, Tuple[int, int]]:
-        """(tail, head) of every in-flight ring in ONE device read — ring
-        offsets don't move during recovery (write-backs change contents
-        only), so recover() reads them once instead of twice per chunk."""
-        dev = self._ring_bounds_dev()
-        if dev is None:
-            return {}
-        arr = np.asarray(dev)
-        return {ri: (int(arr[ri, 0]), int(arr[ri, 1]))
-                for ri in range(arr.shape[0])}
-
     def _update_ring_mirror(self, completed_epoch: int) -> None:
         """Checkpoint-completion hook: advance the host ring-tail mirror
         to the completed epoch's end fence (matches ifl.truncate). A
@@ -804,117 +606,6 @@ class ClusterRunner:
             self._ck_log_heads = {
                 k: v for k, v in self._ck_log_heads.items()
                 if k >= completed_epoch}
-
-    def _ring_chunk_fn(self, ri: int, m: int):
-        return self._jitted(("ring_chunk", ri, m), lambda: (
-            lambda el, start: ifl.slice_steps(el, start, m)))
-
-    def _route_chunk_fn(self, eidx: int, m: int, all_lanes: bool = False):
-        """Read + route one [m]-step window of edge ``eidx``'s producer
-        ring — one program with the loop state (window start, leading
-        skip, rebalance offset, remaining needed steps) carried ON
-        DEVICE, so a chunk costs no host→device put of its own.
-
-        Two variants, both prewarmed:
-        - fused (default): the consumer's lane is selected INSIDE the
-          program. Crucial for the single-failure case: XLA then scatters
-          only that lane's rows (a general scatter runs ~row-at-a-time
-          on TPU, so materializing all P lanes costs ~P times more).
-        - ``all_lanes``: the full [m, P, cap] routed block — the routing
-          is consumer-independent, so a connected multi-subtask failure
-          routes each window ONCE and lane-selects per consumer (the
-          reference re-serves the in-flight log per requesting channel;
-          here the exchange is the expensive part and it is shared).
-
-        Replay windows are UNIFORM: every window is m steps, the first
-        starting one slot before the fence (that dead slot is masked by
-        ``lead`` and later replaced by the checkpointed edge buffer) —
-        one compiled program serves every chunk instead of a first-chunk
-        (m-1) shape variant doubling the prewarm. ``need_left`` masks
-        steps past the replay range invalid (the replay-padding
-        contract); ``lead`` masks the leading dead slot of window 0."""
-        def make():
-            body = self._route_body(eidx, m)
-            if all_lanes:
-                def f(el, start, rr0, need_left, lead):
-                    raw = ifl.slice_steps_at(el, start, m)
-                    routed, cnt = body(raw, None, rr0, need_left, lead)
-                    return (routed, start + m, rr0 + cnt, need_left - m,
-                            jnp.zeros_like(lead))
-            else:
-                def f(el, start, sub, rr0, need_left, lead):
-                    raw = ifl.slice_steps_at(el, start, m)
-                    lane, cnt = body(raw, sub, rr0, need_left, lead)
-                    return (lane, start + m, rr0 + cnt, need_left - m,
-                            jnp.zeros_like(lead))
-            return f
-        return self._jitted(("route_chunk", eidx, m, all_lanes), make)
-
-    def _lane_select_fn(self, eidx: int, m: int):
-        """Select one consumer lane of a routed [m, P, cap] block."""
-        return self._jitted(("lane_select", eidx, m), lambda: (
-            lambda routed, sub: jax.tree_util.tree_map(
-                lambda x: x[:, sub], routed)))
-
-    def _route_body(self, eidx: int, m: int):
-        """The shared exchange-replay body: mask the ``lead`` leading
-        slots and steps past ``need_left`` invalid, then take the
-        edge's route (``CompiledJob.route_edge``, the block program's
-        own) — to all destination lanes (``sub`` None), or to the
-        single consumer lane ``sub`` DIRECTLY, bit-identical to the full
-        route's lane: a dynamic exchange then counts a [m, n] membership
-        mask (routing._block_to_target_lane) instead of the [m, T, n]
-        one-hot, a whole window of m steps in one piece where the full
-        exchange goes chunk by chunk."""
-        compiled = self.executor.compiled
-
-        def body(raw, sub, rr0, need_left, lead):
-            need = jnp.clip(need_left, 0, m)
-            idx = jnp.arange(m, dtype=jnp.int32)
-            live = (idx >= lead) & (idx < need)
-            raw = raw._replace(valid=raw.valid & live[:, None, None])
-            r, _ = compiled.route_edge(eidx, raw, rr0, lane=sub)
-            return r, raw.count().sum()
-        return scoped("exchange")(body)
-
-    def _route_raw_fn(self, eidx: int, m: int, all_lanes: bool = False):
-        """Spill-path twin of :meth:`_route_chunk_fn`: routes a
-        host-assembled raw chunk instead of reading the device ring,
-        advancing the same device-carried loop state."""
-        def make():
-            body = self._route_body(eidx, m)
-            if all_lanes:
-                def f(raw, start, rr0, need_left, lead):
-                    routed, cnt = body(raw, None, rr0, need_left, lead)
-                    return (routed, start + m, rr0 + cnt, need_left - m,
-                            jnp.zeros_like(lead))
-            else:
-                def f(raw, start, sub, rr0, need_left, lead):
-                    lane, cnt = body(raw, sub, rr0, need_left, lead)
-                    return (lane, start + m, rr0 + cnt, need_left - m,
-                            jnp.zeros_like(lead))
-            return f
-        return self._jitted(("route_raw", eidx, m, all_lanes), make)
-
-    #: replica rows one call of the rebuild program copies: its scratch
-    #: is this many log rows, not the whole replica set (which, gathered
-    #: beside the carry, does not fit the chip once a job is deep)
-    REPLICA_COPY_ROWS = 64
-
-    def _replica_copy_fn(self):
-        """``replicas[ri] = logs[oi]`` for ``REPLICA_COPY_ROWS`` pairs
-        (``ri`` past the end: no row), in place on the donated replicas."""
-        return self._jitted(("replica_copy",), lambda: (
-            lambda replicas, logs, ri, oi: jax.tree_util.tree_map(
-                lambda s, l: s.at[ri].set(l[oi], mode="drop"),
-                replicas, logs)), donate=(0,))
-
-    def _first_chunk_fn(self, eidx: int):
-        """Replace the first window's dead leading slot with the
-        checkpointed depth-1 edge buffer (replay step 0 consumes it)."""
-        return self._jitted(("first_chunk", eidx), lambda: (
-            lambda buf_sub, routed: jax.tree_util.tree_map(
-                lambda a, b: b.at[0].set(a[0]), buf_sub, routed)))
 
     # --- timers / epoch services ---------------------------------------------
 
@@ -1250,13 +941,12 @@ class ClusterRunner:
         # rings now.
         if n_steps > 0:
             c = runner.executor.carry
-            ch = runner._chunk()
+            progs = runner.failover.programs
             bufs = list(c.edge_bufs)
             for eidx, e in enumerate(job.edges):
                 ri = runner.executor.compiled.ring_index[e.src]
                 z = jnp.asarray(0, jnp.int32)
-                routed, *_ = runner._route_chunk_fn(
-                    eidx, ch, all_lanes=True)(
+                routed, *_ = progs.route_chunk(eidx, progs.chunk, True)(
                     c.out_rings[ri],
                     jnp.asarray(fence + n_steps - 1, jnp.int32),
                     z, jnp.asarray(1, jnp.int32), z)
@@ -2087,1552 +1777,24 @@ class ClusterRunner:
         self._m_steps.inc()
         self.heartbeats.beat_all_except(self.failed)
 
-    # --- failure injection ---------------------------------------------------
-
-    def _inject_fn(self, vid: int):
-        """One fused kill program per vertex class (eager per-array
-        zeroing would copy the carry once per touched leaf)."""
-        compiled = self.executor.compiled
-        nr = compiled.plan.num_replicas
-
-        def make():
-            def f(carry, sub, flat, held_idx):
-                fresh = clog.create(compiled.log_capacity,
-                                    compiled.max_epochs)
-                ops = list(carry.op_states)
-                ops[vid] = jax.tree_util.tree_map(
-                    lambda x: x.at[sub].set(jnp.zeros_like(x[sub])),
-                    ops[vid])
-                logs = jax.tree_util.tree_map(
-                    lambda s, fr: s.at[flat].set(fr), carry.logs, fresh)
-                replicas = carry.replicas
-                if nr > 0:
-                    replicas = jax.tree_util.tree_map(
-                        lambda s, fr: s.at[held_idx].set(
-                            jnp.broadcast_to(
-                                fr, held_idx.shape + fr.shape),
-                            mode="drop"),
-                        replicas, fresh)
-                rings = list(carry.out_rings)
-                if vid in compiled.ring_index:
-                    ri = compiled.ring_index[vid]
-                    el = rings[ri]
-                    rings[ri] = el._replace(
-                        keys=el.keys.at[:, sub].set(0),
-                        values=el.values.at[:, sub].set(0),
-                        timestamps=el.timestamps.at[:, sub].set(0),
-                        valid=el.valid.at[:, sub].set(False))
-                return carry._replace(
-                    op_states=tuple(ops), logs=logs, replicas=replicas,
-                    out_rings=tuple(rings),
-                    record_counts=carry.record_counts.at[flat].set(0))
-            return f
-        return self._jitted(("inject", vid), make, donate=(0,))
+    # --- the failure path (runtime/failover.py) ------------------------------
 
     def inject_failure(self, flat_subtasks: Sequence[int]) -> None:
-        """Kill subtasks: zero their device state — operator slice, causal
-        log row, held replica rows, and their shard of the vertex's
-        in-flight output ring (the producer's subpartition log dies with
-        the producer). (Fault-injection API the reference delegates to
-        Jepsen, flink-jepsen/.)"""
-        # A kill landing mid-pipelined-fence DRAINS the in-flight seal
-        # deterministically: the tail belongs to an epoch every victim
-        # completed healthy, so joining it first (seal + ledger +
-        # checkpoint ack all land) makes the post-kill storage state a
-        # pure function of the kill point — recovery then sees either a
-        # completed fence or a cleanly pending one, never a half-sealed
-        # epoch. So does the sink tap: a block still in flight (only
-        # where an exception abandoned a block loop) is read before the
-        # kill decides which pending shards are lost.
-        self._join_fence_tail()
-        self._read_sink_tap()
-        carry = self.executor.carry
-        nr = self.executor.compiled.plan.num_replicas
-        for flat in flat_subtasks:
-            self.failed.add(flat)
-            self.heartbeats.mark_dead(flat)
-            vid, sub = self._vertex_of(flat)
-            held = np.full((max(nr, 1),), max(nr, 1), np.int32)
-            hl = self.plan.replicas_held_by(flat)
-            held[:len(hl)] = hl
-            carry = self._inject_fn(vid)(
-                carry, jnp.asarray(sub, jnp.int32),
-                jnp.asarray(flat, jnp.int32), jnp.asarray(held))
-        self.executor.carry = carry
-
-    def _vertex_of(self, flat: int) -> Tuple[int, int]:
-        for v in self.job.vertices:
-            base = self.job.subtask_base(v.vertex_id)
-            if base <= flat < base + v.parallelism:
-                return v.vertex_id, flat - base
-        raise ValueError(f"no subtask {flat}")
-
-    # --- recovery (reference §3.4 signature path) ----------------------------
+        """Kill subtasks (:meth:`Failover.inject_failure`)."""
+        self.failover.inject_failure(flat_subtasks)
 
     def detect_failures(self) -> List[int]:
         return self.heartbeats.expired()
 
-    def recover(self, drill: bool = False,
-                host_rows: Optional[Dict[int, Tuple[np.ndarray, int]]]
-                = None,
-                pre_patch_join: Optional[Callable[[], None]] = None
-                ) -> RecoveryReport:
-        """Public entry for :meth:`_recover_impl` that additionally
-        lands an incident bundle (obs/incident.py) when the protocol
-        itself fails — a recovery that cannot complete is exactly the
-        moment the forensic state (ledgers, determinant windows, HLC
-        timeline) is about to become unreachable. No-op passthrough
-        when the incident plane is disabled."""
-        tr = get_tracer()
-        # The phases run through one body, so they are a chain of
-        # consecutive spans (children of ``recovery``) whose stamps also
-        # fill ``RecoveryReport.phase_ms``.
-        phases: Dict[str, float] = {}
-        try:
-            with tr.span("recovery", drill=bool(drill),
-                         victims=sorted(self.failed)) as span, \
-                    tr.chain("recovery.", into=phases,
-                             drill=bool(drill)) as chain:
-                report = self._recover_impl(
-                    phases, chain, drill=drill, host_rows=host_rows,
-                    pre_patch_join=pre_patch_join)
-                span.set(from_epoch=report.from_epoch,
-                         steps_replayed=report.steps_replayed,
-                         records_replayed=report.records_replayed,
-                         recovery_ms=report.recovery_ms)
-                return report
-        except Exception as e:
-            from clonos_tpu.obs.incident import get_incidents
-            get_incidents().signal(
-                "recovery.failure",
-                epoch=int(getattr(self.auditor, "last_epoch", -1)),
-                error=f"{type(e).__name__}: {str(e)[:200]}",
-                drill=bool(drill),
-                failed=sorted(self.failed))
-            raise
+    def recover(self, drill: bool = False, host_rows=None,
+                pre_patch_join=None) -> RecoveryReport:
+        """Recover every failed subtask (:meth:`Failover.recover`)."""
+        return self.failover.recover(drill, host_rows, pre_patch_join)
 
-    def _recover_impl(self, phases: Dict[str, float], chain,
-                      drill: bool = False,
-                      host_rows: Optional[Dict[int, Tuple[np.ndarray, int]]]
-                      = None,
-                      pre_patch_join: Optional[Callable[[], None]] = None
-                      ) -> RecoveryReport:
-        """Run the full causal-recovery protocol for all failed subtasks,
-        in topological order (an upstream's reconstructed ring shard feeds
-        its downstream's replay — the reference's staged
-        WaitingConnections/in-flight-request ordering).
+    def prewarm_recovery(self) -> float:
+        """Compile the recovery programs (:meth:`Failover.prewarm`)."""
+        return self.failover.prewarm()
 
-        ``drill=True`` (failover rehearsal) runs the identical replay
-        protocol but makes none of the failure-handling *decisions* —
-        pending checkpoints are not ignored (they may yet complete),
-        no IGNORE_CHECKPOINT determinants are logged, the checkpoint
-        interval is not backed off, and recovered timer effects are not
-        re-fired — so the job state is bit-identical afterwards.
-
-        ``host_rows`` maps flat subtask -> (rows, abs_start): an external
-        determinant source that replaces the on-device replica fetch for
-        those subtasks — the standby-HOST path, where the rows come from
-        a RemoteReplicaMirror after a whole-host loss (reference
-        DeterminantResponseEvent arriving over the wire instead of the
-        local piggyback channel).
-
-        The finalize drains the final packed barrier-read on a worker
-        thread while the main thread runs the audit validator, with an
-        explicit join + deferred-assert check before returning; revive
-        bookkeeping runs only after the join and state-verify pass (a
-        failed verify leaves the subtasks marked dead, and an audit
-        divergence is re-raised after verify and revive).
-
-        ``pre_patch_join`` is the bootstrap-overlap hook: a callable
-        joined (once) immediately before the FIRST ``_patch`` call —
-        the earliest point recovery reads the roll-gap/async ledgers a
-        bootstrap derives on a worker thread concurrently with this
-        replay. Its blocked wall is attributed to
-        ``finalize.listener-reattach``, not to the patch phase."""
-        if not self.failed:
-            raise rec.RecoveryError("no failed subtasks")
-        # Defensive: inject_failure already drains the pipelined fence,
-        # but recovery must never run against a half-sealed tail.
-        self._join_fence_tail()
-        if not self.standbys.has_state():
-            raise rec.RecoveryError(
-                "no completed checkpoint to restore standbys from")
-        t0 = _time.monotonic()
-        chain.switch("restore")
-        topo_pos = {vid: i for i, vid in
-                    enumerate(self.executor.compiled.topo)}
-        failed = tuple(sorted(
-            self.failed, key=lambda f: (topo_pos[self._vertex_of(f)[0]], f)))
-
-        # (1) RunStandbyTaskStrategy.onTaskFailure: ignore checkpoints the
-        # dead tasks never acked; back off the checkpoint interval.
-        ignored: Tuple[int, ...] = ()
-        if not drill:
-            ignored = tuple(self.coordinator.ignore_unacked_for(set(failed)))
-            self.coordinator.backoff()
-            # Healthy tasks log the ignore decision (reference
-            # StreamTask.ignoreCheckpoint:891-915 — the RPC arrival is a
-            # determinant so their own later recoveries replay it).
-            healthy = [f for f in range(self.job.total_subtasks())
-                       if f not in self.failed]
-            for cid in ignored:
-                self.executor.append_async_many(
-                    healthy, det.IgnoreCheckpointDeterminant(
-                        record_count=self.executor.global_record_stamp(),
-                        checkpoint_id=cid))
-
-        ckpt = self.standbys.latest
-        from_epoch = ckpt.checkpoint_id + 1
-        fence = self._fence_step[from_epoch]
-        n_steps = self.global_step - fence
-        snap: LeanSnapshot = jax.tree_util.tree_map(jnp.asarray, ckpt.carry)
-        managers: List[rec.RecoveryManager] = []
-        total_dets = 0
-        total_records = 0
-        # Shard-local restore accounting: bytes each failed subtask's
-        # rehydration actually moves vs the full snapshot a global
-        # rollback would re-load (the paper's local-recovery claim as a
-        # measurable ratio; surfaces on the RecoveryReport).
-        restore_bytes = 0
-        checkpoint_bytes = (int(getattr(ckpt, "size_bytes", 0) or 0)
-                            or cp.carry_nbytes(ckpt.carry))
-        tr = get_tracer()
-        patched = self.executor.carry
-        # Ring bounds for routing coverage decisions: the host mirror
-        # (tails move only at checkpoint completion, heads advance one
-        # per superstep == global_step) when valid, else one device read.
-        # The device values recovery actually used are re-checked in the
-        # final packed read either way (fail-loud, not trust).
-        bounds_dev = self._ring_bounds_dev()
-        nrings = len(patched.out_rings)
-        if self._ring_mirror_valid:
-            # Heads advance once per superstep wherever the executor is
-            # driven from; its own step ledger is the authoritative one.
-            head_m = len(self.executor.step_input_history)
-            self._bounds_cache = {
-                ri: (self._ring_tail_mirror, head_m)
-                for ri in range(nrings)}
-        else:
-            barr = (np.asarray(bounds_dev) if nrings
-                    else np.zeros((0, 2), np.int32))
-            self._bounds_cache = {ri: (int(barr[ri, 0]), int(barr[ri, 1]))
-                                  for ri in range(nrings)}
-        self._route_cache = {}
-        self._route_cache_hits = 0
-        vid_failed_counts: Dict[int, int] = {}
-        for flat in failed:
-            v_of = self._vertex_of(flat)[0]
-            vid_failed_counts[v_of] = vid_failed_counts.get(v_of, 0) + 1
-        prev_vid = None
-        chain.switch("fetch_determinants")
-
-        # ---- phase A: determinant metadata for ALL failed subtasks ----
-        # Dispatch every per-subtask parse/meta program up front, then pay
-        # at most ONE host read for the whole failure set. Subtasks whose
-        # cleanness the host can derive itself (no async rows since the
-        # fence — executor.async_counts ledger — and fence log heads in
-        # hand) skip even that: their metadata becomes deferred asserts
-        # in the final packed read, and their replay defers its sync too:
-        # every host read stalls the dispatch queue behind it.
-        with self._ck_heads_lock:
-            ck_heads = self._ck_log_heads.get(ckpt.checkpoint_id)
-        from clonos_tpu.api.operators import HostFeedSource
-        prep: Dict[int, Dict[str, Any]] = {}
-        slow_reads: List[Tuple[int, str, Any]] = []
-        for flat in failed:
-            vid_a, _sub_a = self._vertex_of(flat)
-            v_a = self.job.vertices[vid_a]
-            if host_rows is not None and flat in host_rows:
-                # External determinant source (standby-host mirror):
-                # no device fetch/parse to dispatch at all.
-                prep[flat] = {"holders": [], "fast": False, "host": True}
-                continue
-            holders_a = [
-                (r, h) for r, (o, h) in enumerate(self.plan.pairs)
-                if o == flat and h not in self.failed]
-            p: Dict[str, Any] = {"holders": holders_a}
-            eligible = (bool(holders_a) and n_steps > 0
-                        and v_a.operator.replay_pad_safe
-                        and not isinstance(v_a.operator, HostFeedSource)
-                        and n_steps <= self._pad_steps())
-            if eligible:
-                t_d, r_d, e_d, small_d = self._device_parse_fn()(
-                    patched.replicas,
-                    jnp.asarray(holders_a[0][0], jnp.int32),
-                    jnp.asarray(from_epoch, jnp.int32))
-                p["det_device"] = (t_d, r_d, e_d)
-                p["small_d"] = small_d
-            if holders_a:
-                hidx_a = jnp.asarray([r for r, _ in holders_a], jnp.int32)
-                p["meta_d"] = self._fetch_meta_fn(len(holders_a))(
-                    patched.replicas, hidx_a,
-                    jnp.asarray(from_epoch, jnp.int32))
-            p["fast"] = (eligible and ck_heads is not None
-                         and vid_a not in self.txn_logs
-                         and self.executor.async_rows_since(
-                             flat, from_epoch) == 0)
-            if not p["fast"]:
-                if "small_d" in p:
-                    slow_reads.append((flat, "small", p["small_d"]))
-                if "meta_d" in p:
-                    slow_reads.append((flat, "meta", p["meta_d"]))
-            prep[flat] = p
-        slow_vals: Dict[Tuple[int, str], np.ndarray] = {}
-        if slow_reads:
-            packed_a = np.asarray(jnp.concatenate(
-                [d.reshape(-1).astype(jnp.int32)
-                 for _f, _k, d in slow_reads]))
-            off_a = 0
-            for flat, kind, d in slow_reads:
-                nsz = int(np.prod(d.shape))
-                slow_vals[(flat, kind)] = packed_a[
-                    off_a: off_a + nsz].reshape(d.shape)
-                off_a += nsz
-
-        for flat in failed:
-            chain.switch("fetch_determinants")
-            vid, sub = self._vertex_of(flat)
-            if vid != prev_vid:
-                # Routed windows are valid only while the upstream rings
-                # they read are final — scope the share to one vertex's
-                # consumers (upstream vertices were patched earlier in
-                # topological order). The cache holds full [m, P, cap]
-                # blocks, so bound its bytes: past the budget every
-                # consumer takes the fused per-lane path instead of an
-                # OOM mid-recovery.
-                self._route_cache = {}
-                share = vid_failed_counts[vid] >= 2
-                if share and n_steps > 0:
-                    ch_ = self._chunk()
-                    nblocks_ = -(-n_steps // ch_)
-                    est = sum(
-                        nblocks_ * ch_
-                        * self.job.vertices[self.job.edges[e2].dst
-                                            ].parallelism
-                        * self.job.edges[e2].capacity * 4 * 4
-                        for e2 in self.job.in_edges(vid))
-                    share = est <= (1 << 30)
-                self._route_cache_enabled = share
-                prev_vid = vid
-            v = self.job.vertices[vid]
-            mgr = rec.RecoveryManager(vid, sub, flat,
-                                      self._make_replayer(vid, sub))
-            managers.append(mgr)
-            in_edges = self.job.in_edges(vid)
-            out_edges = self.job.out_edges(vid)
-
-            # FSM: standby -> connections re-established + state restored.
-            mgr.notify_start_recovery(in_edges, out_edges)
-            mgr.notify_state_restoration_complete()
-            for e in in_edges:
-                mgr.notify_new_input_channel(e)
-            for e in out_edges:
-                mgr.notify_new_output_channel(e)
-
-            # DeterminantRequest flood to surviving holders of this log
-            # (programs were dispatched in phase A; values arrive either
-            # from the phase-A packed read or — fast path — as deferred
-            # asserts in the final one).
-            p = prep[flat]
-            holders = p["holders"]
-            fast = p["fast"]
-            synthesized = False
-            if p.get("host"):
-                # Mirror-sourced determinants (whole-host loss): the rows
-                # arrived over the wire; everything downstream of the
-                # fetch (merge, replay, verify, patch) is identical.
-                rows_h, start_h = host_rows[flat]
-                mgr.expect_determinant_responses(1)
-                mgr.notify_determinant_response(
-                    np.asarray(rows_h, np.int32), int(start_h))
-            elif not holders and n_steps > 0:
-                if out_edges:
-                    raise rec.RecoveryError(
-                        f"subtask {flat}: no surviving replica holds its "
-                        f"determinant log (sharing depth / replication "
-                        f"factor too shallow for this failure pattern)")
-                # Pure sink: nobody downstream replicates its log. Its
-                # inputs replay exactly from the upstream ring; its own
-                # nondeterminism (time/rng step inputs) is re-synthesized
-                # from the coordinator's input ledger. (The reference has
-                # the same boundary: sink exactly-once needs transactional
-                # sinks, TwoPhaseCommitSinkFunction.)
-                synthesized = True
-            r_best = None
-            det_device = None
-            clean_n = None
-            if p.get("host"):
-                pass          # responses already delivered above
-            elif fast:
-                # Host-derived cleanness: zero async rows since the fence
-                # means the log holds exactly n_steps k-row sync blocks
-                # starting at the checkpointed head. Everything the old
-                # metadata read returned is therefore known here; the
-                # device parse/meta values become deferred asserts.
-                ck_head_f = int(ck_heads[flat])
-                det_device = p["det_device"]
-                clean_n, clean_start = DETS_PER_STEP * n_steps, ck_head_f
-                r_best = holders[0][0]
-                mgr.expect_determinant_responses(1)
-                mgr.notify_determinant_response(
-                    np.zeros((0, det.NUM_LANES), np.int32), clean_start)
-            elif holders:
-                # Holders are bit-identical replicas by construction, so
-                # when their metadata agrees the merge is "pull one body"
-                # (saves H-1 multi-MB transfers + 2(H-1) round-trips).
-                meta = slow_vals[(flat, "meta")]
-                consistent = (len(np.unique(meta[:, 0])) == 1
-                              and len(np.unique(meta[:, 1])) == 1)
-                # Clean path off the ledger fast lane: the device parse
-                # (phase A) says whether the stream is pure sync rows; if
-                # so the multi-MB body never crosses the host link.
-                if consistent and (flat, "small") in slow_vals:
-                    cnt_s, start_s, nanch, cleanflag = (
-                        int(x) for x in slow_vals[(flat, "small")])
-                    if cleanflag and nanch == n_steps:
-                        det_device = p["det_device"]
-                        clean_n, clean_start = cnt_s, start_s
-                        mgr.expect_determinant_responses(1)
-                        mgr.notify_determinant_response(
-                            np.zeros((0, det.NUM_LANES), np.int32),
-                            start_s)
-                if det_device is None:
-                    use = ([holders[0]] if consistent else holders)
-                    mgr.expect_determinant_responses(len(use))
-                    fetch = self._fetch_fn()
-                    for j, (r, _h) in enumerate(use):
-                        buf, count, start = fetch(
-                            patched.replicas, jnp.asarray(r, jnp.int32),
-                            jnp.asarray(from_epoch, jnp.int32))
-                        mgr.notify_determinant_response(
-                            np.asarray(buf)[: int(meta[j, 0])],
-                            int(meta[j, 1]))
-                # A single consistent replica's device bytes can restore
-                # the log directly; disagreeing holders must go through
-                # the host merge (r_best None -> chunked upload path).
-                r_best = holders[0][0] if consistent else None
-            else:
-                mgr.expect_determinant_responses(0)
-            if synthesized:
-                rows = self._synthesize_det_rows(fence, n_steps)
-                start = (int(ck_heads[flat]) if ck_heads is not None
-                         else int(np.asarray(snap.log_heads[flat])))
-            elif det_device is not None:
-                rows = np.zeros((0, det.NUM_LANES), np.int32)
-                start = clean_start
-            else:
-                rows, start = mgr.merged_determinants()
-            total_dets += clean_n if clean_n is not None else len(rows)
-            chain.switch("inputs")
-
-            # Lost inputs: the checkpointed edge buffer (the depth-1 batch
-            # spanning the fence) + the upstream rings' raw outputs,
-            # re-routed through the deterministic exchange. Upstream ring
-            # shards zeroed by a connected failure were rebuilt earlier in
-            # this loop (topological order).
-            from clonos_tpu.api.operators import (HostFeedSource,
-                                                  TwoInputOperator)
-            input_steps = None
-            if isinstance(v.operator, TwoInputOperator):
-                input_steps = list(zip(
-                    self._replay_inputs(patched, snap, in_edges[0], sub,
-                                        fence, n_steps),
-                    self._replay_inputs(patched, snap, in_edges[1], sub,
-                                        fence, n_steps)))
-            elif in_edges:
-                input_steps = self._replay_inputs(patched, snap, in_edges[0],
-                                                  sub, fence, n_steps)
-            elif isinstance(v.operator, HostFeedSource) and n_steps > 0:
-                input_steps = self._reread_feed(vid, sub, snap, rows, n_steps)
-            chain.switch("replay")
-
-            plan = rec.ReplayPlan(
-                vertex_id=vid, subtask=sub, flat_subtask=flat,
-                from_epoch=from_epoch, input_steps=input_steps,
-                det_rows=rows, det_start=start,
-                checkpoint_op_state=snap.op_states[vid],
-                n_steps=n_steps, verify_outputs=not synthesized,
-                det_device=det_device)
-            restore_bytes += rec.plan_restore_nbytes(plan)
-            # Fast path: replay dispatches only — output-cut verification
-            # and the consumed total ride the final packed read.
-            result = mgr.run_replay(plan, defer_sync=fast)
-            if not result.deferred:
-                total_records += result.records_replayed
-            # Re-fire recovered timer effects (rows are already spliced
-            # into the rebuilt log; only the callback side-effects re-run —
-            # reference LogReplayerImpl.triggerAsyncEvent:102).
-            svc = self.timer_services.get(flat)
-            if svc is not None and not drill:
-                for _step_i, ad in result.async_events:
-                    if isinstance(ad, det.TimerTriggerDeterminant):
-                        svc.refire(ad)
-            # Transactional sink: its pending transaction shards died with
-            # the task — rebuild them from the replayed outputs BEFORE any
-            # commit can run (2PC abort+regenerate; TwoPhaseCommitSink
-            # recoverAndAbort analog).
-            if vid in self.txn_logs and n_steps > 0:
-                self.txn_logs[vid].drop_uncommitted_shards(sub)
-                self._rebuild_txn_shards(vid, sub, result, from_epoch,
-                                         fence, n_steps)
-            chain.switch("patch")
-
-            rebuilt = np.asarray(result.rebuilt_log_rows)
-            # The regenerated determinant rows must equal the recovered ones
-            # (bit-identical replay; reference post-replay log asserts).
-            # Skipped when rebuilt IS the recovered buffer (clean path):
-            # verify() already established the only re-derived lane
-            # (BUFFER_BUILT) matches, and comparing a view against itself
-            # would be dead work masquerading as a check.
-            if not synthesized and not result.rebuilt_is_view \
-                    and not np.array_equal(
-                        rebuilt, rows[: rebuilt.shape[0]]):
-                raise rec.RecoveryError(
-                    f"subtask {flat}: replayed determinant stream diverges "
-                    f"from the recovered log")
-
-            if pre_patch_join is not None:
-                # Bootstrap's ledger-derivation thread must land before
-                # _patch reads roll_gap_async; the blocked remainder is
-                # the non-overlapped listener-reattach cost (the rest
-                # rode inside the replay window above).
-                chain.switch("finalize.listener-reattach")
-                pre_patch_join()
-                chain.switch("patch")    # the wait is not the patch's
-                pre_patch_join = None
-            patched = self._patch(patched, snap, vid, sub, flat,
-                                  result, rebuilt, from_epoch, fence,
-                                  n_steps, replica_src=r_best,
-                                  det_n=clean_n,
-                                  clean_sync=det_device is not None,
-                                  ck_head=(int(ck_heads[flat])
-                                           if ck_heads is not None
-                                           else None))
-        chain.switch("replica_rebuild")
-
-        # Replica rows held by revived subtasks: replicas are identical to
-        # their owner's log by construction (same bulk appends), so rebuild
-        # by copying the owner's (possibly just-restored) log row — one
-        # batched scatter for the whole failure set.
-        rs, os_ = [], []
-        for flat in failed:
-            for r in self.plan.replicas_held_by(flat):
-                rs.append(r)
-                os_.append(self.plan.pairs[r][0])
-        # Fixed-size scatters (padded with out-of-range rows, mode=drop)
-        # so one prewarmed program serves every failure-set size.
-        n = self.REPLICA_COPY_ROWS
-        for lo in range(0, len(rs), n):
-            rs_p = np.full((n,), self.plan.num_replicas, np.int32)
-            os_p = np.zeros((n,), np.int32)
-            rs_p[:len(rs[lo:lo + n])] = rs[lo:lo + n]
-            os_p[:len(os_[lo:lo + n])] = os_[lo:lo + n]
-            patched = patched._replace(replicas=self._replica_copy_fn()(
-                patched.replicas, patched.logs,
-                jnp.asarray(rs_p), jnp.asarray(os_p)))
-
-        self.executor.carry = patched
-        self._bounds_cache = None
-        self._route_cache = {}     # free the held routed device buffers
-
-        # ---- final packed read: completion barrier + deferred asserts ----
-        # ONE device->host transfer closes the protocol: the restored log
-        # heads (graft landed), the ring bounds recovery routed against,
-        # and for every fast-path subtask its parse/meta metadata, its
-        # on-device output-cut verification flag, and its consumed total.
-        # TPU programs execute in dispatch order, so this read — dispatched
-        # last — is also the barrier the old device_sync(patched) was.
-        # Sub-attribution: ``finalize.barrier-read`` = the packed
-        # concatenate + d2h transfer (dispatch-order barrier: it pays
-        # for every program still in flight), ``finalize.state-verify``
-        # = the host-side deferred asserts. The transfer drains on a
-        # worker thread while the main thread runs the audit validator
-        # inside the same window; the sub-spans keep their true walls
-        # and ``finalize.overlap-saved`` carries the credit, so
-        # sum(finalize.*) - overlap-saved == finalize (overlap
-        # attributed, never hidden). The join + deferred asserts run
-        # before recover() returns — a mis-speculated fast-path replay
-        # raises here, before any live step, with the audit validator
-        # as an independent gate on the replayed state. Revive
-        # bookkeeping runs after verify: a failed barrier/verify leaves
-        # the subtasks marked dead so the failure is retryable, never
-        # silently "healthy".
-        # ``finalize`` is the chain's last span; its children below use
-        # their own spans (the barrier's on whichever thread drains it).
-        chain.switch("finalize")
-        fin_span = tr.current_span()
-        fin_before = phases.get("finalize", 0.0)
-        fast_mgrs = [m for m in managers if prep[m.flat_subtask]["fast"]]
-        with tr.span("recovery.finalize.barrier-dispatch",
-                     drill=drill) as disp:
-            fl_d = jnp.asarray(list(failed), jnp.int32)
-            pieces = [patched.logs.head[fl_d].astype(jnp.int32)]
-            if nrings:
-                pieces.append(bounds_dev.reshape(-1).astype(jnp.int32))
-            for m in fast_mgrs:
-                pf = prep[m.flat_subtask]
-                pieces += [
-                    pf["small_d"].astype(jnp.int32),
-                    pf["meta_d"].reshape(-1).astype(jnp.int32),
-                    m.result.verify_ok_d.astype(jnp.int32).reshape(1),
-                    m.result.consumed_d.astype(jnp.int32).reshape(1)]
-            packed_f = jnp.concatenate(pieces)        # dispatch only
-        phases["finalize.barrier-dispatch"] = (
-            phases.get("finalize.barrier-dispatch", 0.0) + disp.ms)
-        barrier: Dict[str, Any] = {"arr": None, "err": None, "span": None}
-
-        def _drain_barrier(parent) -> None:
-            with tr.attach(parent):
-                with tr.span("recovery.finalize.barrier-read",
-                             drill=drill) as sp:
-                    try:
-                        barrier["arr"] = np.asarray(packed_f)
-                    except Exception as err:  # surfaces at the join below
-                        barrier["err"] = err
-            barrier["span"] = sp
-
-        def _verify(arr_f: np.ndarray) -> int:
-            verified_records = 0
-            off_f = len(failed)
-            heads_after = arr_f[:off_f]
-            if nrings:
-                bounds_np = arr_f[off_f: off_f + nrings * 2].reshape(
-                    nrings, 2)
-                off_f += nrings * 2
-                if self._ring_mirror_valid:
-                    for ri in range(nrings):
-                        want = (self._ring_tail_mirror,
-                                len(self.executor.step_input_history))
-                        got = (int(bounds_np[ri, 0]),
-                               int(bounds_np[ri, 1]))
-                        if got != want:
-                            raise rec.RecoveryError(
-                                f"ring {ri}: host bound mirror {want} "
-                                f"diverges from device bounds {got} — "
-                                f"recovery routed against wrong "
-                                f"coverage; state suspect")
-            want_n = DETS_PER_STEP * n_steps
-            for m in fast_mgrs:
-                flat_m = m.flat_subtask
-                pf = prep[flat_m]
-                ck_head_m = int(ck_heads[flat_m])
-                small_np = arr_f[off_f: off_f + 4]
-                off_f += 4
-                nh = len(pf["holders"])
-                meta_np = arr_f[off_f: off_f + 2 * nh].reshape(nh, 2)
-                off_f += 2 * nh
-                ok_f = int(arr_f[off_f])
-                consumed_f = int(arr_f[off_f + 1])
-                off_f += 2
-                if (tuple(int(x) for x in small_np)
-                        != (want_n, ck_head_m, n_steps, 1)):
-                    raise rec.RecoveryError(
-                        f"subtask {flat_m}: host-derived clean stream "
-                        f"(n={want_n}, start={ck_head_m}, "
-                        f"anchors={n_steps}) contradicted by device "
-                        f"parse {[int(x) for x in small_np]} — "
-                        f"async-row ledger or fence-head cache is "
-                        f"wrong; state suspect")
-                for j in range(nh):
-                    if (int(meta_np[j, 0]), int(meta_np[j, 1])) \
-                            != (want_n, ck_head_m):
-                        raise rec.RecoveryError(
-                            f"subtask {flat_m}: replica holder {j} "
-                            f"metadata {meta_np[j].tolist()} disagrees "
-                            f"with ({want_n}, {ck_head_m}) — replicas "
-                            f"inconsistent")
-                if int(heads_after[list(failed).index(flat_m)]) \
-                        != ck_head_m + want_n:
-                    raise rec.RecoveryError(
-                        f"subtask {flat_m}: restored log head "
-                        f"{int(heads_after[list(failed).index(flat_m)])}"
-                        f" != fence head {ck_head_m} + {want_n} rows")
-                if not ok_f:
-                    # Resolve the device arrays and let verify() build
-                    # the detailed divergence message (failure path: the
-                    # extra transfer is fine).
-                    m.result.emit_counts = np.asarray(m.result.emit_counts)
-                    m.result.expected_emits = np.asarray(
-                        m.result.expected_emits)
-                    try:
-                        m.result.verify()
-                    except rec.RecoveryError as err:
-                        raise rec.RecoveryError(
-                            f"subtask {flat_m}: {err}") from None
-                    raise rec.RecoveryError(
-                        f"subtask {flat_m}: device verify flag tripped "
-                        f"but host recheck passed — flag/stream mismatch")
-                m.result.records_replayed = consumed_f
-                verified_records += consumed_f
-            return verified_records
-
-        def _revive() -> None:
-            for flat in failed:
-                self.heartbeats.revive(flat)
-            self.failed.clear()
-            if not drill:
-                self.coordinator.reset_interval()
-
-        def _audit():
-            # Audit validation (obs/audit.py): recompute every replayed
-            # closed epoch's digest from the patched carry and compare
-            # against the sealed ledger — one match/divergence instant
-            # per epoch lands under this recovery's trace id. Abort
-            # policy raises AuditDivergenceError here: fail loudly
-            # before the job resumes on state that did not reproduce
-            # the original execution.
-            # Returns its span (None with the audit off).
-            if not self.auditor.enabled:
-                return None
-            with tr.span("recovery.audit", drill=drill) as sp:
-                validator = rec.AuditValidator(
-                    self.executor, self.coordinator.read_ledger(),
-                    on_divergence=self.auditor.on_divergence)
-                try:
-                    validator.validate(
-                        range(from_epoch, self.executor.epoch_id))
-                finally:
-                    # evidence reaches the metrics plane even when the
-                    # abort policy throws mid-validation
-                    self._m_audit_matches.inc(validator.stats["match"])
-                    self._m_audit_div.inc(validator.stats["divergence"])
-            phases["audit"] = phases.get("audit", 0.0) + sp.ms
-            return sp
-
-        audit_span = None
-        audit_err: Optional[Exception] = None
-        th = threading.Thread(target=_drain_barrier, args=(fin_span,),
-                              name="recovery-finalize-barrier")
-        th.start()
-        # Host-side finalize work folded into the barrier window: the
-        # audit validator's digest recompute reads the same patched
-        # carry the packed read waits on (its transfers interleave with
-        # the barrier d2h instead of queuing after it). Revive
-        # bookkeeping does NOT fold in: it must stay after the join +
-        # state-verify below — if the packed read or a deferred assert
-        # raises, self.failed and the heartbeat table must still mark
-        # the subtasks dead so a retry of recover() sees them. An audit
-        # divergence is held and re-raised after verify (a verify
-        # failure wins), and the join runs unconditionally so the
-        # barrier thread never outlives this call.
-        try:
-            audit_span = _audit()
-        except Exception as err:
-            audit_err = err
-        finally:
-            # KeyboardInterrupt/SystemExit skip the deferral but
-            # still land here: the thread never leaks.
-            th.join()
-        if barrier["err"] is not None:
-            raise barrier["err"]
-        read = barrier["span"]
-        phases["finalize.barrier-read"] = (
-            phases.get("finalize.barrier-read", 0.0) + read.ms)
-        with tr.span("recovery.finalize.state-verify", drill=drill) as sp:
-            total_records += _verify(barrier["arr"])
-        verify_ms = sp.ms
-        phases["finalize.state-verify"] = (
-            phases.get("finalize.state-verify", 0.0) + verify_ms)
-        chain.close()
-        # ``finalize`` and ``finalize.overlap-saved`` are derived from
-        # the sub-spans' own stamps, not from the window's wall, so
-        # sum(finalize.*) - overlap-saved == finalize holds exactly: the
-        # barrier read is on the critical path only for the part that
-        # ran after the audit (the main thread's work in the window)
-        # had ended; what ran under the audit is the saving. The audit
-        # has its own key.
-        exposed_ms = _exposed_ms(
-            read.mono, read.mono + read.dur,
-            read.mono if audit_span is None
-            else audit_span.mono + audit_span.dur)
-        phases["finalize"] = fin_before + disp.ms + exposed_ms + verify_ms
-        # Verify passed, NOW the subtasks may be marked healthy; a held
-        # audit divergence propagates after revive.
-        _revive()
-        if audit_err is not None:
-            raise audit_err
-        phases["finalize.overlap-saved"] = (
-            phases.get("finalize.overlap-saved", 0.0)
-            + read.ms - exposed_ms)
-        report = RecoveryReport(
-            failed_subtasks=failed, from_epoch=from_epoch,
-            steps_replayed=n_steps, determinants_replayed=total_dets,
-            records_replayed=total_records,
-            ignored_checkpoints=ignored,
-            recovery_ms=(_time.monotonic() - t0) * 1e3,
-            managers=tuple(managers), phase_ms=phases, drill=drill,
-            restore_bytes=restore_bytes, checkpoint_bytes=checkpoint_bytes)
-        if not drill:
-            # Rehearsals must not inflate the recovery count/latency
-            # series operators alert on.
-            self.reports.append(report)
-            self._m_recovery_ms.update(report.recovery_ms)
-            self._m_recovered_records.inc(report.records_replayed)
-            # Per-phase latency distributions (recovery.replay-ms p50/p99
-            # etc.) — the tuning surface for the paper's headline claim.
-            for pname, ms in phases.items():
-                self._mgroup.histogram(f"recovery.{pname}-ms").update(ms)
-        return report
-
-    def prewarm_recovery(self, vertex_ids: Optional[Sequence[int]] = None,
-                         spill_paths: bool = False) -> float:
-        """Compile every recovery program a standby will need, at job
-        start — the reference keeps standby tasks *deployed* so failover
-        only switches them to RUNNING (Task.java:300-302, :1040,
-        Execution.java:373-377 state re-dispatch); the TPU analog of
-        "deployed" is "XLA-compiled": after this, the failure path runs
-        entirely on cached executables (recovery-time-to-resume drops from
-        minutes of compile to milliseconds of replay).
-
-        Requires ``num_standby >= 1`` (the knob that buys warm failover).
-        Returns wall-clock seconds spent compiling. For vertices whose
-        input edge is statically routed the replay program is specialized
-        per subtask; all subtasks are prewarmed.
-        """
-        if self.standbys.num_standby_per_vertex < 1:
-            raise rec.RecoveryError(
-                "prewarm_recovery needs num_standby >= 1 (no standby "
-                "programs requested)")
-        t0 = _time.monotonic()
-        from clonos_tpu.api.operators import TwoInputOperator
-        from clonos_tpu.api.records import RecordBatch as RB
-        ch = self._chunk()
-        carry = self.executor.carry
-        compiled = self.executor.compiled
-        zero = lambda shape, dt=jnp.int32: jnp.zeros(shape, dt)
-
-        def zero_batch(lead):
-            return RB(zero(lead), zero(lead), zero(lead),
-                      zero(lead, jnp.bool_))
-
-        # Fetch + replica copy + ring bounds + replica-sourced log restore.
-        if compiled.plan.num_replicas > 0:
-            self._fetch_fn()(carry.replicas, jnp.asarray(0, jnp.int32),
-                             jnp.asarray(0, jnp.int32))
-            self._device_parse_fn()(carry.replicas,
-                                    jnp.asarray(0, jnp.int32),
-                                    jnp.asarray(0, jnp.int32))
-            holders_per_owner = {}
-            for (o, _h) in compiled.plan.pairs:
-                holders_per_owner[o] = holders_per_owner.get(o, 0) + 1
-            for h in sorted(set(holders_per_owner.values())):
-                self._fetch_meta_fn(h)(carry.replicas, zero((h,)),
-                                       jnp.asarray(0, jnp.int32))
-            self._log_restore_from_replica_fn()(
-                carry.replicas, jnp.asarray(0, jnp.int32),
-                jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
-                jnp.asarray(0, jnp.int32), zero((compiled.max_epochs,)),
-                zero((compiled.max_epochs,), jnp.bool_),
-                jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32))
-            # Donated arg: compiled against the live carry, not run (see
-            # the whole-carry programs below).
-            n = self.REPLICA_COPY_ROWS
-            self._replica_copy_fn().lower(
-                carry.replicas, carry.logs, zero((n,)), zero((n,))
-            ).compile()
-        if carry.out_rings:
-            self._ring_bounds()
-        # Shared log-restore programs.
-        st = clog.create(compiled.log_capacity, compiled.max_epochs)
-        st = self._log_restore_fn()(
-            zero((ch * DETS_PER_STEP, det.NUM_LANES)),
-            jnp.asarray(0, jnp.int32), st)
-        self._log_finalize_fn()(
-            st, zero((compiled.max_epochs,)),
-            zero((compiled.max_epochs,), jnp.bool_),
-            jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32))
-
-        vids = (list(vertex_ids) if vertex_ids is not None
-                else [v.vertex_id for v in self.job.vertices])
-        # Independent compiles run CONCURRENTLY: each job below first-calls
-        # one jit program; XLA compilations of distinct programs proceed in
-        # parallel across threads (the executions they also trigger are
-        # tiny and serialize on the device queue). This roughly divides
-        # prewarm wall-clock by min(#workers, #independent programs).
-        jobs: List[Any] = []
-        z = jnp.asarray(0, jnp.int32)
-        nrp = max(compiled.plan.num_replicas, 1)
-
-        def _edge_jobs(vid: int) -> None:
-            v = self.job.vertices[vid]
-            in_edges = self.job.in_edges(vid)
-            # Ring/route/concat programs for each input edge.
-            for eidx in in_edges:
-                e = self.job.edges[eidx]
-                src_p = self.job.vertices[e.src].parallelism
-                src_cap = compiled.vertex_out_capacity(e.src)
-                ri = compiled.ring_index[e.src]
-                el = carry.out_rings[ri]
-                z = jnp.asarray(0, jnp.int32)
-                # Uniform [ch] replay windows: ONE shape per edge (the
-                # old first-chunk ch-1 variants doubled these compiles).
-                # Both routing variants: fused lane (single failure) and
-                # all-lane + select (connected-failure sharing).
-                jobs.append(lambda eidx=eidx, el=el, z=z:
-                            self._route_chunk_fn(eidx, ch)(
-                                el, z, z, z, z, z))
-
-                def _all_lane(eidx=eidx, el=el, z=z):
-                    routed, *_ = self._route_chunk_fn(
-                        eidx, ch, all_lanes=True)(el, z, z, z, z)
-                    self._lane_select_fn(eidx, ch)(routed, z)
-                jobs.append(_all_lane)
-                if spill_paths:
-                    # Spill-path twin (AVAILABILITY wrap recovery):
-                    # doubles the exchange compiles, so opt-in — a
-                    # ring-covered recovery (the common case) never
-                    # takes this path.
-                    jobs.append(lambda ri=ri, el=el, z=z:
-                                self._ring_chunk_fn(ri, ch)(el, z))
-                    jobs.append(lambda eidx=eidx, src_p=src_p,
-                                src_cap=src_cap, z=z:
-                                self._route_raw_fn(eidx, ch)(
-                                    zero_batch((ch, src_p, src_cap)),
-                                    z, z, z, z, z))
-                    jobs.append(lambda eidx=eidx, src_p=src_p,
-                                src_cap=src_cap, z=z:
-                                self._route_raw_fn(
-                                    eidx, ch, all_lanes=True)(
-                                    zero_batch((ch, src_p, src_cap)),
-                                    z, z, z, z))
-                jobs.append(lambda eidx=eidx, e=e:
-                            self._first_chunk_fn(eidx)(
-                                zero_batch((1, e.capacity)),
-                                zero_batch((ch, e.capacity))))
-
-        def _vertex_jobs(vid: int) -> None:
-            v = self.job.vertices[vid]
-            in_edges = self.job.in_edges(vid)
-            _edge_jobs(vid)
-            # Replay block program(s).
-            slot_keys = compiled.consumer_slot_keys(vid)
-            subs = range(v.parallelism) if slot_keys is not None else [0]
-            in_cap = (self.job.edges[in_edges[0]].capacity if in_edges
-                      else compiled.vertex_out_capacity(vid))
-            state0 = jax.tree_util.tree_map(
-                lambda x: x[0][None], carry.op_states[vid])
-            if isinstance(v.operator, TwoInputOperator):
-                cap2 = self.job.edges[in_edges[1]].capacity
-                chunk0 = (zero_batch((ch, in_cap)), zero_batch((ch, cap2)))
-            else:
-                chunk0 = zero_batch((ch, in_cap))
-
-            def _replay_job(sub, state0=state0, chunk0=chunk0):
-                rp = self._make_replayer(vid, sub)
-                rp._jit_block(state0, chunk0, zero((ch,)), zero((ch,)),
-                              jnp.asarray(sub, jnp.int32),
-                              jnp.zeros((), jnp.int32))
-                # tslice serves the pad-fixed stream length (the shape
-                # every failure uses; see LogReplayer.pad_steps).
-                rp._jit_tslice(zero((rp.pad_steps or ch,)),
-                               jnp.asarray(0, jnp.int32))
-            for sub in subs:
-                jobs.append(lambda sub=sub: _replay_job(sub))
-            # Whole-carry programs (graft / kill / ring write) take the
-            # carry DONATED, so executing them here would need a second,
-            # disposable carry — at the headline deployment that is
-            # 5.24 GiB next to the live 5.24 GiB, the all-lane route
-            # programs' ~2 GB each and the kill program's 1.5 GB of
-            # scratch, on a 16 GB chip. Lowering + compiling against the
-            # LIVE carry allocates nothing and donates nothing, and the
-            # executable it leaves in the jit's cache is the one the
-            # failure path dispatches.
-            jobs.append(lambda: self._graft_fn(vid).lower(
-                carry, state0, st, z, z, z).compile())
-            jobs.append(lambda: self._inject_fn(vid).lower(
-                carry, z, z, jnp.full((nrp,), nrp, jnp.int32)).compile())
-            if vid in compiled.ring_index:
-                ri = compiled.ring_index[vid]
-                jobs.append(lambda: self._ring_write_fn(ri, ch).lower(
-                    carry.out_rings[ri],
-                    zero_batch((ch, compiled.vertex_out_capacity(vid))),
-                    z, z, z, z).compile())
-
-        for vid in vids:
-            _vertex_jobs(vid)
-
-        from concurrent.futures import ThreadPoolExecutor
-        with ThreadPoolExecutor(max_workers=4) as pool:
-            for res in pool.map(lambda j: j(), jobs):
-                pass
-        # AOT-lower the standby's first-step (block) program into the
-        # persistent compile cache too. A rehydrated standby's first
-        # dispatch after restore is then a cache hit, not a recompile
-        # in the finalize tail; a program that does not compile fails
-        # the prewarm here, not the failover later.
-        from clonos_tpu.utils.compile_cache import aot_lower_first_step
-        aot_lower_first_step(self.executor, self._mgroup)
-        return _time.monotonic() - t0
-
-    def failover_drill(self, flats: Optional[Sequence[int]] = None
-                       ) -> float:
-        """Rehearse a failover end-to-end and return its wall-clock
-        seconds: inject a failure, run the full recovery protocol, and
-        rely on bit-identical recovery to leave the job state canonically
-        unchanged (executor.canonical_carry: live log/ring content equal;
-        physically-dead pre-fence slots may differ — nothing ever reads
-        them). The reference's RunStandbyTaskStrategy keeps standby
-        executions *running* (Task.java:300-302, Execution.java:373-377),
-        so their whole failure path is hot; compiling programs
-        (prewarm_recovery) is necessary but not sufficient for that — the
-        first execution still pays allocator growth, transfer-path and
-        host-pool warmup. One drill moves all of it off the real failure
-        path.
-
-        Default drill set: one subtask of every vertex class, failed
-        together (a connected multi-class failure exercises every class's
-        replay program and the staged topological recovery)."""
-        if self.failed:
-            raise rec.RecoveryError("cannot drill with real failures "
-                                    "pending")
-        if not self.standbys.has_state():
-            raise rec.RecoveryError(
-                "failover_drill needs a completed checkpoint")
-        t0 = _time.monotonic()
-        fence = self._fence_step[self.standbys.latest.checkpoint_id + 1]
-        if self.global_step == fence:
-            import warnings
-            warnings.warn(
-                "failover_drill at an epoch fence replays zero steps; "
-                "run it mid-epoch so the chunked replay path executes")
-        if flats is None:
-            flats = [self.job.subtask_base(v.vertex_id)
-                     for v in self.job.vertices]
-        flats = list(flats)
-        # The drill must NEVER corrupt a healthy job: verify every drilled
-        # log has a surviving replica holder BEFORE zeroing any device
-        # state (recover() makes the same check, but only after the
-        # injection has already destroyed the state it needs).
-        if self.global_step > fence:
-            fset = set(flats)
-            for flat in flats:
-                vid, _ = self._vertex_of(flat)
-                if not self.job.out_edges(vid):
-                    continue       # sinks synthesize; no holder needed
-                if not any(o == flat and h not in fset
-                           for (o, h) in self.plan.pairs):
-                    raise rec.RecoveryError(
-                        f"failover_drill: subtask {flat} would have no "
-                        f"surviving determinant replica under drill set "
-                        f"{sorted(fset)} — drill fewer subtasks at once "
-                        f"or deepen sharing/replication")
-            # Input reconstruction needs the whole replay window in the
-            # upstream rings (or spill): check BEFORE zeroing state too.
-            n_steps = self.global_step - fence
-            if (n_steps > self.executor.compiled.inflight_ring_steps
-                    and self.executor.spill_logs is None):
-                raise rec.RecoveryError(
-                    f"failover_drill: {n_steps} steps since the last "
-                    f"completed checkpoint exceed the in-flight ring "
-                    f"({self.executor.compiled.inflight_ring_steps} "
-                    f"steps) and spill is disabled — drill earlier or "
-                    f"enable spill")
-        self.inject_failure(flats)
-        self.recover(drill=True)
-        return _time.monotonic() - t0
-
-    def _rebuild_txn_shards(self, vid: int, sub: int,
-                            result: rec.ReplayResult, from_epoch: int,
-                            fence: int, n_steps: int) -> None:
-        """Reconstruct the failed sink subtask's pending transaction
-        shards from its replayed output chunks, epoch by epoch."""
-        tl = self.txn_logs[vid]
-        chunks = [jax.tree_util.tree_map(np.asarray, c)
-                  for c in (result.out_chunks or [])]
-
-        def steps_slice(lo: int, hi: int) -> np.ndarray:
-            rows = []
-            for i, c in enumerate(chunks):
-                ch_n = c.keys.shape[0]
-                base = i * self._chunk()
-                a = max(lo, base)
-                b = min(hi, base + ch_n)
-                for s in range(a, b):
-                    m = c.valid[s - base]
-                    if m.any():
-                        rows.append(np.stack(
-                            [c.keys[s - base][m], c.values[s - base][m],
-                             c.timestamps[s - base][m]], axis=1))
-            return (np.concatenate(rows, axis=0) if rows
-                    else np.zeros((0, 3), np.int32))
-
-        cur = self.executor.epoch_id
-        for e in range(from_epoch, cur + 1):
-            if e not in self._fence_step:
-                continue
-            lo = self._fence_step[e] - fence
-            hi = (self._fence_step.get(e + 1, fence + n_steps) - fence
-                  if e < cur else n_steps)
-            tl.rebuild_shard(e, sub, steps_slice(lo, min(hi, n_steps)))
-
-    # --- input reconstruction ------------------------------------------------
-
-    def _ring_steps(self, patched: JobCarry, src_vid: int, start: int,
-                    n: int, need: Optional[int] = None):
-        """Raw output steps [start, start+n) of a producer vertex, from the
-        device ring — falling back to the host spill for steps the ring no
-        longer retains (reference SpilledReplayIterator.java:61).
-
-        ``need``: how many leading steps must actually be present
-        (default n). With need < n the returned [n]-shaped batch may hold
-        dead entries past ``need`` — chunked replay reads fixed-size
-        [CH] windows whose tail can extend past the ring head."""
-        if need is None:
-            need = n
-        compiled = self.executor.compiled
-        ri = compiled.ring_index[src_vid]
-        el = patched.out_rings[ri]
-        # Coverage math from the bounds cache (one read per recover();
-        # ring offsets are stable across recovery — write-backs replace
-        # contents only), so the fast path costs zero host round-trips.
-        if getattr(self, "_bounds_cache", None) and ri in self._bounds_cache:
-            tail, head = self._bounds_cache[ri]
-        else:
-            tail, head = int(el.tail), int(el.head)
-        got_start = max(start, tail)
-        cnt = max(min(head - got_start, n), 0)
-        # Steps physically retained by the ring: slice_steps only clamps to
-        # ``tail``, but when checkpoints stall past ring capacity newer
-        # appends have clobbered positions of steps < head - ring_steps —
-        # those must come from the spill even though tail hasn't advanced.
-        ring_lo = max(tail, head - el.ring_steps)
-        batch, _, _ = self._ring_chunk_fn(ri, n)(
-            el, jnp.asarray(start, jnp.int32))
-        if got_start == start and start >= ring_lo and cnt >= need:
-            return batch
-        # Ring shortfall: pull the missing leading steps from the spill.
-        if self.executor.spill_logs is None:
-            raise rec.RecoveryError(
-                f"in-flight log of vertex {src_vid} lost steps "
-                f"[{start}, {max(got_start, ring_lo)}) and spill is disabled")
-        spill = self.executor.spill_logs[ri]
-        boundary = min(start + n, max(got_start, ring_lo))
-        required_end = min(start + need, boundary)
-        parts = []
-        have = start
-        # Prefetching epoch reads (reference SpilledReplayIterator.java:61
-        # — async reads run ahead of consumption).
-        eps = spill.retained_epochs()
-        if eps:
-            it = ifl.ReplayIterator(spill, eps[0], eps[-1])
-            try:
-                for ep_start, ep_batch in it.epochs():
-                    ep_n = ep_batch.keys.shape[0]
-                    lo = max(have, ep_start)
-                    hi = min(ep_start + ep_n, boundary)
-                    if hi > lo:
-                        parts.append(jax.tree_util.tree_map(
-                            lambda x: x[lo - ep_start: hi - ep_start],
-                            ep_batch))
-                        have = hi
-                    if have >= boundary:
-                        break
-            except (SegmentCorruptError, StorageError) as e:
-                # Torn/corrupt/missing segment on refill: surface as a
-                # labeled recovery failure, never as garbage replay bytes
-                # (satellite: spill-file durability).
-                raise rec.RecoveryError(
-                    f"vertex {src_vid}: tiered refill failed — {e}") from e
-            finally:
-                it.close()
-        if have < required_end:
-            raise rec.RecoveryError(
-                f"vertex {src_vid}: spill does not cover steps "
-                f"[{have}, {required_end})")
-        if have < boundary:
-            # Dead filler past the needed range (fixed-shape chunk reads).
-            ref = parts[0] if parts else batch
-            parts.append(jax.tree_util.tree_map(
-                lambda x: jnp.zeros((boundary - have,) + x.shape[1:],
-                                    x.dtype), ref))
-        if boundary < start + n:
-            parts.append(jax.tree_util.tree_map(
-                lambda x: x[boundary - got_start: start + n - got_start],
-                batch))
-        out = jax.tree_util.tree_map(
-            lambda *xs: jnp.concatenate(xs, axis=0), *parts)
-        if out.keys.shape[0] != n:
-            raise rec.RecoveryError(
-                f"vertex {src_vid}: reconstructed {out.keys.shape[0]} of "
-                f"{n} in-flight steps")
-        return out
-
-    def _replay_inputs(self, patched: JobCarry, snap: LeanSnapshot,
-                       eidx: int, sub: int, fence: int, n_steps: int):
-        """The failed consumer's lost inputs on edge ``eidx``: the
-        checkpointed depth-1 edge buffer (its input at the first lost step)
-        followed by the upstream's ring outputs [fence, fence+n-1), routed
-        through the deterministic exchange.
-
-        Returns a LIST of block-sized chunks ([CH, cap] each; the last
-        covers the tail) so every device program here is fixed-shape and
-        prewarm-compiled — recovery pays no XLA compile (warm standby)."""
-        e = self.job.edges[eidx]
-        ch = self._chunk()
-        compiled = self.executor.compiled
-        ri = compiled.ring_index[e.src]
-        first = jax.tree_util.tree_map(
-            lambda x: x[sub][None], snap.edge_bufs[eidx])
-        if n_steps <= 0:
-            return []
-        el = patched.out_rings[ri]
-        if self._bounds_cache and ri in self._bounds_cache:
-            tail, head = self._bounds_cache[ri]
-        else:
-            tail, head = int(el.tail), int(el.head)
-        ring_lo = max(tail, head - el.ring_steps)
-        # Uniform [ch] windows: window i covers absolute steps
-        # [fence-1+i*ch, fence-1+(i+1)*ch). Window slot j (global) holds
-        # step fence-1+j; slot 0 is dead (pre-fence) — masked by ``lead``
-        # and replaced with the checkpointed edge buffer. One compiled
-        # program per edge serves every chunk (prewarm halved vs the old
-        # first-chunk (ch-1) shape variants). Loop state lives ON DEVICE
-        # (no host scalar put per chunk); coverage decisions use the host
-        # bounds.
-        start_d = jnp.asarray(fence - 1, jnp.int32)
-        sub_d = jnp.asarray(sub, jnp.int32)
-        rr_d = jnp.asarray(snap.rr_offsets[eidx][0], jnp.int32)
-        need_d = jnp.asarray(n_steps, jnp.int32)
-        lead_d = jnp.asarray(1, jnp.int32)
-        chunks = []
-        nblocks = -(-n_steps // ch)
-        for i in range(nblocks):
-            h_start = fence - 1 + i * ch
-            # Real ring steps this window must provide (its live slots).
-            lo_real = max(h_start, fence)
-            hi_real = min(h_start + ch, fence - 1 + n_steps)
-            h_need = max(hi_real - lo_real, 0)
-            covered = (lo_real >= ring_lo and lo_real >= tail
-                       and head - lo_real >= h_need)
-            share = self._route_cache_enabled
-
-            def raw_window():
-                # Spill-backed window, shaped like the ring window: pull
-                # the real steps from ring+spill and shift window 0 down
-                # one slot (its dead leading slot carries no step).
-                raw = self._ring_steps(patched, e.src, lo_real, ch,
-                                       need=h_need)
-                if i == 0:
-                    raw = jax.tree_util.tree_map(
-                        lambda x: jnp.roll(x, 1, axis=0).at[0].set(
-                            jnp.zeros_like(x[0])), raw)
-                return raw
-
-            if not share:
-                # Single failed consumer: the fused variant scatters only
-                # this lane's rows (~P times cheaper than materializing
-                # the whole routed block).
-                if covered:
-                    lane, start_d, rr_d, need_d, lead_d = \
-                        self._route_chunk_fn(eidx, ch)(
-                            el, start_d, sub_d, rr_d, need_d, lead_d)
-                else:
-                    lane, start_d, rr_d, need_d, lead_d = \
-                        self._route_raw_fn(eidx, ch)(
-                            raw_window(), start_d, sub_d, rr_d, need_d,
-                            lead_d)
-            else:
-                # Multiple failed consumers: route the window once to all
-                # lanes, cache it, and lane-select per consumer
-                # (recover() scopes the cache to one vertex's group).
-                key = (eidx, i)
-                cached = self._route_cache.get(key)
-                if cached is None:
-                    if covered:
-                        routed, start_d, rr_d, need_d, lead_d = \
-                            self._route_chunk_fn(eidx, ch, all_lanes=True)(
-                                el, start_d, rr_d, need_d, lead_d)
-                    else:
-                        routed, start_d, rr_d, need_d, lead_d = \
-                            self._route_raw_fn(eidx, ch, all_lanes=True)(
-                                raw_window(), start_d, rr_d, need_d,
-                                lead_d)
-                    self._route_cache[key] = routed
-                else:
-                    routed = cached
-                    self._route_cache_hits += 1
-                lane = self._lane_select_fn(eidx, ch)(routed, sub_d)
-            if i == 0:
-                chunks.append(self._first_chunk_fn(eidx)(first, lane))
-            else:
-                chunks.append(lane)
-        return chunks
-
-    def _reread_feed(self, vid: int, sub: int, snap: LeanSnapshot,
-                     rows: np.ndarray, n_steps: int):
-        """Rebuild a HostFeedSource's lost input batches: offset from the
-        checkpointed operator state, per-step pull counts from the recorded
-        BUFFER_BUILT determinants, records from the rewindable reader.
-        Returns block-sized chunks (zero-padded tail) like
-        :meth:`_replay_inputs`."""
-        reader = self.executor.feed_readers.get(vid)
-        if reader is None:
-            raise rec.RecoveryError(
-                f"vertex {vid}: HostFeedSource has no registered feed "
-                f"reader to re-read from")
-        v = self.job.vertices[vid]
-        b = v.operator.batch_size
-        anchors = det.sync_anchors(rows)[:n_steps]
-        counts = rows[anchors + 3, det.LANE_P].astype(np.int64)
-        offset = int(np.asarray(snap.op_states[vid]["offset"][sub]))
-        ch = self._chunk()
-        padded = -(-n_steps // ch) * ch
-        keys = np.zeros((padded, b), np.int32)
-        vals = np.zeros((padded, b), np.int32)
-        valid = np.zeros((padded, b), bool)
-        for i, c in enumerate(counts):
-            ks, vs = reader.read_at(sub, offset, int(c))
-            keys[i, :int(c)], vals[i, :int(c)] = ks, vs
-            valid[i, :int(c)] = True
-            offset += int(c)
-        from clonos_tpu.api.records import RecordBatch as RB
-        zts = np.zeros((padded, b), np.int32)
-        return [RB(jnp.asarray(keys[lo:lo + ch]),
-                   jnp.asarray(vals[lo:lo + ch]),
-                   jnp.asarray(zts[lo:lo + ch]),
-                   jnp.asarray(valid[lo:lo + ch]))
-                for lo in range(0, padded, ch)]
-
-    def _synthesize_det_rows(self, fence_global: int,
-                             n_steps: int) -> np.ndarray:
-        """Rebuild a sink's per-step determinant rows from the executor's
-        step-input ledger (times/rng draws for the lost steps). BUFFER_BUILT
-        payloads are placeholders — the replayer fills real emit counts into
-        the rebuilt rows."""
-        hist = self.executor.step_input_history[fence_global:
-                                                fence_global + n_steps]
-        if len(hist) < n_steps:
-            raise rec.RecoveryError("step-input ledger shorter than the "
-                                    "lost step range")
-        rows = np.zeros((n_steps * DETS_PER_STEP, det.NUM_LANES), np.int32)
-        for i, (t, r) in enumerate(hist):
-            base = i * DETS_PER_STEP
-            rows[base, det.LANE_TAG] = det.TIMESTAMP
-            rows[base, det.LANE_P] = -1 if t < 0 else 0
-            rows[base, det.LANE_P + 1] = t
-            rows[base + 1, det.LANE_TAG] = det.RNG
-            rows[base + 1, det.LANE_P] = r
-            rows[base + 2, det.LANE_TAG] = det.ORDER
-            rows[base + 3, det.LANE_TAG] = det.BUFFER_BUILT
-        return rows
-
-    def _make_replayer(self, vid: int, sub: int) -> rec.LogReplayer:
-        """Standby replay program for (vertex, subtask); compiled programs
-        are cached on the operator so repeated failures (and prewarm)
-        share them."""
-        v = self.job.vertices[vid]
-        slot_keys = self.executor.compiled.consumer_slot_keys(vid)
-        compiled = self.executor.compiled
-        return rec.LogReplayer(
-            v.operator, v.parallelism, vertex_name=v.name,
-            block_steps=self._recovery_ch,
-            in_slot_keys=(slot_keys[sub:sub + 1]
-                          if slot_keys is not None else None),
-            pad_steps=compiled.inflight_ring_steps,
-            mesh=compiled.mesh, task_axis=compiled.task_axis)
-
-    def _log_restore_fn(self):
-        cap = self.executor.compiled.log_capacity
-
-        def make():
-            def f(rows_chunk, count, state):
-                return clog.append(state, rows_chunk, count)
-            return f
-        return self._jitted(("log_append",), make)
-
-    def _log_restore_from_replica_fn(self):
-        """Rebuild a failed task's log row ON DEVICE from a surviving
-        replica: the replayed determinant stream was verified equal to the
-        recovered one, so the replica's bytes ARE the restored log — no
-        host round-trip of the rows."""
-        cap = self.executor.compiled.log_capacity
-        me = self.executor.compiled.max_epochs
-
-        def make():
-            def f(replicas, r, from_epoch, used, ck_head,
-                  epoch_offs, epoch_mask, latest, base):
-                rep_one = jax.tree_util.tree_map(lambda x: x[r], replicas)
-                buf, _cnt, _start = clog.get_determinants(
-                    rep_one, from_epoch, cap)
-                st = clog.create(cap, me)
-                st = st._replace(head=ck_head, tail=ck_head)
-                st = clog.append(st, buf, used)
-                return st._replace(
-                    epoch_starts=jnp.where(epoch_mask, epoch_offs,
-                                           st.epoch_starts),
-                    latest_epoch=jnp.maximum(st.latest_epoch, latest),
-                    epoch_base=jnp.maximum(st.epoch_base, base))
-            return f
-        return self._jitted(("log_restore_replica",), make)
-
-    def _log_finalize_fn(self):
-        def make():
-            def f(state, epoch_offs, epoch_mask, latest, base):
-                starts = jnp.where(epoch_mask, epoch_offs,
-                                   state.epoch_starts)
-                return state._replace(
-                    epoch_starts=starts,
-                    latest_epoch=jnp.maximum(state.latest_epoch, latest),
-                    epoch_base=jnp.maximum(state.epoch_base, base))
-            return f
-        return self._jitted(("log_finalize",), make)
-
-    def _graft_fn(self, vid: int):
-        def make():
-            def f(carry, new_state, restored_log, sub, flat, rc):
-                ops = list(carry.op_states)
-                ops[vid] = jax.tree_util.tree_map(
-                    lambda live_x, new_x: live_x.at[sub].set(new_x[0]),
-                    ops[vid], new_state)
-                logs = jax.tree_util.tree_map(
-                    lambda s, r: s.at[flat].set(r), carry.logs,
-                    restored_log)
-                return carry._replace(
-                    op_states=tuple(ops), logs=logs,
-                    record_counts=carry.record_counts.at[flat].set(rc))
-            return f
-        # Donated: an un-donated graft copies the whole multi-GB carry
-        # (rings included) per failed subtask, thrashing the allocator.
-        return self._jitted(("graft", vid), make, donate=(0,))
-
-    def _ring_write_fn(self, ri: int, m: int):
-        """Write an [m, cap] replayed output chunk into ring ``ri`` at
-        steps [base, base+m), keeping only steps in [keep_from, hi);
-        returns (ring, base + m) so the loop cursor stays on device."""
-        def make():
-            def f(el, chunk, base, sub, keep_from, hi):
-                steps = base + jnp.arange(m, dtype=jnp.int32)
-                keep = (steps >= keep_from) & (steps < hi)
-                pos = jnp.where(keep, steps & (el.ring_steps - 1),
-                                el.ring_steps)        # OOB row -> dropped
-                return el._replace(
-                    keys=el.keys.at[pos, sub].set(chunk.keys, mode="drop"),
-                    values=el.values.at[pos, sub].set(chunk.values,
-                                                      mode="drop"),
-                    timestamps=el.timestamps.at[pos, sub].set(
-                        chunk.timestamps, mode="drop"),
-                    valid=el.valid.at[pos, sub].set(chunk.valid,
-                                                    mode="drop")), base + m
-            return f
-        return self._jitted(("ring_write", ri, m), make, donate=(0,))
-
-    def _patch(self, carry: JobCarry, snap: LeanSnapshot, vid: int,
-               sub: int, flat: int, result: rec.ReplayResult,
-               det_rows: np.ndarray, from_epoch: int, fence: int,
-               n_steps: int, replica_src: Optional[int] = None,
-               det_n: Optional[int] = None, clean_sync: bool = False,
-               ck_head: Optional[int] = None) -> JobCarry:
-        """Graft the rebuilt subtask back into the live carry. Every
-        device program here is fixed-shape (chunked appends/writes) so a
-        prewarmed standby pays zero XLA compile on the failure path.
-
-        ``clean_sync`` (device-resident determinant stream): the rows
-        never came to the host, but the stream is pure k-row sync blocks
-        so the anchors are exactly ``i * DETS_PER_STEP``; ``det_n`` is
-        its device-verified row count."""
-        compiled = self.executor.compiled
-        ch4 = self._chunk() * DETS_PER_STEP
-        if ck_head is None:
-            ck_head = int(np.asarray(snap.log_heads[flat]))
-        n = det_rows.shape[0] if det_n is None else det_n
-        # Epoch->offset index entries died with the task; rebuild them from
-        # the fence-step ledger. Sync blocks anchor at TIMESTAMP rows.
-        if clean_sync:
-            ts_pos = np.arange(n // DETS_PER_STEP,
-                               dtype=np.int64) * DETS_PER_STEP
-        elif n > 0:
-            ts_pos = det.sync_anchors(det_rows)
-        else:
-            ts_pos = np.zeros((0,), np.int64)
-        me = compiled.max_epochs
-        epoch_offs = np.zeros((me,), np.int32)
-        epoch_mask = np.zeros((me,), bool)
-        latest = 0
-        for e in range(from_epoch, self.executor.epoch_id + 1):
-            if e in self._fence_step:
-                step_i = self._fence_step[e] - fence
-                # from_epoch starts exactly at the checkpointed head (async
-                # rows appended in the roll gap come after the fence);
-                # later fences anchor at their first step's TIMESTAMP row
-                # minus the roll-gap ledger — async rows appended after
-                # the roll but before the epoch's first step (fence
-                # SOURCE_CHECKPOINTs, ignore broadcasts, between-epoch
-                # service calls) precede that anchor yet belong to the
-                # NEW epoch (executor.roll_gap_async).
-                gap = self.executor.roll_gap_async.get((flat, e), 0)
-                if step_i == 0:
-                    off = ck_head
-                elif step_i < len(ts_pos):
-                    off = ck_head + int(ts_pos[step_i]) - gap
-                else:
-                    off = ck_head + n - gap
-                epoch_offs[e % me] = off
-                epoch_mask[e % me] = True
-                latest = max(latest, e)
-        if replica_src is not None:
-            # The replayed stream was verified equal to the recovered one,
-            # so the replica's device bytes ARE the restored log (no h2d).
-            restored = self._log_restore_from_replica_fn()(
-                carry.replicas, jnp.asarray(replica_src, jnp.int32),
-                jnp.asarray(from_epoch, jnp.int32),
-                jnp.asarray(n, jnp.int32), jnp.asarray(ck_head, jnp.int32),
-                jnp.asarray(epoch_offs), jnp.asarray(epoch_mask),
-                jnp.asarray(latest, jnp.int32),
-                jnp.asarray(from_epoch, jnp.int32))
-        else:
-            # Synthesized streams (sink recovery) upload in fixed chunks.
-            restored = clog.create(compiled.log_capacity,
-                                   compiled.max_epochs)
-            base = jnp.asarray(ck_head, jnp.int32)
-            restored = restored._replace(head=base, tail=base)
-            app = self._log_restore_fn()
-            for lo in range(0, n, ch4):
-                cnt = min(ch4, n - lo)
-                chunk = np.zeros((ch4, det.NUM_LANES), np.int32)
-                chunk[:cnt] = det_rows[lo:lo + cnt]
-                restored = app(jnp.asarray(chunk),
-                               jnp.asarray(cnt, jnp.int32), restored)
-            restored = self._log_finalize_fn()(
-                restored, jnp.asarray(epoch_offs), jnp.asarray(epoch_mask),
-                jnp.asarray(latest, jnp.int32),
-                jnp.asarray(from_epoch, jnp.int32))
-        # Operator state slice + log row + record count in one program.
-        # Deferred replays keep the consumed total on device — the add
-        # happens there and the host never waits for it.
-        rc = snap.record_counts[flat] + (
-            result.consumed_d if result.deferred
-            else result.records_replayed)
-        carry = self._graft_fn(vid)(
-            carry, result.op_state, restored,
-            jnp.asarray(sub, jnp.int32), jnp.asarray(flat, jnp.int32), rc)
-        # In-flight ring shard reconstruction: write the replayed outputs
-        # back into the producer's ring at their original step offsets
-        # (reference buildAndLogBuffer — the standby re-cuts identical
-        # buffers and re-logs them so downstream recoveries can be
-        # served). Only the last ring_steps replayed steps fit; earlier
-        # chunks are masked out (spill-backed replays longer than the
-        # ring must not wrap into newer steps).
-        rings = list(carry.out_rings)
-        if vid in compiled.ring_index and result.out_chunks is not None \
-                and n_steps > 0:
-            ri = compiled.ring_index[vid]
-            el = rings[ri]
-            keep_from = jnp.asarray(fence + n_steps
-                                    - min(n_steps, el.ring_steps),
-                                    jnp.int32)
-            hi = jnp.asarray(fence + n_steps, jnp.int32)
-            sub_j = jnp.asarray(sub, jnp.int32)
-            ch = self._chunk()
-            base_d = None
-            for i, chunk in enumerate(result.out_chunks):
-                m = chunk.keys.shape[0]
-                base_i = fence + i * ch
-                if base_i + m <= fence + n_steps - min(n_steps,
-                                                       el.ring_steps):
-                    continue      # wholly before the retained window
-                if base_d is None:
-                    base_d = jnp.asarray(base_i, jnp.int32)
-                el, base_d = self._ring_write_fn(ri, m)(
-                    el, chunk, base_d, sub_j, keep_from, hi)
-            rings[ri] = el
-        return carry._replace(out_rings=tuple(rings))
+    def failover_drill(self, flats: Optional[Sequence[int]] = None) -> float:
+        """Rehearse a failover end to end (:meth:`Failover.drill`)."""
+        return self.failover.drill(flats)

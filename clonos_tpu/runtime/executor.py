@@ -1695,7 +1695,7 @@ class LocalExecutor:
             # it precedes the epoch's first TIMESTAMP anchor in the log.
             # Recovery rebuilds the epoch->offset index from those anchors
             # and subtracts this ledger to place the boundary exactly
-            # (cluster._patch; SOURCE_CHECKPOINT / IGNORE_CHECKPOINT /
+            # (failover._epoch_index; SOURCE_CHECKPOINT / IGNORE_CHECKPOINT /
             # service calls between epochs all land here).
             for f in flat_subtasks:
                 k = (f, self.epoch_id)
@@ -1735,7 +1735,7 @@ class LocalExecutor:
         standby-host bootstrap derives them from mirrored determinant
         streams, possibly on a worker thread overlapped with replay).
         One atomic-enough install point: callers must invoke this BEFORE
-        anything reads the ledgers — recovery's ``_patch`` reads
+        anything reads the ledgers — ``Failover._epoch_index`` reads
         ``roll_gap_async`` when rebuilding epoch start offsets, so the
         bootstrap joins its derivation thread at recovery's pre-patch
         join point, not after replay."""

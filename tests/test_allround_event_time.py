@@ -119,8 +119,8 @@ def test_late_records_are_dropped_and_counted_as_the_reference_counts(
     reference's all the same, and both count the same records. The
     killed subtask holds 16 replica logs; rebuilt three at a time here,
     so that the rebuild takes several calls of its program."""
-    from clonos_tpu.runtime.cluster import ClusterRunner
-    monkeypatch.setattr(ClusterRunner, "REPLICA_COPY_ROWS", 3)
+    from clonos_tpu.runtime.recovery_programs import RecoveryPrograms
+    monkeypatch.setattr(RecoveryPrograms, "REPLICA_COPY_ROWS", 3)
     cfg = config(max_lag_ms=1300)
     tracer = obs.get_tracer()
     before = tracer.counters()

@@ -227,7 +227,7 @@ def test_replay_program_takes_the_block_programs_names(tmp_path):
     cfg = tiny_config("tiny-nexmark-q5")
     stream = job.make_stream(cfg, {"table_epochs": 2}, 7)
     runner = job.make_runner(cfg, stream, 7, str(tmp_path / "ck"), 1)
-    replayer = runner._make_replayer(2, 1)          # ``count``, subtask 1
+    replayer = runner.failover.programs.replayer(2, 1)          # ``count``, subtask 1
     assert replayer.vertex_name == "count"
     recorded = []
     real = jax.named_scope

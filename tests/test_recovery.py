@@ -74,6 +74,16 @@ def test_fsm_rejects_out_of_order_events():
     mgr = rec.RecoveryManager(1, 0, 2, replayer=None)
     with pytest.raises(rec.RecoveryError):
         mgr.notify_determinant_response(np.zeros((0, 8), np.int32), 0)
+    # ... and a replay takes its inputs as a list of chunks: the stacked
+    # batch of before the chunked form is refused by name.
+    from clonos_tpu.api.records import empty
+    window = _job().vertices[1].operator
+    plan = rec.ReplayPlan(
+        vertex_id=1, subtask=0, flat_subtask=2, from_epoch=1,
+        input_steps=empty((4, 8)), det_rows=np.zeros((0, 8), np.int32),
+        det_start=0, checkpoint_op_state=None, n_steps=0)
+    with pytest.raises(rec.RecoveryError, match="stacked RecordBatch"):
+        rec.LogReplayer(window, 2, block_steps=4).replay(plan)
 
 
 # --- end-to-end recovery -----------------------------------------------------
@@ -128,6 +138,80 @@ def test_prewarmed_recovery_bit_identical_and_reusable():
     _carries_equal(r.executor.carry, golden.executor.carry)
     golden.step()
     r.step()
+    _carries_equal(r.executor.carry, golden.executor.carry)
+
+
+def _served_runner(tmp_path, tag):
+    """chip_smoke's served shape at a tiny size: host source -> keyBy ->
+    count window -> keyBy -> reduce -> transactional sink."""
+    import chip_smoke as cs
+    from clonos_tpu.api.feeds import ListFeedReader
+    shape = cs.ServedShape(parallelism=2, batch=4, num_keys=7,
+                           edge_capacity=16, steps_per_epoch=4,
+                           window_steps=4, kill_after=2, epochs=4)
+    r = ClusterRunner(cs.build_served_job(shape), steps_per_epoch=4,
+                      log_capacity=256, max_epochs=8, inflight_ring_steps=16,
+                      seed=1, logical_time=True, audit=False,
+                      checkpoint_dir=str(tmp_path / tag))
+    r.executor.register_feed(0, ListFeedReader(list(cs.make_feed(shape, 3))))
+    return r
+
+
+#: kill shape -> (job, victims as (vertex, subtask) pairs). Not here: a
+#: connected cascade, which leaves a log fewer holders than any log has
+#: whole and so builds ``fetch_meta`` at that count on the failure path
+#: (ROADMAP S6; a drill by the same victims builds it in set-up).
+KILL_SHAPES = {
+    "one-keyed-subtask": ("wc", [(1, 1)]),
+    "two-subtasks-of-a-vertex": ("wc", [(1, 0), (1, 1)]),
+    "pure-sink": ("wc", [(2, 1)]),
+    "host-feed-source": ("served", [(0, 1)]),
+    "transactional-sink": ("served", [(3, 0)]),
+}
+
+
+@pytest.mark.parametrize("shape", list(KILL_SHAPES))
+def test_prewarmed_recovery_builds_no_program(shape, tmp_path):
+    """After ``prewarm_recovery()`` the kill and the recovery run on
+    programs that exist: JAX builds or fetches none (the tracer's
+    ``compile.programs``, obs/trace.py's listener), whatever the kill's
+    shape, and the carry is the never-failed run's. A program the
+    warm-up declares at one shape and the failure path calls at another
+    would compile here: a runner's recovery programs are its own. What
+    the protocol dispatches op by op (a slice, a concatenate) is the
+    process's, and a rehearsal on a runner of its own builds it first."""
+    from clonos_tpu import obs
+    kind, victims = KILL_SHAPES[shape]
+
+    def make(tag):
+        return (_served_runner(tmp_path, tag) if kind == "served"
+                else _runner(TIMES))
+
+    def drive(r):
+        r.run_epoch()
+        r.run_epoch()
+        r.step()
+        r.step()
+        return r
+
+    def kill_and_recover(r):
+        r.inject_failure([r.job.subtask_base(vid) + sub
+                          for vid, sub in victims])
+        return r.recover()
+
+    golden = drive(make("golden"))
+    kill_and_recover(drive(make("rehearsal")))
+    r = make("killed")
+    r.prewarm_recovery()
+    drive(r)
+    obs.trace.install_compile_listener()
+    tracer = obs.get_tracer()
+    before = tracer.counters().get("compile.programs", 0)
+    report = kill_and_recover(r)
+    built = tracer.counters().get("compile.programs", 0) - before
+    assert built == 0, [c["args"]["fun_name"] for c in tracer.records()
+                        if c["name"] == "compile"][-built:]
+    assert report.steps_replayed == 2
     _carries_equal(r.executor.carry, golden.executor.carry)
 
 
@@ -256,12 +340,13 @@ def test_replica_rebuild_copies_a_bounded_number_of_rows_a_call(sinks, calls):
     make = lambda: drive(ClusterRunner(_bench_job(8), steps_per_epoch=3,
                                        seed=11))
     golden, r = make(), make()
-    assert ClusterRunner.REPLICA_COPY_ROWS == 64
+    progs = r.failover.programs
+    assert progs.REPLICA_COPY_ROWS == 64
     failed = [3 * 8 + s for s in range(sinks)]
     held = [x for f in failed for x in r.plan.replicas_held_by(f)]
     assert len(held) == 24 * sinks
-    copy, seen = r._replica_copy_fn(), []
-    r._replica_copy_fn = lambda: lambda replicas, logs, ri, oi: (
+    copy, seen = progs.replica_copy(), []
+    progs.replica_copy = lambda: lambda replicas, logs, ri, oi: (
         seen.append(np.asarray(ri)), copy(replicas, logs, ri, oi))[1]
     r.inject_failure(failed)
     r.recover()
@@ -413,7 +498,7 @@ def test_same_vertex_pair_failure_shares_routed_windows():
     assert report.failed_subtasks == (2, 3)
     # The second consumer must have HIT the shared routed windows (pins
     # the cache keying; bit-identity alone would pass a broken cache).
-    assert r._route_cache_hits > 0
+    assert report.route_cache_hits > 0
     _carries_equal(r.executor.carry, golden.executor.carry)
     golden.step()
     r.step()

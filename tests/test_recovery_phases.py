@@ -146,15 +146,16 @@ def test_overlap_verify_failure_keeps_subtasks_dead_and_retryable(tmp_path):
     r.run_epoch(complete_checkpoint=False)
 
     flat = 2 + 1
-    orig_bounds = r._ring_bounds_dev
-    assert orig_bounds() is not None        # the job has in-flight rings
+    progs = r.failover.programs
+    orig_bounds = progs.ring_bounds()
+    assert r.executor.carry.out_rings       # the job has in-flight rings
     r.inject_failure([flat])
     # Deterministic verify trip: skew the ring-bounds lanes of the
     # packed read so the deferred assert sees device bounds that
     # contradict the host mirror. Routing coverage decisions read the
     # (valid, untampered) host mirror, so the replay itself is sound —
     # only the final state-verify fires.
-    r._ring_bounds_dev = lambda: orig_bounds() + 1
+    progs.ring_bounds = lambda: lambda rings: orig_bounds(rings) + 1
     with pytest.raises(rec.RecoveryError, match="state suspect"):
         r.recover()
     assert flat in r.failed                    # NOT marked healthy
@@ -163,7 +164,7 @@ def test_overlap_verify_failure_keeps_subtasks_dead_and_retryable(tmp_path):
                    for t in threading.enumerate())
     # Un-tamper and retry: the protocol reruns end-to-end, and only a
     # recover() that passed verify revives the subtask.
-    r._ring_bounds_dev = orig_bounds
+    progs.ring_bounds = lambda: orig_bounds
     report = r.recover()
     assert not r.failed
     assert flat not in r.heartbeats._dead

@@ -501,5 +501,5 @@ def test_block_roll_and_replay_programs_carry_the_named_scopes(tmp_path):
     # metadata only: the program text without debug info does not name them
     plain = ex._jit_roll.lower(ex.carry, 1).as_text()
     assert "causal-log" not in plain
-    replayer = runner._make_replayer(1, 0)
+    replayer = runner.failover.programs.replayer(1, 0)
     assert replayer.vertex_name == "window"

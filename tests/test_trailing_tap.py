@@ -259,8 +259,8 @@ def test_a_kill_after_single_steps_replays_into_the_same_stream(
         assert runner._tap_pending is None
     base = runner.job.subtask_base
     at_entry = []
-    inner = runner._recover_impl
-    runner._recover_impl = lambda *a, **kw: (
+    inner = runner.failover._run
+    runner.failover._run = lambda *a, **kw: (
         at_entry.append(runner._tap_pending), inner(*a, **kw))[1]
     runner.inject_failure([base(1) + 1, base(2), base(3) + 1])
     assert runner._tap_pending is None
