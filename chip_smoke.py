@@ -59,6 +59,16 @@ it pass off the device:
      are equal, and equal to the topology's NumPy reference, no loss.
      Run it on the chip after any change to the join's block form (D17:
      a block form right alone is not verified). Not in the default parts.
+  Q  the join whose interval each key takes from its own data and the
+     exact windowed mean (NEXmark query 4) inside a job's block program:
+     the benchmark's ``nexmark-average-price`` job at its tiny stand-in's
+     sizes (bids that wait for their auction, duplicate auctions, bids on
+     expired and never-opened ids and under the reserve, auctions no bid
+     counted for), once in blocks of 1,024 steps and once in blocks of
+     16. Pass = both committed streams equal the topology's NumPy
+     reference and the join's totals the reference's, no loss. Run it on
+     the chip after any change to either block form (D17). Not in the
+     default parts.
   C  job A again under a four-chip task mesh, when there are four chips.
      Pass = committed stream byte-identical to A's, ledgers equal, every
      sharded carry leaf on four devices at a quarter each.
@@ -715,12 +725,10 @@ def committed_by_epoch(graph, stream, seed: int, spe: int, epochs: int,
     return got, runner
 
 
-def check_incremental_join_in_a_job(seed: int, spe: int = 2048,
-                                    epochs: int = 3):
-    """Part I: the committed stream of the ``nexmark-local-items`` job
-    run in blocks of 1,024 steps against the same job run in blocks of
-    16 and against the topology's plain reference; returns (rows
-    compared, of them flushed out of the bag, chunks run step by step)."""
+def tiny_topology(config: str, spe: int, seed: int):
+    """A benchmark topology at its tiny stand-in's sizes with epochs of
+    ``spe`` steps: (configuration, stream, its plain reference, its
+    ``job.py``'s ``build``)."""
     import json
     bench = os.path.join(HERE, "benchmark")
     if bench not in sys.path:
@@ -729,12 +737,21 @@ def check_incremental_join_in_a_job(seed: int, spe: int = 2048,
     from benchlib.byname import module_at
 
     with open(os.path.join(bench, "tests", "tiny", "bench", "configs",
-                           "tiny-nexmark-q3.json")) as f:
+                           config + ".json")) as f:
         cfg = json.load(f)
     cfg["steps_per_epoch"] = spe
-    stream = bench_job.make_stream(cfg, {"table_epochs": 2}, seed)
-    ref = module_at(bench_job.topology_file(cfg, "reference.py"))
-    build = module_at(bench_job.topology_file(cfg, "job.py")).build
+    return (cfg, bench_job.make_stream(cfg, {"table_epochs": 2}, seed),
+            module_at(bench_job.topology_file(cfg, "reference.py")),
+            module_at(bench_job.topology_file(cfg, "job.py")).build)
+
+
+def check_incremental_join_in_a_job(seed: int, spe: int = 2048,
+                                    epochs: int = 3):
+    """Part I: the committed stream of the ``nexmark-local-items`` job
+    run in blocks of 1,024 steps against the same job run in blocks of
+    16 and against the topology's plain reference; returns (rows
+    compared, of them flushed out of the bag, chunks run step by step)."""
+    cfg, stream, ref, build = tiny_topology("tiny-nexmark-q3", spe, seed)
 
     def committed(block_steps: int):
         got, runner = committed_by_epoch(build(cfg), stream, seed, spe,
@@ -758,6 +775,45 @@ def check_incremental_join_in_a_job(seed: int, spe: int = 2048,
             f"({want.flushed} flushed, {want.bag_expired} expired in the "
             f"bag, {stepped} chunks step by step)")
     return compared, want.flushed, stepped
+
+
+def check_best_in_interval_in_a_job(seed: int, spe: int = 2048,
+                                    epochs: int = 3):
+    """Part Q: the committed stream of the ``nexmark-average-price`` job
+    (the join whose interval each key takes from its own data, then the
+    exact windowed mean) run in blocks of 1,024 steps against the same
+    job run in blocks of 16 and against the topology's plain reference,
+    and the join's totals against the reference's; returns (rows
+    compared, rows the join emitted, bids that counted, chunks run step
+    by step)."""
+    cfg, stream, ref, build = tiny_topology("tiny-nexmark-q4", spe, seed)
+    want = ref.expected(cfg, stream.keys, stream.vals, epochs)
+    stepped = 0
+    for block_steps in (1024, 16):
+        got, runner = committed_by_epoch(build(cfg), stream, seed, spe,
+                                         epochs, block_steps,
+                                         "best in interval")
+        bad, failed, compared = ref.check(got, want, cfg, epochs)
+        if bad:
+            raise AssertionError(
+                f"best in interval, blocks of {block_steps} steps: {bad} "
+                f"rows differ from the reference in epochs {failed}")
+        state = runner.executor.vertex_state(4)
+        total = {k: int(np.asarray(state[k]).sum())
+                 for k in ("rows", "valid", "under", "orphans", "duplicates",
+                           "no_valid")}
+        theirs = dict(rows=want.winning_rows, valid=want.valid,
+                      under=want.under, orphans=want.orphans,
+                      duplicates=want.duplicates, no_valid=want.no_valid)
+        if total != theirs:
+            raise AssertionError(
+                f"best in interval, blocks of {block_steps} steps: totals "
+                f"{total}, the reference's {theirs}")
+        stepped = max(stepped, int(np.asarray(state["step_chunks"]).sum()))
+    if min(want.valid, want.under, want.duplicates, want.no_valid) < 100:
+        raise AssertionError(
+            f"best in interval: the traffic left a branch out ({want})")
+    return compared, want.winning_rows, want.valid, stepped
 
 
 # --- main --------------------------------------------------------------------
@@ -821,7 +877,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=21)
     ap.add_argument("--parts", default="KABC",
-                    help="which of K, J, S, I, A, B, C to run (C needs A)")
+                    help="which of K, J, S, I, Q, A, B, C to run (C needs A)")
     args = ap.parse_args(argv)
     t_start = time.monotonic()
 
@@ -884,6 +940,16 @@ def main(argv=None) -> int:
         say(f"I pass: incremental join, blocks of 1,024 steps == blocks of "
             f"16 == the reference over {rows} rows, {flushed} of them "
             f"flushed, {stepped} chunks by the step form "
+            f"({time.monotonic() - t0:.1f}s)")
+
+    if "Q" in parts:
+        t0 = time.monotonic()
+        rows, won, valid, stepped = check_best_in_interval_in_a_job(args.seed)
+        mark = print_routes(tracer, mark, "Q")
+        say(f"Q pass: best in interval and the exact mean, blocks of 1,024 "
+            f"steps == blocks of 16 == the reference over {rows} rows "
+            f"({won} auctions won by the best of {valid} bids that "
+            f"counted), {stepped} chunks by the step form "
             f"({time.monotonic() - t0:.1f}s)")
 
     shape = ServedShape()

@@ -268,6 +268,70 @@ class DataStream:
             own_columns=own_columns, bag_capacity=bag_capacity)
         return self._attach2(other, name, op, parallelism, edge_capacity)
 
+    def join_best_in_interval(self, other: "DataStream", num_keys: int,
+                              length_of: Callable, floor_of: Callable,
+                              emit_of: Callable, out_of_orderness: int = 0,
+                              capacity: Optional[int] = None,
+                              own_columns: Optional[int] = None,
+                              pool_capacity: int = 1024,
+                              edge_capacity: Optional[int] = None,
+                              name: str = "best-in-interval",
+                              parallelism: Optional[int] = None
+                              ) -> "DataStream":
+        """Join in which every key takes its interval from its own data
+        and only an interval's best probe comes out: a record ``(key, v,
+        t)`` of ``self`` opens ``[t, t + length_of(v))`` for its key
+        with the floor ``floor_of(v)``; a record ``(key, price, t)`` of
+        ``other`` counts for the interval of its key that holds ``t``
+        if ``price >= floor``; when the watermark passes an interval's
+        end it is one row ``(emit_of(v), largest price that counted,
+        end - 1)``, or none (Beam's NEXmark ``WinningBids``: auctions and
+        the bids inside their ``[dateTime, expires)`` at or over the
+        reserve; operators.BestInIntervalJoinOperator has the rule). The
+        three functions are traced elementwise over the value lane, as
+        :meth:`map`'s ``fn`` is. Both inputs must be key_by()'d.
+
+        ``capacity``: rows a subtask may emit a step; ``pool_capacity``:
+        probes a subtask may keep waiting for the watermark to reach
+        their own time; ``own_columns``: required — a column only for
+        the keys a subtask owns (as :meth:`window_top`);
+        ``edge_capacity``: the receive window of BOTH input edges. What
+        passes a capacity is counted and stops the run at the next
+        fence."""
+        from clonos_tpu.api.operators import BestInIntervalJoinOperator
+        if not (self._keyed and other._keyed):
+            raise ValueError(
+                "join_best_in_interval requires key_by() on both inputs")
+        op = BestInIntervalJoinOperator(
+            num_keys=num_keys, length_of=length_of, floor_of=floor_of,
+            emit_of=emit_of, out_of_orderness=out_of_orderness,
+            capacity=capacity or self._env.default_edge_capacity,
+            own_columns=own_columns, pool_capacity=pool_capacity)
+        return self._attach2(other, name, op, parallelism, edge_capacity)
+
+    def window_mean(self, num_keys: int, window_size: int,
+                    slide: Optional[int] = None, out_of_orderness: int = 0,
+                    edge_capacity: Optional[int] = None,
+                    name: str = "window-mean",
+                    parallelism: Optional[int] = None) -> "DataStream":
+        """Event-time windowed mean per key — sliding by ``slide``,
+        tumbling without it — exact whatever the values add up to: one
+        row ``(key, round-half-up(sum / count), window end - 1)`` per key
+        a firing window holds a record of, values in ``[0, 2**30)``
+        (operators.EventTimeWindowMeanOperator; behind
+        :meth:`join_best_in_interval` it is NEXmark query 4, "Average
+        Price for a Category"). Requires key_by(). A late record and a
+        value out of range are counted and stop the run at the next
+        fence. The rows lie on dense ``slot x key`` lanes: give the next
+        edge ``open_windows x num_keys`` slots."""
+        from clonos_tpu.api.operators import EventTimeWindowMeanOperator
+        if not self._keyed:
+            raise ValueError("window_mean requires key_by() first")
+        op = EventTimeWindowMeanOperator(
+            num_keys=num_keys, window_size=window_size,
+            slide=slide or window_size, out_of_orderness=out_of_orderness)
+        return self._attach(name, op, parallelism, capacity=edge_capacity)
+
     def window_top(self, num_keys: int, window_size: int,
                    slide: Optional[int] = None, out_of_orderness: int = 0,
                    capacity: Optional[int] = None,

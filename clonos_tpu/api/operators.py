@@ -24,6 +24,7 @@ CausalTimeService.java:48-67). This makes every operator deterministic given
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -945,6 +946,142 @@ class SlidingEventTimeWindowOperator(EventTimeWindow):
     @property
     def _slide(self) -> int:
         return self.slide
+
+
+@dataclasses.dataclass
+class EventTimeWindowMeanOperator(SlidingEventTimeWindowOperator):
+    """Event-time windowed MEAN per key, sliding or tumbling, exact: a
+    window that fires emits one row ``(key, round-half-up(sum / count),
+    window end - 1)`` for every key it holds a record of (Beam's
+    ``Mean.perKey`` behind ``Math.round``; NEXmark query 4, "Average
+    Price for a Category"). Watermark, slots, fire, the batched-watermark
+    rule and the dense ``slot x key`` lanes are :class:`EventTimeWindow`'s;
+    a row is stamped with its window's last millisecond, as
+    :class:`EventTimeWindowTopOperator` stamps its.
+
+    **Exact.** A value in ``[0, 2**30)`` goes into two sums as two limbs
+    of 15 bits beside the count, so that neither sum passes int32 while
+    a (window, key) holds fewer than 65,536 records, whatever they add up
+    to (prices reach 10**8: four hundred of them pass int32 and a
+    float32's 24 bits both); the mean is their long division by the
+    count, rounded half up. A value outside that range is refused and
+    counted (``refused``); ``late`` counts the records that missed ANY
+    of the windows that hold them (a sliding window's older ones fire
+    first, and a mean that lacks a row is a wrong mean, where
+    :class:`EventTimeWindow` counts only the records no window took);
+    both are ``fence_losses``."""
+
+    fence_totals = EventTimeWindow.fence_totals + (
+        ("refused", "window.refused_values"),)
+    fence_losses = ("late", "refused")
+
+    _LIMB = 15
+
+    def init_state(self, parallelism: int):
+        state = super().init_state(parallelism)
+        state.update(acc_hi=jnp.zeros_like(state["acc"]),
+                     count=jnp.zeros_like(state["acc"]),
+                     refused=jnp.zeros_like(state["late"]))
+        return state
+
+    def _limbs(self, b: RecordBatch):
+        """``(taken, low limb, high limb, refused [..., P])`` of a
+        receive window's records."""
+        fits = (b.values >= 0) & (b.values < 1 << 2 * self._LIMB)
+        return (b.valid & fits, b.values & ((1 << self._LIMB) - 1),
+                b.values >> self._LIMB,
+                jnp.sum((b.valid & ~fits).astype(jnp.int32), axis=-1))
+
+    def _mean(self, lo, hi, count):
+        """``round-half-up((hi * 2**15 + lo) / count)`` by long division
+        (0 where ``count`` is): the one step that passes int32 is taken
+        in uint32, which ``remainder * 2**15 + lo < 2**32`` fits."""
+        u = lambda x: x.astype(jnp.uint32)
+        n = jnp.maximum(count, 1)
+        rest = u(hi % n) * (1 << self._LIMB) + u(lo)
+        return ((hi // n << self._LIMB) + (rest // u(n)).astype(jnp.int32)
+                + (2 * (rest % u(n)) >= u(n)).astype(jnp.int32))
+
+    def _rows(self, lo, hi, count, fire_l, win_end_l):
+        """The rows of one or many steps over the dense lanes ``[..., W *
+        nk]``, and how many ``[...]``."""
+        out = zero_invalid(RecordBatch(
+            keys=jnp.broadcast_to(jnp.asarray(self.static_out_keys()),
+                                  lo.shape),
+            values=self._mean(lo, hi, count), timestamps=win_end_l - 1,
+            valid=fire_l & (count != 0)))
+        return out, jnp.sum(out.valid.astype(jnp.int32), axis=-1)
+
+    def process(self, state, batch, ctx):
+        nk = self.num_keys
+        took, lo, hi, refused = self._limbs(batch)
+
+        def one(acc, acc_hi, count, win, max_ts, b: RecordBatch, took, lo,
+                hi):
+            max_ts = jnp.maximum(max_ts, jnp.max(
+                jnp.where(b.valid, b.timestamps, _NO_TS)))
+            wm = max_ts - self.out_of_orderness
+            win_end, fire = self._fire_step(win, wm)               # [W]
+            out, fired = self._rows(
+                acc.reshape(-1), acc_hi.reshape(-1), count.reshape(-1),
+                jnp.repeat(fire, nk), jnp.repeat(win_end, nk))
+            acc, acc_hi, count = (jnp.where(fire[:, None], 0, x)
+                                  for x in (acc, acc_hi, count))
+            win = jnp.where(fire, _NO_WINDOW, win)
+            win, placed, ok_any = self._place_step(win, wm, took,
+                                                   b.timestamps)
+            key = jnp.clip(b.keys, 0, nk - 1)
+            for slot, ok in placed:
+                add = lambda x, v: x.at[slot, key].add(
+                    jnp.where(ok, v, 0), mode="drop")
+                acc, acc_hi, count = (add(acc, lo), add(acc_hi, hi),
+                                      add(count, jnp.ones_like(lo)))
+            ok_all = functools.reduce(jnp.logical_and,
+                                      (ok for _, ok in placed))
+            late = jnp.sum((took & ~ok_all).astype(jnp.int32))
+            return acc, acc_hi, count, win, max_ts, late, fired, out
+
+        acc, acc_hi, count, win, max_ts, late, fired, out = jax.vmap(one)(
+            state["acc"], state["acc_hi"], state["count"], state["win"],
+            state["max_ts"], batch, took, lo, hi)
+        return dict(
+            acc=acc, acc_hi=acc_hi, count=count, win=win, max_ts=max_ts,
+            late=state["late"] + late, fired=state["fired"] + fired,
+            refused=state["refused"] + refused), out
+
+    def process_block(self, state, batches, bctx):
+        # :class:`EventTimeWindow`'s block form over three accumulators
+        p = batches.keys.shape[1]
+        nk, w = self.num_keys, self.open_windows
+        took, lo, hi, refused = self._limbs(batches)
+        max_ts = self._block_max_ts(state["max_ts"], batches.valid,
+                                    batches.timestamps)
+        wm = max_ts - self.out_of_orderness                       # [K, P]
+        key = jnp.clip(batches.keys, 0, nk - 1)
+        add_lo, add_n, taken, _ = self._block_place(
+            took, key, lo, batches.timestamps, wm, want_counts=True)
+        ok_all = functools.reduce(jnp.logical_and, (
+            self._accepts(took, batches.timestamps // self.slide - j,
+                          wm[:, :, None], False)
+            for j in range(self.window_size // self.slide)))
+        add_hi = self._block_place(took, key, hi, batches.timestamps, wm)[0]
+        held, win_end, fire = self._block_slots(state["win"], taken, wm)
+        fire_l = self._block_lanes(fire)                       # [K,P,W*nk]
+        (acc, lo), (acc_hi, hi), (count, n) = (
+            self._block_accumulate(state[k].reshape(p, w * nk), add, fire_l)
+            for k, add in (("acc", add_lo), ("acc_hi", add_hi),
+                           ("count", add_n)))
+        out, fired = self._rows(lo, hi, n, fire_l,
+                                self._block_lanes(win_end))
+        return dict(
+            acc=acc[-1].reshape(p, w, nk),
+            acc_hi=acc_hi[-1].reshape(p, w, nk),
+            count=count[-1].reshape(p, w, nk), win=held[-1],
+            max_ts=max_ts[-1],
+            late=state["late"] + jnp.sum(
+                (took & ~ok_all).astype(jnp.int32), axis=(0, 2)),
+            fired=state["fired"] + fired.sum(axis=0),
+            refused=state["refused"] + refused.sum(axis=0)), out
 
 
 @dataclasses.dataclass
@@ -2046,8 +2183,200 @@ def _pack_by_rank(mask: jnp.ndarray, fields, width: int):
                   for x in fields), rank[..., -1] + 1)
 
 
+class _ChunkedJoin(_OwnColumns, TwoInputOperator):
+    """What the two-input operators on own columns that take a block in
+    chunks of steps share (:class:`IncrementalJoinOperator`,
+    :class:`BestInIntervalJoinOperator`): the two-input watermark, the
+    records off the id ring, a chunk's records packed to the front, its
+    rows as histogram lanes ``step x capacity + rank``, the step form as
+    a chunk of one step, and the block form — a loop over the chunks,
+    none over the steps, in which a chunk that is not *quiet* runs step
+    by step under a ``lax.cond`` and is counted (``step_chunks``), so
+    that the block form is the step form bit for bit on any input.
+
+    An operator says what a chunk does — :meth:`_chunk`, exact for one
+    step always and for more while the chunk is quiet in the operator's
+    own sense — which of its state a chunk works on (``_CORE``, with the
+    ``ring_too_small`` and ``step_chunks`` counters), how many receive
+    windows a chunk's records are packed into a side
+    (``_PACKED_WINDOWS``) and how many lanes its row operands have
+    (:meth:`_row_lanes`)."""
+
+    num_keys: int
+    out_of_orderness: int
+    capacity: int
+
+    #: steps of a chunk, at most (short enough that what an operator
+    #: keeps for a while — a ttl, an interval — outlasts or fits it)
+    _CHUNK_STEPS = 32
+    _PACKED_WINDOWS: Tuple[int, int]
+    _CORE: Tuple[str, ...]
+
+    def _chunk(self, core, left, right, wm):
+        """One chunk of ``S`` steps over every subtask: ``core``, the
+        chunk's records of each side in (step, slot) order as ``(key,
+        value, timestamp, step, valid)`` of ``[P, M]`` and ``wm [S, P]``
+        -> the new ``core``, the chunk's rows as histogram operands
+        ``(lane, key, value, timestamp, valid) [P, N]``, the rows a step
+        emits ``[P, S]`` and which subtasks the chunk was not quiet for
+        ``[P]``."""
+        raise NotImplementedError
+
+    def _row_lanes(self, widths) -> int:
+        """``N`` of :meth:`_chunk`'s row operands, given the packed
+        widths of the two sides."""
+        raise NotImplementedError
+
+
+    @property
+    def out_capacity(self):  # type: ignore[override]
+        return self.capacity
+
+    def _in_range(self, b: RecordBatch):
+        return b.valid & (b.keys >= 0) & (b.keys < self.num_keys)
+
+    def _watermark(self, max_l, max_r):
+        lo = jnp.minimum(max_l, max_r)       # _NO_TS while an input is silent
+        return jnp.where(lo != _NO_TS, lo - self.out_of_orderness, _NO_TS)
+
+    def _chunk_of(self, steps: int) -> int:
+        """Steps a chunk of a block of ``steps`` takes: they divide the
+        block, and a chunk's rows fit the histogram's lanes."""
+        from clonos_tpu.ops.histogram import KERNEL_MAX_KEYS
+        most = max(1, min(self._CHUNK_STEPS,
+                          KERNEL_MAX_KEYS // self.capacity))
+        return max(s for s in range(1, most + 1) if steps % s == 0)
+
+    @scoped("emit")
+    def _rows_of(self, rows, emitted, steps: int) -> RecordBatch:
+        """The rows ``_chunk`` hands back as batches ``[..., P, steps,
+        capacity]``: a row's place is its lane."""
+        from clonos_tpu.ops.histogram import keyed_hist
+        lane, key, val, ts, ok = rows
+        cap = self.capacity
+        key, val, ts = (
+            keyed_hist(lane, x, ok, steps * cap, want_counts=False)[0]
+            .reshape(lane.shape[:-1] + (steps, cap)) for x in (key, val, ts))
+        valid = jnp.arange(cap, dtype=jnp.int32) < emitted[..., None]
+        return zero_invalid(RecordBatch(key, val, ts, valid))
+
+    def _step(self, core, left, right, wm):
+        """One step, exactly: ``core``, the two receive windows ``[P,
+        B]`` and ``wm [P]`` -> ``(core, rows [P, capacity])``."""
+        zero = jnp.zeros_like(left.keys)
+        as_chunk = lambda b: (b.keys, b.values, b.timestamps, zero,
+                              self._in_range(b))
+        core, rows, emitted, _ = self._chunk(
+            core, as_chunk(left), as_chunk(right), wm[None])
+        out = self._rows_of(rows, emitted, 1)
+        return core, jax.tree_util.tree_map(lambda x: x[:, 0], out)
+
+    def _top_ts(self, b: RecordBatch):
+        """The largest timestamp among a receive window's records."""
+        return jnp.max(jnp.where(self._in_range(b), b.timestamps, _NO_TS),
+                       axis=-1)
+
+    def _core(self, state, left, right, steps_axis=()):
+        """The part of ``state`` a chunk works on, with the records whose
+        key lies off the ring counted."""
+        core = {k: state[k] for k in self._CORE}
+        core["ring_too_small"] = core["ring_too_small"] + sum(
+            jnp.sum((b.valid & ~self._in_range(b)).astype(jnp.int32),
+                    axis=steps_axis + (-1,)) for b in (left, right))
+        return core
+
+    def process2(self, state, left, right, ctx):
+        max_l = jnp.maximum(state["max_ts_left"], self._top_ts(left))
+        max_r = jnp.maximum(state["max_ts_right"], self._top_ts(right))
+        core, out = self._step(self._core(state, left, right), left, right,
+                               self._watermark(max_l, max_r))
+        return dict(state, **core, max_ts_left=max_l,
+                    max_ts_right=max_r), out
+
+    @scoped("compact")
+    def _packed(self, b: RecordBatch, chunks: int, width: int):
+        """A block's records chunk by chunk, packed to the front in
+        (step, slot) order: ``(key, value, timestamp, step, valid)`` of
+        ``[chunks, P, width]``, and how many a chunk brought ``[chunks,
+        P]`` (past ``width`` they do not fit)."""
+        K, p, e = b.keys.shape
+        s = K // chunks
+        flat = lambda x: x.reshape(chunks, s, p, e).transpose(
+            0, 2, 1, 3).reshape(chunks, p, s * e)
+        ok = flat(self._in_range(b))
+        step = jnp.broadcast_to(
+            jnp.repeat(jnp.arange(s, dtype=jnp.int32), e), ok.shape)
+        fields = (flat(b.keys), flat(b.values), flat(b.timestamps), step)
+        if s * e <= width:                   # as they lie: nothing to pack
+            return fields + (ok,), jnp.sum(ok.astype(jnp.int32), axis=-1)
+        packed, total = _pack_by_rank(ok, fields, width)
+        return packed + (jnp.arange(width, dtype=jnp.int32)
+                         < total[..., None],), total
+
+    def process_block(self, state, batches, bctx):
+        left, right = batches
+        K, p, e = left.keys.shape
+        S, cap = self._chunk_of(K), self.capacity
+        chunks = K // S
+        max_l = jnp.maximum(state["max_ts_left"][None],
+                            _running_max(self._top_ts(left)))     # [K, P]
+        max_r = jnp.maximum(state["max_ts_right"][None],
+                            _running_max(self._top_ts(right)))
+        wm = self._watermark(max_l, max_r).reshape(chunks, S, p)
+        widths = tuple(min(S * e, w * e) for w in self._PACKED_WINDOWS)
+        (l_pack, l_total), (r_pack, r_total) = (
+            self._packed(b, chunks, w)
+            for b, w in zip((left, right), widths))
+        full = (l_total > widths[0]) | (r_total > widths[1])   # [chunks, P]
+        by_chunk = lambda b: jax.tree_util.tree_map(
+            lambda x: x.reshape((chunks, S) + x.shape[1:]), b)
+        core = self._core(state, left, right, steps_axis=(0,))
+        # a chunk's rows as histogram operands, as wide as either way of
+        # running it makes them
+        wide = max(self._row_lanes(widths), S * cap)
+
+        def quiet(core, xs):
+            l_pack, r_pack, _, _, wm = xs
+            core, rows, emitted, loud = self._chunk(core, l_pack, r_pack, wm)
+            pad = lambda x: jnp.pad(x, ((0, 0), (0, wide - x.shape[-1])))
+            return core, tuple(pad(x) for x in rows), emitted, loud
+
+        def by_steps(core, xs):
+            _, _, l_raw, r_raw, wm = xs
+            core, out = jax.lax.scan(
+                lambda c, x: self._step(c, *x), core, (l_raw, r_raw, wm))
+            lanes = lambda x: jnp.pad(
+                x.transpose(1, 0, 2).reshape(p, S * cap),
+                ((0, 0), (0, wide - S * cap)))
+            lane = jnp.broadcast_to(jnp.arange(wide, dtype=jnp.int32),
+                                    (p, wide))
+            return core, (lane, lanes(out.keys), lanes(out.values),
+                          lanes(out.timestamps), lanes(out.valid)), \
+                out.count().T
+
+        def chunk(core, xs):
+            fast, rows, emitted, loud = quiet(core, xs[:-1])
+            loud = loud | xs[-1]
+            core, rows, emitted = jax.lax.cond(
+                jnp.any(loud), lambda: by_steps(core, xs[:-1]),
+                lambda: (fast, rows, emitted))
+            core["step_chunks"] = core["step_chunks"] + loud.astype(jnp.int32)
+            return core, (rows, emitted)
+
+        core, (rows, emitted) = jax.lax.scan(
+            chunk, core, (l_pack, r_pack, by_chunk(left), by_chunk(right),
+                          wm, full))
+        out = self._rows_of(rows, emitted, S)         # [chunks, P, S, cap]
+        out = jax.tree_util.tree_map(
+            lambda x: x.transpose(0, 2, 1, 3).reshape(K, p, cap), out)
+        return dict(state, **core, max_ts_left=max_l[-1],
+                    max_ts_right=max_r[-1]), out
+
+
+
+
 @dataclasses.dataclass
-class IncrementalJoinOperator(_OwnColumns, TwoInputOperator):
+class IncrementalJoinOperator(_ChunkedJoin):
     """Join of two keyed streams over their whole history, with no
     window: the left input BUILDS — a key's first record registers it —
     and the right input PROBES; a probe whose key is not registered
@@ -2147,8 +2476,6 @@ class IncrementalJoinOperator(_OwnColumns, TwoInputOperator):
     fence_losses = ("bag_overflow", "dropped", "unplaced", "ring_too_small")
     fence_peaks = (("live_peak", "join.live_persons"),)
 
-    #: steps of a chunk, at most (short enough that a ttl outlasts it)
-    _CHUNK_STEPS = 32
     #: a chunk's records are packed into this many receive windows a
     #: side (left, right); a chunk that brings more runs step by step
     _PACKED_WINDOWS = (2, 4)
@@ -2163,10 +2490,6 @@ class IncrementalJoinOperator(_OwnColumns, TwoInputOperator):
         if min(self.ttl, self.capacity, self.bag_capacity) < 1:
             raise ValueError("ttl, capacity and bag_capacity must be "
                              "positive")
-
-    @property
-    def out_capacity(self):  # type: ignore[override]
-        return self.capacity
 
     _COUNTERS = tuple(k for k, _ in fence_totals)
 
@@ -2185,25 +2508,8 @@ class IncrementalJoinOperator(_OwnColumns, TwoInputOperator):
             max_ts_right=jnp.full((p,), _NO_TS, jnp.int32))
         return state
 
-    # --- what both forms share ------------------------------------------------
-
-    def _in_range(self, b: RecordBatch):
-        return b.valid & (b.keys >= 0) & (b.keys < self.num_keys)
-
-    def _watermark(self, max_l, max_r):
-        lo = jnp.minimum(max_l, max_r)       # _NO_TS while an input is silent
-        return jnp.where(lo != _NO_TS, lo - self.out_of_orderness, _NO_TS)
-
     def _live(self, ts, wm):
         return (ts != _NO_TS) & (ts + self.ttl > wm)
-
-    def _chunk_of(self, steps: int) -> int:
-        """Steps a chunk of a block of ``steps`` takes: they divide the
-        block, and a chunk's rows fit the histogram's lanes."""
-        from clonos_tpu.ops.histogram import KERNEL_MAX_KEYS
-        most = max(1, min(self._CHUNK_STEPS,
-                          KERNEL_MAX_KEYS // self.capacity))
-        return max(s for s in range(1, most + 1) if steps % s == 0)
 
     def _chunk(self, core, left, right, wm):
         """One chunk of ``S`` steps over every subtask, exact if the
@@ -2343,130 +2649,299 @@ class IncrementalJoinOperator(_OwnColumns, TwoInputOperator):
     _CORE = ("cols", "person_ts", "bag_key", "bag_val", "bag_ts", "bag_n",
              "live_peak") + _COUNTERS
 
-    @scoped("emit")
-    def _rows_of(self, rows, emitted, steps: int) -> RecordBatch:
-        """The rows ``_chunk`` hands back as batches ``[..., P, steps,
-        capacity]``: a row's place is its lane."""
-        from clonos_tpu.ops.histogram import keyed_hist
-        lane, key, val, ts, ok = rows
-        cap = self.capacity
-        key, val, ts = (
-            keyed_hist(lane, x, ok, steps * cap, want_counts=False)[0]
-            .reshape(lane.shape[:-1] + (steps, cap)) for x in (key, val, ts))
-        valid = jnp.arange(cap, dtype=jnp.int32) < emitted[..., None]
-        return zero_invalid(RecordBatch(key, val, ts, valid))
+    def _row_lanes(self, widths) -> int:
+        return widths[1] + self.bag_capacity + widths[1]
 
-    def _step(self, core, left, right, wm):
-        """One step, exactly: ``core``, the two receive windows ``[P,
-        B]`` and ``wm [P]`` -> ``(core, rows [P, capacity])``."""
-        zero = jnp.zeros_like(left.keys)
-        as_chunk = lambda b: (b.keys, b.values, b.timestamps, zero,
-                              self._in_range(b))
-        core, rows, emitted, _ = self._chunk(
-            core, as_chunk(left), as_chunk(right), wm[None])
-        out = self._rows_of(rows, emitted, 1)
-        return core, jax.tree_util.tree_map(lambda x: x[:, 0], out)
 
-    def _top_ts(self, b: RecordBatch):
-        """The largest timestamp among a receive window's records."""
-        return jnp.max(jnp.where(self._in_range(b), b.timestamps, _NO_TS),
-                       axis=-1)
+@dataclasses.dataclass
+class BestInIntervalJoinOperator(_ChunkedJoin):
+    """Join of two keyed streams in which every key takes its interval
+    from its own data, and of which only the best probe of an interval
+    comes out: the left input OPENS — a record ``(key, v, t)`` opens
+    ``[t, t + length_of(v))`` for its key, with a floor ``floor_of(v)``
+    and a payload ``emit_of(v)`` — and the right input PROBES: a record
+    ``(key, price, t)`` counts for the interval of its key that holds
+    ``t`` if ``price >= floor``; when the watermark passes an interval's
+    end it closes, and is one row ``(payload, largest price that
+    counted, end - 1)`` if any did. NEXmark query 4's first half, Beam's
+    ``WinningBids`` ("the winning bid of every closed auction": bids
+    inside an auction's ``[dateTime, expires)``, at or over its reserve,
+    the highest), is its textbook use; "auction" and "bid" below are
+    its words for an opening record and a probe. ``length_of``,
+    ``floor_of`` and ``emit_of`` are traced elementwise functions of the
+    value lane, as a ``map``'s ``fn`` is: the job says what an auction's
+    value means.
 
-    def _core(self, state, left, right, steps_axis=()):
-        """The part of ``state`` a chunk works on, with the records whose
-        key lies off the ring counted."""
-        core = {k: state[k] for k in self._CORE}
-        core["ring_too_small"] = core["ring_too_small"] + sum(
-            jnp.sum((b.valid & ~self._in_range(b)).astype(jnp.int32),
-                    axis=steps_axis + (-1,)) for b in (left, right))
-        return core
+    **Watermark.** Flink's rule for a two-input operator
+    (:class:`_ChunkedJoin`): the smaller of the two inputs' running
+    maxima less ``out_of_orderness``, advanced once a step BEFORE the
+    step's records; none while an input is silent (nothing resolves,
+    nothing closes).
 
-    def process2(self, state, left, right, ctx):
-        max_l = jnp.maximum(state["max_ts_left"], self._top_ts(left))
-        max_r = jnp.maximum(state["max_ts_right"], self._top_ts(right))
-        core, out = self._step(self._core(state, left, right), left, right,
-                               self._watermark(max_l, max_r))
-        return dict(state, **core, max_ts_left=max_l,
-                    max_ts_right=max_r), out
+    **A step**, per subtask, in this order, none of it dependent on the
+    slot order inside the step: (1) the bids whose own ``t`` the
+    watermark has reached are *resolved* — those that waited, then the
+    step's own — against the auction their key holds open: inside its
+    interval and at or over its floor a bid counts (``valid``; the
+    auction keeps the largest), inside and under it counts in ``under``,
+    outside any in ``orphans`` (no auction, one not yet open at ``t``,
+    one expired before ``t``); (2) the auctions whose end the
+    watermark has reached close, in key order: a row each if a bid
+    counted, else ``no_valid``, and the column is free; (3) the step's
+    auctions, key by key: if the key holds an open auction they are
+    ``duplicates``, else the one with the smallest ``(t, v)`` opens and
+    the others are duplicates; (4) the bids not yet resolved wait, in
+    arrival order. A bid thus waits until every auction record with a
+    ``t`` at or before its own has arrived (the watermark says so), and
+    an interval's result is the maximum over every bid of its key with
+    ``start <= t < end`` and ``price >= floor``, whenever it arrived
+    within the bound.
 
-    @scoped("compact")
-    def _packed(self, b: RecordBatch, chunks: int, width: int):
-        """A block's records chunk by chunk, packed to the front in
-        (step, slot) order: ``(key, value, timestamp, step, valid)`` of
-        ``[chunks, P, width]``, and how many a chunk brought ``[chunks,
-        P]`` (past ``width`` they do not fit)."""
-        K, p, e = b.keys.shape
-        s = K // chunks
-        flat = lambda x: x.reshape(chunks, s, p, e).transpose(
-            0, 2, 1, 3).reshape(chunks, p, s * e)
-        ok = flat(self._in_range(b))
-        step = jnp.broadcast_to(
-            jnp.repeat(jnp.arange(s, dtype=jnp.int32), e), ok.shape)
-        fields = (flat(b.keys), flat(b.values), flat(b.timestamps), step)
-        if s * e <= width:                   # as they lie: nothing to pack
-            return fields + (ok,), jnp.sum(ok.astype(jnp.int32), axis=-1)
-        packed, total = _pack_by_rank(ok, fields, width)
-        return packed + (jnp.arange(width, dtype=jnp.int32)
-                         < total[..., None],), total
+    **What is lost is counted and loud** (``fence_losses``): a bid that
+    finds ``pool_capacity`` bids waiting on its subtask
+    (``pool_overflow``), a row past ``capacity`` a subtask a step
+    (``dropped``), an auction whose key this subtask holds no column
+    for (``unplaced``; a bid with such a key resolves as an orphan: the
+    planner binds every key a subtask can be sent behind ``key_by()``)
+    and a record whose key lies outside ``[0, num_keys)``
+    (``ring_too_small``). ``open_peak`` and ``pool_peak`` are the most
+    auctions a subtask has held open, and bids waiting, after a step.
 
-    def process_block(self, state, batches, bctx):
-        left, right = batches
-        K, p, e = left.keys.shape
-        S, cap = self._chunk_of(K), self.capacity
-        chunks = K // S
-        max_l = jnp.maximum(state["max_ts_left"][None],
-                            _running_max(self._top_ts(left)))     # [K, P]
-        max_r = jnp.maximum(state["max_ts_right"][None],
-                            _running_max(self._top_ts(right)))
-        wm = self._watermark(max_l, max_r).reshape(chunks, S, p)
-        widths = tuple(min(S * e, w * e) for w in self._PACKED_WINDOWS)
-        (l_pack, l_total), (r_pack, r_total) = (
-            self._packed(b, chunks, w)
-            for b, w in zip((left, right), widths))
-        full = (l_total > widths[0]) | (r_total > widths[1])   # [chunks, P]
-        by_chunk = lambda b: jax.tree_util.tree_map(
-            lambda x: x.reshape((chunks, S) + x.shape[1:]), b)
-        core = self._core(state, left, right, steps_axis=(0,))
-        # a chunk's rows as histogram operands, as wide as either way of
-        # running it makes them
-        wide = max(widths[1] + self.bag_capacity + widths[1], S * cap)
+    **State.** Per own column (:class:`_OwnColumns`; there is no dense
+    form) the open auction's start, end, floor, payload, best price and
+    how many bids counted; per subtask the waiting bids ``(key, price,
+    timestamp)`` in arrival order.
 
-        def quiet(core, xs):
-            l_pack, r_pack, _, _, wm = xs
-            core, rows, emitted, loud = self._chunk(core, l_pack, r_pack, wm)
-            pad = lambda x: jnp.pad(x, ((0, 0), (0, wide - x.shape[-1])))
-            return core, tuple(pad(x) for x in rows), emitted, loud
+    **The block form** (:class:`_ChunkedJoin`: chunks of 32 steps, no
+    loop over the steps, no scatter, no gather, no sort). A chunk's
+    auctions are packed to the front and compared with the subtask's
+    columns once: per column the auction that opens in the chunk, if
+    any, behind the one it held. Those two a column, the ones that
+    exist, are packed into ``_ACTIVE`` intervals with the steps between
+    which each is open, and ONE comparison of the waiting and the
+    chunk's bids — as they lie in their receive windows — with the
+    active intervals carries, per interval, the bids inside it, those
+    that count and the best (a reduction with three results). A row's
+    place is the histogram lane ``closing step x capacity + rank``. The
+    chunk is quiet while no key opens twice in it, its intervals fit
+    ``_ACTIVE``, the waiting bids fit the pool after every step and the
+    packed auctions their window.
 
-        def by_steps(core, xs):
-            _, _, l_raw, r_raw, wm = xs
-            core, out = jax.lax.scan(
-                lambda c, x: self._step(c, *x), core, (l_raw, r_raw, wm))
-            lanes = lambda x: jnp.pad(
-                x.transpose(1, 0, 2).reshape(p, S * cap),
-                ((0, 0), (0, wide - S * cap)))
-            lane = jnp.broadcast_to(jnp.arange(wide, dtype=jnp.int32),
-                                    (p, wide))
-            return core, (lane, lanes(out.keys), lanes(out.values),
-                          lanes(out.timestamps), lanes(out.valid)), \
-                out.count().T
+    A row's key is the payload, not a key the subtask received: the
+    operator does not claim ``emits_received_keys``.
+    """
 
-        def chunk(core, xs):
-            fast, rows, emitted, loud = quiet(core, xs[:-1])
-            loud = loud | xs[-1]
-            core, rows, emitted = jax.lax.cond(
-                jnp.any(loud), lambda: by_steps(core, xs[:-1]),
-                lambda: (fast, rows, emitted))
-            core["step_chunks"] = core["step_chunks"] + loud.astype(jnp.int32)
-            return core, (rows, emitted)
+    num_keys: int
+    length_of: Callable[[jnp.ndarray], jnp.ndarray]
+    floor_of: Callable[[jnp.ndarray], jnp.ndarray]
+    emit_of: Callable[[jnp.ndarray], jnp.ndarray]
+    out_of_orderness: int = 0
+    capacity: int = 32
+    own_columns: Optional[int] = None
+    pool_capacity: int = 1024
 
-        core, (rows, emitted) = jax.lax.scan(
-            chunk, core, (l_pack, r_pack, by_chunk(left), by_chunk(right),
-                          wm, full))
-        out = self._rows_of(rows, emitted, S)         # [chunks, P, S, cap]
-        out = jax.tree_util.tree_map(
-            lambda x: x.transpose(0, 2, 1, 3).reshape(K, p, cap), out)
-        return dict(state, **core, max_ts_left=max_l[-1],
-                    max_ts_right=max_r[-1]), out
+    fence_totals = (
+        ("rows", "winbid.rows"), ("valid", "winbid.valid_bids"),
+        ("under", "winbid.under_reserve"), ("orphans", "winbid.orphan_bids"),
+        ("duplicates", "winbid.duplicate_auctions"),
+        ("no_valid", "winbid.no_valid_bids"),
+        ("step_chunks", "winbid.step_form_chunks"),
+        ("pool_overflow", "winbid.pool_overflow"),
+        ("dropped", "winbid.dropped_rows"),
+        ("unplaced", "winbid.unplaced_records"),
+        ("ring_too_small", "winbid.ring_too_small"))
+    fence_losses = ("pool_overflow", "dropped", "unplaced", "ring_too_small")
+    fence_peaks = (("open_peak", "winbid.open_auctions"),
+                   ("pool_peak", "winbid.pool_fill"))
+
+    #: a chunk's auctions are packed into one receive window; its bids
+    #: are compared as they lie (a window a step)
+    _PACKED_WINDOWS = (1, _ChunkedJoin._CHUNK_STEPS)
+    #: intervals a chunk of more than one step compares its bids with (a
+    #: step compares them with two a column)
+    _ACTIVE = 256
+
+    _COLUMN = (("i_start", _NO_TS), ("i_end", _NO_TS), ("i_floor", 0),
+               ("i_pay", 0), ("i_best", _NO_TS), ("i_hits", 0))
+    _CORE = (("cols", "pool_key", "pool_val", "pool_ts", "pool_n")
+             + tuple(k for k, _ in _COLUMN)
+             + tuple(k for k, _ in fence_totals + fence_peaks))
+
+    def __post_init__(self):
+        if self.own_columns is None:
+            raise ValueError(
+                "join_best_in_interval keeps its intervals on own_columns: "
+                "there is no dense form")
+        if min(self.capacity, self.pool_capacity) < 1:
+            raise ValueError("capacity and pool_capacity must be positive")
+
+    def init_state(self, parallelism: int):
+        p, c, g = parallelism, self._columns, self.pool_capacity
+        state = {k: jnp.zeros((p,), jnp.int32)
+                 for k, _ in self.fence_totals + self.fence_peaks}
+        state.update({k: jnp.full((p, c), free, jnp.int32)
+                      for k, free in self._COLUMN})
+        state.update(
+            cols=self._init_cols(p),
+            pool_key=jnp.zeros((p, g), jnp.int32),
+            pool_val=jnp.zeros((p, g), jnp.int32),
+            pool_ts=jnp.zeros((p, g), jnp.int32),
+            pool_n=jnp.zeros((p,), jnp.int32),
+            max_ts_left=jnp.full((p,), _NO_TS, jnp.int32),
+            max_ts_right=jnp.full((p,), _NO_TS, jnp.int32))
+        return state
+
+    def _row_lanes(self, widths) -> int:
+        return 2 * self._columns
+
+    def _chunk(self, core, left, right, wm):
+        from clonos_tpu.ops.matops import running_count
+        S, cap, g, c = (wm.shape[0], self.capacity, self.pool_capacity,
+                        self._columns)
+        cols = core["cols"]
+        lk, lv, lt, ls, lok = left
+        rk, rv, rt, rs, rok = right
+        n = lambda m, axis=-1: jnp.sum(m.astype(jnp.int32), axis=axis)
+        steps = jnp.arange(S, dtype=jnp.int32)
+        wm_p = wm.T                                                # [P, S]
+        # the first step of the chunk at which the watermark has reached
+        # ``when [P, X]`` (S: not in this chunk)
+        reached = lambda when: n(wm_p[:, None, :] < when[:, :, None])
+        # a column's two intervals side by side, [P, C] x 2 -> [P, 2 C]:
+        # the one it held when the chunk began, the one that opens in it
+        pair = lambda a, b: jnp.stack([a, b], axis=-1).reshape(-1, 2 * c)
+
+        with jax.named_scope("lookup"):
+            open0 = core["i_end"] != _NO_TS
+            close0 = jnp.where(open0, reached(core["i_end"]), 0)   # [P, C]
+            # each column's auctions of the chunk: how many, and of those
+            # that come once the column is free the first (one pass over
+            # the pairs)
+            m = lok[:, :, None] & (lk[:, :, None] == cols[:, None, :])
+            may = m & (ls[:, :, None] >= close0[:, None, :])
+
+            def first(x, y):
+                early = (x[1] < y[1]) | ((x[1] == y[1]) & (
+                    (x[2] < y[2]) | ((x[2] == y[2]) & (x[3] <= y[3]))))
+                return (x[0] + y[0],) + tuple(
+                    jnp.where(early, a, b) for a, b in zip(x[1:], y[1:]))
+
+            came, fs, ft, fv = jax.lax.reduce(
+                (m.astype(jnp.int32), jnp.where(may, ls[:, :, None], S),
+                 jnp.where(may, lt[:, :, None], _NO_LO),
+                 jnp.where(may, lv[:, :, None], _NO_LO)),
+                (jnp.int32(0), jnp.int32(S), jnp.int32(_NO_LO),
+                 jnp.int32(_NO_LO)), first, (1,))
+            opens = fs < S
+            fv = jnp.where(opens, fv, 0)
+            end1 = jnp.where(opens, ft + self.length_of(fv), _NO_TS)
+            close1 = jnp.where(opens, jnp.maximum(fs + 1, reached(end1)), S)
+            # not quiet: a key that opens a second time inside the chunk
+            loud = jnp.any(m & (ls[:, :, None] >= close1[:, None, :]),
+                           axis=(1, 2))
+            on = pair(open0, opens)
+            starts = pair(core["i_start"], jnp.where(opens, ft, _NO_TS))
+            ends = pair(core["i_end"], end1)
+            floors = pair(core["i_floor"], self.floor_of(fv))
+            since = pair(jnp.full_like(fs, -1), fs)    # open after this step
+            until = pair(close0, close1)               # ... through this one
+            fields = (pair(cols, cols), starts, ends, floors, since, until)
+            width = 2 * c if S == 1 else min(self._ACTIVE, 2 * c)
+            if width == 2 * c:                   # as they lie
+                a_on = on
+            else:
+                fields, total = _pack_by_rank(on, fields, width)
+                a_on = jnp.arange(width, dtype=jnp.int32) < total[:, None]
+                loud = loud | (total > width)
+            a_key, a_start, a_end, a_floor, a_since, a_until = (
+                x[:, None, :] for x in fields)
+        with jax.named_scope("place"):
+            # the bids that wait, then the chunk's, in the order they
+            # came: when each is resolved
+            cat = lambda a, b: jax.lax.optimization_barrier(
+                jnp.concatenate([a, b], axis=-1))
+            q_ok = cat(jnp.arange(g, dtype=jnp.int32)[None, :]
+                       < core["pool_n"][:, None], rok)
+            q_key, q_val, q_ts = (cat(core["pool_key"], rk),
+                                  cat(core["pool_val"], rv),
+                                  cat(core["pool_ts"], rt))
+            born = cat(jnp.zeros_like(core["pool_key"]), rs)
+            at = jnp.maximum(born, reached(q_ts))                  # [P, N]
+            resolved = q_ok & (at < S)
+            waits = q_ok & ~resolved
+            # one pass over the (bid, interval) pairs: the interval is
+            # its key's, open when the bid is resolved, and holds its t
+            k, v, t, r = (x[:, :, None] for x in (q_key, q_val, q_ts, at))
+            inside = (resolved[:, :, None] & a_on[:, None, :] & (k == a_key)
+                      & (r > a_since) & (r <= a_until)
+                      & (t >= a_start) & (t < a_end))
+            counts = inside & (v >= a_floor)
+            n_in, n_valid, best = jax.lax.reduce(
+                (inside.astype(jnp.int32), counts.astype(jnp.int32),
+                 jnp.where(counts, v, _NO_TS)),
+                (jnp.int32(0), jnp.int32(0), jnp.int32(_NO_TS)),
+                lambda x, y: (x[0] + y[0], x[1] + y[1],
+                              jnp.maximum(x[2], y[2])), (1,))      # [P, A]
+            # the bids waiting after each step of the chunk
+            fill = n((q_ok[:, :, None] & (born[:, :, None] <= steps)
+                      & (r > steps)), axis=1)                      # [P, S]
+            loud = loud | jnp.any(fill > g, axis=-1)
+            (pool_key, pool_val, pool_ts), w_total = _pack_by_rank(
+                waits, (q_key, q_val, q_ts), g)
+        with jax.named_scope("emit"):
+            if width != 2 * c:       # back onto the columns' lanes
+                mine = on[:, :, None] & (
+                    (running_count(on) - 1)[:, :, None]
+                    == jnp.arange(width, dtype=jnp.int32))
+                n_valid = jnp.sum(jnp.where(mine, n_valid[:, None, :], 0),
+                                  axis=-1)
+                best = jnp.max(jnp.where(mine, best[:, None, :], _NO_TS),
+                               axis=-1)
+            hits = pair(core["i_hits"], jnp.zeros_like(fs)) + n_valid
+            top = jnp.maximum(
+                pair(core["i_best"], jnp.full_like(fs, _NO_TS)), best)
+            pay = pair(core["i_pay"], self.emit_of(fv))
+            # an interval that closes in the chunk is a row at its
+            # closing step if a bid counted, in key order
+            closes = on & (until < S)
+            fire = closes & (hits > 0)
+            f_hot = fire[:, None, :] & (until[:, None, :]
+                                        == steps[None, :, None])
+            rank = jnp.sum(jnp.where(f_hot, running_count(f_hot) - 1, 0),
+                           axis=1)                                 # [P, 2C]
+            n_rows = n(f_hot)                                      # [P, S]
+            emitted = jnp.minimum(n_rows, cap)
+            rows = (until * cap + rank, pay, top, ends - 1,
+                    fire & (rank < cap))
+            # what a column holds when the chunk ends
+            by_col = lambda x: x.reshape(-1, c, 2)
+            keep1 = opens & (close1 == S)
+            keep0 = ~opens & open0 & (close0 == S)
+            held = lambda x, none: jnp.where(
+                keep1, by_col(x)[..., 1],
+                jnp.where(keep0, by_col(x)[..., 0], none))
+            column = {key: held(x, none) for (key, none), x in zip(
+                self._COLUMN, (starts, ends, floors, pay, top, hits))}
+        at_step = steps[None, :, None]
+        open_after = n(on[:, None, :] & (since[:, None, :] <= at_step)
+                       & (until[:, None, :] > at_step))            # [P, S]
+        new = dict(
+            core, **column, pool_key=pool_key, pool_val=pool_val,
+            pool_ts=pool_ts, pool_n=jnp.minimum(w_total, g),
+            rows=core["rows"] + n(emitted),
+            valid=core["valid"] + n(n_valid),
+            under=core["under"] + n(n_in) - n(n_valid),
+            orphans=core["orphans"] + n(resolved) - n(n_in),
+            duplicates=core["duplicates"] + n(came) - n(opens),
+            no_valid=core["no_valid"] + n(closes & ~fire),
+            pool_overflow=core["pool_overflow"] + jnp.maximum(w_total - g, 0),
+            dropped=core["dropped"] + n(n_rows - emitted),
+            unplaced=core["unplaced"] + n(lok) - n(came),
+            open_peak=jnp.maximum(core["open_peak"],
+                                  jnp.max(open_after, axis=-1)),
+            pool_peak=jnp.maximum(core["pool_peak"],
+                                  jnp.minimum(jnp.max(fill, axis=-1), g)))
+        return new, rows, emitted, loud
 
 
 @dataclasses.dataclass

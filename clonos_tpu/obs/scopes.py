@@ -20,11 +20,16 @@ same names where they run the same code. A path reads
 ``.../lookup``      a record's own column (``_OwnColumns._column``: the
                     windowed top and the window join); with it, a
                     step's sum, earliest and latest per own column
-                    (``SessionWindowOperator._arrivals``)
+                    (``SessionWindowOperator._arrivals``); a chunk's
+                    opening records against the own columns and the
+                    intervals that are active in it
+                    (``BestInIntervalJoinOperator._chunk``)
 ``.../place``       records into ``slot x key`` lanes (``_EventTimeSlots
                     ._block_place``); a step's arrivals into sessions: the
                     running latest, where a session starts, where two
-                    merge (``SessionWindowOperator.process_block``)
+                    merge (``SessionWindowOperator.process_block``); a
+                    chunk's probes into its active intervals, the best
+                    of each (``BestInIntervalJoinOperator._chunk``)
 ``.../segsum``      the accumulators' running sum that restarts at a fire
                     (``_block_accumulate``), or where a session starts
                     (``SessionWindowOperator.process_block``)

@@ -317,6 +317,9 @@ def _contract_cases():
         ("sliding", ops.SlidingEventTimeWindowOperator(
             num_keys=13, window_size=300, slide=100,
             out_of_orderness=100)),
+        ("window-mean", ops.EventTimeWindowMeanOperator(
+            num_keys=13, window_size=300, slide=100,
+            out_of_orderness=100)),
         ("window-join", ops.EventTimeWindowJoinOperator(
             num_keys=13, window_size=400, out_of_orderness=100,
             capacity=16)),

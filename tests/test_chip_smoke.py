@@ -76,6 +76,12 @@ def test_part_i_tiny_incremental_join_in_wide_blocks_against_narrow_ones():
     assert rows > 1000 and flushed > 100 and stepped > 0
 
 
+def test_part_q_tiny_best_in_interval_in_wide_blocks_against_narrow_ones():
+    rows, won, valid, stepped = chip_smoke.check_best_in_interval_in_a_job(
+        21, spe=128, epochs=6)
+    assert rows > 100 and won > 500 and valid > won
+
+
 def test_main_refuses_to_run_without_a_tpu(capsys):
     assert chip_smoke.main([]) != 0
     out = capsys.readouterr()

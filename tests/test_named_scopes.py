@@ -72,7 +72,21 @@ TOPOLOGIES = {
         "vertex/join" + part
         for part in ("", "/compact", "/compact/hist", "/lookup", "/place",
                      "/place/hist", "/emit", "/emit/hist")}),
+    "nexmark-average-price": ("tiny-nexmark-q4", RANKED | {
+        "vertex/host-source", "vertex/parse", "vertex/auctions",
+        "vertex/bids", "vertex/sink"} | {
+        "vertex/winning" + part
+        for part in ("", "/compact", "/compact/hist", "/lookup",
+                     "/lookup/hist", "/place", "/place/hist", "/emit",
+                     "/emit/hist")} | {
+        "vertex/mean" + part
+        for part in ("", "/place", "/place/hist", "/segsum")}),
 }
+
+
+#: the topologies whose two-input vertex takes a block in chunks
+#: (``operators._ChunkedJoin``), and that vertex
+CHUNKED = {"nexmark-local-items": "join", "nexmark-average-price": "winning"}
 
 
 def tiny_config(name):
@@ -147,14 +161,14 @@ def test_block_names_every_scope_its_topology_should_produce(lowered):
     (``vertex/join/while/body/closed_call/lookup/...``)."""
     topology, runner, cfg, text, _ = lowered
     found = scopes_in(lower_block(runner, cfg).compile().as_text())
-    if topology == "nexmark-local-items":
+    if topology in CHUNKED:
         # the chunk that runs step by step is a loop in a conditional in
         # a loop, and XLA:CPU calls the inner body where the TPU's
         # compiler inlines it: its kernels may show without (part of)
         # their caller's path here, by whichever trace of the jitted
         # histogram came first (tests/test_tpu_aot.py reads the TPU's
         # program)
-        found -= {"hist", "vertex/join/hist"}
+        found -= {"hist", f"vertex/{CHUNKED[topology]}/hist"}
     else:
         assert scopes_in(text) == found
     assert found == COMMON | TOPOLOGIES[topology][1]

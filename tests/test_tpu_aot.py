@@ -384,6 +384,83 @@ def test_incremental_join_block_form_compiles_at_the_cells_widths(v5e):
         P * 4 * E * cfg["own_columns"]) * 4
 
 
+def test_best_in_interval_block_form_compiles_at_the_cells_widths(v5e):
+    """``nexmark-q4``'s ``winning`` vertex at its own widths — 8,192 ids
+    in 640 own columns a subtask, 768 receive slots a subtask a step on
+    both inputs, 3,072 waiting bids, 32 rows — over a whole block of
+    1,024 steps of 16 subtasks, inside the job's block program: under
+    ``vertex/winning`` and ``vertex/mean`` no scatter, no gather and no
+    sort; no loop over the block's steps (the loops are the 32 chunks,
+    and the 32 steps of a chunk that is not quiet, under a conditional);
+    the auctions' packing, the active intervals, the pool and the rows
+    take the Mosaic kernel; and the comparison of a chunk's 27,648 bids
+    with its 256 active intervals fuses without a ``[P, N, 256]``
+    array."""
+    import json
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    for p in (bench, root):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    from benchlib import job
+    from benchlib.byname import module_at
+    from clonos_tpu.runtime.executor import CompiledJob
+    with open(os.path.join(bench, "configs", "nexmark-q4.json")) as f:
+        cfg = json.load(f)
+    compiled = CompiledJob(
+        module_at(job.topology_file(cfg, "job.py")).build(cfg),
+        log_capacity=8192, max_epochs=8, inflight_ring_steps=2048)
+    assert [p.route for _, p in sorted(compiled.edge_plans.items())] == [
+        "dynamic"] * 3
+    K, P, E = cfg["block_steps"], cfg["parallelism"], cfg["edge_capacity"]
+    op = compiled.job.vertices[4].operator
+    S = op._chunk_of(K)
+    assert (S, K // S) == (32, 32)
+    mesh = Mesh(np.array(v5e[:1]), ("tasks",))
+    with histogram.kernel_mesh(mesh, "tasks"):
+        lowered, records = lower_block(compiled, K,
+                                       SingleDeviceSharding(v5e[0]))
+    forms = [r["args"] for r in records if r["name"] == "hist.kernel"]
+    assert {f["form"] for f in forms} == {"mxu"}
+    shapes = {(f["rows"], f["cols"], f["lanes"]) for f in forms}
+    chunks, pool, cap, cols, active = (
+        K // S, cfg["pool_capacity"], cfg["winning_capacity"],
+        cfg["own_columns"], op._ACTIVE)
+    assert {(chunks * P, S * E, E),                    # the auctions packed
+            (P, 2 * cols, active),                     # the active intervals
+            (P, pool + S * E, pool),                   # the pool
+            (chunks * P, 2 * cols, S * cap)} <= shapes           # the rows
+    exe = lowered.compile()
+    text = exe.as_text()
+    for vertex, least in (("winning", 500), ("mean", 100)):
+        mine = [line for line in text.splitlines()
+                if f"vertex/{vertex}" in line]
+        assert len(mine) > least
+        for op_name in ("scatter", "gather", "sort"):
+            assert not [line for line in mine
+                        if re.search(rf"= .* {op_name}\(", line)], op_name
+        loops = sorted(
+            m.group(1) for line in mine if " while(" in line for m in [
+                re.search(rf'op_name="[^"]*?vertex/{vertex}/([^"]*)"', line)]
+            if m)
+        # two loops, by the scope they were traced under: the chunks, and
+        # — in the conditional's branch — the steps of a chunk that is
+        # not quiet; the mean has none
+        assert loops == (
+            ["while", "while/body/closed_call/cond/branch_1_fun/while"]
+            if vertex == "winning" else [])
+    # the [P, N, 256] comparison never exists as an array: its shape
+    # shows inside fusions only
+    pairs, computation = f"[{P},{pool + S * E},{active}]", ""
+    assert pairs in text
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            computation = line.split()[0]
+        assert pairs not in line or "fused_computation" in computation, line
+
+
 def test_window_join_block_form_compiles_at_the_cells_widths(v5e):
     """``nexmark-q8``'s ``join`` vertex at its own widths — 4,096 ids in
     384 own columns a subtask (``window_join`` derived them: 280 ids at
