@@ -49,7 +49,16 @@ it pass off the device:
      (tests/test_user_sessions.py); what only the chip's compiler can get
      wrong is the block form fused into a whole block program (PR 40: a
      restarting sum came out wrong there from step 640 on, and not
-     alone). Not in the default parts.
+     alone). Then the same at 8 subtasks and 512 bids a step, receive
+     windows of 512 slots: wide enough for the lookup's head and tails
+     (PR 49; the hot bidder's owner is sent ~390 bids a step, past the
+     128-slot head), and a third run in blocks of 1,024 steps with the
+     split switched off, every slot compared: all three streams equal,
+     no block on the dense branch in the first two. Then the three again
+     with three hot bidders, a quarter of a step's bids each: a step
+     with three targets past the head sends its block down the ``cond``'s
+     dense branch, inside the block program, and the blocks of 1,024
+     steps must take it. Not in the default parts.
   I  the incremental join (NEXmark query 3) inside a job's block program:
      the benchmark's ``nexmark-local-items`` job at its tiny stand-in's
      sizes (persons that expire inside the run, auctions that come after
@@ -638,17 +647,28 @@ def check_block_until_ready() -> None:
             "after it still waited")
 
 
-def check_sessions_in_a_job(seed: int, spe: int = 2048, epochs: int = 3
+def check_sessions_in_a_job(seed: int, spe: int = 2048, epochs: int = 3,
+                            p: int = 4, batch: int = 16, hot: int = 1
                             ) -> int:
     """Part S: the committed stream of a session-window job run in
-    blocks of 1,024 steps against the same job run in blocks of 16;
-    returns the rows compared."""
+    blocks of 1,024 steps against the same job run in blocks of 16 and,
+    where its receive windows (``p`` of ``p x batch`` slots) are wide
+    enough for the lookup's head and tails, against blocks of 1,024
+    steps with the split switched off; returns the rows compared. With
+    one ``hot`` bidder no block may take the lookup's dense branch; with
+    three, each sent a quarter of a step's bids, blocks of 1,024 steps
+    must (a step with three targets past the head) and the streams
+    agree all the same."""
+    from unittest import mock
+
     import jax.numpy as jnp
+    from clonos_tpu.api import operators as ops
     from clonos_tpu.api.environment import StreamEnvironment
     from clonos_tpu.api.feeds import ListFeedReader
     from clonos_tpu.runtime.cluster import ClusterRunner
 
-    p, batch, nk = 4, 16, 2048
+    nk = 2048
+    splits = ops._OwnColumns._splits(p, p * batch)
     feed = np.random.RandomState(seed).randint(
         1, 1 << 28, (p, epochs * spe * batch, 2)).astype(np.int32)
 
@@ -658,7 +678,8 @@ def check_sessions_in_a_job(seed: int, spe: int = 2048, epochs: int = 3
         # the rest by the last 1,000 persons and 10 ahead
         ts = 7 * step + ((vals >> 2) & 1023) % 7
         last = ts // 5
-        bidder = jnp.where((vals & 3) != 0, last // 100 * 100 + 1,
+        bidder = jnp.where((vals & 3) != 0,
+                           last // 100 * 100 + 1 + ((vals & 3) - 1) % hot,
                            last - 999 + (vals >> 12) % 1010)
         return bidder % nk, jnp.ones_like(vals), ts
 
@@ -683,10 +704,35 @@ def check_sessions_in_a_job(seed: int, spe: int = 2048, epochs: int = 3
         lost = runner.executor.check_overflow()
         if lost:
             raise AssertionError(f"sessions, blocks of {block_steps}: {lost}")
+        dense = int(np.asarray(runner.executor.vertex_state(2)
+                               ["dense_blocks"]).sum())
+        if dense and hot == 1:
+            raise AssertionError(
+                f"sessions, blocks of {block_steps}: {dense} blocks "
+                f"compared every slot (more than two targets past the "
+                f"head in one step)")
         (txn,) = runner.txn_logs.values()
-        return sort_rows(np.asarray(txn.committed_stream(), np.int32))
+        return sort_rows(np.asarray(txn.committed_stream(), np.int32)), dense
 
-    wide, narrow = committed(1024), committed(16)
+    (wide, dense), (narrow, dense16) = committed(1024), committed(16)
+    if splits and hot > 2:
+        if not dense:
+            raise AssertionError(
+                f"sessions, {hot} hot bidders: no block of 1,024 steps "
+                f"took the lookup's dense branch")
+        say(f"S sessions, {hot} hot bidders: {dense} of "
+            f"{epochs * -(-spe // 1024)} blocks of 1,024 steps and "
+            f"{dense16} of {epochs * spe // 16} blocks of 16 on the "
+            f"lookup's dense branch")
+    if splits:
+        with mock.patch.object(ops._OwnColumns, "_splits",
+                               staticmethod(lambda p, b: False)):
+            every_slot, _ = committed(1024)
+        if wide.shape != every_slot.shape or not np.array_equal(
+                wide, every_slot):
+            raise AssertionError(
+                f"sessions: {wide.shape[0]} rows committed by head and "
+                f"tails, {every_slot.shape[0]} with every slot compared")
     if wide.shape != narrow.shape or not np.array_equal(wide, narrow):
         raise AssertionError(
             f"sessions: {wide.shape[0]} rows committed in blocks of 1,024 "
@@ -868,7 +914,7 @@ def print_routes(tracer, since: int, part: str) -> int:
     # of the event-time windows (fired, late, dropped; the most sessions
     # a subtask has held open)
     for name, n in sorted(tracer.counters().items()):
-        if name.startswith(("exchange.", "window.", "join.")):
+        if name.startswith(("exchange.", "window.", "join.", "lookup.")):
             say(f"{part} counter {name} = {n}")
     return len(recs)
 
@@ -929,9 +975,15 @@ def main(argv=None) -> int:
     if "S" in parts:
         t0 = time.monotonic()
         rows = check_sessions_in_a_job(args.seed)
+        split = check_sessions_in_a_job(args.seed, p=8, batch=64)
+        crowded = check_sessions_in_a_job(args.seed, p=8, batch=64, hot=3)
         mark = print_routes(tracer, mark, "S")
         say(f"S pass: session windows, blocks of 1,024 steps == blocks of "
-            f"16 over {rows} rows ({time.monotonic() - t0:.1f}s)")
+            f"16 over {rows} rows; receive windows of 8 x 512 by head and "
+            f"tails, blocks of 1,024 steps == blocks of 16 == every slot "
+            f"compared over {split} rows, and over {crowded} rows with "
+            f"three hot bidders, the blocks of 1,024 steps on the dense "
+            f"branch ({time.monotonic() - t0:.1f}s)")
 
     if "I" in parts:
         t0 = time.monotonic()

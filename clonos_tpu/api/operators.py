@@ -105,7 +105,12 @@ class Operator:
 
     #: per-subtask int32 totals in operator state that the fence's one
     #: health read brings back, and the counter each feeds
-    #: (``<counter>.<vertex name>``): ``((state key, counter), ...)``
+    #: (``<counter>.<vertex name>``): ``((state key, counter), ...)``.
+    #: A total is what a replay of the lane counts again, with one
+    #: exception: ``lookup.dense_blocks`` says which branch a block of
+    #: ALL subtasks took, which one replayed lane cannot know, so a
+    #: recovered subtask's stands at its checkpoint's and the counter is
+    #: fed no negative growth (``ClusterRunner._absorb_fence_health``)
     fence_totals: Tuple[Tuple[str, str], ...] = ()
 
     #: the state keys among ``fence_totals`` that count LOSSES the job's
@@ -1329,6 +1334,56 @@ class IntervalJoinOperator(TwoInputOperator):
         return {"lv": lv, "lt": lt, "lm": lm, "cursor": cur}, out
 
 
+#: slots at the front of every target's receive window that a block's
+#: own-column lookup compares as they lie: one lane tile
+_HEAD = 128
+#: targets a step whose slots past the head the lookup compares too (the
+#: step's first *over* targets, in subtask order). Two, because a hot id
+#: that moves at most once a step straddles at most two owners in one
+#: step (``nexmark-q5``'s moves every 1.5 steps, ``nexmark-q11``'s every
+#: 4.5), and every other target of a step of ``P x batch`` records holds
+#: a fraction of a head
+_TAILS = 2
+
+
+class _HeadAndTails(NamedTuple):
+    """A block's receive windows ``[K, P, B]`` as the slots a target can
+    fill: the head ``[K, P, _HEAD]`` of every target, and the slots past
+    it ``[_TAILS, K, B - _HEAD]`` of each step's first ``_TAILS`` over
+    targets, picked out — and put back — by a one-hot select over the
+    subtasks (no gather). The tails' axis leads, so that the steps and
+    not the two tails lie along the sublanes of what is compared."""
+
+    #: bool ``[_TAILS, K, P]``: the step's j-th over target, one-hot
+    pick: jnp.ndarray
+    #: bool ``[]``: some step has more over targets than tails
+    crowded: jnp.ndarray
+
+    def head(self, x: jnp.ndarray) -> jnp.ndarray:
+        return x[..., :_HEAD]
+
+    def tails(self, x: jnp.ndarray) -> jnp.ndarray:
+        """``x [K, P, B]`` past the head, of the picked targets (a tail
+        that picks none: zeros, nothing valid)."""
+        pick, past = self.pick[..., None], x[None, :, :, _HEAD:]
+        if x.dtype == jnp.bool_:
+            return jnp.any(pick & past, axis=2)
+        return jnp.sum(jnp.where(pick, past, 0), axis=2)
+
+    def own(self, cols: jnp.ndarray) -> jnp.ndarray:
+        """The picked targets' columns, ``cols [P, C]`` -> ``[_TAILS, K,
+        C]``."""
+        return jnp.sum(jnp.where(self.pick[..., None], cols, 0), axis=2)
+
+    def back(self, x: jnp.ndarray, fill) -> jnp.ndarray:
+        """``x [_TAILS, K, N]`` at its target, ``fill`` at every other:
+        ``[K, P, N]``."""
+        out = jnp.full_like(x[0, :, None], fill)
+        for pick, tail in zip(self.pick, x):
+            out = jnp.where(pick[:, :, None], tail[:, None], out)
+        return out
+
+
 class _OwnColumns:
     """A table with a column a key, on every subtask (dense) or, with
     ``own_columns``, only for the keys a subtask owns: what every such
@@ -1337,7 +1392,26 @@ class _OwnColumns:
     the planner binds it (``CompiledJob._bind_own_columns``), so the
     binding is state — one operator object serves any number of plans,
     a checkpoint carries it. A record finds its column by its key's
-    rank among the bound keys (:meth:`_column`)."""
+    rank among the bound keys (:meth:`_column`).
+
+    **A block's lookup works on the slots a target can fill**
+    (:meth:`_by_head_and_tails`; the windowed top's :meth:`_column_block`
+    and the session window's ``_arrivals_block``). A receive window is as
+    wide as the fullest target's worst step, and a step holds ``P x
+    batch`` records: under a hot key one target fills its window and the
+    others a few slots. So the block form compares the first
+    :data:`_HEAD` slots of every target, and all further slots of the
+    step's first :data:`_TAILS` targets that hold a valid record there
+    (*over* targets: read from the mask, so slots that are no prefix —
+    a static plan's — are as right as the exchange's packed ones). A
+    block in which some step has more over targets compares every slot
+    of every target as the step form does, under one ``lax.cond``, and
+    counts in ``dense_blocks`` (``lookup.dense_blocks``, one a block:
+    how often the split did not engage). Both branches give the dense
+    form's answers bit for bit. The split is built only for a window it
+    at least halves (:meth:`_splits`); any other shape — a narrow
+    window, one lane of a replay — runs the dense form alone, no
+    ``cond``, and counts nothing."""
 
     num_keys: int
     own_columns: Optional[int]
@@ -1362,15 +1436,70 @@ class _OwnColumns:
                 f"{state['cols'].shape}")
         return dict(state, cols=cols)
 
+    @staticmethod
+    def _rank(cols, keys):
+        """``(column, held)`` of each key of a row ``keys [..., B]`` among
+        the row's columns ``cols [..., C]``. The bound keys are an
+        ascending prefix, so a held key's column is the count of bound
+        keys below it."""
+        k, c = keys[..., None], cols[..., None, :]
+        return (jnp.sum((k > c).astype(jnp.int32), axis=-1),
+                jnp.any(k == c, axis=-1))
+
     @scoped("lookup")
     def _column(self, cols, keys):
         """``(column, held)`` of each record's key on its subtask:
-        ``cols [P, C]`` against ``keys [..., P, B]``. The bound keys are
-        an ascending prefix, so a held key's column is the count of
-        bound keys below it."""
-        k, c = keys[..., None], cols[:, None, :]
-        return (jnp.sum((k > c).astype(jnp.int32), axis=-1),
-                jnp.any(k == c, axis=-1))
+        ``cols [P, C]`` against ``keys [..., P, B]``, every slot against
+        every column."""
+        return self._rank(cols, keys)
+
+    @staticmethod
+    def _splits(p: int, b: int) -> bool:
+        """Whether head and tails compare at most half of a ``[P, B]``
+        receive window's slots (16 x 768: 3,328 of 12,288; not 16 x 192,
+        not one lane, not a window of one head)."""
+        return b > _HEAD and 2 * (p * _HEAD + _TAILS * (b - _HEAD)) <= p * b
+
+    def _by_head_and_tails(self, mask, dense, split):
+        """A block's lookup over the receive windows ``mask [K, P, B]``
+        marks (class docstring): ``(result, crowded)`` — ``split(parts)``
+        on the :class:`_HeadAndTails` of ``mask`` or, in a block with a
+        step of more over targets than tails, ``dense()``; ``crowded``
+        says which (None: the shape has no split, ``dense()`` it is)."""
+        _, p, b = mask.shape
+        if not self._splits(p, b):
+            return dense(), None
+        over = jnp.any(mask[..., _HEAD:], axis=-1)                 # [K, P]
+        nth = jnp.cumsum(over.astype(jnp.int32), axis=1) - 1
+        parts = _HeadAndTails(
+            pick=over & (
+                nth == jnp.arange(_TAILS, dtype=jnp.int32)[:, None, None]),
+            crowded=jnp.any(nth[:, -1] >= _TAILS))
+        return (jax.lax.cond(parts.crowded, dense, lambda: split(parts)),
+                parts.crowded)
+
+    @staticmethod
+    def _dense_blocks(state, crowded):
+        """``state["dense_blocks"]`` after a block: one more on the
+        first subtask (the fence sums the leaf) where it was crowded."""
+        n = state["dense_blocks"]
+        if crowded is None:
+            return n
+        return n + (crowded & (jnp.arange(n.shape[0]) == 0)).astype(n.dtype)
+
+    @scoped("lookup")
+    def _column_block(self, cols, keys, valid):
+        """:meth:`_column` of a block's receive windows ``keys [K, P,
+        B]``, on head and tails: the same ``(column, held)`` at every
+        valid slot (a slot with no record reads ``(0, False)`` where the
+        split left it out), and whether the block was crowded."""
+        def split(parts):
+            head = self._rank(cols, parts.head(keys))
+            tails = self._rank(parts.own(cols), parts.tails(keys))
+            return tuple(jnp.concatenate([h, parts.back(t, 0)], axis=-1)
+                         for h, t in zip(head, tails))
+        return self._by_head_and_tails(
+            valid, lambda: self._rank(cols, keys), split)
 
     def _lane_words(self, cols, slots: int):
         """``slot * num_keys + key`` of every lane of a subtask's
@@ -1697,7 +1826,12 @@ class EventTimeWindowTopOperator(_OwnColumns, _EventTimeSlots, Operator):
     read as no record. A record's column is its key's rank among the
     subtask's bound keys, by comparison with all of them (no gather by a
     computed index); the block form has no scan over the steps and no
-    scatter, and agrees with the step form bit for bit.
+    scatter, and agrees with the step form bit for bit. It looks up
+    only the slots a target can fill — a 128-slot head of every receive
+    window and the rest of a step's two fullest (:class:`_OwnColumns`)
+    — where the window is wide enough for that to halve the work;
+    ``dense_blocks`` counts the blocks that compared every slot all the
+    same.
     """
 
     num_keys: int
@@ -1711,7 +1845,8 @@ class EventTimeWindowTopOperator(_OwnColumns, _EventTimeSlots, Operator):
     emits_received_keys = True
 
     fence_totals = EventTimeWindow.fence_totals + (
-        ("dropped", "window.dropped_rows"),)
+        ("dropped", "window.dropped_rows"),
+        ("dense_blocks", "lookup.dense_blocks"))
     fence_losses = ("late", "dropped")
 
     def __post_init__(self):
@@ -1807,7 +1942,8 @@ class EventTimeWindowTopOperator(_OwnColumns, _EventTimeSlots, Operator):
         p = batches.keys.shape[1]
         w, c = self.open_windows, self._columns
         valid = batches.valid
-        col, held = self._column(state["cols"], batches.keys)
+        (col, held), crowded = self._column_block(
+            state["cols"], batches.keys, valid)
         max_ts = self._block_max_ts(state["max_ts"], valid,
                                     batches.timestamps)
         wm = max_ts - self.out_of_orderness                       # [K, P]
@@ -1824,7 +1960,8 @@ class EventTimeWindowTopOperator(_OwnColumns, _EventTimeSlots, Operator):
             state, acc=acc[-1].reshape(p, w, c), win=held_win[-1],
             max_ts=max_ts[-1], late=state["late"] + n(valid & ~ok_any),
             fired=state["fired"] + out.count().sum(axis=0),
-            dropped=state["dropped"] + dropped.sum(axis=0)), out
+            dropped=state["dropped"] + dropped.sum(axis=0),
+            dense_blocks=self._dense_blocks(state, crowded)), out
 
 
 #: "no record yet": the fold's identity for an earliest timestamp
@@ -1939,9 +2076,14 @@ class SessionWindowOperator(_OwnColumns, Operator):
     The block form has no scan over the steps, no scatter and no gather
     by a computed index: an arrival's count, sum, earliest and latest
     come from the comparison of the step's records with the subtask's
-    columns; the newest session's latest is a running maximum along the
-    steps, a session starts where an arrival lies more than ``gap``
-    above it (a *break*) or the watermark has passed it, a break is
+    columns — of the slots a target can fill, a 128-slot head of every
+    receive window and the rest of a step's two fullest, where the
+    window is wide enough for that to halve the work
+    (:class:`_OwnColumns`; ``dense_blocks`` counts the blocks that
+    compared every slot all the same); the newest session's latest is
+    a running maximum along the steps, a session starts where an
+    arrival lies more than ``gap`` above it (a *break*) or the
+    watermark has passed it, a break is
     undone when a later arrival reaches back across it while the older
     session is still open, sums are running sums that restart at the
     breaks that stand, and a session's row leaves at the one step where
@@ -1959,7 +2101,8 @@ class SessionWindowOperator(_OwnColumns, Operator):
 
     fence_totals = EventTimeWindow.fence_totals + (
         ("dropped", "window.dropped_rows"),
-        ("disordered", "window.disordered_arrivals"))
+        ("disordered", "window.disordered_arrivals"),
+        ("dense_blocks", "lookup.dense_blocks"))
     fence_losses = ("late", "dropped", "disordered")
     fence_peaks = (("open_peak", "window.open_sessions"),)
 
@@ -1993,34 +2136,73 @@ class SessionWindowOperator(_OwnColumns, Operator):
         return jnp.where(max_ts == _NO_TS, _NO_TS,
                          max_ts - self.out_of_orderness)
 
-    @scoped("lookup")
-    def _arrivals(self, cols, b: RecordBatch, wm):
-        """``(sum, earliest, latest)`` per column ``[..., P, C]`` of the
-        records of ``b [..., P, B]`` a step takes under the watermark
-        ``wm [..., P]`` (no record: 0, ``_NO_LO``, ``_NO_TS``), and how
-        many it does not take ``[..., P]``. One comparison of every
-        record with every column carries all four."""
-        took = b.valid & (b.timestamps + self.gap > wm[..., None])
-        m = took[..., None] & (b.keys[..., None] == cols[:, None, :])
-        ts = b.timestamps[..., None]                    # [..., P, B, C]
+    def _took(self, b: RecordBatch, wm):
+        """The records of ``b [..., P, B]`` a step takes under the
+        watermark ``wm [..., P]``."""
+        return b.valid & (b.timestamps + self.gap > wm[..., None])
+
+    @staticmethod
+    def _fold(cols, keys, values, ts, took):
+        """``(count, sum, earliest, latest)`` per column ``[..., C]`` of
+        the records ``[..., B]`` that ``took`` marks, a row of columns
+        ``cols [..., C]`` a row of records (no record: 0, 0, ``_NO_LO``,
+        ``_NO_TS``). One comparison of every record with every column of
+        its row carries all four."""
+        m = took[..., None] & (keys[..., None] == cols[..., None, :])
+        ts = ts[..., None]                              # [..., B, C]
         # one pass over the pairs: a reduction with four results (four
         # reductions of their own each compare every pair again: on the
         # v5e 128 ms a block of the cell's 9.4e9 pairs, half of it the
         # count)
-        n, s, lo, hi = jax.lax.reduce(
-            (m.astype(jnp.int32), jnp.where(m, b.values[..., None], 0),
+        return jax.lax.reduce(
+            (m.astype(jnp.int32), jnp.where(m, values[..., None], 0),
              jnp.where(m, ts, _NO_LO), jnp.where(m, ts, _NO_TS)),
             (jnp.int32(0), jnp.int32(0), jnp.int32(_NO_LO),
              jnp.int32(_NO_TS)),
             lambda x, y: (x[0] + y[0], x[1] + y[1],
                           jnp.minimum(x[2], y[2]), jnp.maximum(x[3], y[3])),
             (m.ndim - 2,))
-        # a column bound to no key holds nothing, whatever key matched it
+
+    @staticmethod
+    def _bound_arrivals(cols, b: RecordBatch, n, s, lo, hi):
+        """What :meth:`_arrivals` returns, of the fold of ``b``: a column
+        bound to no key holds nothing, whatever key matched it, and a
+        valid record no column took is late."""
         bound = cols != NO_KEY
         late = (jnp.sum(b.valid.astype(jnp.int32), axis=-1)
                 - jnp.sum(jnp.where(bound, n, 0), axis=-1))
         return (jnp.where(bound, s, 0), jnp.where(bound, lo, _NO_LO),
                 jnp.where(bound, hi, _NO_TS), late)
+
+    @scoped("lookup")
+    def _arrivals(self, cols, b: RecordBatch, wm):
+        """``(sum, earliest, latest)`` per column ``[..., P, C]`` of the
+        records of ``b [..., P, B]`` a step takes under the watermark
+        ``wm [..., P]`` (no record: 0, ``_NO_LO``, ``_NO_TS``), and how
+        many it does not take ``[..., P]``: every record against every
+        column of its subtask."""
+        return self._bound_arrivals(cols, b, *self._fold(
+            cols, b.keys, b.values, b.timestamps, self._took(b, wm)))
+
+    @scoped("lookup")
+    def _arrivals_block(self, cols, b: RecordBatch, wm):
+        """:meth:`_arrivals` of a block ``b [K, P, B]``, on head and
+        tails (:class:`_OwnColumns`): the tails' folds go back to their
+        targets and into the head's with ``+``, ``+``, ``min``, ``max``
+        — the same four numbers a column, bit for bit — and whether the
+        block was crowded."""
+        fields = (b.keys, b.values, b.timestamps, self._took(b, wm))
+
+        def split(parts):
+            n, s, lo, hi = self._fold(cols, *map(parts.head, fields))
+            tn, ts, tlo, thi = self._fold(parts.own(cols),
+                                          *map(parts.tails, fields))
+            return (n + parts.back(tn, 0), s + parts.back(ts, 0),
+                    jnp.minimum(lo, parts.back(tlo, _NO_LO)),
+                    jnp.maximum(hi, parts.back(thi, _NO_TS)))
+        folded, crowded = self._by_head_and_tails(
+            fields[3], lambda: self._fold(cols, *fields), split)
+        return self._bound_arrivals(cols, b, *folded), crowded
 
     @scoped("emit")
     def _emit(self, cols, fire, sums, ends):
@@ -2097,7 +2279,8 @@ class SessionWindowOperator(_OwnColumns, Operator):
         max_ts = _EventTimeSlots._block_max_ts(
             self, state["max_ts"], batches.valid, batches.timestamps)
         wm, wm0 = self._watermark(max_ts), self._watermark(state["max_ts"])
-        s, lo, hi, late = self._arrivals(state["cols"], batches, wm)
+        (s, lo, hi, late), crowded = self._arrivals_block(
+            state["cols"], batches, wm)
         # Two steps that stand for the state go in front — the older
         # open session's sum and latest as an arrival, then the newest
         # one's — so every array from here on is [K + 2, P, C].
@@ -2167,7 +2350,8 @@ class SessionWindowOperator(_OwnColumns, Operator):
             fired=state["fired"] + out.count().sum(axis=0),
             dropped=state["dropped"] + dropped.sum(axis=0),
             disordered=state["disordered"] + n(disordered).sum(axis=0),
-            open_peak=jnp.maximum(state["open_peak"], held)), out
+            open_peak=jnp.maximum(state["open_peak"], held),
+            dense_blocks=self._dense_blocks(state, crowded)), out
 
 
 def _pack_by_rank(mask: jnp.ndarray, fields, width: int):

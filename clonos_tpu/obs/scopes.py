@@ -20,7 +20,10 @@ same names where they run the same code. A path reads
 ``.../lookup``      a record's own column (``_OwnColumns._column``: the
                     windowed top and the window join); with it, a
                     step's sum, earliest and latest per own column
-                    (``SessionWindowOperator._arrivals``); a chunk's
+                    (``SessionWindowOperator._arrivals``); both as a
+                    block's head and tails, with the selects that pick
+                    the tails out and put them back (``_column_block``,
+                    ``_arrivals_block``); a chunk's
                     opening records against the own columns and the
                     intervals that are active in it
                     (``BestInIntervalJoinOperator._chunk``)

@@ -1455,7 +1455,11 @@ class ClusterRunner:
         # ``fence_totals``), then the exchange — records an edge has
         # dropped, and the most a target of a dynamic edge has been sent
         # in one step; both only grow, and stay absent while 0; last the
-        # operators' high-water marks (``fence_peaks``), fed alike.
+        # operators' high-water marks (``fence_peaks``), fed alike. A
+        # total that reads BELOW its last reading feeds nothing: a
+        # recovery put a replayed lane's state in a victim's place, and
+        # a diagnostic the one-lane replay does not count again
+        # (``lookup.dense_blocks``) then stands at its checkpoint's.
         compiled = self.executor.compiled
         seen = [(f"{counter}.{v.name}", n, True) for (v, _, counter), n
                 in zip(compiled.fence_total_slots(), parts["totals"])]
@@ -1467,7 +1471,8 @@ class ClusterRunner:
                  in zip(compiled.fence_peak_slots(), parts["marks"])]
         tr = get_tracer()
         for counter, n, even_zero in seen:
-            grown = int(n) - self._fence_counter_totals.get(counter, 0)
+            grown = max(
+                int(n) - self._fence_counter_totals.get(counter, 0), 0)
             self._fence_counter_totals[counter] = int(n)
             if grown or even_zero:
                 tr.count(counter, grown)

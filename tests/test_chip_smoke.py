@@ -70,6 +70,20 @@ def test_part_s_tiny_sessions_in_wide_blocks_against_narrow_ones():
     assert chip_smoke.check_sessions_in_a_job(21, spe=128, epochs=5) > 200
 
 
+def test_part_s_tiny_sessions_by_head_and_tails_against_every_slot():
+    # 8 receive windows of 512 slots: the split is built, the hot
+    # bidder's owner is past the head every step
+    assert chip_smoke.check_sessions_in_a_job(21, spe=128, epochs=3, p=8,
+                                              batch=64) > 100
+
+
+def test_part_s_tiny_sessions_of_three_hot_bidders_take_the_dense_branch():
+    # three targets past the head in one step: the block programs hold
+    # the cond and its dense branch runs (the check raises if it does not)
+    assert chip_smoke.check_sessions_in_a_job(21, spe=128, epochs=3, p=8,
+                                              batch=64, hot=3) > 100
+
+
 def test_part_i_tiny_incremental_join_in_wide_blocks_against_narrow_ones():
     rows, flushed, stepped = chip_smoke.check_incremental_join_in_a_job(
         21, spe=128, epochs=6)

@@ -70,7 +70,8 @@ def _bound_state(op, P, owner):
     return op.bind_own_columns(state, cols)
 
 
-def _blocks(seed, n_blocks, K, P, B, owner, spread, nk, foreign=0.1):
+def _blocks(seed, n_blocks, K, P, B, owner, spread, nk, foreign=0.1,
+            share=0.7):
     """Random blocks: a subtask's keys mostly its own (``owner``), some
     from -1 to past the table and some another subtask's; event time
     ``10 * step + [0, spread)``; small values, so that sums tie."""
@@ -89,18 +90,21 @@ def _blocks(seed, n_blocks, K, P, B, owner, spread, nk, foreign=0.1):
             jnp.asarray(rng.randint(1, 3, (K, P, B)), jnp.int32),
             jnp.asarray(10 * steps[:, None, None]
                         + rng.randint(0, spread, (K, P, B)), jnp.int32),
-            jnp.asarray(rng.rand(K, P, B) < 0.7))))
+            jnp.asarray(rng.rand(K, P, B) < share))))
     return out
 
 
 def _step_and_block(op, state, blocks, K, P):
     """Run ``blocks`` through ``process_block`` and, step by step, through
-    ``process``; assert both agree after every block and return the final
-    state and all rows."""
+    ``process``; assert both agree after every block — every leaf but
+    ``dense_blocks``, which says how a block's lookup was done and which
+    only the block form counts — and return the final state and all
+    rows."""
     import jax
     import jax.numpy as jnp
     from clonos_tpu.api import operators as ops
     by_block, by_step, rows = state, state, []
+    aside = lambda s: dict(s, dense_blocks=0)
     step_fn = jax.jit(lambda s, b, k, bctx: op.process(s, b,
                                                        bctx.at_step(k)))
     block_fn = jax.jit(op.process_block)
@@ -118,8 +122,10 @@ def _step_and_block(op, state, blocks, K, P):
                 lambda x: x[k], batches), k, bctx)
             outs.append(o)
         stepped = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *outs)
-        for a, b in zip(jax.tree_util.tree_leaves((by_block, out)),
-                        jax.tree_util.tree_leaves((by_step, stepped))):
+        assert not np.asarray(by_step["dense_blocks"]).any()
+        for a, b in zip(
+                jax.tree_util.tree_leaves((aside(by_block), out)),
+                jax.tree_util.tree_leaves((aside(by_step), stepped))):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         rows.append(out)
     return by_block, rows
@@ -128,20 +134,31 @@ def _step_and_block(op, state, blocks, K, P):
 @pytest.mark.parametrize("own", [None, 12], ids=["dense", "own-columns"])
 @pytest.mark.parametrize("slide", [20, 100], ids=["sliding", "tumbling"])
 @pytest.mark.parametrize("spread", [10, 160], ids=["in-bound", "late"])
-def test_step_form_equals_block_form_bit_for_bit(own, slide, spread):
+@pytest.mark.parametrize("wide", [False, True],
+                         ids=["narrow", "head-and-tails"])
+def test_step_form_equals_block_form_bit_for_bit(own, slide, spread, wide):
     """Three blocks of 24 steps over 4 subtasks: the state after each
     block and every row agree, with records from other subtasks' keys
     and from outside the table among them (counted late, with own
     columns; dense, only those outside the table are), records behind
     the watermark at a spread of 160 ms against a bound of 10, and ties
-    (values of 1 and 2 over a few keys)."""
-    K, P, B, nk = 24, 4, 20, 32
-    owner = np.random.RandomState(7).randint(0, P, nk)
+    (values of 1 and 2 over a few keys). ``wide``: four blocks of 8
+    steps over 8 receive windows of 384 slots, which the block form
+    looks up by head and tails — a block each of steps with up to two
+    targets past the head (they change from step to step), of one step
+    with three (the dense form, counted in ``dense_blocks``), of two
+    every step, and of slots that are no prefix."""
+    from test_head_and_tails import WIDE, widely
+    K, P, B, nk = WIDE + (32,) if wide else (24, 4, 20, 32)
+    owner = (np.random.RandomState(7).permutation(nk) % P if wide
+             else np.random.RandomState(7).randint(0, P, nk))
     op = _top_op(own, slide=slide, nk=nk)
-    state, rows = _step_and_block(
-        op, _bound_state(op, P, owner),
-        _blocks(3, 3, K, P, B, owner, spread, nk), K, P)
+    blocks = (widely(_blocks(3, 4, K, P, B, owner, spread, nk, share=1.0), 9)
+              if wide else _blocks(3, 3, K, P, B, owner, spread, nk))
+    state, rows = _step_and_block(op, _bound_state(op, P, owner), blocks, K,
+                                  P)
     total = lambda k: int(np.asarray(state[k]).sum())
+    assert total("dense_blocks") == int(wide)
     assert total("fired") == sum(int(r.valid.sum()) for r in rows) > 20
     assert total("late") > 0        # unowned or outside keys at least
     if spread > 10:
@@ -173,7 +190,7 @@ def test_rows_past_the_capacity_are_dropped_and_counted():
     assert np.asarray(out.values)[0].tolist() == [1, 1, 1]
     assert np.asarray(out.timestamps)[0].tolist() == [99, 99, 99]
     assert {k: int(state[k][0]) for k, _ in op.fence_totals} == {
-        "late": 0, "fired": 3, "dropped": 5}
+        "late": 0, "fired": 3, "dropped": 5, "dense_blocks": 0}
 
 
 def test_only_the_largest_goes_on_and_an_unowned_key_is_counted():

@@ -100,12 +100,15 @@ def _blocks(seed, n_blocks, K, P, B, owner, tick, spread, nk, share=0.12,
 
 def _step_and_block(op, state, blocks, K, P):
     """Run ``blocks`` through ``process_block`` and, step by step, through
-    ``process``; assert both agree after every block and return the final
-    state and all rows."""
+    ``process``; assert both agree after every block — every leaf but
+    ``dense_blocks``, which says how a block's lookup was done and which
+    only the block form counts — and return the final state and all
+    rows."""
     import jax
     import jax.numpy as jnp
     from clonos_tpu.api import operators as ops
     by_block, by_step, rows = state, state, []
+    aside = lambda s: dict(s, dense_blocks=0)
     step_fn = jax.jit(lambda s, b, k, bctx: op.process(s, b,
                                                        bctx.at_step(k)))
     block_fn = jax.jit(op.process_block)
@@ -125,8 +128,10 @@ def _step_and_block(op, state, blocks, K, P):
         stepped = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *outs)
         assert (jax.tree_util.tree_structure((by_block, out))
                 == jax.tree_util.tree_structure((by_step, stepped)))
-        for a, b in zip(jax.tree_util.tree_leaves((by_block, out)),
-                        jax.tree_util.tree_leaves((by_step, stepped))):
+        assert not np.asarray(by_step["dense_blocks"]).any()
+        for a, b in zip(
+                jax.tree_util.tree_leaves((aside(by_block), out)),
+                jax.tree_util.tree_leaves((aside(by_step), stepped))):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         rows.append(out)
     return by_block, rows
@@ -229,24 +234,42 @@ def test_within_the_bound_both_forms_are_flinks_sessions(
 
 @pytest.mark.parametrize("own", [None, 5], ids=["dense", "own-columns"])
 @pytest.mark.parametrize("capacity", [None, 2], ids=["all-rows", "overflow"])
+@pytest.mark.parametrize("wide", [False, True],
+                         ids=["narrow", "head-and-tails"])
 @pytest.mark.parametrize("gap, bound, tick, spread", [
     (30, 10, 10, 40), (30, 25, 10, 60), (50, 10, 10, 80), (30, 10, 3, 35)],
     ids=["behind-bound", "wide-bound", "late", "slow-clock"])
-def test_step_form_equals_block_form_bit_for_bit(own, capacity, gap, bound,
-                                                 tick, spread):
+def test_step_form_equals_block_form_bit_for_bit(own, capacity, wide, gap,
+                                                 bound, tick, spread):
     """The same with records behind the bound — arrivals that spread over
     more than ``gap`` or fall below their key's newest session (merged
     all the same, and counted), records whose own window the watermark
     has passed (late) — and a capacity of 2 rows a subtask a step: state,
-    rows, their order and every total agree after each of four blocks."""
-    K, P, B, nk = 16, 3, 6, 8
-    owner = (None if own is None
+    rows, their order and every total agree after each of four blocks.
+    ``wide``: over 8 receive windows of 384 slots, which the block form
+    looks up by head and tails — a block each of steps with up to two
+    targets past the head (they change from step to step), of one step
+    with three (the dense form, counted in ``dense_blocks``), of two
+    every step, and of slots that are no prefix."""
+    from test_head_and_tails import WIDE, widely
+    K, P, B, nk = WIDE + (24,) if wide else (16, 3, 6, 8)
+    owner = (None if own is None else np.arange(nk) % P if wide
              else np.random.RandomState(7).randint(0, P, nk))
     op = _op(own, gap=gap, bound=bound, capacity=capacity, nk=nk)
-    state, rows = _step_and_block(
-        op, _bound_state(op, P, owner),
-        _blocks(5, 4, K, P, B, owner, tick, spread, nk, share=0.3), K, P)
+    blocks = _blocks(5, 4, K, P, B, owner, tick, spread, nk,
+                     share=1.0 if wide else 0.3)
+    if wide:
+        # a third of the keys pause for the two blocks in the middle (their
+        # records go to a key of the same subtask), so that sessions close
+        for i in (1, 2):
+            k = blocks[i].keys
+            blocks[i] = blocks[i]._replace(
+                keys=k - P * ((k >= P) & (k < 2 * P)))
+        blocks = widely(blocks, 9)
+    state, rows = _step_and_block(op, _bound_state(op, P, owner), blocks, K,
+                                  P)
     total = lambda k: int(np.asarray(state[k]).sum())
+    assert total("dense_blocks") == int(wide)
     assert total("fired") == sum(int(r.valid.sum()) for r in rows) > 0
     assert total("late") > 0
     if capacity is None:
@@ -337,7 +360,8 @@ def test_rows_past_the_capacity_are_dropped_and_counted():
         [(k, k + 1, 5) for k in range(6)], [(7, 1, 100)]])
     assert fired[1] == [(k, k + 1, 15) for k in range(4)]
     assert {k: int(state[k][0]) for k, _ in op.fence_totals} == {
-        "late": 0, "fired": 4, "dropped": 2, "disordered": 0}
+        "late": 0, "fired": 4, "dropped": 2, "disordered": 0,
+        "dense_blocks": 0}
     assert op.fence_losses == ("late", "dropped", "disordered")
     assert op.fence_peaks == (("open_peak", "window.open_sessions"),)
 
