@@ -78,6 +78,19 @@ it pass off the device:
      reference and the join's totals the reference's, no loss. Run it on
      the chip after any change to either block form (D17). Not in the
      default parts.
+  U  the union inside a job's block program: the benchmark's
+     ``allround-event-time`` job at ``allround-upstream``'s own widths
+     (8 subtasks, the tumbling window's rows over a static route 256
+     wide and the sliding window's over one 384 wide, into 256) in
+     blocks of 1,024 steps, its union packed by rank (the block form: a
+     running count and three keyed histograms) and once more with the
+     union's block form patched to the scan of its step form (a stable
+     sort and four gathers a step). Pass = both committed streams equal
+     the topology's NumPy reference. Then a union that overflows: two
+     keyed streams of ~96 and ~64 records a subtask a step into 160,
+     the same two forms, equal streams and fewer rows than records. Run
+     it on the chip after any change to ``UnionOperator`` (D17). Not in
+     the default parts.
   C  job A again under a four-chip task mesh, when there are four chips.
      Pass = committed stream byte-identical to A's, ledgers equal, every
      sharded carry leaf on four devices at a quarter each.
@@ -771,10 +784,11 @@ def committed_by_epoch(graph, stream, seed: int, spe: int, epochs: int,
     return got, runner
 
 
-def tiny_topology(config: str, spe: int, seed: int):
-    """A benchmark topology at its tiny stand-in's sizes with epochs of
-    ``spe`` steps: (configuration, stream, its plain reference, its
-    ``job.py``'s ``build``)."""
+def bench_topology(config: str, spe: int, seed: int, tiny: bool = True):
+    """A benchmark topology at its tiny stand-in's sizes (or, ``tiny``
+    off, at the sizes of the cell's own file) with epochs of ``spe``
+    steps: (configuration, stream, its plain reference, its ``job.py``'s
+    ``build``)."""
     import json
     bench = os.path.join(HERE, "benchmark")
     if bench not in sys.path:
@@ -782,8 +796,8 @@ def tiny_topology(config: str, spe: int, seed: int):
     from benchlib import job as bench_job
     from benchlib.byname import module_at
 
-    with open(os.path.join(bench, "tests", "tiny", "bench", "configs",
-                           config + ".json")) as f:
+    where = ("tests", "tiny", "bench", "configs") if tiny else ("configs",)
+    with open(os.path.join(bench, *where, config + ".json")) as f:
         cfg = json.load(f)
     cfg["steps_per_epoch"] = spe
     return (cfg, bench_job.make_stream(cfg, {"table_epochs": 2}, seed),
@@ -797,7 +811,7 @@ def check_incremental_join_in_a_job(seed: int, spe: int = 2048,
     run in blocks of 1,024 steps against the same job run in blocks of
     16 and against the topology's plain reference; returns (rows
     compared, of them flushed out of the bag, chunks run step by step)."""
-    cfg, stream, ref, build = tiny_topology("tiny-nexmark-q3", spe, seed)
+    cfg, stream, ref, build = bench_topology("tiny-nexmark-q3", spe, seed)
 
     def committed(block_steps: int):
         got, runner = committed_by_epoch(build(cfg), stream, seed, spe,
@@ -832,7 +846,7 @@ def check_best_in_interval_in_a_job(seed: int, spe: int = 2048,
     and the join's totals against the reference's; returns (rows
     compared, rows the join emitted, bids that counted, chunks run step
     by step)."""
-    cfg, stream, ref, build = tiny_topology("tiny-nexmark-q4", spe, seed)
+    cfg, stream, ref, build = bench_topology("tiny-nexmark-q4", spe, seed)
     want = ref.expected(cfg, stream.keys, stream.vals, epochs)
     stepped = 0
     for block_steps in (1024, 16):
@@ -860,6 +874,88 @@ def check_best_in_interval_in_a_job(seed: int, spe: int = 2048,
         raise AssertionError(
             f"best in interval: the traffic left a branch out ({want})")
     return compared, want.winning_rows, want.valid, stepped
+
+
+def check_union_in_a_job(seed: int, spe: int = 2048, epochs: int = 3,
+                         tiny: bool = False):
+    """Part U: the committed stream of the ``allround-event-time`` job
+    in blocks of 1,024 steps with its union packed by rank against the
+    same job with the union's block form patched to the scan of its step
+    form, both against the topology's plain reference; then the same two
+    forms of a union that overflows, against each other. Returns (rows
+    compared with the reference, rows the overflowing union committed,
+    records it was sent)."""
+    import contextlib
+    from unittest import mock
+
+    from clonos_tpu.api import operators as ops
+    from clonos_tpu.api.environment import StreamEnvironment
+
+    config = "tiny-allround-upstream" if tiny else "allround-upstream"
+    cfg, stream, ref, build = bench_topology(config, spe, seed, tiny)
+    forms = (("packed by rank", contextlib.nullcontext()),
+             ("the step form's scan", mock.patch.object(
+                 ops.UnionOperator, "process_block",
+                 ops.TwoInputOperator.process_block)))
+
+    def committed(graph, form, what):
+        with form:
+            return committed_by_epoch(graph, stream, seed, spe, epochs,
+                                      1024, what)
+
+    want = ref.expected(cfg, stream.keys, stream.vals, epochs)
+    for name, form in forms:
+        got, runner = committed(build(cfg), form, "union")
+        compiled = runner.executor.compiled
+        (union,) = (v for v in compiled.job.vertices if v.name == "union")
+        widths = [compiled.edge_plans[i].width
+                  for i, e in enumerate(compiled.job.edges)
+                  if e.dst == union.vertex_id] + [union.operator.capacity]
+        if not tiny and widths != [256, 384, 256]:
+            raise AssertionError(f"union: edges and capacity {widths}, the "
+                                 f"cell's are 256, 384 and 256")
+        bad, failed, compared = ref.check(got, want, cfg, epochs)
+        if bad:
+            raise AssertionError(
+                f"union, {name}: {bad} rows differ from the reference in "
+                f"epochs {failed}")
+    if compared < epochs * spe:
+        raise AssertionError(f"union in a job: only {compared} rows")
+
+    # two keyed streams into a union too narrow for both in about every
+    # other step: three records in four from the left, one in two from
+    # the right, over keys spread evenly (an edge must drop nothing)
+    p, batch = cfg["parallelism"], cfg["batch"]
+    cap = 5 * batch // 4
+
+    def overflowing():
+        env = StreamEnvironment(name="smoke-union",
+                                num_key_groups=cfg["num_key_groups"],
+                                default_edge_capacity=batch)
+        spread = env.host_source(batch_size=batch, parallelism=p).map(
+            lambda k, v, t: ((k * 8191 + v) & 0xFFFFF, v, t), name="spread")
+        left = spread.filter(lambda k, v, t: v % 4 != 0, name="left")
+        right = spread.filter(lambda k, v, t: v % 2 == 1, name="right")
+        (left.key_by().union(right.key_by(), capacity=cap)
+            .sink(parallelism=p, transactional=True, capacity=cap))
+        return env.build()
+
+    rows = []
+    for name, form in forms:
+        got, _ = committed(overflowing(), form, "overflowing union")
+        rows.append(sort_rows(np.concatenate(
+            [np.asarray(r).reshape(-1, 3) for e in sorted(got)
+             for r in got[e]])))
+    sent = epochs * spe * p * batch * 5 // 4
+    if rows[0].shape != rows[1].shape or not np.array_equal(*rows):
+        raise AssertionError(
+            f"overflowing union: {rows[0].shape[0]} rows committed packed "
+            f"by rank, {rows[1].shape[0]} by the step form's scan")
+    if not sent // 2 < rows[0].shape[0] < sent * 99 // 100:
+        raise AssertionError(
+            f"overflowing union: {rows[0].shape[0]} rows of about {sent} "
+            f"records: the case drops next to nothing, or half")
+    return compared, int(rows[0].shape[0]), sent
 
 
 # --- main --------------------------------------------------------------------
@@ -923,7 +1019,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=21)
     ap.add_argument("--parts", default="KABC",
-                    help="which of K, J, S, I, Q, A, B, C to run (C needs A)")
+                    help="which of K, J, S, I, Q, U, A, B, C to run (C needs A)")
     args = ap.parse_args(argv)
     t_start = time.monotonic()
 
@@ -1002,6 +1098,16 @@ def main(argv=None) -> int:
             f"steps == blocks of 16 == the reference over {rows} rows "
             f"({won} auctions won by the best of {valid} bids that "
             f"counted), {stepped} chunks by the step form "
+            f"({time.monotonic() - t0:.1f}s)")
+
+    if "U" in parts:
+        t0 = time.monotonic()
+        rows, kept, sent = check_union_in_a_job(args.seed)
+        mark = print_routes(tracer, mark, "U")
+        say(f"U pass: the union at 8 x 1,024 x (256 + 384) -> 256 in a "
+            f"job, packed by rank == the step form's scan == the reference "
+            f"over {rows} rows; overflowing, the two forms agree on "
+            f"{kept} rows of {sent} records "
             f"({time.monotonic() - t0:.1f}s)")
 
     shape = ServedShape()
@@ -1102,7 +1208,7 @@ def main(argv=None) -> int:
     say(f"compile cache: {entries1} entries at end "
         f"({entries1 - entries0} added by this run)")
     say(f"total {time.monotonic() - t_start:.1f}s; parts run: "
-        f"{''.join(p for p in 'KJSIABC' if p in parts)}")
+        f"{''.join(p for p in 'KJSIQUABC' if p in parts)}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": n_dev}}), flush=True)

@@ -1091,10 +1091,16 @@ class EventTimeWindowMeanOperator(SlidingEventTimeWindowOperator):
 
 @dataclasses.dataclass
 class UnionOperator(TwoInputOperator):
-    """Merge two streams: left records first, then right, compacted into a
-    fixed output capacity (the union / ConnectedStreams.map-same-type
-    shape). Deterministic concatenation order replaces the reference's
-    arrival-order race."""
+    """Merge two streams: left records first, then right, packed by rank
+    into a fixed output capacity (the union / ConnectedStreams.map-same-
+    type shape). Deterministic concatenation order replaces the
+    reference's arrival-order race; a record whose rank among the valid
+    ones is at or past ``capacity`` is a (deterministic) overflow drop.
+
+    The step form sorts and gathers, one row at a time; the block form
+    packs a whole block by rank (:func:`_pack_by_rank`: a running count
+    and a keyed histogram a field — no sort, no gather). The two agree
+    bit for bit (``tests/test_operators_block.py``)."""
 
     capacity: int
 
@@ -1120,15 +1126,18 @@ class UnionOperator(TwoInputOperator):
         return state, jax.vmap(one)(left, right)
 
     def process_block(self, state, batches, bctx):
-        # Stateless: flatten [K, P] into one vmapped batch dim.
-        left, right = batches
-        K, p = left.keys.shape[:2]
-        rs = lambda b: jax.tree_util.tree_map(
-            lambda x: x.reshape((K * p,) + x.shape[2:]), b)
+        # Stateless. A valid slot's place is its rank among the valid
+        # ones in slot order (the order of the step form's stable sort);
+        # a rank past the capacity is out of the histogram's range and
+        # dropped, a place no record took holds its 0 in every field.
         with jax.named_scope("compact"):
-            _, out = self.process2(state, rs(left), rs(right), None)
-        return state, jax.tree_util.tree_map(
-            lambda x: x.reshape((K, p) + x.shape[1:]), out)
+            keys, vals, ts, valid = (
+                jnp.concatenate(both, axis=-1) for both in zip(*batches))
+            packed, count = _pack_by_rank(valid, (keys, vals, ts),
+                                          self.capacity)
+            taken = (jnp.arange(self.capacity, dtype=jnp.int32)
+                     < count[..., None])
+        return state, RecordBatch(*packed, taken)
 
 
 @dataclasses.dataclass

@@ -40,8 +40,9 @@ same names where they run the same code. A path reads
                     the windowed top and the session window)
 ``.../readback``    the running value read back per record
                     (``KeyedReduceOperator.process_block*``)
-``.../compact``     both inputs' records packed to the front
-                    (``UnionOperator``)
+``.../compact``     records packed by rank to the front: both inputs'
+                    (``UnionOperator.process_block``), a chunk's
+                    (``_ChunkedJoin._packed``)
 ``exchange``        an edge's route (``CompiledJob.route_edge``);
 ``exchange/rank``   a record's arrival rank at its target (the running
                     count, ``matops.running_count``)

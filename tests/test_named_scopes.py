@@ -49,7 +49,9 @@ TOPOLOGIES = {
         "vertex/tumbling/place/hist", "vertex/tumbling/segsum",
         "vertex/sliding", "vertex/sliding/place",
         "vertex/sliding/place/hist", "vertex/sliding/segsum",
-        "vertex/union", "vertex/union/compact", "vertex/sink"}),
+        # the union is its compaction: no op of its own outside it
+        "vertex/union/compact", "vertex/union/compact/hist",
+        "vertex/sink"}),
     "nexmark-window-join": ("tiny-nexmark-q8", RANKED | {
         "vertex/host-source", "vertex/parse", "vertex/persons",
         "vertex/auctions", "vertex/join", "vertex/join/lookup",

@@ -118,6 +118,75 @@ def test_two_input_union_block_equals_scan():
     _assert_equal(ref[1], blk[1])
 
 
+def _union_inputs(case, seed=5):
+    """(capacity, left, right) of one case of the union's block form:
+    ``[K, P, Bl]`` and ``[K, P, Br]`` batches whose invalid lanes hold
+    garbage, as a producer that does not zero them would leave them."""
+    rng = np.random.RandomState(seed)
+    k, p, bl, br, cap, density = case
+
+    def side(b):
+        full = lambda: rng.randint(-2 ** 31, 2 ** 31, (k, p, b),
+                                   dtype=np.int64).astype(np.int32)
+        keys, vals, ts = full(), full(), full()
+        vals[0, 0, : min(b, 2)] = -2 ** 31
+        ts[0, 0, : min(b, 2)] = -2 ** 31
+        keys[0, 0, : min(b, 2)] = 2 ** 31 - 1
+        valid = rng.rand(k, p, b) < density
+        valid[0, 0] = True                # the extremes are records
+        if k > 2:
+            valid[1] = False              # rows with no record at all
+            valid[2] = True               # rows where every slot is one
+        return RecordBatch(*(jnp.asarray(a)
+                             for a in (keys, vals, ts, valid)))
+    return cap, side(bl), side(br)
+
+
+@pytest.mark.parametrize("case", [
+    (K, P, B, B, 7, 0.7), (K, P, B, B, 2 * B, 0.7), (K, P, 3, 5, 4, 0.5),
+    (K, P, 3, 5, 8, 1.0), (K, P, 3, 5, 1, 0.5), (4, 2, 256, 384, 256, 0.5),
+    (4, 2, 256, 384, 256, 0.01), (2, 1, 256, 384, 640, 0.9)],
+    ids=["overflow-17-into-7", "nothing-dropped", "3+5-into-4",
+         "3+5-all-valid", "3+5-into-1", "256+384-overflow",
+         "256+384-sparse", "256+384-as-wide-as-both"])
+def test_union_block_packs_by_rank_what_the_step_form_sorts(case):
+    """``UnionOperator.process_block`` (packed by rank: no sort, no
+    gather) against the scan of ``process2`` (a stable argsort and four
+    gathers), in every lane of every field: the overflow drop takes the
+    last records in left-then-right slot order, a row with no record is
+    zeros, values, timestamps and keys span the whole int32 range, the
+    two inputs need not be as wide as each other, and invalid lanes are
+    zero whatever the inputs held there."""
+    from clonos_tpu.api.operators import TwoInputOperator
+    cap, left, right = _union_inputs(case)
+    op = UnionOperator(capacity=cap)
+    k, p = left.valid.shape[:2]
+    t = jnp.arange(k, dtype=jnp.int32)
+    bctx = BlockContext(times=t, rng_bits=t, epoch=jnp.zeros((), jnp.int32),
+                        step0=jnp.zeros((), jnp.int32),
+                        subtask=jnp.arange(p, dtype=jnp.int32))
+    ref = jax.jit(lambda s, b, c: TwoInputOperator.process_block(
+        op, s, b, c))((), (left, right), bctx)[1]
+    blk = jax.jit(op.process_block)((), (left, right), bctx)[1]
+    _assert_equal(ref, blk)
+    n = np.asarray(left.valid).sum(-1) + np.asarray(right.valid).sum(-1)
+    np.testing.assert_array_equal(np.asarray(blk.count()),
+                                  np.minimum(n, cap))
+    if case[4] < case[2] + case[3] and case[5] >= 0.5:
+        assert (n > cap).any(), "the case drops nothing"
+    # the records that stay are the first in left-then-right slot order
+    both = np.concatenate([np.asarray(left.valid), np.asarray(right.valid)],
+                          axis=-1)
+    vals = np.concatenate([np.asarray(left.values),
+                           np.asarray(right.values)], axis=-1)
+    for idx in np.ndindex(k, p):
+        want = vals[idx][both[idx]][:cap]
+        np.testing.assert_array_equal(
+            np.asarray(blk.values)[idx][: want.size], want)
+    for f in (blk.keys, blk.values, blk.timestamps):
+        assert not np.asarray(f)[~np.asarray(blk.valid)].any()
+
+
 def test_interval_join_grouped_block_equals_scan():
     """The grouped join block (G steps fused per scan iteration) must be
     bit-identical to the sequential per-step semantics — state (ring

@@ -517,6 +517,65 @@ def test_window_join_block_form_compiles_at_the_cells_widths(v5e):
     assert exe.memory_analysis().temp_size_in_bytes < K * P * E * C
 
 
+def test_union_block_form_compiles_at_the_cells_widths(v5e):
+    """``allround-upstream``'s ``union`` vertex at its own widths — the
+    tumbling window's rows over a static route 256 wide and the sliding
+    window's over one 384 wide, into 256 — over a whole block of 1,024
+    steps of 8 subtasks, inside the job's block program: under
+    ``vertex/union`` no sort, no gather, no scatter and no loop (until
+    PR 50 a stable sort of the 640 slots and four gathers of 2,097,152
+    elements); the compaction takes the Mosaic kernel, ``[8192, 640] ->
+    256`` a field; and the five keyed edges are planned as at the tiny
+    size (``tests/test_allround_event_time.py``)."""
+    import json
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    for p in (bench, root):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    from benchlib import job
+    from benchlib.byname import module_at
+    from clonos_tpu.runtime.executor import CompiledJob
+    with open(os.path.join(bench, "configs", "allround-upstream.json")) as f:
+        cfg = json.load(f)
+    # the logs and rings at a size this test can describe quickly; the
+    # vertices, their edges and the block's steps as the cell has them
+    compiled = CompiledJob(
+        module_at(job.topology_file(cfg, "job.py")).build(cfg),
+        log_capacity=8192, max_epochs=8, inflight_ring_steps=2048)
+    names = [v.name for v in compiled.job.vertices]
+    plans = {(names[e.src], names[e.dst]): compiled.edge_plans[i]
+             for i, e in enumerate(compiled.job.edges)
+             if i in compiled.edge_plans}
+    assert {k: p.route for k, p in plans.items()} == {
+        ("event-time", "keyed-state"): "dynamic",
+        ("operator-state", "tumbling"): "identity",
+        ("tumbling", "sliding"): "static", ("tumbling", "union"): "static",
+        ("sliding", "union"): "static"}
+    widths = (plans["tumbling", "union"].width,
+              plans["sliding", "union"].width)
+    K, P, cap = cfg["block_steps"], cfg["parallelism"], cfg["union_capacity"]
+    assert widths == (256, 384) and cap == 256
+    mesh = Mesh(np.array(v5e[:1]), ("tasks",))
+    with histogram.kernel_mesh(mesh, "tasks"):
+        lowered, records = lower_block(compiled, K,
+                                       SingleDeviceSharding(v5e[0]))
+    forms = [r["args"] for r in records if r["name"] == "hist.kernel"]
+    assert {f["form"] for f in forms} == {"mxu"}
+    assert [(f["rows"], f["cols"], f["lanes"], f["planes"])
+            for f in forms].count((K * P, sum(widths), cap, 4)) == 3
+    exe = lowered.compile()
+    mine = [line for line in exe.as_text().splitlines()
+            if "vertex/union" in line]
+    assert len(mine) > 20
+    assert any("tpu_custom_call" in line for line in mine)
+    for op in ("while", "scatter", "gather", "sort"):
+        assert not [line for line in mine
+                    if re.search(rf"= \S+ {op}\(", line)], op
+
+
 @pytest.mark.parametrize("shape,rung,gathers", [
     ((1024, 16, 320), 20480, 0), ((1024, 16, 320), 5120, 4),
     ((1024, 16, 256), 8192, 0), ((512, 16, 640), 320, 4)],
