@@ -91,6 +91,17 @@ it pass off the device:
      the same two forms, equal streams and fewer rows than records. Run
      it on the chip after any change to ``UnionOperator`` (D17). Not in
      the default parts.
+  X  ``nexmark-q3-x4`` at the cell's own shape, when there are four
+     chips: the carry built under its shardings (16 GiB, 14 of them one
+     leaf of 3,584 replica logs: every chip a quarter of every sharded
+     leaf, none ever a leaf whole), a warm epoch, the prewarm, two epochs
+     whose checkpoints stay pending, then the connected failure of one
+     subtask of every vertex on the auctions' path and its recovery on
+     the mesh, the join's two inputs re-routed from two rings, one of
+     them rebuilt by a victim upstream. Pass = the committed stream,
+     before and after, equals the topology's NumPy reference, no loss,
+     and the fullest chip holds about a quarter of the carry. Not in the
+     default parts.
   C  job A again under a four-chip task mesh, when there are four chips.
      Pass = committed stream byte-identical to A's, ledgers equal, every
      sharded carry leaf on four devices at a quarter each.
@@ -799,7 +810,8 @@ def bench_topology(config: str, spe: int, seed: int, tiny: bool = True):
     where = ("tests", "tiny", "bench", "configs") if tiny else ("configs",)
     with open(os.path.join(bench, *where, config + ".json")) as f:
         cfg = json.load(f)
-    cfg["steps_per_epoch"] = spe
+    if spe is not None:
+        cfg["steps_per_epoch"] = spe
     return (cfg, bench_job.make_stream(cfg, {"table_epochs": 2}, seed),
             module_at(bench_job.topology_file(cfg, "reference.py")),
             module_at(bench_job.topology_file(cfg, "job.py")).build)
@@ -961,6 +973,65 @@ def check_union_in_a_job(seed: int, spe: int = 2048, epochs: int = 3,
 # --- main --------------------------------------------------------------------
 
 
+def check_cascade_on_the_mesh(seed: int, ckpt_dir: str, chips: int = 4,
+                              config: str = "nexmark-q3-x4",
+                              tiny: bool = False) -> dict:
+    """Part X: the benchmark's ``nexmark-q3-x4`` deployment as its file
+    sizes it (``tiny``: its stand-in, for the CPU test) over a task mesh
+    of ``chips`` devices, through the cell's own kill; returns what the
+    part prints."""
+    import jax
+    from clonos_tpu.obs import get_tracer
+    cfg, stream, ref, _ = bench_topology(config, None, seed, tiny=tiny)
+    from benchlib import job as bench_job      # on the path by now
+    before = get_tracer().counters()
+    t0 = time.monotonic()
+    runner = bench_job.make_runner(cfg, stream, seed, ckpt_dir, chips)
+    built = {k: get_tracer().counters().get(k, 0) - before.get(k, 0)
+             for k in ("carry.bytes", "carry.max_device_bytes",
+                       "carry.build_us")}
+    if built["carry.max_device_bytes"] > 0.26 * built["carry.bytes"]:
+        raise AssertionError(f"cascade: a chip was built more than its "
+                             f"quarter of the carry: {built}")
+    (txn,) = runner.txn_logs.values()
+    got = {}
+    txn.committer = lambda e, rows: got.setdefault(e, []).append(
+        np.asarray(rows))
+    runner.run_epoch(complete_checkpoint=True)
+    runner.prewarm_recovery()
+    for _ in range(cfg["kill"]["uncompleted_epochs"]):
+        runner.run_epoch(complete_checkpoint=False)
+    runner.inject_failure([runner.job.subtask_base(v) + s
+                           for v, s in cfg["kill"]["victims"]])
+    jax.block_until_ready(runner.executor.carry)
+    r0 = time.monotonic()
+    report = runner.recover()
+    jax.block_until_ready(runner.executor.carry)
+    recover_s = time.monotonic() - r0
+    for _ in range(2):
+        runner.run_epoch(complete_checkpoint=True)
+    runner.drain_fence()
+    lost = runner.executor.check_overflow()
+    if lost:
+        raise AssertionError(f"cascade: {lost}")
+    epochs = runner.executor.epoch_id
+    want = ref.expected(cfg, stream.keys, stream.vals, epochs)
+    bad, failed, compared = ref.check(got, want, cfg, epochs)
+    if bad or report.steps_replayed != (cfg["kill"]["uncompleted_epochs"]
+                                        * cfg["steps_per_epoch"]):
+        raise AssertionError(
+            f"cascade: {bad} rows differ from the reference in epochs "
+            f"{failed}; {report.steps_replayed} steps replayed")
+    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in jax.devices()[:chips])
+    return {"rows": compared, "epochs": epochs, "victims": report.victims,
+            "fetch_hops": report.fetch_hops, "recover_s": recover_s,
+            "carry_gib": built["carry.bytes"] / 2**30,
+            "fullest_gib": built["carry.max_device_bytes"] / 2**30,
+            "build_s": built["carry.build_us"] / 1e6,
+            "peak_gib": peak / 2**30, "part_s": time.monotonic() - t0}
+
+
 def cache_entries(cache_dir: str) -> int:
     if not os.path.isdir(cache_dir):
         return 0
@@ -1019,7 +1090,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=21)
     ap.add_argument("--parts", default="KABC",
-                    help="which of K, J, S, I, Q, U, A, B, C to run (C needs A)")
+                    help="which of K, J, S, I, Q, U, X, A, B, C to run (C needs A)")
     args = ap.parse_args(argv)
     t_start = time.monotonic()
 
@@ -1172,6 +1243,23 @@ def main(argv=None) -> int:
         del res, runner, rep, ex
         gc.collect()
 
+    if "X" in parts:
+        if n_dev < 4:
+            say(f"X: not run ({n_dev} device)")
+        else:
+            x = check_cascade_on_the_mesh(
+                args.seed, os.path.join(OUT_DIR, "ckpt-x"))
+            mark = print_routes(tracer, mark, "X")
+            say(f"X pass: nexmark-q3-x4 on a 4-device mesh, carry "
+                f"{x['carry_gib']:.2f} GiB built in {x['build_s']:.1f}s, "
+                f"{x['fullest_gib']:.2f} on the fullest chip (peak "
+                f"{x['peak_gib']:.2f}); {x['victims']} connected victims "
+                f"recovered in {x['recover_s']:.3f}s from holders "
+                f"{x['fetch_hops']} edge(s) down; {x['rows']} committed "
+                f"rows over {x['epochs']} epochs == the reference "
+                f"({x['part_s']:.1f}s)")
+            gc.collect()
+
     if "C" in parts:
         if n_dev < 4:
             say(f"mesh: not run ({n_dev} device)")
@@ -1208,7 +1296,7 @@ def main(argv=None) -> int:
     say(f"compile cache: {entries1} entries at end "
         f"({entries1 - entries0} added by this run)")
     say(f"total {time.monotonic() - t_start:.1f}s; parts run: "
-        f"{''.join(p for p in 'KJSIQUABC' if p in parts)}")
+        f"{''.join(p for p in 'KJSIQUXABC' if p in parts)}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": n_dev}}), flush=True)

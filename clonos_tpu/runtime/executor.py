@@ -519,12 +519,35 @@ class CompiledJob:
 
     # --- initialization -----------------------------------------------------
 
+    def build_carry(self) -> JobCarry:
+        """The initial carry on the devices, for a live executor. Under a
+        mesh one jitted program builds it with ``carry_shardings()`` as
+        its ``out_shardings``: every device makes its own shard of every
+        leaf and none ever holds a leaf whole (the default sharing
+        depth's replica logs alone are 14 GiB on ``nexmark-q3-x4``).
+        The span ``setup.init-carry`` times the build; ``carry.bytes``
+        and ``carry.max_device_bytes`` (the fullest device's share) count
+        what it left."""
+        tr = get_tracer()
+        with tr.span("setup.init-carry") as span:
+            if self.mesh is None:
+                carry = self.init_carry()
+            else:
+                shardings = self.carry_shardings(
+                    jax.eval_shape(self.init_carry))
+                carry = jax.jit(self.init_carry, out_shardings=shardings)()
+            carry = distinct_buffers(carry)
+            jax.block_until_ready(carry)
+            total, fullest = carry_device_bytes(carry)
+            span.set(bytes=total, max_device_bytes=fullest)
+        tr.count("carry.bytes", total)
+        tr.count("carry.max_device_bytes", fullest)
+        tr.count("carry.build_us", int(span.ms * 1e3))
+        return carry
+
     def init_carry(self) -> JobCarry:
-        if DETS_PER_STEP * self.inflight_ring_steps > self.log_capacity:
-            # Not fatal (logs may checkpoint more often than rings wrap),
-            # but the block path appends 4K rows per block and requires
-            # block <= capacity; enforced in run_block.
-            pass
+        """The initial carry as a pure function (traceable: ``jit``,
+        ``eval_shape``); :meth:`build_carry` is what an executor calls."""
         def init_state(v):
             state = v.operator.init_state(v.parallelism)
             cols = self.own_columns.get(v.vertex_id)
@@ -749,6 +772,47 @@ class CompiledJob:
             consumed=outs.consumed[0])
 
 
+def _shard_buffers(leaf) -> List[Tuple[Any, int, int]]:
+    """``(device, buffer address, bytes)`` of every shard of a leaf this
+    process can address."""
+    return [(s.device, s.data.unsafe_buffer_pointer(), s.data.nbytes)
+            for s in leaf.addressable_shards]
+
+
+def distinct_buffers(carry: JobCarry) -> JobCarry:
+    """The carry with no device buffer under two leaves. Constructors
+    hand several leaves one buffer (a ring's head, tail and epoch marks
+    are one zero), and the donated block program rejects that ("donate
+    the same buffer twice"): a leaf that shares a buffer with an earlier
+    one is copied, every other leaf stays where it was built — a copy of
+    every leaf would hold the largest one twice, which a leaf past half a
+    chip's free memory cannot afford. Later programs keep the buffers
+    distinct (outputs alias donated inputs one to one)."""
+    leaves, treedef = jax.tree_util.tree_flatten(carry)
+    seen = set()
+    for i, leaf in enumerate(leaves):
+        leaf = jnp.asarray(leaf)
+        here = {(dev, ptr) for dev, ptr, _ in _shard_buffers(leaf)}
+        if here & seen:
+            leaf = leaf.copy()
+            here = {(dev, ptr) for dev, ptr, _ in _shard_buffers(leaf)}
+        seen |= here
+        leaves[i] = leaf
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def carry_device_bytes(carry: JobCarry) -> Tuple[int, int]:
+    """``(bytes of the whole carry, bytes on the fullest device)``, from
+    the shards as they lie: a replicated leaf counts once on every
+    device that holds it, and once in the total."""
+    total, per_device = 0, {}
+    for leaf in jax.tree_util.tree_leaves(carry):
+        total += leaf.nbytes
+        for dev, _ptr, nbytes in _shard_buffers(leaf):
+            per_device[dev] = per_device.get(dev, 0) + nbytes
+    return total, max(per_device.values(), default=0)
+
+
 def _canon_log(state: clog.ThreadLogState) -> clog.ThreadLogState:
     """Zero ring rows outside [tail, head) and epoch-index slots outside
     [epoch_base, latest_epoch] — the physically-present-but-logically-dead
@@ -950,7 +1014,7 @@ class LocalExecutor:
         self.steps_per_epoch = steps_per_epoch
         self.block_steps = min(block_steps or 512, steps_per_epoch,
                                inflight_ring_steps)
-        self.carry = self.compiled.init_carry()
+        self.carry = self.compiled.build_carry()
         self.time_source = (LogicalTimeSource(self) if logical_time
                             else CausalTimeSource())
         self._seed = seed
@@ -1094,21 +1158,6 @@ class LocalExecutor:
             self._jit_det_window = jax.jit(
                 partial(clog.epoch_row_windows,
                         max_rows=self._det_window_rows))
-        # Anti-alias the initial carry: constructors (and XLA CSE inside
-        # jitted init paths) can hand several leaves the same underlying
-        # buffer, which the donated block program rejects ("donate the
-        # same buffer twice"). An eager copy per leaf guarantees distinct
-        # buffers once; later programs keep them distinct (outputs alias
-        # donated inputs one-to-one). Leaf by leaf, each original dropped
-        # as its copy replaces it: copying the tree in one expression
-        # holds two carries at once, which a carry past half the chip's
-        # memory cannot afford.
-        leaves, treedef = jax.tree_util.tree_flatten(self.carry)
-        self.carry = None
-        for i, leaf in enumerate(leaves):
-            leaves[i] = jnp.asarray(leaf).copy()
-        del leaf
-        self.carry = jax.tree_util.tree_unflatten(treedef, leaves)
         # Epoch 0 starts at log offset 0 for every log.
         self.carry = self._jit_roll(self.carry, 0)
         self.step_input_history: List[Tuple[int, int]] = []

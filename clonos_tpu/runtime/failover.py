@@ -91,6 +91,17 @@ class RecoveryReport:
     #: routed edge windows a later consumer of the same vertex took from
     #: an earlier one's (observability/test hook)
     route_cache_hits: int = 0
+    #: the farthest number of edges downstream from which any failed
+    #: subtask fetched its determinants (the holder's vertex against the
+    #: victim's; 0 where none came from a replica); with :attr:`victims`
+    #: also the counters ``recovery.fetch_hops`` and ``recovery.victims``
+    #: of a recovery that is no drill
+    fetch_hops: int = 0
+
+    @property
+    def victims(self) -> int:
+        """How many subtasks failed together."""
+        return len(self.failed_subtasks)
 
 
 @dataclasses.dataclass
@@ -103,6 +114,8 @@ class _Victim:
     sub: int
     #: surviving (replica row, holder) pairs of its log
     holders: List[Tuple[int, int]] = dataclasses.field(default_factory=list)
+    #: edges from its vertex down to the holder its determinants came from
+    fetch_hops: int = 0
     host: bool = False       # determinants come from ``host_rows``
     #: cleanness derived on the host: its metadata and its replay's
     #: checks are deferred asserts of the final packed read
@@ -185,6 +198,8 @@ class Failover:
         self._m_recovered_records = group.counter("recovery.records-replayed")
         self._m_audit_matches = group.counter("audit.epochs-validated")
         self._m_audit_div = group.counter("audit.divergences")
+        #: edges from an owner's vertex down to a holder's
+        self._hops = runner.job.graph_info(0).distances()
 
     def _vertex_of(self, flat: int) -> Tuple[int, int]:
         job = self.runner.job
@@ -495,8 +510,11 @@ class Failover:
                 # no device fetch/parse to dispatch at all.
                 v.host = True
                 continue
-            v.holders = [(i, h) for i, (o, h) in enumerate(rt.plan.pairs)
-                         if o == v.flat and h not in rt.failed]
+            whole = [(i, h) for i, (o, h) in enumerate(rt.plan.pairs)
+                     if o == v.flat]
+            v.holders = [(i, h) for i, h in whole if h not in rt.failed]
+            if not v.holders and r.n_steps > 0 and rt.job.out_edges(v.vid):
+                raise rec.RecoveryError(self._no_holder_message(v, whole))
             op = rt.job.vertices[v.vid].operator
             eligible = (bool(v.holders) and r.n_steps > 0
                         and op.replay_pad_safe
@@ -508,10 +526,16 @@ class Failover:
                     jnp.asarray(v.holders[0][0], jnp.int32), from_epoch_d)
                 v.parsed = tuple(parsed)
             if v.holders:
-                v.meta_d = self.programs.fetch_meta(len(v.holders))(
-                    r.carry.replicas,
-                    jnp.asarray([i for i, _ in v.holders], jnp.int32),
+                # At the log's whole holder count, the shape the warm-up
+                # compiled: a connected failure leaves fewer, and the
+                # rows past them ask the first survivor again.
+                rows = [i for i, _ in v.holders]
+                rows += rows[:1] * (len(whole) - len(rows))
+                v.meta_d = self.programs.fetch_meta(len(whole))(
+                    r.carry.replicas, jnp.asarray(rows, jnp.int32),
                     from_epoch_d)
+                v.fetch_hops = int(self._hops[
+                    v.vid, self._vertex_of(v.holders[0][1])[0]])
             v.fast = (eligible and r.ck_heads is not None
                       and v.vid not in rt.txn_logs
                       and rt.executor.async_rows_since(
@@ -528,6 +552,22 @@ class Failover:
                 n = int(np.prod(d.shape))
                 setattr(v, name, packed[off: off + n].reshape(d.shape))
                 off += n
+
+    def _no_holder_message(self, v: _Victim,
+                           whole: List[Tuple[int, int]]) -> str:
+        """Names the log no survivor holds, and who held it."""
+        job = self.runner.job
+
+        def name(flat: int) -> str:
+            vid, sub = self._vertex_of(flat)
+            return f"{job.vertices[vid].name}[{sub}]"
+
+        held = ", ".join(name(h) for _, h in whole) or "nobody"
+        return (f"subtask {v.flat} ({name(v.flat)}): no surviving replica "
+                f"holds its determinant log — it was kept by {held}, all "
+                f"failed with it (sharing depth {job.sharing_depth} / "
+                f"replication factor {self.runner.plan.replication_factor} "
+                f"too shallow for this failure pattern)")
 
     def _scope_route_cache(self, r: _Recovery, vid: int) -> None:
         """Routed windows are valid only while the upstream rings they
@@ -628,18 +668,13 @@ class Failover:
             v.r_best = v.holders[0][0] if consistent else None
         else:
             if r.n_steps > 0:
-                if out_edges:
-                    raise rec.RecoveryError(
-                        f"subtask {v.flat}: no surviving replica holds "
-                        f"its determinant log (sharing depth / "
-                        f"replication factor too shallow for this "
-                        f"failure pattern)")
                 # Pure sink: nobody downstream replicates its log. Its
                 # inputs replay exactly from the upstream ring; its own
                 # nondeterminism (time/rng step inputs) is re-synthesized
                 # from the coordinator's input ledger. (The reference has
                 # the same boundary: sink exactly-once needs transactional
-                # sinks, TwoPhaseCommitSinkFunction.)
+                # sinks, TwoPhaseCommitSinkFunction.) Any other task
+                # without a holder was refused in phase A.
                 v.synthesized = True
             mgr.expect_determinant_responses(0)
         if v.det_device is not None:
@@ -912,7 +947,7 @@ class Failover:
             ck_head_m = int(r.ck_heads[flat_m])
             small_np = arr_f[off_f: off_f + 4]
             off_f += 4
-            nh = len(v.holders)
+            nh = v.meta_d.shape[0]
             meta_np = arr_f[off_f: off_f + 2 * nh].reshape(nh, 2)
             off_f += 2 * nh
             ok_f = int(arr_f[off_f])
@@ -995,8 +1030,12 @@ class Failover:
             managers=tuple(v.mgr for v in r.victims), phase_ms=r.phases,
             drill=r.drill, restore_bytes=r.restore_bytes,
             checkpoint_bytes=r.checkpoint_bytes,
-            route_cache_hits=r.route_cache_hits)
+            route_cache_hits=r.route_cache_hits,
+            fetch_hops=max(v.fetch_hops for v in r.victims))
         if not r.drill:
+            tr = get_tracer()
+            tr.count("recovery.victims", report.victims)
+            tr.count("recovery.fetch_hops", report.fetch_hops)
             # Rehearsals must not inflate the recovery count/latency
             # series operators alert on.
             self.reports.append(report)

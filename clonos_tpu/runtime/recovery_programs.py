@@ -40,6 +40,41 @@ def _z():
     return jnp.asarray(0, jnp.int32)
 
 
+def _whole_shards(stack, mesh, axis: str) -> bool:
+    """Whether every leaf of a stacked log leads with an axis the mesh
+    divides: the rule table (parallel/distributed.py) shards it then."""
+    return mesh is not None and all(
+        x.ndim >= 1 and x.shape[0] > 0 and x.shape[0] % mesh.shape[axis] == 0
+        for x in jax.tree_util.tree_leaves(stack))
+
+
+def take_row(stack, r, mesh, axis: str):
+    """Row ``r`` of every leaf of a stacked log (``replicas``: one leaf of
+    it is 14 GiB at a deep sharing depth). On one device a dynamic index.
+    Over a mesh the stack is sharded on that axis, and a dynamic index
+    into it makes the partitioner gather the whole stack onto every
+    chip: there each chip reads the row out of its own shard, or zeros
+    where the row is another chip's, and one all-reduce of the row
+    (4 MiB) hands it to all."""
+    if not _whole_shards(stack, mesh, axis):
+        return jax.tree_util.tree_map(lambda x: x[r], stack)
+    from jax.sharding import PartitionSpec as P
+
+    def local(stack, r):
+        shard = jax.lax.axis_index(axis)
+
+        def one(x):
+            at = r - shard * x.shape[0]
+            mine = (at >= 0) & (at < x.shape[0])
+            row = x[jnp.clip(at, 0, x.shape[0] - 1)]
+            return jax.lax.psum(jnp.where(mine, row, jnp.zeros_like(row)),
+                                axis)
+        return jax.tree_util.tree_map(one, stack)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(axis), P()),
+                         out_specs=P(), check_vma=False)(stack, r)
+
+
 @dataclasses.dataclass(frozen=True)
 class Program:
     """One program of the failure path: the jitted body and
@@ -121,14 +156,18 @@ class RecoveryPrograms:
         ch = self.chunk
         return -(-self.compiled.inflight_ring_steps // ch) * ch
 
+    def _replica(self, replicas, r):
+        """One replica log of the stack (:func:`take_row`)."""
+        return take_row(replicas, r, self.compiled.mesh,
+                        self.compiled.task_axis)
+
     # --- determinant fetch ---------------------------------------------------
 
     @_program("fetch")
     def fetch(self):
         cap = self.compiled.log_capacity
         return (lambda replicas, r, from_epoch: clog.get_determinants(
-                    jax.tree_util.tree_map(lambda x: x[r], replicas),
-                    from_epoch, cap),
+                    self._replica(replicas, r), from_epoch, cap),
                 lambda c: (c.replicas, _z(), _z()))
 
     @_program("fetch_meta")
@@ -163,8 +202,7 @@ class RecoveryPrograms:
 
         def f(replicas, r, from_epoch):
             buf, count, start = clog.get_determinants(
-                jax.tree_util.tree_map(lambda x: x[r], replicas),
-                from_epoch, cap)
+                self._replica(replicas, r), from_epoch, cap)
             tags = buf[:, det.LANE_TAG]
             rowmask = jnp.arange(cap) < count
             cond = (rowmask & (tags == det.TIMESTAMP)
@@ -357,7 +395,18 @@ class RecoveryPrograms:
             logs = jax.tree_util.tree_map(
                 lambda s, fr: s.at[flat].set(fr), carry.logs, fresh)
             replicas = carry.replicas
-            if nr > 0:
+            if nr > 0 and _whole_shards(replicas, compiled.mesh,
+                                        compiled.task_axis):
+                # A scatter into the sharded stack would gather it whole
+                # onto every chip (take_row); a select touches a chip's
+                # own shard only.
+                held = jnp.zeros((nr,), jnp.bool_).at[held_idx].set(
+                    True, mode="drop")
+                replicas = jax.tree_util.tree_map(
+                    lambda s, fr: jnp.where(
+                        held.reshape((nr,) + (1,) * fr.ndim), fr, s),
+                    replicas, fresh)
+            elif nr > 0:
                 replicas = jax.tree_util.tree_map(
                     lambda s, fr: s.at[held_idx].set(
                         jnp.broadcast_to(fr, held_idx.shape + fr.shape),
@@ -414,9 +463,8 @@ class RecoveryPrograms:
 
         def f(replicas, r, from_epoch, used, ck_head,
               epoch_offs, epoch_mask, latest, base):
-            rep_one = jax.tree_util.tree_map(lambda x: x[r], replicas)
             buf, _cnt, _start = clog.get_determinants(
-                rep_one, from_epoch, cap)
+                self._replica(replicas, r), from_epoch, cap)
             st = clog.create(cap, me)
             st = st._replace(head=ck_head, tail=ck_head)
             st = clog.append(st, buf, used)

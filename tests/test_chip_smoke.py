@@ -104,6 +104,15 @@ def test_part_u_tiny_union_by_rank_against_the_step_forms_scan():
     assert rows > 512 and sent // 2 < kept < sent
 
 
+def test_part_x_tiny_cascade_on_a_mesh_of_four(tmp_path):
+    # nexmark-q3-x4's stand-in: 8 subtasks a vertex over four forced
+    # host devices, the default sharing depth, the cell's own kill
+    x = chip_smoke.check_cascade_on_the_mesh(
+        21, str(tmp_path / "ck"), config="tiny-nexmark-q3-x4", tiny=True)
+    assert (x["victims"], x["fetch_hops"], x["epochs"]) == (4, 1, 5)
+    assert x["rows"] > 500 and x["fullest_gib"] < 0.26 * x["carry_gib"]
+
+
 def test_main_refuses_to_run_without_a_tpu(capsys):
     assert chip_smoke.main([]) != 0
     out = capsys.readouterr()

@@ -157,11 +157,13 @@ def _served_runner(tmp_path, tag):
     return r
 
 
-#: kill shape -> (job, victims as (vertex, subtask) pairs). Not here: a
-#: connected cascade, which leaves a log fewer holders than any log has
-#: whole and so builds ``fetch_meta`` at that count on the failure path
-#: (ROADMAP S6; a drill by the same victims builds it in set-up).
+#: kill shape -> (job, victims as (vertex, subtask) pairs). A connected
+#: cascade leaves a log fewer holders than any log has whole:
+#: ``fetch_meta`` asks at the whole count and the rows past the
+#: survivors repeat the first (PR 51; it built the program at the
+#: smaller count on the failure path before).
 KILL_SHAPES = {
+    "connected-cascade": ("served", [(0, 1), (1, 1)]),
     "one-keyed-subtask": ("wc", [(1, 1)]),
     "two-subtasks-of-a-vertex": ("wc", [(1, 0), (1, 1)]),
     "pure-sink": ("wc", [(2, 1)]),
