@@ -85,12 +85,15 @@ it pass off the device:
      blocks of 1,024 steps, its union packed by rank (the block form: a
      running count and three keyed histograms) and once more with the
      union's block form patched to the scan of its step form (a stable
-     sort and four gathers a step). Pass = both committed streams equal
-     the topology's NumPy reference. Then a union that overflows: two
-     keyed streams of ~96 and ~64 records a subtask a step into 160,
-     the same two forms, equal streams and fewer rows than records. Run
-     it on the chip after any change to ``UnionOperator`` (D17). Not in
-     the default parts.
+     sort and four gathers a step), and a third time with the keyed-
+     state mapper's read-back patched to its gather form (the job's 200
+     keys take the dense compare over the key lanes; PR 52). Pass = all
+     three committed streams equal the topology's NumPy reference. Then
+     a union that overflows: two keyed streams of ~96 and ~64 records a
+     subtask a step into 160, the union's two forms, equal streams and
+     fewer rows than records. Run it on the chip after any change to
+     ``UnionOperator`` or to ``KeyedReduceOperator.process_block``
+     (D17). Not in the default parts.
   X  ``nexmark-q3-x4`` at the cell's own shape, when there are four
      chips: the carry built under its shardings (16 GiB, 14 of them one
      leaf of 3,584 replica logs: every chip a quarter of every sharded
@@ -893,31 +896,53 @@ def check_union_in_a_job(seed: int, spe: int = 2048, epochs: int = 3,
     """Part U: the committed stream of the ``allround-event-time`` job
     in blocks of 1,024 steps with its union packed by rank against the
     same job with the union's block form patched to the scan of its step
-    form, both against the topology's plain reference; then the same two
-    forms of a union that overflows, against each other. Returns (rows
+    form, and against the same job with the keyed-state mapper's
+    read-back patched from the dense compare to the gather, all three
+    against the topology's plain reference; then the union's two forms
+    of a union that overflows, against each other. Returns (rows
     compared with the reference, rows the overflowing union committed,
     records it was sent)."""
     import contextlib
     from unittest import mock
 
+    import jax
     from clonos_tpu.api import operators as ops
     from clonos_tpu.api.environment import StreamEnvironment
 
     config = "tiny-allround-upstream" if tiny else "allround-upstream"
     cfg, stream, ref, build = bench_topology(config, spe, seed, tiny)
-    forms = (("packed by rank", contextlib.nullcontext()),
-             ("the step form's scan", mock.patch.object(
-                 ops.UnionOperator, "process_block",
-                 ops.TwoInputOperator.process_block)))
+    unions = (("packed by rank", contextlib.nullcontext()),
+              ("the step form's scan", mock.patch.object(
+                  ops.UnionOperator, "process_block",
+                  ops.TwoInputOperator.process_block)))
+    if not 0 < cfg["num_keys"] <= ops._DENSE_READBACK_KEYS:
+        raise AssertionError(f"{cfg['num_keys']} keys: the first two runs "
+                             f"do not take the dense read-back")
+    forms = tuple(u + (False,) for u in unions) + ((
+        "the read-back by a gather",
+        mock.patch.object(ops, "_DENSE_READBACK_KEYS", 0), True),)
+
+    gathers = []        # per read-back traced: did it hold a gather?
+
+    def read_running(acc_end, keys, real=ops._read_running):
+        # a function of its own a call: a trace cached under ``real``
+        # would answer for another value of the constant
+        gathers.append(" gather[" in str(jax.make_jaxpr(
+            lambda a, k: real(a, k))(acc_end, keys)))
+        return real(acc_end, keys)
 
     def committed(graph, form, what):
-        with form:
+        with form, mock.patch.object(ops, "_read_running", read_running):
             return committed_by_epoch(graph, stream, seed, spe, epochs,
                                       1024, what)
 
     want = ref.expected(cfg, stream.keys, stream.vals, epochs)
-    for name, form in forms:
+    for name, form, by_gather in forms:
+        del gathers[:]
         got, runner = committed(build(cfg), form, "union")
+        if set(gathers) != {by_gather}:
+            raise AssertionError(f"union, {name}: the block program's "
+                                 f"read-backs held a gather: {gathers}")
         compiled = runner.executor.compiled
         (union,) = (v for v in compiled.job.vertices if v.name == "union")
         widths = [compiled.edge_plans[i].width
@@ -953,7 +978,7 @@ def check_union_in_a_job(seed: int, spe: int = 2048, epochs: int = 3,
         return env.build()
 
     rows = []
-    for name, form in forms:
+    for name, form in unions:
         got, _ = committed(overflowing(), form, "overflowing union")
         rows.append(sort_rows(np.concatenate(
             [np.asarray(r).reshape(-1, 3) for e in sorted(got)
@@ -1176,9 +1201,10 @@ def main(argv=None) -> int:
         rows, kept, sent = check_union_in_a_job(args.seed)
         mark = print_routes(tracer, mark, "U")
         say(f"U pass: the union at 8 x 1,024 x (256 + 384) -> 256 in a "
-            f"job, packed by rank == the step form's scan == the reference "
-            f"over {rows} rows; overflowing, the two forms agree on "
-            f"{kept} rows of {sent} records "
+            f"job, packed by rank == the step form's scan == the keyed "
+            f"read-back by a gather (dense in the other two) == the "
+            f"reference over {rows} rows; overflowing, the union's two "
+            f"forms agree on {kept} rows of {sent} records "
             f"({time.monotonic() - t0:.1f}s)")
 
     shape = ServedShape()

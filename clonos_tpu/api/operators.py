@@ -352,6 +352,35 @@ class SyntheticSource(Operator):
         return {"seq": state["seq"] + n * K}, out
 
 
+#: widest ``num_keys`` whose running values ``KeyedReduceOperator
+#: .process_block`` reads back by a compare over the key lanes; a wider
+#: table is read by a gather. The gather costs ~10-13 ns a slot whatever
+#: the table, the compare ~0.9-2.2 ps a slot a key lane: alone on a v5e at
+#: ``keys s32[1024, 8, 512]``, ms a call at 200 / 1,024 / 4,096 / 8,192 /
+#: 16,384 keys, the gather 43.6 / 43.8 / 54.2 / 54.4 / 54.7, the compare
+#: 1.85 / 4.13 / 15.0 / 32.7 / 67.2 — they cross near 13,400 keys
+_DENSE_READBACK_KEYS = 8192
+
+
+def _read_running(acc_end: jnp.ndarray, keys: jnp.ndarray) -> jnp.ndarray:
+    """``out[k, p, b] = acc_end[k, p, clip(keys[k, p, b], 0, nk - 1)]``:
+    every slot's running value out of its (step, subtask) row's table, a
+    key past the table reading the last key's as the step form's
+    ``new_acc[b.keys]`` does. Up to ``_DENSE_READBACK_KEYS`` key lanes
+    with no gather: one compare, one select and one sum over the key
+    lanes, which fuse (no ``[K, P, B, nk]`` array exists, and no scratch
+    at any width); one lane matches, so the sum is that lane's value bit
+    for bit."""
+    K, p, nk = acc_end.shape
+    if nk > _DENSE_READBACK_KEYS:
+        return jnp.take_along_axis(
+            acc_end.reshape(K * p, nk), keys.reshape(K * p, -1), axis=1,
+            mode="clip").reshape(keys.shape)
+    hit = (jnp.clip(keys, 0, nk - 1)[..., None]
+           == jnp.arange(nk, dtype=jnp.int32))
+    return jnp.sum(jnp.where(hit, acc_end[:, :, None, :], 0), axis=-1)
+
+
 @dataclasses.dataclass
 class KeyedReduceOperator(Operator):
     """Running keyed reduce over a dense key table (keyed-state analog of the
@@ -361,6 +390,11 @@ class KeyedReduceOperator(Operator):
     only its own keys (``CompiledJob._plan_edges`` defines the property and
     plans from it), so tables never conflict. Emits the updated running
     value for every input record (Flink reduce semantics).
+
+    A key is in ``[0, num_keys)``. A valid record with a key past the
+    table adds to no sum and leaves with the LAST key's running value, in
+    the step form (``new_acc[b.keys]`` clamps) and in both read-backs of
+    the block form (:func:`_read_running`) alike.
     """
 
     num_keys: int
@@ -406,22 +440,16 @@ class KeyedReduceOperator(Operator):
         if self.reduce_fn is not jnp.add:
             return super().process_block(state, batches, bctx)
         from clonos_tpu.ops.histogram import keyed_hist
-        K, p, _ = batches.keys.shape
-        nk = self.num_keys
         acc0 = state["acc"]                               # [P, nk]
         contrib, _ = keyed_hist(batches.keys, batches.values,
-                                batches.valid, nk,
+                                batches.valid, self.num_keys,
                                 want_counts=False)        # [K, P, nk]
         with jax.named_scope("segsum"):
             cum = jnp.cumsum(contrib, axis=0)             # inclusive prefix
             acc_end = acc0[None] + cum                    # [K, P, nk]
         with jax.named_scope("readback"):
-            out_vals = jnp.where(
-                batches.valid,
-                jnp.take_along_axis(
-                    acc_end.reshape(K * p, nk),
-                    batches.keys.reshape(K * p, -1), axis=1
-                ).reshape(batches.keys.shape), 0)
+            out_vals = jnp.where(batches.valid,
+                                 _read_running(acc_end, batches.keys), 0)
         return ({"acc": acc0 + cum[-1]},
                 zero_invalid(batches._replace(values=out_vals)))
 

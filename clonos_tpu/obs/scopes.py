@@ -38,8 +38,10 @@ same names where they run the same code. A path reads
                     (``SessionWindowOperator.process_block``)
 ``.../emit``        a fire's rows, compacted (``_emit`` of the window join,
                     the windowed top and the session window)
-``.../readback``    the running value read back per record
-                    (``KeyedReduceOperator.process_block*``)
+``.../readback``    the running value read back per record: a compare
+                    over the key lanes, a gather from a wide table
+                    (``KeyedReduceOperator.process_block``), a static
+                    gather (``process_block_static_keys``)
 ``.../compact``     records packed by rank to the front: both inputs'
                     (``UnionOperator.process_block``), a chunk's
                     (``_ChunkedJoin._packed``)

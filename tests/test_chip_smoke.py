@@ -97,8 +97,10 @@ def test_part_q_tiny_best_in_interval_in_wide_blocks_against_narrow_ones():
 
 
 def test_part_u_tiny_union_by_rank_against_the_step_forms_scan():
-    # the tiny stand-in's union (64 + 448 slots into 64), then 48 + 32
-    # records a subtask a step into 80
+    # the tiny stand-in's union (64 + 448 slots into 64), a third run of
+    # the job with the keyed-state mapper's read-back by a gather (PR 52:
+    # each run's block program is checked for the form it traced), then
+    # 48 + 32 records a subtask a step into 80
     rows, kept, sent = chip_smoke.check_union_in_a_job(21, spe=128,
                                                        epochs=4, tiny=True)
     assert rows > 512 and sent // 2 < kept < sent

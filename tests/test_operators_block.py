@@ -26,6 +26,13 @@ def _bctx(times=None):
         subtask=jnp.arange(P, dtype=jnp.int32))
 
 
+def _block_ctx(k, p):
+    t = jnp.arange(k, dtype=jnp.int32)
+    return BlockContext(times=t, rng_bits=t, epoch=jnp.zeros((), jnp.int32),
+                        step0=jnp.zeros((), jnp.int32),
+                        subtask=jnp.arange(p, dtype=jnp.int32))
+
+
 def _batches(seed=0):
     rng = np.random.RandomState(seed)
     keys = rng.randint(0, NK, (K, P, B)).astype(np.int32)
@@ -107,6 +114,78 @@ def test_reduce_static_keys_equals_dynamic():
     _assert_equal(dyn, sta)
 
 
+def _reduce_inputs(nk, k, p, b, seed=11):
+    """A ``[k, p, b]`` block for a keyed reduce over ``nk`` keys: rows
+    whose slots all carry one key, rows with no record and rows all
+    valid, one valid record in ten with a key past the table (the
+    largest int32 among them), values over +-2**30 (sums pass 2**16 and
+    wrap), and garbage in the invalid lanes."""
+    rng = np.random.RandomState(seed)
+    full = lambda: rng.randint(-2 ** 31, 2 ** 31, (k, p, b),
+                               dtype=np.int64).astype(np.int32)
+    keys = rng.randint(0, nk, (k, p, b)).astype(np.int32)
+    keys[0, 0] = nk - 1                     # a row of duplicates
+    keys[-1, :, : b // 2] = keys[-1, :, :1]
+    past = rng.rand(k, p, b) < 0.1
+    past[0, 0, 1] = past[0, 0, 2] = True
+    keys[past] = nk + rng.randint(0, 1000, int(past.sum()))
+    keys[0, 0, 1] = 2 ** 31 - 1
+    keys[0, 0, 2] = nk                      # the first key past the table
+    vals = rng.randint(-2 ** 30, 2 ** 30, (k, p, b)).astype(np.int32)
+    valid = rng.rand(k, p, b) < 0.7
+    valid[0] = True
+    if k > 2:
+        valid[1] = False
+    keys, vals = np.where(valid, keys, full()), np.where(valid, vals, full())
+    return RecordBatch(*(jnp.asarray(a)
+                         for a in (keys, vals, full(), valid))), past & valid
+
+
+@pytest.mark.parametrize("form", ["dense", "gather"])
+@pytest.mark.parametrize("nk,k,p,b", [
+    (5, K, P, B), (200, K, P, 130), ("widest", 3, 2, 200),
+    ("widest+1", 3, 2, 200)])
+def test_reduce_block_reads_back_what_the_step_form_reads(nk, k, p, b, form,
+                                                          monkeypatch):
+    """``KeyedReduceOperator.process_block``'s two read-backs — the dense
+    compare over the key lanes (tables up to ``_DENSE_READBACK_KEYS``
+    wide) and the gather (wider ones), each forced on every width by
+    patching the constant — against the scan of ``process``, state and
+    rows bit for bit: duplicates of a key in a row carry one value,
+    invalid slots leave as zeros whatever they held, a ``B`` that is no
+    multiple of 128, sums that pass 2**16 and wrap, and a valid record
+    whose key is past the table reads the last key's running value (the
+    block form used to say -2**31 there)."""
+    from clonos_tpu.api import operators
+    widest = operators._DENSE_READBACK_KEYS
+    nk = {"widest": widest, "widest+1": widest + 1}.get(nk, nk)
+    monkeypatch.setattr(operators, "_DENSE_READBACK_KEYS",
+                        nk if form == "dense" else nk - 1)
+    op = KeyedReduceOperator(num_keys=nk)
+    batches, past = _reduce_inputs(nk, k, p, b)
+    bctx = _block_ctx(k, p)
+    state = {"acc": jnp.asarray(np.random.RandomState(3).randint(
+        -2 ** 20, 2 ** 20, (p, nk)).astype(np.int32))}
+    took = str(jax.make_jaxpr(op.process_block)(state, batches, bctx))
+    assert (" gather[" in took) == (form == "gather")
+    ref = jax.jit(lambda s, b, c: _scan_reference(op, s, b, c))(
+        state, batches, bctx)
+    blk = jax.jit(op.process_block)(state, batches, bctx)
+    _assert_equal(ref, blk)
+    # a key past the table reads the table's last key, through its step
+    assert past.sum() > 2
+    keys, vals, valid = (np.asarray(x) for x in (
+        batches.keys, batches.values, batches.valid))
+    last = np.asarray(state["acc"])[:, -1] + np.cumsum(
+        np.where(valid & (keys == nk - 1), vals, 0).sum(-1, dtype=np.int64),
+        axis=0)                                            # [k, p], exact
+    want = np.broadcast_to(last.astype(np.int32)[..., None], past.shape)
+    np.testing.assert_array_equal(np.asarray(blk[1].values)[past],
+                                  want[past])
+    for f in (blk[1].keys, blk[1].values, blk[1].timestamps):
+        assert not np.asarray(f)[~np.asarray(blk[1].valid)].any()
+
+
 def test_two_input_union_block_equals_scan():
     op = UnionOperator(capacity=2 * B)
     left, right = _batches(1), _batches(2)
@@ -161,10 +240,7 @@ def test_union_block_packs_by_rank_what_the_step_form_sorts(case):
     cap, left, right = _union_inputs(case)
     op = UnionOperator(capacity=cap)
     k, p = left.valid.shape[:2]
-    t = jnp.arange(k, dtype=jnp.int32)
-    bctx = BlockContext(times=t, rng_bits=t, epoch=jnp.zeros((), jnp.int32),
-                        step0=jnp.zeros((), jnp.int32),
-                        subtask=jnp.arange(p, dtype=jnp.int32))
+    bctx = _block_ctx(k, p)
     ref = jax.jit(lambda s, b, c: TwoInputOperator.process_block(
         op, s, b, c))((), (left, right), bctx)[1]
     blk = jax.jit(op.process_block)((), (left, right), bctx)[1]

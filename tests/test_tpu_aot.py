@@ -517,16 +517,11 @@ def test_window_join_block_form_compiles_at_the_cells_widths(v5e):
     assert exe.memory_analysis().temp_size_in_bytes < K * P * E * C
 
 
-def test_union_block_form_compiles_at_the_cells_widths(v5e):
-    """``allround-upstream``'s ``union`` vertex at its own widths — the
-    tumbling window's rows over a static route 256 wide and the sliding
-    window's over one 384 wide, into 256 — over a whole block of 1,024
-    steps of 8 subtasks, inside the job's block program: under
-    ``vertex/union`` no sort, no gather, no scatter and no loop (until
-    PR 50 a stable sort of the 640 slots and four gathers of 2,097,152
-    elements); the compaction takes the Mosaic kernel, ``[8192, 640] ->
-    256`` a field; and the five keyed edges are planned as at the tiny
-    size (``tests/test_allround_event_time.py``)."""
+def allround_upstream_job():
+    """(``allround-upstream``'s configuration, its job compiled, its
+    planned edges by the vertices' names): the logs and rings at a size a
+    test can describe quickly; the vertices, their edges and the block's
+    steps as the cell has them."""
     import json
     import os
     import sys
@@ -540,8 +535,6 @@ def test_union_block_form_compiles_at_the_cells_widths(v5e):
     from clonos_tpu.runtime.executor import CompiledJob
     with open(os.path.join(bench, "configs", "allround-upstream.json")) as f:
         cfg = json.load(f)
-    # the logs and rings at a size this test can describe quickly; the
-    # vertices, their edges and the block's steps as the cell has them
     compiled = CompiledJob(
         module_at(job.topology_file(cfg, "job.py")).build(cfg),
         log_capacity=8192, max_epochs=8, inflight_ring_steps=2048)
@@ -549,6 +542,20 @@ def test_union_block_form_compiles_at_the_cells_widths(v5e):
     plans = {(names[e.src], names[e.dst]): compiled.edge_plans[i]
              for i, e in enumerate(compiled.job.edges)
              if i in compiled.edge_plans}
+    return cfg, compiled, plans
+
+
+def test_union_block_form_compiles_at_the_cells_widths(v5e):
+    """``allround-upstream``'s ``union`` vertex at its own widths — the
+    tumbling window's rows over a static route 256 wide and the sliding
+    window's over one 384 wide, into 256 — over a whole block of 1,024
+    steps of 8 subtasks, inside the job's block program: under
+    ``vertex/union`` no sort, no gather, no scatter and no loop (until
+    PR 50 a stable sort of the 640 slots and four gathers of 2,097,152
+    elements); the compaction takes the Mosaic kernel, ``[8192, 640] ->
+    256`` a field; and the five keyed edges are planned as at the tiny
+    size (``tests/test_allround_event_time.py``)."""
+    cfg, compiled, plans = allround_upstream_job()
     assert {k: p.route for k, p in plans.items()} == {
         ("event-time", "keyed-state"): "dynamic",
         ("operator-state", "tumbling"): "identity",
@@ -574,6 +581,45 @@ def test_union_block_form_compiles_at_the_cells_widths(v5e):
     for op in ("while", "scatter", "gather", "sort"):
         assert not [line for line in mine
                     if re.search(rf"= \S+ {op}\(", line)], op
+
+
+@pytest.mark.parametrize("past", [False, True],
+                         ids=["200-keys-dense", "past-the-constant-gather"])
+def test_keyed_readback_compiles_without_a_gather_at_the_cells_widths(
+        v5e, past, monkeypatch):
+    """``allround-upstream``'s ``keyed-state`` vertex — the keyed mapper
+    behind the job's one dynamic exchange, 8 subtasks x 512 receive slots
+    x 1,024 steps read out of ``[1024, 8, 200]`` running counts — inside
+    the job's block program: under ``vertex/keyed-state`` no gather (until
+    PR 52 a ``take_along_axis`` of 4,194,304 elements, half of the
+    block), no scatter, no loop, and nothing anywhere the size of slots
+    x key lanes (3.4 GB: the compare, the select and the sum fuse). The
+    same job one key past ``_DENSE_READBACK_KEYS`` keeps the gather (the
+    constant set to 199: at 8,193 keys the job's windows and rings need
+    30 GB, which the compiler refuses)."""
+    from clonos_tpu.api import operators
+    keys = 200
+    assert keys <= operators._DENSE_READBACK_KEYS
+    if past:
+        monkeypatch.setattr(operators, "_DENSE_READBACK_KEYS", keys - 1)
+    cfg, compiled, plans = allround_upstream_job()
+    edge = plans["event-time", "keyed-state"]
+    K, P, B = cfg["block_steps"], cfg["parallelism"], edge.width
+    assert (edge.route, K, P, B) == ("dynamic", 1024, 8, 512)
+    mesh = Mesh(np.array(v5e[:1]), ("tasks",))
+    with histogram.kernel_mesh(mesh, "tasks"):
+        lowered, _ = lower_block(compiled, K, SingleDeviceSharding(v5e[0]))
+    exe = lowered.compile()
+    mine = [line for line in exe.as_text().splitlines()
+            if "vertex/keyed-state" in line]
+    assert any("keyed-state/readback" in line for line in mine)
+    found = {op: [line for line in mine
+                  if re.search(rf"= \S+ {op}\(", line)]
+             for op in ("while", "scatter", "gather")}
+    assert bool(found["gather"]) == past
+    assert not found["while"] and not found["scatter"]
+    # all of the program's scratch is under a byte a slot a key lane
+    assert exe.memory_analysis().temp_size_in_bytes < K * P * B * keys
 
 
 @pytest.mark.parametrize("shape,rung,gathers", [
